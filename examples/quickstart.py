@@ -1,7 +1,8 @@
 """grapevine-tpu quickstart: server + two clients, end to end.
 
-Runs entirely in-process on the CPU backend (no TPU needed — the same
-code drives a TPU engine unchanged). Demonstrates the full reference
+Runs entirely in-process, on whatever platform JAX gives it (a TPU if
+one is attached; ``JAX_PLATFORMS=cpu`` for a machine without one — the
+same code either way). Demonstrates the full reference
 workflow (reference README.md:126-175): attested-style Auth handshake,
 challenge-signed queries, CRUD on fixed-size records, zero-id "next
 message" semantics, and the expiry sweep.
@@ -14,13 +15,6 @@ import sys
 import time
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
-# default to the CPU backend so the demo runs anywhere; set
-# GRAPEVINE_PLATFORM=tpu to drive real hardware
-_platform = os.environ.get("GRAPEVINE_PLATFORM", "cpu")
-os.environ["JAX_PLATFORMS"] = _platform
-import jax
-
-jax.config.update("jax_platforms", _platform)
 
 from grapevine_tpu.config import GrapevineConfig
 from grapevine_tpu.server.client import GrapevineClient

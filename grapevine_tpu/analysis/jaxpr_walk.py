@@ -52,9 +52,12 @@ def site_of(eqn, pkg: str = "grapevine_tpu") -> str:
     the innermost user frame (preferring frames inside ``pkg``).
 
     The allowlist (:mod:`.allowlist`) is keyed on these, so the key must
-    survive line churn: function granularity, no line numbers. Returns
-    ``"<unknown>"`` when the trace carries no usable frames (e.g. a
-    jaxpr rebuilt without source info)."""
+    survive line churn: function granularity, no line numbers. The
+    function is its bare name — the traceback reports qualified names
+    (``phase_a_batch.<locals>.apply_batch``, ``_DenseGroups.select_by_rank``)
+    and this is the one place that reduces them to the last component.
+    Returns ``"<unknown>"`` when the trace carries no usable frames
+    (e.g. a jaxpr rebuilt without source info)."""
     tb = getattr(eqn.source_info, "traceback", None)
     frames = list(tb.frames) if tb is not None else []
     best = None
@@ -64,10 +67,11 @@ def site_of(eqn, pkg: str = "grapevine_tpu") -> str:
             continue  # an analyzer's own make_jaxpr frame, never a site
         if f"/{pkg}/" in fn or fn.startswith(f"{pkg}/"):
             tail = fn.split(f"{pkg}/")[-1]
-            return f"{tail}:{fr.function_name}"
+            return f"{tail}:{fr.function_name.rsplit('.', 1)[-1]}"
         if best is None and "site-packages" not in fn and "/jax/" not in fn \
                 and not fn.endswith("/jax.py"):
-            best = f"{fn.rsplit('/', 1)[-1]}:{fr.function_name}"
+            best = (f"{fn.rsplit('/', 1)[-1]}:"
+                    f"{fr.function_name.rsplit('.', 1)[-1]}")
     return best or "<unknown>"
 
 

@@ -46,8 +46,8 @@ from typing import Callable, Iterable
 from .jaxpr_walk import _sub_jaxprs, census, site_of
 
 #: sink table: primitive -> (kind, fn(eqn) -> index operand atoms)
-_CALLBACK_PRIMS = ("debug_callback", "pure_callback", "io_callback",
-                   "host_callback_call", "outside_call")
+_CALLBACK_PRIMS = ("debug_callback", "debug_print", "pure_callback",
+                   "io_callback", "host_callback_call", "outside_call")
 
 EMPTY: frozenset = frozenset()
 

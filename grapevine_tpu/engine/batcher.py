@@ -31,6 +31,7 @@ order — never completion order (OPERATIONS.md §16).
 
 from __future__ import annotations
 
+import logging
 import threading
 import time
 from collections import deque
@@ -58,6 +59,8 @@ from .state import (
 from .metrics import EngineMetrics
 from .round_step import engine_flush_step, engine_round_step
 from .step import engine_step
+
+_log = logging.getLogger(__name__)
 
 
 def pack_batch(reqs: list[QueryRequest], batch_size: int, now: int) -> dict:
@@ -256,7 +259,6 @@ class GrapevineEngine:
                  durability: DurabilityConfig | None = None):
         self.config = config or GrapevineConfig()
         self.ecfg = EngineConfig.from_config(self.config)
-        self.state: EngineState = init_engine(self.ecfg, seed)
         #: bucket-axis sharding (config.py ``shards``; parallel/mesh.py):
         #: at shards > 1 the step/flush dispatch through the shard_map'd
         #: programs on a mesh over the first N devices. The adapters
@@ -266,9 +268,14 @@ class GrapevineEngine:
         #: downstream (journal, checkpoint, leakmon, oracle suites) can
         #: tell the difference.
         self._mesh = None
+        #: per-leaf NamedSharding of the live state at shards > 1 (None
+        #: single-chip): a restored checkpoint is placed shard by shard
+        #: through these, never staged whole on the first device
+        state_shardings = None
         if self.config.shards > 1:
             from ..parallel import (
-                make_mesh, make_sharded_step, shard_engine_state,
+                init_sharded_engine, make_mesh, make_sharded_step,
+                make_sharded_sweep,
             )
 
             devs = jax.devices()
@@ -279,12 +286,30 @@ class GrapevineEngine:
                     "one contiguous heap range per device"
                 )
             self._mesh = make_mesh(devs[: self.config.shards])
-            self._shard_state = shard_engine_state
-            self.state = shard_engine_state(self.state, self._mesh)
+            # created directly sharded: a mesh exists because one chip
+            # cannot hold the trees, so they must never be staged on one
+            self.state: EngineState = init_sharded_engine(
+                self.ecfg, self._mesh, seed
+            )
+            state_shardings = jax.tree.map(lambda x: x.sharding, self.state)
+            if self.config.bucket_cipher_impl == "pallas_fused":
+                # said once, at build: the fused gather/scatter kernels
+                # are single-chip (tree plaintext must not transit ICI)
+                _log.warning(
+                    "bucket_cipher_impl='pallas_fused' with shards=%d "
+                    "runs as 'pallas': the sharded round gathers, psums "
+                    "and only then decrypts with the Pallas cipher "
+                    "kernel; the fused gather/scatter kernels do not "
+                    "engage under shard_map",
+                    self.config.shards,
+                )
             sstep = make_sharded_step(self.ecfg, self._mesh)
             step_fn = lambda _ecfg, state, batch: sstep(state, batch)  # noqa: E731
             self._step = step_fn
+            ssweep = make_sharded_sweep(self.ecfg, self._mesh)
+            self._sweep = lambda _ecfg, state, *clock: ssweep(state, *clock)
         else:
+            self.state = init_engine(self.ecfg, seed)
             step_fn = (engine_round_step if self.config.commit == "phase"
                        else engine_step)
             # donate the state: trees update in place (no per-round copy,
@@ -293,9 +318,9 @@ class GrapevineEngine:
             self._step = jax.jit(
                 step_fn, static_argnums=(0,), donate_argnums=(1,)
             )
-        self._sweep = jax.jit(
-            expiry_sweep, static_argnums=(0,), donate_argnums=(1,)
-        )
+            self._sweep = jax.jit(
+                expiry_sweep, static_argnums=(0,), donate_argnums=(1,)
+            )
         #: delayed batched eviction (PR 15, config.py evict_every): the
         #: resolved cadence E and the jitted flush program. Flush fires
         #: strictly every E dispatched rounds — a pure function of the
@@ -330,21 +355,18 @@ class GrapevineEngine:
         #: a journal written at depth 2 replays bit-identically on a
         #: depth-1 engine (replay order is journal order at every
         #: depth; tests/test_pipeline.py pins the cross-depth restore).
-        #: Auto: 2 on TPU backends (the device round is the long pole —
+        #: Auto: 2 on the TPU (the device round is the long pole —
         #: overlapping host work and the journal fsync behind it is the
-        #: whole win, priced on-chip by tools/tpu_capture.py
-        #: ``pipeline_perf``), 1 elsewhere — on a host-bound CPU the
+        #: whole win; not measured on the chip), 1 on the CPU — there the
         #: extra in-flight round buys no overlap but costs up to one
         #: full device round of open-loop commit latency (measured:
         #: PERF.md Round 11; the vphases/sort flip-on-evidence playbook)
         if self.config.pipeline_depth is not None:
             self.pipeline_depth = self.config.pipeline_depth
         else:
-            from ..config import TPU_BACKENDS
+            from ..config import on_tpu
 
-            self.pipeline_depth = (
-                2 if jax.default_backend() in TPU_BACKENDS else 1
-            )
+            self.pipeline_depth = 2 if on_tpu() else 1
         self.metrics = EngineMetrics()
         #: last sampled per-tree eviction-buffer occupancy (health view)
         self._ebuf_counts: dict = {}
@@ -374,19 +396,13 @@ class GrapevineEngine:
             from .checkpoint import DurabilityManager
 
             self.durability = DurabilityManager(
-                durability, self.ecfg, registry=self.metrics.registry
+                durability, self.ecfg, registry=self.metrics.registry,
+                state_shardings=state_shardings,
             )
             with self.metrics.time_phase("replay"):
                 self.state = self.durability.recover(
                     self.state, self._replay_record
                 )
-                if self._mesh is not None:
-                    # a loaded checkpoint materializes host-side on the
-                    # default device; re-place it on the mesh so the
-                    # first live round doesn't pay an implicit reshard
-                    # (replayed rounds already ran the sharded program,
-                    # so this is a no-op re-placement in that case)
-                    self.state = self._shard_state(self.state, self._mesh)
                 jax.block_until_ready(self.state.free_top)
         if self.evict_every > 1:
             # cadence counter recovered FROM STATE, never from a host
@@ -466,8 +482,8 @@ class GrapevineEngine:
         early; ``flush_now`` passes 1). The async dispatch is the
         point: the flush rides the device queue behind the window's
         last round, filling the idle window the bubble-ratio gauge
-        prices (tools/tpu_capture.py ``evict_perf`` banks the on-chip
-        overlap number) — the ``flush`` phase series measures enqueue
+        prices (the overlap is not measured on the chip) — the
+        ``flush`` phase series measures enqueue
         cost; device time lands in the next round's ``evict`` wait
         like all device work."""
         if self._flush_step is None:

@@ -237,9 +237,16 @@ def state_to_bytes(ecfg: EngineConfig, state: EngineState) -> bytes:
     return b"".join(parts)
 
 
-def bytes_to_state(ecfg: EngineConfig, data: bytes) -> EngineState:
+def bytes_to_state(
+    ecfg: EngineConfig, data: bytes, shardings=None
+) -> EngineState:
     """Inverse of :func:`state_to_bytes`; rejects geometry mismatches and
-    truncated buffers whole (CheckpointError)."""
+    truncated buffers whole (CheckpointError).
+
+    ``shardings``: a pytree of one ``jax.sharding.Sharding`` per state
+    leaf (a mesh engine's live placement). Each leaf then goes from host
+    memory straight to its shards' devices; without it, to the default
+    device."""
     if len(data) < 4:
         raise CheckpointError("state payload truncated (no manifest)")
     (head_len,) = struct.unpack_from("<I", data, 0)
@@ -269,7 +276,13 @@ def bytes_to_state(ecfg: EngineConfig, data: bytes) -> EngineState:
         )
     off = 4 + head_len
     leaves = []
-    for (dt_str, shape), want in zip(decl, spec):
+    places = (jax.tree_util.tree_leaves(shardings)
+              if shardings is not None else [None] * len(spec))
+    if len(places) != len(spec):
+        raise ValueError(
+            f"shardings has {len(places)} leaves, state has {len(spec)}"
+        )
+    for (dt_str, shape), want, place in zip(decl, spec, places):
         dt = np.dtype(dt_str)
         shape = tuple(shape)
         if shape != tuple(want.shape) or dt.newbyteorder("=") != np.dtype(
@@ -283,7 +296,9 @@ def bytes_to_state(ecfg: EngineConfig, data: bytes) -> EngineState:
         if off + nbytes > len(data):
             raise CheckpointError("state payload truncated (leaf cut short)")
         arr = np.frombuffer(data, dt, count=nbytes // dt.itemsize, offset=off)
-        leaves.append(jax.numpy.asarray(arr.reshape(shape).astype(dt.newbyteorder("="))))
+        leaves.append(jax.device_put(
+            arr.reshape(shape).astype(dt.newbyteorder("=")), place
+        ))
         off += nbytes
     if off != len(data):
         raise CheckpointError(
@@ -337,11 +352,12 @@ def write_checkpoint(
 
 
 def load_checkpoint(
-    path: str, root_key: bytes, ecfg: EngineConfig
+    path: str, root_key: bytes, ecfg: EngineConfig, shardings=None
 ) -> tuple[int, EngineState]:
     """Load a sealed checkpoint; returns ``(seq, state)``. Any
     truncation, tamper, or geometry mismatch raises CheckpointError —
-    the state is never half-loaded."""
+    the state is never half-loaded. ``shardings``: see
+    :func:`bytes_to_state`."""
     with open(path, "rb") as fh:
         blob = fh.read()
     head = MAGIC + struct.pack("<I", VERSION)
@@ -357,7 +373,7 @@ def load_checkpoint(
     if len(payload) < 8:
         raise CheckpointError(f"{path}: payload truncated")
     (seq,) = struct.unpack_from("<Q", payload, 0)
-    return seq, bytes_to_state(ecfg, payload[8:])
+    return seq, bytes_to_state(ecfg, payload[8:], shardings)
 
 
 def find_latest_checkpoint(state_dir: str) -> tuple[int, str] | None:
@@ -407,11 +423,14 @@ class DurabilityManager:
     counts, and durations — never content."""
 
     def __init__(self, dcfg: DurabilityConfig, ecfg: EngineConfig,
-                 registry=None):
+                 registry=None, state_shardings=None):
         from .journal import BatchJournal
 
         self.dcfg = dcfg
         self.ecfg = ecfg
+        #: a mesh engine's per-leaf placement (bytes_to_state); None =
+        #: restored checkpoints go to the default device
+        self.state_shardings = state_shardings
         os.makedirs(dcfg.state_dir, exist_ok=True)
         key_path = dcfg.seal_key_file or os.path.join(
             dcfg.state_dir, "root.key"
@@ -485,7 +504,7 @@ class DurabilityManager:
         latest = find_latest_checkpoint(self.dcfg.state_dir)
         if latest is not None:
             seq, state = load_checkpoint(
-                latest[1], self.root_key, self.ecfg
+                latest[1], self.root_key, self.ecfg, self.state_shardings
             )
             if seq != latest[0]:
                 # the filename seq picks which file to load; the sealed
@@ -582,7 +601,9 @@ class DurabilityManager:
             os.fsync(dfd)
         finally:
             os.close(dfd)
-        got_seq, state = load_checkpoint(path, self.root_key, self.ecfg)
+        got_seq, state = load_checkpoint(
+            path, self.root_key, self.ecfg, self.state_shardings
+        )
         if got_seq != seq:
             raise CheckpointError(
                 f"{path}: shipped checkpoint payload seq {got_seq} != "

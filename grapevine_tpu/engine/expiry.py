@@ -16,6 +16,15 @@ chunks under ``lax.scan``: decrypt chunk → expire → re-encrypt under the
 next epoch, all inside one scan body — at no point does more than one
 chunk of plaintext exist in HBM (a mid-sweep memory snapshot exposes at
 most ~8 M words, not the bus).
+
+On a mesh (``axis_name`` set, under ``parallel.make_sharded_sweep``'s
+shard_map) each chip sweeps the contiguous heap range of buckets it
+owns and the two cross-tree facts — which message ids survive, how many
+recipients remain — are summed over the axis once per tree; everything
+else is replicated private state that every chip sweeps identically.
+Plain ``jit`` cannot do this: GSPMD will not partition a scan over the
+sharded chunk axis, replicates the trees instead, and a 2^22 bus on
+four v5e chips then fails to compile for lack of HBM.
 """
 
 from __future__ import annotations
@@ -86,20 +95,23 @@ def _expired(ts_lo, ts_hi, now_lo, now_hi, period) -> jnp.ndarray:
     return le & ((d_hi > 0) | (d_lo > period))
 
 
-def _chunk_rows(cfg: OramConfig) -> int:
-    """Rows per scan chunk: power of two, ~8M words of keystream."""
-    n = cfg.n_buckets_padded
+def _chunk_rows(cfg: OramConfig, n: int) -> int:
+    """Rows per scan chunk of an ``n``-row (local) tree: power of two,
+    ~8M words of keystream."""
     rpc = 1
     while rpc * 2 <= n and rpc * 2 * cfg.row_words <= (1 << 23):
         rpc *= 2
     return rpc
 
 
-def _chunked_tree_sweep(cfg: OramConfig, oram: OramState, carry0, body):
+def _chunked_tree_sweep(cfg: OramConfig, oram: OramState, carry0, body,
+                        axis_name=None):
     """Run ``body(carry, (plaintext idx [rpc, Z], plaintext val
     [rpc, Z*V])) -> (carry, (idx', val'))`` over the whole tree in
     chunks, with per-chunk decrypt/re-encrypt when the cipher is on.
     Returns (carry, OramState with new tree + nonces/epoch advanced).
+    With ``axis_name`` the tree planes are this chip's rows only and
+    the carry is this chip's partial result (the caller reduces it).
 
     A recursive position map (cfg.posmap set, oram/posmap.py) adds the
     per-slot leaf-metadata plane, encrypted under the same per-bucket
@@ -109,10 +121,15 @@ def _chunked_tree_sweep(cfg: OramConfig, oram: OramState, carry0, body):
     the SENTINEL idx), but its ciphertext epoch must follow the bucket.
     """
     z, v = cfg.bucket_slots, cfg.value_words
-    n = cfg.n_buckets_padded
-    rpc = _chunk_rows(cfg)
+    n = oram.tree_val.shape[0]  # == n_buckets_padded off the mesh
+    rpc = _chunk_rows(cfg, n)
     nch = n // rpc
-    bids = jnp.arange(n, dtype=U32).reshape(nch, rpc)
+    bids = jnp.arange(n, dtype=U32)
+    if axis_name is not None:
+        # global heap ids of the owned rows: the keystream is keyed by them
+        base = jax.lax.axis_index(axis_name).astype(U32) * U32(n)
+        bids = bids + base
+    bids = bids.reshape(nch, rpc)
     idx3 = oram.tree_idx.reshape(nch, rpc, z)
     val3 = oram.tree_val.reshape(nch, rpc, z * v)
     eps = oram.nonces.reshape(nch, rpc, 2)
@@ -124,11 +141,14 @@ def _chunked_tree_sweep(cfg: OramConfig, oram: OramState, carry0, body):
     )
 
     delayed = cfg.delayed_eviction
-    tag3 = (
-        oram.fetch_tag.reshape(nch, rpc)
-        if delayed
-        else jnp.zeros((nch, rpc), U32)
-    )
+    if not delayed:
+        tag3 = jnp.zeros((nch, rpc), U32)
+    elif axis_name is None:
+        tag3 = oram.fetch_tag.reshape(nch, rpc)
+    else:  # the tag plane is replicated: take the owned rows' tags
+        tag3 = jax.lax.dynamic_slice(
+            oram.fetch_tag, (base,), (n,)
+        ).reshape(nch, rpc)
 
     def scan_body(carry, xs):
         bid, ix, vl, ep, lf, tg = xs
@@ -186,7 +206,8 @@ def _chunked_tree_sweep(cfg: OramConfig, oram: OramState, carry0, body):
 
 
 def expiry_sweep(
-    ecfg: EngineConfig, state: EngineState, now, period, now_hi=0
+    ecfg: EngineConfig, state: EngineState, now, period, now_hi=0,
+    axis_name=None,
 ) -> EngineState:
     now = U32(now)
     now_hi = U32(now_hi)
@@ -215,7 +236,12 @@ def expiry_sweep(
 
     present0 = jnp.zeros((n_msgs,), jnp.bool_)
     with device_phase("sweep_records"):
-        present, rec = _chunked_tree_sweep(rcfg, state.rec, present0, rec_body)
+        present, rec = _chunked_tree_sweep(
+            rcfg, state.rec, present0, rec_body, axis_name
+        )
+        if axis_name is not None:
+            # a message id survives if any chip's rows still hold it
+            present = jax.lax.psum(present.astype(U32), axis_name) > 0
 
         # tree-top cache planes (cfg.top_cache_levels > 0): the cached
         # top buckets' live blocks exist ONLY here — their HBM rows are
@@ -309,8 +335,10 @@ def expiry_sweep(
 
     with device_phase("sweep_mailbox"):
         recips, mb = _chunked_tree_sweep(
-            ecfg.mb, state.mb, jnp.zeros((), U32), mb_body
+            ecfg.mb, state.mb, jnp.zeros((), U32), mb_body, axis_name
         )
+        if axis_name is not None:
+            recips = jax.lax.psum(recips, axis_name)
         # mailbox tree-top cache: plaintext pass, stash standing (see
         # the records cache sweep above)
         if ecfg.mb.top_cache_levels:

@@ -439,10 +439,7 @@ class StandbyReplica:
                 )
             if seq <= self.dm.seq:
                 return
-            state = self.dm.install_checkpoint(seq, blob)
-            if eng._mesh is not None:
-                state = eng._shard_state(state, eng._mesh)
-            eng.state = state
+            eng.state = self.dm.install_checkpoint(seq, blob)
             # re-anchor the replay cadence audit at the new base
             eng._replay_since = None
 
@@ -570,10 +567,7 @@ class StandbyReplica:
                     # standby must have been fed continuously.
                     with open(latest[1], "rb") as fh:
                         blob = fh.read()
-                    state = self.dm.install_checkpoint(latest[0], blob)
-                    if eng._mesh is not None:
-                        state = eng._shard_state(state, eng._mesh)
-                    eng.state = state
+                    eng.state = self.dm.install_checkpoint(latest[0], blob)
                     eng._replay_since = None
                 reader = BatchJournal(
                     primary_state_dir, self.dm.root_key, self.dm.ecfg

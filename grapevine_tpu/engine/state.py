@@ -119,11 +119,10 @@ class EngineConfig:
             # directly (config.py knob docstrings). Resolved here —
             # engine construction time — because config objects must
             # stay importable without initializing a JAX backend.
-            from ..config import TPU_BACKENDS
+            from ..config import on_tpu
 
-            on_tpu = jax.default_backend() in TPU_BACKENDS
             if vimpl is None:
-                vimpl = "dense" if on_tpu else "scan"
+                vimpl = "dense" if on_tpu() else "scan"
             if simpl is None:
                 # "xla" on EVERY backend until measured otherwise: on
                 # XLA:CPU the native serial sort (~0.4 µs/elem) beats
@@ -131,15 +130,14 @@ class EngineConfig:
                 # PER scatter, one per pass — bench.py `sort_ab`,
                 # PERF.md Round 7); on TPU — where scatters vectorize
                 # and the bitonic lax.sort is the O(n log² n) side —
-                # the decision belongs to tools/tpu_capture.py's
-                # `sort_perf` A/B on a real chip (the vphases_impl
-                # playbook).
+                # the decision belongs to a `sort_perf` A/B on a real
+                # chip: not measured on the chip.
                 simpl = "xla"
         # position-map impl: auto resolves to "flat" on every backend —
         # the recursive map trades ~2× HBM path traffic per round for a
         # ~sqrt(blocks)× smaller resident footprint, a win only once
-        # capacity outgrows private memory (flip per OPERATIONS.md §13
-        # or after tools/tpu_capture.py posmap_perf prices it on-chip)
+        # capacity outgrows private memory (flip per OPERATIONS.md §13;
+        # not measured on the chip)
         pimpl = cfg.posmap_impl if cfg.posmap_impl is not None else "flat"
         # tree-top cache: auto = 4 on every backend under the phase
         # engine (0 under commit="op" — the differential oracle stays
@@ -156,9 +154,9 @@ class EngineConfig:
         rec_tc = min(tc, cfg.records_height)
         mb_tc = min(tc, cfg.mailbox_height)
         # delayed batched eviction (config.py evict_every): auto = 1 on
-        # every backend until tools/tpu_capture.py's evict_perf stage
-        # prices the flush-overlap win on a real chip (the
-        # vphases/sort/posmap/tree-cache flip-on-evidence playbook).
+        # every backend: the flush-overlap win is not measured on the
+        # chip (the vphases/sort/posmap/tree-cache flip-on-evidence
+        # playbook).
         # Per-tree fetch-round windows: the records tree runs one round
         # per engine round (window = E, F = B), the mailbox tree two
         # (rounds A and C: window = 2E, F = B·D).

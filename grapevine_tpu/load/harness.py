@@ -70,9 +70,9 @@ def calibrate_unloaded_round(engine, now: int, reps: int = 3) -> tuple:
     target: ``max(250 ms, 8× the unloaded round)``. The capacity
     question is where latency departs from the intrinsic baseline, not
     whether a 2-vCPU sandbox meets a production target it never could
-    (OPERATIONS.md §15); the one formula lives here so the CI bench
-    (bench.py load_scenarios) and the chip capture (tools/
-    tpu_capture.py load_perf) can never diverge on methodology.
+    (OPERATIONS.md §15); the one formula lives here so every caller
+    (bench.py load_scenarios on the CPU or the chip) shares one
+    methodology.
     Min-of-``reps`` after a warm call (the PERF.md noise rule)."""
     idents = identity_pool(8)
     batch = engine.ecfg.batch_size

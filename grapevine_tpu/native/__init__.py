@@ -2,9 +2,11 @@
 
 r255.c is compiled with the system C compiler into a cached shared
 object next to the source (the build-time codegen analog of the
-reference's api/build.rs protoc step). If no compiler is available the
-package degrades to the pure-Python paths — callers must treat ``lib``
-as Optional.
+reference's api/build.rs protoc step). If the library cannot be built
+or loaded the package degrades to the pure-Python paths — callers must
+treat ``lib`` as Optional — and ``load_error`` says why, so a server
+can log the degradation instead of serving sr25519 at pure-Python
+speed without a word (:func:`log_state`).
 
 Thread-safety contract, per wrapper class:
 
@@ -31,16 +33,24 @@ _SO = _DIR / "_r255.so"
 
 _lock = threading.Lock()
 lib = None
+#: why ``lib`` is None (no compiler, failed build, unloadable or stale
+#: library); None while the native library is live
+load_error: str | None = None
 
 
-def _build() -> Path | None:
+class _NativeUnavailable(Exception):
+    """The library could not be built; the message is ``load_error``."""
+
+
+def _build() -> Path:
     try:
         if _SO.exists() and _SO.stat().st_mtime >= _SRC.stat().st_mtime:
             return _SO
     except OSError:
-        # a cached .so without the C source (or vice versa): use the .so
-        # if present, otherwise fall back to pure Python
-        return _SO if _SO.exists() else None
+        # a cached .so without the C source: use it
+        if _SO.exists():
+            return _SO
+        raise _NativeUnavailable(f"neither {_SRC.name} nor {_SO.name} found")
     # compile to a private temp file, then atomically rename: concurrent
     # importers (pytest workers, server + bench) must never dlopen a
     # half-written .so or have a mapped one rewritten under them
@@ -50,27 +60,49 @@ def _build() -> Path | None:
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
         os.replace(tmp, _SO)
-    except (OSError, subprocess.SubprocessError):
+    except (OSError, subprocess.SubprocessError) as e:
         tmp.unlink(missing_ok=True)
-        return None
+        err = getattr(e, "stderr", b"") or b""
+        raise _NativeUnavailable(
+            f"building {_SRC.name} with {cc!r} failed: {e} "
+            f"{err.decode(errors='replace')[-300:]}".strip()
+        ) from e
     return _SO
 
 
 def _load():
-    global lib
-    so = _build()
-    if so is None:
-        return None
+    global load_error
+    load_error = None
     try:
-        handle = ctypes.CDLL(str(so))
-    except OSError:
-        return None
-    try:
-        return _bind(handle)
-    except AttributeError:
+        so = _build()
+        bound = _bind(ctypes.CDLL(str(so)))
+    except _NativeUnavailable as e:
+        load_error = str(e)
+    except OSError as e:
+        load_error = f"loading {_SO.name} failed: {e}"
+    except AttributeError as e:
         # a cached .so built from older source (missing a newer export):
-        # degrade to pure Python rather than failing the package import
-        return None
+        # degrade rather than failing the package import
+        load_error = f"{_SO.name} is stale (missing export: {e})"
+    else:
+        if bound is not None:
+            return bound
+        load_error = f"{_SO.name}: r255_init failed"
+    return None
+
+
+def log_state(logger) -> None:
+    """One line on which sr25519/merlin backend is live: INFO when the
+    native library loaded, WARNING (with the reason) when the session
+    crypto is running on the pure-Python paths."""
+    if lib is not None:
+        logger.info("native session library %s loaded", _SO.name)
+    else:
+        logger.warning(
+            "native session library unavailable (%s): sr25519 verify and "
+            "merlin transcripts run on the pure-Python paths",
+            load_error,
+        )
 
 
 def _bind(handle):

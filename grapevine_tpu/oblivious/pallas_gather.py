@@ -21,8 +21,11 @@ fusing there would trade that property for bandwidth.
 
 Like the fused cipher kernel (pallas_cipher.py) this reuses
 bucket_cipher's ChaCha core verbatim and is asserted bit-identical to
-the jnp path (tests/test_pallas_gather.py); off-TPU it runs in Pallas
-interpret mode.
+the jnp path (tests/test_pallas_gather.py); on the CPU it runs in
+Pallas interpret mode. Both kernels move one row per grid step: an
+8-row manual-DMA pair was removed in PR 22 because Mosaic refuses a DMA
+window narrower than a 128-word tile, and the 4-word index, 2-word
+nonce and 1016-word value rows are all such windows.
 """
 
 from __future__ import annotations
@@ -37,15 +40,6 @@ from jax.experimental.pallas import tpu as pltpu
 from .pallas_cipher import keystream_tile
 
 U32 = jnp.uint32
-
-#: HBM-resident ref memory space across the pallas-tpu API rename:
-#: newer jax exposes ``pltpu.MemorySpace.HBM``; older releases spell
-#: the same "leave it in HBM, kernel DMAs tiles itself" contract
-#: ``TPUMemorySpace.ANY`` (the idiom all the manual-DMA examples of
-#: that era used). getattr keeps the new name authoritative when
-#: present, so TPU-validated behavior is unchanged there.
-_MS = getattr(pltpu, "MemorySpace", None)
-HBM = _MS.HBM if _MS is not None else pltpu.TPUMemorySpace.ANY
 
 
 def _gather_kernel(
@@ -146,128 +140,6 @@ def gather_decrypt_rows(
     return oidx[:, 0, :], oval[:, 0, :]
 
 
-def _gather_tiled_kernel(
-    bucket_ref,  # scalar-prefetch: u32[R_pad] row indices (public path)
-    key_ref,  # u32[1, 8] (VMEM)
-    idx_hbm,  # u32[n, z]   whole tree_idx, stays in HBM
-    val_hbm,  # u32[n, zv]  whole tree_val, stays in HBM
-    non_hbm,  # u32[n, 2]   whole nonces, stays in HBM
-    oidx_ref,  # u32[T, z]  (VMEM out block)
-    oval_ref,  # u32[T, zv]
-    scr_idx,  # u32[T, z]   VMEM scratch
-    scr_val,  # u32[T, zv]
-    scr_non,  # u32[T, 2]
-    sems,  # DMA semaphores (T, 3)
-    *,
-    t,
-    nb,
-    z,
-    n_words,
-    rounds,
-):
-    """T rows per grid step: T×3 async row DMAs issued back-to-back,
-    then ONE vectorized [T, nb] keystream + XOR. Amortizes per-step
-    pipeline overhead and fills the VPU lanes that the one-row kernel
-    leaves idle (a [1, nb] ChaCha tile uses 1 of 8 sublanes)."""
-    i = pl.program_id(0)
-
-    def dmas(k):
-        row = bucket_ref[i * t + k]
-        return (
-            pltpu.make_async_copy(idx_hbm.at[row], scr_idx.at[k], sems.at[k, 0]),
-            pltpu.make_async_copy(val_hbm.at[row], scr_val.at[k], sems.at[k, 1]),
-            pltpu.make_async_copy(non_hbm.at[row], scr_non.at[k], sems.at[k, 2]),
-        )
-
-    for k in range(t):  # static unroll: issue every DMA before any wait
-        for d in dmas(k):
-            d.start()
-    for k in range(t):
-        for d in dmas(k):
-            d.wait()
-    bids = jnp.stack([bucket_ref[i * t + k] for k in range(t)])  # [T]
-    n1 = jnp.broadcast_to(bids[:, None], (t, nb))
-    n2 = jnp.broadcast_to(scr_non[:, 0][:, None], (t, nb))
-    n3 = jnp.broadcast_to(scr_non[:, 1][:, None], (t, nb))
-    ks = keystream_tile(key_ref, n1, n2, n3, nb, rounds)
-    written = ((scr_non[:, 0] != U32(0)) | (scr_non[:, 1] != U32(0)))[:, None]
-    oidx_ref[:, :] = scr_idx[:, :] ^ jnp.where(written, ks[:, :z], U32(0))
-    oval_ref[:, :] = scr_val[:, :] ^ jnp.where(
-        written, ks[:, z:n_words], U32(0)
-    )
-
-
-@functools.partial(
-    jax.jit, static_argnames=("z", "rounds", "tile", "interpret")
-)
-def gather_decrypt_rows_tiled(
-    key: jax.Array,  # u32[8]
-    tree_idx: jax.Array,  # u32[n_padded * z]
-    tree_val: jax.Array,  # u32[n_padded, z*v]
-    nonces: jax.Array,  # u32[n_padded, 2]
-    flat_b: jax.Array,  # u32[R] heap-bucket indices (public transcript)
-    z: int,
-    rounds: int = 8,
-    tile: int = 8,
-    interpret: bool = False,
-):
-    """Tiled variant of :func:`gather_decrypt_rows` (same contract).
-
-    The trees stay in HBM (``MemorySpace.HBM`` refs) and each grid step
-    manually DMAs ``tile`` rows into VMEM scratch — the Pallas analog of
-    a batched dynamic gather — instead of one pipelined block per row.
-    At B=2048 the one-row grid is ~43k steps; this cuts it ``tile``-fold
-    and runs the ChaCha tile [T, nb] wide. ``tile=8`` keeps the output
-    block sublane-aligned (u32 tiling is (8, 128)).
-    """
-    n_padded = tree_val.shape[0]
-    zv = tree_val.shape[1]
-    r = flat_b.shape[0]
-    w = z + zv
-    nb = (w + 15) // 16
-    idx_rows = tree_idx.reshape(n_padded, z)
-    if rounds == 0:
-        return idx_rows[flat_b], tree_val[flat_b]
-    r_pad = -(-r // tile) * tile
-    if r_pad != r:
-        # padded steps fetch row 0 harmlessly; outputs are sliced off
-        flat_b = jnp.pad(flat_b, (0, r_pad - r))
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(r_pad // tile,),
-        in_specs=[
-            pl.BlockSpec((1, 8), lambda i, b_ref: (0, 0)),
-            pl.BlockSpec(memory_space=HBM),
-            pl.BlockSpec(memory_space=HBM),
-            pl.BlockSpec(memory_space=HBM),
-        ],
-        out_specs=[
-            pl.BlockSpec((tile, z), lambda i, b_ref: (i, 0)),
-            pl.BlockSpec((tile, zv), lambda i, b_ref: (i, 0)),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((tile, z), U32),
-            pltpu.VMEM((tile, zv), U32),
-            pltpu.VMEM((tile, 2), U32),
-            pltpu.SemaphoreType.DMA((tile, 3)),
-        ],
-    )
-    oidx, oval = pl.pallas_call(
-        functools.partial(
-            _gather_tiled_kernel, t=tile, nb=nb, z=z, n_words=w,
-            rounds=rounds,
-        ),
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((r_pad, z), U32),
-            jax.ShapeDtypeStruct((r_pad, zv), U32),
-        ],
-        interpret=interpret,
-    )(flat_b, key[None, :], idx_rows, tree_val, nonces)
-    return oidx[:r], oval[:r]
-
-
 def _scatter_kernel(
     bucket_ref,  # scalar-prefetch: u32[R] write targets (junk-redirected)
     key_ref,  # u32[1, 1, 8]
@@ -298,142 +170,6 @@ def _scatter_kernel(
     # the write epoch rides the same pass — the separate XLA nonce
     # scatter the jnp path pays (round.py) has no fused-path cost at all
     ononce_ref[0, 0, :] = epoch_ref[0, 0, :]
-
-
-def _scatter_tiled_kernel(
-    bucket_ref,  # scalar-prefetch: u32[R_pad] targets (junk-redirected)
-    key_ref,  # u32[1, 8] (VMEM)
-    idx_new_ref,  # u32[T, z]   plaintext rows (VMEM block)
-    val_new_ref,  # u32[T, zv]
-    epoch_ref,  # u32[1, 2]     write epoch (VMEM)
-    tree_idx_in,  # aliased HBM input (unread)
-    tree_val_in,  # aliased HBM input (unread)
-    nonces_in,  # aliased HBM input (unread)
-    oidx_hbm,  # u32[n, z]   aliased HBM output
-    oval_hbm,  # u32[n, zv]  aliased HBM output
-    onon_hbm,  # u32[n, 2]   aliased HBM output
-    scr_idx,  # u32[T, z]   VMEM scratch (ciphertext staging)
-    scr_val,  # u32[T, zv]
-    scr_non,  # u32[T, 2]
-    sems,  # DMA semaphores (T, 3)
-    *,
-    t,
-    nb,
-    z,
-    n_words,
-    rounds,
-):
-    """Write-back mirror of :func:`_gather_tiled_kernel`: one [T, nb]
-    keystream, then T×3 async row DMAs VMEM→HBM. Junk-redirected rows
-    may race on the junk row; its bytes are never read."""
-    i = pl.program_id(0)
-    bids = jnp.stack([bucket_ref[i * t + k] for k in range(t)])  # [T]
-    n1 = jnp.broadcast_to(bids[:, None], (t, nb))
-    n2 = jnp.broadcast_to(epoch_ref[0, 0], (t, nb))
-    n3 = jnp.broadcast_to(epoch_ref[0, 1], (t, nb))
-    ks = keystream_tile(key_ref, n1, n2, n3, nb, rounds)
-    scr_idx[:, :] = idx_new_ref[:, :] ^ ks[:, :z]
-    scr_val[:, :] = val_new_ref[:, :] ^ ks[:, z:n_words]
-    scr_non[:, :] = jnp.broadcast_to(epoch_ref[0, :], (t, 2))
-
-    def dmas(k):
-        row = bucket_ref[i * t + k]
-        return (
-            pltpu.make_async_copy(scr_idx.at[k], oidx_hbm.at[row], sems.at[k, 0]),
-            pltpu.make_async_copy(scr_val.at[k], oval_hbm.at[row], sems.at[k, 1]),
-            pltpu.make_async_copy(scr_non.at[k], onon_hbm.at[row], sems.at[k, 2]),
-        )
-
-    for k in range(t):
-        for d in dmas(k):
-            d.start()
-    for k in range(t):
-        for d in dmas(k):
-            d.wait()
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("z", "rounds", "tile", "interpret"),
-    donate_argnums=(1, 2, 3),
-)
-def scatter_encrypt_rows_tiled(
-    key: jax.Array,  # u32[8]
-    tree_idx: jax.Array,  # u32[n_padded * z] (updated in place)
-    tree_val: jax.Array,  # u32[n_padded, z*v] (updated in place)
-    nonces: jax.Array,  # u32[n_padded, 2] (updated in place)
-    flat_b: jax.Array,  # u32[R] heap-bucket targets (public transcript)
-    owner: jax.Array,  # bool[R]; False rows must not write
-    epoch: jax.Array,  # u32[2]
-    new_pidx: jax.Array,  # u32[R, z]
-    new_pval: jax.Array,  # u32[R, z*v]
-    z: int,
-    rounds: int,
-    tile: int = 8,
-    interpret: bool = False,
-):
-    """Tiled variant of :func:`scatter_encrypt_rows` (same contract).
-
-    Padded steps and non-owner rows both redirect to the junk row;
-    DMA write races there are benign (the row is never read).
-    """
-    n_padded = tree_val.shape[0]
-    zv = tree_val.shape[1]
-    r = flat_b.shape[0]
-    w = z + zv
-    nb = (w + 15) // 16
-    idx_rows = tree_idx.reshape(n_padded, z)
-    junk = U32(n_padded - 1)
-    tgt = jnp.where(owner, flat_b, junk)
-    r_pad = -(-r // tile) * tile
-    if r_pad != r:
-        pad = r_pad - r
-        tgt = jnp.pad(tgt, (0, pad), constant_values=n_padded - 1)
-        new_pidx = jnp.pad(new_pidx, ((0, pad), (0, 0)))
-        new_pval = jnp.pad(new_pval, ((0, pad), (0, 0)))
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(r_pad // tile,),
-        in_specs=[
-            pl.BlockSpec((1, 8), lambda i, b_ref: (0, 0)),
-            pl.BlockSpec((tile, z), lambda i, b_ref: (i, 0)),
-            pl.BlockSpec((tile, zv), lambda i, b_ref: (i, 0)),
-            pl.BlockSpec((1, 2), lambda i, b_ref: (0, 0)),
-            pl.BlockSpec(memory_space=HBM),
-            pl.BlockSpec(memory_space=HBM),
-            pl.BlockSpec(memory_space=HBM),
-        ],
-        out_specs=[
-            pl.BlockSpec(memory_space=HBM),
-            pl.BlockSpec(memory_space=HBM),
-            pl.BlockSpec(memory_space=HBM),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((tile, z), U32),
-            pltpu.VMEM((tile, zv), U32),
-            pltpu.VMEM((tile, 2), U32),
-            pltpu.SemaphoreType.DMA((tile, 3)),
-        ],
-    )
-    oidx, oval, ononce = pl.pallas_call(
-        functools.partial(
-            _scatter_tiled_kernel, t=tile, nb=nb, z=z, n_words=w,
-            rounds=rounds,
-        ),
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((n_padded, z), U32),
-            jax.ShapeDtypeStruct((n_padded, zv), U32),
-            jax.ShapeDtypeStruct((n_padded, 2), U32),
-        ],
-        # operands incl. scalar prefetch: tgt=0, key=1, new_pidx=2,
-        # new_pval=3, epoch=4, idx_rows=5, tree_val=6, nonces=7
-        input_output_aliases={5: 0, 6: 1, 7: 2},
-        interpret=interpret,
-    )(tgt, key[None, :], new_pidx, new_pval, epoch[None, :], idx_rows,
-      tree_val, nonces)
-    return oidx.reshape(-1), oval, ononce
 
 
 @functools.partial(

@@ -22,11 +22,11 @@ cross-validated model — see tools/check_cost_model.py for the gate):
   (OPERATIONS.md §21 carries the triage runbook).
 
 Bandwidth constants: ``GRAPEVINE_COST_GBPS`` (the operator's
-calibrated value — the ``cost_calibrate`` capture stage in
-tools/tpu_capture.py fits it on real silicon) with conservative
-per-backend placeholders until then. A placeholder constant shifts the
-residual's LEVEL, not its drift: triage on change, not magnitude,
-until calibration lands.
+calibrated achieved value — not measured on the chip yet), else the
+device's published peak from ``PEAK_GBPS``, keyed by ``device_kind``.
+A peak in place of an achieved constant shifts the residual's LEVEL,
+not its drift: triage on change, not magnitude, until calibration
+lands.
 """
 
 from __future__ import annotations
@@ -35,26 +35,36 @@ import os
 
 from ..analysis.costmodel import COST_PHASES, engine_cost_ledger
 
-#: pre-calibration achieved-bandwidth placeholders (GB/s) per JAX
-#: backend — deliberately conservative; cost_calibrate replaces them
-DEFAULT_GBPS = {"cpu": 8.0, "gpu": 400.0, "tpu": 800.0}
+#: published peak memory bandwidth (GB/s) per ``device_kind``, with its
+#: source. A device that is not here is an error, never a default.
+PEAK_GBPS = {
+    # Google Cloud documentation, "TPU v5e": 16 GB HBM2e at 819 GB/s
+    "TPU v5 lite": 819.0,
+    # XLA:CPU (tests, one sandbox core): a placeholder of the order of
+    # one DDR channel, not a measurement — CPU rounds are not
+    # bandwidth-bound (PERF.md Round 13), only the drift is read there
+    "cpu": 8.0,
+}
 
 
 def resolve_bandwidth_gbps(override: float | None = None) -> float:
-    """Calibrated-constant resolution order: explicit override →
-    ``GRAPEVINE_COST_GBPS`` → per-backend placeholder."""
+    """Resolution order: explicit override → ``GRAPEVINE_COST_GBPS`` →
+    the published peak of ``jax.devices()[0].device_kind``."""
     if override is not None:
         return float(override)
     env = os.environ.get("GRAPEVINE_COST_GBPS")
     if env:
         return float(env)
-    try:
-        import jax
+    import jax
 
-        backend = jax.default_backend()
-    except Exception:  # pragma: no cover - jax import/env failure
-        backend = "cpu"
-    return DEFAULT_GBPS.get(backend, DEFAULT_GBPS["cpu"])
+    kind = jax.devices()[0].device_kind
+    if kind not in PEAK_GBPS:
+        raise ValueError(
+            f"no published peak bandwidth for device_kind {kind!r}: add "
+            "it to obs/costmon.py PEAK_GBPS with its source, or set "
+            "GRAPEVINE_COST_GBPS"
+        )
+    return PEAK_GBPS[kind]
 
 
 class CostMonitor:

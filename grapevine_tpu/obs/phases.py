@@ -45,8 +45,9 @@ Host-side phases (histograms + ``jax.profiler`` annotations):
                   profiler captures.
 
 Device-side scopes (``device_phase``): named_scope annotations compiled
-into the jit'd round so TPU profiler captures (tools/tpu_capture.py
-stage 6) attribute HLO time to fetch/apply/evict/writeback per tree.
+into the jit'd round so TPU profiler captures (profile_tpu.py, the
+live ``/profile`` endpoint) attribute HLO time to
+fetch/apply/evict/writeback per tree.
 """
 
 from __future__ import annotations

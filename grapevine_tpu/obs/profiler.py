@@ -3,9 +3,8 @@
 The TPU profiler is the only instrument that can split device time
 inside the fused round program (the host phase timers stop at the
 ``evict`` wait; the ``jax.named_scope`` annotations compiled into the
-round only become visible in a profiler capture). Until now getting one
-meant restarting the server under ``tools/tpu_capture.py`` — this module
-makes a capture a runtime operation instead: ``/profile?ms=N``
+round only become visible in a profiler capture). This module makes a
+capture a runtime operation of the serving process: ``/profile?ms=N``
 (obs/httpd.py) starts a ``jax.profiler`` trace on the live process,
 sleeps N milliseconds while the engine keeps serving, stops the trace,
 and returns the capture directory. Load the result in Perfetto /

@@ -213,9 +213,8 @@ class RoundTracer:
     def span_durations_ms(self, name: str) -> list[float]:
         """Non-zero durations (ms) of one phase span across the
         retained ledgers, oldest first — the A/B tooling's accessor
-        (bench.py ``pipeline_ab``, tools/tpu_capture.py
-        ``pipeline_perf``), shared so the banked journal-span
-        methodology can never diverge between the two. Phase-level by
+        (bench.py ``pipeline_ab``), kept here so the banked
+        journal-span methodology has one definition. Phase-level by
         construction: the ring holds nothing finer."""
         if name not in ALLOWED_SPAN_NAMES:
             raise ValueError(

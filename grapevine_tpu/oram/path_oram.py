@@ -54,7 +54,7 @@ from typing import Callable, NamedTuple
 
 import jax
 
-from ..config import TPU_BACKENDS as _TPU_BACKENDS
+from ..config import on_tpu as _on_tpu
 import jax.numpy as jnp
 
 from ..oblivious.bucket_cipher import epoch_next, row_keystream  # noqa: F401  (row_keystream used by cipher_rows)
@@ -164,10 +164,10 @@ def cipher_rows(
     if not cfg.encrypted:
         return pidx, pval
     z = cfg.bucket_slots
-    if cfg.cipher_impl in ("pallas", "pallas_fused", "pallas_fused_tiled"):
+    if cfg.cipher_impl in ("pallas", "pallas_fused"):
         from ..oblivious.pallas_cipher import cipher_rows_pallas
 
-        interpret = jax.default_backend() not in _TPU_BACKENDS
+        interpret = not _on_tpu()
         if interpret and pidx.shape[0] >= 2048:
             # trace-time (once per compile), not per round: interpret
             # mode on a production-size engine means thousands of

@@ -49,7 +49,7 @@ from __future__ import annotations
 
 import jax
 
-from ..config import TPU_BACKENDS as _TPU_BACKENDS
+from ..config import on_tpu as _on_tpu
 import jax.numpy as jnp
 
 from ..oblivious.primitives import SENTINEL, rank_of
@@ -373,24 +373,18 @@ def oram_round(
     )
     top_slots = path_slot_indices(cfg, top_b).reshape(-1)  # [B*kc*z]
 
-    fused = cfg.cipher_impl in ("pallas_fused", "pallas_fused_tiled")
+    fused = cfg.cipher_impl == "pallas_fused"
     with device_phase("oram_fetch"):
         if axis_name is None and fused and cfg.encrypted:
             # single-chip fast path: gather + decrypt in ONE HBM pass
             # (oblivious/pallas_gather.py); the sharded path below keeps
             # decrypt-after-psum so tree plaintext never transits ICI
-            from ..oblivious.pallas_gather import (
-                gather_decrypt_rows,
-                gather_decrypt_rows_tiled,
-            )
+            from ..oblivious.pallas_gather import gather_decrypt_rows
 
-            g = (gather_decrypt_rows_tiled
-                 if cfg.cipher_impl == "pallas_fused_tiled"
-                 else gather_decrypt_rows)
-            pidx, pval = g(
+            pidx, pval = gather_decrypt_rows(
                 state.cipher_key, state.tree_idx, state.tree_val, state.nonces,
                 bot_b, z=z, rounds=cfg.cipher_rounds,
-                interpret=jax.default_backend() not in _TPU_BACKENDS,
+                interpret=not _on_tpu(),
             )
         else:
             pidx = _path_gather(
@@ -561,20 +555,14 @@ def oram_round(
             # write-back mirror of the fused fetch; pallas_gather.py) —
             # the nonce commit rides the same kernel, so this branch has no
             # XLA scatter at all
-            from ..oblivious.pallas_gather import (
-                scatter_encrypt_rows,
-                scatter_encrypt_rows_tiled,
-            )
+            from ..oblivious.pallas_gather import scatter_encrypt_rows
 
-            sc = (scatter_encrypt_rows_tiled
-                  if cfg.cipher_impl == "pallas_fused_tiled"
-                  else scatter_encrypt_rows)
-            tree_idx_new, tree_val_new, nonces = sc(
+            tree_idx_new, tree_val_new, nonces = scatter_encrypt_rows(
                 state.cipher_key, state.tree_idx, state.tree_val, state.nonces,
                 bot_b, fowner_bot, state.epoch,
                 bot_pidx, bot_pval,
                 z=z, rounds=cfg.cipher_rounds,
-                interpret=jax.default_backend() not in _TPU_BACKENDS,
+                interpret=not _on_tpu(),
             )
         else:
             enc_pidx, enc_pval = cipher_rows(
@@ -756,21 +744,15 @@ def _oram_fetch_round(
     )
     top_slots = path_slot_indices(cfg, top_b).reshape(-1)
 
-    fused = cfg.cipher_impl in ("pallas_fused", "pallas_fused_tiled")
+    fused = cfg.cipher_impl == "pallas_fused"
     with device_phase("oram_fetch"):
         if axis_name is None and fused and cfg.encrypted:
-            from ..oblivious.pallas_gather import (
-                gather_decrypt_rows,
-                gather_decrypt_rows_tiled,
-            )
+            from ..oblivious.pallas_gather import gather_decrypt_rows
 
-            g = (gather_decrypt_rows_tiled
-                 if cfg.cipher_impl == "pallas_fused_tiled"
-                 else gather_decrypt_rows)
-            pidx, pval = g(
+            pidx, pval = gather_decrypt_rows(
                 state.cipher_key, state.tree_idx, state.tree_val, state.nonces,
                 bot_b, z=z, rounds=cfg.cipher_rounds,
-                interpret=jax.default_backend() not in _TPU_BACKENDS,
+                interpret=not _on_tpu(),
             )
         else:
             pidx = _path_gather(
@@ -1114,22 +1096,16 @@ def oram_flush(
         pidx2 = new_pidx.reshape(t, z)
         pval2 = new_pval.reshape(t, z * v)
         epochs_w = jnp.broadcast_to(state.epoch[None, :], (t, 2))
-        fused = cfg.cipher_impl in ("pallas_fused", "pallas_fused_tiled")
+        fused = cfg.cipher_impl == "pallas_fused"
         if axis_name is None and fused and cfg.encrypted:
-            from ..oblivious.pallas_gather import (
-                scatter_encrypt_rows,
-                scatter_encrypt_rows_tiled,
-            )
+            from ..oblivious.pallas_gather import scatter_encrypt_rows
 
-            sc = (scatter_encrypt_rows_tiled
-                  if cfg.cipher_impl == "pallas_fused_tiled"
-                  else scatter_encrypt_rows)
-            tree_idx_new, tree_val_new, nonces = sc(
+            tree_idx_new, tree_val_new, nonces = scatter_encrypt_rows(
                 state.cipher_key, state.tree_idx, state.tree_val,
                 state.nonces, tgt_b, tree_tgt, state.epoch,
                 pidx2, pval2,
                 z=z, rounds=cfg.cipher_rounds,
-                interpret=jax.default_backend() not in _TPU_BACKENDS,
+                interpret=not _on_tpu(),
             )
         else:
             enc_pidx, enc_pval = cipher_rows(

@@ -14,6 +14,7 @@ from .mesh import (
     make_mesh,
     make_sharded_flush,
     make_sharded_step,
+    make_sharded_sweep,
     shard_engine_state,
     validate_sharded_geometry,
 )
@@ -25,6 +26,7 @@ __all__ = [
     "make_mesh",
     "make_sharded_flush",
     "make_sharded_step",
+    "make_sharded_sweep",
     "shard_engine_state",
     "validate_sharded_geometry",
 ]

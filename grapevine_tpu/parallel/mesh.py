@@ -33,25 +33,13 @@ import functools
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..engine.expiry import expiry_sweep
 from ..engine.round_step import engine_flush_step, engine_round_step
 from ..engine.state import EngineConfig, EngineState
 from ..oram.path_oram import OramState
 
 #: mesh axis across which the bucket trees are sharded
 TREE_AXIS = "tree"
-
-# shard_map across the API move: newer jax exposes ``jax.shard_map``
-# (replication check spelled ``check_vma``); older releases ship it as
-# ``jax.experimental.shard_map.shard_map`` with ``check_rep``. Same
-# semantics either way; the new name stays authoritative when present.
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-    _SHARD_MAP_NOCHECK = {"check_vma": False}
-else:  # pragma: no cover - exercised only on older jaxlibs
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    _SHARD_MAP_NOCHECK = {"check_rep": False}
-
 
 def make_mesh(devices=None) -> Mesh:
     """1-D mesh over the given (default: all) devices."""
@@ -135,6 +123,33 @@ def shard_engine_state(state: EngineState, mesh: Mesh) -> EngineState:
     )
 
 
+def engine_state_shardings(mesh: Mesh) -> EngineState:
+    """``engine_state_specs`` as NamedShardings on ``mesh`` (a pytree
+    prefix of EngineState, as ``jit``'s in/out_shardings accept)."""
+    return jax.tree.map(
+        lambda s: NamedSharding(mesh, s), engine_state_specs(),
+        is_leaf=lambda s: isinstance(s, P),
+    )
+
+
+def _jit_sharded(fn, mesh: Mesh, n_replicated_in: int, n_replicated_out: int):
+    """``jit`` a shard_map'd state program with its shardings pinned.
+
+    Left to infer them from its arguments, jit compiles the program
+    TWICE: JAX reports a zero-length plane (``tree_leaf`` under a flat
+    position map) as replicated on the way out whatever its spec says,
+    so the second call's state no longer matches the first call's
+    cache key. Found on four v5e chips in PR 22, where the second
+    compile of the 2^22 round hid inside the first served round."""
+    state, rep = engine_state_shardings(mesh), NamedSharding(mesh, P())
+    outs = (state,) + (rep,) * n_replicated_out
+    return jax.jit(
+        fn, donate_argnums=0,
+        in_shardings=(state,) + (rep,) * n_replicated_in,
+        out_shardings=outs if n_replicated_out else state,
+    )
+
+
 def init_sharded_engine(ecfg: EngineConfig, mesh: Mesh, seed: int = 0) -> EngineState:
     """Initialize engine state *directly* sharded over the mesh.
 
@@ -146,13 +161,9 @@ def init_sharded_engine(ecfg: EngineConfig, mesh: Mesh, seed: int = 0) -> Engine
     device only, so peak memory is the sharded footprint itself."""
     from ..engine.state import init_engine
 
-    specs = engine_state_specs()
-    shardings = jax.tree.map(
-        lambda s: NamedSharding(mesh, s), specs,
-        is_leaf=lambda s: isinstance(s, P),
-    )
     return jax.jit(
-        lambda: init_engine(ecfg, seed), out_shardings=shardings
+        lambda: init_engine(ecfg, seed),
+        out_shardings=engine_state_shardings(mesh),
     )()
 
 
@@ -163,8 +174,10 @@ def validate_sharded_geometry(ecfg: EngineConfig, mesh: Mesh) -> None:
     Everything the sharded step/flush pair DOES cover is silent here:
     evict_every >= 1 (the owner-masked flush), recursive position maps
     (inner trees replicated), tree-top caching (cache planes
-    replicated), all cipher impls (the fused Pallas scatter falls back
-    to the jnp cipher inside shard_map), both sort/vphases impls.
+    replicated), all cipher impls ("pallas_fused" runs as "pallas"
+    inside shard_map — gather, psum, then the Pallas cipher kernel —
+    and GrapevineEngine says so once at WARNING when it is built), both
+    sort/vphases impls.
     """
     n_dev = mesh.devices.size
     for label, cfg in (("records", ecfg.rec), ("mailbox", ecfg.mb)):
@@ -193,14 +206,14 @@ def make_sharded_step(ecfg: EngineConfig, mesh: Mesh):
     """
     validate_sharded_geometry(ecfg, mesh)
     specs = engine_state_specs()
-    step = _shard_map(
+    step = jax.shard_map(
         functools.partial(engine_round_step, ecfg, axis_name=TREE_AXIS),
         mesh=mesh,
         in_specs=(specs, P()),
         out_specs=(specs, P(), P()),
-        **_SHARD_MAP_NOCHECK,
+        check_vma=False,
     )
-    return jax.jit(step, donate_argnums=0)
+    return _jit_sharded(step, mesh, 1, 2)
 
 
 def make_sharded_flush(ecfg: EngineConfig, mesh: Mesh):
@@ -224,11 +237,33 @@ def make_sharded_flush(ecfg: EngineConfig, mesh: Mesh):
         )
     validate_sharded_geometry(ecfg, mesh)
     specs = engine_state_specs()
-    flush = _shard_map(
+    flush = jax.shard_map(
         functools.partial(engine_flush_step, ecfg, axis_name=TREE_AXIS),
         mesh=mesh,
         in_specs=(specs,),
         out_specs=specs,
-        **_SHARD_MAP_NOCHECK,
+        check_vma=False,
     )
-    return jax.jit(flush, donate_argnums=0)
+    return _jit_sharded(flush, mesh, 0, 0)
+
+
+def make_sharded_sweep(ecfg: EngineConfig, mesh: Mesh):
+    """Jit-compiled expiry sweep with the bucket trees sharded.
+
+    Same semantics as ``expiry_sweep(ecfg, state, now, period, now_hi)``
+    and bit-identical results (tests/test_parallel.py): each chip sweeps
+    the heap range it owns, and which message ids survive and how many
+    recipients remain are summed over the mesh (engine/expiry.py). The
+    sweep must be shard_map'd like the step: under plain ``jit`` GSPMD
+    replicates the trees rather than partition the chunk scan, which a
+    mesh-sized bus does not survive."""
+    validate_sharded_geometry(ecfg, mesh)
+    specs = engine_state_specs()
+    sweep = jax.shard_map(
+        functools.partial(expiry_sweep, ecfg, axis_name=TREE_AXIS),
+        mesh=mesh,
+        in_specs=(specs, P(), P(), P()),
+        out_specs=specs,
+        check_vma=False,
+    )
+    return _jit_sharded(sweep, mesh, 3, 0)

@@ -5,26 +5,9 @@ from __future__ import annotations
 
 import argparse
 import logging
-import os
 import sys
 
-
-def _pin_platform() -> None:
-    """Honor JAX_PLATFORMS before any backend initializes.
-
-    Site hooks may pin a platform via ``jax.config`` (overriding the env
-    var), so an explicit request like ``JAX_PLATFORMS=cpu`` must be
-    re-asserted through the config API."""
-    want = os.environ.get("JAX_PLATFORMS")
-    if want:
-        import jax
-
-        jax.config.update("jax_platforms", want)
-
-
-_pin_platform()
-
-from ..config import GrapevineConfig  # noqa: E402
+from ..config import GrapevineConfig, setup_compile_cache
 
 
 def build_parser() -> argparse.ArgumentParser:
@@ -117,8 +100,8 @@ def build_parser() -> argparse.ArgumentParser:
         "stash-update only. Responses and logical state are "
         "bit-identical at every E; the flush cadence is a pure round "
         "count, never buffer contents (CI-audited). 1 = per-round "
-        "eviction, bit for bit; unset = auto (currently 1 — "
-        "tools/tpu_capture.py evict_perf settles the on-chip flip). "
+        "eviction, bit for bit; unset = auto (currently 1; the "
+        "on-chip flush overlap is not measured on the chip). "
         "Device-owning roles only",
     )
     p.add_argument(
@@ -606,6 +589,16 @@ def main(argv=None) -> int:
     args = parser.parse_args(argv)
     _reject_misapplied_flags(parser, args, argv)
     logging.basicConfig(level=logging.DEBUG if args.verbose else logging.INFO)
+    log = logging.getLogger(__name__)
+    if args.role != "fleet":
+        # every other role verifies request signatures: say once which
+        # sr25519 backend is live (WARNING when it is pure Python)
+        from .. import native
+
+        native.log_state(log)
+    if args.role not in ("fleet", "frontend"):
+        # roles that own an engine compile; frontends stay JAX-free
+        log.info("compile cache: %s", setup_compile_cache())
     config = GrapevineConfig(
         max_messages=args.msg_capacity,
         max_recipients=args.recipient_capacity,

@@ -1,11 +1,9 @@
-"""State-comparison helpers shared by tests and the TPU capture tool."""
+"""State-comparison helpers shared by tests, bench.py and chip_smoke.py."""
 
 from __future__ import annotations
 
 import jax
 import numpy as np
-
-from ..config import TPU_BACKENDS
 
 __all__ = [
     "states_equal_excluding_junk",
@@ -13,7 +11,6 @@ __all__ = [
     "assert_logical_state_equal",
     "logical_block_map",
     "assert_logical_content_equal",
-    "TPU_BACKENDS",
 ]
 
 

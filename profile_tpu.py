@@ -2,11 +2,12 @@
 """Capture a JAX profiler trace of the engine round on real TPU.
 
 PERF.md lever 1: replace the analytic ~5-10 ms/round cost model with a
-trace-backed attribution. Run on a host with a working TPU backend:
+trace-backed attribution. Run on a machine with a TPU, as the one
+process that uses it:
 
     python profile_tpu.py [--impl jnp|pallas|pallas_fused]
                           [--cap-log2 20] [--batch 2048] [--rounds 8]
-                          [--outdir /tmp/grapevine-trace]
+                          [--outdir chiprun_out/grapevine-trace]
 
 Prints one JSON line with per-round wall time and writes a perfetto/
 tensorboard trace directory. View: tensorboard --logdir <outdir>, or
@@ -20,6 +21,7 @@ from __future__ import annotations
 
 import argparse
 import json
+import os
 import statistics
 import sys
 import time
@@ -32,17 +34,20 @@ def main() -> int:
     ap.add_argument("--cap-log2", type=int, default=20)
     ap.add_argument("--batch", type=int, default=2048)
     ap.add_argument("--rounds", type=int, default=8)
-    ap.add_argument("--outdir", default="/tmp/grapevine-trace")
+    ap.add_argument("--outdir", default=os.path.join(
+        os.path.dirname(os.path.abspath(__file__)),
+        "chiprun_out", "grapevine-trace"))
     args = ap.parse_args()
 
     import jax
 
-    from grapevine_tpu.config import TPU_BACKENDS
+    from grapevine_tpu.config import setup_compile_cache
 
-    backend = jax.default_backend()
-    if backend not in TPU_BACKENDS:
-        print(json.dumps({"error": f"needs a TPU backend, have {backend!r}"}))
+    platform = jax.devices()[0].platform
+    if platform != "tpu":
+        print(json.dumps({"error": f"needs a TPU, have {platform!r}"}))
         return 1
+    setup_compile_cache()
 
     import bench
 

@@ -182,7 +182,7 @@ def test_pod_2e24_round_and_sweep():
     # GRAPEVINE_BIG_SWEEP=0 skips the expiry sweep: the sweep dominates
     # wall clock (ChaCha over 2×32 GB at 2^24) and was already executed
     # at full scale single-device (BIGRUN_r4.md); the sharded-2^24
-    # attempt targets the ROUND under collectives (VERDICT r4 #6)
+    # attempt targets the ROUND under collectives (round-4 review #6)
     if os.environ.get("GRAPEVINE_BIG_SWEEP", "1") == "0":
         return
 

@@ -211,8 +211,8 @@ def test_evict_config_validation():
         GrapevineConfig(evict_buffer_slots=0)
     from grapevine_tpu.engine.state import EngineConfig
 
-    # auto resolves to 1 (per-round eviction) on every backend until
-    # tools/tpu_capture.py evict_perf prices the flush overlap on-chip
+    # auto resolves to 1 (per-round eviction) on every backend: the
+    # flush overlap is not measured on the chip
     auto = EngineConfig.from_config(GrapevineConfig(**BASE))
     assert auto.evict_every == 1
     assert auto.rec.evict_window == 1

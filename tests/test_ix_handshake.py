@@ -1,6 +1,6 @@
 """IX handshake: static-key authentication inside the handshake.
 
-VERDICT r3 #4 / reference shape ``mc-attest-ake`` (grapevine.proto:17-36,
+round-3 review #4 / reference shape ``mc-attest-ake`` (grapevine.proto:17-36,
 README.md:177-183): both sides' statics are authenticated by the DH mix
 (ee ‖ es ‖ se) — an active MITM that substitutes either key derives
 different channel keys, so the first frame fails AEAD; a pinned server

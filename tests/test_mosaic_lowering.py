@@ -1,15 +1,29 @@
-"""TPU Mosaic lowering contract for the Pallas kernels (CPU-hosted).
+"""The repo's compiler checks: what a TPU v5e's compiler accepts,
+checked without the chip (CPU-hosted).
 
-Interpret-mode tests prove kernel SEMANTICS but not the Mosaic tiling
-contract — all three kernels passed interpret-mode CI for two rounds
-while the first real TPU window rejected them at lowering (rank-1 block
-of 86 rows: neither full-array nor 128-aligned; TPURUN_r5.jsonl).
-``jax.export(platforms=("tpu",))`` runs the Pallas→Mosaic lowering
-pipeline on a CPU-only host, so this gate catches the whole class
-without hardware. Full geometry sweep: tools/mosaic_lowering_check.py.
+Interpret-mode tests prove kernel SEMANTICS but not the Mosaic
+contract. Two rounds of kernels passed interpret-mode CI and were then
+refused on first contact with a chip (rank-1 block of 86 rows,
+TPURUN_r5.jsonl), and the ``jax.export(platforms=("tpu",))`` gate that
+replaced that lesson still passed an 8-row manual-DMA kernel pair that
+Mosaic refuses outright ("Slice shape along dimension 1 must be
+aligned to tiling (128), but is 4" — removed in PR 22): export stops
+at lowering and never runs the Mosaic compile proper.
+
+So the kernel cases here COMPILE — ``.lower(...).compile()`` against a
+described, unattached ``v5e:2x2`` chip — at the real row widths of the
+served geometry (2^20 messages / B=2048), and assert the Mosaic kernel
+is in the compiled program. A compile that passes is not a chip run:
+chip_smoke.py's kernel phase is where the same kernels execute.
+
+The topology is described inside a module-scoped fixture, never at
+import: only one process may load libtpu, and under pytest-xdist every
+worker imports every test file — only the worker that RUNS this file
+may touch it. Keep every compiler check in this one file.
 """
 
 import functools
+import os
 
 import jax
 import jax.numpy as jnp
@@ -19,98 +33,124 @@ from jax import export
 from grapevine_tpu.oblivious.pallas_cipher import cipher_rows_pallas
 from grapevine_tpu.oblivious.pallas_gather import (
     gather_decrypt_rows,
-    gather_decrypt_rows_tiled,
     scatter_encrypt_rows,
-    scatter_encrypt_rows_tiled,
 )
 
 U32 = jnp.uint32
 
 
-def _lower_tpu(fn, *specs, **static):
-    export.export(jax.jit(functools.partial(fn, **static)),
-                  platforms=("tpu",))(*specs)
+@pytest.fixture(scope="module")
+def one_chip():
+    """A ``SingleDeviceSharding`` on one chip of a described v5e:2x2,
+    with the persistent compile cache off around the module (a compile
+    for an unattached chip is written to the cache but cannot be read
+    back, so the next run would warn and compile again)."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
 
-
-def _s(*shape):
-    return jax.ShapeDtypeStruct(shape, U32)
-
-
-@pytest.mark.parametrize("r,z,vw", [(172, 4, 380), (14, 4, 1016)])
-def test_cipher_kernel_lowers_for_tpu(r, z, vw):
-    _lower_tpu(cipher_rows_pallas, _s(8), _s(r), _s(r, 2), _s(r, z),
-               _s(r, vw), rounds=8, interpret=False)
-
-
-#: jaxlib 0.4.36 mis-canonicalizes a 0-d vector load compared against a
-#: scalar inside the one-row gather kernel's Mosaic lowering
-#: ('arith.cmpi' op requires all operands to have the same type — a
-#: vector<i32> vs i32 operand pair from ``nonce_row_ref[0, 0, 0] != 0``,
-#: pallas_gather.py:76). Fixed in later jaxlib; the tiled kernel pair
-#: and the one-row scatter lower clean even here. TRACKING: remove this
-#: gate when the container's jaxlib moves past 0.4.36 — the skip is
-#: version-scoped so current jax keeps running the case.
-_JAXLIB_MOSAIC_CMPI_BUG = tuple(
-    int(x) for x in jax.lib.__version__.split(".")[:3]
-) <= (0, 4, 36)
-
-
-@pytest.mark.parametrize(
-    "fn", [gather_decrypt_rows, gather_decrypt_rows_tiled]
-)
-def test_gather_kernel_lowers_for_tpu(fn):
-    if fn is gather_decrypt_rows and _JAXLIB_MOSAIC_CMPI_BUG:
-        pytest.skip(
-            "jaxlib <= 0.4.36 Mosaic cmpi vector/scalar bug on the "
-            "one-row gather kernel (see _JAXLIB_MOSAIC_CMPI_BUG)"
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
         )
-    n, r, z, v = 65, 22, 4, 254
-    _lower_tpu(fn, _s(8), _s(n * z), _s(n, z * v),
-               _s(n, 2), _s(r), z=z, rounds=8, interpret=False)
+    except Exception as e:  # noqa: BLE001 — any failure means "cannot"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
 
 
-@pytest.mark.parametrize(
-    "fn", [scatter_encrypt_rows, scatter_encrypt_rows_tiled]
-)
-def test_scatter_kernel_lowers_for_tpu(fn):
-    n, r, z, v = 65, 22, 4, 254
-    specs = [_s(8), _s(n * z), _s(n, z * v), _s(n, 2), _s(r),
-             jax.ShapeDtypeStruct((r,), jnp.bool_), _s(2), _s(r, z),
-             _s(r, z * v)]
-    _lower_tpu(fn, *specs, z=z, rounds=8, interpret=False)
+def _compile_for(chip, fn, *specs, **static):
+    """Compile ``fn`` for the described chip; return the compiled text."""
+    specs = [
+        jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=chip) for s in specs
+    ]
+    return (
+        jax.jit(functools.partial(fn, **static))
+        .lower(*specs).compile().as_text()
+    )
+
+
+def _s(*shape, dtype=U32):
+    return jax.ShapeDtypeStruct(shape, dtype)
+
+
+def _served_tree(tree):
+    """(padded buckets, fetched rows per round, Z, value words per row)
+    of one tree of the geometry chip_smoke.py serves: 2^20 messages,
+    2^12 recipients, B=2048, density 2, the TPU's knob defaults."""
+    from grapevine_tpu.config import GrapevineConfig
+    from grapevine_tpu.engine.state import EngineConfig
+
+    cfg = GrapevineConfig(
+        max_messages=1 << 20, max_recipients=1 << 12, batch_size=2048,
+        tree_density=2, vphases_impl="dense", sort_impl="xla",
+    )
+    ecfg = EngineConfig.from_config(cfg)
+    oc = {"records": ecfg.rec, "mailbox": ecfg.mb}[tree]
+    fetches = cfg.batch_size * (
+        1 if tree == "records" else cfg.resolved_mailbox_choices
+    )
+    rows = fetches * (oc.path_len - oc.top_cache_levels)
+    return (oc.n_buckets_padded, rows, oc.bucket_slots,
+            oc.bucket_slots * oc.value_words)
+
+
+@pytest.mark.parametrize("tree", ["records", "mailbox"])
+def test_cipher_kernel_compiles_for_v5e(one_chip, tree):
+    _, r, z, zv = _served_tree(tree)
+    text = _compile_for(
+        one_chip, cipher_rows_pallas, _s(8), _s(r), _s(r, 2), _s(r, z),
+        _s(r, zv), rounds=8, interpret=False,
+    )
+    assert "tpu_custom_call" in text
+
+
+def test_cipher_kernel_compiles_at_the_row_count_the_chip_refused(one_chip):
+    """172 rows of 4+380 words: the first chip window's rejection
+    (TPURUN_r5.jsonl ``mosaic`` stage), kept as a regression case."""
+    text = _compile_for(
+        one_chip, cipher_rows_pallas, _s(8), _s(172), _s(172, 2),
+        _s(172, 4), _s(172, 380), rounds=8, interpret=False,
+    )
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("tree", ["records", "mailbox"])
+def test_gather_kernel_compiles_for_v5e(one_chip, tree):
+    n, r, z, zv = _served_tree(tree)
+    text = _compile_for(
+        one_chip, gather_decrypt_rows, _s(8), _s(n * z), _s(n, zv),
+        _s(n, 2), _s(r), z=z, rounds=8, interpret=False,
+    )
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("tree", ["records", "mailbox"])
+def test_scatter_kernel_compiles_for_v5e(one_chip, tree):
+    n, r, z, zv = _served_tree(tree)
+    text = _compile_for(
+        one_chip, scatter_encrypt_rows, _s(8), _s(n * z), _s(n, zv),
+        _s(n, 2), _s(r), _s(r, dtype=jnp.bool_), _s(2), _s(r, z),
+        _s(r, zv), z=z, rounds=8, interpret=False,
+    )
+    assert "tpu_custom_call" in text
 
 
 # ----------------------------------------------------------------------
 # the whole phase-major engine round, per vphases impl: the sort/scan
 # path (variadic lax.sort, associative scans, cummax/cummin, scatter
 # tables) must lower for TPU cross-platform just like the Pallas
-# kernels — a scan geometry that only ever ran on CPU would repeat the
-# window-1 lowering surprise at the first vphases_perf A/B.
+# kernels. Export is enough for these six (no Pallas kernel inside:
+# the jnp cipher); the Pallas round is compiled for real below.
 # ----------------------------------------------------------------------
 
 
-@pytest.mark.parametrize(
-    "impl,sort,geom",
-    [
-        # (batch, max_messages, max_recipients, mailbox_cap, density);
-        # scan gets both geometries (the new, never-TPU-compiled path),
-        # dense one (it already compiled on the real chip in window 1).
-        # Each vphases impl also lowers with sort_impl="radix" — the
-        # counting-pass engine (scatter-bincount, [B,R] cumsum tables,
-        # per-pass unique scatters) must pass the Mosaic pipeline
-        # BEFORE the sort_perf capture stage meets a real chip, or that
-        # window repeats the window-1 lowering surprise.
-        ("scan", "xla", (8, 64, 8, 4, 2)),
-        ("scan", "xla", (16, 1 << 10, 1 << 6, 62, 4)),  # production-shaped
-        ("dense", "xla", (8, 64, 8, 4, 2)),
-        ("scan", "radix", (8, 64, 8, 4, 2)),
-        ("scan", "radix", (16, 1 << 10, 1 << 6, 62, 4)),
-        ("dense", "radix", (8, 64, 8, 4, 2)),
-    ],
-)
-def test_engine_round_lowers_for_tpu(impl, sort, geom):
-    from grapevine_tpu.config import GrapevineConfig
-    from grapevine_tpu.engine.round_step import engine_round_step
+def _round_specs(cfg):
     from grapevine_tpu.engine.state import (
         EngineConfig,
         ID_WORDS,
@@ -119,17 +159,7 @@ def test_engine_round_lowers_for_tpu(impl, sort, geom):
         init_engine,
     )
 
-    b, cap, recips, mcap, density = geom
-    cfg = GrapevineConfig(
-        max_messages=cap,
-        max_recipients=recips,
-        mailbox_cap=mcap,
-        batch_size=b,
-        tree_density=density,
-        bucket_cipher_rounds=8,
-        vphases_impl=impl,
-        sort_impl=sort,
-    )
+    b = cfg.batch_size
     ecfg = EngineConfig.from_config(cfg)
     state = jax.eval_shape(lambda: init_engine(ecfg, 0))
     batch = {
@@ -141,7 +171,71 @@ def test_engine_round_lowers_for_tpu(impl, sort, geom):
         "now": _s(),
         "now_hi": _s(),
     }
+    return ecfg, state, batch
+
+
+@pytest.mark.parametrize(
+    "impl,sort,geom",
+    [
+        # (batch, max_messages, max_recipients, mailbox_cap, density);
+        # scan gets both geometries, dense one. Each vphases impl also
+        # lowers with sort_impl="radix" — the counting-pass engine
+        # (scatter-bincount, [B,R] cumsum tables, per-pass unique
+        # scatters) must pass the TPU lowering before a sort A/B meets
+        # a real chip.
+        ("scan", "xla", (8, 64, 8, 4, 2)),
+        ("scan", "xla", (16, 1 << 10, 1 << 6, 62, 4)),  # production-shaped
+        ("dense", "xla", (8, 64, 8, 4, 2)),
+        ("scan", "radix", (8, 64, 8, 4, 2)),
+        ("scan", "radix", (16, 1 << 10, 1 << 6, 62, 4)),
+        ("dense", "radix", (8, 64, 8, 4, 2)),
+    ],
+)
+def test_engine_round_lowers_for_tpu(impl, sort, geom):
+    from grapevine_tpu.config import GrapevineConfig
+    from grapevine_tpu.engine.round_step import engine_round_step
+
+    b, cap, recips, mcap, density = geom
+    ecfg, state, batch = _round_specs(GrapevineConfig(
+        max_messages=cap,
+        max_recipients=recips,
+        mailbox_cap=mcap,
+        batch_size=b,
+        tree_density=density,
+        bucket_cipher_rounds=8,
+        vphases_impl=impl,
+        sort_impl=sort,
+    ))
     export.export(
         jax.jit(functools.partial(engine_round_step, ecfg)),
         platforms=("tpu",),
     )(state, batch)
+
+
+def test_engine_round_with_fused_kernels_compiles_for_v5e(
+    one_chip, monkeypatch
+):
+    """The whole round through ``bucket_cipher_impl="pallas_fused"``
+    compiles for the chip with all three Mosaic kernels inside. The
+    program picks interpret mode from ``jax.default_backend()``, which
+    is the CPU here, so the test steers that one question (no program
+    option exists for it, on purpose)."""
+    from grapevine_tpu.config import GrapevineConfig
+    from grapevine_tpu.engine.round_step import engine_round_step
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    ecfg, state, batch = _round_specs(GrapevineConfig(
+        max_messages=64, max_recipients=8, mailbox_cap=4, batch_size=8,
+        tree_density=2, bucket_cipher_impl="pallas_fused",
+        vphases_impl="dense", sort_impl="xla",
+    ))
+    place = lambda t: jax.tree.map(  # noqa: E731
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        t,
+    )
+    text = (
+        jax.jit(functools.partial(engine_round_step, ecfg),
+                donate_argnums=(0,))
+        .lower(place(state), place(batch)).compile().as_text()
+    )
+    assert text.count("tpu_custom_call") >= 3

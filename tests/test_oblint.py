@@ -238,6 +238,38 @@ def test_mutants_caught_for_the_right_reason():
         assert kinds.count(kind) >= 1, (name, kind, kinds)
 
 
+def test_debug_print_of_a_secret_in_the_engine_round_is_caught():
+    """The leaky-debug-print mutant seeded into the REAL round: under
+    the installed JAX ``jax.debug.print`` traces to a ``debug_print``
+    primitive (it was ``debug_callback`` when the analyzer was
+    written), and a printed op type inside ``engine_round_step`` must
+    read as a callback sink under the production allowlist — exactly
+    one finding more than the clean round's none."""
+    import check_oblivious as gate
+
+    from grapevine_tpu.engine import round_step
+    from grapevine_tpu.engine.state import init_engine
+
+    ecfg = gate._small_engine(*gate.SMOKE_COMBO)
+
+    def leaky_round(st, ba):
+        jax.debug.print("op type {t}", t=ba["req_type"][0])
+        return round_step.engine_round_step(ecfg, st, ba)
+
+    rep = analyze(
+        leaky_round,
+        {"state": jax.eval_shape(lambda: init_engine(ecfg, 0)),
+         "batch": gate._batch_spec(ecfg)},
+        secrets=round_step.OBLINT_SECRETS,
+        allowlist=ENGINE_ALLOWLIST,
+        name="engine_round/leaky_debug_print",
+    )
+    assert [(v.kind, v.prim) for v in rep.violations] == [
+        ("callback", "debug_print")
+    ], rep.summary()
+    assert rep.violations[0].labels == ("batch.req_type",)
+
+
 # ----------------------------------------------------------------------
 # 3. the engine audit (smoke always-on; sweep reachability; full = slow)
 # ----------------------------------------------------------------------

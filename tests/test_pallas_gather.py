@@ -99,7 +99,7 @@ def _run_crd(impl: str, seed: int = 9):
 
 @pytest.mark.slow  # the repo's single fattest test (~66 s interpret-mode
 # e2e over three cipher impls); the kernel-level equality tests above
-# stay always-on and the TPU capture's mosaic stage re-proves this
+# stay always-on and chip_smoke.py's kernel phase re-proves this
 # contract on device — moved off the tier-1 budget in PR 3
 def test_engine_round_identical_across_cipher_impls():
     """Full engine C-R-D through the fused fetch ≡ the jnp path: same
@@ -112,95 +112,6 @@ def test_engine_round_identical_across_cipher_impls():
     outs_j, state_j = _run_crd("jnp")
     assert outs_f == outs_j
     same, first_diff = states_equal_excluding_junk(state_j, state_f)
-    assert same, f"state diverges at {first_diff}"
-
-
-def test_tiled_gather_matches_gather_then_xor():
-    """Kernel-level: the manual-DMA tiled gather ≡ gather → XOR,
-    including ragged R (padding steps fetch row 0 harmlessly)."""
-    from grapevine_tpu.oblivious.pallas_gather import gather_decrypt_rows_tiled
-
-    rng = np.random.default_rng(2)
-    n, z, v = 64, 4, 6
-    zv = z * v
-    tree_idx = jnp.asarray(rng.integers(0, 2**31, (n * z,)), jnp.uint32)
-    tree_val = jnp.asarray(rng.integers(0, 2**31, (n, zv)), jnp.uint32)
-    nonces = jnp.asarray(rng.integers(0, 3, (n, 2)), jnp.uint32)
-    key = jnp.asarray(rng.integers(0, 2**31, (8,)), jnp.uint32)
-    flat_b = jnp.asarray(rng.integers(0, n, (17,)), jnp.uint32)
-    oi, ov = gather_decrypt_rows_tiled(
-        key, tree_idx, tree_val, nonces, flat_b, z=z, rounds=8,
-        interpret=True,
-    )
-    pidx = tree_idx.reshape(n, z)[flat_b]
-    pval = tree_val[flat_b]
-    pn = nonces[flat_b]
-    ks = row_keystream(key, flat_b, pn, z + zv, 8)
-    written = ((pn[:, 0] != 0) | (pn[:, 1] != 0))[:, None]
-    assert np.array_equal(
-        np.asarray(oi), np.asarray(pidx ^ jnp.where(written, ks[:, :z], 0))
-    )
-    assert np.array_equal(
-        np.asarray(ov), np.asarray(pval ^ jnp.where(written, ks[:, z:], 0))
-    )
-
-
-def test_tiled_scatter_matches_encrypt_then_scatter():
-    """Kernel-level: the manual-DMA tiled write-back ≡ cipher_rows →
-    masked scatter, with duplicate junk-redirects and ragged R."""
-    from grapevine_tpu.oblivious.pallas_gather import scatter_encrypt_rows_tiled
-
-    rng = np.random.default_rng(5)
-    n, z, v = 32, 4, 6
-    zv = z * v
-    tree_idx = jnp.asarray(rng.integers(0, 2**31, (n * z,)), jnp.uint32)
-    tree_val = jnp.asarray(rng.integers(0, 2**31, (n, zv)), jnp.uint32)
-    nonces = jnp.asarray(rng.integers(0, 3, (n, 2)), jnp.uint32)
-    key = jnp.asarray(rng.integers(0, 2**31, (8,)), jnp.uint32)
-    epoch = jnp.asarray([7, 0], jnp.uint32)
-    flat_b = jnp.asarray([3, 9, 3, 20, 11], jnp.uint32)
-    owner = jnp.asarray([True, True, False, True, True])
-    new_pidx = jnp.asarray(rng.integers(0, 2**31, (5, z)), jnp.uint32)
-    new_pval = jnp.asarray(rng.integers(0, 2**31, (5, zv)), jnp.uint32)
-    orig_i = np.asarray(tree_idx).reshape(n, z).copy()
-    orig_v = np.asarray(tree_val).copy()
-    orig_n = np.asarray(nonces).copy()
-    oi, ov, on = scatter_encrypt_rows_tiled(
-        key, tree_idx, tree_val, nonces, flat_b, owner, epoch, new_pidx,
-        new_pval, z=z, rounds=8, interpret=True,
-    )
-    oi = np.asarray(oi).reshape(n, z)
-    ov = np.asarray(ov)
-    on = np.asarray(on)
-    ks = row_keystream(
-        key, flat_b, jnp.broadcast_to(epoch[None, :], (5, 2)), z + zv, 8
-    )
-    ref_i, ref_v = orig_i.copy(), orig_v.copy()
-    for j in range(5):
-        if bool(owner[j]):
-            ref_i[int(flat_b[j])] = np.asarray(new_pidx[j] ^ ks[j, :z])
-            ref_v[int(flat_b[j])] = np.asarray(new_pval[j] ^ ks[j, z:])
-    for row in range(n - 1):
-        if row in (3, 9, 11, 20):
-            assert np.array_equal(oi[row], ref_i[row]), f"idx row {row}"
-            assert np.array_equal(ov[row], ref_v[row]), f"val row {row}"
-            assert np.array_equal(on[row], np.asarray(epoch)), f"non {row}"
-        else:
-            assert np.array_equal(oi[row], orig_i[row]), row
-            assert np.array_equal(ov[row], orig_v[row]), row
-            assert np.array_equal(on[row], orig_n[row]), f"non {row}"
-
-
-@pytest.mark.slow  # interpret-mode engine round: ~26 s; kernel-level
-# tiled equality stays in tier-1 (test_tiled_*), this e2e pass is -m slow
-def test_engine_round_identical_tiled_impl():
-    """Same contract for the tiled fused impl (manual-DMA kernels)."""
-    from grapevine_tpu.testing.compare import states_equal_excluding_junk
-
-    outs_t, state_t = _run_crd("pallas_fused_tiled")
-    outs_j, state_j = _run_crd("jnp")
-    assert outs_t == outs_j
-    same, first_diff = states_equal_excluding_junk(state_j, state_t)
     assert same, f"state diverges at {first_diff}"
 
 

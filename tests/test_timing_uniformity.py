@@ -1,4 +1,4 @@
-"""Timing-uniformity leak test (VERDICT r3 #6).
+"""Timing-uniformity leak test (round-3 review #6).
 
 The reference's invariant covers timing, not just access patterns
 (reference grapevine.proto:120-122). Transcript bit-equality cannot see

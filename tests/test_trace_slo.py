@@ -385,7 +385,7 @@ def test_slo_burn_rate_flips_healthz(tier):
 @pytest.mark.slow  # ~67 s: the first capture pays jax.profiler's lazy
 # init, and the test is wall-clock-flaky under concurrent load (socket
 # timeout mid-init). Moved in the PR-9 tier-1 re-budget; the capture
-# path stays covered here in slow and by tpu_capture's live_profile.
+# path stays covered here in slow.
 def test_profile_endpoint_gated_capture(tier):
     """/profile?ms=N runs a live jax.profiler capture (enabled in this
     fixture) and refuses a concurrent one with 409."""

@@ -1,4 +1,4 @@
-"""u64 timestamp/seq lanes (VERDICT r3 weak #7: 2106 rollover + 2^32
+"""u64 timestamp/seq lanes (round-3 review weak #7: 2106 rollover + 2^32
 creates-per-lifetime were conscious-but-narrow u32 bounds; both are now
 two u32 lanes end to end — device layouts, responses, expiry)."""
 

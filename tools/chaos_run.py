@@ -26,8 +26,15 @@ Usage::
         # hot-standby mode: SIGKILL the primary once at every fault
         # site and verify the promoted replica instead of a restart
 
-The child re-enters this file with ``--child``; a shared JAX persistent
-compilation cache keeps relaunches from re-paying the compile.
+The child re-enters this file with ``--child``; parent and children
+share JAX's persistent compilation cache at its one fixed place
+(``grapevine_tpu.config.setup_compile_cache``), so relaunches do not
+re-pay the compile. A SIGKILLed child can at worst leave a torn entry
+there, which jax detects on read and recompiles (see that helper).
+
+A CPU tool: the parent (oracle, in-process standby replica) and every
+child run JAX at the same time, which only the CPU platform allows, so
+``main`` pins it for all of them.
 """
 
 from __future__ import annotations
@@ -36,7 +43,6 @@ import argparse
 import hashlib
 import os
 import random
-import shutil
 import signal
 import subprocess
 import sys
@@ -189,11 +195,12 @@ def _run_events(engine, events, start: int, progress=None):
 
 
 def run_child(args) -> int:
-    from grapevine_tpu.config import DurabilityConfig
+    from grapevine_tpu.config import DurabilityConfig, setup_compile_cache
     from grapevine_tpu.engine.batcher import GrapevineEngine
     from grapevine_tpu.engine.checkpoint import state_to_bytes
     from grapevine_tpu.obs.leakmon import EngineLeakMonitor, LeakMonitorConfig
 
+    setup_compile_cache()
     dcfg = DurabilityConfig(
         state_dir=args.state_dir,
         checkpoint_every_rounds=args.checkpoint_every,
@@ -305,52 +312,8 @@ def _parse_progress(path: str):
     return seq_hashes, finals, leakmons
 
 
-def _fork_cache(shared_dir: str) -> str:
-    """Hardlink-clone the shared XLA compilation cache for ONE child
-    launch. jax 0.4.x's persistent cache writes entries with a plain
-    ``write_bytes`` — NOT atomic — so a SIGKILL mid-compile leaves a
-    torn ``.cache`` prefix that every later process silently loads as
-    a wrong executable (observed: bit-divergent replay the moment a
-    kill site lands near a fresh compile, e.g. the delayed-eviction
-    flush program compiling in the same event as the first
-    checkpoint). Each launch therefore runs against a disposable fork
-    of known-good entries; only launches that EXIT CLEANLY merge their
-    new entries back (atomically) via :func:`_merge_cache`."""
-    d = tempfile.mkdtemp(prefix="chaos-cache-fork-")
-    for name in os.listdir(shared_dir):
-        try:
-            os.link(os.path.join(shared_dir, name), os.path.join(d, name))
-        except OSError:  # pragma: no cover - cross-device fallback
-            try:
-                shutil.copyfile(os.path.join(shared_dir, name),
-                                os.path.join(d, name))
-            except OSError:
-                pass
-    return d
-
-
-def _merge_cache(fork_dir: str, shared_dir: str) -> None:
-    """Promote a CLEAN child's new cache entries into the shared dir
-    with write-tmp + os.replace (the atomicity jax's own put lacks).
-    Existing shared entries are never touched (jax entries are
-    content-addressed by key)."""
-    for name in os.listdir(fork_dir):
-        dst = os.path.join(shared_dir, name)
-        if os.path.exists(dst):
-            continue
-        tmp = dst + f".tmp.{os.getpid()}"
-        try:
-            shutil.copyfile(os.path.join(fork_dir, name), tmp)
-            os.replace(tmp, dst)
-        except OSError:  # pragma: no cover - best-effort cache
-            try:
-                os.unlink(tmp)
-            except OSError:
-                pass
-
-
 def run_trial(trial: int, mode: str, rng: random.Random, args,
-              oracle_hashes, oracle_final, cache_dir: str) -> list[str]:
+              oracle_hashes, oracle_final) -> list[str]:
     """One kill-recover-verify trial; returns a list of failure strings."""
     errors: list[str] = []
     if mode.startswith("flush.") and (args.evict_every or 1) <= 1:
@@ -384,11 +347,7 @@ def run_trial(trial: int, mode: str, rng: random.Random, args,
             child_cmd += ["--evict-every", str(args.evict_every)]
         if args.shards is not None:
             child_cmd += ["--shards", str(args.shards)]
-        base_env = dict(
-            os.environ,
-            JAX_COMPILATION_CACHE_DIR=cache_dir,
-            JAX_PLATFORMS=os.environ.get("JAX_PLATFORMS", "cpu"),
-        )
+        base_env = dict(os.environ, JAX_PLATFORMS="cpu")
         base_env.pop("GRAPEVINE_FAULTS", None)
         if (args.shards or 1) > 1:
             # the child needs a mesh: force the virtual CPU device
@@ -404,12 +363,6 @@ def run_trial(trial: int, mode: str, rng: random.Random, args,
         launch = 0
         while True:
             env = dict(base_env)
-            # disposable cache fork per launch: a SIGKILL can tear the
-            # non-atomic jax cache writes, and a torn entry silently
-            # loads as a WRONG executable on the next launch (see
-            # _fork_cache) — only clean exits merge entries back
-            cache_fork = _fork_cache(cache_dir)
-            env["JAX_COMPILATION_CACHE_DIR"] = cache_fork
             timer_kill = None
             if launch == 0:
                 if mode == "timer":
@@ -437,9 +390,6 @@ def run_trial(trial: int, mode: str, rng: random.Random, args,
                     proc.send_signal(signal.SIGKILL)
             _, err = proc.communicate()
             rc = proc.returncode
-            if rc == 0:
-                _merge_cache(cache_fork, cache_dir)
-            shutil.rmtree(cache_fork, ignore_errors=True)
             if rc == 0:
                 break
             if rc != -signal.SIGKILL:
@@ -484,8 +434,7 @@ def run_trial(trial: int, mode: str, rng: random.Random, args,
 
 
 def run_standby_trial(trial: int, mode: str, rng: random.Random, args,
-                      oracle_hashes, oracle_final,
-                      cache_dir: str) -> list[str]:
+                      oracle_hashes, oracle_final) -> list[str]:
     """One kill-the-primary takeover trial (--standby).
 
     The parent process hosts a live :class:`StandbyReplica` (same
@@ -566,10 +515,7 @@ def run_standby_trial(trial: int, mode: str, rng: random.Random, args,
                 child_cmd += ["--evict-every", str(args.evict_every)]
             if args.shards is not None:
                 child_cmd += ["--shards", str(args.shards)]
-            env = dict(
-                os.environ,
-                JAX_PLATFORMS=os.environ.get("JAX_PLATFORMS", "cpu"),
-            )
+            env = dict(os.environ, JAX_PLATFORMS="cpu")
             env.pop("GRAPEVINE_FAULTS", None)
             if (args.shards or 1) > 1:
                 flags = env.get("XLA_FLAGS", "")
@@ -578,8 +524,6 @@ def run_standby_trial(trial: int, mode: str, rng: random.Random, args,
                         f"{flags} --xla_force_host_platform_device_count="
                         f"{args.shards}"
                     ).strip()
-            cache_fork = _fork_cache(cache_dir)
-            env["JAX_COMPILATION_CACHE_DIR"] = cache_fork
             timer_kill = None
             if mode == "timer":
                 timer_kill = rng.uniform(1.0, args.timer_max_s)
@@ -602,9 +546,6 @@ def run_standby_trial(trial: int, mode: str, rng: random.Random, args,
                     proc.send_signal(signal.SIGKILL)
             _, err = proc.communicate()
             rc = proc.returncode
-            if rc == 0:
-                _merge_cache(cache_fork, cache_dir)
-            shutil.rmtree(cache_fork, ignore_errors=True)
             if rc not in (0, -signal.SIGKILL):
                 errors.append(
                     f"trial {trial} [standby:{mode}]: primary exited "
@@ -688,10 +629,9 @@ def run_trials(n_trials: int, args=None, modes=None) -> list[str]:
 
     args = args or parse_args([])
     rng = random.Random(args.seed)
-    cache_dir = os.path.join(
-        tempfile.gettempdir(), "grapevine_chaos_jax_cache"
-    )
-    os.makedirs(cache_dir, exist_ok=True)
+    from grapevine_tpu.config import setup_compile_cache
+
+    setup_compile_cache()  # the oracle's and the standby's compiles
     t0 = time.monotonic()
     oracle_hashes, oracle_final = oracle(
         args.schedule_seed, args.events, args.posmap_impl,
@@ -707,8 +647,7 @@ def run_trials(n_trials: int, args=None, modes=None) -> list[str]:
     trial_fn = run_standby_trial if args.standby else run_trial
     for trial, mode in enumerate(modes):
         failures.extend(
-            trial_fn(trial, mode, rng, args, oracle_hashes, oracle_final,
-                     cache_dir)
+            trial_fn(trial, mode, rng, args, oracle_hashes, oracle_final)
         )
     return failures
 
@@ -781,6 +720,7 @@ def parse_args(argv):
 
 
 def main(argv=None) -> int:
+    os.environ["JAX_PLATFORMS"] = "cpu"  # before anything loads jax
     args = parse_args(argv if argv is not None else sys.argv[1:])
     if args.child:
         return run_child(args)

@@ -216,19 +216,18 @@ def audit_sharded_oram_flush(allowlist, sort_impl: str, recursive: bool,
     from grapevine_tpu.oram import round as oround
     from grapevine_tpu.oram.path_oram import init_oram
     from grapevine_tpu.parallel.mesh import (
-        _SHARD_MAP_NOCHECK, TREE_AXIS, _oram_specs, _shard_map,
-        make_mesh,
+        TREE_AXIS, _oram_specs, make_mesh,
     )
 
     cfg = _small_oram_cfg(recursive, k, ee=ee)
     state = jax.eval_shape(lambda: init_oram(cfg, jax.random.PRNGKey(0)))
     mesh = make_mesh(jax.devices()[:shards])
     specs = _oram_specs()
-    fn = _shard_map(
+    fn = jax.shard_map(
         lambda st: oround.oram_flush(cfg, st, TREE_AXIS,
                                      sort_impl=sort_impl),
         mesh=mesh, in_specs=(specs,), out_specs=specs,
-        **_SHARD_MAP_NOCHECK,
+        check_vma=False,
     )
     return analyze(
         fn,

@@ -447,17 +447,15 @@ def _trace_sharded(cfg, what, mesh, idxs=None, b=0):
 
     from grapevine_tpu.oram.path_oram import init_oram
     from grapevine_tpu.oram.round import oram_flush, oram_round
-    from grapevine_tpu.parallel.mesh import (
-        _SHARD_MAP_NOCHECK, TREE_AXIS, _oram_specs, _shard_map,
-    )
+    from grapevine_tpu.parallel.mesh import TREE_AXIS, _oram_specs
 
     state = jax.eval_shape(lambda: init_oram(cfg, jax.random.PRNGKey(0)))
     specs = _oram_specs()
     if what == "flush":
-        fn = _shard_map(
+        fn = jax.shard_map(
             lambda st: oram_flush(cfg, st, TREE_AXIS),
             mesh=mesh, in_specs=(specs,), out_specs=specs,
-            **_SHARD_MAP_NOCHECK,
+            check_vma=False,
         )
         return jax.make_jaxpr(fn)(state)
     cidxs = jnp.asarray(idxs)
@@ -474,9 +472,9 @@ def _trace_sharded(cfg, what, mesh, idxs=None, b=0):
         )
 
     lf = jax.ShapeDtypeStruct((b,), jnp.uint32)
-    fn = _shard_map(
+    fn = jax.shard_map(
         run, mesh=mesh, in_specs=(specs, P(), P(), P(), P()),
-        out_specs=(specs, P(), P()), **_SHARD_MAP_NOCHECK,
+        out_specs=(specs, P(), P()), check_vma=False,
     )
     return jax.make_jaxpr(fn)(state, lf, lf, lf, lf)
 
@@ -672,9 +670,7 @@ def check_sharded_evict_accounting(
 
     from grapevine_tpu.oram import round as round_mod
     from grapevine_tpu.oram.path_oram import init_oram
-    from grapevine_tpu.parallel.mesh import (
-        _SHARD_MAP_NOCHECK, TREE_AXIS, _oram_specs, _shard_map,
-    )
+    from grapevine_tpu.parallel.mesh import TREE_AXIS, _oram_specs
 
     def apply_batch(vals0, present0):
         return jnp.sum(vals0, axis=1), vals0, present0
@@ -687,15 +683,15 @@ def check_sharded_evict_accounting(
         )
 
     specs = _oram_specs()
-    s_round = jax.jit(_shard_map(
+    s_round = jax.jit(jax.shard_map(
         functools.partial(run_round, TREE_AXIS),
         mesh=mesh, in_specs=(specs, P(), P(), P(), P(), P()),
-        out_specs=(specs, P(), P()), **_SHARD_MAP_NOCHECK,
+        out_specs=(specs, P(), P()), check_vma=False,
     ))
-    s_flush = jax.jit(_shard_map(
+    s_flush = jax.jit(jax.shard_map(
         lambda st: round_mod.oram_flush(cfg, st, TREE_AXIS),
         mesh=mesh, in_specs=(specs,), out_specs=specs,
-        **_SHARD_MAP_NOCHECK,
+        check_vma=False,
     ))
     one_round = jax.jit(functools.partial(run_round, None))
     one_flush = jax.jit(lambda st: round_mod.oram_flush(cfg, st, None))

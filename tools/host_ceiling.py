@@ -5,7 +5,7 @@ sign + AEAD seal, gRPC loopback, server envelope decode, session lookup,
 AEAD open, challenge lockstep, request unpack/validate, batched sr25519
 verification, scheduling, response seal — with the device round replaced
 by an instant canned response. This is the frontend's ceiling: a device
-engine faster than this number is wasted (VERDICT r4 weak #3).
+engine faster than this number is wasted (round-4 review weak #3).
 
 Run:  python tools/host_ceiling.py [--clients 32] [--ops 40] [--batch 64]
                                    [--legacy]
