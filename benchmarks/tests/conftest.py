@@ -1,0 +1,15 @@
+"""The benchmark's tests run on the CPU: four virtual devices for the
+sharded configuration, set before anything imports jax."""
+
+import os
+import sys
+
+os.environ["JAX_PLATFORMS"] = "cpu"
+if "xla_force_host_platform_device_count" not in os.environ.get("XLA_FLAGS", ""):
+    os.environ["XLA_FLAGS"] = (
+        os.environ.get("XLA_FLAGS", "")
+        + " --xla_force_host_platform_device_count=4").strip()
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
