@@ -37,7 +37,6 @@ import time
 from collections import deque
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from ..config import DurabilityConfig, GrapevineConfig
@@ -132,15 +131,25 @@ def unpack_responses(resp: dict, n: int) -> list[QueryResponse]:
 class PendingRound:
     """Handle to a dispatched-but-unsynced round; ``resolve()`` blocks."""
 
-    __slots__ = ("_engine", "_resp", "_n", "_t0", "_transcript", "_batch",
-                 "_spans", "_enq", "_qdepth")
+    __slots__ = ("_engine", "_resp", "_n", "_t0", "_t1", "_transcript",
+                 "_batch", "_spans", "_counts", "_seq", "_enq", "_qdepth",
+                 "_behind_other")
 
     def __init__(self, engine, resp, n, t0, transcript=None, batch=None,
-                 spans=None):
+                 spans=None, t1=None, behind_other=False):
         self._engine = engine
         self._resp = resp
         self._n = n
+        #: perf_counter at the start of the jit'd round's enqueue, and
+        #: when the enqueue call returned (the end of this round's
+        #: dispatch: the earliest the device could have started it)
         self._t0 = t0
+        self._t1 = t0 if t1 is None else t1
+        #: a flush or an expiry sweep went to the device since the round
+        #: before: its time lies between the two rounds' ready stamps,
+        #: so this round's ``device`` span is an upper bound
+        #: (device_exact 0)
+        self._behind_other = behind_other
         #: leak-monitor hand-off (engine.leakmon set): the round's public
         #: transcript (still a device array — the copy happens on the
         #: monitor thread) plus the host-side batch dict its key groups
@@ -152,6 +161,11 @@ class PendingRound:
         #: tracer's ledger accumulates here, and the leak monitor's
         #: phase durations derive from it
         self._spans = spans
+        #: per-round counts for the tracer ledger (obs/tracer.py
+        #: ROUND_COUNTS), stamped by the scheduler (note_counts), and
+        #: the ledger's seq once resolve() has recorded it
+        self._counts = None
+        self._seq = None
         #: perf_counter enqueue time of the round's OLDEST op, stamped
         #: by the scheduler (set_enqueued_at) — the SLO's enqueue→settle
         #: anchor; None on the direct (schedulerless) path
@@ -181,12 +195,31 @@ class PendingRound:
             self._spans = {}
         self._spans[name] = (start_s, dur_s)
 
+    def note_counts(self, **counts) -> None:
+        """Add per-round counts (obs/tracer.py ROUND_COUNTS: sums and
+        sizes over the whole round, never a fact about one op) to this
+        round's ledger. Must be called before ``resolve()``."""
+        if self._counts is None:
+            self._counts = {}
+        self._counts.update(counts)
+
+    def note_settle(self, start_s: float, dur_s: float) -> None:
+        """Add the scheduler's ``settle`` span, which ends after
+        ``resolve()`` recorded the ledger: the recorded round is amended
+        by its seq, so there is one ledger per round, still."""
+        tracer = self._engine.tracer
+        if tracer is not None and self._seq is not None:
+            tracer.amend_round(self._seq, {"settle": (start_s, dur_s)})
+
     def resolve(self) -> list[QueryResponse]:
-        m = self._engine.metrics
+        eng = self._engine
+        m = eng.metrics
         # "evict" = device round completion measured from the host: the
         # jit'd fetch/apply/evict/write-back program finishes inside this
-        # wait (per-stage device splits live in the profiler trace via
-        # jax.named_scope — the host cannot time inside one XLA program)
+        # wait (per-stage device splits come from a profiler capture
+        # reduced by obs/phases.py DEVICE_SCOPES — the host cannot time
+        # inside one XLA program)
+        waited = _still_running(self._resp)
         t_ev = time.perf_counter()
         with m.time_phase("evict"):
             jax.block_until_ready(self._resp)
@@ -203,21 +236,34 @@ class PendingRound:
         spans = dict(self._spans or {})
         spans["evict"] = (t_ev, t_dm - t_ev)
         spans["demux"] = (t_dm, t_done - t_dm)
-        # the host-observed device window (async enqueue → readiness
-        # OBSERVED at resolve), emitted on EVERY config — durability on
-        # or off — so the trace JSON shape is stable across configs
-        # (obs/tracer.py zero-fills the journal/checkpoint spans it
-        # never sees). Under the pipelined scheduler resolve runs after
-        # the next round's collection window, so this is an UPPER bound
-        # on device-busy time — exact only when the evict wait is
-        # nonzero (the device was still running when the host arrived)
-        spans["device"] = (self._t0, t_dm - self._t0)
-        r0 = min(s for s, _ in spans.values())
+        # the collection window opens the round; the queue wait of its
+        # oldest op may reach back before it and stays out of the span
+        r0 = min(s for k, (s, _) in spans.items() if k != "queue")
+        # the two device windows (obs/tracer.py DERIVED_SPANS), emitted
+        # on EVERY config so the trace JSON shape is stable. "inflight"
+        # = async enqueue → readiness OBSERVED at resolve, the rounds
+        # dispatched ahead included. "device" = this round's own time:
+        # the device runs rounds in dispatch order, so it started this
+        # one when the previous one was ready or when this one's enqueue
+        # returned, whichever came last; rounds resolve in dispatch
+        # order on one thread, so the previous ready stamp is the
+        # engine's last. Exact when the device was still running each
+        # time the host arrived to wait and ran no other program (a
+        # flush, a sweep) in between (device_exact), else an upper
+        # bound.
+        spans["inflight"] = (self._t0, t_dm - self._t0)
+        prev_ready, prev_waited = eng._last_ready
+        d0 = min(max(self._t1, prev_ready), t_dm)
+        spans["device"] = (d0, t_dm - d0)
+        eng._last_ready = (t_dm, waited)
         spans["round"] = (r0, t_done - r0)
-        tracer = self._engine.tracer
+        counts = dict(self._counts or {})
+        counts["device_exact"] = int(
+            waited and prev_waited and not self._behind_other)
+        tracer = eng.tracer
         if tracer is not None:
             # a few dict ops + schema check; the ring is lock-cheap
-            tracer.record_round(spans)
+            self._seq = tracer.record_round(spans, counts)
         slo = self._engine.slo
         if slo is not None:
             # enqueue→settle commit latency, worst op in the batch: the
@@ -239,12 +285,19 @@ class PendingRound:
         if lm is not None and self._transcript is not None:
             # one non-blocking queue put; detectors run on the monitor's
             # own thread (obs/leakmon.py), never on the round path.
-            # "device" stays tracer-only — the flightrec phase schema is
-            # the canonical PHASES (+ round)
-            phases = {k: d for k, (_, d) in spans.items() if k != "device"}
+            # the derived windows stay tracer-only — the flightrec phase
+            # schema is the canonical PHASES (+ round)
+            phases = {k: d for k, (_, d) in spans.items()
+                      if k not in ("device", "inflight", "queue")}
             lm.submit_round(self._batch, self._transcript, self._n, bs,
                             phases, queue_depth=self._qdepth)
         return out
+
+
+def _still_running(resp) -> bool:
+    """True when the device has not finished the round yet: the host
+    arrived first and its wait is the device's remaining time."""
+    return not all(x.is_ready() for x in jax.tree.leaves(resp))
 
 
 class GrapevineEngine:
@@ -343,6 +396,13 @@ class GrapevineEngine:
                 engine_flush_step, static_argnums=(0,), donate_argnums=(1,)
             )
         self._rounds_since_flush = 0
+        #: (perf_counter when the last resolved round was observed
+        #: ready, whether the host had to wait for it): what the next
+        #: round's own ``device`` span starts from (PendingRound.resolve)
+        self._last_ready: tuple[float, bool] = (0.0, False)
+        #: a flush or sweep program was enqueued since the last round's
+        #: dispatch: the next round's ``device`` span is not its own time
+        self._other_device_work = False
         #: replay-time cadence audit (see _replay_record): rounds seen
         #: since the last KIND_FLUSH record; None until the first
         #: replayed record initializes it from the recovered state
@@ -502,6 +562,7 @@ class GrapevineEngine:
             faults.crash("flush.pre_dispatch")
         with self.metrics.time_phase("flush"):
             self.state = self._flush_step(self.ecfg, self.state)
+        self._other_device_work = True
         self.metrics.record_flush()
         if faults.active():
             faults.crash("flush.post_dispatch")
@@ -572,196 +633,6 @@ class GrapevineEngine:
         """Attach a CostMonitor; subsequent rounds score their device
         span against the modeled roofline floor."""
         self.costmon = costmon
-
-    def calibrate_sort_phase(self, reps: int = 5) -> float:
-        """Measure the round's bounded-key sort workload standalone and
-        record it under the ``sort`` phase (obs/phases.py).
-
-        The host cannot time inside the fused round program, but every
-        sort the round runs is shape-static and data-independent
-        (oblivious), so a standalone jitted run of the same sort
-        machinery at the same geometry IS the per-round sort cost. The
-        workload reproduces each sort site at its round shape under the
-        engine's configured ``sort_impl``/``vphases_impl``: the three
-        eviction leaf-rank sorts at their working-set sizes, the
-        admission walk's slot grouping (both vphases impls), and —
-        scan impl — the three dedup group sorts, the per-phase
-        bucket/record index group sorts, and the wide-key recipient
-        grouping sort (always ``lax.sort``, counted because the round
-        pays it). Called once at serving startup (CLI engine/mono
-        roles) — one small jit compile, zero hot-path cost. Returns
-        the min-of-``reps`` seconds (the unbiased estimator for a
-        shape-static program under scheduler noise).
-        """
-        ecfg = self.ecfg
-        b, d = ecfg.batch_size, ecfg.mb_choices
-        jobs = []  # one per ORAM round: A (mailbox), B (records), C (mailbox)
-        for cfg, nb in ((ecfg.mb, b * d), (ecfg.rec, b), (ecfg.mb, b * d)):
-            w = cfg.stash_size + nb * cfg.path_len * cfg.bucket_slots + nb
-            jobs.append(
-                (w, cfg.height, max(1, cfg.dummy_index.bit_length()), nb)
-            )
-        simpl, vimpl = ecfg.sort_impl, ecfg.vphases_impl
-        slot_bits = max(1, (b - 1).bit_length())
-        # per-phase index group bounds (vphases._index_groups): bucket
-        # groups in rounds A/C, record-block groups in round B
-        g_bits = (
-            max(1, (ecfg.mb_table_buckets + 1 + b - 1).bit_length()),
-            max(1, (ecfg.rec.blocks + 1 + b - 1).bit_length()),
-            max(1, (ecfg.mb_table_buckets + 1 + b - 1).bit_length()),
-        )
-
-        def workload(key):
-            from ..oblivious.radix import radix_group_sort, radix_rank
-            from ..oblivious.segmented import (
-                group_sort,
-                multiword_group_sort,
-            )
-
-            u32 = jnp.uint32
-            outs = []
-            ks = jax.random.split(key, 3 * len(jobs) + 2)
-            for i, (w, h, kb, nb) in enumerate(jobs):
-                leaf = jax.random.bits(ks[3 * i], (w,), u32) & u32(
-                    (1 << h) - 1
-                )
-                if simpl == "radix":
-                    outs.append(radix_rank(leaf, h + 1))
-                else:
-                    outs.append(jnp.argsort(leaf))
-                if vimpl == "scan":
-                    idxs = jax.random.bits(ks[3 * i + 1], (nb,), u32) & u32(
-                        (1 << kb) - 1
-                    )
-                    gs = (
-                        radix_group_sort([idxs], kb)
-                        if simpl == "radix"
-                        else multiword_group_sort([idxs])
-                    )
-                    outs.extend(gs)
-                    gi = jax.random.bits(ks[3 * i + 2], (b,), u32) & u32(
-                        (1 << g_bits[i]) - 1
-                    )
-                    outs.extend(
-                        group_sort(gi, sort_impl=simpl, key_bits=g_bits[i])
-                    )
-            # admission slot grouping (runs under BOTH vphases impls)
-            rslot = jax.random.bits(ks[-2], (b,), u32) & u32(
-                (1 << slot_bits) - 1
-            )
-            outs.extend(
-                group_sort(rslot, sort_impl=simpl, key_bits=slot_bits)
-            )
-            if vimpl == "scan":
-                # recipient grouping: 10-word wide key, always lax.sort
-                kcols = [
-                    jax.random.bits(ks[-1], (b,), u32) for _ in range(10)
-                ]
-                outs.extend(multiword_group_sort(kcols))
-            return outs
-
-        fn = jax.jit(workload)
-        key = jax.random.PRNGKey(0)
-        jax.block_until_ready(fn(key))  # compile + warm
-        best = None
-        for _ in range(max(1, reps)):
-            t0 = time.perf_counter()
-            jax.block_until_ready(fn(key))
-            dt = time.perf_counter() - t0
-            best = dt if best is None else min(best, dt)
-        self.metrics.observe_phase("sort", best)
-        return best
-
-    def calibrate_posmap_phase(self, reps: int = 5) -> float:
-        """Measure the round's position-resolution workload standalone
-        and record it under the ``posmap`` phase (obs/phases.py).
-
-        Same calibration stance as ``calibrate_sort_phase``: the host
-        cannot time inside the fused round program, but position
-        resolution is shape-static and data-independent (that is the
-        whole obliviousness claim — tools/check_posmap_oblivious.py), so
-        a standalone jitted run of the SAME ``lookup_remap_round``
-        machinery at the round's exact geometry — all three ORAM rounds'
-        batch lookups (mailbox A, records B, mailbox C) — IS the
-        per-round position-handling cost. Under ``posmap_impl="flat"``
-        that is one private gather + scatter per round; under
-        ``"recursive"`` it is the internal ORAM's full rounds, which is
-        exactly the number /trace needs to attribute separately from
-        ``oram_evict``. One small jit compile at serving startup, zero
-        hot-path cost; min-of-``reps`` seconds returned.
-        """
-        import time as _time
-
-        from ..oram.posmap import init_posmap, lookup_remap_round
-        from ..oram.round import occurrence_masks, occurrence_masks_sorted
-
-        ecfg = self.ecfg
-        b, d = ecfg.batch_size, ecfg.mb_choices
-        jobs = [(ecfg.mb, b * d), (ecfg.rec, b), (ecfg.mb, b * d)]
-        occ, simpl = ecfg.vphases_impl, ecfg.sort_impl
-
-        # fresh per-tree posmap pytrees at the engine's geometry: the
-        # cost is data-independent, so a fresh state prices the live one
-        # without touching device state under the lock. init_posmap, not
-        # init_oram — materializing full payload-scale trees just to
-        # read .posmap would transiently double tree memory at startup
-        pms = [
-            init_posmap(cfg, jax.random.PRNGKey(17 + i))
-            for i, (cfg, _) in enumerate(jobs)
-        ]
-
-        def workload(key, pms):
-            outs = []
-            ks = jax.random.split(key, 4 * len(jobs))
-            for i, (cfg, nb) in enumerate(jobs):
-                u32 = jnp.uint32
-                idxs = jax.random.bits(ks[4 * i], (nb,), u32) % u32(
-                    cfg.blocks + 1
-                )
-                nl = jax.random.bits(ks[4 * i + 1], (nb,), u32) & u32(
-                    cfg.leaves - 1
-                )
-                dl = jax.random.bits(ks[4 * i + 2], (nb,), u32) & u32(
-                    cfg.leaves - 1
-                )
-                if occ == "scan":
-                    fo, lo, _ = occurrence_masks_sorted(
-                        idxs, cfg.dummy_index, sort_impl=simpl,
-                        key_bits=max(1, cfg.dummy_index.bit_length()),
-                    )
-                else:
-                    fo, lo, _ = occurrence_masks(idxs, cfg.dummy_index)
-                pm_nl = pm_dl = None
-                if cfg.posmap is not None:
-                    il = cfg.posmap.inner_leaves
-                    pm_bits = jax.random.bits(ks[4 * i + 3], (2, nb), u32)
-                    pm_nl = pm_bits[0] & u32(il - 1)
-                    pm_dl = pm_bits[1] & u32(il - 1)
-                pm2, leaves, inner = lookup_remap_round(
-                    cfg, pms[i], idxs, nl, dl, fo, lo,
-                    pm_new_leaves=pm_nl, pm_dummy_leaves=pm_dl,
-                    occ_impl=occ, sort_impl=simpl,
-                )
-                # the updated map must be a live output — an unused pm2
-                # lets XLA dead-code-eliminate the remap scatter (flat)
-                # / the internal round's eviction write-back (recursive)
-                # and the phase gauge would undercount
-                outs.append((pm2, leaves))
-                if inner is not None:
-                    outs.append(inner)
-            return outs
-
-        fn = jax.jit(workload)
-        key = jax.random.PRNGKey(0)
-        jax.block_until_ready(fn(key, pms))  # compile + warm
-        best = None
-        for _ in range(max(1, reps)):
-            t0 = _time.perf_counter()
-            jax.block_until_ready(fn(key, pms))
-            dt = _time.perf_counter() - t0
-            best = dt if best is None else min(best, dt)
-        self.metrics.observe_phase("posmap", best)
-        return best
 
     def handle_queries(
         self, reqs: list[QueryRequest], now: int
@@ -839,10 +710,9 @@ class GrapevineEngine:
         point; the "journal" series isolates what it costs."""
         if self.durability is not None:
             t_j0 = time.perf_counter()
-            self.durability.append_round(batch, n_real)
-            j_s = time.perf_counter() - t_j0
-            self.metrics.observe_phase("journal", j_s)
-            spans["journal"] = (t_j0, j_s)
+            with self.metrics.time_phase("journal"):
+                self.durability.append_round(batch, n_real)
+            spans["journal"] = (t_j0, time.perf_counter() - t_j0)
         if faults.active():
             # the pipelined crash window: this round is durable (its
             # frame is fsynced) but not yet dispatched, while the
@@ -859,7 +729,7 @@ class GrapevineEngine:
         self.state, resp, transcript = self._step(
             self.ecfg, self.state, batch
         )
-        return t0, resp, transcript
+        return t0, time.perf_counter(), resp, transcript
 
     def handle_queries_async(
         self, reqs: list[QueryRequest], now: int
@@ -889,9 +759,11 @@ class GrapevineEngine:
             # isolates it).
             t_d0 = time.perf_counter()
             spans: dict = {}
+            behind_other = self._other_device_work
+            self._other_device_work = False
             with self.metrics.time_phase("dispatch"):
                 self._journal_round(batch, len(reqs), spans)
-                t0, resp, transcript = self._dispatch_round(batch)
+                t0, t1, resp, transcript = self._dispatch_round(batch)
             if faults.active():
                 faults.crash("round.post_dispatch")
             # delayed eviction: the E-th round's flush journals and
@@ -917,7 +789,8 @@ class GrapevineEngine:
                 spans["checkpoint"] = (t_c0, time.perf_counter() - t_c0)
             spans["dispatch"] = (t_d0, time.perf_counter() - t_d0)
         if lm is None:
-            return PendingRound(self, resp, len(reqs), t0, spans=spans)
+            return PendingRound(self, resp, len(reqs), t0, spans=spans, t1=t1,
+                                behind_other=behind_other)
         # hand the monitor only the key-material columns: retaining the
         # full batch dict would pin the (B, PAYLOAD_WORDS) payload array
         # in the monitor queue for grouping that never reads it
@@ -926,7 +799,8 @@ class GrapevineEngine:
         }
         return PendingRound(
             self, resp, len(reqs), t0,
-            transcript=transcript, batch=key_cols, spans=spans,
+            transcript=transcript, batch=key_cols, spans=spans, t1=t1,
+            behind_other=behind_other,
         )
 
     def handle_queries_with_transcript(self, reqs, now):
@@ -970,6 +844,7 @@ class GrapevineEngine:
                     np.uint32((int(now) >> 32) & 0xFFFFFFFF),
                 )
                 jax.block_until_ready(self.state.free_top)
+            self._other_device_work = True
             evicted = int(self.state.free_top) - before
             self.metrics.record_sweep(evicted)
             if self.durability is not None and self.durability.should_checkpoint():

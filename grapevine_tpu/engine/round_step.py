@@ -193,190 +193,204 @@ def engine_round_step(
     Same signature and return shape as `engine_step`: ``(state',
     responses, transcripts u32[B, 3])``.
     """
-    b = batch["req_type"].shape[0]
-    now = batch["now"].astype(U32)
-    # u64 clock: low lane in "now", optional high lane in "now_hi"
-    # (absent in pre-widening batch dicts — membership is trace-static)
-    now_hi = (
-        batch["now_hi"].astype(U32) if "now_hi" in batch else jnp.zeros((), U32)
-    )
-    rt = batch["req_type"].astype(U32)
-    auth = batch["auth"]
-    msg_id = batch["msg_id"]
-    recipient = batch["recipient"]
-    payload = batch["payload"]
+    with device_phase("request_unpack"):
+        b = batch["req_type"].shape[0]
+        now = batch["now"].astype(U32)
+        # u64 clock: low lane in "now", optional high lane in "now_hi"
+        # (absent in pre-widening batch dicts — membership is trace-static)
+        now_hi = (
+            batch["now_hi"].astype(U32) if "now_hi" in batch else jnp.zeros((), U32)
+        )
+        now2 = jnp.stack([now, now_hi])
+        rt = batch["req_type"].astype(U32)
+        auth = batch["auth"]
+        msg_id = batch["msg_id"]
+        recipient = batch["recipient"]
+        payload = batch["payload"]
 
-    d = ecfg.mb_choices  # candidate buckets fetched per op (mailbox tier)
-    keys = jax.random.split(state.rng, 8)
-    k_next = keys[7]
-    nl_a, nl_b, nl_c = (
-        jax.random.bits(keys[0], (b * d,), U32) & U32(ecfg.mb.leaves - 1),
-        jax.random.bits(keys[1], (b,), U32) & U32(ecfg.rec.leaves - 1),
-        jax.random.bits(keys[2], (b * d,), U32) & U32(ecfg.mb.leaves - 1),
-    )
-    dl_a, dl_b, dl_c = (
-        jax.random.bits(keys[3], (b * d,), U32) & U32(ecfg.mb.leaves - 1),
-        jax.random.bits(keys[4], (b,), U32) & U32(ecfg.rec.leaves - 1),
-        jax.random.bits(keys[5], (b * d,), U32) & U32(ecfg.mb.leaves - 1),
-    )
-    id_rand = jax.random.bits(keys[6], (b, 3), U32)
+        d = ecfg.mb_choices  # candidate buckets fetched per op (mailbox tier)
+        keys = jax.random.split(state.rng, 8)
+        k_next = keys[7]
+        nl_a, nl_b, nl_c = (
+            jax.random.bits(keys[0], (b * d,), U32) & U32(ecfg.mb.leaves - 1),
+            jax.random.bits(keys[1], (b,), U32) & U32(ecfg.rec.leaves - 1),
+            jax.random.bits(keys[2], (b * d,), U32) & U32(ecfg.mb.leaves - 1),
+        )
+        dl_a, dl_b, dl_c = (
+            jax.random.bits(keys[3], (b * d,), U32) & U32(ecfg.mb.leaves - 1),
+            jax.random.bits(keys[4], (b,), U32) & U32(ecfg.rec.leaves - 1),
+            jax.random.bits(keys[5], (b * d,), U32) & U32(ecfg.mb.leaves - 1),
+        )
+        id_rand = jax.random.bits(keys[6], (b, 3), U32)
 
-    # recursive position map (oram/posmap.py): each round additionally
-    # needs fresh uniform *internal* leaves — drawn from a fold_in side
-    # stream so the flat engine's draws above are untouched bit-for-bit
-    # (the flat↔recursive response/state identity contract)
-    recursive = ecfg.rec.posmap is not None
-    pm = {"a": (None, None), "b": (None, None), "c": (None, None)}
-    if recursive:
-        mb_il = ecfg.mb.posmap.inner_leaves
-        rec_il = ecfg.rec.posmap.inner_leaves
-        kpm = jax.random.split(jax.random.fold_in(state.rng, 0x504D), 6)
-        pm = {
-            "a": (jax.random.bits(kpm[0], (b * d,), U32) & U32(mb_il - 1),
-                  jax.random.bits(kpm[1], (b * d,), U32) & U32(mb_il - 1)),
-            "b": (jax.random.bits(kpm[2], (b,), U32) & U32(rec_il - 1),
-                  jax.random.bits(kpm[3], (b,), U32) & U32(rec_il - 1)),
-            "c": (jax.random.bits(kpm[4], (b * d,), U32) & U32(mb_il - 1),
-                  jax.random.bits(kpm[5], (b * d,), U32) & U32(mb_il - 1)),
+        # recursive position map (oram/posmap.py): each round additionally
+        # needs fresh uniform *internal* leaves — drawn from a fold_in side
+        # stream so the flat engine's draws above are untouched bit-for-bit
+        # (the flat↔recursive response/state identity contract)
+        recursive = ecfg.rec.posmap is not None
+        pm = {"a": (None, None), "b": (None, None), "c": (None, None)}
+        if recursive:
+            mb_il = ecfg.mb.posmap.inner_leaves
+            rec_il = ecfg.rec.posmap.inner_leaves
+            kpm = jax.random.split(jax.random.fold_in(state.rng, 0x504D), 6)
+            pm = {
+                "a": (jax.random.bits(kpm[0], (b * d,), U32) & U32(mb_il - 1),
+                      jax.random.bits(kpm[1], (b * d,), U32) & U32(mb_il - 1)),
+                "b": (jax.random.bits(kpm[2], (b,), U32) & U32(rec_il - 1),
+                      jax.random.bits(kpm[3], (b,), U32) & U32(rec_il - 1)),
+                "c": (jax.random.bits(kpm[4], (b * d,), U32) & U32(mb_il - 1),
+                      jax.random.bits(kpm[5], (b * d,), U32) & U32(mb_il - 1)),
+            }
+
+        is_create = rt == C.REQUEST_TYPE_CREATE
+        is_read = rt == C.REQUEST_TYPE_READ
+        is_update = rt == C.REQUEST_TYPE_UPDATE
+        is_delete = rt == C.REQUEST_TYPE_DELETE
+        is_real = is_create | is_read | is_update | is_delete
+        id_zero = is_zero_words(msg_id)
+        zero_recip = is_zero_words(recipient)
+
+        ka = jnp.where((is_create | ~id_zero)[:, None], recipient, auth)
+        # D candidate buckets per op (salted independent keyed hashes);
+        # every op fetches ALL candidates so the transcript hides which one
+        # holds the recipient (vphases.phase_a_batch chooses with masks)
+        bucket2 = jnp.stack(
+            [
+                jax.vmap(
+                    lambda k, c=c: mb_bucket_hash(
+                        state.hash_key, k, ecfg.mb_table_buckets, salt=c
+                    )
+                )(ka)
+                for c in range(d)
+            ],
+            axis=1,
+        )  # u32[B,D]
+        idxs_mb2 = jnp.where(is_real[:, None], bucket2, U32(ecfg.mb.dummy_index))
+        idxs_mb_flat = idxs_mb2.reshape(b * d)
+
+        # allocation candidates: the top B free blocks, pre-gathered so the
+        # freelist array never enters device decision logic (vphases assigns
+        # the n-th successful create candidate n). The rank arithmetic uses
+        # the +max_messages modular bias so lanes past the stack top never
+        # wrap below zero in u32 (free_top + mm - 1 <= 2^31 - 1 at the
+        # certified blocks <= 2^30 bound; the & mask is mod mm) — bit-
+        # identical to free_top-1-ks on every selected lane, and interval-
+        # transparent to rangelint instead of a masked wraparound.
+        ks = jnp.arange(b, dtype=U32)
+        mm_mask = U32(ecfg.max_messages - 1)
+        cand_pos = jnp.where(
+            ks < state.free_top,
+            (state.free_top + mm_mask - ks) & mm_mask,
+            U32(0),
+        )
+        cand_idx = state.freelist[cand_pos]
+
+        # ---- round A: mailbox (capacity, append, zero-id select/pop) ------
+        ctx = {
+            "is_real": is_real,
+            "is_create": is_create,
+            "is_read": is_read,
+            "is_update": is_update,
+            "is_delete": is_delete,
+            "id_zero": id_zero,
+            "zero_recip": zero_recip,
+            "ka": ka,
+            "idxs_mb2": idxs_mb2,
+            "cand_idx": cand_idx,
+            "id_key": state.id_key,
+            "id_rand": id_rand,
+            "free_top0": state.free_top,
+            "recipients0": state.recipients,
+            "seq0": state.seq,
+            "now": now,
+            "now_hi": now_hi,
+            "auth": auth,
+            "recipient": recipient,
+            "msg_id": msg_id,
+            "payload": payload,
         }
-
-    is_create = rt == C.REQUEST_TYPE_CREATE
-    is_read = rt == C.REQUEST_TYPE_READ
-    is_update = rt == C.REQUEST_TYPE_UPDATE
-    is_delete = rt == C.REQUEST_TYPE_DELETE
-    is_real = is_create | is_read | is_update | is_delete
-    id_zero = is_zero_words(msg_id)
-    zero_recip = is_zero_words(recipient)
-
-    ka = jnp.where((is_create | ~id_zero)[:, None], recipient, auth)
-    # D candidate buckets per op (salted independent keyed hashes);
-    # every op fetches ALL candidates so the transcript hides which one
-    # holds the recipient (vphases.phase_a_batch chooses with masks)
-    bucket2 = jnp.stack(
-        [
-            jax.vmap(
-                lambda k, c=c: mb_bucket_hash(
-                    state.hash_key, k, ecfg.mb_table_buckets, salt=c
-                )
-            )(ka)
-            for c in range(d)
-        ],
-        axis=1,
-    )  # u32[B,D]
-    idxs_mb2 = jnp.where(is_real[:, None], bucket2, U32(ecfg.mb.dummy_index))
-    idxs_mb_flat = idxs_mb2.reshape(b * d)
-
-    # allocation candidates: the top B free blocks, pre-gathered so the
-    # freelist array never enters device decision logic (vphases assigns
-    # the n-th successful create candidate n). The rank arithmetic uses
-    # the +max_messages modular bias so lanes past the stack top never
-    # wrap below zero in u32 (free_top + mm - 1 <= 2^31 - 1 at the
-    # certified blocks <= 2^30 bound; the & mask is mod mm) — bit-
-    # identical to free_top-1-ks on every selected lane, and interval-
-    # transparent to rangelint instead of a masked wraparound.
-    ks = jnp.arange(b, dtype=U32)
-    mm_mask = U32(ecfg.max_messages - 1)
-    cand_pos = jnp.where(
-        ks < state.free_top,
-        (state.free_top + mm_mask - ks) & mm_mask,
-        U32(0),
-    )
-    cand_idx = state.freelist[cand_pos]
-
-    # ---- round A: mailbox (capacity, append, zero-id select/pop) ------
-    ctx = {
-        "is_real": is_real,
-        "is_create": is_create,
-        "is_read": is_read,
-        "is_update": is_update,
-        "is_delete": is_delete,
-        "id_zero": id_zero,
-        "zero_recip": zero_recip,
-        "ka": ka,
-        "idxs_mb2": idxs_mb2,
-        "cand_idx": cand_idx,
-        "id_key": state.id_key,
-        "id_rand": id_rand,
-        "free_top0": state.free_top,
-        "recipients0": state.recipients,
-        "seq0": state.seq,
-        "now": now,
-        "now_hi": now_hi,
-        "auth": auth,
-        "recipient": recipient,
-        "msg_id": msg_id,
-        "payload": payload,
-    }
     with device_phase("round_a_mailbox"):
+        # the callback's own precomputation (recipient groups) counts
+        # as the round's apply stage
+        with device_phase("oram_apply"):
+            apply_a = phase_a_batch(ecfg, ctx)
         mb1, out_a, leaf_a = oram_round(
             ecfg.mb, state.mb, idxs_mb_flat, nl_a, dl_a,
-            phase_a_batch(ecfg, ctx), axis_name,
+            apply_a, axis_name,
             occ_impl=ecfg.vphases_impl, sort_impl=ecfg.sort_impl,
             pm_new_leaves=pm["a"][0], pm_dummy_leaves=pm["a"][1],
         )
-    # n_allocs <= free_top by phase-A admission (the quota invariant the
-    # oracle-equality suites pin), so the subtraction cannot wrap; that
-    # argument lives in RANGE_ALLOWLIST, and the min re-establishes the
-    # stack bound for interval reasoning downstream (identity at runtime)
-    free_top = jnp.minimum(
-        state.free_top - out_a["n_allocs"], U32(ecfg.max_messages)
-    )
-    recipients = state.recipients + out_a["n_claims"]
-    seq_lo, seq_hi = u64_add_u32(state.seq[0], state.seq[1], U32(b))
-    seq = jnp.stack([seq_lo, seq_hi])
+    with device_phase("freelist_counters"):
+        # n_allocs <= free_top by phase-A admission (the quota invariant the
+        # oracle-equality suites pin), so the subtraction cannot wrap; that
+        # argument lives in RANGE_ALLOWLIST, and the min re-establishes the
+        # stack bound for interval reasoning downstream (identity at runtime)
+        free_top = jnp.minimum(
+            state.free_top - out_a["n_allocs"], U32(ecfg.max_messages)
+        )
+        recipients = state.recipients + out_a["n_claims"]
+        seq_lo, seq_hi = u64_add_u32(state.seq[0], state.seq[1], U32(b))
+        seq = jnp.stack([seq_lo, seq_hi])
 
     # ---- round B: records (verify, insert, mutate, remove) ------------
-    # id words 0-1 are the PRP-encrypted (nonce, block index)
-    # (oblivious/prp.py); mailbox entries store the same encrypted form,
-    # so one decrypt covers explicit-id and zero-id-selected lookups
-    create_ok = out_a["create_ok"]
-    enc_w0 = jnp.where(id_zero, out_a["sel_blk"], msg_id[:, 0])
-    enc_w1 = jnp.where(id_zero, out_a["sel_idw"], msg_id[:, 1])
-    dec_blk = prp2_decrypt(state.id_key, enc_w0, enc_w1, ecfg.id_bits)
-    lookup_blk = jnp.where(create_ok, out_a["alloc_idx"], dec_blk)
-    real_b = is_real & (
-        create_ok | (~is_create & (~id_zero | out_a["sel_found"]))
-    )
-    idx_b = jnp.where(
-        real_b, lookup_blk & U32(ecfg.rec.blocks - 1), U32(ecfg.rec.dummy_index)
-    )
-    ctx_b = {
-        **ctx,
-        "idx_b": idx_b,
-        "real_b": real_b,
-        "create_ok": create_ok,
-        "new_id": out_a["new_id"],
-        "sel_blk": out_a["sel_blk"],
-        "sel_idw": out_a["sel_idw"],
-    }
+    with device_phase("request_unpack"):
+        # id words 0-1 are the PRP-encrypted (nonce, block index)
+        # (oblivious/prp.py); mailbox entries store the same encrypted form,
+        # so one decrypt covers explicit-id and zero-id-selected lookups
+        create_ok = out_a["create_ok"]
+        enc_w0 = jnp.where(id_zero, out_a["sel_blk"], msg_id[:, 0])
+        enc_w1 = jnp.where(id_zero, out_a["sel_idw"], msg_id[:, 1])
+        dec_blk = prp2_decrypt(state.id_key, enc_w0, enc_w1, ecfg.id_bits)
+        lookup_blk = jnp.where(create_ok, out_a["alloc_idx"], dec_blk)
+        real_b = is_real & (
+            create_ok | (~is_create & (~id_zero | out_a["sel_found"]))
+        )
+        idx_b = jnp.where(
+            real_b, lookup_blk & U32(ecfg.rec.blocks - 1), U32(ecfg.rec.dummy_index)
+        )
+        ctx_b = {
+            **ctx,
+            "idx_b": idx_b,
+            "real_b": real_b,
+            "create_ok": create_ok,
+            "new_id": out_a["new_id"],
+            "sel_blk": out_a["sel_blk"],
+            "sel_idw": out_a["sel_idw"],
+        }
     with device_phase("round_b_records"):
+        with device_phase("oram_apply"):
+            apply_b = phase_b_batch(ecfg, ctx_b)
         rec1, out_b, leaf_b = oram_round(
             ecfg.rec, state.rec, idx_b, nl_b, dl_b,
-            phase_b_batch(ecfg, ctx_b), axis_name,
+            apply_b, axis_name,
             occ_impl=ecfg.vphases_impl, sort_impl=ecfg.sort_impl,
             pm_new_leaves=pm["b"][0], pm_dummy_leaves=pm["b"][1],
         )
 
-    # freed blocks return to the freelist in slot order — one vectorized
-    # scatter, visible only to the next batch (phase-major commit rule)
-    dels = out_b["del_ok"]
-    push_pos = jnp.where(
-        dels, free_top + rank_of(dels).astype(U32), U32(ecfg.max_messages)
-    )
-    freelist = state.freelist.at[push_pos].set(idx_b, mode="drop")
-    free_top = free_top + jnp.sum(dels.astype(U32))
+    with device_phase("freelist_counters"):
+        # freed blocks return to the freelist in slot order — one vectorized
+        # scatter, visible only to the next batch (phase-major commit rule)
+        dels = out_b["del_ok"]
+        push_pos = jnp.where(
+            dels, free_top + rank_of(dels).astype(U32), U32(ecfg.max_messages)
+        )
+        freelist = state.freelist.at[push_pos].set(idx_b, mode="drop")
+        free_top = free_top + jnp.sum(dels.astype(U32))
 
     # ---- round C: mailbox finalization --------------------------------
-    ctx_c = {
-        **ctx,
-        "del_ok": out_b["del_ok"],
-        "upd_ok": out_b["upd_ok"],
-        "rm_a": out_a["rm_a"],
-    }
+    with device_phase("request_unpack"):
+        ctx_c = {
+            **ctx,
+            "del_ok": out_b["del_ok"],
+            "upd_ok": out_b["upd_ok"],
+            "rm_a": out_a["rm_a"],
+        }
     with device_phase("round_c_mailbox"):
+        with device_phase("oram_apply"):
+            apply_c = phase_c_batch(ecfg, ctx_c)
         mb2, _out_c, leaf_c = oram_round(
             ecfg.mb, mb1, idxs_mb_flat, nl_c, dl_c,
-            phase_c_batch(ecfg, ctx_c), axis_name,
+            apply_c, axis_name,
             occ_impl=ecfg.vphases_impl, sort_impl=ecfg.sort_impl,
             pm_new_leaves=pm["c"][0], pm_dummy_leaves=pm["c"][1],
         )
@@ -395,30 +409,31 @@ def engine_round_step(
         auth=auth,
         recipient=recipient,
         payload=payload,
-        now2=jnp.stack([now, now_hi]).astype(U32),
+        now2=now2,
     )
-    # transcript: D leaves per mailbox round + 1 records leaf per op —
-    # [B, 2D+1] columns (a_0..a_{D-1}, b, c_0..c_{D-1}); every entry an
-    # independent uniform draw either way. Recursive posmap: the
-    # internal ORAM's accesses are public transcript too — the same
-    # layout is appended as columns [2D+1, 2(2D+1)) so the leak monitor
-    # audits the position-resolution traffic alongside the payload's
-    # (obs/leakmon.py mb_pm/rec_pm streams)
-    if recursive:
-        transcripts = jnp.concatenate(
-            [
-                leaf_a[:, 0].reshape(b, d), leaf_b[:, 0:1],
-                leaf_c[:, 0].reshape(b, d),
-                leaf_a[:, 1].reshape(b, d), leaf_b[:, 1:2],
-                leaf_c[:, 1].reshape(b, d),
-            ],
-            axis=1,
-        )
-    else:
-        transcripts = jnp.concatenate(
-            [leaf_a.reshape(b, d), leaf_b[:, None], leaf_c.reshape(b, d)],
-            axis=1,
-        )
+    with device_phase("transcript"):
+        # transcript: D leaves per mailbox round + 1 records leaf per op —
+        # [B, 2D+1] columns (a_0..a_{D-1}, b, c_0..c_{D-1}); every entry an
+        # independent uniform draw either way. Recursive posmap: the
+        # internal ORAM's accesses are public transcript too — the same
+        # layout is appended as columns [2D+1, 2(2D+1)) so the leak monitor
+        # audits the position-resolution traffic alongside the payload's
+        # (obs/leakmon.py mb_pm/rec_pm streams)
+        if recursive:
+            transcripts = jnp.concatenate(
+                [
+                    leaf_a[:, 0].reshape(b, d), leaf_b[:, 0:1],
+                    leaf_c[:, 0].reshape(b, d),
+                    leaf_a[:, 1].reshape(b, d), leaf_b[:, 1:2],
+                    leaf_c[:, 1].reshape(b, d),
+                ],
+                axis=1,
+            )
+        else:
+            transcripts = jnp.concatenate(
+                [leaf_a.reshape(b, d), leaf_b[:, None], leaf_c.reshape(b, d)],
+                axis=1,
+            )
 
     new_state = EngineState(
         rec=rec1,

@@ -13,8 +13,10 @@ cross-validated model — see tools/check_cost_model.py for the gate):
   ``phase`` is the only label key, and label *values* are the fixed
   phase names, never geometry).
 - **roofline residual** (runtime): each resolved round pairs the
-  tracer's host-observed device span against the modeled floor
-  (steady-state bytes ÷ calibrated achieved bandwidth). The exported
+  tracer's ``device`` span — the round's own device time as the host
+  can know it (obs/tracer.py), which stays one round's time at
+  pipeline depth 2 where dispatch → ready holds three — against the
+  modeled floor (steady-state bytes ÷ calibrated achieved bandwidth). The exported
   ratio ``measured / floor`` reads as "how far off the bandwidth
   roofline this round ran": residual DRIFT is the alert signal — a
   regressed knob, a silently grown geometry, or a mispredicting model
@@ -138,8 +140,8 @@ class CostMonitor:
         ).set(self.floor_ms)
         self._g_residual = registry.gauge(
             "grapevine_cost_roofline_residual",
-            "Last round's host-observed device span / modeled roofline "
-            "floor (drift, not level, is the alert signal)",
+            "Last round's own device span / modeled roofline floor "
+            "(drift, not level, is the alert signal)",
         )
         self._g_residual_max = registry.gauge(
             "grapevine_cost_roofline_residual_max",
@@ -150,8 +152,13 @@ class CostMonitor:
         """Score one resolved round's device span against the floor.
 
         ``spans`` is the round's span ledger (name -> (start_s,
-        dur_s)); the ``device`` span is the host-observed upper bound
-        on device-busy time the tracer records."""
+        dur_s)); the ``device`` span is the round's own device time as
+        the host can know it (previous round ready, or this one's
+        dispatch end, to this one ready): right at every pipeline
+        depth, an upper bound while the host arrives after the device
+        finished, and on the round behind a flush or an expiry sweep,
+        whose device time it can hold too (``device_exact`` 0 in the
+        ledger's counts)."""
         dev = spans.get("device")
         if dev is None or self.floor_ms <= 0.0:
             return

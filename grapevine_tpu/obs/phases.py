@@ -14,40 +14,26 @@ Host-side phases (histograms + ``jax.profiler`` annotations):
 - ``dispatch``  — host pack + device round enqueue (engine/batcher.py)
 - ``evict``     — device round completion wait: the ORAM fetch / apply /
                   evict / write-back program measured from the host
-                  (per-stage device splits are in the profiler trace via
-                  the ``jax.named_scope`` annotations, not in metrics —
-                  the host cannot time inside one XLA program)
+                  (per-stage device splits come from a profiler capture
+                  reduced by the ``DEVICE_SCOPES`` below, not from
+                  metrics — the host cannot time inside one XLA program)
 - ``demux``     — device→wire response unpacking
 - ``sweep``     — expiry sweep (engine/expiry.py)
 - ``journal``   — sealed batch-journal append + fsync (engine/journal.py)
 - ``checkpoint``— sealed whole-state checkpoint write (engine/checkpoint.py)
 - ``replay``    — startup journal replay (recovery; engine/batcher.py)
-- ``sort``      — the round's bounded-key sort workload, measured by
-                  calibration (GrapevineEngine.calibrate_sort_phase):
-                  the host cannot time inside the fused round program,
-                  but every sort in the round is shape-static and
-                  data-independent (oblivious), so a standalone run of
-                  the SAME jitted sort program at the round's geometry
-                  IS the per-round sort cost — /metrics separates it
-                  from the rest of the ``evict`` phase without touching
-                  the hot path. Labelled batch-level by construction
-                  (geometry only, never request data).
-- ``posmap``    — per-round position-resolution cost, measured the same
-                  calibration way (GrapevineEngine.calibrate_posmap_phase
-                  runs the round's exact lookup_and_remap workload —
-                  all three ORAM rounds' batch lookups — standalone at
-                  the round geometry): under a recursive position map
-                  (oram/posmap.py) this is the internal ORAM's rounds,
-                  under a flat one the private gather/scatter pair, so
-                  /trace and the flight recorder attribute position
-                  handling separately from ``oram_evict``. Also a
-                  device_phase scope inside the jit'd round for TPU
-                  profiler captures.
+- ``flush``     — delayed-eviction flush enqueue (engine/batcher.py)
 
-Device-side scopes (``device_phase``): named_scope annotations compiled
-into the jit'd round so TPU profiler captures (profile_tpu.py, the
-live ``/profile`` endpoint) attribute HLO time to
-fetch/apply/evict/writeback per tree.
+Device-side scopes (``device_phase``): ``jax.named_scope`` annotations
+compiled into the jit'd programs, so a profiler capture (the benchmark's
+``--trace 1`` run, the live ``/profile`` endpoint, profile_tpu.py)
+carries for every device op the path of scopes it was traced under
+(``.../grapevine/round_a_mailbox/grapevine/oram_fetch/grapevine/
+cipher_decrypt/...``). ``DEVICE_SCOPES`` is the fixed list; every op of
+the round program sits under at least one of them
+(tests/test_device_scopes.py), and ``benchmarks/lib/xplane_scopes.py``
+reduces a capture by them. Scopes nest: a round scope holds the four
+stage scopes, a stage scope holds leaf scopes.
 """
 
 from __future__ import annotations
@@ -58,7 +44,41 @@ import time
 #: canonical phase label values — the registry declares exactly these,
 #: so a typo'd phase name raises instead of minting a new series
 PHASES = ("assembly", "verify", "dispatch", "evict", "demux", "sweep",
-          "journal", "checkpoint", "replay", "sort", "posmap", "flush")
+          "journal", "checkpoint", "replay", "flush")
+
+#: canonical device scope names — ``device_phase`` refuses any other, so
+#: a typo'd or per-op scope name raises at trace time instead of minting
+#: a path no reader knows (the ``PHASES`` stance, for the device side)
+DEVICE_SCOPES = (
+    # the round program, top level (engine/round_step.py)
+    "request_unpack",      # batch columns -> masks, keys, bucket hashes,
+                           # allocation candidates, round B's index
+    "round_a_mailbox", "round_b_records", "round_c_mailbox",
+    "freelist_counters",   # free_top / recipients / seq / freelist push
+    "respond",             # response assembly (engine/responses.py)
+    "transcript",          # the public leaf transcript's assembly
+    # the four stages of one tree round (oram/round.py, path_oram.py)
+    "oram_fetch", "oram_apply", "oram_evict", "oram_writeback",
+    # leaves of oram_fetch
+    "dedup",               # first/last-occurrence masks
+    "posmap",              # position lookup + remap (oram/posmap.py)
+    "path_index",          # leaf -> heap bucket ids, owner map
+    "path_gather",         # tree rows -> path working set
+    "psum_assembly",       # the mesh's all-reduce of the gathered rows
+    "cipher_decrypt",
+    "cache_read",          # tree-top cache planes -> working set
+    # leaves of oram_evict
+    "oram_evict_sort",     # the working set's sort by leaf
+    "stash_compact",       # leftover rows -> stash / eviction buffer
+    # leaves of oram_writeback
+    "cipher_encrypt",
+    "path_scatter",        # path working set -> tree rows
+    "cache_write",         # working set -> tree-top cache planes
+    # other programs
+    "engine_flush", "oram_flush", "sweep_records", "sweep_mailbox",
+    # bounded-key sorts (oblivious/radix.py), one scope per digit pass
+    "radix_rank", "radix_group_sort",
+) + tuple(f"radix_pass_s{shift}" for shift in range(64))
 
 #: fixed histogram boundaries for phase durations (seconds). Spans the
 #: measured range: ~100 µs host phases at B=8 up to multi-second expiry
@@ -102,6 +122,14 @@ def phase_timer(histogram, phase: str, annotate: bool = True):
             histogram.observe(dt, phase=phase)
 
 
+def trace_span(span: str):
+    """Only the ``grapevine/<span>`` profiler annotation, for a ledger
+    span whose duration the caller takes from its own stamps (the
+    scheduler's ``assembly`` and ``settle``): the round ledger and a
+    capture then show the same spans, on the profiler's clock."""
+    return phase_timer(None, span)
+
+
 def device_phase(name: str):
     """``jax.named_scope`` wrapper for phases *inside* jit'd programs.
 
@@ -110,4 +138,10 @@ def device_phase(name: str):
     """
     import jax
 
+    if name not in DEVICE_SCOPES:
+        raise ValueError(
+            f"device_phase: {name!r} is not a device scope (see "
+            "obs/phases.py DEVICE_SCOPES) — a scope is a stage of the "
+            "round program, never an operation"
+        )
     return jax.named_scope(f"grapevine/{name}")

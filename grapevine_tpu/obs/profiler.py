@@ -18,9 +18,11 @@ rather than corrupting the active session), and the duration is clamped
 to ``max_ms``.
 
 Leak stance: the profiler records *phase-level* annotations
-(``grapevine/<phase>`` TraceAnnotations and named_scopes — obs/phases.py)
-and XLA op timings, all functions of (capacity, batch size); request
-payloads and identities never enter trace metadata. The capture
+(``grapevine/<phase>`` TraceAnnotations and the ``DEVICE_SCOPES``
+named_scopes — obs/phases.py) and XLA op timings, all functions of
+(capacity, batch size); request payloads and identities never enter
+trace metadata. The Python tracer is off: a capture holds the program's
+own spans and the device's ops, not every Python call. The capture
 directory itself stays operator-local — the endpoint returns its path,
 never its contents.
 """
@@ -65,7 +67,13 @@ class ProfilerGate:
             self._n += 1
             trace_dir = os.path.join(self.outdir, f"capture-{self._n:04d}")
             os.makedirs(trace_dir, exist_ok=True)
-            jax.profiler.start_trace(trace_dir)
+            # Python tracer off, as the benchmark's captures are: with
+            # it on, 4 s of the served bus is 1.5 M events and slows
+            # the rounds being captured (PERF.md section 4). The
+            # program's own host spans are TraceAnnotations and stay.
+            options = jax.profiler.ProfileOptions()
+            options.python_tracer_level = 0
+            jax.profiler.start_trace(trace_dir, profiler_options=options)
             try:
                 time.sleep(ms / 1e3)
             finally:

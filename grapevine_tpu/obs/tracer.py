@@ -5,25 +5,36 @@ doing (fill, detector stats); this module answers *where the time went*
 — the question that sizes ROADMAP items 1-2 (tree-top caching, pipelined
 rounds) before anyone builds them. Each committed round contributes one
 span ledger assembled from the phase timers the engine already runs
-(assembly/verify/dispatch/journal/checkpoint/evict/demux plus the
-host-observed device window), kept in a fixed ring like the flight
-recorder and exported two ways:
+(assembly/verify/dispatch/journal/checkpoint/evict/demux, the
+scheduler's queue wait and settle fan-out, and the two device windows:
+``device``, the round's own device time as the host can know it, and
+``inflight``, dispatch to observed ready with the rounds queued ahead
+of it), plus a handful of per-round counts, kept in a fixed ring like
+the flight recorder and exported two ways:
 
 - ``chrome_trace()`` — Chrome trace-event JSON (the ``/trace`` endpoint,
   obs/httpd.py), loadable directly in Perfetto / chrome://tracing, with
-  host spans and the device window on separate tracks so the
-  host/device overlap is visible per round. Rounds alternate between
-  two lanes per track (tid = lane): the pipelined scheduler keeps up to
-  two rounds in flight, and the trace-event format requires complete
-  (``X``) events on one tid to nest or stay disjoint — consecutive
-  overlapping rounds on a single track would misrender;
+  host spans, the device windows and the queue wait on separate tracks
+  so the host/device overlap is visible per round. Rounds alternate
+  between two lanes per track (tid = lane): the pipelined scheduler
+  keeps up to two rounds in flight, and the trace-event format requires
+  complete (``X``) events on one tid to nest or stay disjoint —
+  consecutive overlapping rounds on a single track would misrender.
+  The ``grapevine/round`` event carries the round's counts as ``args``;
 - ``grapevine_round_bubble_ratio`` — a derived gauge: the windowed mean
   fraction of each round's wall clock the host spends *blocked* on the
   device (the ``evict`` wait over the whole round span). This is the
   number that sizes the pipelined-round refactor (Palermo,
   arXiv:2411.05400, motivates protocol/hardware pipelining from exactly
-  this phase-overlap accounting). Read it as the host/device balance
-  ``b``: with one device, double-buffered rounds take
+  this phase-overlap accounting). It reads the ``evict`` and ``round``
+  spans, not ``device``: at pipeline depth 1 read it as the host/device
+  balance ``b`` below; at depth 2 a round's span runs from its
+  collection window to its answers, two rounds later, so ``b`` reads
+  about a third of what a serial round would show, and the balance is
+  ``device`` (the round's own device time, which tiles the wall clock
+  when the device sets the pace) against ``verify`` + ``dispatch`` +
+  ``demux`` + ``settle`` (OPERATIONS.md §12). With one device,
+  double-buffered rounds take
   ``max(host, device)`` instead of today's ``host + device``, so the
   steady-state speedup is ``1 / max(b, 1-b)`` — maximal (≈2×) at
   ``b ≈ 0.5``, and ≈1× at *both* extremes: near 0 the host path is the
@@ -34,12 +45,15 @@ recorder and exported two ways:
 Leak stance — the PR-1/2 contract, enforced structurally: a span is a
 *phase*, never an operation. ``record_round()`` validates every ledger
 against the fixed span-name allowlist (the canonical phases plus the
-derived ``device``/``round`` windows) and rejects anything else with
-:class:`TelemetryLeakError`; a span value is exactly a ``(start,
-duration)`` pair of floats. There is no field in which an op type, a
-client identity, or a per-op timestamp *could* travel — every span
-covers the whole fixed-size round, so its timing is a function of
-(capacity, batch size), never of the ops inside (obs/phases.py).
+derived ``device``/``inflight``/``queue``/``settle``/``round`` windows)
+and rejects anything else with :class:`TelemetryLeakError`; a span
+value is exactly a ``(start, duration)`` pair of floats. A count is one
+of :data:`ROUND_COUNTS` and a sum over the whole round (how many ops,
+their queue wait added up, how many rounds were dispatched ahead),
+checked the same way. There is no field in which an op type, a client
+identity, or a per-op timestamp *could* travel — every span covers the
+whole fixed-size round, so its timing is a function of (capacity, batch
+size), never of the ops inside (obs/phases.py).
 
 Shape stability: every recorded ledger is normalized to carry exactly
 :data:`STABLE_SPANS` — configurations without durability contribute
@@ -51,14 +65,18 @@ Timestamps are ``time.perf_counter`` seconds (one clock domain across
 the scheduler and batcher call sites); the Chrome export converts to
 microseconds as the trace-event format requires.
 
-Span pairing: collector-side spans (assembly/verify) are stamped onto
-the round's own handle (engine/batcher.py PendingRound.note_span), so a
-ledger always describes exactly one round even under the pipelined
-scheduler — there is no cross-round staging here.
+Span pairing: collector-side spans (assembly/verify/queue) are stamped
+onto the round's own handle (engine/batcher.py PendingRound.note_span),
+so a ledger always describes exactly one round even under the pipelined
+scheduler — there is no cross-round staging here. ``settle`` ends after
+``resolve()`` has recorded the ledger, so the scheduler adds it to the
+recorded round by its ``seq`` (:meth:`RoundTracer.amend_round`): one
+ledger per round, still.
 
-Thread-safety: one lock around the ring; ``record_round()`` runs on the
-collector thread (PendingRound.resolve), ``chrome_trace()`` on the
-metrics scrape thread.
+Thread-safety: one lock around the ring; ``record_round()`` and
+``amend_round()`` run on the collector thread (PendingRound.resolve,
+BatchScheduler._settle), ``chrome_trace()`` on the metrics scrape
+thread.
 """
 
 from __future__ import annotations
@@ -70,20 +88,44 @@ import threading
 from .phases import PHASES
 from .registry import TelemetryLeakError, TelemetryRegistry
 
-#: spans assembled on the host side of every round (obs/phases.py names)
+#: spans assembled on the host side of every round (obs/phases.py
+#: names, plus the scheduler's ``settle``: the ``set_result`` fan-out
+#: and bookkeeping after ``resolve()`` returned)
 HOST_SPANS = (
     "assembly", "verify", "dispatch", "journal", "checkpoint",
-    "evict", "demux",
+    "evict", "demux", "settle",
 )
+
+#: windows derived from stamps rather than timed in place: ``queue`` =
+#: enqueue of the round's oldest admitted op -> dispatch; ``inflight`` =
+#: dispatch -> observed ready (rounds queued ahead on the device
+#: included); ``device`` = the round's own device time as the host can
+#: know it, from max(end of its dispatch, the previous round's observed
+#: ready) to its own observed ready (a flush or an expiry sweep the
+#: device ran since the round before can fall inside it:
+#: ``device_exact`` is 0 then);
+#: ``round`` = collection window -> answers unpacked
+DERIVED_SPANS = ("queue", "inflight", "device", "round")
 
 #: every recorded ledger carries exactly these spans (missing ones are
 #: normalized to zero duration at the round start) — the stable shape
 #: contract consumers rely on across durability/impl configs
-STABLE_SPANS = HOST_SPANS + ("device", "round")
+STABLE_SPANS = HOST_SPANS + DERIVED_SPANS
 
 #: names a ledger may mention at all: the stable set plus any canonical
-#: phase (sweep/replay/sort appear in calibration or recovery ledgers)
+#: phase (sweep/replay appear in recovery ledgers)
 ALLOWED_SPAN_NAMES = frozenset(STABLE_SPANS) | frozenset(PHASES)
+
+#: per-round counts a ledger may carry beside its spans, each a sum or
+#: a size over the whole round: ``ops`` admitted, ``rejected`` by batch
+#: verification, ``queue_wait_sum_s`` = sum over the admitted ops of
+#: (dispatch - enqueue), ``rounds_ahead`` = rounds dispatched and
+#: unresolved at this dispatch, ``device_exact`` = 1 when the device was
+#: still running this round and the one before each time the host
+#: arrived to wait, and ran no flush or sweep between the two (so
+#: ``device`` is this round's own device time, not an upper bound)
+ROUND_COUNTS = ("ops", "rejected", "queue_wait_sum_s", "rounds_ahead",
+                "device_exact")
 
 
 def _check_span(name: str, value) -> tuple[float, float]:
@@ -110,6 +152,22 @@ def _check_span(name: str, value) -> tuple[float, float]:
             f"bounds ({start!r}, {dur!r})"
         )
     return start, dur
+
+
+def _check_count(name: str, value) -> float:
+    if name not in ROUND_COUNTS:
+        raise TelemetryLeakError(
+            f"round tracer: count {name!r} is not a round count "
+            f"(allowed: {list(ROUND_COUNTS)}) — a count is a sum over "
+            "the whole round, never a fact about one op"
+        )
+    if isinstance(value, bool) or not isinstance(value, (int, float)) \
+            or not math.isfinite(value) or value < 0:
+        raise TelemetryLeakError(
+            f"round tracer: count {name!r} must be a finite number "
+            f">= 0, got {value!r}"
+        )
+    return value
 
 
 class RoundTracer:
@@ -146,17 +204,20 @@ class RoundTracer:
 
     # -- recording ------------------------------------------------------
 
-    def record_round(self, spans: dict) -> None:
-        """Append one round's ledger; raises TelemetryLeakError unless
-        every span fits the phase-level schema. Missing STABLE_SPANS are
-        normalized to zero duration so the trace shape is identical with
-        and without durability (journal/checkpoint) and across impls."""
+    def record_round(self, spans: dict, counts: dict | None = None) -> int:
+        """Append one round's ledger and return its ``seq``; raises
+        TelemetryLeakError unless every span and count fits the
+        phase-level schema. Missing STABLE_SPANS are normalized to zero
+        duration so the trace shape is identical with and without
+        durability (journal/checkpoint), a scheduler, and across impls."""
         if not isinstance(spans, dict):
             raise TelemetryLeakError(
                 "round tracer: a ledger must be a {span: (start, dur)} dict")
         merged: dict[str, tuple[float, float]] = {}
         for name, value in spans.items():
             merged[name] = _check_span(name, value)
+        checked = {name: _check_count(name, value)
+                   for name, value in (counts or {}).items()}
         # anchor for normalized zero-duration spans: the round span's
         # start, else the earliest recorded start, else 0
         anchor = merged.get("round", (None, 0.0))[0]
@@ -166,9 +227,11 @@ class RoundTracer:
             merged.setdefault(name, (anchor, 0.0))
         with self._lock:
             self._n += 1
-            self._ring[(self._n - 1) % self.capacity] = {
-                "seq": self._n,
+            seq = self._n
+            self._ring[(seq - 1) % self.capacity] = {
+                "seq": seq,
                 "spans": merged,
+                "counts": checked,
             }
             retained = min(self._n, self.capacity)
             bubble = self._bubble_locked()
@@ -176,6 +239,20 @@ class RoundTracer:
             self._c_rounds.inc()
             self._g_retained.set(retained)
             self._g_bubble.set(bubble)
+        return seq
+
+    def amend_round(self, seq: int, spans: dict) -> bool:
+        """Add spans that end after the ledger was recorded (the
+        scheduler's ``settle``) to round ``seq``, under the same schema.
+        False when the ring has already let that round go."""
+        checked = {name: _check_span(name, value)
+                   for name, value in spans.items()}
+        with self._lock:
+            entry = self._ring[(seq - 1) % self.capacity]
+            if entry is None or entry["seq"] != seq:
+                return False
+            entry["spans"].update(checked)
+        return True
 
     # -- derived signals ------------------------------------------------
 
@@ -237,12 +314,17 @@ class RoundTracer:
     #: tid must nest or stay disjoint per the trace-event format —
     #: adjacent rounds overlap, alternate rounds cannot
     _LANES = 2
+    #: which track a span rides: 0 host phases, 1 device windows
+    #: (``inflight`` holds ``device``), 2 queue wait
+    _TRACK = {"device": 1, "inflight": 1, "queue": 2}
 
     def chrome_trace(self) -> dict:
         """The retained rounds as Chrome trace-event JSON (Perfetto-
         loadable): complete ("X") events in microseconds, host spans on
-        tids 1-2 and the device window on tids 3-4 of one process
-        (round seq picks the lane)."""
+        tids 1-2, the device windows (``inflight`` holding ``device``)
+        on tids 3-4 and the queue wait on tids 5-6 of one process (round
+        seq picks the lane). The ``grapevine/round`` event's ``args``
+        carry the round's counts beside its ``seq``."""
         with self._lock:
             entries = self._recent_locked(self.capacity)
             bubble = self._bubble_locked()
@@ -260,12 +342,19 @@ class RoundTracer:
                 {"name": "thread_name", "ph": "M", "pid": 1,
                  "tid": 1 + self._LANES + lane,
                  "args": {"name": f"device window (lane {lane})"}})
+            events.append(
+                {"name": "thread_name", "ph": "M", "pid": 1,
+                 "tid": 1 + 2 * self._LANES + lane,
+                 "args": {"name": f"queue wait (lane {lane})"}})
         for entry in entries:
             seq = entry["seq"]
             lane = seq % self._LANES
             for name, (start, dur) in sorted(
                 entry["spans"].items(), key=lambda kv: (kv[1][0], kv[0])
             ):
+                args = {"seq": seq}
+                if name == "round":
+                    args.update(entry["counts"])
                 events.append({
                     "name": f"grapevine/{name}",
                     "cat": "round",
@@ -273,9 +362,8 @@ class RoundTracer:
                     "ts": int(start * 1e6),
                     "dur": max(0, int(dur * 1e6)),
                     "pid": 1,
-                    "tid": (1 + self._LANES + lane) if name == "device"
-                    else 1 + lane,
-                    "args": {"seq": seq},
+                    "tid": 1 + self._TRACK.get(name, 0) * self._LANES + lane,
+                    "args": args,
                 })
         return {
             "traceEvents": events,

@@ -56,10 +56,12 @@ DEPTH_BUCKETS = (
     512.0, 1024.0, 2048.0, 4096.0,
 )
 
-#: span names whose utilization fraction is exported — the host spans
-#: of the PR-6 tracer ledger plus the host-observed device window
-#: (obs/tracer.py HOST_SPANS + "device"); declared at registration so a
-#: typo'd (or per-op) phase value raises instead of minting a series
+#: span names whose utilization fraction is exported — host spans of
+#: the tracer ledger plus "device", the round's own device time as the
+#: host can know it (obs/tracer.py; one round's time at every pipeline
+#: depth, where dispatch -> ready holds the rounds queued ahead);
+#: declared at registration so a typo'd (or per-op) phase value raises
+#: instead of minting a series
 UTILIZATION_SPANS = (
     "assembly", "verify", "dispatch", "journal", "checkpoint",
     "evict", "demux", "device",
@@ -119,7 +121,7 @@ class WorkloadTelemetry:
             "grapevine_load_phase_utilization",
             "windowed mean fraction of each round's wall clock spent in "
             "the phase (from the PR-6 span ledgers; 'device' = the "
-            "host-observed device window)",
+            "round's own device time as the host observes it)",
             labels={"phase": UTILIZATION_SPANS})
         self._c_saturated = registry.counter(
             "grapevine_load_saturated_rounds_total",

@@ -553,15 +553,19 @@ def _path_gather(tree: jax.Array, path_b: jax.Array, axis_name: str | None):
     collective form of BASELINE config 5's sharded bucket tree. The
     addresses touched remain exactly the public path, preserving the
     transcript."""
-    if axis_name is None:
-        return tree[path_b]
-    n_local = tree.shape[0]
-    base = (jax.lax.axis_index(axis_name) * n_local).astype(U32)
-    loc = path_b - base
-    mine = (path_b >= base) & (path_b < base + U32(n_local))
-    vals = tree[jnp.where(mine, loc, 0)]
-    mask = mine.reshape(mine.shape + (1,) * (vals.ndim - 1))
-    return jax.lax.psum(jnp.where(mask, vals, jnp.zeros_like(vals)), axis_name)
+    with device_phase("path_gather"):
+        if axis_name is None:
+            return tree[path_b]
+        n_local = tree.shape[0]
+        base = (jax.lax.axis_index(axis_name) * n_local).astype(U32)
+        loc = path_b - base
+        mine = (path_b >= base) & (path_b < base + U32(n_local))
+        vals = tree[jnp.where(mine, loc, 0)]
+        mask = mine.reshape(mine.shape + (1,) * (vals.ndim - 1))
+        with device_phase("psum_assembly"):
+            return jax.lax.psum(
+                jnp.where(mask, vals, jnp.zeros_like(vals)), axis_name
+            )
 
 
 def _path_scatter(
@@ -576,22 +580,23 @@ def _path_scatter(
     consistent with no collective). ``owner`` optionally masks out slots
     that must not be written at all (round.py's duplicate-bucket copies);
     masked slots are dropped via out-of-range targets."""
-    if axis_name is None:
-        if owner is None:
-            return tree.at[path_b].set(new_vals, unique_indices=True)
-        tgt = jnp.where(owner, path_b, U32(tree.shape[0]))
-        # in-bounds targets are unique by construction: the owner map
-        # gives every heap bucket exactly one owning column, so at most
-        # one write lands on any row (the rest drop out of bounds)
+    with device_phase("path_scatter"):
+        if axis_name is None:
+            if owner is None:
+                return tree.at[path_b].set(new_vals, unique_indices=True)
+            tgt = jnp.where(owner, path_b, U32(tree.shape[0]))
+            # in-bounds targets are unique by construction: the owner map
+            # gives every heap bucket exactly one owning column, so at most
+            # one write lands on any row (the rest drop out of bounds)
+            return tree.at[tgt].set(new_vals, mode="drop", unique_indices=True)
+        n_local = tree.shape[0]
+        base = (jax.lax.axis_index(axis_name) * n_local).astype(U32)
+        loc = path_b - base
+        mine = (path_b >= base) & (path_b < base + U32(n_local))
+        if owner is not None:
+            mine = mine & owner
+        tgt = jnp.where(mine, loc, U32(n_local))  # out of range = dropped
         return tree.at[tgt].set(new_vals, mode="drop", unique_indices=True)
-    n_local = tree.shape[0]
-    base = (jax.lax.axis_index(axis_name) * n_local).astype(U32)
-    loc = path_b - base
-    mine = (path_b >= base) & (path_b < base + U32(n_local))
-    if owner is not None:
-        mine = mine & owner
-    tgt = jnp.where(mine, loc, U32(n_local))  # out of range = dropped
-    return tree.at[tgt].set(new_vals, mode="drop", unique_indices=True)
 
 
 def path_slot_indices(cfg: OramConfig, path_b: jax.Array) -> jax.Array:

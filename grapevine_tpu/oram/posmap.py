@@ -372,11 +372,12 @@ def lookup_remap_round(
     ``inner_leaves`` is the internal ORAM's public transcript (None for
     flat)."""
     if cfg.posmap is None:
-        leaves = jnp.where(first_occ, pm_state[idxs], dummy_leaves)
-        remap_tgt = jnp.where(last_occ, idxs, U32(cfg.blocks + 1))
-        pm2 = pm_state.at[remap_tgt].set(
-            new_leaves, mode="drop", unique_indices=True
-        )
+        with device_phase("posmap"):
+            leaves = jnp.where(first_occ, pm_state[idxs], dummy_leaves)
+            remap_tgt = jnp.where(last_occ, idxs, U32(cfg.blocks + 1))
+            pm2 = pm_state.at[remap_tgt].set(
+                new_leaves, mode="drop", unique_indices=True
+            )
         return pm2, leaves, None
     if pm_new_leaves is None or pm_dummy_leaves is None:
         raise ValueError(

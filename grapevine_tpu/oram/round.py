@@ -197,18 +197,18 @@ def _assign_evictions(
     h, z = cfg.height, cfg.bucket_slots
     w = valid.shape[0]
     skey = jnp.where(valid, wleaf, U32(0xFFFFFFFF))
-    if sort_impl == "radix":
-        # leaves are h bits; invalid rows sort last under the 2^h
-        # sentinel exactly as they do under 0xFFFFFFFF (both stable
-        # sorts keep equal keys in working-set order), so the
-        # permutation is bit-identical to the argsort — at h+1
-        # declared key bits instead of a 32-bit comparison sort
-        with device_phase("oram_evict_sort"):
+    with device_phase("oram_evict_sort"):
+        if sort_impl == "radix":
+            # leaves are h bits; invalid rows sort last under the 2^h
+            # sentinel exactly as they do under 0xFFFFFFFF (both stable
+            # sorts keep equal keys in working-set order), so the
+            # permutation is bit-identical to the argsort — at h+1
+            # declared key bits instead of a 32-bit comparison sort
             eperm = radix_rank(
                 jnp.where(valid, wleaf, U32(1) << U32(h)), h + 1
             )
-    else:
-        eperm = jnp.argsort(skey)
+        else:
+            eperm = jnp.argsort(skey)
     sleaf = skey[eperm]
     svalid = valid[eperm]
     iota_w = jnp.arange(w, dtype=jnp.int32)
@@ -326,52 +326,55 @@ def oram_round(
     nslots = b * plen * z
     recursive = cfg.posmap is not None
 
-    # --- 1. dedup, position-map read/remap, path fetch -----------------
-    if occ_impl == "scan":
-        # block indices are bounded: real < blocks, dummy = blocks
-        first_occ, last_occ, _ = occurrence_masks_sorted(
-            idxs, cfg.dummy_index, sort_impl=sort_impl,
-            key_bits=max(1, cfg.dummy_index.bit_length()),
+    with device_phase("oram_fetch"):
+        # --- 1. dedup, position-map read/remap, path fetch -----------------
+        with device_phase("dedup"):
+            if occ_impl == "scan":
+                # block indices are bounded: real < blocks, dummy = blocks
+                first_occ, last_occ, _ = occurrence_masks_sorted(
+                    idxs, cfg.dummy_index, sort_impl=sort_impl,
+                    key_bits=max(1, cfg.dummy_index.bit_length()),
+                )
+            else:
+                first_occ, last_occ, _ = occurrence_masks(idxs, cfg.dummy_index)
+        posmap, leaves, inner_leaves = lookup_remap_round(
+            cfg, state.posmap, idxs, new_leaves, dummy_leaves,
+            first_occ, last_occ,
+            pm_new_leaves=pm_new_leaves, pm_dummy_leaves=pm_dummy_leaves,
+            occ_impl=occ_impl, sort_impl=sort_impl,
         )
-    else:
-        first_occ, last_occ, _ = occurrence_masks(idxs, cfg.dummy_index)
-    posmap, leaves, inner_leaves = lookup_remap_round(
-        cfg, state.posmap, idxs, new_leaves, dummy_leaves,
-        first_occ, last_occ,
-        pm_new_leaves=pm_new_leaves, pm_dummy_leaves=pm_dummy_leaves,
-        occ_impl=occ_impl, sort_impl=sort_impl,
-    )
 
-    path_b = jax.vmap(lambda lf: path_bucket_indices(cfg, lf))(leaves)  # [B,plen]
-    flat_b = path_b.reshape(b * plen)
-    bmap = _bucket_owner_map(cfg, flat_b)  # heap bucket → owner column
-    cols_flat = jnp.repeat(jnp.arange(b, dtype=U32), plen)
-    fowner = bmap[flat_b] == cols_flat
+        with device_phase("path_index"):
+            path_b = jax.vmap(lambda lf: path_bucket_indices(cfg, lf))(leaves)  # [B,plen]
+            flat_b = path_b.reshape(b * plen)
+            bmap = _bucket_owner_map(cfg, flat_b)  # heap bucket → owner column
+            cols_flat = jnp.repeat(jnp.arange(b, dtype=U32), plen)
+            fowner = bmap[flat_b] == cols_flat
 
-    # tree-top cache split (cfg.top_cache_levels = kc): the top kc
-    # levels of every path resolve against the decrypted-resident cache
-    # planes; ONLY the bottom plen−kc levels touch the encrypted HBM
-    # tree arrays — the round's HBM path traffic and cipher row count
-    # both shrink by kc/plen (the jaxpr audit in
-    # tools/check_tree_cache_oblivious.py pins this). kc=0 degenerates
-    # to the full-path program bit-for-bit.
-    # HBM slot planes are addressed on the bucket axis ([n, Z] reshape
-    # views — free, layout-identical): flat slot ids (bucket·Z + slot)
-    # escape u32/int32 one geometry doubling before bucket ids do, so
-    # the certified u32 bound rides the bucket axis (rangelint;
-    # OPERATIONS.md §18). The tiny cache planes keep flat addressing.
-    kc = cfg.top_cache_levels
-    nbot = plen - kc
-    bot_b = path_b[:, kc:].reshape(b * nbot)
-    # level ℓ < kc heap ids are < 2^kc − 1 = cache_buckets by
-    # construction (path_bucket_indices level structure); the min
-    # states that per-level invariant, which a whole-array interval
-    # cannot carry through the column slice (runtime identity)
-    top_b = jnp.minimum(
-        path_b[:, :kc].reshape(b * kc),
-        U32(max(cfg.cache_buckets, 1) - 1),
-    )
-    top_slots = path_slot_indices(cfg, top_b).reshape(-1)  # [B*kc*z]
+            # tree-top cache split (cfg.top_cache_levels = kc): the top kc
+            # levels of every path resolve against the decrypted-resident cache
+            # planes; ONLY the bottom plen−kc levels touch the encrypted HBM
+            # tree arrays — the round's HBM path traffic and cipher row count
+            # both shrink by kc/plen (the jaxpr audit in
+            # tools/check_tree_cache_oblivious.py pins this). kc=0 degenerates
+            # to the full-path program bit-for-bit.
+            # HBM slot planes are addressed on the bucket axis ([n, Z] reshape
+            # views — free, layout-identical): flat slot ids (bucket·Z + slot)
+            # escape u32/int32 one geometry doubling before bucket ids do, so
+            # the certified u32 bound rides the bucket axis (rangelint;
+            # OPERATIONS.md §18). The tiny cache planes keep flat addressing.
+            kc = cfg.top_cache_levels
+            nbot = plen - kc
+            bot_b = path_b[:, kc:].reshape(b * nbot)
+            # level ℓ < kc heap ids are < 2^kc − 1 = cache_buckets by
+            # construction (path_bucket_indices level structure); the min
+            # states that per-level invariant, which a whole-array interval
+            # cannot carry through the column slice (runtime identity)
+            top_b = jnp.minimum(
+                path_b[:, :kc].reshape(b * kc),
+                U32(max(cfg.cache_buckets, 1) - 1),
+        )
+        top_slots = path_slot_indices(cfg, top_b).reshape(-1)  # [B*kc*z]
 
     fused = cfg.cipher_impl == "pallas_fused"
     with device_phase("oram_fetch"):
@@ -392,20 +395,22 @@ def oram_round(
             )  # [B*nbot, z]
             pval = _path_gather(state.tree_val, bot_b, axis_name)  # [B*nbot, z*v]
             pnonce = _path_gather(state.nonces, bot_b, axis_name)
-            pidx, pval = cipher_rows(
-                cfg, state.cipher_key, bot_b, pnonce, pidx, pval
-            )
+            with device_phase("cipher_decrypt"):
+                pidx, pval = cipher_rows(
+                    cfg, state.cipher_key, bot_b, pnonce, pidx, pval
+                )
         if kc:
             # cached top levels: plain private gathers, no cipher — the
             # cache planes are plaintext working state like the stash
-            pidx = jnp.concatenate(
-                [state.cache_idx[top_slots].reshape(b, kc, z),
-                 pidx.reshape(b, nbot, z)], axis=1,
-            ).reshape(b * plen, z)
-            pval = jnp.concatenate(
-                [state.cache_val[top_b].reshape(b, kc, z * v),
-                 pval.reshape(b, nbot, z * v)], axis=1,
-            ).reshape(b * plen, z * v)
+            with device_phase("cache_read"):
+                pidx = jnp.concatenate(
+                    [state.cache_idx[top_slots].reshape(b, kc, z),
+                     pidx.reshape(b, nbot, z)], axis=1,
+                ).reshape(b * plen, z)
+                pval = jnp.concatenate(
+                    [state.cache_val[top_b].reshape(b, kc, z * v),
+                     pval.reshape(b, nbot, z * v)], axis=1,
+                ).reshape(b * plen, z * v)
         # non-owner copies of shared buckets are invalidated
         pidx = jnp.where(fowner[:, None], pidx, SENTINEL)
         if recursive:
@@ -417,77 +422,82 @@ def oram_round(
                 state.tree_leaf.reshape(-1, z), bot_b, axis_name
             )
             pnonce_l = _path_gather(state.nonces, bot_b, axis_name)
-            pleaf = leaf_plane_cipher(
-                cfg, state.cipher_key, bot_b, pnonce_l, pleaf,
-            )
-            if kc:
-                pleaf = jnp.concatenate(
-                    [state.cache_leaf[top_slots].reshape(b, kc, z),
-                     pleaf.reshape(b, nbot, z)], axis=1,
+            with device_phase("cipher_decrypt"):
+                pleaf = leaf_plane_cipher(
+                    cfg, state.cipher_key, bot_b, pnonce_l, pleaf,
                 )
+            if kc:
+                with device_phase("cache_read"):
+                    pleaf = jnp.concatenate(
+                        [state.cache_leaf[top_slots].reshape(b, kc, z),
+                         pleaf.reshape(b, nbot, z)], axis=1,
+                    )
             pleaf = pleaf.reshape(-1)
 
-    w = s + nslots + b  # + b reserved rows for net inserts
-    widx0 = jnp.concatenate(
-        [state.stash_idx, pidx.reshape(-1), jnp.full((b,), SENTINEL, U32)]
-    )
-    wval0 = jnp.concatenate(
-        [state.stash_val, pval.reshape(-1, v), jnp.zeros((b, v), U32)], axis=0
-    )
+    with device_phase("oram_fetch"):
+        w = s + nslots + b  # + b reserved rows for net inserts
+        widx0 = jnp.concatenate(
+            [state.stash_idx, pidx.reshape(-1), jnp.full((b,), SENTINEL, U32)]
+        )
+        wval0 = jnp.concatenate(
+            [state.stash_val, pval.reshape(-1, v), jnp.zeros((b, v), U32)], axis=0
+        )
 
-    # --- 2. vectorized slot-order apply --------------------------------
-    # Initial presence via a dense block-index → working-set-row map (one
-    # scatter + one gather; block indices are unique among live blocks,
-    # so at most one row writes each map slot). Replaces a [B, W] compare
-    # that costs O(B·W) — ~3·10^8 bools per round at B=2048. The map is
-    # private working memory, same standing as the posmap.
-    iota_w = jnp.arange(w, dtype=U32)
-    # non-real rows (SENTINEL, dummy) drop out of bounds: a live block
-    # occupies exactly one working-set row, so in-bounds targets are
-    # unique and the scatter can use the parallel lowering
-    row_map = jnp.full((cfg.blocks + 2,), U32(w)).at[
-        jnp.where(widx0 < U32(cfg.blocks), widx0, U32(cfg.blocks + 2))
-    ].set(iota_w, mode="drop", unique_indices=True)
-    pos0 = row_map[jnp.minimum(idxs, U32(cfg.blocks))]  # u32[B]; w = absent
-    present0 = pos0 != U32(w)
-    pos0 = jnp.minimum(pos0, U32(w - 1))
-    vals0 = jnp.where(
-        present0[:, None], wval0[pos0.astype(jnp.int32)], 0
-    )  # u32[B, V]
+    with device_phase("oram_apply"):
+        # --- 2. vectorized slot-order apply --------------------------------
+        # Initial presence via a dense block-index → working-set-row map (one
+        # scatter + one gather; block indices are unique among live blocks,
+        # so at most one row writes each map slot). Replaces a [B, W] compare
+        # that costs O(B·W) — ~3·10^8 bools per round at B=2048. The map is
+        # private working memory, same standing as the posmap.
+        iota_w = jnp.arange(w, dtype=U32)
+        # non-real rows (SENTINEL, dummy) drop out of bounds: a live block
+        # occupies exactly one working-set row, so in-bounds targets are
+        # unique and the scatter can use the parallel lowering
+        row_map = jnp.full((cfg.blocks + 2,), U32(w)).at[
+            jnp.where(widx0 < U32(cfg.blocks), widx0, U32(cfg.blocks + 2))
+        ].set(iota_w, mode="drop", unique_indices=True)
+        pos0 = row_map[jnp.minimum(idxs, U32(cfg.blocks))]  # u32[B]; w = absent
+        present0 = pos0 != U32(w)
+        pos0 = jnp.minimum(pos0, U32(w - 1))
+        vals0 = jnp.where(
+            present0[:, None], wval0[pos0.astype(jnp.int32)], 0
+        )  # u32[B, V]
 
     with device_phase("oram_apply"):
         outs, final_val, final_alive = apply_batch(vals0, present0)
 
-    # --- final per-key state → working-set rows ------------------------
-    # the round's last op on each key commits the callback's final state:
-    # updates rewrite (or kill) the existing row; net inserts land in the
-    # b reserved trailing rows (row s + nslots + slot index)
-    upd = last_occ & present0
-    ins = last_occ & ~present0 & final_alive
+    with device_phase("oram_apply"):
+        # --- final per-key state → working-set rows ------------------------
+        # the round's last op on each key commits the callback's final state:
+        # updates rewrite (or kill) the existing row; net inserts land in the
+        # b reserved trailing rows (row s + nslots + slot index)
+        upd = last_occ & present0
+        ins = last_occ & ~present0 & final_alive
 
-    slot_iota = jnp.arange(b, dtype=U32)
-    row_tgt = jnp.where(
-        upd, pos0, jnp.where(ins, U32(s + nslots) + slot_iota, U32(w))
-    )  # OOB = no write
-    widx = widx0.at[row_tgt].set(
-        jnp.where(final_alive, idxs, SENTINEL), mode="drop"
-    )
-    wval = wval0.at[row_tgt.astype(jnp.int32)].set(final_val, mode="drop")
+        slot_iota = jnp.arange(b, dtype=U32)
+        row_tgt = jnp.where(
+            upd, pos0, jnp.where(ins, U32(s + nslots) + slot_iota, U32(w))
+        )  # OOB = no write
+        widx = widx0.at[row_tgt].set(
+            jnp.where(final_alive, idxs, SENTINEL), mode="drop"
+        )
+        wval = wval0.at[row_tgt.astype(jnp.int32)].set(final_val, mode="drop")
 
-    if recursive:
-        # leaves ride the per-slot metadata plane (the map is its own
-        # ORAM now — it cannot be gathered); rows committed this round
-        # take their key's winning fresh leaf, the same value the map's
-        # remap just recorded (the posmap↔metadata invariant)
-        wleaf = jnp.concatenate(
-            [state.stash_leaf, pleaf, jnp.zeros((b,), U32)]
-        ).at[row_tgt].set(new_leaves, mode="drop")
-    else:
-        # leaves for the whole working set come from the remapped private
-        # posmap (the authoritative assignment — the tree stores no
-        # leaves): rows touched this round already read back their op's
-        # new leaf
-        wleaf = working_leaves(posmap, cfg, widx)
+        if recursive:
+            # leaves ride the per-slot metadata plane (the map is its own
+            # ORAM now — it cannot be gathered); rows committed this round
+            # take their key's winning fresh leaf, the same value the map's
+            # remap just recorded (the posmap↔metadata invariant)
+            wleaf = jnp.concatenate(
+                [state.stash_leaf, pleaf, jnp.zeros((b,), U32)]
+            ).at[row_tgt].set(new_leaves, mode="drop")
+        else:
+            # leaves for the whole working set come from the remapped private
+            # posmap (the authoritative assignment — the tree stores no
+            # leaves): rows touched this round already read back their op's
+            # new leaf
+            wleaf = working_leaves(posmap, cfg, widx)
 
     # --- 3. joint level-synchronous greedy eviction --------------------
     # One argsort of the working set by leaf, then per level: entries
@@ -518,37 +528,39 @@ def oram_round(
                 wleaf, mode="drop", unique_indices=True
             )
 
-        # --- 4. stash recompaction -------------------------------------
-        leftover = valid & ~placed
-        srank = rank_of(leftover)
-        starget = jnp.where(leftover, srank, s)  # OOB = dropped
-        stash_idx = jnp.full((s,), SENTINEL, U32).at[starget].set(
-            widx, mode="drop", unique_indices=True
-        )
-        stash_val = jnp.zeros((s, v), U32).at[starget].set(
-            wval, mode="drop", unique_indices=True
-        )
-        stash_leaf = (
-            jnp.zeros((s,), U32).at[starget].set(
-                wleaf, mode="drop", unique_indices=True
+        with device_phase("stash_compact"):
+            # --- 4. stash recompaction -------------------------------------
+            leftover = valid & ~placed
+            srank = rank_of(leftover)
+            starget = jnp.where(leftover, srank, s)  # OOB = dropped
+            stash_idx = jnp.full((s,), SENTINEL, U32).at[starget].set(
+                widx, mode="drop", unique_indices=True
             )
-            if recursive
-            else state.stash_leaf
-        )
-        n_left = jnp.sum(leftover.astype(jnp.int32))
-        # == n_left - min(n_left, s), in the interval-transparent form
-        stash_dropped = jnp.maximum(n_left - s, 0).astype(U32)
+            stash_val = jnp.zeros((s, v), U32).at[starget].set(
+                wval, mode="drop", unique_indices=True
+            )
+            stash_leaf = (
+                jnp.zeros((s,), U32).at[starget].set(
+                    wleaf, mode="drop", unique_indices=True
+                )
+                if recursive
+                else state.stash_leaf
+            )
+            n_left = jnp.sum(leftover.astype(jnp.int32))
+            # == n_left - min(n_left, s), in the interval-transparent form
+            stash_dropped = jnp.maximum(n_left - s, 0).astype(U32)
 
-    # the eviction output new_pidx/new_pval is [col, level, slot]-
-    # ordered, so the top-kc/bottom split is a contiguous reshape per
-    # column; one owner bit per bucket row covers all z slots on the
-    # bucket-axis scatters below
-    fowner_bot = fowner.reshape(b, plen)[:, kc:].reshape(b * nbot)
-    bot_pidx = new_pidx.reshape(b, plen, z)[:, kc:].reshape(b * nbot, z)
-    bot_pval = new_pval.reshape(b, plen, z * v)[:, kc:].reshape(
-        b * nbot, z * v
-    )
-    epochs_w = jnp.broadcast_to(state.epoch[None, :], (b * nbot, 2))
+    with device_phase("oram_writeback"):
+        # the eviction output new_pidx/new_pval is [col, level, slot]-
+        # ordered, so the top-kc/bottom split is a contiguous reshape per
+        # column; one owner bit per bucket row covers all z slots on the
+        # bucket-axis scatters below
+        fowner_bot = fowner.reshape(b, plen)[:, kc:].reshape(b * nbot)
+        bot_pidx = new_pidx.reshape(b, plen, z)[:, kc:].reshape(b * nbot, z)
+        bot_pval = new_pval.reshape(b, plen, z * v)[:, kc:].reshape(
+            b * nbot, z * v
+        )
+        epochs_w = jnp.broadcast_to(state.epoch[None, :], (b * nbot, 2))
     with device_phase("oram_writeback"):
         if axis_name is None and fused and cfg.encrypted:
             # single-chip fast path: encrypt + scatter in ONE HBM pass (the
@@ -565,14 +577,15 @@ def oram_round(
                 interpret=not _on_tpu(),
             )
         else:
-            enc_pidx, enc_pval = cipher_rows(
-                cfg,
-                state.cipher_key,
-                bot_b,
-                epochs_w,
-                bot_pidx,
-                bot_pval,
-            )
+            with device_phase("cipher_encrypt"):
+                enc_pidx, enc_pval = cipher_rows(
+                    cfg,
+                    state.cipher_key,
+                    bot_b,
+                    epochs_w,
+                    bot_pidx,
+                    bot_pval,
+                )
             tree_idx_new = _path_scatter(
                 state.tree_idx.reshape(-1, z), bot_b, enc_pidx, axis_name,
                 fowner_bot,
@@ -593,19 +606,20 @@ def oram_round(
             # unique in-bounds targets); replicated private state, so no
             # collective even under sharding — every chip writes the
             # identical values (the stash-recompaction standing)
-            fowner_top = fowner.reshape(b, plen)[:, :kc].reshape(b * kc)
-            cache_idx_new = _path_scatter(
-                state.cache_idx, top_slots,
-                new_pidx.reshape(b, plen, z)[:, :kc].reshape(-1), None,
-                jnp.repeat(fowner_top, z),
-            )
-            cache_val_new = _path_scatter(
-                state.cache_val, top_b,
-                new_pval.reshape(b, plen, z * v)[:, :kc].reshape(
-                    b * kc, z * v
-                ),
-                None, fowner_top,
-            )
+            with device_phase("cache_write"):
+                fowner_top = fowner.reshape(b, plen)[:, :kc].reshape(b * kc)
+                cache_idx_new = _path_scatter(
+                    state.cache_idx, top_slots,
+                    new_pidx.reshape(b, plen, z)[:, :kc].reshape(-1), None,
+                    jnp.repeat(fowner_top, z),
+                )
+                cache_val_new = _path_scatter(
+                    state.cache_val, top_b,
+                    new_pval.reshape(b, plen, z * v)[:, :kc].reshape(
+                        b * kc, z * v
+                    ),
+                    None, fowner_top,
+                )
         else:
             cache_idx_new = state.cache_idx
             cache_val_new = state.cache_val
@@ -614,50 +628,53 @@ def oram_round(
             from .path_oram import leaf_plane_cipher
 
             pleaf3 = new_pleaf.reshape(b, plen, z)
-            enc_pleaf = leaf_plane_cipher(
-                cfg, state.cipher_key, bot_b, epochs_w,
-                pleaf3[:, kc:].reshape(b * nbot, z),
-            )
+            with device_phase("cipher_encrypt"):
+                enc_pleaf = leaf_plane_cipher(
+                    cfg, state.cipher_key, bot_b, epochs_w,
+                    pleaf3[:, kc:].reshape(b * nbot, z),
+                )
             tree_leaf_new = _path_scatter(
                 state.tree_leaf.reshape(-1, z), bot_b, enc_pleaf, axis_name,
                 fowner_bot,
             ).reshape(-1)
             if kc:
-                cache_leaf_new = _path_scatter(
-                    state.cache_leaf, top_slots,
-                    pleaf3[:, :kc].reshape(-1), None,
-                    jnp.repeat(fowner_top, z),
-                )
+                with device_phase("cache_write"):
+                    cache_leaf_new = _path_scatter(
+                        state.cache_leaf, top_slots,
+                        pleaf3[:, :kc].reshape(-1), None,
+                        jnp.repeat(fowner_top, z),
+                    )
         else:
             tree_leaf_new = state.tree_leaf
-    new_state = OramState(
-        tree_idx=tree_idx_new,
-        tree_val=tree_val_new,
-        cache_idx=cache_idx_new,
-        cache_val=cache_val_new,
-        cache_leaf=cache_leaf_new,
-        tree_leaf=tree_leaf_new,
-        stash_idx=stash_idx,
-        stash_val=stash_val,
-        stash_leaf=stash_leaf,
-        # evict_window == 1: the buffer planes are zero-length and the
-        # window bookkeeping never advances — bit-for-bit the pre-PR-15
-        # per-round-eviction program
-        ebuf_idx=state.ebuf_idx,
-        ebuf_val=state.ebuf_val,
-        ebuf_leaf=state.ebuf_leaf,
-        ebuf_paths=state.ebuf_paths,
-        ebuf_rounds=state.ebuf_rounds,
-        ebuf_gen=state.ebuf_gen,
-        fetch_tag=state.fetch_tag,
-        posmap=posmap,
-        overflow=state.overflow + stash_dropped,
-        nonces=nonces,
-        cipher_key=state.cipher_key,
-        epoch=epoch_next(state.epoch),
-    )
-    if recursive:
-        leaves = jnp.stack([leaves, inner_leaves], axis=1)
+    with device_phase("oram_writeback"):
+        new_state = OramState(
+            tree_idx=tree_idx_new,
+            tree_val=tree_val_new,
+            cache_idx=cache_idx_new,
+            cache_val=cache_val_new,
+            cache_leaf=cache_leaf_new,
+            tree_leaf=tree_leaf_new,
+            stash_idx=stash_idx,
+            stash_val=stash_val,
+            stash_leaf=stash_leaf,
+            # evict_window == 1: the buffer planes are zero-length and the
+            # window bookkeeping never advances — bit-for-bit the pre-PR-15
+            # per-round-eviction program
+            ebuf_idx=state.ebuf_idx,
+            ebuf_val=state.ebuf_val,
+            ebuf_leaf=state.ebuf_leaf,
+            ebuf_paths=state.ebuf_paths,
+            ebuf_rounds=state.ebuf_rounds,
+            ebuf_gen=state.ebuf_gen,
+            fetch_tag=state.fetch_tag,
+            posmap=posmap,
+            overflow=state.overflow + stash_dropped,
+            nonces=nonces,
+            cipher_key=state.cipher_key,
+            epoch=epoch_next(state.epoch),
+        )
+        if recursive:
+            leaves = jnp.stack([leaves, inner_leaves], axis=1)
     return new_state, outs, leaves
 
 
@@ -703,46 +720,49 @@ def _oram_fetch_round(
     nslots = b * plen * z
     recursive = cfg.posmap is not None
 
-    # --- 1. dedup, position-map read/remap, path fetch (as E=1) --------
-    if occ_impl == "scan":
-        first_occ, last_occ, _ = occurrence_masks_sorted(
-            idxs, cfg.dummy_index, sort_impl=sort_impl,
-            key_bits=max(1, cfg.dummy_index.bit_length()),
+    with device_phase("oram_fetch"):
+        # --- 1. dedup, position-map read/remap, path fetch (as E=1) --------
+        with device_phase("dedup"):
+            if occ_impl == "scan":
+                first_occ, last_occ, _ = occurrence_masks_sorted(
+                    idxs, cfg.dummy_index, sort_impl=sort_impl,
+                    key_bits=max(1, cfg.dummy_index.bit_length()),
+                )
+            else:
+                first_occ, last_occ, _ = occurrence_masks(idxs, cfg.dummy_index)
+        posmap, leaves, inner_leaves = lookup_remap_round(
+            cfg, state.posmap, idxs, new_leaves, dummy_leaves,
+            first_occ, last_occ,
+            pm_new_leaves=pm_new_leaves, pm_dummy_leaves=pm_dummy_leaves,
+            occ_impl=occ_impl, sort_impl=sort_impl,
         )
-    else:
-        first_occ, last_occ, _ = occurrence_masks(idxs, cfg.dummy_index)
-    posmap, leaves, inner_leaves = lookup_remap_round(
-        cfg, state.posmap, idxs, new_leaves, dummy_leaves,
-        first_occ, last_occ,
-        pm_new_leaves=pm_new_leaves, pm_dummy_leaves=pm_dummy_leaves,
-        occ_impl=occ_impl, sort_impl=sort_impl,
-    )
 
-    path_b = jax.vmap(lambda lf: path_bucket_indices(cfg, lf))(leaves)
-    flat_b = path_b.reshape(b * plen)
-    bmap = _bucket_owner_map(cfg, flat_b)
-    cols_flat = jnp.repeat(jnp.arange(b, dtype=U32), plen)
-    # keep = this round's owner copy of a bucket that is NOT stale: a
-    # bucket tagged earlier in this flush window already surrendered its
-    # live rows to the buffer, so its HBM/cache bytes are dead copies
-    fresh = state.fetch_tag[flat_b] != state.ebuf_gen
-    keep = (bmap[flat_b] == cols_flat) & fresh
+        with device_phase("path_index"):
+            path_b = jax.vmap(lambda lf: path_bucket_indices(cfg, lf))(leaves)
+            flat_b = path_b.reshape(b * plen)
+            bmap = _bucket_owner_map(cfg, flat_b)
+            cols_flat = jnp.repeat(jnp.arange(b, dtype=U32), plen)
+            # keep = this round's owner copy of a bucket that is NOT stale: a
+            # bucket tagged earlier in this flush window already surrendered its
+            # live rows to the buffer, so its HBM/cache bytes are dead copies
+            fresh = state.fetch_tag[flat_b] != state.ebuf_gen
+            keep = (bmap[flat_b] == cols_flat) & fresh
 
-    # HBM slot planes are addressed on the bucket axis ([n, Z] reshape
-    # views) exactly as in oram_round — flat slot ids escape u32/int32
-    # one geometry doubling before bucket ids do (rangelint;
-    # OPERATIONS.md §18). The tiny cache planes keep flat addressing.
-    kc = cfg.top_cache_levels
-    nbot = plen - kc
-    bot_b = path_b[:, kc:].reshape(b * nbot)
-    # level ℓ < kc heap ids are < 2^kc − 1 = cache_buckets by
-    # construction; the min states that per-level invariant for
-    # interval reasoning (runtime identity, see oram_round)
-    top_b = jnp.minimum(
-        path_b[:, :kc].reshape(b * kc),
-        U32(max(cfg.cache_buckets, 1) - 1),
-    )
-    top_slots = path_slot_indices(cfg, top_b).reshape(-1)
+            # HBM slot planes are addressed on the bucket axis ([n, Z] reshape
+            # views) exactly as in oram_round — flat slot ids escape u32/int32
+            # one geometry doubling before bucket ids do (rangelint;
+            # OPERATIONS.md §18). The tiny cache planes keep flat addressing.
+            kc = cfg.top_cache_levels
+            nbot = plen - kc
+            bot_b = path_b[:, kc:].reshape(b * nbot)
+            # level ℓ < kc heap ids are < 2^kc − 1 = cache_buckets by
+            # construction; the min states that per-level invariant for
+            # interval reasoning (runtime identity, see oram_round)
+            top_b = jnp.minimum(
+                path_b[:, :kc].reshape(b * kc),
+                U32(max(cfg.cache_buckets, 1) - 1),
+        )
+        top_slots = path_slot_indices(cfg, top_b).reshape(-1)
 
     fused = cfg.cipher_impl == "pallas_fused"
     with device_phase("oram_fetch"):
@@ -760,18 +780,20 @@ def _oram_fetch_round(
             )  # [B*nbot, z]
             pval = _path_gather(state.tree_val, bot_b, axis_name)
             pnonce = _path_gather(state.nonces, bot_b, axis_name)
-            pidx, pval = cipher_rows(
-                cfg, state.cipher_key, bot_b, pnonce, pidx, pval
-            )
+            with device_phase("cipher_decrypt"):
+                pidx, pval = cipher_rows(
+                    cfg, state.cipher_key, bot_b, pnonce, pidx, pval
+                )
         if kc:
-            pidx = jnp.concatenate(
-                [state.cache_idx[top_slots].reshape(b, kc, z),
-                 pidx.reshape(b, nbot, z)], axis=1,
-            ).reshape(b * plen, z)
-            pval = jnp.concatenate(
-                [state.cache_val[top_b].reshape(b, kc, z * v),
-                 pval.reshape(b, nbot, z * v)], axis=1,
-            ).reshape(b * plen, z * v)
+            with device_phase("cache_read"):
+                pidx = jnp.concatenate(
+                    [state.cache_idx[top_slots].reshape(b, kc, z),
+                     pidx.reshape(b, nbot, z)], axis=1,
+                ).reshape(b * plen, z)
+                pval = jnp.concatenate(
+                    [state.cache_val[top_b].reshape(b, kc, z * v),
+                     pval.reshape(b, nbot, z * v)], axis=1,
+                ).reshape(b * plen, z * v)
         # non-owner copies AND stale copies are invalidated
         pidx = jnp.where(keep[:, None], pidx, SENTINEL)
         if recursive:
@@ -781,63 +803,68 @@ def _oram_fetch_round(
                 state.tree_leaf.reshape(-1, z), bot_b, axis_name
             )
             pnonce_l = _path_gather(state.nonces, bot_b, axis_name)
-            pleaf = leaf_plane_cipher(
-                cfg, state.cipher_key, bot_b, pnonce_l, pleaf,
-            )
-            if kc:
-                pleaf = jnp.concatenate(
-                    [state.cache_leaf[top_slots].reshape(b, kc, z),
-                     pleaf.reshape(b, nbot, z)], axis=1,
+            with device_phase("cipher_decrypt"):
+                pleaf = leaf_plane_cipher(
+                    cfg, state.cipher_key, bot_b, pnonce_l, pleaf,
                 )
+            if kc:
+                with device_phase("cache_read"):
+                    pleaf = jnp.concatenate(
+                        [state.cache_leaf[top_slots].reshape(b, kc, z),
+                         pleaf.reshape(b, nbot, z)], axis=1,
+                    )
             pleaf = pleaf.reshape(-1)
 
-    # working set = stash ∪ buffer ∪ fetched paths ∪ B insert rows
-    w = s + c + nslots + b
-    widx0 = jnp.concatenate(
-        [state.stash_idx, state.ebuf_idx, pidx.reshape(-1),
-         jnp.full((b,), SENTINEL, U32)]
-    )
-    wval0 = jnp.concatenate(
-        [state.stash_val, state.ebuf_val, pval.reshape(-1, v),
-         jnp.zeros((b, v), U32)], axis=0
-    )
+    with device_phase("oram_fetch"):
+        # working set = stash ∪ buffer ∪ fetched paths ∪ B insert rows
+        w = s + c + nslots + b
+        widx0 = jnp.concatenate(
+            [state.stash_idx, state.ebuf_idx, pidx.reshape(-1),
+             jnp.full((b,), SENTINEL, U32)]
+        )
+        wval0 = jnp.concatenate(
+            [state.stash_val, state.ebuf_val, pval.reshape(-1, v),
+             jnp.zeros((b, v), U32)], axis=0
+        )
 
-    # --- 2. vectorized slot-order apply (as E=1; see oram_round) -------
-    iota_w = jnp.arange(w, dtype=U32)
-    row_map = jnp.full((cfg.blocks + 2,), U32(w)).at[
-        jnp.where(widx0 < U32(cfg.blocks), widx0, U32(cfg.blocks + 2))
-    ].set(iota_w, mode="drop", unique_indices=True)
-    pos0 = row_map[jnp.minimum(idxs, U32(cfg.blocks))]
-    present0 = pos0 != U32(w)
-    pos0 = jnp.minimum(pos0, U32(w - 1))
-    vals0 = jnp.where(
-        present0[:, None], wval0[pos0.astype(jnp.int32)], 0
-    )
+    with device_phase("oram_apply"):
+        # --- 2. vectorized slot-order apply (as E=1; see oram_round) -------
+        iota_w = jnp.arange(w, dtype=U32)
+        row_map = jnp.full((cfg.blocks + 2,), U32(w)).at[
+            jnp.where(widx0 < U32(cfg.blocks), widx0, U32(cfg.blocks + 2))
+        ].set(iota_w, mode="drop", unique_indices=True)
+        pos0 = row_map[jnp.minimum(idxs, U32(cfg.blocks))]
+        present0 = pos0 != U32(w)
+        pos0 = jnp.minimum(pos0, U32(w - 1))
+        vals0 = jnp.where(
+            present0[:, None], wval0[pos0.astype(jnp.int32)], 0
+        )
 
     with device_phase("oram_apply"):
         outs, final_val, final_alive = apply_batch(vals0, present0)
 
-    upd = last_occ & present0
-    ins = last_occ & ~present0 & final_alive
+    with device_phase("oram_apply"):
+        upd = last_occ & present0
+        ins = last_occ & ~present0 & final_alive
 
-    slot_iota = jnp.arange(b, dtype=U32)
-    row_tgt = jnp.where(
-        upd, pos0, jnp.where(ins, U32(s + c + nslots) + slot_iota, U32(w))
-    )
-    widx = widx0.at[row_tgt].set(
-        jnp.where(final_alive, idxs, SENTINEL), mode="drop"
-    )
-    wval = wval0.at[row_tgt.astype(jnp.int32)].set(final_val, mode="drop")
+        slot_iota = jnp.arange(b, dtype=U32)
+        row_tgt = jnp.where(
+            upd, pos0, jnp.where(ins, U32(s + c + nslots) + slot_iota, U32(w))
+        )
+        widx = widx0.at[row_tgt].set(
+            jnp.where(final_alive, idxs, SENTINEL), mode="drop"
+        )
+        wval = wval0.at[row_tgt.astype(jnp.int32)].set(final_val, mode="drop")
 
-    if recursive:
-        # the only consumer of leaf assignments in the fetch round is
-        # the recursive per-row leaf plane below (flat maps resolve
-        # leaves from the posmap at FLUSH time — no eviction happens
-        # here, so tracing a working_leaves gather would add a dead
-        # secret-indexed access for the analyzers to walk)
-        wleaf = jnp.concatenate(
-            [state.stash_leaf, state.ebuf_leaf, pleaf, jnp.zeros((b,), U32)]
-        ).at[row_tgt].set(new_leaves, mode="drop")
+        if recursive:
+            # the only consumer of leaf assignments in the fetch round is
+            # the recursive per-row leaf plane below (flat maps resolve
+            # leaves from the posmap at FLUSH time — no eviction happens
+            # here, so tracing a working_leaves gather would add a dead
+            # secret-indexed access for the analyzers to walk)
+            wleaf = jnp.concatenate(
+                [state.stash_leaf, state.ebuf_leaf, pleaf, jnp.zeros((b,), U32)]
+            ).at[row_tgt].set(new_leaves, mode="drop")
 
     # --- 3. recompact EVERYTHING into buffer ∪ stash (no eviction) -----
     # buffer-first: the buffer is where window contents are expected to
@@ -869,45 +896,46 @@ def _oram_fetch_round(
         # form (rangelint; the sticky counter's 2^16 budget absorbs it)
         dropped = jnp.maximum(n_live - (c + s), 0).astype(U32)
 
-    # --- 4. window bookkeeping; the tree/cache/nonces are UNTOUCHED ----
-    # the append row: rounds < W whenever a fetch round runs (the
-    # batcher flushes at W and resets the counter); the min states that
-    # schedule invariant, which the declared [0, W] state bound cannot
-    # carry by itself (runtime identity — without it the slice start
-    # could reach the plane's end and XLA would clamp the write)
-    ebuf_paths = jax.lax.dynamic_update_slice(
-        state.ebuf_paths, leaves,
-        ((jnp.minimum(state.ebuf_rounds, U32(cfg.evict_window - 1))
-          * U32(b)).astype(jnp.int32),),
-    )
-    # monotone generations make scatter-max exact for duplicate buckets
-    fetch_tag = state.fetch_tag.at[flat_b].max(state.ebuf_gen)
+    with device_phase("oram_evict"):
+        # --- 4. window bookkeeping; the tree/cache/nonces are UNTOUCHED ----
+        # the append row: rounds < W whenever a fetch round runs (the
+        # batcher flushes at W and resets the counter); the min states that
+        # schedule invariant, which the declared [0, W] state bound cannot
+        # carry by itself (runtime identity — without it the slice start
+        # could reach the plane's end and XLA would clamp the write)
+        ebuf_paths = jax.lax.dynamic_update_slice(
+            state.ebuf_paths, leaves,
+            ((jnp.minimum(state.ebuf_rounds, U32(cfg.evict_window - 1))
+              * U32(b)).astype(jnp.int32),),
+        )
+        # monotone generations make scatter-max exact for duplicate buckets
+        fetch_tag = state.fetch_tag.at[flat_b].max(state.ebuf_gen)
 
-    new_state = OramState(
-        tree_idx=state.tree_idx,
-        tree_val=state.tree_val,
-        cache_idx=state.cache_idx,
-        cache_val=state.cache_val,
-        cache_leaf=state.cache_leaf,
-        tree_leaf=state.tree_leaf,
-        stash_idx=stash_idx,
-        stash_val=stash_val,
-        stash_leaf=stash_leaf,
-        ebuf_idx=ebuf_idx,
-        ebuf_val=ebuf_val,
-        ebuf_leaf=ebuf_leaf,
-        ebuf_paths=ebuf_paths,
-        ebuf_rounds=state.ebuf_rounds + U32(1),
-        ebuf_gen=state.ebuf_gen,
-        fetch_tag=fetch_tag,
-        posmap=posmap,
-        overflow=state.overflow + dropped,
-        nonces=state.nonces,
-        cipher_key=state.cipher_key,
-        epoch=state.epoch,
-    )
-    if recursive:
-        leaves = jnp.stack([leaves, inner_leaves], axis=1)
+        new_state = OramState(
+            tree_idx=state.tree_idx,
+            tree_val=state.tree_val,
+            cache_idx=state.cache_idx,
+            cache_val=state.cache_val,
+            cache_leaf=state.cache_leaf,
+            tree_leaf=state.tree_leaf,
+            stash_idx=stash_idx,
+            stash_val=stash_val,
+            stash_leaf=stash_leaf,
+            ebuf_idx=ebuf_idx,
+            ebuf_val=ebuf_val,
+            ebuf_leaf=ebuf_leaf,
+            ebuf_paths=ebuf_paths,
+            ebuf_rounds=state.ebuf_rounds + U32(1),
+            ebuf_gen=state.ebuf_gen,
+            fetch_tag=fetch_tag,
+            posmap=posmap,
+            overflow=state.overflow + dropped,
+            nonces=state.nonces,
+            cipher_key=state.cipher_key,
+            epoch=state.epoch,
+        )
+        if recursive:
+            leaves = jnp.stack([leaves, inner_leaves], axis=1)
     return new_state, outs, leaves
 
 
@@ -1108,9 +1136,10 @@ def oram_flush(
                 interpret=not _on_tpu(),
             )
         else:
-            enc_pidx, enc_pval = cipher_rows(
-                cfg, state.cipher_key, tgt_b, epochs_w, pidx2, pval2
-            )
+            with device_phase("cipher_encrypt"):
+                enc_pidx, enc_pval = cipher_rows(
+                    cfg, state.cipher_key, tgt_b, epochs_w, pidx2, pval2
+                )
             tree_idx_new = _path_scatter(
                 state.tree_idx.reshape(-1, z), tgt_b, enc_pidx, axis_name,
                 tree_tgt,
@@ -1129,13 +1158,14 @@ def oram_flush(
             # cache planes are indexed by heap id directly (a heap
             # prefix), so the clamped tgt_b slots address them; only
             # cached targets land, the rest drop out of bounds
-            cache_idx_new = _path_scatter(
-                state.cache_idx, cache_tgt_slots, new_pidx, None,
-                jnp.repeat(is_cached, z),
-            )
-            cache_val_new = _path_scatter(
-                state.cache_val, tgt_b, pval2, None, is_cached
-            )
+            with device_phase("cache_write"):
+                cache_idx_new = _path_scatter(
+                    state.cache_idx, cache_tgt_slots, new_pidx, None,
+                    jnp.repeat(is_cached, z),
+                )
+                cache_val_new = _path_scatter(
+                    state.cache_val, tgt_b, pval2, None, is_cached
+                )
         else:
             cache_idx_new = state.cache_idx
             cache_val_new = state.cache_val
@@ -1144,41 +1174,44 @@ def oram_flush(
             from .path_oram import leaf_plane_cipher
 
             pleaf2 = new_pleaf.reshape(t, z)
-            enc_pleaf = leaf_plane_cipher(
-                cfg, state.cipher_key, tgt_b, epochs_w, pleaf2
-            )
+            with device_phase("cipher_encrypt"):
+                enc_pleaf = leaf_plane_cipher(
+                    cfg, state.cipher_key, tgt_b, epochs_w, pleaf2
+                )
             tree_leaf_new = _path_scatter(
                 state.tree_leaf.reshape(-1, z), tgt_b, enc_pleaf, axis_name,
                 tree_tgt,
             ).reshape(-1)
             if kc:
-                cache_leaf_new = _path_scatter(
-                    state.cache_leaf, cache_tgt_slots, new_pleaf, None,
-                    jnp.repeat(is_cached, z),
-                )
+                with device_phase("cache_write"):
+                    cache_leaf_new = _path_scatter(
+                        state.cache_leaf, cache_tgt_slots, new_pleaf, None,
+                        jnp.repeat(is_cached, z),
+                    )
         else:
             tree_leaf_new = state.tree_leaf
 
-    return OramState(
-        tree_idx=tree_idx_new,
-        tree_val=tree_val_new,
-        cache_idx=cache_idx_new,
-        cache_val=cache_val_new,
-        cache_leaf=cache_leaf_new,
-        tree_leaf=tree_leaf_new,
-        stash_idx=stash_idx,
-        stash_val=stash_val,
-        stash_leaf=stash_leaf,
-        ebuf_idx=jnp.full((c,), SENTINEL, U32),
-        ebuf_val=jnp.zeros((c, v), U32),
-        ebuf_leaf=jnp.zeros_like(state.ebuf_leaf),
-        ebuf_paths=state.ebuf_paths,  # inactive at rounds=0; public
-        ebuf_rounds=jnp.zeros((), U32),
-        ebuf_gen=state.ebuf_gen + U32(1),
-        fetch_tag=state.fetch_tag,  # generation bump re-validates all
-        posmap=posmap,
-        overflow=state.overflow + stash_dropped,
-        nonces=nonces,
-        cipher_key=state.cipher_key,
-        epoch=epoch_next(state.epoch),
-    )
+    with device_phase("oram_flush"):
+        return OramState(
+            tree_idx=tree_idx_new,
+            tree_val=tree_val_new,
+            cache_idx=cache_idx_new,
+            cache_val=cache_val_new,
+            cache_leaf=cache_leaf_new,
+            tree_leaf=tree_leaf_new,
+            stash_idx=stash_idx,
+            stash_val=stash_val,
+            stash_leaf=stash_leaf,
+            ebuf_idx=jnp.full((c,), SENTINEL, U32),
+            ebuf_val=jnp.zeros((c, v), U32),
+            ebuf_leaf=jnp.zeros_like(state.ebuf_leaf),
+            ebuf_paths=state.ebuf_paths,  # inactive at rounds=0; public
+            ebuf_rounds=jnp.zeros((), U32),
+            ebuf_gen=state.ebuf_gen + U32(1),
+            fetch_tag=state.fetch_tag,  # generation bump re-validates all
+            posmap=posmap,
+            overflow=state.overflow + stash_dropped,
+            nonces=nonces,
+            cipher_key=state.cipher_key,
+            epoch=epoch_next(state.epoch),
+        )

@@ -31,11 +31,28 @@ from collections import deque
 from concurrent.futures import Future
 
 from ..engine.batcher import GrapevineEngine
+from ..obs.phases import trace_span
 from ..session import schnorrkel
 from ..wire.records import QueryRequest, QueryResponse
 
 #: (pub, context, message, signature) as taken by the scheme's verify
 AuthItem = tuple[bytes, bytes, bytes, bytes]
+
+
+def round_counts(enqueued: list[float], taken: int, t_dispatch: float,
+                 rounds_ahead: int) -> dict:
+    """The per-round counts the scheduler adds to a round's ledger
+    (obs/tracer.py ROUND_COUNTS): ``enqueued`` are the perf_counter
+    enqueue stamps of the ops admitted to the round, ``taken`` how many
+    ops the window took off the queue (the rest failed verification),
+    ``rounds_ahead`` how many rounds were dispatched and unresolved at
+    this dispatch."""
+    return {
+        "ops": len(enqueued),
+        "rejected": taken - len(enqueued),
+        "queue_wait_sum_s": sum(max(0.0, t_dispatch - t) for t in enqueued),
+        "rounds_ahead": rounds_ahead,
+    }
 
 
 class AuthFailure(Exception):
@@ -123,10 +140,12 @@ class BatchScheduler:
         ] = []
         self._inflight: list[Future] = []
         self._last_enqueue = 0.0
-        #: monotonic enqueue time of the current queue head — the age of
-        #: the oldest waiting op is the healthz stall signal (obs/httpd)
+        #: perf_counter enqueue time of the current queue head — the age
+        #: of the oldest waiting op is the healthz stall signal
+        #: (obs/httpd). Every stamp the scheduler takes is perf_counter:
+        #: one clock for its deadlines, the round ledger and the SLO
         self._head_enqueue = 0.0
-        #: monotonic dispatch time of the round currently in flight on
+        #: perf_counter dispatch time of the round currently in flight on
         #: the device, None when none is. A wedge inside resolve() (the
         #: device never returning) empties the queue but freezes this —
         #: stall_age() must see it, or healthz serves 200 while every
@@ -171,16 +190,16 @@ class BatchScheduler:
         op's QueryResponse, or raises AuthFailure / SchedulerShutdown /
         the round's error exactly as ``submit`` would."""
         fut: Future = Future()
-        # perf_counter enqueue stamp: the SLO's enqueue→settle anchor
-        # (one clock domain with the batcher's round spans); the
-        # scheduler's own deadline math stays on time.monotonic
+        # perf_counter enqueue stamp: the SLO's enqueue→settle anchor,
+        # the ledger's queue wait and the window's idle-gap deadline
+        # (one clock domain with the batcher's round spans)
         t_enq = time.perf_counter()
         with self._cv:
             if self._closed:
                 raise SchedulerShutdown("scheduler closed")
             self._queue.append((req, auth, fut, t_enq))
             depth = len(self._queue)
-            self._last_enqueue = time.monotonic()
+            self._last_enqueue = t_enq
             if depth == 1:
                 self._head_enqueue = self._last_enqueue
             if self.metrics is not None:
@@ -207,7 +226,7 @@ class BatchScheduler:
         stall age means the engine thread has wedged — whether the ops
         are still queued or already on the device (the healthz
         trip-wire)."""
-        now = time.monotonic()
+        now = time.perf_counter()
         with self._cv:
             q_age = now - self._head_enqueue if self._queue else 0.0
         t = self._inflight_since  # benign unlocked float read
@@ -269,7 +288,7 @@ class BatchScheduler:
         bs = self.engine.ecfg.batch_size
         depth = self.pipeline_depth
         #: the bounded in-flight ledger: (PendingRound, live futures,
-        #: monotonic dispatch time) in dispatch order. After a dispatch
+        #: perf_counter dispatch time) in dispatch order. After a dispatch
         #: the collector settles the ledger down to ``depth`` rounds, so
         #: at depth 2 round k+2's collection window, verification, and
         #: journal fsync all run while rounds k and k+1 are still on the
@@ -340,26 +359,28 @@ class BatchScheduler:
                     # still commits after the idle gap. The wait runs
                     # while the device executes the previous round (see
                     # below), so it costs no device idle time under load.
-                    t_asm0 = time.monotonic()
-                    t_asm0_pc = time.perf_counter()  # tracer clock
+                    t_asm0 = time.perf_counter()
                     deadline = t_asm0 + w_wait
                     hit_cap = False
-                    while len(self._queue) < w_target and not self._closed:
-                        now = time.monotonic()
-                        wait_until = min(
-                            deadline, self._last_enqueue + w_gap
-                        )
-                        if now >= wait_until:
-                            hit_cap = now >= deadline
-                            break
-                        self._cv.wait(timeout=wait_until - now)
+                    with trace_span("assembly"):
+                        while (len(self._queue) < w_target
+                               and not self._closed):
+                            now = time.perf_counter()
+                            wait_until = min(
+                                deadline, self._last_enqueue + w_gap
+                            )
+                            if now >= wait_until:
+                                hit_cap = now >= deadline
+                                break
+                            self._cv.wait(timeout=wait_until - now)
                     chunk, self._queue = self._queue[:bs], self._queue[bs:]
                     backlog = len(self._queue)
-                    asm_s = time.monotonic() - t_asm0
+                    t_asm1 = time.perf_counter()
+                    asm_s = t_asm1 - t_asm0
                     if self._queue:
                         # remaining head has been waiting since roughly
                         # now (it arrived during this window)
-                        self._head_enqueue = time.monotonic()
+                        self._head_enqueue = t_asm1
                     if self.metrics is not None:
                         self.metrics.observe_queue_depth(len(self._queue))
                         self.metrics.observe_phase("assembly", asm_s)
@@ -377,32 +398,31 @@ class BatchScheduler:
             ] + [f for _, _, f, _ in chunk]
             pending, live = (None, [])
             if chunk:
-                t_v0 = time.monotonic()
-                t_v0_pc = time.perf_counter()
+                t_v0 = time.perf_counter()
                 if self.metrics is not None:
                     with self.metrics.time_phase("verify"):
                         live = self._verify_chunk(chunk)
                 else:
                     live = self._verify_chunk(chunk)
-                ver_s = time.monotonic() - t_v0
+                ver_s = time.perf_counter() - t_v0
                 if live:
                     reqs = [r for r, _ in live]
                     try:
                         # async dispatch: the device starts this round
                         # while we resolve the previous one and collect
                         # the next — PERF.md's dispatch/compute overlap
+                        t_disp = time.perf_counter()
                         pending = self.engine.handle_queries_async(
                             reqs, self.clock()
                         )
-                        t_disp = time.monotonic()
                         # collector-side spans + the oldest op's enqueue
                         # stamp ride the round handle itself, so the
                         # tracer/SLO pair them with THIS round even
                         # while the pipeline overlaps the next window
                         # (getattr: test fakes return bare objects)
                         if getattr(pending, "note_span", None) is not None:
-                            pending.note_span("assembly", t_asm0_pc, asm_s)
-                            pending.note_span("verify", t_v0_pc, ver_s)
+                            pending.note_span("assembly", t_asm0, asm_s)
+                            pending.note_span("verify", t_v0, ver_s)
                             # post-dispatch backlog: the queue-depth
                             # sample obs/workload.py histograms at
                             # round cadence (and flightrec records)
@@ -414,9 +434,17 @@ class BatchScheduler:
                             # signatures are their cheapest input) a
                             # lever on the SLO burn rate
                             enq_by_fut = {f: t for _, _, f, t in chunk}
-                            pending.set_enqueued_at(
-                                min(enq_by_fut[f] for _, f in live)
-                            )
+                            enqs = [enq_by_fut[f] for _, f in live]
+                            oldest = min(enqs)
+                            pending.set_enqueued_at(oldest)
+                            # the ledger's queue wait: the oldest
+                            # admitted op's as a span, every admitted
+                            # op's as one sum — a round's aggregate,
+                            # never one op's wait (obs/tracer.py)
+                            pending.note_span(
+                                "queue", oldest, max(0.0, t_disp - oldest))
+                            pending.note_counts(**round_counts(
+                                enqs, len(chunk), t_disp, len(ledger)))
                     except Exception as exc:  # pragma: no cover - defensive
                         for _, fut in live:
                             if not fut.done():
@@ -491,11 +519,23 @@ class BatchScheduler:
         ]
 
     def _settle(self, pending, live):
-        """Resolve a dispatched round and deliver its responses."""
+        """Resolve a dispatched round and deliver its responses. The
+        ``settle`` span covers the ``set_result`` fan-out (each call
+        wakes one waiting handler thread); it ends after ``resolve()``
+        recorded the round's ledger, so it is added to that ledger
+        afterwards (PendingRound.note_settle)."""
         try:
             resps = pending.resolve()
-            for (_, fut), resp in zip(live, resps):
-                fut.set_result(resp)
+            # the round's settle stamp, taken once and handed to every
+            # handler on its future: what a handler waits after it is
+            # the wake-up, not the round (server/service.py)
+            t_s0 = time.perf_counter()
+            with trace_span("settle"):
+                for (_, fut), resp in zip(live, resps):
+                    fut.settled_at = t_s0
+                    fut.set_result(resp)
+            if getattr(pending, "note_settle", None) is not None:
+                pending.note_settle(t_s0, time.perf_counter() - t_s0)
         except Exception as exc:  # pragma: no cover - defensive
             for _, fut in live:
                 if not fut.done():

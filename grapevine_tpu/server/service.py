@@ -48,6 +48,9 @@ from .uri import SERVICE_NAME  # noqa: E402  (re-export, see uri.py)
 #: bytes appended to the challenge seed inside the Auth ciphertext: the
 #: server-assigned session token the client must present as channel_id.
 SESSION_TOKEN_SIZE = 16
+#: the stages of one Query on the handler thread (the ``phase`` values
+#: of grapevine_service_seconds_total)
+SERVICE_STAGES = ("open", "wait", "wake", "seal")
 
 
 def run_expiry_loop(engine, config, stop_event, clock, health=None):
@@ -174,6 +177,23 @@ class GrapevineServer:
             self.metrics_registry = TelemetryRegistry()
         self._g_sessions = self.metrics_registry.gauge(
             "grapevine_sessions", "live authenticated sessions"
+        )
+        #: where a Query's time goes on this side of the scheduler, as
+        #: sums over every Query served (never a per-op series): four
+        #: perf_counter stamps per op feed one counter per stage
+        self._c_service_s = self.metrics_registry.counter(
+            "grapevine_service_seconds_total",
+            "handler-thread seconds summed over all Queries, by stage: "
+            "open = envelope decode + AEAD open + challenge + unpack + "
+            "validate; wait = submit -> the round's settle stamp; wake "
+            "= settle stamp -> the handler thread running again; seal = "
+            "pack + AEAD seal + envelope encode",
+            labels={"phase": SERVICE_STAGES},
+        )
+        self._c_service_n = self.metrics_registry.counter(
+            "grapevine_service_queries_total",
+            "Queries answered through the scheduler (the divisor of "
+            "grapevine_service_seconds_total)",
         )
         #: multiprocess verify/codec pipeline (server/hostpipe.py):
         #: 0 = the historical in-process path, N = a pool of N worker
@@ -337,7 +357,40 @@ class GrapevineServer:
                 len(dead), worker_index,
             )
 
+    def _submit_timed(self, req, challenge, t_in: float | None):
+        """``scheduler.submit`` with the service-stage stamps around it:
+        returns ``(response, t_awake)``. ``t_in`` is when the handler
+        took the request (None on the hostpipe path, whose open and
+        seal run in a worker process and are not timed here). The
+        scheduler stamps the round's settle time once, before its
+        ``set_result`` loop, and leaves it on the future."""
+        auth = (
+            req.auth_identity,
+            C.GRAPEVINE_CHALLENGE_SIGNING_CONTEXT,
+            challenge,
+            req.auth_signature,
+        )
+        t_submit = time.perf_counter()
+        nowait = getattr(self.scheduler, "submit_nowait", None)
+        if nowait is None:
+            # the frontend role's remote scheduler: one blocking call to
+            # the engine tier, which stamps nothing here
+            resp, t_settled = self.scheduler.submit(req, auth=auth), None
+        else:
+            fut = nowait(req, auth)
+            resp = fut.result()
+            t_settled = getattr(fut, "settled_at", None)
+        t_awake = time.perf_counter()
+        if t_settled is None:
+            t_settled = t_awake
+        if t_in is not None:
+            self._c_service_s.inc(t_submit - t_in, phase="open")
+        self._c_service_s.inc(t_settled - t_submit, phase="wait")
+        self._c_service_s.inc(t_awake - t_settled, phase="wake")
+        return resp, t_awake
+
     def _query(self, request_bytes: bytes, context: grpc.ServicerContext) -> bytes:
+        t_in = time.perf_counter()
         try:
             envelope = pw.decode_envelope(request_bytes)
         except ValueError as exc:
@@ -386,15 +439,7 @@ class GrapevineServer:
             # signature checked inside the round's batch verification
             # (scheduler.py: one multi-scalar multiplication per round)
             try:
-                resp = self.scheduler.submit(
-                    req,
-                    auth=(
-                        req.auth_identity,
-                        C.GRAPEVINE_CHALLENGE_SIGNING_CONTEXT,
-                        challenge,
-                        req.auth_signature,
-                    ),
-                )
+                resp, t_awake = self._submit_timed(req, challenge, t_in)
             except AuthFailure:
                 context.abort(grpc.StatusCode.UNAUTHENTICATED, "bad challenge signature")
             except SchedulerShutdown as exc:
@@ -403,7 +448,10 @@ class GrapevineServer:
                 # against a serving replica
                 context.abort(grpc.StatusCode.UNAVAILABLE, str(exc))
             ciphertext = session.channel.encrypt(resp.pack())
-        return pw.encode_envelope(pw.EnvelopeMessage(data=ciphertext))
+        out = pw.encode_envelope(pw.EnvelopeMessage(data=ciphertext))
+        self._c_service_s.inc(time.perf_counter() - t_awake, phase="seal")
+        self._c_service_n.inc()
+        return out
 
     def _query_hostpipe(self, envelope, session, now, context) -> bytes:
         """The multiprocess Query path: AEAD open, challenge draw,
@@ -451,15 +499,7 @@ class GrapevineServer:
                 )
             session.last_used = now
             try:
-                resp = self.scheduler.submit(
-                    req,
-                    auth=(
-                        req.auth_identity,
-                        C.GRAPEVINE_CHALLENGE_SIGNING_CONTEXT,
-                        challenge,
-                        req.auth_signature,
-                    ),
-                )
+                resp, _ = self._submit_timed(req, challenge, None)
             except AuthFailure:
                 context.abort(
                     grpc.StatusCode.UNAUTHENTICATED, "bad challenge signature"
@@ -476,6 +516,7 @@ class GrapevineServer:
                     grpc.StatusCode.UNAVAILABLE,
                     "host worker lost; re-authenticate",
                 )
+        self._c_service_n.inc()
         return pw.encode_envelope(pw.EnvelopeMessage(data=ciphertext))
 
     # -- lifecycle ------------------------------------------------------
@@ -608,15 +649,6 @@ class GrapevineServer:
         wires ``--metrics-port`` here."""
         from ..obs import MetricsServer
 
-        if self.engine is not None:
-            try:  # populate the "sort" phase split before the first scrape
-                self.engine.calibrate_sort_phase()
-            except Exception:  # best-effort: metrics must still bind
-                pass
-            try:  # and the "posmap" position-resolution split (PR 7)
-                self.engine.calibrate_posmap_phase()
-            except Exception:
-                pass
         lm = self.leakmon
         self._metrics_server = MetricsServer(
             self.metrics_registry,

@@ -279,14 +279,6 @@ class EngineServer:
         session-layer registry."""
         from ..obs import MetricsServer
 
-        try:  # populate the "sort" phase split before the first scrape
-            self.engine.calibrate_sort_phase()
-        except Exception:  # best-effort: metrics must still bind
-            pass
-        try:  # and the "posmap" position-resolution split (PR 7)
-            self.engine.calibrate_posmap_phase()
-        except Exception:
-            pass
         lm = self.leakmon
         self._metrics_server = MetricsServer(
             self.engine.metrics.registry,

@@ -199,26 +199,3 @@ def test_radix_engine_round_sheds_bounded_sorts():
         f"radix engine round traces {n_radix} sort ops — more than the "
         f"gated wide-key residue; a bounded-key sort escaped the knob"
     )
-
-
-# ----------------------------------------------------------------------
-# obs: the sort phase calibration registers cleanly
-# ----------------------------------------------------------------------
-
-
-def test_sort_phase_calibration_registers():
-    eng = GrapevineEngine(
-        GrapevineConfig(
-            max_messages=64, max_recipients=8, mailbox_cap=4,
-            batch_size=4, bucket_cipher_rounds=0, vphases_impl="scan",
-            sort_impl="radix",
-        )
-    )
-    dt = eng.calibrate_sort_phase(reps=2)
-    assert dt > 0
-    snap = eng.metrics.registry.snapshot()
-    key = "grapevine_phase_seconds{phase=sort}_count"
-    assert snap.get(key, 0) >= 1, sorted(
-        k for k in snap if "phase" in k
-    )[:10]
-    eng.metrics.registry.audit()  # leak policy still holds

@@ -90,9 +90,15 @@ def test_chrome_trace_is_valid_and_loadable_shape():
         if e["ph"] == "X":  # complete events: the Perfetto essentials
             assert {"ts", "dur", "tid", "cat"} <= set(e)
             assert isinstance(e["ts"], int) and e["dur"] >= 0
-    # the device window rides its own thread track (seq 1 = lane 1)
+    # the device windows and the queue wait ride their own thread
+    # tracks (seq 1 = lane 1)
     tids = {e["tid"] for e in parsed["traceEvents"] if e["ph"] == "X"}
-    assert tids == {2, 4}
+    assert tids == {2, 4, 6}
+    by_name = {e["name"]: e["tid"] for e in parsed["traceEvents"]
+               if e["ph"] == "X"}
+    assert by_name["grapevine/device"] == by_name["grapevine/inflight"] == 4
+    assert by_name["grapevine/queue"] == 6
+    assert by_name["grapevine/settle"] == by_name["grapevine/round"] == 2
 
 
 def test_chrome_trace_lanes_keep_pipelined_rounds_disjoint():
@@ -143,7 +149,9 @@ def test_span_schema_has_teeth():
 def test_allowed_span_names_stay_inside_phase_vocabulary():
     from grapevine_tpu.obs.phases import PHASES
 
-    assert ALLOWED_SPAN_NAMES <= set(PHASES) | {"device", "round"}
+    from grapevine_tpu.obs.tracer import DERIVED_SPANS
+
+    assert ALLOWED_SPAN_NAMES <= set(PHASES) | set(DERIVED_SPANS) | {"settle"}
 
 
 def test_tracer_gauges_export():
@@ -327,15 +335,20 @@ def test_trace_endpoint_serves_chrome_trace_json(tier):
 
 def test_trace_spans_carry_no_per_op_fields(tier):
     """Leak check: every span name is a phase, args carry only the
-    round seq — nowhere for an op type, client id, or per-op timestamp
-    to travel."""
+    round seq and, on the round's own event, the allowlisted whole-round
+    counts — nowhere for an op type, client id, or per-op timestamp to
+    travel."""
+    from grapevine_tpu.obs.tracer import ROUND_COUNTS
+
     srv, port = tier
     _, body = _get(f"http://127.0.0.1:{port}/trace")
     for e in json.loads(body)["traceEvents"]:
         if e.get("cat") != "round":
             continue
         assert e["name"].removeprefix("grapevine/") in ALLOWED_SPAN_NAMES
-        assert set(e.get("args", {})) <= {"seq"}
+        extra = set(ROUND_COUNTS) if e["name"] == "grapevine/round" else set()
+        assert set(e.get("args", {})) <= {"seq"} | extra
+        assert all(isinstance(v, (int, float)) for v in e["args"].values())
         assert isinstance(e["ts"], int) and isinstance(e["dur"], int)
 
 
@@ -380,6 +393,28 @@ def test_slo_burn_rate_flips_healthz(tier):
         srv.slo = real
     status, _ = _get(f"http://127.0.0.1:{port}/healthz")
     assert status == 200
+
+
+def test_profiler_gate_captures_with_the_python_tracer_off(monkeypatch,
+                                                            tmp_path):
+    """A capture of the served bus holds the program's spans and the
+    device's ops, not every Python call (1.5 M events in 4 s slow the
+    rounds being captured): the gate starts its trace as the benchmark
+    does."""
+    import jax.profiler
+
+    from grapevine_tpu.obs.profiler import ProfilerGate
+
+    calls = []
+    monkeypatch.setattr(
+        jax.profiler, "start_trace",
+        lambda d, **kw: calls.append(("start", d, kw)))
+    monkeypatch.setattr(
+        jax.profiler, "stop_trace", lambda: calls.append(("stop",)))
+    out = ProfilerGate(outdir=str(tmp_path)).capture(ms=1)
+    (_, trace_dir, kw), stop = calls
+    assert stop == ("stop",) and trace_dir == out["trace_dir"]
+    assert kw["profiler_options"].python_tracer_level == 0
 
 
 @pytest.mark.slow  # ~67 s: the first capture pays jax.profiler's lazy

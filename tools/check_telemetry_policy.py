@@ -210,19 +210,24 @@ def audit_trace_slo_registry() -> dict:
                 "scalars with no dimensions by design"
             )
 
-    stray = ALLOWED_SPAN_NAMES - set(PHASES) - {"device", "round"}
+    # the derived windows and the scheduler's settle fan-out are whole-
+    # round spans too (obs/tracer.py DERIVED_SPANS)
+    stray = (ALLOWED_SPAN_NAMES - set(PHASES)
+             - {"device", "inflight", "queue", "round", "settle"})
     if stray:
         raise SystemExit(
             f"tracer span allowlist drifted outside the phase "
             f"vocabulary: {sorted(stray)}"
         )
-    for bad_ledger, why in (
-        ({"op_read": (0.0, 1.0)}, "per-op span name"),
-        ({"evict": "not-a-span"}, "non-(start,dur) span value"),
-        ({"evict": (0.0, -1.0)}, "negative duration"),
+    for bad_ledger, bad_counts, why in (
+        ({"op_read": (0.0, 1.0)}, None, "per-op span name"),
+        ({"evict": "not-a-span"}, None, "non-(start,dur) span value"),
+        ({"evict": (0.0, -1.0)}, None, "negative duration"),
+        ({"evict": (0.0, 1.0)}, {"reads": 3}, "per-op-type count"),
+        ({"evict": (0.0, 1.0)}, {"ops": "many"}, "non-numeric count"),
     ):
         try:
-            tracer.record_round(bad_ledger)
+            tracer.record_round(bad_ledger, bad_counts)
         except TelemetryLeakError:
             continue
         raise SystemExit(
