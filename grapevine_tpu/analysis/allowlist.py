@@ -27,7 +27,8 @@ rest IS public — which is why the cipher key is a taint anchor).
    movement over fixed [B]/[W] arrays — every row moves exactly once
    per pass; the permutation's value is secret, its shape is not.
 4. *fixed full sweeps*: iota-scheduled walks that touch every row
-   regardless of the data (the expiry sweep).
+   regardless of the data (the expiry sweep; the level-dense top of
+   ``oram_round``, whose heap range is a constant of the shapes).
 """
 
 from __future__ import annotations
@@ -41,33 +42,42 @@ _ORAM_CORE = (
     _A("gather", "oram/path_oram.py:_path_gather",
        "path fetch indexed by one-time leaves: each position is read "
        "once then remapped, so every fetched path is an independent "
-       "uniform draw (Path-ORAM invariant)"),
+       "uniform draw (Path-ORAM invariant); the level-dense top of "
+       "oram_round rides the same gather as a fixed sweep — the heap "
+       "range [2^k-1, 2^Ld-1) at constant indices, every round"),
     _A("scatter", "oram/path_oram.py:_path_scatter",
-       "write-back of exactly the fetched paths, owner-masked — the "
-       "write transcript is identical to the read transcript"),
+       "write-back of exactly the fetched rows — the fixed dense heap "
+       "range (all-true owner mask) and the fetched paths below it, "
+       "owner-masked — the write transcript is identical to the read "
+       "transcript"),
     _A("gather", "oram/path_oram.py:working_leaves",
        "leaf lookup in the flat position table, private working memory "
        "(one fixed [W]-shaped gather per round)"),
     _A("gather", "oram/round.py:oram_round",
-       "private working-set reads: block->row map, initial-value rows, "
-       "and cache-top planes — stash-standing memory on a fixed "
-       "per-round schedule"),
+       "private working-set reads: block->row map and initial-value "
+       "rows — stash-standing memory on a fixed per-round schedule "
+       "(the cache-top planes join the working set whole, no gather)"),
     _A("scatter", "oram/round.py:oram_round",
        "commits into private planes (working rows, eviction slots, "
-       "stash recompaction, cache-top write-back): fixed shapes, "
-       "unique in-bounds targets, owner-masked"),
+       "stash recompaction): fixed shapes, unique in-bounds targets; "
+       "the cache-top planes leave as whole slices of the eviction "
+       "output (an elementwise select keeps a bucket no path met)"),
     _A("scatter", "oram/round.py:_bucket_owner_map",
-       "owner election: one scatter-min over exactly B*path_len heap "
-       "slots per round into a private dense map, whatever the leaves"),
+       "owner election: one scatter-min over exactly the per-path "
+       "heap slots of the round — B*(path_len-Le) under the levels "
+       "the batch covers, B*path_len in the fetch-only round — into "
+       "a private map of fixed shape, whatever the leaves; covered "
+       "buckets are never looked up in it"),
     _A("gather", "oram/round.py:occurrence_masks_sorted",
        "sorted dedup: permutation/boundary gathers over fixed [B] "
        "arrays — oblivious-sort data movement, schedule fixed by B"),
     _A("gather", "oram/round.py:_assign_evictions",
        "eviction assignment: sort-permutation and bucket-map gathers "
        "over the fixed working set — oblivious permutation plumbing; "
-       "ONE body serves per-round eviction (owner columns over [W]) "
-       "and the delayed flush (public deduplicated targets over "
-       "[C+S])"),
+       "ONE body serves per-round eviction (bucket -> output row over "
+       "[W]: covered buckets their own id without a lookup, deeper "
+       "buckets their owner copy) and the delayed flush (public "
+       "deduplicated targets over [C+S])"),
     _A("scatter", "oram/round.py:_assign_evictions",
        "eviction assignment: inverse-permutation scatters over the "
        "fixed working set — every row written exactly once per pass "

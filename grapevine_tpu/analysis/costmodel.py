@@ -11,10 +11,12 @@ key-volume, scatter elements, and the flush-amortized steady-state round
 1. **Analytic** (:func:`oram_round_rows` / :func:`oram_flush_rows` /
    :func:`engine_round_rows` / :func:`expiry_sweep_rows`): a pure
    function of geometry × knobs (``vphases/sort/posmap/cache-k/
-   evict_every``), written from the round's documented schedule — fetch
-   moves ``B·(path_len−k)`` bucket rows per HBM plane, cache planes move
-   ``B·k``, the recursive leaf plane re-gathers the nonce plane, E=1
-   write-back mirrors the fetch, a flush scatters exactly
+   evict_every``), written from the round's documented schedule — the
+   E=1 fetch moves each of the top ``Ld`` levels once and
+   ``B·(path_len−Ld)`` per-path rows below them per HBM plane with the
+   cache planes passed whole, the E>1 fetch ``B·(path_len−k)`` rows and
+   ``B·k`` cache rows, the recursive leaf plane re-gathers the nonce
+   plane, E=1 write-back mirrors the fetch, a flush scatters exactly
    ``flush_target_slots`` rows with zero gathers, and the expiry sweep
    streams every tree plane through its chunked scan exactly once.
 2. **Traced** (:func:`traced_access_rows` / :func:`traced_scan_rows`):
@@ -128,6 +130,29 @@ def oram_planes(cfg, prefix: str = "") -> dict:
     return planes
 
 
+def _round_dense_levels(cfg, b: int) -> int:
+    """Top levels one E=1 ``oram_round`` of ``b`` paths moves whole:
+    every level L with 2^L <= b, never fewer than the cached levels nor
+    more than the tree has. Written from the documented schedule, not
+    read off ``OramConfig.dense_levels`` — the trace is the referee."""
+    ld = 0
+    while (1 << ld) <= b:
+        ld += 1
+    return min(max(ld, cfg.top_cache_levels), cfg.path_len)
+
+
+def _round_hbm_rows(cfg, b: int) -> int:
+    """Bucket rows one ``oram_round`` moves per HBM plane and
+    direction: per-path below the cache for the fetch-only E>1 round;
+    for the E=1 round the dense heap range below the cache plus the
+    per-path rows of the levels under it."""
+    k = cfg.top_cache_levels
+    if cfg.delayed_eviction:
+        return b * (cfg.path_len - k)
+    ld = _round_dense_levels(cfg, b)
+    return ((1 << ld) - (1 << k)) + b * (cfg.path_len - ld)
+
+
 def oram_round_rows(cfg, b: int, prefix: str = "") -> dict:
     """Predicted rows per plane for ONE ``oram_round(cfg, ·)`` with a
     batch of ``b`` indices — the E=1 fetch+write-back round, or the
@@ -135,15 +160,19 @@ def oram_round_rows(cfg, b: int, prefix: str = "") -> dict:
 
     The schedule being priced (oram/round.py):
 
-    - fetch gathers ``R = b·(path_len−k)`` bucket rows per bottom HBM
-      plane (idx, val, nonces; + the leaf plane under a recursive map,
-      which re-gathers the nonce plane for its own keystream — the
-      second nonce gather);
-    - the tree-top cache serves the top ``k`` levels: ``C = b·k`` rows
-      per cache plane;
+    - the E=1 round is level-dense above, per-path below: the top
+      ``Ld = clamp(floor(log2 b) + 1, k, path_len)`` levels are each
+      moved whole, once (`_round_hbm_rows`), so a bottom HBM plane
+      (idx, val, nonces; + the leaf plane under a recursive map, which
+      re-gathers the nonce plane for its own keystream — the second
+      nonce gather) gathers ``R = (2^Ld − 2^k) + b·(path_len − Ld)``
+      rows, and the cache planes join and leave the working set whole:
+      no gather or scatter op ever names them (``C = 0``);
     - E=1 write-back scatters the same row counts back (nonces only
       when the at-rest cipher is on — plaintext trees commit no epoch);
-    - E>1 rounds are HBM-read-only: zero tree/cache scatters
+    - the E>1 fetch-only round keeps the per-path layout: ``R =
+      b·(path_len−k)`` gathered rows per HBM plane, ``C = b·k`` per
+      cache plane, and it is HBM-read-only: zero tree/cache scatters
       (the check_evict_round_accounting claim);
     - a recursive position map resolves the batch through exactly one
       internal round of the same ``b`` (oram/posmap.py), composed here
@@ -155,8 +184,8 @@ def oram_round_rows(cfg, b: int, prefix: str = "") -> dict:
     cb = cfg.cache_buckets
     recursive = cfg.posmap is not None
     wb = 0 if cfg.delayed_eviction else 1  # write-back present?
-    R = b * (cfg.path_len - k)
-    C = b * k
+    R = _round_hbm_rows(cfg, b)
+    C = b * k if cfg.delayed_eviction else 0
 
     rows = {
         f"{prefix}tree_idx": PlaneRows((n, z), 1, z, R, wb * R),
@@ -260,8 +289,8 @@ def shard_local_rows(rows: dict, shards: int) -> dict:
     sharded plane's leading dim divides by the shard count (one
     contiguous heap range per chip), while replicated planes — cache,
     inner posmap trees — keep their full shape. Row COUNTS are
-    untouched: each chip's fetch gathers the full uniform
-    ``B·(path_len−k)`` masked rows from its local range, and each
+    untouched: each chip's fetch gathers the round's full uniform
+    row count, masked, from its local range, and each
     chip's flush dispatches the full uniform ``t``-row drop-mode
     scatter — the owner mask bounds which rows LAND, never the static
     per-chip op shape (the leak argument in oram/round.py)."""
@@ -849,8 +878,11 @@ def _round_sort_keys(cfg, b: int, sort_impl: str, occ_impl: str) -> int:
     plen = cfg.path_len
     keys = 0
     if not cfg.delayed_eviction:
-        w = cfg.stash_size + b * plen * z + b  # E=1 working set
-        keys += w
+        # E=1 working set: stash, every dense bucket once, the per-path
+        # copies below, b insert rows
+        ld = _round_dense_levels(cfg, b)
+        rows = ((1 << ld) - 1) + b * (plen - ld)
+        keys += cfg.stash_size + rows * z + b
     if occ_impl == "scan":
         keys += b  # occurrence group sort
     if cfg.posmap is not None:
@@ -883,7 +915,7 @@ def _round_cipher_rows(cfg, b: int) -> int:
     if not cfg.encrypted:
         inner = 0
     else:
-        R = b * (cfg.path_len - cfg.top_cache_levels)
+        R = _round_hbm_rows(cfg, b)
         streams = 2 if cfg.posmap is not None else 1  # idx/val + leaf
         passes = 1 if cfg.delayed_eviction else 2  # fetch (+ write-back)
         inner = R * streams * passes
@@ -1087,10 +1119,13 @@ def ab_verdict(kind: str, *, scope: str = "machinery",
             out["arms"][f"k{k}"] = {"modeled_bytes": int(nbytes)}
         out["winner"] = _pick(out["arms"], [f"k{k}" for k in ks])
         out["basis"] = (
-            "each cached level converts B HBM path rows/plane to "
-            "private rows both directions; bytes fall monotonically "
-            "in k, so the deepest arm wins unless the cut is inside "
-            "the tie band"
+            "a cached level leaves the HBM planes both directions: "
+            "2^L rows/plane where the round is level-dense (2^L <= B), "
+            "B rows/plane below that; bytes fall monotonically in k, "
+            "so the deepest arm wins unless the cut is inside the tie "
+            "band (a level the batch covers saves only its own 2^L "
+            "rows: a cut of 2-11 % at the banked machinery configs, "
+            "inside the band in the engine sweeps)"
         )
     elif kind == "evict":
         es = tuple(arms) if arms else (1, 2, 4, 8)
@@ -1106,10 +1141,12 @@ def ab_verdict(kind: str, *, scope: str = "machinery",
         out["winner"] = _pick(out["arms"], [f"e{e}" for e in es])
         out["basis"] = (
             "amortized flush rows = min(E·F·path_len, n_buckets)/E: "
-            "below saturation that equals the E=1 write-back exactly "
-            "(a byte-tie, so the window's dedup sort + buffer are pure "
-            "overhead and E=1 wins); past saturation the min clamps "
-            "and larger E strictly drops bytes"
+            "below saturation the E>1 arms tie (the window's dedup "
+            "sort + buffer are pure overhead), past it the min clamps "
+            "and larger E strictly drops bytes; the E=1 round moves "
+            "each level the batch covers once, both directions, so it "
+            "is under every E>1 arm's per-path fetch until E·F paths "
+            "saturate a tree far larger than the batch"
         )
     elif kind == "sharded_evict":
         es = tuple(arms) if arms else (1, 2, 4)
@@ -1198,12 +1235,34 @@ def _drop_nonce_regather(rows):
     return _scale_plane(rows, "nonces", g=0.5)
 
 
-@_cost_mutant("forget_cache_planes", "round_cached", "gather-undercount")
+@_cost_mutant("forget_cache_planes", "round_fetch_cached",
+              "gather-undercount")
 def _forget_cache(rows):
-    """A model that prices the cached top levels as free."""
+    """A model that prices the cached top levels of the per-path fetch
+    round (E>1) as free. (The E=1 round passes the cache planes whole:
+    no op names them, which `price_dense_levels_per_path` pins from the
+    other side.)"""
     rows = _scale_plane(rows, "cache_idx", g=0, s=0)
     rows = _scale_plane(rows, "cache_val", g=0, s=0)
     return _scale_plane(rows, "cache_leaf", g=0, s=0)
+
+
+@_cost_mutant("forget_dense_range", "round_cached", "gather-undercount")
+def _forget_dense_range(rows):
+    """A model that prices only the per-path rows of the E=1 round and
+    forgets the dense heap range read above them (at the audit
+    geometry b=8, height=5, k=2: 28 rows a plane, 12 of them dense)."""
+    return _scale_plane(rows, "tree_val", g=16 / 28)
+
+
+@_cost_mutant("price_dense_levels_per_path", "round_cached",
+              "gather-overcount")
+def _price_per_path(rows):
+    """The pre-dense model: every level below the cache priced once
+    per path, b·(path_len−k) = 32 rows where the round moves 28 — the
+    checker must see an over-count as loudly as an under-count, or a
+    round that silently fell back to per-path rows would pass."""
+    return _scale_plane(rows, "tree_val", g=32 / 28, s=32 / 28)
 
 
 @_cost_mutant("forget_writeback_half", "round", "scatter-undercount")
@@ -1367,7 +1426,7 @@ def _mutant_fixtures():
     flat, flat_b = by_name["flat_k0_e1"]
     cached, cached_b = by_name["flat_k2_e1"]
     recursive, rec_b = by_name["recursive_k2_e1"]
-    evict, _ = by_name["flat_k2_e2_fetch"]
+    evict, evict_b = by_name["flat_k2_e2_fetch"]
     _, sh_cfg, sh_n = audit_sharded_flush_configs()[0]
     return {
         "flush_sharded": (cross_validate_sharded_flush,
@@ -1377,6 +1436,8 @@ def _mutant_fixtures():
                          {"cfg": cached, "b": cached_b}),
         "round_recursive": (cross_validate_round,
                             {"cfg": recursive, "b": rec_b}),
+        "round_fetch_cached": (cross_validate_round,
+                               {"cfg": evict, "b": evict_b}),
         "flush": (cross_validate_flush, {"cfg": evict}),
         "engine": (cross_validate_engine_round,
                    {"ecfg": engines["engine_e1"]}),

@@ -428,6 +428,13 @@ class GrapevineEngine:
 
             self.pipeline_depth = 2 if on_tpu() else 1
         self.metrics = EngineMetrics()
+        layout = self.round_layout()
+        self.metrics.set_round_layout(layout)
+        _log.info(
+            "round layout (dense_levels, fetched_bucket_rows per "
+            "oram_round): %s",
+            ", ".join(f"{t}={v}" for t, v in layout.items()),
+        )
         #: last sampled per-tree eviction-buffer occupancy (health view)
         self._ebuf_counts: dict = {}
         #: streaming obliviousness auditor (obs/leakmon.py), attached by
@@ -912,6 +919,17 @@ class GrapevineEngine:
             # being violated before overflow ever fires
             self.metrics.observe_evict_buffer(sum(ebuf.values()))
         return counts
+
+    def round_layout(self) -> dict:
+        """``{tree: (dense_levels, fetched_bucket_rows)}`` of one
+        ``oram_round`` on each tree at this engine's geometry: the
+        records round makes B accesses, a mailbox round B·D."""
+        b, d = self.ecfg.batch_size, self.ecfg.mb_choices
+        return {
+            tree: (cfg.dense_levels(n), cfg.fetched_bucket_rows(n))
+            for tree, cfg, n in (("rec", self.ecfg.rec, b),
+                                 ("mb", self.ecfg.mb, b * d))
+        }
 
     def health(self) -> dict:
         """Aggregate state + batch-level counters (never per-client)."""

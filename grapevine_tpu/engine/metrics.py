@@ -107,6 +107,19 @@ class EngineMetrics:
             "max sampled delayed-eviction buffer occupancy (the "
             "near-overflow canary: approaching evict_buffer_slots "
             "means the window is undersized — OPERATIONS.md §19)")
+        # the round's layout engages by geometry, so these are static
+        # per engine: set once at construction (set_round_layout)
+        trees = {"tree": ("rec", "mb")}
+        self._g_dense = r.gauge(
+            "grapevine_round_dense_levels",
+            "top tree levels one oram_round moves whole, once, at "
+            "constant addresses (a function of batch, tree height and "
+            "tree-top cache levels — oram/round.py)", labels=trees)
+        self._g_rows = r.gauge(
+            "grapevine_round_fetched_bucket_rows",
+            "HBM bucket rows one oram_round gathers and decrypts (and "
+            "writes back when it evicts): the dense range under the "
+            "cache plus one row per path and deeper level", labels=trees)
         self._h_phase = r.histogram(
             "grapevine_phase_seconds",
             "wall time per round phase (batch-level; obs/phases.py)",
@@ -133,6 +146,13 @@ class EngineMetrics:
             self._c_underfull.inc()
         self._g_occupancy.set(n_real / batch_size if batch_size else 0.0)
         self._h_round.observe(seconds)
+
+    def set_round_layout(self, layout: dict) -> None:
+        """``{tree: (dense_levels, fetched_bucket_rows)}`` of one
+        ``oram_round`` per tree, from the resolved geometry."""
+        for tree, (dense, rows) in layout.items():
+            self._g_dense.set(dense, tree=tree)
+            self._g_rows.set(rows, tree=tree)
 
     def record_sweep(self, evicted: int) -> None:
         self._c_sweeps.inc()

@@ -358,6 +358,37 @@ class OramConfig:
     def dummy_index(self) -> int:
         return self.blocks
 
+    def covered_levels(self, accesses: int) -> int:
+        """How many top levels one ``oram_round`` of ``accesses`` paths
+        covers at least once over: a level of 2^L buckets with
+        2^L <= accesses. Every bucket of such a level is an eviction
+        target every round (oram/round.py). A function of the batch and
+        the height alone — NOT of ``top_cache_levels``, so the cache
+        never changes where a block is placed."""
+        return min(accesses.bit_length(), self.path_len)
+
+    def dense_levels(self, accesses: int) -> int:
+        """``Ld``: how many top levels one ``oram_round`` of ``accesses``
+        paths holds level-dense (oram/round.py): read and written back
+        whole, once, as a fixed heap range, where deeper levels stay
+        per-path. The covered levels, and never fewer than the tree-top
+        cache (its planes are the first dense levels). The
+        delayed-eviction fetch round stays per-path: only its cache
+        levels are dense."""
+        if self.delayed_eviction:
+            return self.top_cache_levels
+        return max(self.covered_levels(accesses), self.top_cache_levels)
+
+    def fetched_bucket_rows(self, accesses: int) -> int:
+        """HBM bucket rows one ``oram_round`` of ``accesses`` paths
+        gathers and decrypts (and, evicting every round, encrypts and
+        scatters back): the dense heap range below the cache,
+        ``[2^k − 1, 2^Ld − 1)``, plus the per-path rows of the deeper
+        levels. A function of shapes only."""
+        ld = self.dense_levels(accesses)
+        return ((1 << ld) - (1 << self.top_cache_levels)
+                + accesses * (self.path_len - ld))
+
 
 class OramState(NamedTuple):
     """ORAM state; a pytree (NamedTuple) so it jits/shards cleanly.

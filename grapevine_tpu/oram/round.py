@@ -12,10 +12,14 @@ PAPERS.md and SURVEY.md §7 "hard parts" 6):
    This is also a security requirement, not just an optimization: if two
    ops on one key both fetched ``posmap[idx]`` the transcript would show
    two identical leaves, correlating ops on the same key. With dedup every
-   transcript entry is an independent uniform leaf. All B paths are then
-   fetched in one gather; buckets shared by several paths (always true
-   near the root) are attributed to a single *owner* path slot and
-   invalidated elsewhere, so each live block enters the working set once.
+   transcript entry is an independent uniform leaf. The fetch is
+   *level-dense above, per-path below*: a level with no more buckets
+   than the round has accesses is read whole, once, as a fixed heap
+   range (the tree-top cache planes are the first of these levels);
+   under those, all B paths are fetched in one gather, and buckets
+   shared by several paths are attributed to a single *owner* path slot
+   and invalidated elsewhere, so each live block enters the working set
+   once (`oram_round` docstring: the layout and why it is safe).
 2. **Apply**: slot-order semantics (the documented within-batch commit
    order, SURVEY.md §7.6) are resolved by a fully **vectorized** batch
    callback — there is NO per-op `lax.scan` anywhere in the round. A
@@ -34,11 +38,12 @@ PAPERS.md and SURVEY.md §7 "hard parts" 6):
    (value, alive) of each key is scattered back to its working-set row —
    net inserts go to B reserved rows — and eviction proceeds.
 3. **Evict**: one level-synchronous greedy pass assigns every working-set
-   entry to the deepest fetched bucket on its own path, jointly across
-   all B paths (an entry's path meets each level in exactly one bucket,
-   so levels vectorize with no conflicts). Leftovers recompact into the
-   stash; one scatter writes all owned buckets back (write transcript ≡
-   read transcript).
+   entry to the deepest fetched bucket on its own path — every bucket of
+   a level the batch covers, the buckets the B paths meet below — jointly (an
+   entry's path meets each level in exactly one bucket, so levels
+   vectorize with no conflicts). Leftovers recompact into the stash; one
+   scatter writes the dense range and all owned buckets back (write
+   transcript ≡ read transcript).
 
 Net effect per round: 2 large HBM transfers (gather + scatter) per tree
 array instead of 2·B small dependent ones, with all decision logic in
@@ -147,55 +152,59 @@ def occurrence_masks_sorted(idxs: jax.Array, dummy_index: int,
     return first_occ, last_occ, chain_slot
 
 
-def _bucket_owner_map(cfg: OramConfig, flat_b: jax.Array) -> jax.Array:
-    """Dense heap-bucket → owner-column map for this round's fetch.
+def _bucket_owner_map(
+    cfg: OramConfig,
+    flat_b: jax.Array,  # u32[R] heap ids of the per-path fetched buckets
+    rows: jax.Array,  # u32[R] each copy's output row (ascending by column)
+    n_rows: int,  # output rows; doubles as the "not fetched" sentinel
+) -> jax.Array:
+    """Heap-bucket → output-row map for this round's fetch.
 
-    Buckets shared by several fetched paths (always true near the root)
-    must contribute their blocks to the working set exactly once and be
-    written back exactly once; the owner is the lowest batch column
-    touching the bucket. One scatter-min over the (unique) heap bucket
+    Buckets shared by several fetched paths must contribute their blocks
+    to the working set exactly once and be written back exactly once;
+    the owner is the lowest batch column touching the bucket, i.e. the
+    lowest output row among its copies (a bucket sits at one level, and
+    rows ascend with the column). One scatter-min over the heap bucket
     ids replaces the O((B·plen)²) all-pairs mask this supersedes, and
-    doubles as the eviction-eligibility oracle: ``map[hb] != B`` iff
-    bucket ``hb`` was fetched this round. (searchsorted/sorted-neighbor
-    alternatives lower to serial scalar loops on TPU — measured at
-    ~0.17 ms per call — while scatter/gather stay vectorized.)
+    doubles as the eviction-eligibility oracle: ``map[hb] != n_rows``
+    iff a fetched path meets bucket ``hb`` this round.
+    (searchsorted/sorted-neighbor alternatives lower to serial scalar
+    loops on TPU — measured at ~0.17 ms per call — while scatter/gather
+    stay vectorized.)
     """
-    b_plen = flat_b.shape[0]
-    plen = cfg.path_len
-    b = b_plen // plen
-    cols = jnp.repeat(jnp.arange(b, dtype=U32), plen)
-    return jnp.full((cfg.n_buckets_padded,), U32(b)).at[flat_b].min(cols)
+    return jnp.full((cfg.n_buckets_padded,), U32(n_rows)).at[flat_b].min(rows)
 
 
 def _assign_evictions(
     cfg: OramConfig,
     valid: jax.Array,  # bool[W] live working-set rows
     wleaf: jax.Array,  # u32[W] leaf assignment per row
-    bucket_map: jax.Array,  # u32[n_buckets_padded] heap bucket -> target
-    n_targets: int,  # target-space size; doubles as the "not fetched" sentinel
-    nslots: int,  # flat output slots (the OOB = unplaced sentinel)
+    bucket_map: jax.Array,  # u32[n_buckets_padded] heap bucket -> output row
+    n_rows: int,  # output bucket rows; doubles as the "not fetched" sentinel
     sort_impl: str,
-    slot_of,  # (target u32[W], level, rank u32[W]) -> flat output slot
+    dense_levels: int = 0,  # levels whose buckets are their own output row
 ):
     """Joint level-synchronous greedy eviction assignment (module
     docstring step 3): one sort of the working set by leaf, then per
     level a segmented rank caps each bucket at Z — O(W) work per level
-    with no [W, n_targets] masks. Returns ``(slot_tgt, placed)`` in
-    working-set order; ``slot_tgt`` indexes a flat output of ``nslots``
-    slots (OOB = unplaced). ONE body serves both write layouts — the
-    placement itself (which entry lands in which bucket) is the same
-    greedy function either way, which the cross-E bit-identity contract
-    depends on:
+    with no [W, n_rows] masks. Returns ``(slot_tgt, placed)`` in
+    working-set order; ``slot_tgt`` = ``row·Z + rank`` indexes a flat
+    output of ``n_rows·Z`` slots (OOB = unplaced). ONE body serves both
+    write layouts — the placement itself (which entry lands in which
+    bucket) is the same greedy function either way, which the cross-E
+    bit-identity contract depends on:
 
-    - per-round eviction (oram_round): ``bucket_map`` = owner columns,
-      ``n_targets`` = B, ``slot_of`` = [col, level, slot] layout over
-      the fetched paths;
+    - per-round eviction (oram_round): ``bucket_map`` sends a bucket
+      that a fetched path meets to its output row (`_bucket_owner_map`);
+      at the ``dense_levels`` — the levels the batch covers — every
+      bucket is a target and its own output row, so those levels skip
+      the lookup;
     - delayed flush (oram_flush): ``bucket_map`` = deduplicated target
-      slots, ``n_targets`` = flush_target_slots, ``slot_of`` =
-      [target, slot] layout over the compacted window union.
+      rows of the compacted window union.
     """
     h, z = cfg.height, cfg.bucket_slots
     w = valid.shape[0]
+    nslots = n_rows * z
     skey = jnp.where(valid, wleaf, U32(0xFFFFFFFF))
     with device_phase("oram_evict_sort"):
         if sort_impl == "radix":
@@ -228,13 +237,17 @@ def _assign_evictions(
         shift = U32(h - level)
         bid = bleaf >> shift  # bucket prefix per entry; sorted ⇒ contiguous
         hb = (U32(1) << U32(level)) - U32(1) + bid  # heap bucket index
-        # one gather answers both "was my bucket fetched" (target !=
-        # n_targets) and which output rows hold it
-        tgt = bucket_map[jnp.minimum(hb, U32(cfg.n_buckets_padded - 1))]
+        elig = svalid & ~placed
+        if level < dense_levels:
+            tgt = hb  # always fetched, and its own output row
+        else:
+            # one gather answers both "was my bucket fetched" (row !=
+            # n_rows) and which output row holds it
+            tgt = bucket_map[jnp.minimum(hb, U32(cfg.n_buckets_padded - 1))]
+            elig = elig & (tgt != U32(n_rows))
         bnd = jnp.concatenate(
             [jnp.ones((1,), jnp.bool_), bid[1:] != bid[:-1]]
         )
-        elig = svalid & ~placed & (tgt != U32(n_targets))
         ei = elig.astype(jnp.int32)
         # exclusive count of eligibles, as the shifted inclusive
         # cumsum (interval-transparent, see primitives.rank_of)
@@ -247,7 +260,7 @@ def _assign_evictions(
         # for interval reasoning (identity at runtime)
         rank = jnp.maximum(ecum - ecum[start], 0)
         chosen = elig & (rank < z)
-        slot = slot_of(tgt, level, rank.astype(U32))
+        slot = tgt * U32(z) + rank.astype(U32)
         slot_tgt_s = jnp.where(chosen, slot, slot_tgt_s)
         placed = placed | chosen
     # back to working-set order (a [W] scatter, so values need no permute)
@@ -291,6 +304,32 @@ def oram_round(
     Returns ``(state', outs, leaves)``; ``leaves`` u32[B] is the public
     transcript (every entry an independent uniform draw).
 
+    **Layout: level-dense above, per-path below.** A level L has 2^L
+    buckets; where 2^L <= B the B paths' copies of that level are no
+    fewer rows than the level itself, so the round reads, evicts into
+    and writes back the WHOLE level once, as a fixed heap range, instead
+    of B owner-masked copies. ``Ld = cfg.dense_levels(B)`` levels are
+    dense (a function of the shapes B, height and top_cache_levels —
+    nothing to configure): the tree-top cache planes are the first
+    ``kc`` of them and enter and leave the working set whole; heap
+    buckets ``[2^kc − 1, 2^Ld − 1)`` are gathered, decrypted, encrypted
+    and scattered at constant indices with an all-true owner mask;
+    levels ``Ld … plen−1`` stay per-path under the owner map. The
+    working set is ``[stash | (2^Ld − 1)·Z dense slots | B·(plen − Ld)·Z
+    per-path slots | B insert rows]``, and the eviction output has one
+    row per dense bucket (its heap id) followed by one per per-path
+    copy. Every bucket of a level the batch covers (2^L <= B,
+    ``cfg.covered_levels(B)`` of them) is an eviction target every
+    round: a block may rest in any bucket on its own path, so the
+    Path-ORAM invariant holds, and the access pattern at the dense
+    levels is a constant — a fixed superset of the buckets the per-path
+    round touched there; the public transcript, ``leaves``, is
+    unchanged. Where the cache is taller than that (B < 2^kc), its
+    deeper planes still come and go whole, but a bucket there is a
+    target only if a fetched path meets it, exactly as without the
+    cache: ``top_cache_levels`` decides which rows come from HBM, never
+    where a block is placed (tests/test_tree_cache.py, contract 1).
+
     ``occ_impl``: "dense" = [B,B]-mask dedup, "scan" = sorted dedup with
     no quadratic intermediate (bit-identical; matches the engine's
     ``vphases_impl`` knob).
@@ -321,9 +360,17 @@ def oram_round(
     from .posmap import lookup_remap_round
 
     b = idxs.shape[0]
-    z, v, plen, h = cfg.bucket_slots, cfg.value_words, cfg.path_len, cfg.height
+    z, v, plen = cfg.bucket_slots, cfg.value_words, cfg.path_len
     s = cfg.stash_size
-    nslots = b * plen * z
+    cb = cfg.cache_buckets  # heap ids [0, cb): the plaintext cache planes
+    le = cfg.covered_levels(b)  # every bucket an eviction target
+    ld = cfg.dense_levels(b)  # max(le, kc): levels held whole
+    nd = (1 << ld) - 1  # heap ids [0, nd): the dense levels, cache included
+    nc = min((1 << le) - 1, cb)  # cache buckets of the covered levels
+    nsp = plen - ld  # per-path levels below them
+    nbot = cfg.fetched_bucket_rows(b)  # rows of the encrypted HBM tree
+    nrows = cb + nbot  # bucket rows of the working set = of the output
+    nslots = nrows * z
     recursive = cfg.posmap is not None
 
     with device_phase("oram_fetch"):
@@ -346,35 +393,39 @@ def oram_round(
 
         with device_phase("path_index"):
             path_b = jax.vmap(lambda lf: path_bucket_indices(cfg, lf))(leaves)  # [B,plen]
-            flat_b = path_b.reshape(b * plen)
-            bmap = _bucket_owner_map(cfg, flat_b)  # heap bucket → owner column
-            cols_flat = jnp.repeat(jnp.arange(b, dtype=U32), plen)
-            fowner = bmap[flat_b] == cols_flat
-
-            # tree-top cache split (cfg.top_cache_levels = kc): the top kc
-            # levels of every path resolve against the decrypted-resident cache
-            # planes; ONLY the bottom plen−kc levels touch the encrypted HBM
-            # tree arrays — the round's HBM path traffic and cipher row count
-            # both shrink by kc/plen (the jaxpr audit in
-            # tools/check_tree_cache_oblivious.py pins this). kc=0 degenerates
-            # to the full-path program bit-for-bit.
+            # below the covered levels a bucket is a target only where a
+            # fetched path meets it. A cache level there (le <= level < ld,
+            # a batch smaller than the cache top) keeps its bucket's own
+            # row; a per-path copy at (column, level >= ld) has output row
+            # nd + column·nsp + (level − ld), and the owner of a shared
+            # bucket is its lowest row.
+            path_rows = jnp.concatenate(
+                [path_b[:, le:ld],
+                 U32(nd) + jnp.arange(b * nsp, dtype=U32).reshape(b, nsp)],
+                axis=1,
+            )
+            bmap = _bucket_owner_map(
+                cfg, path_b[:, le:].reshape(-1), path_rows.reshape(-1), nrows
+            )
+            sparse_b = path_b[:, ld:].reshape(b * nsp)
+            sparse_rows = path_rows[:, ld - le:].reshape(b * nsp)
+            # HBM rows of the round, read and written at the same addresses:
+            # the constant dense range below the cache, then the per-path
+            # rows — ONLY these touch the encrypted tree arrays, so the
+            # round's HBM traffic and cipher row count are
+            # cfg.fetched_bucket_rows(B) (the jaxpr audit in
+            # tools/check_tree_cache_oblivious.py pins this).
             # HBM slot planes are addressed on the bucket axis ([n, Z] reshape
             # views — free, layout-identical): flat slot ids (bucket·Z + slot)
             # escape u32/int32 one geometry doubling before bucket ids do, so
             # the certified u32 bound rides the bucket axis (rangelint;
-            # OPERATIONS.md §18). The tiny cache planes keep flat addressing.
-            kc = cfg.top_cache_levels
-            nbot = plen - kc
-            bot_b = path_b[:, kc:].reshape(b * nbot)
-            # level ℓ < kc heap ids are < 2^kc − 1 = cache_buckets by
-            # construction (path_bucket_indices level structure); the min
-            # states that per-level invariant, which a whole-array interval
-            # cannot carry through the column slice (runtime identity)
-            top_b = jnp.minimum(
-                path_b[:, :kc].reshape(b * kc),
-                U32(max(cfg.cache_buckets, 1) - 1),
-        )
-        top_slots = path_slot_indices(cfg, top_b).reshape(-1)  # [B*kc*z]
+            # OPERATIONS.md §18).
+            bot_b = jnp.concatenate(
+                [jnp.arange(cb, nd, dtype=U32), sparse_b]
+            )
+            owner_bot = jnp.concatenate(
+                [jnp.ones((nd - cb,), jnp.bool_), bmap[sparse_b] == sparse_rows]
+            )
 
     fused = cfg.cipher_impl == "pallas_fused"
     with device_phase("oram_fetch"):
@@ -392,27 +443,15 @@ def oram_round(
         else:
             pidx = _path_gather(
                 state.tree_idx.reshape(-1, z), bot_b, axis_name
-            )  # [B*nbot, z]
-            pval = _path_gather(state.tree_val, bot_b, axis_name)  # [B*nbot, z*v]
+            )  # [nbot, z]
+            pval = _path_gather(state.tree_val, bot_b, axis_name)  # [nbot, z*v]
             pnonce = _path_gather(state.nonces, bot_b, axis_name)
             with device_phase("cipher_decrypt"):
                 pidx, pval = cipher_rows(
                     cfg, state.cipher_key, bot_b, pnonce, pidx, pval
                 )
-        if kc:
-            # cached top levels: plain private gathers, no cipher — the
-            # cache planes are plaintext working state like the stash
-            with device_phase("cache_read"):
-                pidx = jnp.concatenate(
-                    [state.cache_idx[top_slots].reshape(b, kc, z),
-                     pidx.reshape(b, nbot, z)], axis=1,
-                ).reshape(b * plen, z)
-                pval = jnp.concatenate(
-                    [state.cache_val[top_b].reshape(b, kc, z * v),
-                     pval.reshape(b, nbot, z * v)], axis=1,
-                ).reshape(b * plen, z * v)
-        # non-owner copies of shared buckets are invalidated
-        pidx = jnp.where(fowner[:, None], pidx, SENTINEL)
+        # non-owner copies of shared per-path buckets are invalidated
+        pidx = jnp.where(owner_bot[:, None], pidx, SENTINEL)
         if recursive:
             # per-slot leaf metadata rides its own (jnp) cipher plane —
             # the fused kernels cover only the idx/val planes
@@ -426,21 +465,28 @@ def oram_round(
                 pleaf = leaf_plane_cipher(
                     cfg, state.cipher_key, bot_b, pnonce_l, pleaf,
                 )
-            if kc:
-                with device_phase("cache_read"):
-                    pleaf = jnp.concatenate(
-                        [state.cache_leaf[top_slots].reshape(b, kc, z),
-                         pleaf.reshape(b, nbot, z)], axis=1,
-                    )
-            pleaf = pleaf.reshape(-1)
 
     with device_phase("oram_fetch"):
+        # the cache planes are plaintext working state like the stash and
+        # the first cb dense rows: they join the working set whole. A cache
+        # bucket under the covered levels that no path of this round meets
+        # is no eviction target, so its blocks sit the round out and the
+        # write-back keeps them (zero-length unless B < 2^kc): the cache
+        # changes what is fetched from HBM, never where a block is placed
+        with device_phase("cache_read"):
+            cache_met = jnp.repeat(jnp.concatenate(
+                [jnp.ones((nc,), jnp.bool_), bmap[nc:cb] != U32(nrows)]
+            ), z)
+            cidx = jnp.where(cache_met, state.cache_idx, SENTINEL)
+            cval = state.cache_val.reshape(cb * z, v)
         w = s + nslots + b  # + b reserved rows for net inserts
         widx0 = jnp.concatenate(
-            [state.stash_idx, pidx.reshape(-1), jnp.full((b,), SENTINEL, U32)]
+            [state.stash_idx, cidx, pidx.reshape(-1),
+             jnp.full((b,), SENTINEL, U32)]
         )
         wval0 = jnp.concatenate(
-            [state.stash_val, pval.reshape(-1, v), jnp.zeros((b, v), U32)], axis=0
+            [state.stash_val, cval, pval.reshape(-1, v),
+             jnp.zeros((b, v), U32)], axis=0
         )
 
     with device_phase("oram_apply"):
@@ -490,7 +536,8 @@ def oram_round(
             # take their key's winning fresh leaf, the same value the map's
             # remap just recorded (the posmap↔metadata invariant)
             wleaf = jnp.concatenate(
-                [state.stash_leaf, pleaf, jnp.zeros((b,), U32)]
+                [state.stash_leaf, state.cache_leaf, pleaf.reshape(-1),
+                 jnp.zeros((b,), U32)]
             ).at[row_tgt].set(new_leaves, mode="drop")
         else:
             # leaves for the whole working set come from the remapped private
@@ -509,10 +556,7 @@ def oram_round(
     with device_phase("oram_evict"):
         valid = widx != SENTINEL
         slot_tgt, placed = _assign_evictions(
-            cfg, valid, wleaf, bmap, b, nslots, sort_impl,
-            # [col, level, slot] layout over the B fetched paths
-            lambda oc, level, rank:
-                (oc * U32(plen) + U32(level)) * U32(z) + rank,
+            cfg, valid, wleaf, bmap, nrows, sort_impl, dense_levels=le
         )
 
         # eviction slots are unique by construction (rank < z within a
@@ -551,16 +595,13 @@ def oram_round(
             stash_dropped = jnp.maximum(n_left - s, 0).astype(U32)
 
     with device_phase("oram_writeback"):
-        # the eviction output new_pidx/new_pval is [col, level, slot]-
-        # ordered, so the top-kc/bottom split is a contiguous reshape per
-        # column; one owner bit per bucket row covers all z slots on the
-        # bucket-axis scatters below
-        fowner_bot = fowner.reshape(b, plen)[:, kc:].reshape(b * nbot)
-        bot_pidx = new_pidx.reshape(b, plen, z)[:, kc:].reshape(b * nbot, z)
-        bot_pval = new_pval.reshape(b, plen, z * v)[:, kc:].reshape(
-            b * nbot, z * v
-        )
-        epochs_w = jnp.broadcast_to(state.epoch[None, :], (b * nbot, 2))
+        # the eviction output is ordered by output row, so the cache
+        # planes are its first cb rows and the HBM rows (bot_b) the rest:
+        # two contiguous slices; one owner bit per bucket row covers all
+        # z slots on the bucket-axis scatters below
+        bot_pidx = new_pidx[cb * z:].reshape(nbot, z)
+        bot_pval = new_pval[cb * z:].reshape(nbot, z * v)
+        epochs_w = jnp.broadcast_to(state.epoch[None, :], (nbot, 2))
     with device_phase("oram_writeback"):
         if axis_name is None and fused and cfg.encrypted:
             # single-chip fast path: encrypt + scatter in ONE HBM pass (the
@@ -571,7 +612,7 @@ def oram_round(
 
             tree_idx_new, tree_val_new, nonces = scatter_encrypt_rows(
                 state.cipher_key, state.tree_idx, state.tree_val, state.nonces,
-                bot_b, fowner_bot, state.epoch,
+                bot_b, owner_bot, state.epoch,
                 bot_pidx, bot_pval,
                 z=z, rounds=cfg.cipher_rounds,
                 interpret=not _on_tpu(),
@@ -588,64 +629,49 @@ def oram_round(
                 )
             tree_idx_new = _path_scatter(
                 state.tree_idx.reshape(-1, z), bot_b, enc_pidx, axis_name,
-                fowner_bot,
+                owner_bot,
             ).reshape(-1)
             tree_val_new = _path_scatter(
-                state.tree_val, bot_b, enc_pval, axis_name, fowner_bot
+                state.tree_val, bot_b, enc_pval, axis_name, owner_bot
             )
             nonces = (
                 _path_scatter(
-                    state.nonces, bot_b, epochs_w, axis_name, fowner_bot
+                    state.nonces, bot_b, epochs_w, axis_name, owner_bot
                 )
                 if cfg.encrypted
                 else state.nonces
             )
-        if kc:
-            # cached levels write back plaintext, owner-masked exactly
-            # like the tree scatters (one owning column per bucket ⇒
-            # unique in-bounds targets); replicated private state, so no
-            # collective even under sharding — every chip writes the
-            # identical values (the stash-recompaction standing)
-            with device_phase("cache_write"):
-                fowner_top = fowner.reshape(b, plen)[:, :kc].reshape(b * kc)
-                cache_idx_new = _path_scatter(
-                    state.cache_idx, top_slots,
-                    new_pidx.reshape(b, plen, z)[:, :kc].reshape(-1), None,
-                    jnp.repeat(fowner_top, z),
-                )
-                cache_val_new = _path_scatter(
-                    state.cache_val, top_b,
-                    new_pval.reshape(b, plen, z * v)[:, :kc].reshape(
-                        b * kc, z * v
-                    ),
-                    None, fowner_top,
-                )
-        else:
-            cache_idx_new = state.cache_idx
-            cache_val_new = state.cache_val
-        cache_leaf_new = state.cache_leaf
+        # cached levels leave as they came: whole planes, plaintext (a
+        # bucket no path met keeps its rows);
+        # replicated private state, so no collective even under sharding —
+        # every chip computes the identical values (the stash-recompaction
+        # standing). Zero-length at kc = 0.
+        with device_phase("cache_write"):
+            cache_idx_new = jnp.where(
+                cache_met, new_pidx[: cb * z], state.cache_idx
+            )
+            cache_val_new = jnp.where(
+                cache_met[:, None], new_pval[: cb * z], cval
+            ).reshape(cb, z * v)
         if recursive:
             from .path_oram import leaf_plane_cipher
 
-            pleaf3 = new_pleaf.reshape(b, plen, z)
             with device_phase("cipher_encrypt"):
                 enc_pleaf = leaf_plane_cipher(
                     cfg, state.cipher_key, bot_b, epochs_w,
-                    pleaf3[:, kc:].reshape(b * nbot, z),
+                    new_pleaf[cb * z:].reshape(nbot, z),
                 )
             tree_leaf_new = _path_scatter(
                 state.tree_leaf.reshape(-1, z), bot_b, enc_pleaf, axis_name,
-                fowner_bot,
+                owner_bot,
             ).reshape(-1)
-            if kc:
-                with device_phase("cache_write"):
-                    cache_leaf_new = _path_scatter(
-                        state.cache_leaf, top_slots,
-                        pleaf3[:, :kc].reshape(-1), None,
-                        jnp.repeat(fowner_top, z),
-                    )
+            with device_phase("cache_write"):
+                cache_leaf_new = jnp.where(
+                    cache_met, new_pleaf[: cb * z], state.cache_leaf
+                )
         else:
             tree_leaf_new = state.tree_leaf
+            cache_leaf_new = state.cache_leaf
     with device_phase("oram_writeback"):
         new_state = OramState(
             tree_idx=tree_idx_new,
@@ -740,8 +766,8 @@ def _oram_fetch_round(
         with device_phase("path_index"):
             path_b = jax.vmap(lambda lf: path_bucket_indices(cfg, lf))(leaves)
             flat_b = path_b.reshape(b * plen)
-            bmap = _bucket_owner_map(cfg, flat_b)
             cols_flat = jnp.repeat(jnp.arange(b, dtype=U32), plen)
+            bmap = _bucket_owner_map(cfg, flat_b, cols_flat, b)
             # keep = this round's owner copy of a bucket that is NOT stale: a
             # bucket tagged earlier in this flush window already surrendered its
             # live rows to the buffer, so its HBM/cache bytes are dead copies
@@ -1067,10 +1093,9 @@ def oram_flush(
             wleaf = working_leaves(posmap, cfg, widx)
 
         valid = widx != SENTINEL
+        # [target, slot] layout over the compacted window union
         slot_tgt, placed = _assign_evictions(
-            cfg, valid, wleaf, dmap, t, t * z, sort_impl,
-            # [target, slot] layout over the compacted window union
-            lambda ts, level, rank: ts * U32(z) + rank,
+            cfg, valid, wleaf, dmap, t, sort_impl
         )
         new_pidx = jnp.full((t * z,), SENTINEL, U32).at[slot_tgt].set(
             widx, mode="drop", unique_indices=True

@@ -130,3 +130,43 @@ def test_engine_health_includes_batch_metrics():
     assert h["messages"] == 0
     assert h["stash_high_water"] >= 0
     assert h["stash_overflow"] == 0
+
+
+def test_round_layout_gauges_say_what_the_shapes_resolve_to():
+    """The level-dense layout engages by geometry (ISSUE 26), so its
+    "hit share" is static: dense levels and fetched HBM bucket rows of
+    one ``oram_round`` per tree, on the registry under the ``tree``
+    label and in the start-up log — here at a geometry where the
+    mailbox tree is dense throughout and the records tree is not."""
+    from grapevine_tpu.obs.exporter import render_prometheus
+
+    cfg = GrapevineConfig(
+        bucket_cipher_rounds=0, max_messages=256, max_recipients=16,
+        mailbox_cap=4, batch_size=4, stash_size=96,
+    )
+    e = GrapevineEngine(cfg, seed=1)
+    rec, mb = e.ecfg.rec, e.ecfg.mb
+    b, bd = 4, 4 * e.ecfg.mb_choices
+    layout = e.round_layout()
+    # floor(log2 b) + 1 levels, never under the cache nor over the tree
+    assert layout["rec"][0] == min(max(3, rec.top_cache_levels), rec.path_len)
+    assert layout["mb"][0] == min(max(bd.bit_length(), mb.top_cache_levels),
+                                  mb.path_len) == mb.path_len
+    assert rec.path_len > layout["rec"][0]  # per-path levels remain
+    for tree, c, n in (("rec", rec, b), ("mb", mb, bd)):
+        ld, rows = layout[tree]
+        assert rows == ((1 << ld) - (1 << c.top_cache_levels)
+                        + n * (c.path_len - ld))
+        assert rows <= n * (c.path_len - c.top_cache_levels)
+    text = render_prometheus(e.metrics.registry)
+    for tree, (ld, rows) in layout.items():
+        assert f'grapevine_round_dense_levels{{tree="{tree}"}} {ld}' in text
+        assert (f'grapevine_round_fetched_bucket_rows{{tree="{tree}"}} '
+                f'{rows}') in text
+    # the delayed-eviction fetch round stays per-path: only the cache
+    import dataclasses
+
+    d = dataclasses.replace(rec, evict_window=2, evict_fetch_count=b,
+                            evict_buffer_slots=64)
+    assert d.dense_levels(b) == d.top_cache_levels
+    assert d.fetched_bucket_rows(b) == b * (d.path_len - d.top_cache_levels)

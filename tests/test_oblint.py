@@ -564,7 +564,8 @@ def test_legacy_checkers_share_the_analyzer_core():
 def test_k0_recursive_census_cell():
     """Regression (ISSUE 12 satellite): the k=0 recursive cell the
     pre-unification wiring never ran always-on — the uncached recursive
-    round must be index-blind and move full B*path_len rows per plane,
+    round must be index-blind and move every level's rows per plane —
+    the levels the batch covers once, the rest per path (ISSUE 26) —
     tree_leaf included, with no cache planes declared. height=5 keeps
     the bucket-axis [n, Z] plane shapes disjoint from the inner posmap
     round's working buffers (the shape-keyed accounting's one
@@ -572,5 +573,6 @@ def test_k0_recursive_census_cell():
     import check_tree_cache_oblivious as cache_gate
 
     out = cache_gate.check_k0_recursive_census(b=4, height=5)
-    assert out["tree_leaf"] == [4 * 6]  # B * (height+1)
+    # B=4 covers levels 0-2 (7 buckets, once); levels 3-5 per path
+    assert out["tree_leaf"] == [7 + 4 * 3]
     assert "cache_idx" not in out

@@ -22,7 +22,6 @@ from grapevine_tpu.oram.path_oram import (
     init_oram,
     oram_access_batch,
     stash_occupancy,
-    tree_occupancy,
 )
 from grapevine_tpu.oram.round import (
     occurrence_masks,
@@ -88,8 +87,10 @@ def kv_apply_batch(cfg, idxs, codes, vals):
     return apply_batch
 
 
-def _random_kv_batches(cfg, n_batches, batch, seed):
+def _random_kv_batches(cfg, n_batches, batch, seed, p_write=0.4):
     rng = np.random.default_rng(seed)
+    w_hi = 0.1 + p_write  # dummy < 0.1 <= write < w_hi <= read < r_hi <= delete
+    r_hi = w_hi + 0.6 * (0.9 - p_write)
     live = set()
     batches = []
     for _ in range(n_batches):
@@ -101,11 +102,11 @@ def _random_kv_batches(cfg, n_batches, batch, seed):
             if r < 0.1:
                 idxs[i] = cfg.dummy_index
                 codes[i] = OP_READ
-            elif r < 0.5 or not live:
-                idxs[i] = rng.integers(0, cfg.leaves)
+            elif r < w_hi or not live:
+                idxs[i] = rng.integers(0, cfg.blocks)
                 codes[i] = OP_WRITE
                 live.add(int(idxs[i]))
-            elif r < 0.8:
+            elif r < r_hi:
                 idxs[i] = rng.choice(sorted(live))
                 codes[i] = OP_READ
             else:
@@ -117,38 +118,135 @@ def _random_kv_batches(cfg, n_batches, batch, seed):
     return batches
 
 
-def test_round_matches_sequential_oram():
-    """Same op stream through oram_access_batch and oram_round gives the
-    same logical outputs and the same final contents (leaves differ — the
-    two paths draw different randomness; semantics must not)."""
-    cfg = OramConfig(height=5, value_words=4, stash_size=96)
-    batch = 12
+#: The level-dense layout's regimes (ISSUE 26), each a geometry of its
+#: own: (height, batch, cached levels k, cipher rounds, recursive
+#: posmap, shards, batches) and the Ld the shapes must resolve to.
+#: ``parent_stash`` is the highest stash occupancy the PARENT commit's
+#: per-path round reached after any round of the same campaign (same
+#: seeds, same leaves; run once against the parent tree, PR 26): the
+#: dense round makes every covered bucket an eviction target, so it
+#: must never do worse. 0 (the stash empty after every round) unless a
+#: case says otherwise.
+LAYOUT_CASES = {
+    # k < Ld < path_len: dense range, then per-path rows (the seed test)
+    "mixed": dict(height=5, batch=12, k=0, ld=4),
+    # the tree is smaller than the batch: Ld = path_len, no per-path row
+    "tree_under_batch": dict(height=3, batch=16, k=1, ld=4),
+    # the batch is smaller than the cache top: Ld = k, no dense HBM row
+    "batch_under_cache": dict(height=5, batch=2, k=3, ld=3),
+    "batch_of_one": dict(height=4, batch=1, k=0, ld=1, n_batches=24),
+    "cipher_cached": dict(height=5, batch=12, k=2, ld=4, cipher=8),
+    "recursive_posmap": dict(height=5, batch=12, k=2, ld=4, cipher=8,
+                             recursive=True, n_batches=5),
+    # density 6 and write-heavy, ~270 blocks live over 508 slots: the
+    # one campaign here in which the parent's stash was ever non-empty
+    "loaded": dict(height=6, batch=8, k=2, ld=4, blocks=384,
+                   p_write=0.85, n_batches=70, parent_stash=2),
+    "sharded": dict(height=5, batch=12, k=2, ld=4, cipher=8, shards=2,
+                    n_batches=5),
+}
+
+
+def _on_own_path(cfg, state, leaf_of):
+    """Every live tree block sits in a bucket on the path of its leaf:
+    the Path-ORAM invariant, read off the decrypted planes."""
+    from grapevine_tpu.oblivious.primitives import SENTINEL
+    from grapevine_tpu.testing.compare import logical_tree_planes
+
+    idx, _val, _leaf = logical_tree_planes(cfg, state)
+    for hb, slot in zip(*np.nonzero(idx[:-1] != int(SENTINEL))):
+        level = int(hb + 1).bit_length() - 1
+        want = (1 << level) - 1 + (int(leaf_of[idx[hb, slot]])
+                                   >> (cfg.height - level))
+        assert hb == want, (
+            f"block {idx[hb, slot]} (leaf {leaf_of[idx[hb, slot]]}) sits "
+            f"in bucket {hb}, off its path (bucket {want} at level {level})"
+        )
+
+
+@pytest.mark.parametrize("case", list(LAYOUT_CASES))
+def test_round_matches_sequential_oram(case):
+    """Same op stream through oram_access_batch (the op-major oracle:
+    per path, one access at a time) and oram_round (level-dense above,
+    per-path below) gives the same logical outputs and the same final
+    contents (leaves differ — the two paths draw different randomness;
+    semantics must not), at every regime of the dense rule, with a
+    recursive position map, sharded over a mesh, and at a batch of one.
+    Placement differs by design, so the final states are compared as
+    contents (``logical_block_map``) and held to the Path-ORAM
+    invariant; stash overflow is 0 and the stash never holds more than
+    the parent's per-path round left on the same seeds."""
+    from grapevine_tpu.oram.posmap import derive_posmap_spec, read_table
+    from grapevine_tpu.testing.compare import logical_block_map
+
+    c = LAYOUT_CASES[case]
+    blocks = c.get("blocks", 1 << c["height"])
+    pm = (derive_posmap_spec(blocks, top_cache_levels=c["k"])
+          if c.get("recursive") else None)
+    cfg = OramConfig(height=c["height"], value_words=4, stash_size=96,
+                     n_blocks=blocks, top_cache_levels=c["k"],
+                     cipher_rounds=c.get("cipher", 0), posmap=pm)
+    batch = c["batch"]
+    assert cfg.dense_levels(batch) == c["ld"]
     key = jax.random.PRNGKey(0)
     st_seq = init_oram(cfg, key)
     st_rnd = init_oram(cfg, key)
 
     seq_step = jax.jit(
-        lambda st, idxs, nl, ops: oram_access_batch(cfg, st, idxs, nl, ops, kv_fn),
-        static_argnums=(),
+        lambda st, idxs, nl, ops, pm_l: oram_access_batch(
+            cfg, st, idxs, nl, ops, kv_fn, pm_leaves=pm_l if pm else None),
     )
 
-    def rnd_fn(st, idxs, nl, dl, codes, vals):
+    def rnd_fn(st, idxs, nl, dl, codes, vals, pm_nl, pm_dl, axis_name=None):
         return oram_round(
-            cfg, st, idxs, nl, dl, kv_apply_batch(cfg, idxs, codes, vals)
+            cfg, st, idxs, nl, dl, kv_apply_batch(cfg, idxs, codes, vals),
+            axis_name=axis_name,
+            pm_new_leaves=pm_nl if pm else None,
+            pm_dummy_leaves=pm_dl if pm else None,
         )
 
-    rnd_step = jax.jit(rnd_fn)
+    if c.get("shards"):
+        import functools
+
+        from jax.sharding import PartitionSpec as P
+
+        from grapevine_tpu.parallel.mesh import (
+            TREE_AXIS,
+            _oram_specs,
+            make_mesh,
+        )
+
+        if len(jax.devices()) < c["shards"]:
+            pytest.skip("needs a multi-device mesh")
+        mesh = make_mesh(jax.devices()[: c["shards"]])
+        specs = _oram_specs()
+        rnd_step = jax.jit(jax.shard_map(
+            functools.partial(rnd_fn, axis_name=TREE_AXIS), mesh=mesh,
+            in_specs=(specs,) + (P(),) * 7,
+            out_specs=(specs, P(), P()), check_vma=False,
+        ))
+    else:
+        rnd_step = jax.jit(rnd_fn)
 
     rkey = jax.random.PRNGKey(42)
-    for bi, (idxs, codes, vals) in enumerate(_random_kv_batches(cfg, 8, batch, 7)):
-        rkey, k1, k2, k3 = jax.random.split(rkey, 4)
-        nl1 = jax.random.bits(k1, (batch,), U32) & U32(cfg.leaves - 1)
-        nl2 = jax.random.bits(k2, (batch,), U32) & U32(cfg.leaves - 1)
-        dl = jax.random.bits(k3, (batch,), U32) & U32(cfg.leaves - 1)
+    stash_max = 0
+    n_batches = c.get("n_batches", 8)
+    for bi, (idxs, codes, vals) in enumerate(
+        _random_kv_batches(cfg, n_batches, batch, 7, c.get("p_write", 0.4))
+    ):
+        rkey, *ks = jax.random.split(rkey, 7)
+
+        def draw(k, n):
+            return jax.random.bits(k, (batch,), U32) & U32(n - 1)
+
+        il = cfg.leaves if pm is None else 1 << pm.inner_height
+        nl1, nl2, dl = (draw(k, cfg.leaves) for k in ks[:3])
+        pm1, pm2, pm3 = (draw(k, il) for k in ks[3:])
         ops = (jnp.asarray(codes), jnp.asarray(vals))
-        st_seq, out_s, _ = seq_step(st_seq, jnp.asarray(idxs), nl1, ops)
+        st_seq, out_s, _ = seq_step(st_seq, jnp.asarray(idxs), nl1, ops, pm1)
         st_rnd, out_r, leaves = rnd_step(
-            st_rnd, jnp.asarray(idxs), nl2, dl, jnp.asarray(codes), jnp.asarray(vals)
+            st_rnd, jnp.asarray(idxs), nl2, dl, jnp.asarray(codes),
+            jnp.asarray(vals), pm2, pm3,
         )
         np.testing.assert_array_equal(
             np.asarray(out_s["present"]), np.asarray(out_r["present"]), f"batch {bi}"
@@ -156,23 +254,31 @@ def test_round_matches_sequential_oram():
         np.testing.assert_array_equal(
             np.asarray(out_s["value"]), np.asarray(out_r["value"]), f"batch {bi}"
         )
-        assert np.asarray(leaves).shape == (batch,)
-        assert np.all(np.asarray(leaves) < cfg.leaves)
+        assert np.asarray(leaves).shape == ((batch, 2) if pm else (batch,))
+        assert np.all(np.asarray(leaves).reshape(batch, -1)[:, 0] < cfg.leaves)
+        stash_max = max(stash_max, int(stash_occupancy(st_rnd)))
 
     assert int(st_seq.overflow) == 0 and int(st_rnd.overflow) == 0
-    # identical logical content: same live blocks in tree+stash
-    assert int(tree_occupancy(st_seq) + stash_occupancy(st_seq)) == int(
-        tree_occupancy(st_rnd) + stash_occupancy(st_rnd)
+    assert stash_max <= c.get("parent_stash", 0), (
+        f"{case}: the dense round left {stash_max} blocks in the stash "
+        f"where the parent's per-path round left {c.get('parent_stash', 0)}"
     )
+    # identical logical content: the same live blocks with the same
+    # values, wherever each state placed them
+    assert logical_block_map(cfg, st_seq) == logical_block_map(cfg, st_rnd)
+    _on_own_path(cfg, st_rnd, np.asarray(read_table(cfg, st_rnd.posmap)))
     # read back every index through the sequential path on both states
-    all_idx = jnp.arange(cfg.leaves, dtype=U32)
-    zeros = jnp.zeros((cfg.leaves, cfg.value_words), U32)
-    ops = (jnp.full((cfg.leaves,), OP_READ, U32), zeros)
-    nl = jax.random.bits(jax.random.PRNGKey(9), (cfg.leaves,), U32) & U32(
+    all_idx = jnp.arange(blocks, dtype=U32)
+    zeros = jnp.zeros((blocks, cfg.value_words), U32)
+    ops = (jnp.full((blocks,), OP_READ, U32), zeros)
+    nl = jax.random.bits(jax.random.PRNGKey(9), (blocks,), U32) & U32(
         cfg.leaves - 1
     )
-    _, back_s, _ = oram_access_batch(cfg, st_seq, all_idx, nl, ops, kv_fn)
-    _, back_r, _ = oram_access_batch(cfg, st_rnd, all_idx, nl, ops, kv_fn)
+    pml = nl & U32((1 << pm.inner_height) - 1) if pm else None
+    _, back_s, _ = oram_access_batch(cfg, st_seq, all_idx, nl, ops, kv_fn,
+                                     pm_leaves=pml)
+    _, back_r, _ = oram_access_batch(cfg, st_rnd, all_idx, nl, ops, kv_fn,
+                                     pm_leaves=pml)
     np.testing.assert_array_equal(np.asarray(back_s["present"]), np.asarray(back_r["present"]))
     np.testing.assert_array_equal(np.asarray(back_s["value"]), np.asarray(back_r["value"]))
 

@@ -14,9 +14,10 @@ The contract of ``GrapevineConfig.tree_top_cache_levels = k``
    round of a soak (a top-cache bug — wrong eviction eligibility, a
    dropped cache write — would first show up as silent stash drift),
    read through ``health()``'s ``stash_occupancy`` fold;
-3. the cached round is index-blind and moves exactly B·(path_len−k)
-   HBM bucket rows per plane (tools/check_tree_cache_oblivious.py,
-   k=0 positive control);
+3. the cached round is index-blind and moves exactly
+   (2^Ld − 2^k) + B·(path_len − Ld) HBM bucket rows per plane — the
+   levels the batch covers once, the rest per path
+   (tools/check_tree_cache_oblivious.py, k=0 positive control);
 4. a cached checkpoint can never silently restore into a
    differently-cached engine (geometry fingerprint covers k);
 5. the leak monitor stays PASS on a live soak with caching enabled.
@@ -275,10 +276,79 @@ def test_tree_cache_access_schedule_audit():
     from check_tree_cache_oblivious import check_tree_cache_schedule
 
     out = check_tree_cache_schedule(b=8, height=5, recursive=False)
-    # per access: path_len − k bucket rows per HBM plane
-    assert out["k0"]["tree_val"] == [8 * 6]
-    assert out["k2"]["tree_val"] == [8 * 4]
-    assert out["k2"]["cache_val"] == [8 * 2]
+    # B=8 covers levels 0-3 (Ld=4): those move once, whole — 15 buckets
+    # at k=0, the 12 under the cache at k=2 — and levels 4-5 per path;
+    # the cache planes pass through whole (no op names them)
+    assert out["k0"]["tree_val"] == [15 + 8 * 2]
+    assert out["k2"]["tree_val"] == [12 + 8 * 2]
+    assert "cache_val" not in out["k2"]
+
+
+def test_level_dense_row_accounting_in_its_three_regimes():
+    """The level-dense rule (ISSUE 26) at a geometry per regime —
+    tree smaller than the batch (Ld = path_len), dense range plus
+    per-path rows, batch smaller than the cache top (Ld = k) — traced
+    rows per HBM plane against the gate's own arithmetic, and never
+    more than the per-path count the rule replaced."""
+    from check_tree_cache_oblivious import check_dense_regimes
+
+    out = check_dense_regimes()
+    assert out["tree_under_batch"] == {"rows": 14, "per_path": 48, "Ld": 4}
+    assert out["mixed"] == {"rows": 18, "per_path": 20, "Ld": 3}
+    assert out["batch_under_cache"] == {"rows": 6, "per_path": 6, "Ld": 3}
+
+
+@pytest.mark.parametrize("batch", [1, 2, 3, 12])
+def test_cache_never_changes_where_a_block_is_placed(batch):
+    """Contract 1 at the ORAM level, every round: the same op stream and
+    the same leaves through ``oram_round`` at k = 0 and k = 3 leave
+    bit-identical decrypted planes (cache overlaid) and stashes — also
+    where the batch is smaller than the cache top (B = 1, 2, 3 cover 1-2
+    levels of the 3 cached), where the deeper cache planes are held
+    whole but a bucket is an eviction target only if a path meets it."""
+    import jax.numpy as jnp
+
+    from test_round import _random_kv_batches, kv_apply_batch
+
+    from grapevine_tpu.oram.path_oram import OramConfig, init_oram
+    from grapevine_tpu.oram.round import oram_round
+    from grapevine_tpu.testing.compare import logical_tree_planes
+
+    cfgs = [OramConfig(height=5, value_words=4, stash_size=96,
+                       cipher_rounds=8, top_cache_levels=k) for k in (0, 3)]
+    assert [c.covered_levels(batch) for c in cfgs] == [batch.bit_length()] * 2
+    assert cfgs[1].dense_levels(batch) == max(batch.bit_length(), 3)
+    steps = [
+        jax.jit(lambda st, idxs, nl, dl, codes, vals, c=c: oram_round(
+            c, st, idxs, nl, dl, kv_apply_batch(c, idxs, codes, vals)))
+        for c in cfgs
+    ]
+    states = [init_oram(c, jax.random.PRNGKey(0)) for c in cfgs]
+    rkey = jax.random.PRNGKey(11)
+    for bi, (idxs, codes, vals) in enumerate(
+        _random_kv_batches(cfgs[0], 16, batch, 13, p_write=0.7)
+    ):
+        rkey, k1, k2 = jax.random.split(rkey, 3)
+        nl, dl = (jax.random.bits(k, (batch,), jnp.uint32)
+                  & jnp.uint32(cfgs[0].leaves - 1) for k in (k1, k2))
+        outs = []
+        for j in range(2):
+            states[j], out, leaves = steps[j](
+                states[j], jnp.asarray(idxs), nl, dl, jnp.asarray(codes),
+                jnp.asarray(vals))
+            outs.append((np.asarray(out["value"]), np.asarray(leaves)))
+        for a, b_ in zip(*outs):
+            assert np.array_equal(a, b_), f"round {bi}: answers or leaves"
+        for a, b_ in zip(*(logical_tree_planes(c, st)
+                           for c, st in zip(cfgs, states))):
+            assert np.array_equal(a, b_), f"round {bi}: placement diverges"
+        for name in ("stash_idx", "stash_val"):
+            assert np.array_equal(getattr(states[0], name),
+                                  getattr(states[1], name)), f"round {bi}"
+    if batch < 4:
+        # few paths a round leave blocks high in the tree: the comparison
+        # above was over cache planes that really hold some
+        assert np.any(np.asarray(states[1].cache_idx) != 0xFFFFFFFF)
 
 
 def test_tree_cache_checkpoint_fingerprint_rejects_cross_k(tmp_path):

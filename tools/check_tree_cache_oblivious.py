@@ -1,6 +1,7 @@
 #!/usr/bin/env python
-"""CI gate: the tree-top-cached round is index-blind AND actually cuts
-the per-access HBM path traffic to the bottom path_len−k levels.
+"""CI gate: the tree-top-cached, level-dense round is index-blind AND
+moves exactly the HBM rows its layout says: the dense heap range below
+the cache once, and per-path rows only under the dense levels.
 
 Two claims, both jaxpr-level (the PR-3/5/7 audit pattern — trace-time
 facts, not runtime sampling):
@@ -17,13 +18,19 @@ facts, not runtime sampling):
 2. **HBM row-count accounting.** Every gather/scatter whose operand is
    one of the big HBM tree planes (``tree_idx`` u32[n·Z], ``tree_val``
    u32[n, Z·V], ``nonces`` u32[n, 2], ``tree_leaf`` u32[n·Z]) must move
-   exactly ``B·(path_len−k)`` bucket rows (``·Z`` slots for the flat
-   slot planes) — i.e. per access, exactly ``path_len−k`` bucket rows
-   per plane, the ISSUE-8 acceptance number. ``k=0`` is the positive
-   control: the same census shows the full ``path_len`` rows, proving
-   the counter sees the traffic it claims to cut. The cache planes must
-   appear in the census at ``k>0`` (the top levels are really served
-   from the cache) and must be absent at ``k=0``.
+   exactly ``(2^Ld − 2^k) + B·(path_len − Ld)`` bucket rows, where
+   ``Ld = clamp(floor(log2 B) + 1, k, path_len)`` is the count of
+   levels the batch covers (ISSUE 26: such a level is moved whole,
+   once, at constant addresses; only the levels under them move one
+   row per path). ``k=0`` is the positive control: the same census
+   shows ``2^k − 1`` more rows — the cached buckets — proving the
+   counter sees the traffic the cache cuts; and three geometries pin
+   the three regimes of the rule (``Ld = path_len``: no per-path row
+   at all; ``k < Ld < path_len``; ``Ld = k``: no dense HBM row). At
+   ``k>0`` the cache planes must pass through the round whole: no
+   gather or scatter names them (a per-path read of a cached level
+   would be one), and the round must return NEW cache planes (the top
+   levels are really evicted into, not passed around).
 
 Wired into tier-1 via tests/test_tree_cache.py; standalone:
 ``python tools/check_tree_cache_oblivious.py``.
@@ -125,12 +132,20 @@ def _plane_rows(jaxpr, cfg) -> dict:
     return _shared_plane_rows(jaxpr, _tree_planes(cfg))
 
 
+def dense_round_rows(b: int, plen: int, k: int) -> int:
+    """HBM bucket rows one E=1 round of ``b`` paths moves per plane and
+    direction, from the layout's rule alone (this gate's own
+    arithmetic — never read off the program's ``OramConfig``)."""
+    ld = min(max(b.bit_length(), k), plen)  # floor(log2 b) + 1, clamped
+    return ((1 << ld) - (1 << k)) + b * (plen - ld)
+
+
 def check_tree_cache_schedule(
     b: int = 8, height: int = 5, verbose: bool = False, recursive: bool = False
 ) -> dict:
     """Run both audits over k ∈ {0, 2}; raises AssertionError on any
     violation, returns the per-k row accounting."""
-    from grapevine_tpu.oram.path_oram import OramConfig
+    from grapevine_tpu.oram.path_oram import OramConfig, OramState
     from grapevine_tpu.oram.posmap import derive_posmap_spec
 
     out = {}
@@ -145,7 +160,7 @@ def check_tree_cache_schedule(
             cipher_rounds=8, top_cache_levels=k, posmap=pm,
         )
         plen = cfg.path_len
-        want = b * (plen - k)
+        want = dense_round_rows(b, plen, k)
 
         # -- 1. index-independence ---------------------------------------
         censuses = {
@@ -167,15 +182,17 @@ def check_tree_cache_schedule(
         )
 
         # -- 2. HBM row accounting ---------------------------------------
-        rows = _plane_rows(_trace_round(cfg, _index_sets(cfg, b)["mixed_dups"], b), cfg)
+        jaxpr = _trace_round(cfg, _index_sets(cfg, b)["mixed_dups"], b)
+        rows = _plane_rows(jaxpr, cfg)
         for pname in ("tree_idx", "tree_val", "nonces"):
             moved = rows[pname]
             assert moved, f"k={k}: no accesses seen on {pname}"
             bad = [r for _, r in moved if r != want]
             assert not bad, (
                 f"k={k}: {pname} moves {sorted(set(bad))} bucket rows "
-                f"per round — every HBM path access must move exactly "
-                f"B·(path_len−k) = {b}·({plen}−{k}) = {want}"
+                f"per round — every HBM tree access must move exactly "
+                f"(2^Ld − 2^k) + B·(path_len − Ld) = {want} "
+                f"(B={b}, path_len={plen})"
             )
         if recursive:
             assert rows["tree_leaf"], f"k={k}: no tree_leaf accesses"
@@ -183,15 +200,21 @@ def check_tree_cache_schedule(
                 f"k={k}: tree_leaf rows diverge from {want}"
             )
         if k:
+            # the cached levels are the first dense levels: their planes
+            # join and leave the working set whole. A gather or scatter
+            # on one is a per-path read of a cached level come back.
             for pname in ("cache_idx", "cache_val"):
-                assert rows[pname], (
-                    f"k={k}: the cache plane {pname} is never accessed — "
-                    "the cached levels are not actually served from the "
-                    "cache"
+                assert not rows[pname], (
+                    f"k={k}: {pname} is gathered/scattered "
+                    f"({rows[pname]}) — the cached levels must pass "
+                    "through the round as whole planes"
                 )
-                assert all(r == b * k for _, r in rows[pname]), (
-                    f"k={k}: {pname} moves {rows[pname]} — want B·k = "
-                    f"{b * k} rows"
+                # flat pytree order = field order up to the posmap (the
+                # only nested field, after every cache plane)
+                i = OramState._fields.index(pname)
+                assert jaxpr.jaxpr.outvars[i] is not jaxpr.jaxpr.invars[i], (
+                    f"k={k}: the round returns its input {pname} — the "
+                    "cached levels are not evicted into"
                 )
         out[f"k{k}"] = {
             p: sorted({r for _, r in rs}) for p, rs in rows.items() if rs
@@ -200,12 +223,68 @@ def check_tree_cache_schedule(
             print(f"k={k} ({'recursive' if recursive else 'flat'}): "
                   f"{out[f'k{k}']}")
 
-    # positive control across k: the counter must SEE the cut
+    # positive control across k: the counter must SEE the cut — the
+    # 2^k − 1 cached buckets leave the HBM planes (k=2 stays under the
+    # default geometry's dense levels, so the cut is dense rows)
     full = out["k0"]["tree_val"][0]
     cut = out["k2"]["tree_val"][0]
-    assert full == b * (height + 1) and cut == b * (height - 1), (
+    assert (full, cut) == (dense_round_rows(b, height + 1, 0),
+                           dense_round_rows(b, height + 1, 2)) and (
+        full - cut == 3
+    ), (
         f"positive control failed: k=0 moves {full} rows, k=2 moves "
-        f"{cut} — expected {b * (height + 1)} vs {b * (height - 1)}"
+        f"{cut} — expected the 2^2−1 = 3 cached buckets between them"
+    )
+    return out
+
+
+def check_dense_regimes(verbose: bool = False) -> dict:
+    """The three regimes of the level-dense rule, each at a geometry of
+    its own (flat posmap, cipher on), rows per HBM plane against the
+    rule's arithmetic AND against the per-path count it replaces:
+
+    - ``Ld = path_len`` (tree smaller than the batch): the whole tree
+      once, no per-path row — far under ``B·(path_len−k)``;
+    - ``k < Ld < path_len``: the dense range plus per-path rows;
+    - ``Ld = k`` (batch smaller than the cache top): no dense HBM row,
+      exactly the per-path count — the rule may never move MORE than
+      the layout it replaced.
+    """
+    from grapevine_tpu.oram.path_oram import OramConfig
+
+    out = {}
+    for name, b, height, k, want_ld in (
+        ("tree_under_batch", 16, 3, 1, 4),
+        ("mixed", 4, 5, 1, 3),
+        ("batch_under_cache", 2, 5, 3, 3),
+    ):
+        cfg = OramConfig(height=height, value_words=8,
+                         n_blocks=1 << height, cipher_rounds=8,
+                         top_cache_levels=k)
+        plen = cfg.path_len
+        want = dense_round_rows(b, plen, k)
+        assert cfg.dense_levels(b) == want_ld, (name, cfg.dense_levels(b))
+        assert cfg.fetched_bucket_rows(b) == want, name
+        per_path = b * (plen - k)
+        assert want <= per_path, (
+            f"{name}: the dense rule moves {want} rows where per-path "
+            f"moved {per_path}"
+        )
+        rows = _plane_rows(
+            _trace_round(cfg, _index_sets(cfg, b)["mixed_dups"], b), cfg
+        )
+        for pname in ("tree_idx", "tree_val", "nonces"):
+            got = sorted({r for _, r in rows[pname]})
+            assert got == [want], (
+                f"{name}: {pname} moves {got} rows per access op — want "
+                f"{want} (B={b}, path_len={plen}, k={k}, Ld={want_ld})"
+            )
+        out[name] = {"rows": want, "per_path": per_path, "Ld": want_ld}
+        if verbose:
+            print(f"dense regime {name}: {out[name]}")
+    assert out["tree_under_batch"]["rows"] == 15 - 1
+    assert out["batch_under_cache"]["rows"] == (
+        out["batch_under_cache"]["per_path"]
     )
     return out
 
@@ -247,14 +326,15 @@ def check_k0_recursive_census(b: int = 4, height: int = 5) -> dict:
     rows = _plane_rows(
         _trace_round(cfg, _index_sets(cfg, b)["mixed_dups"], b), cfg
     )
-    want = b * cfg.path_len  # k=0: the full path on every plane
+    # k=0: every level on the HBM planes — the dense ones once
+    want = dense_round_rows(b, cfg.path_len, 0)
     for pname in ("tree_idx", "tree_val", "nonces", "tree_leaf"):
         moved = rows[pname]
         assert moved, f"k=0 recursive: no accesses seen on {pname}"
         bad = [r for _, r in moved if r != want]
         assert not bad, (
             f"k=0 recursive: {pname} moves {sorted(set(bad))} rows — "
-            f"want the full B*path_len = {want}"
+            f"want (2^Ld − 1) + B·(path_len − Ld) = {want}"
         )
     assert "cache_idx" not in rows, "k=0 must declare no cache planes"
     return {p: sorted({r for _, r in rs}) for p, rs in rows.items() if rs}
@@ -291,8 +371,10 @@ def check_evict_round_accounting(
     1. **Fetch rounds are read-only on HBM.** The fetch-only round's
        census is identical across adversarial index sets (index-blind,
        claim 1 of the per-round audit), its tree-plane GATHERS move
-       exactly ``B·(path_len−k)`` bucket rows per plane — the same
-       fetch traffic as the E=1 round — and it contains ZERO scatters
+       exactly ``B·(path_len−k)`` bucket rows per plane — the
+       per-path fetch (the E=1 round's level-dense layout is not this
+       program's: ISSUE 26 left the delayed pair as it was) — and it
+       contains ZERO scatters
        on any tree/nonce/cache plane: the scatter+encrypt half of the
        round is really gone from the steady state.
     2. **The flush writes exactly the window, deduplicated.** One
@@ -787,6 +869,8 @@ def main(argv=None) -> int:
         print(f"[check_tree_cache_oblivious] recursive={recursive}: OK {out}")
     out = check_k0_recursive_census(b=4, height=5)
     print(f"[check_tree_cache_oblivious] k0-recursive cell: OK {out}")
+    out = check_dense_regimes(verbose=True)
+    print(f"[check_tree_cache_oblivious] dense regimes: OK {out}")
     for recursive in (False, True):
         out = check_evict_round_accounting(verbose=True,
                                            recursive=recursive)
@@ -807,7 +891,8 @@ def main(argv=None) -> int:
               "scatter mutant passed the sharded partition audit")
         return 1
     print("[check_tree_cache_oblivious] PASS: cached round is index-blind "
-          "and HBM path traffic is exactly B·(path_len−k) rows per plane; "
+          "and HBM tree traffic is exactly (2^Ld − 2^k) + B·(path_len − Ld) "
+          "rows per plane; "
           "delayed-eviction fetch rounds are HBM-read-only, each flush "
           "writes exactly the E-round window, and the sharded flush "
           "owner-partitions that window across the mesh")
