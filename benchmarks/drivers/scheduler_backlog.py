@@ -7,15 +7,23 @@ real sr25519 signature over a seeded challenge, made before the window
 and checked in the round's batch verification. Traffic parameters
 (``traffic/<name>.json``): ``outstanding_rounds``, ``mix``,
 ``identities``, ``recipient_zipf``, ``presign_ops_per_s``.
+
+The loader shares the collector's interpreter lock, so what its thread
+does inside the window is taken from the program. It is kept small and
+it is measured: every request that names no message id (three in five)
+is built before the window; what is kept for the oracle leaves the
+cycle collector's sight wave by wave (``lib/gcwatch.py``); and the
+thread's own CPU seconds (``time.thread_time``, which does not count
+waiting for the lock) are stamped per wave, apart for the calls into
+the program's ``submit_nowait`` and for everything else.
 """
 
 from __future__ import annotations
 
 import queue
-import random
 import time
 
-from ..lib import opmix
+from ..lib import gcwatch, opmix
 from ..lib.identities import SigningPool
 from ..lib.roundlog import annotation
 
@@ -24,8 +32,9 @@ STALL_S = 300.0
 
 
 def prepare(ctx) -> dict:
-    from grapevine_tpu.wire import records
-
+    """Identities and the script, here; the signatures start in the
+    worker processes and are collected by ``ready``, after the harness
+    has warmed the round program beside them."""
     tr = ctx.traffic
     bs = ctx.cfg.batch_size
     outstanding = tr["outstanding_rounds"] * bs
@@ -34,43 +43,69 @@ def prepare(ctx) -> dict:
     try:
         idents = pool.identities(ctx.ident_seed, tr["identities"])
         script = opmix.script(ctx.seed, n_script, tr)
-        auth = pool.sign_script(ctx.seed, idents, [e[1] for e in script])
+        signed = pool.sign_script(ctx.seed, idents, [e[1] for e in script])
+    except BaseException:
+        pool.close()
+        raise
+    return {"idents": idents, "pubs": [pub for _, pub in idents],
+            "script": script, "pool": pool, "signed": signed, "next": 0,
+            "reused": 0, "futures": [], "ready": [], "cpu": [], "bs": bs,
+            "outstanding": outstanding}
+
+
+def ready(ctx, state) -> None:
+    """The rest of set-up: the signatures, then every request that can
+    be built without an answer, and the first ``outstanding`` ready."""
+    from grapevine_tpu.wire import records
+
+    pool = state.pop("pool")
+    try:
+        auth = state["auth"] = state.pop("signed")()
     finally:
         pool.close()
-    pubs = [pub for _, pub in idents]
-    ctx.say(phase="traffic", identities=len(idents), presigned_ops=n_script,
-            outstanding=outstanding, signing_workers=pool.n)
-    state = {"idents": idents, "pubs": pubs, "script": script, "auth": auth,
-             "known": opmix.KnownIds(pubs), "records": records,
-             "rng": random.Random(f"{ctx.seed}-payloads"), "next": 0,
-             "reused": 0, "futures": [], "ready": [], "bs": bs,
-             "outstanding": outstanding}
-    _build(state, outstanding)
-    return state
+    script, pubs = state["script"], state["pubs"]
+    payloads = state["payloads"] = opmix.Payloads(ctx.seed)
+    state["known"] = opmix.KnownIds(pubs)
+    state["records"] = records
+    state["prebuilt"] = [
+        None if opmix.needs_answers(e) else opmix.build_request(
+            e, j, auth[j], None, pubs, payloads, records)
+        for j, e in enumerate(script)]
+    ctx.say(phase="traffic", identities=len(pubs), presigned_ops=len(script),
+            prebuilt_ops=sum(r is not None for r in state["prebuilt"]),
+            outstanding=state["outstanding"], signing_workers=pool.n)
+    _build(state, state["outstanding"])
 
 
 def _build(state, upto: int) -> None:
-    """Top the ready list up to ``upto`` built requests."""
-    ready, script, auth = state["ready"], state["script"], state["auth"]
-    while len(ready) < upto:
+    """Top the ready list up to ``upto`` requests."""
+    ready_, script, auth = state["ready"], state["script"], state["auth"]
+    prebuilt = state["prebuilt"]
+    while len(ready_) < upto:
         j = state["next"]
         if j >= len(script):
             # a faster program than the pre-signing allowed for: the
             # script starts over (same signatures, same verification work)
             j = state["next"] = 0
             state["reused"] += 1
-        item = auth[j]
-        ready.append((opmix.build_request(
-            script[j], item, state["known"], state["pubs"], state["rng"],
-            state["records"]), item))
+        req = prebuilt[j] or opmix.build_request(
+            script[j], j, auth[j], state["known"], state["pubs"],
+            state["payloads"], state["records"])
+        ready_.append((req, auth[j]))
         state["next"] = j + 1
 
 
-def _submit(ctx, state, n: int) -> None:
-    ready = state["ready"]
-    take, state["ready"] = ready[:n], ready[n:]
+def _submit(ctx, state, n: int) -> float:
+    """``n`` ready ops into the scheduler; returns the CPU seconds this
+    thread spent inside the program's ``submit_nowait``."""
+    ready_ = state["ready"]
+    take, state["ready"] = ready_[:n], ready_[n:]
     submit = ctx.server.scheduler.submit_nowait
-    state["futures"] += [submit(req, item) for req, item in take]
+    c0 = time.thread_time()
+    futures = [submit(req, item) for req, item in take]
+    c1 = time.thread_time()
+    state["futures"] += futures
+    return c1 - c0
 
 
 def run(ctx, state, t_open: float) -> float:
@@ -88,13 +123,17 @@ def run(ctx, state, t_open: float) -> float:
     while True:
         with annotation("bench/wait_round"):
             e = resolved.get(timeout=STALL_S)
+        c0 = time.thread_time()
         if e["t_resolved"] >= t_close:
             break
         with annotation("bench/submit"):
-            _submit(ctx, state, len(e["reqs"]))
+            inside = _submit(ctx, state, len(e["reqs"]))
         with annotation("bench/build_wave"):
             state["known"].learn(e["reqs"], e["resps"])
             _build(state, bs)
+            gcwatch.settle()
+        # [CPU s inside submit_nowait, CPU s of everything else] per wave
+        state["cpu"].append((inside, time.thread_time() - c0 - inside))
     ctx.log.on_resolved = None
     return e["t_resolved"]
 
@@ -108,12 +147,24 @@ def finish(ctx, state) -> dict:
             fut.result(timeout=max(0.1, deadline - time.perf_counter()))
         except Exception:  # noqa: BLE001 — any failure is an op not answered
             unanswered += 1
+    cpu = state["cpu"]
     return {"attempted": len(state["futures"]), "unanswered": unanswered,
-            "summary": {"presigned_script_reused": state["reused"]}}
+            "loadgen_cpu": cpu,
+            "summary": {
+                # > 0: the program outran presign_ops_per_s and met ops
+                # and signatures a second time; the run is not comparable
+                "presigned_script_reused": state["reused"],
+                "loadgen_waves": len(cpu),
+                "loadgen_submit_cpu_s": sum(c[0] for c in cpu),
+                "loadgen_own_cpu_s": sum(c[1] for c in cpu)}}
 
 
 def stop(ctx, state) -> None:
-    """Nothing of this driver outlives the window."""
+    """Nothing of this driver outlives the window; the signing pool
+    only if set-up failed before ``ready`` closed it."""
+    pool = state.pop("pool", None)
+    if pool is not None:
+        pool.close()
 
 
 def end_to_end(ctx, obs: dict) -> dict:

@@ -19,11 +19,12 @@ import threading
 import time
 import types
 
-from . import compare, round_bytes
+from . import compare, gcwatch, round_bytes
 from . import wire as W
 from ..readers import xplane_busy
 from .manifest import HERE, Benchmark, load_kind
 from .roundlog import RoundLog, SubmitLog
+from .stats import percentile
 
 
 def say(**kv) -> None:
@@ -226,7 +227,10 @@ class Cell:
                           self.server.scheduler.idle_gap * 1e3},
             state_bytes=sum(x.nbytes for x in jax.tree.leaves(engine.state)))
         self.log = RoundLog(engine)
-        self.waits = SubmitLog(self.server.scheduler)
+        # only a driver that reads enqueue -> settle waits pays for them:
+        # the wrapper costs every op a callback on the collector thread
+        self.waits = (SubmitLog(self.server.scheduler)
+                      if getattr(self.driver, "SUBMIT_LOG", False) else None)
         self.warm = False
 
     def drive(self, seed: int, seconds: float, trace: bool,
@@ -253,13 +257,24 @@ class Cell:
                 first = warm_round(self.server, state["idents"])
                 self.warm = True
                 say(phase="first_round", compile_or_load_and_run_s=first)
+            if hasattr(driver, "ready"):
+                # what the driver started in prepare() beside the first
+                # round (signatures in worker processes) is collected here
+                t0 = time.perf_counter()
+                driver.ready(ctx, state)
+                say(phase="ready", ready_s=time.perf_counter() - t0)
             first_round = len(self.log.entries)
             gc.collect()
             gc.freeze()  # set-up's objects leave the collector's sight
+            watch = gcwatch.GcWatch()
             t_open = time.perf_counter()
             tracing = (Tracing(os.path.join(self.scratch, "trace"), t_open,
                                seconds, self.traffic) if trace else None)
-            t_end = driver.run(ctx, state, t_open)
+            watch.start()
+            try:
+                t_end = driver.run(ctx, state, t_open)
+            finally:
+                gc_seen = watch.stop()
             peak = memory_peak_bytes()
             plain_trace, tr0, tr1 = (tracing.result() if tracing
                                      else (None, None, None))
@@ -279,7 +294,8 @@ class Cell:
                             if t_process_start is not None else None),
                 "batch_size": self.cfg.batch_size, "shards": self.cfg.shards,
                 "geometry": self.geometry, "device_kind": self.dev["kind"],
-                "submit_waits": self.waits.waits, "memory_peak_bytes": peak,
+                "submit_waits": self.waits.waits if self.waits else [],
+                "collections": gc_seen, "memory_peak_bytes": peak,
                 "trace": plain_trace, "trace_window": (tr0, tr1)}
 
     def judge(self, obs: dict) -> tuple[bool, int, dict]:
@@ -303,6 +319,9 @@ class Cell:
                 shard_layout_faults(self.engine, self.cfg.shards),
         }
         correct, lines = compare.verdict(numbers)
+        #: each number compared beside its limit, for the result line
+        self.compared = {x["compared"]: {"value": x["value"],
+                                         "limit": x["limit"]} for x in lines}
         for line in lines:
             say(phase="compare", **line)
         say(phase="oracle", replay_s=time.perf_counter() - t0,
@@ -316,14 +335,28 @@ class Cell:
         return correct, int(failed), rep
 
     def close(self) -> None:
-        self.waits.fail_pending()
+        if self.waits is not None:
+            self.waits.fail_pending()
         self.server.stop()
+
+
+def period_spread_ms(rounds: list) -> dict | None:
+    """How the time between consecutive rounds' answers was spread over
+    the window: a few long rounds (a stalled host) and a slower host
+    read alike in a rate and apart here."""
+    ts = [e["t_resolved"] for e in rounds]
+    steps = [1e3 * (b - a) for a, b in zip(ts, ts[1:])]
+    if len(steps) < 10:
+        return None
+    return {"p10": percentile(steps, 10), "p50": percentile(steps, 50),
+            "p90": percentile(steps, 90), "max": max(steps)}
 
 
 def run_cell(bench: Benchmark, cell_name: str, seed: int, seconds: float,
              trace: bool, t_process_start: float, scratch: str) -> dict:
     """One run: the result line as a dict (``correct``, ``attempted``,
-    ``failed``, ``metrics``, ``device`` and, traced, ``breakdown``)."""
+    ``failed``, ``metrics``, ``device``, traced ``breakdown``, and last
+    ``compared``: each number compared beside its limit)."""
     import jax
 
     layer_metrics = bench.per_layer(cell_name) if trace else []
@@ -348,8 +381,9 @@ def run_cell(bench: Benchmark, cell_name: str, seed: int, seconds: float,
         round_fill_mean=(statistics.fmean(
             len(e["reqs"]) for e in obs["rounds"]) / cell.cfg.batch_size
             if obs["rounds"] else None),
+        round_period_ms=period_spread_ms(obs["rounds"]),
         memory_peak_bytes=obs["memory_peak_bytes"], end_to_end=values,
-        **observed.get("summary", {}))
+        **obs["collections"], **observed.get("summary", {}))
     device = {**cell.dev, "memory_peak_bytes": obs["memory_peak_bytes"]}
     result = {"correct": bool(correct), "attempted": observed["attempted"],
               "failed": failed, "metrics": {}, "device": device}
@@ -357,6 +391,7 @@ def run_cell(bench: Benchmark, cell_name: str, seed: int, seconds: float,
         for m in bench.end_to_end(cell_name):
             result["metrics"][m["name"]] = {"value": values[m["name"]],
                                             "unit": m["unit"]}
+        result["compared"] = cell.compared
         return result
     for entry, spec in layer_metrics:
         value = readers[spec["reader"]].read(spec.get("params", {}), obs)
@@ -368,4 +403,5 @@ def run_cell(bench: Benchmark, cell_name: str, seed: int, seconds: float,
         device.update(busy_s=busy["busy_s"], window_s=busy["window_s"])
         result["breakdown"] = busy["breakdown"]
         say(phase="trace", **busy["summary"])
+    result["compared"] = cell.compared
     return result

@@ -35,14 +35,14 @@ def _keygen_slice(args) -> list:
 def _sign_slice(args) -> bytes:
     """Signatures over seeded challenges for one slice of the script;
     one ``bytes`` back (challenge 32 | signature 64 per op)."""
-    seed, lo, sks = args
+    seed, lo, sks, askers = args
     scheme = _scheme()
     rng = random.Random(f"{seed}-challenges-{lo}")
     out = bytearray()
-    for sk in sks:
+    for a in askers:
         challenge = rng.randbytes(W.CHALLENGE_SIZE)
         out += challenge
-        out += scheme.sign(sk, W.SIGNING_CONTEXT, challenge)
+        out += scheme.sign(sks[a], W.SIGNING_CONTEXT, challenge)
     return bytes(out)
 
 
@@ -64,19 +64,28 @@ class SigningPool:
         return [kp for part in self._pool.map(_keygen_slice, jobs)
                 for kp in part]
 
-    def sign_script(self, seed: int, idents, askers) -> list[tuple]:
-        """One scheduler AuthItem ``(pub, context, challenge, signature)``
-        per entry of ``askers`` (identity indices), in order."""
+    def sign_script(self, seed: int, idents, askers):
+        """Starts the workers on one signature per entry of ``askers``
+        (identity indices) and returns at once, so that the caller's own
+        set-up goes on beside them. The function returned waits for the
+        last and gives one scheduler AuthItem ``(pub, context, challenge,
+        signature)`` per entry, in order."""
         n = len(askers)
         step = -(-n // (self.n * 4)) if n else 1
-        jobs = [(seed, lo, [idents[a][0] for a in askers[lo:lo + step]])
-                for lo in range(0, n, step)]
-        blob = b"".join(self._pool.map(_sign_slice, jobs))
+        sks = [sk for sk, _ in idents]
+        pending = self._pool.map_async(
+            _sign_slice, [(seed, lo, sks, askers[lo:lo + step])
+                          for lo in range(0, n, step)])
         w = W.CHALLENGE_SIZE + W.SIGNATURE_SIZE
-        return [(idents[a][1], W.SIGNING_CONTEXT,
-                 blob[j * w:j * w + W.CHALLENGE_SIZE],
-                 blob[j * w + W.CHALLENGE_SIZE:(j + 1) * w])
-                for j, a in enumerate(askers)]
+
+        def collect() -> list[tuple]:
+            blob = b"".join(pending.get())
+            return [(idents[a][1], W.SIGNING_CONTEXT,
+                     blob[j * w:j * w + W.CHALLENGE_SIZE],
+                     blob[j * w + W.CHALLENGE_SIZE:(j + 1) * w])
+                    for j, a in enumerate(askers)]
+
+        return collect
 
     def close(self) -> None:
         self._pool.close()

@@ -5,10 +5,10 @@ A traffic file gives the mix as fractions of six op kinds (the mix of
 identities and the Zipf exponent of recipients. ``script()`` draws, from
 the seed alone, each op's kind, its asker and its draws — so every seed
 offers the same amount of every kind of work, and challenge signatures
-can be made before the window. Which message id a by-id op names is
-decided when the op is built, from what the engine has answered so far
-(``KnownIds``): ids are engine-private and exist only once a CREATE has
-succeeded.
+and every request that names no message id can be made before the
+window. Which message id a by-id op names is decided when the op is
+built, from what the engine has answered so far (``KnownIds``): ids are
+engine-private and exist only once a CREATE has succeeded.
 """
 
 from __future__ import annotations
@@ -153,18 +153,42 @@ class KnownIds:
         return None
 
 
-def build_request(entry, auth_item, known: KnownIds, pubs, rng, records):
-    """The program's ``QueryRequest`` for one script entry, signed by
-    ``auth_item``. ``records`` is the program's wire-record module
-    (``grapevine_tpu.wire.records``): the benchmark builds the objects
-    the scheduler takes and nothing else of it."""
+class Payloads:
+    """A seeded pool of payloads: op ``j`` of a script carries payload
+    ``j * K mod n`` (``K`` odd, ``n`` a power of two, so ``n`` ops in a
+    row carry ``n`` different ones). A million scripted ops share 15 MB
+    of payloads, and none is drawn inside the window."""
+
+    K = 2654435761
+
+    def __init__(self, seed: int, n: int = 1 << 14):
+        rng = random.Random(f"{seed}-payloads")
+        self.pool = [rng.randbytes(W.PAYLOAD_SIZE) for _ in range(n)]
+
+    def of(self, j: int) -> bytes:
+        return self.pool[(j * self.K) % len(self.pool)]
+
+
+def needs_answers(entry) -> bool:
+    """Whether the op names a message id, which exists only once a
+    CREATE has been answered: such an op is built inside the window,
+    every other one before it."""
+    return entry[0] not in ("create",) + _ZERO_ID_KINDS
+
+
+def build_request(entry, j: int, auth_item, known: KnownIds | None, pubs,
+                  payloads: Payloads, records):
+    """The program's ``QueryRequest`` for entry ``j`` of a script,
+    signed by ``auth_item``. ``records`` is the program's wire-record
+    module (``grapevine_tpu.wire.records``): the benchmark builds the
+    objects the scheduler takes and nothing else of it. ``known`` is
+    read only where ``needs_answers(entry)``."""
     kind, asker, rcp_draw, u = entry
     pub, _, _, sig = auth_item
     rec = {}
     if kind == "create":
         rt = W.CREATE
-        rec = {"recipient": pubs[rcp_draw],
-               "payload": rng.randbytes(W.PAYLOAD_SIZE)}
+        rec = {"recipient": pubs[rcp_draw], "payload": payloads.of(j)}
     elif kind == "read_next":
         rt = W.READ
     elif kind == "pop_next":
@@ -173,14 +197,14 @@ def build_request(entry, auth_item, known: KnownIds, pubs, rng, records):
         named = known.pick(asker, u)
         if named is None:
             # nothing known yet: a seeded id that names no record
-            named = (rng.randbytes(W.MSG_ID_SIZE), asker, rcp_draw)
+            named = (payloads.of(j)[:W.MSG_ID_SIZE], asker, rcp_draw)
         mid, _snd, rcp = named
         if kind == "read_id":
             rt, rec = W.READ, {"msg_id": mid}
         elif kind == "update":
             rt = W.UPDATE
             rec = {"msg_id": mid, "recipient": pubs[rcp],
-                   "payload": rng.randbytes(W.PAYLOAD_SIZE)}
+                   "payload": payloads.of(j)}
         else:
             rt, rec = W.DELETE, {"msg_id": mid, "recipient": pubs[rcp]}
     return records.QueryRequest(

@@ -49,6 +49,7 @@ HLO_STAT = "Hlo Proto"
 SCOPE_PREFIX = re.compile(r"^(.*grapevine/[A-Za-z0-9_]+)")
 #: a scope path that names no ``grapevine/`` scope (the empty one too)
 UNSCOPED = r"^(?!.*grapevine/)"
+_SCOPE_NAME = re.compile(r"grapevine/([A-Za-z0-9_]+)")
 
 
 # -- the protobuf wire format, as far as an XSpace needs it --------------
@@ -397,6 +398,30 @@ def scope_table(capture: dict, device: int = 0):
             path = paths[e[3]] if e[3] >= 0 else ""
             total[path] = total.get(path, 0.0) + ns
     return {p: ns / rounds / 1e6 for p, ns in total.items()}, rounds
+
+
+def scope_chain(path: str) -> str:
+    """A scope path's ``grapevine/`` names in order, repeats folded,
+    joined by ``/``: ``round_a_mailbox/oram_apply``; "" for none."""
+    names: list[str] = []
+    for name in _SCOPE_NAME.findall(path):
+        if name not in names:
+            names.append(name)
+    return "/".join(names)
+
+
+def op_scope_chains(capture: dict, device: int = 0) -> dict[str, str]:
+    """{``xplane.short_name`` of a device op: its scope chain}: what
+    lets the ten longest ops of a ``breakdown`` be read without the
+    capture. An op's name is its own in the compiled program, so it
+    has one path."""
+    for idx, plane in xplane.device_planes(capture):
+        if idx == device:
+            paths = capture["scope_paths"]
+            return {xplane.short_name(e[0]):
+                    scope_chain(paths[e[3]]) if e[3] >= 0 else ""
+                    for e in xplane.line_events(plane, xplane.OPS_LINE)}
+    return {}
 
 
 def idle_unattributed_ms(capture: dict, device: int = 0):

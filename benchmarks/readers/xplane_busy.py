@@ -5,7 +5,8 @@ round would need at the published HBM peak. ``params``: ``quantity`` is
 
 from __future__ import annotations
 
-from ..lib import peaks, round_bytes, xplane
+from ..lib import peaks, round_bytes, xplane, xplane_scopes
+from . import xplane_scope
 
 
 def device_busy(obs: dict):
@@ -20,7 +21,8 @@ def device_busy(obs: dict):
     if s is None:
         return None
     return {"busy_s": s["busy_s"], "window_s": s["window_s"],
-            "breakdown": s["breakdown"],
+            "breakdown": dict(s["breakdown"], device_ops=_named_by_scope(
+                obs, s["breakdown"]["device_ops"])),
             "summary": {"rounds_in_trace": s["rounds"],
                         "trace_period_ms": s["period_ms"],
                         "host_period_ms_on_trace_clock": s["host_period_ms"],
@@ -30,6 +32,18 @@ def device_busy(obs: dict):
                         "idle_share": 1.0 - s["busy_s"] / s["window_s"],
                         "busy_ms_per_round": s["busy_ms_per_round"],
                         "per_device": s["per_device"]}}
+
+
+def _named_by_scope(obs: dict, device_ops: list) -> list:
+    """Each op of the breakdown with the chain of program scopes it ran
+    under in front (``round_a_mailbox/oram_apply fusion.106
+    s32[507904]``), from the capture on disk; as it is without one."""
+    cap = xplane_scope.capture(obs) if "ctx" in obs else None
+    if cap is None:
+        return device_ops
+    chains = xplane_scopes.op_scope_chains(cap)
+    return [[f"{chains[name]} {name}"[:96] if chains.get(name) else name,
+             seconds] for name, seconds in device_ops]
 
 
 def read(params: dict, obs: dict):

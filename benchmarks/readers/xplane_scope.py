@@ -23,10 +23,10 @@ import re
 
 from ..lib import xplane_scopes
 
-_SCOPE = re.compile(r"grapevine/([A-Za-z0-9_]+)")
 
-
-def _capture(obs: dict):
+def capture(obs: dict):
+    """The run's capture as ``lib/xplane_scopes.py`` reads it, once a
+    run; None without one."""
     if "_scopes" not in obs:
         path = xplane_scopes.capture_file(obs["ctx"].scratch)
         obs["_scopes"] = xplane_scopes.read(path) if path else None
@@ -38,7 +38,7 @@ def _table(obs: dict, device: int):
     a run; the first reduction of device 0 says the table."""
     cache = obs.setdefault("_scope_tables", {})
     if device not in cache:
-        cache[device] = xplane_scopes.scope_table(_capture(obs), device)
+        cache[device] = xplane_scopes.scope_table(capture(obs), device)
         if device == 0 and cache[device] is not None:
             _say_table(obs, cache[device])
     return cache[device]
@@ -49,11 +49,7 @@ def _say_table(obs: dict, table) -> None:
     folded), largest first: PERF.md's scope table."""
     by_chain: dict[str, float] = {}
     for path, ms in table[0].items():
-        names = []
-        for name in _SCOPE.findall(path):
-            if name not in names:
-                names.append(name)
-        chain = "/".join(names) or "(no scope)"
+        chain = xplane_scopes.scope_chain(path) or "(no scope)"
         by_chain[chain] = by_chain.get(chain, 0.0) + ms
     top = sorted(by_chain.items(), key=lambda kv: -kv[1])
     spans: dict[str, int] = {}
@@ -68,8 +64,8 @@ def _say_table(obs: dict, table) -> None:
 def read(params: dict, obs: dict):
     if obs.get("trace") is None:
         return None
-    capture = _capture(obs)
-    if capture is None:
+    cap = capture(obs)
+    if cap is None:
         return None
     device = params.get("device", 0)
     q = params.get("quantity", "scope")
@@ -81,5 +77,5 @@ def read(params: dict, obs: dict):
                         else xplane_scopes.UNSCOPED)
         return sum(ms for path, ms in table[0].items() if rx.search(path))
     if q == "idle_unattributed":
-        return xplane_scopes.idle_unattributed_ms(capture, device)
+        return xplane_scopes.idle_unattributed_ms(cap, device)
     raise ValueError(f"xplane_scope reader: unknown quantity {q!r}")

@@ -56,6 +56,10 @@ def main() -> int:
                                   args.seconds, bool(args.trace),
                                   T_PROCESS_START, scratch)
     print(json.dumps(result), flush=True)
+    # the last lines of standard error: each number compared, its limit
+    for name, x in result["compared"].items():
+        print(f"compared {name} {x['value']} limit {x['limit']}",
+              file=sys.stderr)
     return 0
 
 

@@ -8,6 +8,7 @@ import os
 import pytest
 
 from benchmarks.lib import opmix, schedules
+from benchmarks.lib import wire as W
 from benchmarks.lib.manifest import HERE
 
 TRICKLE = json.load(open(os.path.join(HERE, "traffic", "trickle-grpc.json")))
@@ -82,3 +83,37 @@ def test_known_ids_learn_and_forget():
     known.learn([delete], [made])
     assert len(known.live) == 0 and len(known.mine[1]) == 0
     assert known.pick(3, 0.99)[0] == mid  # deleted a moment ago
+
+
+def test_payloads_come_from_a_seeded_pool_and_spread_over_it():
+    a, b = opmix.Payloads(5, 64), opmix.Payloads(5, 64)
+    assert a.pool == b.pool != opmix.Payloads(6, 64).pool
+    assert all(len(p) == W.PAYLOAD_SIZE for p in a.pool)
+    # any 64 ops in a row carry 64 different payloads
+    assert len({a.of(j) for j in range(1000, 1064)}) == 64
+    assert a.of(7) is a.of(7 + 64)  # shared, not copied
+
+
+def test_requests_that_name_no_id_are_built_without_answers():
+    from grapevine_tpu.wire import records as R
+
+    pubs = [i.to_bytes(2, "big") * 16 for i in range(BACKLOG["identities"])]
+    script = opmix.script(3, 400, BACKLOG)
+    payloads = opmix.Payloads(3, 64)
+    early = [e for e in script if not opmix.needs_answers(e)]
+    assert {e[0] for e in early} == {"create", "read_next", "pop_next"}
+    assert 0.5 < len(early) / len(script) < 0.7  # 60 % of the mix
+    item = (pubs[0], b"", b"", b"\x00" * 64)
+    known = opmix.KnownIds(pubs)
+    for j, e in enumerate(script):
+        if opmix.needs_answers(e):
+            with pytest.raises(AttributeError):  # it would ask ``known``
+                opmix.build_request(e, j, item, None, pubs, payloads, R)
+            req = opmix.build_request(e, j, item, known, pubs, payloads, R)
+            # nothing known yet: a seeded id that names no record
+            assert req.record.msg_id == payloads.of(j)[:W.MSG_ID_SIZE]
+        else:
+            req = opmix.build_request(e, j, item, None, pubs, payloads, R)
+            assert req.validate() is req
+            if e[0] == "create":
+                assert req.record.payload is payloads.of(j)

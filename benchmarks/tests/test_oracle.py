@@ -26,13 +26,15 @@ def _rounds(seed, n_rounds=80, batch=24):
     ref = ReferenceEngine(config=cfg, rng=random.Random(seed))
     pubs = [bytes([i + 1]) * 32 for i in range(TRAFFIC["identities"])]
     known = opmix.KnownIds(pubs)
-    rng = random.Random(seed)
+    payloads = opmix.Payloads(seed, 256)
     script = opmix.script(seed, n_rounds * batch, TRAFFIC)
     rounds = []
     for k in range(n_rounds):
         reqs = [opmix.build_request(
-            e, (pubs[e[1]], b"", b"", b"\x00" * 64), known, pubs, rng, R)
-            for e in script[k * batch:(k + 1) * batch]]
+            e, j, (pubs[e[1]], b"", b"", b"\x00" * 64), known, pubs,
+            payloads, R)
+            for j, e in enumerate(script[k * batch:(k + 1) * batch],
+                                  k * batch)]
         resps = ref.handle_batch(reqs, 1000 + k)
         known.learn(reqs, resps)
         rounds.append({"reqs": reqs, "now": 1000 + k, "resps": resps})

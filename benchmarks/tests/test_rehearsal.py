@@ -1,10 +1,13 @@
 """The harness itself, rehearsed on the CPU at a toy geometry (2^10
-messages, B=16; the sharded configuration on four virtual devices), and
-the self-test of ``correct``: the timed path broken underneath must come
+messages, B=16; the sharded configuration on four virtual devices), a
+third configuration added as files alone, and the self-test of
+``correct``: the timed path broken underneath must come
 out as not correct, through the same comparison code. ``run.py`` as a
 command still refuses anything but a TPU."""
 
+import json
 import os
+import shutil
 import subprocess
 import sys
 import time
@@ -12,8 +15,8 @@ import time
 import pytest
 
 from benchmarks.lib import harness
-from benchmarks.lib.manifest import ROOT, Benchmark
-from toy import toy_bench
+from benchmarks.lib.manifest import ROOT, Benchmark, ManifestError
+from toy import TOY_DATA, toy_bench
 
 pytestmark = pytest.mark.filterwarnings("ignore")
 
@@ -40,6 +43,9 @@ def test_every_cell_runs_and_is_correct(cell, trace, tmp_path):
     bench = toy_bench(tmp_path / "base")
     r = _run(cell, tmp_path, trace=trace)
     assert r["correct"] is True and r["failed"] == 0 and r["attempted"] > 0
+    # each number compared beside its limit, as the result's last key
+    assert list(r)[-1] == "compared" and len(r["compared"]) == 7
+    assert all(x == {"value": 0, "limit": 0} for x in r["compared"].values())
     group = "per_layer" if trace else "end_to_end"
     declared = {m["name"] for m in bench._metrics_for(group, cell)}
     got = set(r["metrics"])
@@ -54,6 +60,120 @@ def test_every_cell_runs_and_is_correct(cell, trace, tmp_path):
         assert got == declared
         assert all(v["value"] > 0 for v in r["metrics"].values())
     assert r["device"]["platform"] == "cpu"  # named, never a device metric
+
+
+def test_a_backlog_cell_accounts_for_its_loader_and_its_collections(
+        tmp_path, capsys):
+    """The loader's share of the interpreter lock and the collector's
+    pauses are part of every backlog run's account."""
+    r = _run("backlog-1chip", tmp_path, trace=True)
+    assert r["correct"] is True
+    submit = r["metrics"]["loadgen_submit_ms"]["value"]
+    own = r["metrics"]["loadgen_own_ms"]["value"]
+    assert submit > 0 and own > 0
+    lines = [json.loads(x) for x in capsys.readouterr().out.splitlines()]
+    samples = next(x for x in lines if x.get("phase") == "samples")
+    assert samples["presigned_script_reused"] == 0
+    waves = samples["loadgen_waves"]
+    assert waves >= samples["rounds_in_window"] - 1 > 0
+    # the metrics are the window's totals per wave
+    assert submit == pytest.approx(
+        1e3 * samples["loadgen_submit_cpu_s"] / waves)
+    assert own == pytest.approx(1e3 * samples["loadgen_own_cpu_s"] / waves)
+    # the log leaves the collector's sight wave by wave: one explicit
+    # generation-1 collection per wave at least, and full collections
+    # find nothing that has grown
+    assert samples["gc_collections_in_window"][1] >= waves
+    assert samples["gc_gen2_in_window"] <= 2
+    assert 0 <= samples["gc_gen2_pause_ms"] <= samples["gc_pause_ms"]
+    traffic = next(x for x in lines if x.get("phase") == "traffic")
+    assert 0.5 < traffic["prebuilt_ops"] / traffic["presigned_ops"] < 0.7
+    init = next(x for x in lines if x.get("phase") == "init")
+    assert init["geometry"]["trees"]["mailbox"]["rows_per_pass"] == 16
+
+
+#: what the next configuration, ``chipshare-2p20-r65536``, brings as
+#: its toy files: a mailbox tree taller than the batch covers (1,024
+#: recipients: 9 levels; 32 accesses a pass cover 6 of them)
+R65536 = "chipshare-2p20-r65536"
+R65536_MIX = "backlog-mixed-r32768"
+
+
+def _checkout_with_a_third_configuration(tmp_path) -> str:
+    """A copy of the benchmark's data files with one configuration, one
+    mix and one cell more, each a new file or a new entry."""
+    root = tmp_path / "checkout"
+    for sub in ("configs", "traffic", "layer_metrics",
+                os.path.join("tests", "data", "configs"),
+                os.path.join("tests", "data", "traffic")):
+        shutil.copytree(os.path.join(ROOT, "benchmarks", sub),
+                        root / "benchmarks" / sub)
+    before = {str(p): p.read_bytes() for p in root.rglob("*.json")}
+    bench = Benchmark.load()
+    manifest = json.loads(json.dumps(bench.manifest))
+    real = bench.config("chipshare-2p20")
+    real["name"] = R65536
+    real["grapevine_config"]["max_recipients"] = 1 << 16
+    real["guarantees"]["max_recipients"] = 1 << 16
+    (root / "benchmarks" / "configs" / f"{R65536}.json").write_text(
+        json.dumps(real))
+    mix = dict(bench.traffic("backlog-mixed"), name=R65536_MIX,
+               identities=1 << 15)
+    (root / "benchmarks" / "traffic" / f"{R65536_MIX}.json").write_text(
+        json.dumps(mix))
+    toy = json.load(open(os.path.join(ROOT, TOY_DATA, "configs",
+                                      "chipshare-2p20.json")))
+    toy["name"] = R65536
+    toy["grapevine_config"]["max_recipients"] = 1024
+    toy["guarantees"]["max_recipients"] = 1024
+    (root / TOY_DATA / "configs" / f"{R65536}.json").write_text(
+        json.dumps(toy))
+    toy_mix = dict(json.load(open(os.path.join(
+        ROOT, TOY_DATA, "traffic", "backlog-mixed.json"))),
+        name=R65536_MIX, identities=512)
+    (root / TOY_DATA / "traffic" / f"{R65536_MIX}.json").write_text(
+        json.dumps(toy_mix))
+    manifest["configs"].append(
+        {**manifest["configs"][0], "name": R65536,
+         "file": f"benchmarks/configs/{R65536}.json"})
+    manifest["workloads"].append(
+        {**bench.cell("backlog-1chip"), "name": "backlog-1chip-r65536",
+         "config": R65536, "traffic": R65536_MIX})
+    for group in ("end_to_end", "per_layer"):
+        for m in manifest[group]:
+            if "backlog-1chip" in m.get("workloads", []):
+                m["workloads"].append("backlog-1chip-r65536")
+    (root / "BENCHMARK.json").write_text(json.dumps(manifest))
+    after = {str(p): p.read_bytes() for p in root.rglob("*.json")}
+    assert all(after[p] == data for p, data in before.items()), \
+        "no file that was there is edited"
+    assert len(after) == len(before) + 5
+    return str(root)
+
+
+def test_a_third_configuration_comes_from_new_files_alone(tmp_path, capsys):
+    root = _checkout_with_a_third_configuration(tmp_path)
+    bench = toy_bench(tmp_path / "base", root)
+    bench.check_files()
+    r = harness.run_cell(bench, "backlog-1chip-r65536", 2**31 + 27, 2.0,
+                         True, time.perf_counter(), str(tmp_path))
+    assert r["correct"] is True and r["failed"] == 0 and r["attempted"] > 0
+    assert {"round_ms", "loadgen_own_ms", "host_floor_ms"} <= set(r["metrics"])
+    lines = [json.loads(x) for x in capsys.readouterr().out.splitlines()]
+    mailbox = next(x for x in lines if x.get("phase") == "init")[
+        "geometry"]["trees"]["mailbox"]
+    # dense levels 4 and 5 whole, levels 6 to 8 a row per access
+    assert (mailbox["path_len"], mailbox["accesses"]) == (9, 32)
+    assert mailbox["rows_per_pass"] == 16 + 32 + 3 * 32
+    # the cells that were there rehearse from the same checkout
+    assert toy_bench(tmp_path / "base", root).cell("backlog-1chip")
+
+
+def test_a_configuration_with_no_toy_geometry_is_refused_by_name(tmp_path):
+    root = _checkout_with_a_third_configuration(tmp_path)
+    os.remove(os.path.join(root, TOY_DATA, "configs", f"{R65536}.json"))
+    with pytest.raises(ManifestError, match=R65536):
+        toy_bench(tmp_path / "base", root)
 
 
 def _break_resolve(monkeypatch, tamper):
@@ -98,6 +218,7 @@ def test_a_broken_answer_is_not_correct(cell, tamper, tmp_path, monkeypatch):
     _break_resolve(monkeypatch, tamper)
     r = _run(cell, tmp_path)
     assert r["correct"] is False and r["failed"] >= 1
+    assert any(x["value"] > x["limit"] for x in r["compared"].values())
 
 
 def test_a_stash_overflow_is_not_correct(tmp_path, monkeypatch):

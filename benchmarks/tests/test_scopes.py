@@ -15,7 +15,8 @@ import pytest
 
 from benchmarks.lib import xplane, xplane_scopes
 from benchmarks.lib.manifest import Benchmark
-from benchmarks.readers import ledger_count, service_stage, xplane_scope
+from benchmarks.readers import (ledger_count, loadgen_cpu, service_stage,
+                                xplane_busy, xplane_scope)
 
 DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data")
 FUSION = "%fusion.1 = u32[8]{0} fusion(u32[8]{0} %p), kind=kLoop"
@@ -214,6 +215,63 @@ def test_service_stage_reader():
     assert service_stage.read(work, obs) == pytest.approx(1000.0)
     no_registry = {"ctx": types.SimpleNamespace(server=object())}
     assert service_stage.read(wake, no_registry) is None
+
+
+def test_loadgen_cpu_reader():
+    waves = [[0.010, 0.002], [0.012, 0.004], [0.030, 0.003]]
+    obs = {"observed": {"loadgen_cpu": waves}}
+    # means: the thread clock ticks in 10 ms steps on the chip's host
+    assert loadgen_cpu.read({"quantity": "submit_ms"}, obs) == \
+        pytest.approx(52.0 / 3)
+    assert loadgen_cpu.read({"quantity": "own_ms"}, obs) == pytest.approx(3.0)
+    # a cell loaded from client processes takes no such stamps
+    assert loadgen_cpu.read({"quantity": "own_ms"}, {"observed": {}}) is None
+    assert loadgen_cpu.read({"quantity": "own_ms"},
+                            {"observed": {"loadgen_cpu": []}}) is None
+    with pytest.raises(ValueError):
+        loadgen_cpu.read({"quantity": "wall_ms"}, obs)
+
+
+def test_scope_chains_by_hand():
+    chain = xplane_scopes.scope_chain
+    assert chain("jit(engine_round_step)/grapevine/round_a_mailbox/"
+                 "grapevine/oram_apply/gather:") == "round_a_mailbox/oram_apply"
+    # a scope entered twice (a callback traced inside itself) folds
+    assert chain("jit(f)/grapevine/round_b_records/grapevine/oram_fetch/"
+                 "grapevine/oram_fetch/grapevine/path_gather") == \
+        "round_b_records/oram_fetch/path_gather"
+    assert chain("jit(f)/mul") == "" and chain("") == ""
+    fusion2 = FUSION.replace("fusion.1 ", "fusion.2 ")
+    capture = _capture(
+        [[FUSION, 110.0, 10.0, 0], [fusion2, 130.0, 10.0, -1]],
+        paths=["jit(f)/grapevine/round_c_mailbox/grapevine/oram_evict/sort"])
+    assert xplane_scopes.op_scope_chains(capture) == {
+        "fusion.1 u32[8]": "round_c_mailbox/oram_evict", "fusion.2 u32[8]": ""}
+    assert xplane_scopes.op_scope_chains(capture, device=3) == {}
+
+
+def test_the_breakdown_names_an_op_with_its_scope_chain(recorded):
+    trace = {"planes": [{"name": p["name"], "lines": [
+        {"name": ln["name"], "events": [e[:3] for e in ln["events"]]}
+        for ln in p["lines"]]} for p in recorded["planes"]]}
+    obs = {"trace": trace, "ctx": types.SimpleNamespace(scratch="/nowhere"),
+           "_scopes": recorded}
+    ops = xplane_busy.device_busy(obs)["breakdown"]["device_ops"]
+    assert len(ops) == 10
+    chains = xplane_scopes.op_scope_chains(recorded)
+    for name, seconds in ops:
+        scope, _, op = name.rpartition(" ")[0].rpartition(" ")
+        assert seconds > 0 and re.match(r"^[a-z_.\-0-9]+$", op), name
+        assert scope.split("/")[0] in (
+            "round_a_mailbox", "round_b_records", "round_c_mailbox"), name
+        assert chains[name[len(scope) + 1:]] == scope
+    assert ops[0][0].startswith("round_")
+    # without a capture the names stay as the trace has them
+    bare = xplane_busy.device_busy({"trace": trace})
+    assert [s for _, s in bare["breakdown"]["device_ops"]] == [
+        s for _, s in ops]
+    assert not any(n.startswith("round_")
+                   for n, _ in bare["breakdown"]["device_ops"])
 
 
 # -- the recorded capture ------------------------------------------------

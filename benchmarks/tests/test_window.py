@@ -2,6 +2,7 @@
 driver closes its window at the first answers to arrive at or after
 ``--seconds``, so a stall that straddles ``--seconds`` is inside it."""
 
+import gc
 import threading
 import time
 import types
@@ -15,10 +16,11 @@ def _drive(monkeypatch, seconds, resolve_at):
     """``run`` over a fake engine whose rounds of 4 ops resolve at the
     offsets ``resolve_at`` (seconds from the window's opening)."""
     monkeypatch.setattr(scheduler_backlog, "_build", lambda state, upto: None)
-    monkeypatch.setattr(scheduler_backlog, "_submit", lambda ctx, state, n: None)
+    monkeypatch.setattr(scheduler_backlog, "_submit",
+                        lambda ctx, state, n: 0.0)
     log = types.SimpleNamespace(on_resolved=None, entries=[])
     ctx = types.SimpleNamespace(log=log, seconds=seconds)
-    state = {"bs": 4, "outstanding": 16,
+    state = {"bs": 4, "outstanding": 16, "cpu": [],
              "known": types.SimpleNamespace(learn=lambda reqs, resps: None)}
     t_open = time.perf_counter()
 
@@ -33,8 +35,14 @@ def _drive(monkeypatch, seconds, resolve_at):
 
     feeder = threading.Thread(target=engine, daemon=True)
     feeder.start()
-    t_end = scheduler_backlog.run(ctx, state, t_open)
+    try:
+        t_end = scheduler_backlog.run(ctx, state, t_open)
+    finally:
+        gc.unfreeze()  # run() freezes its log wave by wave
     feeder.join()
+    # one pair of CPU stamps per wave: every round but the closing one
+    assert len(state["cpu"]) == sum(
+        e["t_resolved"] < t_open + seconds for e in log.entries)
     obs = {"window": (t_open, t_end),
            "rounds": [e for e in log.entries
                       if t_open <= e["t_resolved"] <= t_end]}

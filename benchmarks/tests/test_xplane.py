@@ -125,13 +125,14 @@ def test_recorded_op_name_match_per_round(recorded):
 
 def test_readers_turn_busy_time_into_metrics(recorded):
     geometry = {"shards": 1, "trees": {"t": {
-        "accesses": 2048, "path_len": 20, "cached_levels": 4,
+        "accesses": 2048, "passes": 1, "path_len": 20, "cached_levels": 4,
         "bucket_slots": 4, "value_words": 256, "encrypted": True}}}
     obs = {"trace": recorded, "geometry": geometry,
            "device_kind": "TPU v5 lite"}
     ms = xplane_busy.read({"quantity": "busy_ms_per_round"}, obs)
     assert ms == pytest.approx(354.5362345)
-    least = 2 * 2048 * 16 * 1030 * 4  # read + written
+    # levels 4..10 whole (2,032 buckets), 11..19 a row per access
+    least = 2 * (2032 + 9 * 2048) * 1030 * 4  # read + written
     share = xplane_busy.read({"quantity": "hbm_roofline_pct"}, obs)
     assert share == pytest.approx(100 * least / 819e9 * 1e3 / ms)
     assert 0 < share < 100

@@ -1,6 +1,12 @@
-"""The real BENCHMARK.json with its configurations swapped for toy
+"""A checkout's BENCHMARK.json with its configurations swapped for toy
 geometries and its traffic read from tests/data: a rehearsal of the
-harness itself on the CPU."""
+harness itself on the CPU.
+
+Both are found by name, as the harness finds the real ones: a
+configuration's toy geometry is ``tests/data/configs/<configuration
+name>.json`` and a mix's toy parameters ``tests/data/traffic/<mix
+name>.json``. So a PR that adds a configuration or a mix adds its toy
+file beside it and edits nothing here."""
 
 from __future__ import annotations
 
@@ -8,22 +14,25 @@ import copy
 import os
 import shutil
 
-from benchmarks.lib.manifest import HERE, ROOT, Benchmark
+from benchmarks.lib.manifest import ROOT, Benchmark, ManifestError
 
-DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data")
-TOY_CONFIG = {"chipshare-2p20": "toy-1dev", "host4-sharded-2p22": "toy-4dev"}
+TOY_DATA = os.path.join("benchmarks", "tests", "data")
 
 
-def toy_bench(base) -> Benchmark:
-    """``base`` (a fresh directory) gets the toy traffic files and the
-    real per-layer metric files."""
-    manifest = copy.deepcopy(Benchmark.load().manifest)
+def toy_bench(base, root: str = ROOT) -> Benchmark:
+    """The benchmark of the checkout at ``root`` at toy size. ``base``
+    (a fresh directory) gets the toy traffic files and the real
+    per-layer metric files."""
+    manifest = copy.deepcopy(Benchmark.load(root).manifest)
     for c in manifest["configs"]:
-        c["file"] = os.path.join("benchmarks", "tests", "data", "configs",
-                                 TOY_CONFIG[c["name"]] + ".json")
+        c["file"] = os.path.join(TOY_DATA, "configs", c["name"] + ".json")
+        if not os.path.isfile(os.path.join(root, c["file"])):
+            raise ManifestError(
+                f"configuration {c['name']!r} has no toy geometry for the "
+                f"CPU rehearsal: add {c['file']}")
     if not os.path.isdir(os.path.join(base, "traffic")):
-        shutil.copytree(os.path.join(DATA, "traffic"),
+        shutil.copytree(os.path.join(root, TOY_DATA, "traffic"),
                         os.path.join(base, "traffic"))
-        shutil.copytree(os.path.join(HERE, "layer_metrics"),
+        shutil.copytree(os.path.join(root, "benchmarks", "layer_metrics"),
                         os.path.join(base, "layer_metrics"))
-    return Benchmark(manifest, ROOT, str(base))
+    return Benchmark(manifest, root, str(base))
