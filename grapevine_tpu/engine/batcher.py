@@ -431,8 +431,8 @@ class GrapevineEngine:
         layout = self.round_layout()
         self.metrics.set_round_layout(layout)
         _log.info(
-            "round layout (dense_levels, fetched_bucket_rows per "
-            "oram_round): %s",
+            "round layout (dense_levels, fetched_bucket_rows, "
+            "perpath_bucket_rows per oram_round): %s",
             ", ".join(f"{t}={v}" for t, v in layout.items()),
         )
         #: last sampled per-tree eviction-buffer occupancy (health view)
@@ -910,8 +910,8 @@ class GrapevineEngine:
                 else {}
             )
             self._ebuf_counts = ebuf
-        for n in counts.values():
-            self.metrics.observe_stash(n)
+        for name, n in counts.items():
+            self.metrics.observe_stash(name, n)
         if ebuf:
             # the buffer-occupancy canary (grapevine_evict_buffer_*):
             # summed over trees at scrape cadence, high-water kept —
@@ -921,12 +921,14 @@ class GrapevineEngine:
         return counts
 
     def round_layout(self) -> dict:
-        """``{tree: (dense_levels, fetched_bucket_rows)}`` of one
-        ``oram_round`` on each tree at this engine's geometry: the
-        records round makes B accesses, a mailbox round B·D."""
+        """``{tree: (dense_levels, fetched_bucket_rows,
+        perpath_bucket_rows)}`` of one ``oram_round`` on each tree at
+        this engine's geometry: the records round makes B accesses, a
+        mailbox round B·D."""
         b, d = self.ecfg.batch_size, self.ecfg.mb_choices
         return {
-            tree: (cfg.dense_levels(n), cfg.fetched_bucket_rows(n))
+            tree: (cfg.dense_levels(n), cfg.fetched_bucket_rows(n),
+                   cfg.perpath_bucket_rows(n))
             for tree, cfg, n in (("rec", self.ecfg.rec, b),
                                  ("mb", self.ecfg.mb, b * d))
         }

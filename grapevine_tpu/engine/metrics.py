@@ -93,10 +93,16 @@ class EngineMetrics:
         self._g_qdepth_hw = r.gauge(
             "grapevine_queue_depth_high_water",
             "max scheduler queue depth observed")
+        # per tree: with per-path levels under the dense ones the mailbox
+        # stash is the first thing an eviction fault would fill, and one
+        # gauge over both trees would hide it behind the records tree's
+        stashes = {"tree": ("rec", "mb", "rec_pm", "mb_pm")}
         self._g_stash_hw = r.gauge(
             "grapevine_stash_high_water",
             "max sampled ORAM stash occupancy (must stay far below "
-            "stash_size; overflow means the eviction invariant broke)")
+            "stash_size; overflow means the eviction invariant broke); "
+            "rec_pm / mb_pm are a recursive position map's inner trees",
+            labels=stashes)
         self._g_ebuf = r.gauge(
             "grapevine_evict_buffer_occupancy",
             "sampled delayed-eviction buffer occupancy, summed over "
@@ -120,6 +126,11 @@ class EngineMetrics:
             "HBM bucket rows one oram_round gathers and decrypts (and "
             "writes back when it evicts): the dense range under the "
             "cache plus one row per path and deeper level", labels=trees)
+        self._g_perpath = r.gauge(
+            "grapevine_round_perpath_bucket_rows",
+            "of those rows, the ones at levels the batch does not cover "
+            "(one per path and level below the dense ones): 0 says the "
+            "round moves its tree whole, level by level", labels=trees)
         self._h_phase = r.histogram(
             "grapevine_phase_seconds",
             "wall time per round phase (batch-level; obs/phases.py)",
@@ -130,7 +141,8 @@ class EngineMetrics:
             buckets=PHASE_BUCKETS)
         self._h_stash = r.histogram(
             "grapevine_stash_occupancy",
-            "sampled stash occupancy (entries)", buckets=STASH_BUCKETS)
+            "sampled stash occupancy (entries)", buckets=STASH_BUCKETS,
+            labels=stashes)
 
     # -- recording ------------------------------------------------------
 
@@ -148,11 +160,13 @@ class EngineMetrics:
         self._h_round.observe(seconds)
 
     def set_round_layout(self, layout: dict) -> None:
-        """``{tree: (dense_levels, fetched_bucket_rows)}`` of one
-        ``oram_round`` per tree, from the resolved geometry."""
-        for tree, (dense, rows) in layout.items():
+        """``{tree: (dense_levels, fetched_bucket_rows,
+        perpath_bucket_rows)}`` of one ``oram_round`` per tree, from the
+        resolved geometry."""
+        for tree, (dense, rows, perpath) in layout.items():
             self._g_dense.set(dense, tree=tree)
             self._g_rows.set(rows, tree=tree)
+            self._g_perpath.set(perpath, tree=tree)
 
     def record_sweep(self, evicted: int) -> None:
         self._c_sweeps.inc()
@@ -166,9 +180,9 @@ class EngineMetrics:
         if failures:
             self._c_authfail.inc(failures)
 
-    def observe_stash(self, occupancy: int) -> None:
-        self._g_stash_hw.set_max(occupancy)
-        self._h_stash.observe(occupancy)
+    def observe_stash(self, tree: str, occupancy: int) -> None:
+        self._g_stash_hw.set_max(occupancy, tree=tree)
+        self._h_stash.observe(occupancy, tree=tree)
 
     def observe_evict_buffer(self, occupancy: int) -> None:
         """Sampled delayed-eviction buffer occupancy (rows, summed over
@@ -214,7 +228,8 @@ class EngineMetrics:
 
     @property
     def stash_high_water(self) -> int:
-        return int(self._g_stash_hw.get())
+        """The largest of the per-tree high-water marks."""
+        return int(max(c.value for _, c in self._g_stash_hw.series()))
 
     # -- export ---------------------------------------------------------
 
@@ -234,7 +249,7 @@ class EngineMetrics:
             "evicted": int(self._c_evicted.get()),
             "batch_verifies": int(self._c_verifies.get()),
             "auth_failures": int(self._c_authfail.get()),
-            "stash_high_water": int(self._g_stash_hw.get()),
+            "stash_high_water": self.stash_high_water,
             "underfull_rounds": int(self._c_underfull.get()),
             "collector_stalls": int(self._c_stalls.get()),
             "queue_depth": int(self._g_qdepth.get()),

@@ -387,7 +387,13 @@ class OramConfig:
         levels. A function of shapes only."""
         ld = self.dense_levels(accesses)
         return ((1 << ld) - (1 << self.top_cache_levels)
-                + accesses * (self.path_len - ld))
+                + self.perpath_bucket_rows(accesses))
+
+    def perpath_bucket_rows(self, accesses: int) -> int:
+        """Of :meth:`fetched_bucket_rows`, the rows at the levels the
+        round does not hold dense: one per path and deeper level. 0 says
+        the round moves the tree whole, level by level."""
+        return accesses * (self.path_len - self.dense_levels(accesses))
 
 
 class OramState(NamedTuple):
@@ -400,8 +406,17 @@ class OramState(NamedTuple):
     slices dominate the round; a fully packed ``[n, Z*(2+V)]`` row
     (1028 words) is not lane-aligned, padding every row to 1152 words
     and again relayout-copying the tree. The split below keeps the
-    value rows exactly ``Z*V`` words (1020 rec / 4096 mb — tile-clean)
-    and the slot metadata 1-D, which XLA never transposes.
+    value rows exactly ``Z*V`` words and the slot metadata 1-D, which
+    XLA never transposes. Tile-clean rows matter still: the records
+    tree's 1024 words are, the mailbox tree's 6080 (Z=4 x 1520) are
+    47.5 lanes of 128, so a v5e's DEFAULT layout for that plane is the
+    transposed ``{0,1}`` and each round copies it whole after its entry
+    and before its exit (``copy.1913``; 2 x 2.4 ms at 2^16 recipients,
+    my chip run, PR 28). Pinning ``{1,0}`` through jit's in/out
+    ``Format`` removes both copies on a cold compile, but an executable
+    that jax 0.9.0 loads from its persistent cache returns default
+    layouts again, so the pin cannot ship; what can is a row padded to
+    a multiple of 128 words (ROADMAP Speed 15).
     """
 
     tree_idx: jax.Array  # u32[n_buckets * Z] flat; SENTINEL = empty slot

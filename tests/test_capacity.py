@@ -80,6 +80,56 @@ def test_pod_capacity_geometry():
     assert ecfg.rec.n_buckets * ecfg.rec.bucket_slots >= 1 << 24
 
 
+def test_the_2p16_recipient_deployment_resolves_to_what_its_file_says():
+    """``benchmarks/configs/chipshare-2p20-r2p16.json`` (the bus whose
+    mailboxes can fill its message store) states what its
+    ``grapevine_config`` resolves to; shapes only, no tree allocated:
+    a mailbox tree of 15 levels of which 4,096 accesses a pass cover
+    13, so 8,192 of its 16,368 rows a pass are per-path rows."""
+    import json
+
+    import jax
+
+    from grapevine_tpu.engine.state import init_engine
+
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks", "configs",
+        "chipshare-2p20-r2p16.json")
+    with open(path) as f:
+        spec = json.load(f)
+    assert spec["grapevine_config"] == {
+        "max_messages": 1 << 20, "max_recipients": 1 << 16,
+        "batch_size": 2048, "tree_density": 2}
+    cfg = GrapevineConfig(**spec["grapevine_config"])
+    ecfg = EngineConfig.from_config(cfg)
+    said = spec["resolves_to"]
+    for tree, oram, accesses in (
+            ("records", ecfg.rec, cfg.batch_size),
+            ("mailbox", ecfg.mb, cfg.batch_size * ecfg.mb_choices)):
+        want = said[tree]
+        assert oram.path_len == want["path_len"]
+        assert oram.dense_levels(accesses) == want["dense_levels"]
+        assert oram.fetched_bucket_rows(accesses) == want["fetched_bucket_rows"]
+        assert oram.perpath_bucket_rows(accesses) == want["perpath_bucket_rows"]
+    mb, want = ecfg.mb, said["mailbox"]
+    assert (mb.path_len, mb.dense_levels(4096)) == (15, 13)
+    assert mb.n_buckets == want["buckets"] == 32767
+    assert ecfg.mb_table_buckets == 1 << 15 and mb.leaves == 1 << 14
+    assert want["accesses_per_pass"] == 4096 < mb.leaves
+    # index row, value row and nonce of one bucket at rest
+    assert 4 * (mb.row_words + 2) == want["bucket_bytes"] == 24344
+    state = jax.eval_shape(lambda: init_engine(ecfg, 0))
+    assert sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(state)) \
+        == said["state_bytes"] == 5_127_466_624
+    assert cfg.mailbox_cap == spec["guarantees"]["mailbox_cap"] == 62
+    assert spec["guarantees"]["max_recipients"] == cfg.max_recipients
+    # its parent's mailbox tree is one the batch covers whole
+    parent = EngineConfig.from_config(GrapevineConfig(**dict(
+        spec["grapevine_config"], max_recipients=1 << 12)))
+    assert parent.mb.perpath_bucket_rows(4096) == 0
+    assert parent.mb.fetched_bucket_rows(4096) == 2032
+
+
 def test_init_sharded_engine_matches_staged_init():
     """Shard-aware init is bit-identical to init-then-shard (threefry is
     deterministic under jit), at a shape small enough to stage both."""

@@ -29,8 +29,8 @@ def test_metrics_ring_and_percentiles():
         m.record_round(n_real=3, batch_size=4, seconds=0.001 * (i + 1))
     m.record_sweep(5)
     m.record_auth(failures=2)
-    m.observe_stash(17)
-    m.observe_stash(9)  # high-water keeps the max
+    m.observe_stash("rec", 17)
+    m.observe_stash("rec", 9)  # high-water keeps the max
     s = m.snapshot()
     assert s["rounds"] == 20
     assert s["real_ops"] == 60
@@ -59,7 +59,7 @@ def test_concurrent_recording_is_lossless():
         for i in range(per):
             m.record_round(n_real=1, batch_size=2, seconds=0.002)
             m.record_auth(failures=1)
-            m.observe_stash(i % 50)
+            m.observe_stash("mb", i % 50)
             m.observe_phase("verify", 0.0005)
             m.observe_queue_depth(i % 7)
             m.record_sweep(2)
@@ -85,7 +85,8 @@ def test_concurrent_recording_is_lossless():
     assert s["round_ms_p50"] == 2.0 and s["round_ms_p99"] == 2.0
     # histogram totals are exact too
     assert s["grapevine_phase_seconds{phase=verify}_count"] == total
-    assert s["grapevine_stash_occupancy_count"] == total
+    assert s["grapevine_stash_occupancy{tree=mb}_count"] == total
+    assert s["grapevine_stash_occupancy{tree=rec}_count"] == 0
     # and the hammered registry still audits clean
     assert m.registry.audit()["ok"]
 
@@ -154,15 +155,20 @@ def test_round_layout_gauges_say_what_the_shapes_resolve_to():
                                   mb.path_len) == mb.path_len
     assert rec.path_len > layout["rec"][0]  # per-path levels remain
     for tree, c, n in (("rec", rec, b), ("mb", mb, bd)):
-        ld, rows = layout[tree]
-        assert rows == ((1 << ld) - (1 << c.top_cache_levels)
-                        + n * (c.path_len - ld))
+        ld, rows, perpath = layout[tree]
+        assert perpath == n * (c.path_len - ld)
+        assert rows == (1 << ld) - (1 << c.top_cache_levels) + perpath
         assert rows <= n * (c.path_len - c.top_cache_levels)
+    # the number that says which kind of round a tree gets: the mailbox
+    # tree is moved whole, the records tree keeps a row per path below
+    assert layout["mb"][2] == 0 < layout["rec"][2]
     text = render_prometheus(e.metrics.registry)
-    for tree, (ld, rows) in layout.items():
+    for tree, (ld, rows, perpath) in layout.items():
         assert f'grapevine_round_dense_levels{{tree="{tree}"}} {ld}' in text
         assert (f'grapevine_round_fetched_bucket_rows{{tree="{tree}"}} '
                 f'{rows}') in text
+        assert (f'grapevine_round_perpath_bucket_rows{{tree="{tree}"}} '
+                f'{perpath}') in text
     # the delayed-eviction fetch round stays per-path: only the cache
     import dataclasses
 
@@ -170,3 +176,67 @@ def test_round_layout_gauges_say_what_the_shapes_resolve_to():
                             evict_buffer_slots=64)
     assert d.dense_levels(b) == d.top_cache_levels
     assert d.fetched_bucket_rows(b) == b * (d.path_len - d.top_cache_levels)
+
+
+def test_round_layout_of_a_mailbox_tree_taller_than_the_batch_covers(caplog):
+    """Dense levels above, per-path levels below, in BOTH trees (the
+    shape of 2^16 recipients at B=2048, here 512 recipients at B=8):
+    ``grapevine_round_perpath_bucket_rows`` is what tells it from a
+    geometry whose mailbox round moves its tree whole, on the registry
+    and in the start-up log."""
+    import logging
+
+    from grapevine_tpu.obs.exporter import render_prometheus
+
+    cfg = GrapevineConfig(
+        bucket_cipher_rounds=0, max_messages=1024, max_recipients=512,
+        mailbox_cap=4, batch_size=8, stash_size=128,
+    )
+    with caplog.at_level(logging.INFO, logger="grapevine_tpu.engine.batcher"):
+        e = GrapevineEngine(cfg, seed=1)
+    mb, bd = e.ecfg.mb, 8 * e.ecfg.mb_choices
+    ld, rows, perpath = e.round_layout()["mb"]
+    assert mb.path_len - ld >= 2  # two per-path levels at least
+    assert perpath == bd * (mb.path_len - ld) > 0
+    assert perpath == mb.perpath_bucket_rows(bd) < rows
+    text = render_prometheus(e.metrics.registry)
+    assert f'grapevine_round_perpath_bucket_rows{{tree="mb"}} {perpath}' in text
+    assert f"mb=({ld}, {rows}, {perpath})" in caplog.text
+    assert e.metrics.registry.audit()["ok"]
+
+
+def test_stash_gauges_are_kept_per_tree():
+    """One gauge over both trees would hide a filling mailbox stash
+    behind the records tree's: the high-water mark and the occupancy
+    histogram carry the ``tree`` label, ``health()`` samples each tree
+    under its own, and the flat ``stash_high_water`` of the snapshot is
+    the largest of them."""
+    from grapevine_tpu.obs.exporter import render_prometheus
+
+    m = EngineMetrics()
+    m.observe_stash("rec", 3)
+    m.observe_stash("mb", 41)
+    m.observe_stash("mb", 12)
+    hw = m.registry.get("grapevine_stash_high_water")
+    assert hw.label_keys == ("tree",)
+    assert hw.get(tree="rec") == 3 and hw.get(tree="mb") == 41
+    assert m.stash_high_water == 41 == m.snapshot()["stash_high_water"]
+    text = render_prometheus(m.registry)
+    assert 'grapevine_stash_high_water{tree="mb"} 41' in text
+    assert 'grapevine_stash_occupancy_count{tree="mb"} 2' in text
+    assert 'grapevine_stash_occupancy_count{tree="rec"} 1' in text
+
+    cfg = GrapevineConfig(
+        bucket_cipher_rounds=0, max_messages=64, max_recipients=16,
+        mailbox_cap=4, batch_size=4, stash_size=96,
+    )
+    e = GrapevineEngine(cfg, seed=1)
+    a, b = bytes([1]) * 32, bytes([2]) * 32
+    e.handle_queries([_req(C.REQUEST_TYPE_CREATE, a, recipient=b)] * 2, NOW)
+    h = e.health()
+    assert set(h["stash_occupancy"]) == {"rec", "mb"}
+    snap = e.metrics.registry.snapshot()
+    for tree, n in h["stash_occupancy"].items():
+        assert snap[f"grapevine_stash_high_water{{tree={tree}}}"] == n
+        assert snap[f"grapevine_stash_occupancy{{tree={tree}}}_count"] == 1
+    assert snap["grapevine_stash_occupancy{tree=mb_pm}_count"] == 0

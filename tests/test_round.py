@@ -326,7 +326,7 @@ SMALL = GrapevineConfig(bucket_cipher_rounds=0,
 
 
 def key(n: int) -> bytes:
-    return bytes([n, n ^ 0x5A]) + b"\x01" * 30
+    return bytes([n & 0xFF, (n & 0xFF) ^ 0x5A, 1 + (n >> 8)]) + b"\x01" * 29
 
 
 def req(rt, auth, msg_id=C.ZERO_MSG_ID, recipient=C.ZERO_PUBKEY, pl=None, tag=0):
@@ -388,11 +388,51 @@ def test_round_engine_matches_batch_oracle_density4():
     assert engine.recipient_count() == oracle.recipient_count() == 0
 
 
-def _run_engine_vs_oracle(cfg, n_steps):
+#: the shape of ``chipshare-2p20-r2p16`` (benchmarks/configs) at a size
+#: the CPU holds: a mailbox tree taller than the batch covers, so a
+#: mailbox round keeps per-path levels under its dense ones
+TALL_MAILBOX = GrapevineConfig(bucket_cipher_rounds=0,
+    max_messages=1024,
+    max_recipients=512,
+    mailbox_cap=4,
+    batch_size=8,
+    stash_size=128,
+)
+
+
+def _run_tall_mailbox_campaign(cfg):
+    from grapevine_tpu.engine.state import EngineConfig
+
+    mb = EngineConfig.from_config(cfg).mb
+    accesses = cfg.batch_size * cfg.resolved_mailbox_choices
+    assert mb.path_len - mb.dense_levels(accesses) >= 2
+    assert accesses < mb.leaves  # the round's paths do not all collide
+    # hundreds of identities: the mailbox paths of a round differ, the
+    # per-path levels evict for real and the mailbox stash takes spill
+    engine, oracle, _ = _run_engine_vs_oracle(cfg, n_steps=24, n_idents=300)
+    assert engine.recipient_count() == oracle.recipient_count() > 40
+
+
+def test_round_engine_matches_batch_oracle_on_a_tall_mailbox_tree():
+    """Per-path levels under dense ones in the MAILBOX tree (every other
+    campaign here covers its mailbox tree whole): randomized CRUD over
+    some hundreds of mailboxes must stay oracle-identical."""
+    _run_tall_mailbox_campaign(TALL_MAILBOX)
+
+
+@pytest.mark.slow  # the cipher-on twin, as its sibling above is
+def test_round_engine_matches_batch_oracle_on_a_tall_mailbox_tree_with_cipher():
+    import dataclasses
+
+    _run_tall_mailbox_campaign(
+        dataclasses.replace(TALL_MAILBOX, bucket_cipher_rounds=8))
+
+
+def _run_engine_vs_oracle(cfg, n_steps, n_idents=5):
     engine = GrapevineEngine(cfg, seed=3)
     oracle = ReferenceEngine(config=cfg, rng=random.Random(99))
     rng = random.Random(1234)
-    idents = [key(i + 1) for i in range(5)]
+    idents = [key(i + 1) for i in range(n_idents)]
     live_ids: list[tuple[bytes, bytes, bytes]] = []
 
     t = NOW
