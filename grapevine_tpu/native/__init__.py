@@ -10,13 +10,16 @@ speed without a word (:func:`log_state`).
 
 Thread-safety contract, per wrapper class:
 
-- group/MSM wrappers (verify1, batch_check, reencode, mult_base) hold
-  the module lock because their C functions use static scratch buffers
-  (they are called from the scheduler's single collector thread anyway);
-- the STROBE/merlin/keccak wrappers are deliberately LOCK-FREE and in
-  exchange their C functions must never use static scratch — they touch
-  only the caller's buffers, because gRPC worker threads run them
-  concurrently on distinct transcripts (one per in-flight signature).
+- the single-item group wrappers (verify1, reencode, mult_base) hold
+  the module lock because their C functions use static scratch (the
+  decoded-key cache): signing and the bisect's leaves, never a round's
+  critical path;
+- chunk_check and the STROBE/merlin/keccak wrappers are deliberately
+  LOCK-FREE and in exchange their C functions must never write static
+  scratch — they touch only the caller's buffers and their own heap
+  arena, because a round's chunk checks run on several threads at once
+  (inside one call, for which ctypes releases the GIL) and gRPC worker
+  threads run transcripts concurrently.
 """
 
 from __future__ import annotations
@@ -25,6 +28,7 @@ import ctypes
 import os
 import subprocess
 import threading
+from array import array
 from pathlib import Path
 
 _DIR = Path(__file__).parent
@@ -42,13 +46,14 @@ class _NativeUnavailable(Exception):
     """The library could not be built; the message is ``load_error``."""
 
 
-def _build() -> Path:
+def _build(force: bool = False) -> Path:
     try:
-        if _SO.exists() and _SO.stat().st_mtime >= _SRC.stat().st_mtime:
+        fresh = _SO.exists() and _SO.stat().st_mtime >= _SRC.stat().st_mtime
+        if fresh and not force:
             return _SO
     except OSError:
         # a cached .so without the C source: use it
-        if _SO.exists():
+        if _SO.exists() and not force:
             return _SO
         raise _NativeUnavailable(f"neither {_SRC.name} nor {_SO.name} found")
     # compile to a private temp file, then atomically rename: concurrent
@@ -56,7 +61,8 @@ def _build() -> Path:
     # half-written .so or have a mapped one rewritten under them
     tmp = _DIR / f"_r255.{os.getpid()}.tmp.so"
     cc = os.environ.get("CC", "cc")
-    cmd = [cc, "-O2", "-shared", "-fPIC", "-o", str(tmp), str(_SRC)]
+    cmd = [cc, "-O2", "-shared", "-fPIC", "-pthread", "-o", str(tmp),
+           str(_SRC)]
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
         os.replace(tmp, _SO)
@@ -70,20 +76,39 @@ def _build() -> Path:
     return _SO
 
 
+def _open(so: Path):
+    """Bind the library at ``so``. A library that lacks an export is
+    unmapped again before AttributeError goes on: the dynamic loader
+    finds a mapped library by its NAME, so the rebuilt file would
+    otherwise never be read."""
+    handle = ctypes.CDLL(str(so))
+    try:
+        return _bind(handle)
+    except AttributeError:
+        import _ctypes
+
+        _ctypes.dlclose(handle._handle)
+        raise
+
+
 def _load():
     global load_error
     load_error = None
     try:
-        so = _build()
-        bound = _bind(ctypes.CDLL(str(so)))
+        try:
+            bound = _open(_build())
+        except AttributeError:
+            # a cached .so newer than the source it was NOT built from
+            # (missing a newer export): rebuild once. Degrading here
+            # would serve sr25519 on the pure-Python path, seconds a
+            # round where the native check takes milliseconds.
+            bound = _open(_build(force=True))
     except _NativeUnavailable as e:
         load_error = str(e)
     except OSError as e:
         load_error = f"loading {_SO.name} failed: {e}"
     except AttributeError as e:
-        # a cached .so built from older source (missing a newer export):
-        # degrade rather than failing the package import
-        load_error = f"{_SO.name} is stale (missing export: {e})"
+        load_error = f"{_SO.name} is stale after a rebuild (missing export: {e})"
     else:
         if bound is not None:
             return bound
@@ -109,8 +134,14 @@ def _bind(handle):
     handle.r255_init.restype = ctypes.c_int
     handle.r255_verify1.restype = ctypes.c_int
     handle.r255_verify1.argtypes = [ctypes.c_char_p] * 4
-    handle.r255_batch_check.restype = ctypes.c_int
-    handle.r255_batch_check.argtypes = [ctypes.c_size_t] + [ctypes.c_char_p] * 5
+    handle.r255_round_check.restype = ctypes.c_int
+    handle.r255_round_check.argtypes = (
+        [ctypes.c_size_t] * 2 + [ctypes.c_char_p] * 7)
+    handle.r255_chunk_scalars.restype = ctypes.c_int
+    handle.r255_chunk_scalars.argtypes = (
+        [ctypes.c_size_t] + [ctypes.c_char_p] * 7
+        + [ctypes.POINTER(ctypes.c_char)] * 2
+    )
     handle.r255_encode.restype = ctypes.c_int
     handle.r255_encode.argtypes = [ctypes.c_char_p, ctypes.c_char_p]
     handle.r255_mult_base.restype = ctypes.c_int
@@ -151,10 +182,60 @@ def verify1(pub: bytes, r_enc: bytes, s: bytes, k: bytes) -> int:
         return lib.r255_verify1(pub, r_enc, s, k)
 
 
-def batch_check(rs: bytes, as_: bytes, z: bytes, zk: bytes, sb: bytes) -> int:
-    n = len(rs) // 32
-    with _lock:
-        return lib.r255_batch_check(n, rs, as_, z, zk, sb)
+def _chunk_args(pubs, sigs, rand, prefix, msgs, ks):
+    """The chunk calls' arguments from lists of byte strings, lengths
+    checked here so the C side can trust every pointer's extent: 32-byte
+    keys, 64-byte signatures, 16 random bytes an item, and either the
+    203-byte STROBE ``prefix`` with the signed ``msgs`` or 32-byte
+    challenges ``ks``. None when a length is off (no such signature)."""
+    n = len(pubs)
+    if (len(sigs) != n or len(rand) != 16 * n
+            or any(len(p) != 32 for p in pubs)
+            or any(len(s) != 64 for s in sigs)):
+        return None
+    if prefix is not None:
+        if len(prefix) != 203 or len(msgs) != n:
+            return None
+        mlens = array("I", map(len, msgs)).tobytes()
+        return (n, b"".join(pubs), b"".join(sigs), rand, prefix,
+                b"".join(msgs), mlens, None)
+    if ks is None or len(ks) != n or any(len(k) != 32 for k in ks):
+        return None
+    return (n, b"".join(pubs), b"".join(sigs), rand, None, None, None,
+            b"".join(ks))
+
+
+def chunk_check(pubs, sigs, rand: bytes, *, prefix: bytes | None = None,
+                msgs=None, ks=None, chunks: int = 1) -> int:
+    """The batch equation over signatures in one crossing: 1 all
+    verify, 0 an equation fails, negative for input that cannot be
+    signatures. ``pubs`` / ``sigs`` / ``msgs`` / ``ks`` are lists of
+    byte strings, ``rand`` 16 unpredictable bytes an item. With
+    ``chunks`` > 1 the items are checked as that many contiguous chunks,
+    each its own equation, on as many threads inside the call
+    (r255_round_check; server/scheduler.py picks the number).
+
+    No module lock, and the GIL is released for the length of the call:
+    a chunk check writes only its own stack and heap arena, so any
+    number of calls and chunks run side by side."""
+    args = _chunk_args(pubs, sigs, rand, prefix, msgs, ks)
+    if args is None:
+        return -1
+    return lib.r255_round_check(args[0], chunks, *args[1:])
+
+
+def chunk_scalars(pubs, sigs, rand: bytes, *, prefix: bytes | None = None,
+                  msgs=None, ks=None) -> tuple[bytes, bytes] | None:
+    """Test hook: the scalars chunk_check derives, as (64 bytes an item:
+    z_i ‖ z_i*k_i mod L, sum z_i*s_i mod L); None for input it refuses."""
+    args = _chunk_args(pubs, sigs, rand, prefix, msgs, ks)
+    if args is None:
+        return None
+    scal = ctypes.create_string_buffer(64 * args[0])
+    sb = ctypes.create_string_buffer(32)
+    if lib.r255_chunk_scalars(*args, scal, sb) != 0:
+        return None
+    return bytes(scal.raw), bytes(sb.raw)
 
 
 def reencode(enc: bytes) -> bytes | None:

@@ -5,28 +5,37 @@
  * types/src/lib.rs:13, README.md:199): field arithmetic mod 2^255-19
  * with 5x51-bit limbs (unsigned __int128 products), extended-Edwards
  * point ops, RFC 9496 ristretto decode/encode, a precomputed fixed-base
- * nibble table, and a Straus interleaved multi-scalar multiplication.
- * Scalar-field (mod L) arithmetic and all hashing stay in Python — the
- * caller passes fully reduced 256-bit little-endian scalars.
+ * nibble table, and a Straus / Pippenger multi-scalar multiplication.
+ * The single-signature check takes fully reduced 256-bit little-endian
+ * scalars from Python; the chunk check does its own arithmetic mod L
+ * and its own merlin challenges, so a chunk is one crossing.
  *
  * Exposed via ctypes (grapevine_tpu/native/__init__.py):
  *   r255_init()                     build the basepoint table (idempotent)
  *   r255_verify1(pub, R, s, k)      s*B == R + k*A          -> 1/0/-1
- *   r255_batch_check(n, Rs, As, z, zk, sb)
- *       fixed(sb) == sum z_i*R_i + zk_i*A_i                 -> 1/0/-1
+ *   r255_round_check(n, k, pubs, sigs, rand, prefix, msgs, mlens, ks)
+ *       the n items as k contiguous chunks; per chunk: parse,
+ *       challenges k_i, z_i from rand, then
+ *       fixed(sum z_i*s_i) == sum z_i*R_i + (z_i*k_i)*A_i   -> 1/0/-1
+ *       REENTRANT: every byte a chunk check writes is on its stack or
+ *       in its own heap arena, so the k chunks run on k threads for
+ *       the length of the call, and any number of calls side by side
  *
  * Verification-only: nothing here handles secrets, so variable-time
  * arithmetic is fine (same stance as the pure-Python path it
  * accelerates, session/ristretto.py).
  *
- * Built by `cc -O2 -shared -fPIC` at first import; correctness is
+ * Built by `cc -O2 -shared -fPIC -pthread` at first import; correctness is
  * pinned by cross-checking against the pure-Python implementation over
  * random points/scalars and the RFC 9496 test vectors
  * (tests/test_native_r255.py).
  */
 
+#include <pthread.h>
+#include <signal.h>
 #include <stdint.h>
 #include <stddef.h>
+#include <stdlib.h>
 #include <string.h>
 
 typedef uint64_t u64;
@@ -399,12 +408,11 @@ static void fixed_mult(ge *r, const uint8_t s[32]) {
  * Straus's ~74n: at n=4096 (a 2048-signature round, 2 points each)
  * that is ~2x fewer point additions, and the bucket scratch is O(2^c)
  * instead of Straus's n*16 table. */
-#define MSM_MAX 4096
 #define STRAUS_MAX 64
 
-static int msm_straus(ge *out, size_t n, const ge *pts, const uint8_t *scalars) {
-    static ge tables[STRAUS_MAX][16];
-    if (n > STRAUS_MAX) return -1;
+/* ``tables``: n rows of 16, the caller's */
+static void msm_straus(ge *out, size_t n, const ge *pts,
+                       const uint8_t *scalars, ge (*tables)[16]) {
     for (size_t i = 0; i < n; i++) {
         ge_identity(&tables[i][0]);
         tables[i][1] = pts[i];
@@ -424,7 +432,6 @@ static int msm_straus(ge *out, size_t n, const ge *pts, const uint8_t *scalars) 
         }
     }
     *out = acc;
-    return 0;
 }
 
 /* c bits of a 32-byte LE scalar starting at bit position `bit` (c <= 8,
@@ -436,11 +443,29 @@ static int scalar_window(const uint8_t *s, int bit, int c) {
     return (int)((v >> shift) & ((1u << c) - 1));
 }
 
-static int msm_pippenger(ge *out, size_t n, const ge *pts,
-                         const uint8_t *scalars) {
-    int c = n < 1024 ? 6 : 8; /* ~optimal where this path runs */
+#define PIPPENGER_MAX_C 8
+#define PIPPENGER_BUCKETS ((1 << PIPPENGER_MAX_C) - 1)
+
+/* the window that costs the fewest additions for n points, from the
+ * count above: ceil(256/c) windows of n scatters, 2*(2^c-1) folds and
+ * c doublings. 4,096 points (one call for a round) take 8; the 400-600
+ * of a chunk of a round spread over the host's cores take 6. */
+static int pippenger_window(size_t n) {
+    int best = 4;
+    size_t best_cost = (size_t)-1;
+    for (int c = 4; c <= PIPPENGER_MAX_C; c++) {
+        size_t windows = (256 + c - 1) / c;
+        size_t cost = windows * (n + 2 * ((1u << c) - 1) + c);
+        if (cost < best_cost) { best_cost = cost; best = c; }
+    }
+    return best;
+}
+
+/* ``buckets``: PIPPENGER_BUCKETS of them, the caller's */
+static void msm_pippenger(ge *out, size_t n, const ge *pts,
+                          const uint8_t *scalars, ge *buckets) {
+    int c = pippenger_window(n);
     int nbuckets = (1 << c) - 1;
-    static ge buckets[255];
     int windows = (256 + c - 1) / c;
     ge acc;
     ge_identity(&acc);
@@ -463,13 +488,19 @@ static int msm_pippenger(ge *out, size_t n, const ge *pts,
         ge_add(&acc, &acc, &sum);
     }
     *out = acc;
-    return 0;
 }
 
-static int msm(ge *out, size_t n, const ge *pts, const uint8_t *scalars) {
-    if (n > MSM_MAX) return -1;
-    if (n <= STRAUS_MAX) return msm_straus(out, n, pts, scalars);
-    return msm_pippenger(out, n, pts, scalars);
+/* how many ``ge`` of scratch msm() needs for n points */
+static size_t msm_scratch(size_t n) {
+    return n <= STRAUS_MAX ? n * 16 : PIPPENGER_BUCKETS;
+}
+
+static void msm(ge *out, size_t n, const ge *pts, const uint8_t *scalars,
+                ge *scratch) {
+    if (n <= STRAUS_MAX)
+        msm_straus(out, n, pts, scalars, (ge (*)[16])scratch);
+    else
+        msm_pippenger(out, n, pts, scalars, scratch);
 }
 
 /* Decoded-public-key cache: ristretto decode costs one field
@@ -478,9 +509,10 @@ static int msm(ge *out, size_t n, const ge *pts, const uint8_t *scalars) {
  * keys, which repeat heavily across a session's requests, while the
  * R_i are fresh nonce points every time. Direct-mapped, keyed by the
  * full 32-byte encoding; stores only successfully-decoded canonical
- * points, so a hit is exactly equivalent to a fresh decode. Callers
- * (r255_verify1 / r255_batch_check) run under the Python wrapper's
- * module lock, which serializes all access to this static table. */
+ * points, so a hit is exactly equivalent to a fresh decode. Its one
+ * caller, r255_verify1, runs under the Python wrapper's module lock,
+ * which serializes all access to this static table; the chunk check
+ * never touches it (it decodes each distinct key of its chunk once). */
 #define PUBCACHE_BITS 13
 #define PUBCACHE_N (1 << PUBCACHE_BITS)
 static struct { uint8_t key[32]; ge val; uint8_t full; } pubcache[PUBCACHE_N];
@@ -510,30 +542,9 @@ int r255_verify1(const uint8_t pub[32], const uint8_t r_enc[32],
     if (ristretto_decode_pub(&a_pt, pub) != 0) return -1;
     if (ristretto_decode(&big_r, r_enc) != 0) return -1;
     fixed_mult(&left, s);
-    ge pts[1] = {a_pt};
-    if (msm(&right, 1, pts, k) != 0) return -1;
+    ge tables[16];
+    msm(&right, 1, &a_pt, k, tables);
     ge_add(&right, &right, &big_r);
-    return ristretto_eq(&left, &right);
-}
-
-/* fixed(sb) == sum z_i*R_i + zk_i*A_i over n items.
- * rs/as_: n*32 bytes of encodings; z/zk: n*32 LE reduced scalars. */
-int r255_batch_check(size_t n, const uint8_t *rs, const uint8_t *as_,
-                     const uint8_t *z, const uint8_t *zk,
-                     const uint8_t sb[32]) {
-    if (r255_init() != 0) return -1;
-    if (2 * n > MSM_MAX) return -1;
-    static ge pts[MSM_MAX];
-    static uint8_t scal[MSM_MAX * 32];
-    for (size_t i = 0; i < n; i++) {
-        if (ristretto_decode(&pts[2 * i], rs + 32 * i) != 0) return -1;
-        if (ristretto_decode_pub(&pts[2 * i + 1], as_ + 32 * i) != 0) return -1;
-        memcpy(scal + 64 * i, z + 32 * i, 32);
-        memcpy(scal + 64 * i + 32, zk + 32 * i, 32);
-    }
-    ge left, right;
-    fixed_mult(&left, sb);
-    if (msm(&right, 2 * n, pts, scal) != 0) return -1;
     return ristretto_eq(&left, &right);
 }
 
@@ -809,4 +820,270 @@ void r255_schnorrkel_challenge(const uint8_t *prefix_blob,
     r255_merlin_append(b, (const uint8_t *)"sign:pk", 7, pub, 32);
     r255_merlin_append(b, (const uint8_t *)"sign:R", 6, r_enc, 32);
     r255_merlin_challenge(b, (const uint8_t *)"sign:c", 6, out64, 64);
+}
+
+/* ------------------------------------------------------------------ */
+/* The chunk check: everything one batch equation needs, in one        */
+/* crossing and with no shared scratch, so the scheduler can run a     */
+/* round's chunks on several threads at once (server/scheduler.py).    */
+/* ------------------------------------------------------------------ */
+
+/* Scalars mod L = 2^252 + SC_C as little-endian u64 limbs. */
+static const u64 SC_L[4] = {0x5812631a5cf5d3edULL, 0x14def9dea2f79cd6ULL,
+                            0, 0x1000000000000000ULL};
+static const u64 SC_2L[4] = {0xb024c634b9eba7daULL, 0x29bdf3bd45ef39acULL,
+                             0, 0x2000000000000000ULL};
+static const u64 SC_C[2] = {0x5812631a5cf5d3edULL, 0x14def9dea2f79cd6ULL};
+
+static void sc_add4(u64 r[4], const u64 a[4]) {
+    u128 carry = 0;
+    for (int i = 0; i < 4; i++) {
+        carry += (u128)r[i] + a[i];
+        r[i] = (u64)carry;
+        carry >>= 64;
+    }
+}
+
+static void sc_sub4(u64 r[4], const u64 a[4]) {  /* r >= a */
+    u64 borrow = 0;
+    for (int i = 0; i < 4; i++) {
+        u64 d = r[i] - a[i], b = r[i] < a[i];
+        r[i] = d - borrow;
+        borrow = b | (d < borrow);
+    }
+}
+
+static int sc_geq4(const u64 a[4], const u64 b[4]) {
+    for (int i = 3; i >= 0; i--)
+        if (a[i] != b[i]) return a[i] > b[i];
+    return 1;
+}
+
+/* out[na+nb] = a[na] * b[nb] */
+static void sc_mul(u64 *out, const u64 *a, int na, const u64 *b, int nb) {
+    memset(out, 0, (size_t)(na + nb) * sizeof(u64));
+    for (int i = 0; i < na; i++) {
+        u64 carry = 0;
+        for (int j = 0; j < nb; j++) {
+            u128 t = (u128)a[i] * b[j] + out[i + j] + carry;
+            out[i + j] = (u64)t;
+            carry = (u64)(t >> 64);
+        }
+        out[i + nb] = carry;
+    }
+}
+
+/* r = x mod L for x of nx <= 8 limbs. 2^252 = -SC_C (mod L), so
+ * x = lo + hi*2^252 = lo - SC_C*hi, and SC_C*hi is 125 bits shorter
+ * than x: fold until hi is 0 (four times for 512 bits), summing the
+ * low parts that came with a plus sign and those with a minus sign
+ * apart (each sum under 2^254), and subtract once at the end. */
+static void sc_reduce(u64 r[4], const u64 *x, int nx) {
+    u64 cur[8], hi[5], acc[2][4] = {{0}, {0}};
+    int n = nx, sign = 0;
+    memcpy(cur, x, (size_t)nx * sizeof(u64));
+    for (;;) {
+        u64 lo[4] = {0, 0, 0, 0};
+        for (int i = 0; i < 4 && i < n; i++) lo[i] = cur[i];
+        lo[3] &= 0x0FFFFFFFFFFFFFFFULL;
+        sc_add4(acc[sign], lo);
+        int nh = n - 3;
+        u64 any = 0;
+        for (int i = 0; i < nh; i++) {
+            hi[i] = cur[i + 3] >> 60;
+            if (i + 4 < n) hi[i] |= cur[i + 4] << 4;
+            any |= hi[i];
+        }
+        if (nh <= 0 || !any) break;
+        sc_mul(cur, SC_C, 2, hi, nh);
+        n = nh + 2;
+        sign ^= 1;
+    }
+    memcpy(r, acc[0], sizeof acc[0]);
+    sc_add4(r, SC_2L);          /* acc[1] < 2^252 + 2^132 < 2L */
+    sc_sub4(r, acc[1]);
+    while (sc_geq4(r, SC_L)) sc_sub4(r, SC_L);
+}
+
+/* A chunk's scalars: for item i, scal[64i..] = z_i (the 128 random
+ * bits, forced odd) and scal[64i+32..] = z_i*k_i mod L; sb = sum
+ * z_i*s_i mod L. With ``prefix_blob`` (sr25519) a signature must carry
+ * schnorrkel's marker bit, which is cleared before the range check,
+ * and k_i is the merlin challenge over (message, key, R); without it
+ * (the RFC 9496 scheme hashes in Python) k_i is ks[32i..]. 0, or -1 for
+ * a signature that cannot be one (no marker, s >= L). */
+static int chunk_scalars(size_t n, const uint8_t *pubs, const uint8_t *sigs,
+                         const uint8_t *rand16, const uint8_t *prefix_blob,
+                         const uint8_t *msgs, const uint32_t *mlens,
+                         const uint8_t *ks, uint8_t *scal, uint8_t sb[32]) {
+    u64 sum[8] = {0}, prod[8], s[4], k[4], z[2], wide[8];
+    for (size_t i = 0; i < n; i++) {
+        const uint8_t *sig = sigs + 64 * i, *pub = pubs + 32 * i;
+        memcpy(s, sig + 32, 32);
+        if (prefix_blob) {
+            if (!(s[3] >> 63)) return -1;
+            s[3] &= 0x7FFFFFFFFFFFFFFFULL;
+        }
+        if (sc_geq4(s, SC_L)) return -1;
+        if (prefix_blob) {
+            uint8_t out64[64];
+            r255_schnorrkel_challenge(prefix_blob, msgs, mlens[i], pub, sig,
+                                      out64);
+            msgs += mlens[i];
+            memcpy(wide, out64, 64);
+            sc_reduce(k, wide, 8);
+        } else {
+            memcpy(k, ks + 32 * i, 32);
+        }
+        memcpy(z, rand16 + 16 * i, 16);
+        z[0] |= 1;
+        sc_mul(prod, z, 2, k, 4);
+        sc_reduce(k, prod, 6);
+        memset(scal + 64 * i, 0, 32);
+        memcpy(scal + 64 * i, z, 16);
+        memcpy(scal + 64 * i + 32, k, 32);
+        /* sum of n <= 2^16 products under 2^381 stays under 2^397 */
+        sc_mul(prod, z, 2, s, 4);
+        u128 carry = 0;
+        for (int j = 0; j < 8; j++) {
+            carry += (u128)sum[j] + (j < 6 ? prod[j] : 0);
+            sum[j] = (u64)carry;
+            carry >>= 64;
+        }
+    }
+    sc_reduce(s, sum, 8);
+    memcpy(sb, s, 32);
+    return 0;
+}
+
+#define CHUNK_MAX 65536
+
+/* test hook: the scalars alone (tests/test_native_r255.py holds them
+ * to Python's big integers and to the golden challenge) */
+int r255_chunk_scalars(size_t n, const uint8_t *pubs, const uint8_t *sigs,
+                       const uint8_t *rand16, const uint8_t *prefix_blob,
+                       const uint8_t *msgs, const uint32_t *mlens,
+                       const uint8_t *ks, uint8_t *scal, uint8_t sb[32]) {
+    if (n > CHUNK_MAX || (!prefix_blob && !ks)) return -1;
+    return chunk_scalars(n, pubs, sigs, rand16, prefix_blob, msgs, mlens, ks,
+                         scal, sb);
+}
+
+/* One random-linear-combination equation over n signatures:
+ *   pubs n*32, sigs n*64 (R ‖ s), rand16 n*16 unpredictable bytes,
+ *   then either prefix_blob (203 B) + msgs (concatenated) + mlens, or ks.
+ * 1 every signature verifies, 0 the equation fails, -1 malformed input
+ * (an s out of range, an encoding that is no point), -2 no memory. */
+static int chunk_check(size_t n, const uint8_t *pubs, const uint8_t *sigs,
+                       const uint8_t *rand16, const uint8_t *prefix_blob,
+                       const uint8_t *msgs, const uint32_t *mlens,
+                       const uint8_t *ks) {
+    if (!INITIALIZED || n > CHUNK_MAX || (!prefix_blob && !ks)) return -1;
+    if (n == 0) return 1;
+    /* the call's own arena: points, MSM scratch, scalars, and the table
+     * that finds a public key this chunk has already decoded */
+    size_t npts = 2 * n, nslots = 4;
+    while (nslots < npts) nslots <<= 1;
+    size_t nscratch = msm_scratch(npts);
+    uint8_t *arena = malloc((npts + nscratch) * sizeof(ge) + npts * 32
+                            + nslots * sizeof(int32_t));
+    if (!arena) return -2;
+    ge *pts = (ge *)arena, *scratch = pts + npts;
+    uint8_t *scal = (uint8_t *)(scratch + nscratch), sb[32];
+    int32_t *slots = (int32_t *)(scal + npts * 32);
+    int rc = -1;
+    if (chunk_scalars(n, pubs, sigs, rand16, prefix_blob, msgs, mlens, ks,
+                      scal, sb) != 0)
+        goto done;
+    memset(slots, 0xFF, nslots * sizeof(int32_t));
+    for (size_t i = 0; i < n; i++) {
+        const uint8_t *pub = pubs + 32 * i;
+        if (ristretto_decode(&pts[2 * i], sigs + 64 * i) != 0) goto done;
+        uint64_t h;
+        memcpy(&h, pub, 8);
+        size_t slot = (size_t)(h ^ (h >> 17) ^ (h >> 31)) & (nslots - 1);
+        while (slots[slot] >= 0 && memcmp(pubs + 32 * slots[slot], pub, 32))
+            slot = (slot + 1) & (nslots - 1);
+        if (slots[slot] >= 0) {
+            pts[2 * i + 1] = pts[2 * slots[slot] + 1];
+        } else {
+            if (ristretto_decode(&pts[2 * i + 1], pub) != 0) goto done;
+            slots[slot] = (int32_t)i;
+        }
+    }
+    ge left, right;
+    fixed_mult(&left, sb);
+    msm(&right, npts, pts, scal, scratch);
+    rc = ristretto_eq(&left, &right);
+done:
+    free(arena);
+    return rc;
+}
+
+/* A round's first pass: the n items as k contiguous chunks of
+ * ceil(n/k), each its own equation (chunk_check), k-1 of them on
+ * threads that live for this call and one on the caller's. The caller
+ * crosses into C once and holds no GIL meanwhile, so a chunk waits for
+ * a core and for nothing else: handing chunks to interpreter threads
+ * instead made each wait twice for the GIL (PERF.md, PR 29: 12 ms for
+ * 2,048 signatures as 8 chunks on an idle host, 77 beside one busy
+ * Python thread). 1 when every chunk verifies, else the first chunk's
+ * answer that is not 1. */
+#define ROUND_MAX_CHUNKS 64
+
+typedef struct {
+    size_t n;
+    const uint8_t *pubs, *sigs, *rand16, *prefix_blob, *msgs, *ks;
+    const uint32_t *mlens;
+    int rc;
+} chunk_job;
+
+static void *chunk_job_run(void *arg) {
+    chunk_job *j = arg;
+    j->rc = chunk_check(j->n, j->pubs, j->sigs, j->rand16, j->prefix_blob,
+                        j->msgs, j->mlens, j->ks);
+    return NULL;
+}
+
+int r255_round_check(size_t n, size_t k, const uint8_t *pubs,
+                     const uint8_t *sigs, const uint8_t *rand16,
+                     const uint8_t *prefix_blob, const uint8_t *msgs,
+                     const uint32_t *mlens, const uint8_t *ks) {
+    if (!prefix_blob && !ks) return -1;
+    if (k < 1) k = 1;
+    if (k > ROUND_MAX_CHUNKS) k = ROUND_MAX_CHUNKS;
+    chunk_job jobs[ROUND_MAX_CHUNKS];
+    pthread_t threads[ROUND_MAX_CHUNKS];
+    int started[ROUND_MAX_CHUNKS] = {0};
+    size_t step = (n + k - 1) / k, njobs = 0;
+    for (size_t i = 0; i < n; i += step, njobs++) {
+        chunk_job *j = &jobs[njobs];
+        j->n = n - i < step ? n - i : step;
+        j->pubs = pubs + 32 * i;
+        j->sigs = sigs + 64 * i;
+        j->rand16 = rand16 + 16 * i;
+        j->prefix_blob = prefix_blob;
+        j->msgs = msgs;
+        j->mlens = prefix_blob ? mlens + i : NULL;
+        j->ks = ks ? ks + 32 * i : NULL;
+        j->rc = -1;
+        if (prefix_blob)
+            for (size_t m = 0; m < j->n; m++) msgs += mlens[i + m];
+    }
+    /* the chunk threads take no signals: the interpreter's handlers
+     * belong on its own threads */
+    sigset_t all, old;
+    sigfillset(&all);
+    pthread_sigmask(SIG_SETMASK, &all, &old);
+    for (size_t c = 1; c < njobs; c++)
+        started[c] = pthread_create(&threads[c], NULL, chunk_job_run,
+                                    &jobs[c]) == 0;
+    pthread_sigmask(SIG_SETMASK, &old, NULL);
+    for (size_t c = 0; c < njobs; c++) {
+        if (started[c]) pthread_join(threads[c], NULL);
+        else chunk_job_run(&jobs[c]);  /* the caller's own, or no thread */
+    }
+    for (size_t c = 0; c < njobs; c++)
+        if (jobs[c].rc != 1) return jobs[c].rc;
+    return 1;
 }

@@ -123,9 +123,12 @@ ALLOWED_SPAN_NAMES = frozenset(STABLE_SPANS) | frozenset(PHASES)
 #: unresolved at this dispatch, ``device_exact`` = 1 when the device was
 #: still running this round and the one before each time the host
 #: arrived to wait, and ran no flush or sweep between the two (so
-#: ``device`` is this round's own device time, not an upper bound)
+#: ``device`` is this round's own device time, not an upper bound),
+#: ``verify_chunks`` = chunk checks the round's first signature pass
+#: ran side by side (1 = one inline call): a function of how many ops
+#: the round took and of the host's cores, never of an op
 ROUND_COUNTS = ("ops", "rejected", "queue_wait_sum_s", "rounds_ahead",
-                "device_exact")
+                "device_exact", "verify_chunks")
 
 
 def _check_span(name: str, value) -> tuple[float, float]:

@@ -9,10 +9,12 @@ device cadence carries no information about load bursts beyond the round
 count itself.
 
 Challenge-signature verification rides the same batching: the round's
-signatures are checked with ONE random-linear-combination multi-scalar
-multiplication (session/ristretto.py:batch_verify — SURVEY.md §2b
-"consider batch verify"); only a failing round pays per-item verification
-to identify offenders, which are rejected without reaching the engine.
+signatures are checked by random-linear-combination multi-scalar
+multiplications (session/ristretto.py:batch_verify — SURVEY.md §2b
+"consider batch verify"), one per chunk of the round, the chunks side by
+side on the host's cores (``verify_lanes``) inside one native call; only
+a failing round pays per-item verification to identify offenders, which
+are rejected without reaching the engine.
 
 The collector is a staged pipeline (PR 10): it keeps up to
 ``pipeline_depth`` dispatched rounds in a bounded in-flight ledger and
@@ -25,6 +27,7 @@ bit-for-bit the pre-PR-10 dispatch-then-settle loop.
 
 from __future__ import annotations
 
+import os
 import threading
 import time
 from collections import deque
@@ -39,19 +42,43 @@ from ..wire.records import QueryRequest, QueryResponse
 AuthItem = tuple[bytes, bytes, bytes, bytes]
 
 
+#: the least signatures a chunk check is given a thread for: below it
+#: Pippenger's bucket pass costs more additions than it saves (a chunk
+#: of 256 already pays 1.3x the additions per signature of one call for
+#: 2,048) and starting the thread costs more than the check
+MIN_VERIFY_CHUNK = 256
+
+#: cores left to the collector's own Python and the ingress beside it
+#: when a round's chunk checks are spread over the rest
+_CORES_KEPT_BACK = 2
+
+
+def verify_lanes() -> int:
+    """How many chunk checks of a round may run at once: the cores this
+    process may use less the two its Python needs. From what the process
+    can see; nothing configures it."""
+    try:
+        cores = len(os.sched_getaffinity(0))
+    except AttributeError:  # not every platform has affinity masks
+        cores = os.cpu_count() or 1
+    return max(1, cores - _CORES_KEPT_BACK)
+
+
 def round_counts(enqueued: list[float], taken: int, t_dispatch: float,
-                 rounds_ahead: int) -> dict:
+                 rounds_ahead: int, verify_chunks: int) -> dict:
     """The per-round counts the scheduler adds to a round's ledger
     (obs/tracer.py ROUND_COUNTS): ``enqueued`` are the perf_counter
     enqueue stamps of the ops admitted to the round, ``taken`` how many
     ops the window took off the queue (the rest failed verification),
     ``rounds_ahead`` how many rounds were dispatched and unresolved at
-    this dispatch."""
+    this dispatch, ``verify_chunks`` how many chunk checks the round's
+    first verification pass ran (1 when it was one inline call)."""
     return {
         "ops": len(enqueued),
         "rejected": taken - len(enqueued),
         "queue_wait_sum_s": sum(max(0.0, t_dispatch - t) for t in enqueued),
         "rounds_ahead": rounds_ahead,
+        "verify_chunks": verify_chunks,
     }
 
 
@@ -101,9 +128,13 @@ class BatchScheduler:
         self.scheme = scheme or schnorrkel
         #: optional multiprocess verify fan-out (server/hostpipe.py):
         #: when GrapevineServer runs a host pipeline it plants the pool
-        #: here, and the round's first-pass batch_verify splits across
-        #: worker processes. None = the historical in-process MSM.
+        #: here, and the round's first-pass batch_verify is offered to
+        #: the worker processes first. None = the in-process chunks.
         self.hostpipe = None
+        #: how many chunk checks of a round may run side by side
+        self._verify_lanes = verify_lanes()
+        #: chunk checks the last round's first verification pass ran
+        self._verify_chunks = 1
         #: optional SLO-adaptive window policy (server/adaptive.py),
         #: planted by the serving layer after observability attaches;
         #: None = the static max_wait/idle_gap/full-batch window
@@ -444,7 +475,8 @@ class BatchScheduler:
                             pending.note_span(
                                 "queue", oldest, max(0.0, t_disp - oldest))
                             pending.note_counts(**round_counts(
-                                enqs, len(chunk), t_disp, len(ledger)))
+                                enqs, len(chunk), t_disp, len(ledger),
+                                self._verify_chunks))
                     except Exception as exc:  # pragma: no cover - defensive
                         for _, fut in live:
                             if not fut.done():
@@ -465,13 +497,19 @@ class BatchScheduler:
                 settle_head()
 
     def _batch_verify_fanout(self, items) -> bool:
-        """First-pass batch verify, fanned across the hostpipe pool when
-        one is attached. The happy path (everything verifies) gets the
-        multiprocess speedup; a False answer hands off to the inline
-        bisect below, which stays in-process — failure is the attacker-
-        funded path and does not deserve the parallel hardware. Any pool
-        fault degrades to the in-process MSM rather than rejecting
-        honest traffic."""
+        """First-pass batch verify: the round's items as k contiguous
+        chunks, each its own random-linear-combination equation with its
+        own randomisers (so soundness per signature is what one equation
+        gives), side by side on k threads inside the scheme's one native
+        call; the answer is the conjunction. k follows from the item
+        count and the cores the process may use (``verify_lanes``), with
+        a least chunk size: a thin round is one inline call. A False
+        answer hands off to the inline bisect below, one chunk at a
+        time — failure is the attacker-funded path and does not deserve
+        the parallel hardware. With a hostpipe pool attached that is
+        asked first; any pool fault degrades to the in-process check
+        rather than rejecting honest traffic."""
+        self._verify_chunks = 1
         if self.hostpipe is not None:
             from .hostpipe import HostPipeError
 
@@ -479,7 +517,9 @@ class BatchScheduler:
                 return self.hostpipe.verify_parallel(items)
             except HostPipeError:
                 pass  # degraded pool: verified correctness beats speed
-        return bool(self.scheme.batch_verify(items))
+        k = max(1, min(self._verify_lanes, len(items) // MIN_VERIFY_CHUNK))
+        self._verify_chunks = k
+        return bool(self.scheme.batch_verify(items, chunks=k))
 
     def _verify_chunk(self, chunk):
         """Batch signature verification; returns surviving (req, fut)."""

@@ -20,6 +20,7 @@ from __future__ import annotations
 
 import functools
 import hashlib
+import os
 
 from .. import native as _native
 
@@ -269,7 +270,8 @@ def verify(pub: bytes, context: bytes, message: bytes, signature: bytes) -> bool
 # verifying individually.
 
 
-#: max items per native MSM call (2 points each; r255.c MSM_MAX = 4096)
+#: most items one native chunk call is given (its arena is ~400 bytes
+#: an item; native/r255.c refuses more than CHUNK_MAX = 65536)
 _NATIVE_CHUNK = 2048
 
 
@@ -329,11 +331,37 @@ def _msm(points: list[RistrettoPoint], scalars: list[int]) -> RistrettoPoint:
     return acc
 
 
+def native_batch_verify(
+    pubs, sigs, rng=None, *, prefix=None, msgs=None, ks=None, chunks=1
+) -> bool:
+    """The batch equation through the native library: parsing,
+    challenges, the arithmetic mod L, decoding and the MSM all happen
+    inside native.chunk_check, one crossing that holds no lock and no
+    GIL, the items as ``chunks`` equations side by side on as many
+    threads (1: the caller's alone). A chunk is at most
+    ``_NATIVE_CHUNK`` items, so there is no cliff at any batch size.
+    The challenges are either derived in C (sr25519: ``prefix``, the
+    context's STROBE blob, and the signed ``msgs``) or given (``ks``,
+    32 bytes each). ``rng`` must be unpredictable to clients."""
+    randbytes = rng.randbytes if rng is not None else os.urandom
+    span = _NATIVE_CHUNK * chunks
+    for i in range(0, len(pubs), span):
+        j = min(i + span, len(pubs))
+        if _native.chunk_check(
+            pubs[i:j], sigs[i:j], randbytes(16 * (j - i)), prefix=prefix,
+            msgs=msgs and msgs[i:j], ks=ks and ks[i:j], chunks=chunks,
+        ) != 1:
+            return False
+    return True
+
+
 def batch_verify_core(
     parsed: list[tuple[bytes, bytes, int, int]],
     rng=None,
 ) -> bool:
-    """Random-linear-combination batch check over pre-parsed items.
+    """Random-linear-combination batch check over pre-parsed items, in
+    pure Python: the fallback without the native library and the oracle
+    the native chunk check is held to (tests/test_native_r255.py).
 
     ``parsed`` holds (R_enc, pub_enc, s, k) per signature — the scheme
     layer (this module's plain Schnorr, or session/schnorrkel.py's
@@ -341,70 +369,34 @@ def batch_verify_core(
 
         Σ z_i·s_i · B  ==  Σ z_i·R_i + Σ (z_i·k_i mod L)·A_i
 
-    is scheme-independent. Shared so both schemes ride the same native
-    one-MSM path. ``rng`` must be unpredictable to clients."""
-    import os
-
-    # the native MSM scratch caps one call at _NATIVE_CHUNK items; larger
-    # batches split into independently-checked chunks (each chunk is its
-    # own random-linear-combination equation), so there is no silent
-    # fallback cliff at any batch size
-    if len(parsed) > _NATIVE_CHUNK:
-        return all(
-            batch_verify_core(parsed[i : i + _NATIVE_CHUNK], rng)
-            for i in range(0, len(parsed), _NATIVE_CHUNK)
-        )
-    if not parsed:
-        return True
-
+    is scheme-independent. ``rng`` must be unpredictable to clients."""
     randbytes = rng.randbytes if rng is not None else os.urandom
-    use_native = _native.lib is not None
-    rs: list[bytes] = []
-    pubs: list[bytes] = []
-    zs: list[bytes] = []
-    zks: list[bytes] = []
     points: list[RistrettoPoint] = []
     scalars: list[int] = []
     sb = 0
     for r_enc, pub, s, k in parsed:
-        if not use_native:
-            try:
-                points.append(RistrettoPoint.decode(r_enc))
-                points.append(_decode_pub_cached(pub))
-            except ValueError:
-                return False
+        try:
+            points.append(RistrettoPoint.decode(r_enc))
+            points.append(_decode_pub_cached(pub))
+        except ValueError:
+            return False
         z = int.from_bytes(randbytes(16), "little") | 1
         sb = (sb + z * s) % L
-        if use_native:
-            rs.append(r_enc)
-            pubs.append(pub)
-            zs.append(z.to_bytes(32, "little"))
-            zks.append((z * k % L).to_bytes(32, "little"))
-        else:
-            scalars.append(z)
-            scalars.append(z * k % L)
-    if use_native:
-        return (
-            _native.batch_check(
-                b"".join(rs),
-                b"".join(pubs),
-                b"".join(zs),
-                b"".join(zks),
-                sb.to_bytes(32, "little"),
-            )
-            == 1
-        )
+        scalars.append(z)
+        scalars.append(z * k % L)
     return _fixed_base_mult(sb) == _msm(points, scalars)
 
 
 def batch_verify(
     items: list[tuple[bytes, bytes, bytes, bytes]],
     rng=None,
+    chunks: int = 1,
 ) -> bool:
     """True iff EVERY (pub, context, message, signature) verifies.
 
     One multi-scalar multiplication for the whole batch (native library
-    when available: ~0.05 ms/signature at batch 64). On False the caller
+    when available: ~0.05 ms/signature at batch 64), or ``chunks`` of
+    them side by side (native_batch_verify). On False the caller
     falls back to per-item verify to identify offenders. ``rng`` must be
     unpredictable to clients (default: os.urandom)."""
     parsed = []
@@ -416,4 +408,9 @@ def batch_verify(
             return False
         k = _h_scalar(_CHAL_DOMAIN, context, signature[:32], pub, message)
         parsed.append((signature[:32], pub, s, k))
+    if _native.lib is not None:
+        return native_batch_verify(
+            [it[0] for it in items], [it[3] for it in items], rng,
+            ks=[k.to_bytes(32, "little") for _, _, _, k in parsed],
+            chunks=chunks)
     return batch_verify_core(parsed, rng)

@@ -226,10 +226,35 @@ def verify(pub: bytes, context: bytes, message: bytes, signature: bytes) -> bool
 def batch_verify(
     items: list[tuple[bytes, bytes, bytes, bytes]],
     rng=None,
+    chunks: int = 1,
 ) -> bool:
-    """True iff EVERY (pub, context, message, signature) verifies —
-    one multi-scalar multiplication per chunk, shared with the RFC-9496
-    scheme through ristretto.batch_verify_core."""
+    """True iff EVERY (pub, context, message, signature) verifies.
+
+    With the native library the batch is ONE crossing (native/r255.c:
+    parse, merlin challenge, the scalars mod L, point decoding, the
+    MSM) that holds no lock and no GIL, checked as ``chunks`` contiguous
+    chunks, each its own equation with its own randomisers, on as many
+    threads inside the call (server/scheduler.py picks the number for a
+    round); without it, the pure-Python equation of
+    ristretto.batch_verify_core."""
+    from .. import native as _native
+
+    if not items:
+        return True
+    pubs, contexts, msgs, sigs = zip(*items)
+    if _native.lib is not None:
+        if len(set(contexts)) > 1:
+            # one STROBE prefix per call: a context at a time (a server
+            # signs under one, wire/constants.py)
+            return all(
+                batch_verify([it for it in items if it[1] == c], rng, chunks)
+                for c in set(contexts)
+            )
+        return _r.native_batch_verify(
+            pubs, sigs, rng,
+            prefix=_context_prefix_blob(bytes(contexts[0])), msgs=msgs,
+            chunks=chunks,
+        )
     parsed_items = []
     for pub, context, message, signature in items:
         if len(pub) != 32:

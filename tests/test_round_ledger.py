@@ -81,7 +81,7 @@ class _Scheme:
         return sig == b"ok"
 
     @classmethod
-    def batch_verify(cls, items):
+    def batch_verify(cls, items, chunks=1):
         return all(cls.verify(*it) for it in items)
 
 
@@ -201,8 +201,9 @@ def test_queue_wait_sum_equals_the_hand_sum(clocked):
         assert e["spans"]["dispatch"][0] > t_dispatch
         assert e["spans"]["inflight"][0] > e["spans"]["dispatch"][0]
     assert taken == 4 * BATCH
-    assert round_counts([1.0, 2.5], 3, 4.0, 2) == {
-        "ops": 2, "rejected": 1, "queue_wait_sum_s": 4.5, "rounds_ahead": 2}
+    assert round_counts([1.0, 2.5], 3, 4.0, 2, 1) == {
+        "ops": 2, "rejected": 1, "queue_wait_sum_s": 4.5, "rounds_ahead": 2,
+        "verify_chunks": 1}
 
 
 def test_settle_lands_on_its_own_round(clocked):

@@ -10,6 +10,8 @@ the test is stable on a single-core host.
 import threading
 import time
 
+import pytest
+
 from grapevine_tpu.engine.metrics import EngineMetrics
 from grapevine_tpu.server.scheduler import BatchScheduler
 from grapevine_tpu.wire import constants as C
@@ -157,3 +159,103 @@ def test_full_batch_commits_without_waiting():
         assert eng.rounds and max(eng.rounds) == _StubEcfg.batch_size
     finally:
         sched.close()
+
+
+# -- a round's signatures on the host's cores ---------------------------
+
+
+class _CountingEngine(_StubEngine):
+    """A stub whose round handles keep what the scheduler stamps on
+    them, as engine/batcher.py's PendingRound does."""
+
+    def __init__(self, batch_size):
+        super().__init__()
+        self.ecfg = type("Ecfg", (), {"batch_size": batch_size})()
+        self.counts: list[dict] = []
+
+    def handle_queries_async(self, reqs, now):
+        resps = self.handle_queries(reqs, now)
+        engine = self
+
+        class _Pending:
+            def note_span(self, name, start, dur):
+                pass
+
+            def set_queue_depth(self, depth):
+                pass
+
+            def set_enqueued_at(self, t):
+                pass
+
+            def note_counts(self, **counts):
+                engine.counts.append(counts)
+
+            def resolve(self):
+                return resps
+
+        return _Pending()
+
+
+@pytest.fixture(scope="module")
+def signed_ops():
+    """2,048 ops' auth items, each under an identity of its own."""
+    from grapevine_tpu.session import schnorrkel
+
+    ctx = C.GRAPEVINE_CHALLENGE_SIGNING_CONTEXT
+    items = []
+    for i in range(2048):
+        sk, pub = schnorrkel.keygen(i.to_bytes(4, "little") * 8)
+        msg = i.to_bytes(32, "little")
+        items.append((pub, ctx, msg, schnorrkel.sign(sk, ctx, msg)))
+    return items
+
+
+@pytest.mark.parametrize(
+    "cores,n_ops,bad,chunks",
+    [
+        (8, 2048, (5, 700, 2047), 6),   # three bad ops in three chunks
+        (8, 2048, (), 6),
+        (8, 35, (), 1),                 # a thin round: one inline call
+        (8, 35, (17,), 1),
+        (8, 600, (599,), 2),            # the least chunk size holds k down
+        (30, 2048, (0,), 8),
+        (2, 2048, (1024,), 1),          # no core to spare: inline
+    ],
+)
+def test_round_verifies_in_chunks_and_rejects_bad_ops_alone(
+        monkeypatch, signed_ops, cores, n_ops, bad, chunks):
+    """k follows from the cores the process may use and the round's
+    size; the conjunction of the chunks' answers decides, and the
+    bisect still finds every bad signature and refuses it alone."""
+    import os
+
+    from grapevine_tpu import native
+    from grapevine_tpu.server.scheduler import AuthFailure
+
+    if native.lib is None:
+        pytest.skip("native library unavailable")
+    monkeypatch.setattr(
+        os, "sched_getaffinity", lambda pid: set(range(cores)), raising=False)
+    eng = _CountingEngine(batch_size=2048)
+    sched = BatchScheduler(eng, max_wait_ms=20_000.0, idle_gap_ms=200.0)
+    try:
+        futs = []
+        with sched._cv:  # the window sees the ops all at once: one round
+            for i in range(n_ops):
+                pub, ctx, msg, sig = signed_ops[i]
+                if i in bad:
+                    sig = sig[:40] + bytes([sig[40] ^ 1]) + sig[41:]
+                futs.append(sched.submit_nowait(_req(), (pub, ctx, msg, sig)))
+        refused = set()
+        for i, fut in enumerate(futs):
+            try:
+                fut.result(timeout=120)
+            except AuthFailure:
+                refused.add(i)
+        assert refused == set(bad)
+        assert eng.rounds == [n_ops - len(bad)]
+        assert [c["verify_chunks"] for c in eng.counts] == [chunks]
+        assert eng.counts[0]["rejected"] == len(bad)
+    finally:
+        sched.close()
+    assert not sched.worker_alive()

@@ -126,6 +126,42 @@ def test_challenge_transcript_labels_golden():
     assert k == 0xB4430E99729B59EBA580AB30C1D0968E4EF06EC3E803E837F1A4BDBEF47ECA
 
 
+def test_golden_challenge_through_the_one_crossing_path():
+    """The same golden, derived inside the native chunk call: with all
+    random bytes 0 the randomiser z is 1, so the call's z*k is k."""
+    from grapevine_tpu import native
+
+    if native.lib is None:
+        pytest.skip("native library unavailable")
+    marked_zero_s = b"\x03" * 32 + b"\x00" * 31 + b"\x80"
+    scal, sb = native.chunk_scalars(
+        [b"\x02" * 32], [marked_zero_s], b"\x00" * 16,
+        prefix=schnorrkel._context_prefix_blob(b"grapevine-challenge"),
+        msgs=[b"\x01" * 32],
+    )
+    assert int.from_bytes(scal[:32], "little") == 1
+    assert int.from_bytes(scal[32:], "little") == \
+        0xB4430E99729B59EBA580AB30C1D0968E4EF06EC3E803E837F1A4BDBEF47ECA
+    assert sb == b"\x00" * 32
+
+
+@pytest.mark.parametrize("bad_context", [None, b"ctx-a", b"ctx-b"])
+def test_batch_verify_across_contexts(bad_context):
+    """A batch may mix signing contexts (the native call takes one
+    STROBE prefix, so the scheme checks a context at a time)."""
+    items = []
+    for i in range(1, 21):
+        sk, pub = _mk(i)
+        ctx = (b"ctx-a", b"ctx-b")[i % 2]
+        msg = os.urandom(32)
+        items.append((pub, ctx, msg, schnorrkel.sign(sk, ctx, msg)))
+    if bad_context is not None:
+        at = next(i for i, it in enumerate(items) if it[1] == bad_context)
+        pub, ctx, msg, sig = items[at]
+        items[at] = (pub, ctx, os.urandom(32), sig)
+    assert schnorrkel.batch_verify(items) is (bad_context is None)
+
+
 def test_scheme_registry():
     assert get_signature_scheme("schnorrkel") is schnorrkel
     assert get_signature_scheme("rfc9496") is ristretto
