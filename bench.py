@@ -88,8 +88,7 @@ def _p99(times_s: list[float]) -> float:
 
 def _mk_engine(cap, recips, batch, stash=None, seed=0, density=2, cipher_impl="jnp",
                vphases_impl=None, cipher_rounds=8, mailbox_cap=None,
-               sort_impl=None, posmap_impl=None, tree_top_cache=None,
-               evict_every=None):
+               sort_impl=None, posmap_impl=None, tree_top_cache=None):
     import jax
 
     from grapevine_tpu.config import GrapevineConfig
@@ -109,7 +108,6 @@ def _mk_engine(cap, recips, batch, stash=None, seed=0, density=2, cipher_impl="j
         sort_impl=sort_impl,
         posmap_impl=posmap_impl,
         tree_top_cache_levels=tree_top_cache,
-        evict_every=evict_every,
         **extra,
     )
     ecfg = EngineConfig.from_config(cfg)
@@ -990,224 +988,6 @@ def bench_tree_cache_ab(smoke):
     return out
 
 
-def bench_evict_ab(smoke):
-    """Config 4f: delayed batched eviction A/B (PR 15; ROADMAP item 1).
-
-    Two scopes, both interleaved min-of-N (the vphases/sort/posmap/
-    tree_cache_ab methodology), cipher ON in both — the amortized
-    encrypt work is half the claim:
-
-    - **machinery**: one records-shaped ORAM isolated (trivial apply
-      callback). Per E arm the component programs are timed separately
-      — the fetch-only round and the flush, each its own jit (an
-      unrolled E-round window in ONE jit would pay an O(E·B) compile
-      that blows the bench cap at E=8/B=1024 without changing what is
-      measured) — and the honest amortized per-round cost is
-      fetch + flush/E. The fetch/e1 ratio is the measured fetch-only
-      fraction, the floor the amortized cost approaches as E grows
-      (the ISSUE-15 acceptance comparator).
-    - **whole round**: engine-level sweep over E × B — what a serving
-      round actually pays with vphases/posmap/response machinery in the
-      loop, same window-averaged timing through the jitted
-      engine_round_step + engine_flush_step pair.
-
-    Honest-reporting note: on this 2-vCPU sandbox the scatter+encrypt
-    half is large (cipher rows + XLA scatter on the host), so the CPU
-    win is real but the flush cannot overlap a device window here —
-    the on-chip number (flush riding the bubble-ratio idle window) is
-    not measured on the chip. Override sweeps
-    with GRAPEVINE_EVICT_AB_BS / GRAPEVINE_EVICT_AB_ES /
-    GRAPEVINE_EVICT_AB_CAPS."""
-    import os
-    import time as _time
-
-    import jax
-    import jax.numpy as jnp
-
-    from grapevine_tpu.engine.round_step import engine_flush_step
-    from grapevine_tpu.oram.path_oram import (
-        OramConfig,
-        derive_evict_buffer_slots,
-        evict_buffer_private_bytes,
-        init_oram,
-    )
-    from grapevine_tpu.oram.round import oram_flush, oram_round
-
-    reps = 3 if smoke else 7
-    out = {"machinery": {}, "sweep": {}}
-
-    # --- machinery: one ORAM isolated, cap × B × E grid ----------------
-    caps = [
-        int(x)
-        for x in os.environ.get(
-            "GRAPEVINE_EVICT_AB_CAPS", "4096" if smoke else "65536"
-        ).split(",")
-    ]
-    bs_m = (64,) if smoke else (256, 1024)
-    es_m = (1, 2) if smoke else (1, 2, 4, 8)
-    rng = np.random.default_rng(6)
-    for cap_n in caps:
-        height = max(1, cap_n.bit_length() - 2)  # density-2 payload shape
-        for b in bs_m:
-            idxs = jnp.asarray(
-                rng.integers(0, cap_n + 1, b).astype(np.uint32)
-            )
-            nl = jnp.asarray(
-                rng.integers(0, 1 << height, b).astype(np.uint32)
-            )
-            dl = jnp.asarray(
-                rng.integers(0, 1 << height, b).astype(np.uint32)
-            )
-            grid = {}
-            for e in es_m:
-                cfg = OramConfig(
-                    height=height, value_words=64, n_blocks=cap_n,
-                    cipher_rounds=8, stash_size=max(96, b // 2 + 96),
-                    evict_window=e,
-                    evict_fetch_count=b if e > 1 else 0,
-                    evict_buffer_slots=(
-                        derive_evict_buffer_slots(cap_n, e, b, 4)
-                        if e > 1 else 0
-                    ),
-                )
-                state = init_oram(cfg, jax.random.PRNGKey(1))
-
-                def apply_batch(vals0, present0):
-                    return jnp.sum(vals0, axis=1), vals0, present0
-
-                def one_round(st, cfg=cfg):
-                    # full-output rule: the new state must be live or
-                    # XLA DCEs the write half of the round
-                    return oram_round(cfg, st, idxs, nl, dl, apply_batch)
-
-                jit_round = jax.jit(one_round)
-                t_round = _min_of(jit_round, (state,), reps)
-                entry = {
-                    "buffer_kib": round(
-                        evict_buffer_private_bytes(cfg) / 1024, 1
-                    ),
-                }
-                if e > 1:
-                    entry["fetch_round_ms"] = round(t_round * 1e3, 3)
-                    # flush timed at a 1-round fill: every flush shape
-                    # (target slots, cipher rows, working set) is a
-                    # static function of the geometry — obliviousness
-                    # means fill level cannot change the cost
-                    st1, _, _ = jit_round(state)
-                    t_flush = _min_of(
-                        jax.jit(lambda s, cfg=cfg: oram_flush(cfg, s)),
-                        (st1,), reps,
-                    )
-                    entry["flush_ms"] = round(t_flush * 1e3, 3)
-                    entry["amortized_round_ms"] = round(
-                        (t_round + t_flush / e) * 1e3, 3
-                    )
-                else:
-                    entry["amortized_round_ms"] = round(t_round * 1e3, 3)
-                grid[f"e{e}"] = entry
-            base = grid["e1"]["amortized_round_ms"]
-            for e in es_m[1:]:
-                g = grid[f"e{e}"]
-                g["speedup_over_e1"] = round(
-                    base / g["amortized_round_ms"], 3
-                )
-                g["fetch_fraction_of_e1"] = round(
-                    g["fetch_round_ms"] / base, 3
-                )
-            grid["model"] = _model_ab(
-                "evict",
-                min((f"e{e}" for e in es_m),
-                    key=lambda a: grid[a]["amortized_round_ms"]),
-                scope="machinery", cap_n=cap_n, batch=b,
-                arms=list(es_m),
-            )
-            out["machinery"][f"round_cap{cap_n}_b{b}"] = grid
-
-    # --- whole round: evict_every the only knob ------------------------
-    sweep = [
-        int(x)
-        for x in os.environ.get(
-            "GRAPEVINE_EVICT_AB_BS", "64" if smoke else "256,1024"
-        ).split(",")
-    ]
-    es = [
-        int(x)
-        for x in os.environ.get(
-            "GRAPEVINE_EVICT_AB_ES", "1,2" if smoke else "1,2,4,8"
-        ).split(",")
-    ]
-    n_windows = 2 if smoke else 5
-    for B in sweep:
-        ctxs = {}
-        for e in es:
-            cfg, ecfg, state, step = _mk_engine(
-                1 << 12, 1 << 9, B, mailbox_cap=8, evict_every=e,
-            )
-            flush = jax.jit(
-                engine_flush_step, static_argnums=(0,),
-                donate_argnums=(1,),
-            )
-            batches = make_batches(3, B, seed=13)
-            state, resp, _ = step(ecfg, state, batches[0])
-            jax.block_until_ready(resp)
-            if e > 1:
-                for _ in range(e - 1):  # finish the first window + warm
-                    state, resp, _ = step(ecfg, state, batches[1])
-                state = flush(ecfg, state)
-                jax.block_until_ready(
-                    jax.tree_util.tree_leaves(state)[0]
-                )
-            ctxs[e] = [ecfg, state, step, flush, batches]
-
-        def one_window(ctx, i, e):
-            ecfg, state, step, flush, batches = ctx
-            t0 = _time.perf_counter()
-            for j in range(e):
-                state, resp, _ = step(
-                    ecfg, state, batches[(i * e + j) % 3]
-                )
-            if e > 1:
-                state = flush(ecfg, state)
-            # block on the WHOLE window output — state included, not
-            # just the last responses: the flush (and the final round's
-            # write half) must finish inside its own arm's timer, or
-            # its device time leaks into the next interleaved arm's
-            # window and the E arms under-report their own flush cost
-            jax.block_until_ready((state, resp))
-            ctx[1] = state
-            return (_time.perf_counter() - t0) / e
-
-        times = {e: [] for e in es}
-        for i in range(n_windows):  # interleaved A/B
-            for e in es:
-                times[e].append(one_window(ctxs[e], i, e))
-        m1 = float(np.min(times[es[0]]))
-        entry = {}
-        for e in es:
-            me = float(np.min(times[e]))
-            entry[f"e{e}"] = {
-                "amortized_round_ms": round(me * 1e3, 2),
-                "median_round_ms": round(
-                    float(np.median(times[e])) * 1e3, 2
-                ),
-                "speedup_over_e1": round(m1 / me, 3),
-            }
-        for e in es:
-            ov = sum(
-                int(np.asarray(getattr(ctxs[e][1], t).overflow))
-                for t in ("rec", "mb")
-            )
-            assert ov == 0, f"overflow at E={e}: {ov}"
-        entry["model"] = _model_ab(
-            "evict",
-            min((f"e{e}" for e in es),
-                key=lambda a: entry[a]["amortized_round_ms"]),
-            scope="sweep", batch=B, arms=list(es),
-        )
-        out["sweep"][str(B)] = entry
-    return out
-
-
 def bench_expiry_sweep(smoke):
     """Config 4: full-bus timestamped eviction scan (reference
     README.md:86-98) at the largest capacity that fits one chip:
@@ -1280,121 +1060,6 @@ def bench_sharded(smoke):
     ops = batch * n_rounds
     return {"ops_per_sec": round(ops / total, 1), "p99_round_ms": round(_p99(times), 2),
             "batch": batch, "capacity_log2": cap.bit_length() - 1, "mesh": n_dev}
-
-
-def bench_sharded_evict_ab(smoke):
-    """Config 5b: owner-masked sharded flush A/B (ISSUE 18; ROADMAP
-    item 1) — delayed batched eviction composed with the bucket-axis
-    mesh. One records-shaped ORAM (the evict_ab machinery geometry,
-    cipher ON, built via costmodel.machinery_oram_cfg so the model
-    prices exactly what is timed) runs sharded per arm over
-    E∈{1,2,4} × shards∈{1,2,4}. Per (s, E>1) arm the fetch-only round
-    and the owner-masked flush are timed as separate jitted shard_map
-    programs (the evict_ab component methodology — an unrolled window
-    in one jit pays an O(E·B) compile without changing what is
-    measured) and amortized as fetch + flush/E.
-    ``fetch_fraction_of_e1`` is the ISSUE-18 acceptance comparator:
-    the steady non-flush sharded round vs the SAME-mesh E=1 round.
-
-    Needs >= 2 devices (main() leaves it out, and says so on stderr,
-    otherwise). On --smoke's virtual CPU devices cross-shard wall-clock
-    ratios would measure vCPU timeslicing, so every reported ratio
-    stays WITHIN one mesh width. Not measured on the chip."""
-    import jax
-    import jax.numpy as jnp
-
-    n_dev = len(jax.devices())
-
-    from jax.sharding import NamedSharding, PartitionSpec as P
-
-    from grapevine_tpu.analysis.costmodel import machinery_oram_cfg
-    from grapevine_tpu.oram.path_oram import init_oram
-    from grapevine_tpu.oram.round import oram_flush, oram_round
-    from grapevine_tpu.parallel.mesh import (
-        TREE_AXIS,
-        _oram_specs,
-        make_mesh,
-    )
-
-    reps = 3 if smoke else 7
-    cap_n, b = (4096, 64) if smoke else (65536, 256)
-    es = (1, 2, 4)
-    shard_arms = [s for s in (1, 2, 4) if s <= n_dev]
-    rng = np.random.default_rng(18)
-    height = max(1, cap_n.bit_length() - 2)
-    idxs = jnp.asarray(rng.integers(0, cap_n + 1, b).astype(np.uint32))
-    nl = jnp.asarray(rng.integers(0, 1 << height, b).astype(np.uint32))
-    dl = jnp.asarray(rng.integers(0, 1 << height, b).astype(np.uint32))
-    specs = _oram_specs()
-    out = {
-        "machinery": {},
-        # geometry keys (tools/check_perf_regression.py): a re-swept
-        # arm grid is a different line, never a regression comparison
-        "shard_count": ",".join(str(s) for s in shard_arms),
-        "evict_every": ",".join(str(e) for e in es),
-    }
-    for s in shard_arms:
-        mesh = make_mesh(jax.devices()[:s])
-        grid = {}
-        for e in es:
-            cfg = machinery_oram_cfg(cap_n, b, e=e)
-            assert cfg.n_buckets_padded % s == 0
-            state = jax.tree.map(
-                lambda sp, x: jax.device_put(x, NamedSharding(mesh, sp)),
-                specs, init_oram(cfg, jax.random.PRNGKey(1)),
-                is_leaf=lambda sp: isinstance(sp, P),
-            )
-
-            def apply_batch(vals0, present0):
-                return jnp.sum(vals0, axis=1), vals0, present0
-
-            def one_round(st, cfg=cfg):
-                return oram_round(cfg, st, idxs, nl, dl, apply_batch,
-                                  axis_name=TREE_AXIS)
-
-            jit_round = jax.jit(jax.shard_map(
-                one_round, mesh=mesh, in_specs=(specs,),
-                out_specs=(specs, P(), P()), check_vma=False,
-            ))
-            t_round = _min_of(jit_round, (state,), reps)
-            entry = {}
-            if e > 1:
-                entry["fetch_round_ms"] = round(t_round * 1e3, 3)
-                # flush timed at a 1-round fill: every flush shape is a
-                # static function of the geometry (obliviousness means
-                # fill level cannot change the cost)
-                st1, _, _ = jit_round(state)
-                jit_flush = jax.jit(jax.shard_map(
-                    lambda st, cfg=cfg: oram_flush(cfg, st, TREE_AXIS),
-                    mesh=mesh, in_specs=(specs,), out_specs=specs,
-                    check_vma=False,
-                ))
-                t_flush = _min_of(jit_flush, (st1,), reps)
-                entry["flush_ms"] = round(t_flush * 1e3, 3)
-                entry["amortized_round_ms"] = round(
-                    (t_round + t_flush / e) * 1e3, 3
-                )
-            else:
-                entry["amortized_round_ms"] = round(t_round * 1e3, 3)
-            grid[f"e{e}"] = entry
-        base = grid["e1"]["amortized_round_ms"]
-        for e in es[1:]:
-            g = grid[f"e{e}"]
-            g["speedup_over_e1"] = round(
-                base / g["amortized_round_ms"], 3
-            )
-            g["fetch_fraction_of_e1"] = round(
-                g["fetch_round_ms"] / base, 3
-            )
-        grid["model"] = _model_ab(
-            "sharded_evict",
-            min((f"e{e}" for e in es),
-                key=lambda a: grid[a]["amortized_round_ms"]),
-            scope="machinery", cap_n=cap_n, batch=b, arms=list(es),
-            shards=s,
-        )
-        out["machinery"][f"round_cap{cap_n}_b{b}_s{s}"] = grid
-    return out
 
 
 def bench_server_loopback(smoke):
@@ -1959,9 +1624,9 @@ def bench_load_scenarios(smoke):
     PASS, else this config errors and ``--smoke`` fails rc!=0.
 
     Second pass (ISSUE 20): the same suite reruns through the
-    multiprocess frontend — hostpipe pool + SLO-adaptive windows +
-    flush-aware collection — against a fresh engine, with the same
-    verdict acceptance plus a knee-no-worse gate vs the first pass."""
+    multiprocess frontend — hostpipe pool + SLO-adaptive windows —
+    against a fresh engine, with the same verdict acceptance plus a
+    knee-no-worse gate vs the first pass."""
     from grapevine_tpu.config import GrapevineConfig
     from grapevine_tpu.engine.batcher import GrapevineEngine
     from grapevine_tpu.load import (
@@ -2097,9 +1762,9 @@ def bench_load_scenarios(smoke):
     # --- second pass: the multiprocess frontend (ISSUE 20) ------------
     # Same engine, same calibrated schedules, but the scheduler now
     # carries the full host pipeline: a 2-worker hostpipe pool planted
-    # for verify fan-out, the SLO-adaptive window policy fed by the
-    # workload telemetry, and a flush-aware collection window. The
-    # acceptance is behavioral, not throughput: every honest generator
+    # for verify fan-out and the SLO-adaptive window policy fed by the
+    # workload telemetry. The acceptance is behavioral, not
+    # throughput: every honest generator
     # must still PASS the leak audit (the adaptive window is driven by
     # public aggregates only — a contents-driven window would flip the
     # detectors), the probe campaign must still end SUSPECT, and the
@@ -2122,7 +1787,6 @@ def bench_load_scenarios(smoke):
 
     pool = HostPipeline(2, registry=TelemetryRegistry())
     adaptive = AdaptiveBatchPolicy(batch, 0.008, 0.002, workload=wl)
-    delayed = getattr(engine, "_flush_step", None) is not None
     hp: dict = {"scenarios": {}, "worker_count": 2, "adaptive_batch": True}
     try:
         for name, schedule in schedules.items():
@@ -2130,18 +1794,13 @@ def bench_load_scenarios(smoke):
                 mb_leaves=engine.ecfg.mb.leaves,
                 rec_leaves=engine.ecfg.rec.leaves,
                 mb_choices=engine.ecfg.mb_choices,
-                # the flush-cadence detector audits the soak whenever
-                # delayed eviction is on: window stretches must never
-                # move the flush itself
-                flush_every=engine.evict_every if delayed else None,
             )
             sink = (
                 ProbeCampaignInjector(mon, engine.ecfg)
                 if name == "adversarial" else mon
             )
             engine.attach_leakmon(sink)
-            sched = BatchScheduler(engine, clock=lambda: NOW,
-                                   flush_window_ms=4.0)
+            sched = BatchScheduler(engine, clock=lambda: NOW)
             sched.hostpipe = pool
             sched.adaptive = adaptive
             try:
@@ -2376,11 +2035,10 @@ def bench_failover_ab(smoke):
     (engine/replication.py). The standby catches up live; then the
     link is cut, the primary appends a controlled durable tail of
     exactly ``tail_frames`` journal records past the standby's applied
-    seq, and ``promote()`` is timed: fence plant + tail drain + pending
-    flush completion + fsync. Three tails — empty (pure fencing floor),
-    E·4 (a few flush windows), and one full checkpoint interval (the
-    worst legal tail: any longer and the standby would bootstrap from
-    the next checkpoint instead). RPO is asserted, not claimed: the
+    seq, and ``promote()`` is timed: fence plant + tail drain + fsync.
+    Three tails — empty (pure fencing floor), 8 frames, and one full
+    checkpoint interval (the worst legal tail: any longer and the
+    standby would bootstrap from the next checkpoint instead). RPO is asserted, not claimed: the
     promoted state must hash bit-identical to the dead primary's.
 
     ``tail_frames`` (the checkpoint interval) is the geometry key:
@@ -2399,12 +2057,10 @@ def bench_failover_ab(smoke):
     from grapevine_tpu.wire.records import QueryRequest, RequestRecord
 
     batch = 4
-    evict_every = 2
     ckpt_interval = 12 if smoke else 32
     cfg = GrapevineConfig(
         max_messages=64, max_recipients=8, mailbox_cap=4,
         batch_size=batch, stash_size=64, bucket_cipher_rounds=0,
-        evict_every=evict_every,
     )
     idents = identity_pool(8)
 
@@ -2423,11 +2079,10 @@ def bench_failover_ab(smoke):
 
     tails = {
         "rto_empty_tail_ms": 0,
-        "rto_e4_tail_ms": evict_every * 4,
+        "rto_8_tail_ms": 8,
         "rto_full_tail_ms": ckpt_interval,
     }
-    out = {"tail_frames": ckpt_interval, "evict_every": evict_every,
-           "rpo_frames": 0}
+    out = {"tail_frames": ckpt_interval, "rpo_frames": 0}
     for metric, tail in tails.items():
         with _tempfile.TemporaryDirectory(prefix="bench-failover-") as root:
             pdir = os.path.join(root, "primary")
@@ -2490,17 +2145,17 @@ def bench_failover_ab(smoke):
             assert live_hash == dead_hash, (
                 "promoted state is not bit-identical to the dead primary"
             )
-            assert info["drained_frames"] >= tail - evict_every, (
-                f"tail drain too short: {info['drained_frames']} < ~{tail}"
+            assert info["drained_frames"] >= tail, (
+                f"tail drain too short: {info['drained_frames']} < {tail}"
             )
             out[metric] = round(info["rto_seconds"] * 1e3, 2)
             replica.close()
     assert out["rto_full_tail_ms"] < 60_000, (
         f"full-interval tail replay blew the RTO budget: {out}"
     )
-    print(f"[bench]   failover_ab: rto empty/{tails['rto_e4_tail_ms']}f/"
+    print(f"[bench]   failover_ab: rto empty/{tails['rto_8_tail_ms']}f/"
           f"{out['tail_frames']}f = {out['rto_empty_tail_ms']}/"
-          f"{out['rto_e4_tail_ms']}/{out['rto_full_tail_ms']} ms "
+          f"{out['rto_8_tail_ms']}/{out['rto_full_tail_ms']} ms "
           f"(rpo 0, bit-identical)", file=sys.stderr, flush=True)
     return out
 
@@ -2518,10 +2173,8 @@ CONFIGS = [
     ("sort_ab", bench_sort_ab),
     ("posmap_ab", bench_posmap_ab),
     ("tree_cache_ab", bench_tree_cache_ab),
-    ("evict_ab", bench_evict_ab),
     ("expiry_sweep", bench_expiry_sweep),
     ("sharded", bench_sharded),
-    ("sharded_evict_ab", bench_sharded_evict_ab),
     ("server_loopback", bench_server_loopback),
     ("host_pipeline_ab", bench_host_pipeline_ab),
     ("slo_loopback", bench_slo_loopback),
@@ -2534,7 +2187,7 @@ CONFIGS = [
 
 #: configs that build a device mesh: left out of the run (and named on
 #: stderr) when fewer than two devices are visible
-MESH_CONFIGS = ("sharded", "sharded_evict_ab")
+MESH_CONFIGS = ("sharded",)
 
 
 def _device() -> dict:

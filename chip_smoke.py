@@ -417,7 +417,6 @@ def serve_and_compare(cfg, seed: int, label: str) -> None:
         resolved={"vphases_impl": engine.ecfg.vphases_impl,
                   "sort_impl": engine.ecfg.sort_impl,
                   "tree_top_cache_levels": engine.ecfg.tree_top_cache_levels,
-                  "evict_every": engine.ecfg.evict_every,
                   "pipeline_depth": engine.pipeline_depth},
         state_bytes=sum(x.nbytes for x in leaves),
         state_bytes_per_device=per_device)

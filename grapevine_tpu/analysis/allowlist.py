@@ -65,52 +65,20 @@ _ORAM_CORE = (
     _A("scatter", "oram/round.py:_bucket_owner_map",
        "owner election: one scatter-min over exactly the per-path "
        "heap slots of the round — B*(path_len-Le) under the levels "
-       "the batch covers, B*path_len in the fetch-only round — into "
-       "a private map of fixed shape, whatever the leaves; covered "
-       "buckets are never looked up in it"),
+       "the batch covers — into a private map of fixed shape, "
+       "whatever the leaves; covered buckets are never looked up in "
+       "it"),
     _A("gather", "oram/round.py:occurrence_masks_sorted",
        "sorted dedup: permutation/boundary gathers over fixed [B] "
        "arrays — oblivious-sort data movement, schedule fixed by B"),
     _A("gather", "oram/round.py:_assign_evictions",
        "eviction assignment: sort-permutation and bucket-map gathers "
-       "over the fixed working set — oblivious permutation plumbing; "
-       "ONE body serves per-round eviction (bucket -> output row over "
-       "[W]: covered buckets their own id without a lookup, deeper "
-       "buckets their owner copy) and the delayed flush (public "
-       "deduplicated targets over [C+S])"),
+       "over the fixed working set — oblivious permutation plumbing "
+       "(bucket -> output row over [W]: covered buckets their own id "
+       "without a lookup, deeper buckets their owner copy)"),
     _A("scatter", "oram/round.py:_assign_evictions",
        "eviction assignment: inverse-permutation scatters over the "
-       "fixed working set — every row written exactly once per pass "
-       "(both the per-round and the flush layouts)"),
-    # -- delayed batched eviction (PR 15): the fetch-only round and the
-    # batched flush. The flush's bucket *targets* derive only from the
-    # public window ledger (ebuf_paths — past transcript); under a
-    # recursive posmap the blanket ``state.posmap`` pytree anchor
-    # over-approximates and taints the INNER tree's ledger too, which
-    # is why ledger-indexed sinks appear here at all (the engine-level
-    # anchors leave the outer ledger untainted, and the row accounting
-    # in check_tree_cache_oblivious.py pins the schedule shape).
-    _A("gather", "oram/round.py:_oram_fetch_round",
-       "fetch round: path fetch + stale-tag reads indexed by one-time "
-       "uniform leaves (the Path-ORAM invariant), plus private "
-       "working-set reads (block->row map, cache-top planes) on the "
-       "fixed per-round schedule — the E=1 round's reads minus all "
-       "write-back"),
-    _A("scatter", "oram/round.py:_oram_fetch_round",
-       "fetch round commits into private planes only: working rows, "
-       "the buffer∪stash recompaction, and the fetch-tag mark over "
-       "exactly B*path_len one-time-leaf slots — zero HBM tree "
-       "scatters (CI-audited row accounting)"),
-    _A("dynamic_update_slice", "oram/round.py:_oram_fetch_round",
-       "window-ledger append at row ebuf_rounds*F — the start index is "
-       "the public round counter; flagged only under the recursive "
-       "posmap's blanket pytree taint (the inner counter rides "
-       "state.posmap)"),
-    _A("scatter", "oram/round.py:oram_flush",
-       "flush write-back: owner-masked scatters into exactly the "
-       "window's fetched buckets (write transcript = union of the "
-       "window's read transcripts) plus stash recompaction into "
-       "private planes — unique in-bounds targets throughout"),
+       "fixed working set — every row written exactly once per pass"),
 )
 
 #: position-map resolution (flat table and recursive internal ORAM)

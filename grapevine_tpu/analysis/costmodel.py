@@ -5,20 +5,18 @@ The CI censuses (tools/check_tree_cache_oblivious.py) already derive the
 round's HBM row traffic from the traced jaxpr — and then throw it away.
 This module keeps it: the same numbers become a :class:`CostLedger` —
 per-phase HBM bytes (gather/scatter rows × row bytes), cipher rows, sort
-key-volume, scatter elements, and the flush-amortized steady-state round
+key-volume, scatter elements, and the steady-state round
 — computed TWICE, from two sources that share no code path:
 
-1. **Analytic** (:func:`oram_round_rows` / :func:`oram_flush_rows` /
-   :func:`engine_round_rows` / :func:`expiry_sweep_rows`): a pure
-   function of geometry × knobs (``vphases/sort/posmap/cache-k/
-   evict_every``), written from the round's documented schedule — the
-   E=1 fetch moves each of the top ``Ld`` levels once and
-   ``B·(path_len−Ld)`` per-path rows below them per HBM plane with the
-   cache planes passed whole, the E>1 fetch ``B·(path_len−k)`` rows and
-   ``B·k`` cache rows, the recursive leaf plane re-gathers the nonce
-   plane, E=1 write-back mirrors the fetch, a flush scatters exactly
-   ``flush_target_slots`` rows with zero gathers, and the expiry sweep
-   streams every tree plane through its chunked scan exactly once.
+1. **Analytic** (:func:`oram_round_rows` / :func:`engine_round_rows` /
+   :func:`expiry_sweep_rows`): a pure function of geometry × knobs
+   (``vphases/sort/posmap/cache-k``), written from the round's
+   documented schedule — the fetch moves each of the top ``Ld`` levels
+   once and ``B·(path_len−Ld)`` per-path rows below them per HBM plane
+   with the cache planes passed whole, the recursive leaf plane
+   re-gathers the nonce plane, write-back mirrors the fetch, and the
+   expiry sweep streams every tree plane through its chunked scan
+   exactly once.
 2. **Traced** (:func:`traced_access_rows` / :func:`traced_scan_rows`):
    an interpreter over the shared :mod:`.jaxpr_walk` equation stream —
    the identical accounting the obliviousness censuses gate on.
@@ -57,7 +55,7 @@ WORD_BYTES = 4
 
 #: phase labels the ledger (and the grapevine_cost_* gauges) aggregate
 #: over — public schedule structure, never data
-COST_PHASES = ("fetch", "writeback", "flush", "sweep")
+COST_PHASES = ("fetch", "writeback", "sweep")
 
 
 class CostModelMismatch(AssertionError):
@@ -102,7 +100,7 @@ class PlaneRows:
 
 
 def oram_planes(cfg, prefix: str = "") -> dict:
-    """Every HBM plane one ``oram_round``/``oram_flush`` at geometry
+    """Every HBM plane one ``oram_round`` at geometry
     ``cfg`` can touch, in the shared ``plane_rows`` declaration format
     (name -> (shape, divisor)) — the tree-cache census's declarations
     plus the nonce plane's recursive alias and the internal posmap
@@ -131,7 +129,7 @@ def oram_planes(cfg, prefix: str = "") -> dict:
 
 
 def _round_dense_levels(cfg, b: int) -> int:
-    """Top levels one E=1 ``oram_round`` of ``b`` paths moves whole:
+    """Top levels one ``oram_round`` of ``b`` paths moves whole:
     every level L with 2^L <= b, never fewer than the cached levels nor
     more than the tree has. Written from the documented schedule, not
     read off ``OramConfig.dense_levels`` — the trace is the referee."""
@@ -143,129 +141,68 @@ def _round_dense_levels(cfg, b: int) -> int:
 
 def _round_hbm_rows(cfg, b: int) -> int:
     """Bucket rows one ``oram_round`` moves per HBM plane and
-    direction: per-path below the cache for the fetch-only E>1 round;
-    for the E=1 round the dense heap range below the cache plus the
-    per-path rows of the levels under it."""
+    direction: the dense heap range below the cache plus the per-path
+    rows of the levels under it."""
     k = cfg.top_cache_levels
-    if cfg.delayed_eviction:
-        return b * (cfg.path_len - k)
     ld = _round_dense_levels(cfg, b)
     return ((1 << ld) - (1 << k)) + b * (cfg.path_len - ld)
 
 
 def oram_round_rows(cfg, b: int, prefix: str = "") -> dict:
     """Predicted rows per plane for ONE ``oram_round(cfg, ·)`` with a
-    batch of ``b`` indices — the E=1 fetch+write-back round, or the
-    delayed-eviction fetch-only round when ``cfg.delayed_eviction``.
+    batch of ``b`` indices.
 
     The schedule being priced (oram/round.py):
 
-    - the E=1 round is level-dense above, per-path below: the top
+    - the round is level-dense above, per-path below: the top
       ``Ld = clamp(floor(log2 b) + 1, k, path_len)`` levels are each
       moved whole, once (`_round_hbm_rows`), so a bottom HBM plane
       (idx, val, nonces; + the leaf plane under a recursive map, which
       re-gathers the nonce plane for its own keystream — the second
       nonce gather) gathers ``R = (2^Ld − 2^k) + b·(path_len − Ld)``
       rows, and the cache planes join and leave the working set whole:
-      no gather or scatter op ever names them (``C = 0``);
-    - E=1 write-back scatters the same row counts back (nonces only
+      no gather or scatter op ever names them (their rows are 0);
+    - write-back scatters the same row counts back (nonces only
       when the at-rest cipher is on — plaintext trees commit no epoch);
-    - the E>1 fetch-only round keeps the per-path layout: ``R =
-      b·(path_len−k)`` gathered rows per HBM plane, ``C = b·k`` per
-      cache plane, and it is HBM-read-only: zero tree/cache scatters
-      (the check_evict_round_accounting claim);
     - a recursive position map resolves the batch through exactly one
       internal round of the same ``b`` (oram/posmap.py), composed here
       under the ``pm_`` prefix.
     """
     z, v = cfg.bucket_slots, cfg.value_words
     n = cfg.n_buckets_padded
-    k = cfg.top_cache_levels
     cb = cfg.cache_buckets
     recursive = cfg.posmap is not None
-    wb = 0 if cfg.delayed_eviction else 1  # write-back present?
     R = _round_hbm_rows(cfg, b)
-    C = b * k if cfg.delayed_eviction else 0
 
     rows = {
-        f"{prefix}tree_idx": PlaneRows((n, z), 1, z, R, wb * R),
-        f"{prefix}tree_val": PlaneRows((n, z * v), 1, z * v, R, wb * R),
+        f"{prefix}tree_idx": PlaneRows((n, z), 1, z, R, R),
+        f"{prefix}tree_val": PlaneRows((n, z * v), 1, z * v, R, R),
         # the fetch always gathers the nonce plane (the keystream input
         # precedes the encrypted? branch); the epoch commit scatter only
         # exists under the cipher. Recursive leaf decrypt re-gathers it.
         f"{prefix}nonces": PlaneRows(
             (n, 2), 1, 2, R * (2 if recursive else 1),
-            wb * R if cfg.encrypted else 0,
+            R if cfg.encrypted else 0,
         ),
     }
     if recursive:
-        rows[f"{prefix}tree_leaf"] = PlaneRows((n, z), 1, z, R, wb * R)
+        rows[f"{prefix}tree_leaf"] = PlaneRows((n, z), 1, z, R, R)
     if cb:
         rows[f"{prefix}cache_idx"] = PlaneRows(
-            (cb * z,), z, z, C, wb * C, hbm=False
+            (cb * z,), z, z, 0, 0, hbm=False
         )
         rows[f"{prefix}cache_val"] = PlaneRows(
-            (cb, z * v), 1, z * v, C, wb * C, hbm=False
+            (cb, z * v), 1, z * v, 0, 0, hbm=False
         )
         if recursive:
             rows[f"{prefix}cache_leaf"] = PlaneRows(
-                (cb * z,), z, z, C, wb * C, hbm=False
+                (cb * z,), z, z, 0, 0, hbm=False
             )
     if recursive:
         from ..oram.posmap import inner_oram_config
 
         rows.update(oram_round_rows(
             inner_oram_config(cfg.posmap), b, prefix=f"{prefix}pm_"
-        ))
-    return rows
-
-
-def flush_target_rows(cfg) -> int:
-    """The analytic flush write-target count — MUST equal
-    ``round.flush_target_slots`` (cross-checked arithmetically by
-    :func:`cross_validate_flush`; the ``min`` is the 1/E amortization
-    past tree saturation)."""
-    return min(cfg.evict_window * cfg.evict_fetch_count * cfg.path_len,
-               cfg.n_buckets_padded)
-
-
-def oram_flush_rows(cfg, prefix: str = "") -> dict:
-    """Predicted rows per plane for ONE ``oram_flush(cfg, ·)``: every
-    plane scatters exactly ``t = flush_target_rows`` rows (the window's
-    fetched buckets, deduplicated), zero gathers anywhere — the window's
-    live rows were pulled into the private buffer at fetch time. A
-    recursive map's internal tree flushes inside the same call."""
-    z, v = cfg.bucket_slots, cfg.value_words
-    n = cfg.n_buckets_padded
-    cb = cfg.cache_buckets
-    recursive = cfg.posmap is not None
-    t = flush_target_rows(cfg)
-
-    rows = {
-        f"{prefix}tree_idx": PlaneRows((n, z), 1, z, 0, t),
-        f"{prefix}tree_val": PlaneRows((n, z * v), 1, z * v, 0, t),
-        f"{prefix}nonces": PlaneRows(
-            (n, 2), 1, 2, 0, t if cfg.encrypted else 0
-        ),
-    }
-    if recursive:
-        rows[f"{prefix}tree_leaf"] = PlaneRows((n, z), 1, z, 0, t)
-    if cb:
-        rows[f"{prefix}cache_idx"] = PlaneRows(
-            (cb * z,), z, z, 0, t, hbm=False
-        )
-        rows[f"{prefix}cache_val"] = PlaneRows(
-            (cb, z * v), 1, z * v, 0, t, hbm=False
-        )
-        if recursive:
-            rows[f"{prefix}cache_leaf"] = PlaneRows(
-                (cb * z,), z, z, 0, t, hbm=False
-            )
-    if recursive:
-        from ..oram.posmap import inner_oram_config
-
-        rows.update(oram_flush_rows(
-            inner_oram_config(cfg.posmap), prefix=f"{prefix}pm_"
         ))
     return rows
 
@@ -284,37 +221,8 @@ def _sharded_plane(name: str) -> bool:
     return base.startswith(("tree_", "nonces"))
 
 
-def shard_local_rows(rows: dict, shards: int) -> dict:
-    """The shard-LOCAL view of an analytic rows dict (ISSUE 18): every
-    sharded plane's leading dim divides by the shard count (one
-    contiguous heap range per chip), while replicated planes — cache,
-    inner posmap trees — keep their full shape. Row COUNTS are
-    untouched: each chip's fetch gathers the round's full uniform
-    row count, masked, from its local range, and each
-    chip's flush dispatches the full uniform ``t``-row drop-mode
-    scatter — the owner mask bounds which rows LAND, never the static
-    per-chip op shape (the leak argument in oram/round.py)."""
-    if shards < 1 or shards & (shards - 1):
-        raise ValueError(f"shards={shards}: want a power of two >= 1")
-    out = {}
-    for name, pr in rows.items():
-        if pr.hbm and _sharded_plane(name):
-            n = pr.shape[0]
-            if n % shards:
-                raise ValueError(
-                    f"{name}: {n} rows do not divide over {shards} "
-                    "shards — the bucket axis shards as contiguous "
-                    "equal heap ranges"
-                )
-            pr = dataclasses.replace(
-                pr, shape=(n // shards,) + tuple(pr.shape[1:])
-            )
-        out[name] = pr
-    return out
-
-
 def engine_planes(ecfg) -> dict:
-    """Both trees' plane declarations for one engine round/flush."""
+    """Both trees' plane declarations for one engine round."""
     return {**oram_planes(ecfg.rec, "rec_"),
             **oram_planes(ecfg.mb, "mb_")}
 
@@ -332,15 +240,6 @@ def engine_round_rows(ecfg) -> dict:
     for name, pr in oram_round_rows(ecfg.mb, b * d, "mb_").items():
         rows[name] = pr.scaled(2)
     return rows
-
-
-def engine_flush_rows(ecfg) -> dict:
-    """One ``engine_flush_step`` = records flush + mailbox flush (runs
-    every ``evict_every`` engine rounds: the records window is E rounds
-    of one fetch each; the mailbox window is 2E rounds, filled at two
-    per engine round — both drain on the same cadence)."""
-    return {**oram_flush_rows(ecfg.rec, "rec_"),
-            **oram_flush_rows(ecfg.mb, "mb_")}
 
 
 # -- analytic derivation: the expiry sweep's chunked full-tree pass -----
@@ -485,50 +384,6 @@ def trace_oram_round(cfg, b: int):
     return jax.make_jaxpr(run)(state, lf, lf, lf, lf)
 
 
-def trace_oram_flush(cfg):
-    import jax
-
-    from ..oram.path_oram import init_oram
-    from ..oram.round import oram_flush
-
-    state = jax.eval_shape(lambda: init_oram(cfg, jax.random.PRNGKey(0)))
-    return jax.make_jaxpr(lambda st: oram_flush(cfg, st))(state)
-
-
-def trace_sharded_oram_flush(cfg, shards: int):
-    """Jaxpr of one owner-masked sharded ``oram_flush`` under
-    ``shard_map`` on a ``shards``-device mesh slice — the engine's
-    exact sharding geometry (parallel/mesh.py), so ``walk_eqns``
-    recurses into the shard body where every sharded plane operand
-    carries its SHARD-LOCAL shape (the
-    tools/check_tree_cache_oblivious.py sharded-audit recipe)."""
-    import jax
-
-    from ..oram.path_oram import init_oram
-    from ..oram.round import oram_flush
-    from ..parallel.mesh import (
-        TREE_AXIS,
-        _oram_specs,
-        make_mesh,
-    )
-
-    devs = jax.devices()
-    if len(devs) < shards:
-        raise ValueError(
-            f"shards={shards} but only {len(devs)} JAX device(s) are "
-            "visible — the sharded flush trace needs a real mesh slice"
-        )
-    mesh = make_mesh(devs[:shards])
-    specs = _oram_specs()
-    state = jax.eval_shape(lambda: init_oram(cfg, jax.random.PRNGKey(0)))
-    fn = jax.shard_map(
-        lambda st: oram_flush(cfg, st, TREE_AXIS),
-        mesh=mesh, in_specs=(specs,), out_specs=specs,
-        check_vma=False,
-    )
-    return jax.make_jaxpr(fn)(state)
-
-
 def _engine_batch_spec(ecfg):
     import jax
     import numpy as np
@@ -557,18 +412,6 @@ def trace_engine_round(ecfg):
     return jax.make_jaxpr(
         lambda st, ba: engine_round_step(ecfg, st, ba)
     )(state, _engine_batch_spec(ecfg))
-
-
-def trace_engine_flush(ecfg):
-    import jax
-
-    from ..engine.round_step import engine_flush_step
-    from ..engine.state import init_engine
-
-    state = jax.eval_shape(lambda: init_engine(ecfg, 0))
-    return jax.make_jaxpr(
-        lambda st: engine_flush_step(ecfg, st)
-    )(state)
 
 
 def trace_expiry_sweep(ecfg):
@@ -631,69 +474,7 @@ def cross_validate_round(cfg, b: int, *, _corrupt=None) -> dict:
         predicted_access_rows(pred),
         traced_access_rows(trace_oram_round(cfg, b), oram_planes(cfg)),
         f"oram_round(b={b}, plen={cfg.path_len}, k={cfg.top_cache_levels},"
-        f" E={cfg.evict_window}, recursive={cfg.posmap is not None})",
-    )
-
-
-def cross_validate_flush(cfg, *, _corrupt=None) -> dict:
-    """One ``oram_flush``: analytic == traced, plus the arithmetic
-    identity of :func:`flush_target_rows` against the shipped
-    ``round.flush_target_slots`` (two derivations of the dedup bound —
-    a model that drops the saturation ``min`` fails here even at
-    unsaturated audit geometry)."""
-    from ..oram.round import flush_target_slots
-
-    t_model = flush_target_rows(cfg)
-    if _corrupt is None and t_model != flush_target_slots(cfg):
-        raise CostModelMismatch(
-            f"flush_target_rows={t_model} != shipped flush_target_slots="
-            f"{flush_target_slots(cfg)}", kind="arithmetic",
-        )
-    pred = oram_flush_rows(cfg)
-    if _corrupt is not None:
-        pred = _corrupt(pred)
-    return _compare(
-        predicted_access_rows(pred),
-        traced_access_rows(trace_oram_flush(cfg), oram_planes(cfg)),
-        f"oram_flush(E={cfg.evict_window}, F={cfg.evict_fetch_count}, "
-        f"t={t_model}, recursive={cfg.posmap is not None})",
-    )
-
-
-def cross_validate_sharded_flush(cfg, shards: int, *,
-                                 _corrupt=None) -> dict:
-    """One owner-masked SHARDED ``oram_flush`` (ISSUE 18): the analytic
-    shard-local rows — full-shape ``t``-row scatters against
-    shard-local plane shapes, replicated inner-posmap planes untouched
-    — must agree bit-exactly with the shard_map-traced census. A model
-    that prices each chip's scatter at its owned share (``t/shards``)
-    fails here as a scatter-undercount: the owner mask bounds which
-    rows land, not the uniform static per-chip op shape."""
-    t = flush_target_rows(cfg)
-    n_local = cfg.n_buckets_padded // shards
-    # audit-geometry ambiguity guard (the tree-cache census's caveat):
-    # the flush compacts private buffers into exactly t-row arrays, so
-    # t (and the buffer slot count) must not collide with any local
-    # plane's leading dim or shape-class attribution goes ambiguous
-    if t == n_local or cfg.evict_buffer_slots == n_local:
-        raise ValueError(
-            f"sharded-flush audit geometry ambiguity: t={t}, "
-            f"buffer={cfg.evict_buffer_slots} vs n_local={n_local} — "
-            "pick a window/fetch count whose dedup bound differs from "
-            "the shard-local bucket count"
-        )
-    pred = shard_local_rows(oram_flush_rows(cfg), shards)
-    if _corrupt is not None:
-        pred = _corrupt(pred)
-    planes = {name: (pr.shape, pr.divisor)
-              for name, pr in shard_local_rows(
-                  oram_flush_rows(cfg), shards).items()}
-    return _compare(
-        predicted_access_rows(pred),
-        traced_access_rows(trace_sharded_oram_flush(cfg, shards), planes),
-        f"sharded_oram_flush(shards={shards}, E={cfg.evict_window}, "
-        f"F={cfg.evict_fetch_count}, t={t}, n_local={n_local}, "
-        f"recursive={cfg.posmap is not None})",
+        f" recursive={cfg.posmap is not None})",
     )
 
 
@@ -707,19 +488,7 @@ def cross_validate_engine_round(ecfg, *, _corrupt=None) -> dict:
     return _compare(
         predicted_access_rows(pred),
         traced_access_rows(trace_engine_round(ecfg), engine_planes(ecfg)),
-        f"engine_round(B={ecfg.batch_size}, D={ecfg.mb_choices}, "
-        f"E={ecfg.evict_every})",
-    )
-
-
-def cross_validate_engine_flush(ecfg, *, _corrupt=None) -> dict:
-    pred = engine_flush_rows(ecfg)
-    if _corrupt is not None:
-        pred = _corrupt(pred)
-    return _compare(
-        predicted_access_rows(pred),
-        traced_access_rows(trace_engine_flush(ecfg), engine_planes(ecfg)),
-        f"engine_flush(E={ecfg.evict_every})",
+        f"engine_round(B={ecfg.batch_size}, D={ecfg.mb_choices})",
     )
 
 
@@ -810,40 +579,30 @@ class PhaseCost:
 @dataclasses.dataclass
 class CostLedger:
     """Per-phase modeled costs for one engine geometry × knob setting,
-    plus the flush-amortized steady-state round aggregate."""
+    plus the steady-state round aggregate."""
 
     phases: dict  # phase name -> PhaseCost
-    evict_every: int
     #: bucket-tree shard count the per-chip views divide over (ISSUE
     #: 18); 1 = single chip. Power of two, like the mesh it models.
     shards: int = 1
 
     @property
     def steady_round_bytes(self) -> float:
-        """HBM bytes per steady-state engine round: fetch + write-back
-        (E=1) + flush/E (E>1). The sweep is operator-cadenced and
-        excluded — it has its own phase entry."""
-        total = (self.phases["fetch"].hbm_bytes
-                 + self.phases["writeback"].hbm_bytes)
-        return total + self.phases["flush"].hbm_bytes / max(
-            1, self.evict_every
-        )
+        """HBM bytes per steady-state engine round: fetch +
+        write-back. The sweep is operator-cadenced and excluded — it
+        has its own phase entry."""
+        return float(self.phases["fetch"].hbm_bytes
+                     + self.phases["writeback"].hbm_bytes)
 
     @property
     def steady_round_cipher_rows(self) -> float:
-        total = (self.phases["fetch"].cipher_rows
-                 + self.phases["writeback"].cipher_rows)
-        return total + self.phases["flush"].cipher_rows / max(
-            1, self.evict_every
-        )
+        return float(self.phases["fetch"].cipher_rows
+                     + self.phases["writeback"].cipher_rows)
 
     @property
     def steady_round_sort_keys(self) -> float:
-        total = (self.phases["fetch"].sort_keys
-                 + self.phases["writeback"].sort_keys)
-        return total + self.phases["flush"].sort_keys / max(
-            1, self.evict_every
-        )
+        return float(self.phases["fetch"].sort_keys
+                     + self.phases["writeback"].sort_keys)
 
     @property
     def per_shard_steady_round_bytes(self) -> float:
@@ -856,11 +615,8 @@ class CostLedger:
         power-of-two division is exact in binary), and replicated-
         plane scatters (inner posmap trees) land in full per chip.
         ``shards=1`` reduces to :attr:`steady_round_bytes` exactly."""
-        total = (self.phases["fetch"].per_chip_bytes(self.shards)
-                 + self.phases["writeback"].per_chip_bytes(self.shards))
-        return total + self.phases["flush"].per_chip_bytes(
-            self.shards
-        ) / max(1, self.evict_every)
+        return float(self.phases["fetch"].per_chip_bytes(self.shards)
+                     + self.phases["writeback"].per_chip_bytes(self.shards))
 
     def floor_ms(self, gbytes_per_s: float) -> float:
         """Roofline round-time floor at a calibrated achieved
@@ -871,18 +627,15 @@ class CostLedger:
 
 def _round_sort_keys(cfg, b: int, sort_impl: str, occ_impl: str) -> int:
     """Sort key-volume of one oram_round: the eviction leaf argsort over
-    the working set (E=1 only — fetch rounds recompact with rank_of,
-    sort-free) plus the dedup group sorts under the scan occurrence
+    the working set plus the dedup group sorts under the scan occurrence
     machinery, composed recursively for the internal map round."""
     z = cfg.bucket_slots
     plen = cfg.path_len
-    keys = 0
-    if not cfg.delayed_eviction:
-        # E=1 working set: stash, every dense bucket once, the per-path
-        # copies below, b insert rows
-        ld = _round_dense_levels(cfg, b)
-        rows = ((1 << ld) - 1) + b * (plen - ld)
-        keys += cfg.stash_size + rows * z + b
+    # working set: stash, every dense bucket once, the per-path copies
+    # below, b insert rows
+    ld = _round_dense_levels(cfg, b)
+    rows = ((1 << ld) - 1) + b * (plen - ld)
+    keys = cfg.stash_size + rows * z + b
     if occ_impl == "scan":
         keys += b  # occurrence group sort
     if cfg.posmap is not None:
@@ -896,46 +649,20 @@ def _round_sort_keys(cfg, b: int, sort_impl: str, occ_impl: str) -> int:
     return keys
 
 
-def _flush_sort_keys(cfg) -> int:
-    """One flush: the public window dedup sort plus the eviction
-    argsort over buffer ∪ stash (recursing into the internal map)."""
-    keys = (cfg.evict_window * cfg.evict_fetch_count * cfg.path_len
-            + cfg.evict_buffer_slots + cfg.stash_size)
-    if cfg.posmap is not None:
-        from ..oram.posmap import inner_oram_config
-
-        keys += _flush_sort_keys(inner_oram_config(cfg.posmap))
-    return keys
-
-
 def _round_cipher_rows(cfg, b: int) -> int:
     """Keystream rows of one oram_round: decrypt the fetched bottom
-    rows (+ the recursive leaf plane's separate stream), and under E=1
-    encrypt the same counts back."""
+    rows (+ the recursive leaf plane's separate stream), and encrypt
+    the same counts back."""
     if not cfg.encrypted:
         inner = 0
     else:
         R = _round_hbm_rows(cfg, b)
         streams = 2 if cfg.posmap is not None else 1  # idx/val + leaf
-        passes = 1 if cfg.delayed_eviction else 2  # fetch (+ write-back)
-        inner = R * streams * passes
+        inner = R * streams * 2  # fetch + write-back
     if cfg.posmap is not None:
         from ..oram.posmap import inner_oram_config
 
         inner += _round_cipher_rows(inner_oram_config(cfg.posmap), b)
-    return inner
-
-
-def _flush_cipher_rows(cfg) -> int:
-    if not cfg.encrypted:
-        inner = 0
-    else:
-        streams = 2 if cfg.posmap is not None else 1
-        inner = flush_target_rows(cfg) * streams
-    if cfg.posmap is not None:
-        from ..oram.posmap import inner_oram_config
-
-        inner += _flush_cipher_rows(inner_oram_config(cfg.posmap))
     return inner
 
 
@@ -961,13 +688,6 @@ def engine_cost_ledger(ecfg, occ_impl: str | None = None,
         n: dataclasses.replace(pr, gather_rows=0)
         for n, pr in round_rows.items()
     })
-    flush = PhaseCost()
-    if ecfg.evict_every > 1:
-        flush.add_rows(engine_flush_rows(ecfg))
-        flush.sort_keys = (_flush_sort_keys(ecfg.rec)
-                           + _flush_sort_keys(ecfg.mb))
-        flush.cipher_rows = (_flush_cipher_rows(ecfg.rec)
-                             + _flush_cipher_rows(ecfg.mb))
     sweep = PhaseCost().add_rows(expiry_sweep_rows(ecfg))
     # the sweep's nonce re-key is a broadcast store over each tree's
     # whole nonce plane (outside the chunk scan)
@@ -987,20 +707,14 @@ def engine_cost_ledger(ecfg, occ_impl: str | None = None,
         _round_sort_keys(ecfg.rec, b, ecfg.sort_impl, occ)
         + 2 * _round_sort_keys(ecfg.mb, b * d, ecfg.sort_impl, occ)
     )
-    if ecfg.evict_every > 1:
-        fetch.cipher_rows = dec_total
-        fetch.sort_keys = sort_total
-    else:
-        # E=1: the fetch/write-back split of the joint round program is
-        # half decrypt, half re-encrypt; the eviction sort rides the
-        # write-back half
-        fetch.cipher_rows = dec_total // 2
-        wb.cipher_rows = dec_total - dec_total // 2
-        wb.sort_keys = sort_total
+    # the fetch/write-back split of the joint round program is half
+    # decrypt, half re-encrypt; the eviction sort rides the write-back
+    # half
+    fetch.cipher_rows = dec_total // 2
+    wb.cipher_rows = dec_total - dec_total // 2
+    wb.sort_keys = sort_total
     return CostLedger(
-        phases={"fetch": fetch, "writeback": wb, "flush": flush,
-                "sweep": sweep},
-        evict_every=ecfg.evict_every,
+        phases={"fetch": fetch, "writeback": wb, "sweep": sweep},
         shards=shards,
     )
 
@@ -1008,23 +722,18 @@ def engine_cost_ledger(ecfg, occ_impl: str | None = None,
 # -- knob A/B verdicts (the model-graded decisions) ---------------------
 
 
-def machinery_oram_cfg(cap_n: int, b: int, *, k: int = 0, e: int = 1):
+def machinery_oram_cfg(cap_n: int, b: int, *, k: int = 0):
     """The records-shaped single-ORAM geometry the bench machinery
-    A/Bs time (bench.py tree_cache_ab/evict_ab: density-2 payload
+    A/Bs time (bench.py tree_cache_ab: density-2 payload
     shape, 64-word values, cipher on) — mirrored here so the model
     prices exactly the banked configuration."""
-    from ..oram.path_oram import OramConfig, derive_evict_buffer_slots
+    from ..oram.path_oram import OramConfig
 
     height = max(1, cap_n.bit_length() - 2)
     return OramConfig(
         height=height, value_words=64, n_blocks=cap_n,
         cipher_rounds=8, stash_size=max(96, b // 2 + 96),
         top_cache_levels=min(k, height),
-        evict_window=e,
-        evict_fetch_count=b if e > 1 else 0,
-        evict_buffer_slots=(
-            derive_evict_buffer_slots(cap_n, e, b, 4) if e > 1 else 0
-        ),
     )
 
 
@@ -1044,37 +753,14 @@ def sweep_engine_ecfg(batch: int, *, cap_log2: int = 12,
 
 
 def oram_steady_bytes(cfg, b: int) -> float:
-    """Amortized HBM bytes per round of one isolated ORAM: the round's
-    gather (+ E=1 write-back) bytes plus flush bytes / E."""
-    total = PhaseCost().add_rows(oram_round_rows(cfg, b)).hbm_bytes
-    if cfg.delayed_eviction:
-        total += (PhaseCost().add_rows(oram_flush_rows(cfg)).hbm_bytes
-                  / cfg.evict_window)
-    return float(total)
-
-
-def oram_sharded_steady_bytes(cfg, b: int, shards: int) -> float:
-    """Per-CHIP amortized HBM bytes per round of one isolated ORAM on a
-    ``shards``-way mesh (ISSUE 18): gather bytes stay at the full
-    uniform per-chip count, owner-masked scatter bytes into the sharded
-    tree planes divide (the uniform-partition idealization — the true
-    per-chip split is path-dependent over the contiguous heap ranges,
-    but the aggregate is exactly the single-chip write set), and
-    replicated inner-posmap scatters land in full. ``shards=1`` equals
-    :func:`oram_steady_bytes` exactly."""
-    if shards < 1 or shards & (shards - 1):
-        raise ValueError(f"shards={shards}: want a power of two >= 1")
-    pc = PhaseCost().add_rows(oram_round_rows(cfg, b))
-    total = pc.per_chip_bytes(shards)
-    if cfg.delayed_eviction:
-        fl = PhaseCost().add_rows(oram_flush_rows(cfg))
-        total += fl.per_chip_bytes(shards) / cfg.evict_window
-    return float(total)
+    """HBM bytes per round of one isolated ORAM: the round's gather
+    and write-back bytes."""
+    return float(PhaseCost().add_rows(oram_round_rows(cfg, b)).hbm_bytes)
 
 
 #: arms whose modeled bytes sit within this fraction of the best arm
 #: are a byte-tie: the verdict then prefers the structurally smaller
-#: arm (less machinery — no dedup sort, no buffer, no private cache)
+#: arm (less machinery — no private cache)
 TIE_BAND = 0.02
 
 
@@ -1090,7 +776,7 @@ def _pick(arms: dict, order) -> str:
 
 def ab_verdict(kind: str, *, scope: str = "machinery",
                cap_n: int = 65536, batch: int = 256, arms=None,
-               backend: str = "cpu", shards: int = 1) -> dict:
+               backend: str = "cpu") -> dict:
     """The model's pick for one shipped A/B config — the number
     bench.py reports next to the measured winner and
     tools/check_cost_model.py grades against every banked
@@ -1099,9 +785,7 @@ def ab_verdict(kind: str, *, scope: str = "machinery",
     The decision rule is modeled amortized HBM bytes with the
     :data:`TIE_BAND` preference for less machinery: a knob arm only
     wins when it actually removes traffic (tree-top cache converts
-    HBM rows to private rows; delayed eviction drops bytes only past
-    window saturation ``E·F·path_len > n_buckets_padded``, where the
-    dedup ``min`` pays off). ``sort`` and ``pipeline`` swap machinery
+    HBM rows to private rows). ``sort`` and ``pipeline`` swap machinery
     without changing plane traffic, so their verdicts are structural
     and flagged in ``basis``.
     """
@@ -1126,45 +810,6 @@ def ab_verdict(kind: str, *, scope: str = "machinery",
             "band (a level the batch covers saves only its own 2^L "
             "rows: a cut of 2-11 % at the banked machinery configs, "
             "inside the band in the engine sweeps)"
-        )
-    elif kind == "evict":
-        es = tuple(arms) if arms else (1, 2, 4, 8)
-        for e in es:
-            if scope == "machinery":
-                cfg = machinery_oram_cfg(cap_n, batch, e=e)
-                nbytes = oram_steady_bytes(cfg, batch)
-            else:
-                led = engine_cost_ledger(sweep_engine_ecfg(
-                    batch, evict_every=e))
-                nbytes = led.steady_round_bytes
-            out["arms"][f"e{e}"] = {"modeled_bytes": int(nbytes)}
-        out["winner"] = _pick(out["arms"], [f"e{e}" for e in es])
-        out["basis"] = (
-            "amortized flush rows = min(E·F·path_len, n_buckets)/E: "
-            "below saturation the E>1 arms tie (the window's dedup "
-            "sort + buffer are pure overhead), past it the min clamps "
-            "and larger E strictly drops bytes; the E=1 round moves "
-            "each level the batch covers once, both directions, so it "
-            "is under every E>1 arm's per-path fetch until E·F paths "
-            "saturate a tree far larger than the batch"
-        )
-    elif kind == "sharded_evict":
-        es = tuple(arms) if arms else (1, 2, 4)
-        out["shards"] = shards
-        for e in es:
-            cfg = machinery_oram_cfg(cap_n, batch, e=e)
-            nbytes = oram_sharded_steady_bytes(cfg, batch, shards)
-            out["arms"][f"e{e}"] = {"modeled_bytes": int(nbytes)}
-        out["winner"] = _pick(out["arms"], [f"e{e}" for e in es])
-        out["basis"] = (
-            "per-chip bytes on the mesh: gathers replicate at the full "
-            "uniform count (the leak argument), owner-masked scatters "
-            "partition /shards with the union exactly the single-chip "
-            "write set — the shard count scales only the scatter half, "
-            "so the E verdict keeps the single-chip structure (byte-"
-            "tie below window saturation, least machinery wins; past "
-            "saturation the dedup min clamps and larger E strictly "
-            "drops per-chip bytes)"
         )
     elif kind == "sort":
         out["arms"] = {"xla": {"model": "W·log2(W) compare sort"},
@@ -1235,21 +880,9 @@ def _drop_nonce_regather(rows):
     return _scale_plane(rows, "nonces", g=0.5)
 
 
-@_cost_mutant("forget_cache_planes", "round_fetch_cached",
-              "gather-undercount")
-def _forget_cache(rows):
-    """A model that prices the cached top levels of the per-path fetch
-    round (E>1) as free. (The E=1 round passes the cache planes whole:
-    no op names them, which `price_dense_levels_per_path` pins from the
-    other side.)"""
-    rows = _scale_plane(rows, "cache_idx", g=0, s=0)
-    rows = _scale_plane(rows, "cache_val", g=0, s=0)
-    return _scale_plane(rows, "cache_leaf", g=0, s=0)
-
-
 @_cost_mutant("forget_dense_range", "round_cached", "gather-undercount")
 def _forget_dense_range(rows):
-    """A model that prices only the per-path rows of the E=1 round and
+    """A model that prices only the per-path rows of the round and
     forgets the dense heap range read above them (at the audit
     geometry b=8, height=5, k=2: 28 rows a plane, 12 of them dense)."""
     return _scale_plane(rows, "tree_val", g=16 / 28)
@@ -1267,29 +900,11 @@ def _price_per_path(rows):
 
 @_cost_mutant("forget_writeback_half", "round", "scatter-undercount")
 def _forget_writeback(rows):
-    """A model that treats the E=1 round as fetch-only (the delayed-
-    eviction schedule applied to the wrong knob setting)."""
+    """A model that treats the round as fetch-only."""
     out = {}
     for name, pr in rows.items():
         out[name] = dataclasses.replace(pr, scatter_rows=0)
     return out
-
-
-@_cost_mutant("halve_flush_targets", "flush", "scatter-undercount")
-def _halve_flush(rows):
-    """A model that halves the flush's deduplicated write set."""
-    return _scale_plane(rows, "tree_val", s=0.5)
-
-
-@_cost_mutant("halve_sharded_flush_scatter", "flush_sharded",
-              "scatter-undercount")
-def _halve_sharded_flush(rows):
-    """A model that prices each chip's flush scatter at its OWNED row
-    share (t/shards) — the ISSUE-18 slip: the owner mask bounds which
-    rows LAND in HBM (the byte ledger's division), never the uniform
-    ``t``-row drop-mode scatter shape every chip dispatches (what the
-    traced census counts — the leak argument)."""
-    return _scale_plane(rows, "tree_val", s=0.5)
 
 
 @_cost_mutant("forget_inner_posmap_round", "round_recursive",
@@ -1331,15 +946,13 @@ def _forget_sweep_val(rows):
 def audit_oram_configs():
     """The shipped trace-only knob matrix the smoke gate and the tests
     cross-validate over: (name, cfg, b) per ``oram_round`` geometry,
-    spanning cache-k × posmap × evict_every (the fetch/flush split).
+    spanning cache-k × posmap.
 
     Audit-geometry discipline (the tree-cache census's caveat, made
     load-bearing here): shape-class attribution is exact only while no
     *private* intermediate shares a declared plane shape — so batch
     sizes are chosen with ``b·(path_len−k)`` (and its cipher-stream
-    doubling) distinct from every padded bucket count, and eviction
-    windows keep ``flush_target_rows < n_buckets_padded`` (saturated
-    flushes compact private buffers into exactly plane-shaped arrays).
+    doubling) distinct from every padded bucket count.
     A violated assumption shows up as a loud mismatch, never a silent
     undercount."""
     from ..oram.path_oram import OramConfig
@@ -1356,92 +969,41 @@ def audit_oram_configs():
         top_cache_levels=2,
         posmap=derive_posmap_spec(32, top_cache_levels=2),
     )
-    evict = OramConfig(height=7, value_words=8, n_blocks=128,
-                       cipher_rounds=8, top_cache_levels=2,
-                       evict_window=2, evict_fetch_count=8,
-                       evict_buffer_slots=64)
-    evict_rec = OramConfig(
-        height=7, value_words=8, n_blocks=128, cipher_rounds=8,
-        top_cache_levels=2, evict_window=2, evict_fetch_count=8,
-        evict_buffer_slots=64,
-        posmap=derive_posmap_spec(128, top_cache_levels=2,
-                                  evict_window=2, evict_fetch_count=8),
-    )
     return [
-        ("flat_k0_e1", flat, 8),
-        ("flat_k2_e1", cached, 8),
-        ("flat_k2_e1_plaintext", plaintext, 8),
-        ("recursive_k2_e1", recursive, 6),
-        ("flat_k2_e2_fetch", evict, 8),
-        ("recursive_k2_e2_fetch", evict_rec, 6),
+        ("flat_k0", flat, 8),
+        ("flat_k2", cached, 8),
+        ("flat_k2_plaintext", plaintext, 8),
+        ("recursive_k2", recursive, 6),
     ]
 
 
-def audit_sharded_flush_configs():
-    """The sharded-flush audit geometries (ISSUE 18): the owner-masked
-    flush cross-validated on the widest mesh slice actually visible
-    (2-way when >=2 devices, else a degenerate 1-way mesh — still a
-    real shard_map trace, so the recipe never silently skips). Flat and
-    recursive (replicated inner trees flushing inside the same pass);
-    ``F=6`` keeps the dedup bound ``t = 2*6*8 = 96`` distinct from the
-    2-way local bucket count 128 (the ambiguity guard)."""
-    import jax
-
-    from ..oram.path_oram import OramConfig
-    from ..oram.posmap import derive_posmap_spec
-
-    shards = 2 if len(jax.devices()) >= 2 else 1
-    geo = dict(height=7, value_words=8, n_blocks=128, cipher_rounds=8,
-               top_cache_levels=2, evict_window=2, evict_fetch_count=6,
-               evict_buffer_slots=64)
-    flat = OramConfig(**geo)
-    rec = OramConfig(**geo, posmap=derive_posmap_spec(
-        128, top_cache_levels=2, evict_window=2, evict_fetch_count=6))
-    return [("sharded_flush_flat", flat, shards),
-            ("sharded_flush_recursive", rec, shards)]
-
-
 def audit_engine_configs():
-    """The engine-level audit geometries: E=1 (joint fetch+write-back
-    round) and E=2 (fetch-only rounds + the flush), both sized so both
-    trees' flush targets stay unsaturated and no private cipher
+    """The engine-level audit geometry, sized so no private cipher
     working set matches a plane's padded bucket count."""
     from ..config import GrapevineConfig
     from ..engine.state import EngineConfig
 
-    e1 = EngineConfig.from_config(GrapevineConfig(
+    return [("engine", EngineConfig.from_config(GrapevineConfig(
         max_messages=1 << 8, max_recipients=1 << 7, batch_size=4,
-    ))
-    e2 = EngineConfig.from_config(GrapevineConfig(
-        max_messages=1 << 8, max_recipients=1 << 8, batch_size=2,
-        evict_every=2,
-    ))
-    return [("engine_e1", e1), ("engine_e2", e2)]
+    )))]
 
 
 def _mutant_fixtures():
     """Small trace-only geometries, one per validator context."""
     by_name = {name: (cfg, b) for name, cfg, b in audit_oram_configs()}
     engines = dict(audit_engine_configs())
-    flat, flat_b = by_name["flat_k0_e1"]
-    cached, cached_b = by_name["flat_k2_e1"]
-    recursive, rec_b = by_name["recursive_k2_e1"]
-    evict, evict_b = by_name["flat_k2_e2_fetch"]
-    _, sh_cfg, sh_n = audit_sharded_flush_configs()[0]
+    flat, flat_b = by_name["flat_k0"]
+    cached, cached_b = by_name["flat_k2"]
+    recursive, rec_b = by_name["recursive_k2"]
     return {
-        "flush_sharded": (cross_validate_sharded_flush,
-                          {"cfg": sh_cfg, "shards": sh_n}),
         "round": (cross_validate_round, {"cfg": flat, "b": flat_b}),
         "round_cached": (cross_validate_round,
                          {"cfg": cached, "b": cached_b}),
         "round_recursive": (cross_validate_round,
                             {"cfg": recursive, "b": rec_b}),
-        "round_fetch_cached": (cross_validate_round,
-                               {"cfg": evict, "b": evict_b}),
-        "flush": (cross_validate_flush, {"cfg": evict}),
         "engine": (cross_validate_engine_round,
-                   {"ecfg": engines["engine_e1"]}),
-        "sweep": (cross_validate_sweep, {"ecfg": engines["engine_e1"]}),
+                   {"ecfg": engines["engine"]}),
+        "sweep": (cross_validate_sweep, {"ecfg": engines["engine"]}),
     }
 
 

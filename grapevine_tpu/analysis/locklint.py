@@ -91,15 +91,6 @@ LOCK_ALLOW: tuple = (
               "fails on the closed pipe with HostWorkerCrash, never a "
               "wrong result — and a stale True only skips crash "
               "handling the close path is about to do anyway"),
-    LockAllow("GrapevineEngine", "_rounds_since_flush",
-              "every write runs under the engine lock "
-              "(_flush_window_locked / recovery); flush_bubble_pending "
-              "takes one unlocked int read for the scheduler's window "
-              "decision — CPython-atomic, one round stale at worst, "
-              "and a stale read only mistimes a collection-window "
-              "stretch (latency, never correctness or cadence: the "
-              "flush itself still fires strictly every evict_every "
-              "rounds under the lock)", reads_only=True),
     LockAllow("GrapevineEngine", "leakmon",
               "attach-before-serve single reference assignment"),
     LockAllow("GrapevineEngine", "tracer",
@@ -110,11 +101,6 @@ LOCK_ALLOW: tuple = (
               "attach-before-serve single reference assignment"),
     LockAllow("GrapevineEngine", "costmon",
               "attach-before-serve single reference assignment"),
-    LockAllow("GrapevineEngine", "_replay_since",
-              "recovery-only scratch (the replay cadence audit): "
-              "written exclusively inside __init__'s single-threaded "
-              "journal replay, before any scheduler/collector thread "
-              "exists; never touched after construction"),
 )
 
 

@@ -10,18 +10,13 @@ into a blanket permission), and the audit run itself errors out.
 
 Obliviousness classes, per ISSUE 12: position-dependent branch,
 key-indexed gather, data-dependent early exit, secret-shaped output,
-un-allowlisted scatter, leaky debug print, python-level branch. An
-eighth (flush-on-buffer-contents, ISSUE 15) pins the delayed-eviction
-cadence — a flush gated on buffer occupancy instead of the round
-counter must FAIL.
+un-allowlisted scatter, leaky debug print, python-level branch.
 
 Overflow classes, per ISSUE 14 (``_RANGE_REGISTRY``, run through
 analysis/rangelint.py): u32 leaf-arithmetic wrap, truncating cast,
 off-by-one axis bound, unbounded scan counter, int32 byte-size
-product — plus, per ISSUE 15, an eviction-buffer index overflow
-(append cursor arithmetic that wraps past the buffer axis). One
-shared runner (check_oblivious's mutant control) proves both
-analyzers alive from a single tier-1 gate.
+product. One shared runner (check_oblivious's mutant control) proves
+both analyzers alive from a single tier-1 gate.
 """
 
 from __future__ import annotations
@@ -138,30 +133,6 @@ def _leaky_debug_print():
     return fn, {"secret": _sds(4), "x": _sds(8)}, ("secret",)
 
 
-@_mutant("flush_on_buffer_contents", "cond-predicate")
-def _flush_on_buffer_contents():
-    """The delayed-eviction failure mode (PR 15): a flush triggered by
-    buffer *occupancy* instead of the round counter. Buffer contents are
-    access-dependent (hot keys dedup to fewer live rows than cold
-    scans), so an occupancy-gated write-back makes the flush *timing* a
-    function of the workload — a recipient-dependent schedule. The
-    production trigger is a pure round count (engine/batcher.py
-    ``_flush_window_locked``); this mutant pins that an occupancy
-    branch cannot slip in unflagged."""
-    import jax.numpy as jnp
-    from jax import lax
-
-    def fn(ebuf_idx, tree):
-        occupancy = jnp.sum(ebuf_idx != jnp.uint32(0xFFFFFFFF))
-        return lax.cond(
-            occupancy > 8,  # "buffer looks full: flush now"
-            lambda: tree + jnp.uint32(1),  # the write-back
-            lambda: tree,
-        )
-
-    return fn, {"ebuf_idx": _sds(16), "tree": _sds(32)}, ("ebuf_idx",)
-
-
 @_mutant("adaptive_batch_from_contents", "cond-predicate")
 def _adaptive_batch_from_contents():
     """The adaptive-batching failure mode (ISSUE 20): a collection
@@ -273,32 +244,6 @@ def _unbounded_scan_counter():
         return jax.lax.scan(body, U32(0), jnp.zeros((1 << 20,), U32))
 
     return fn, {"inc": _sds(2)}, {"inc": (0, 1 << 16)}
-
-
-@_range_mutant("ebuf_index_overflow", "overflow")
-def _ebuf_index_overflow():
-    """The delayed-eviction (ISSUE 15) buffer-cursor failure mode: an
-    append position computed as ``rounds · window_paths`` without the
-    window-invariant reset — at a 2^20-slot ledger a u32 round counter
-    that never resets walks the product past 2^32 and the append cursor
-    wraps to the front of the buffer, silently overwriting live rows.
-    The production program resets ``ebuf_rounds`` at every flush and
-    declares its [0, W] budget (path_oram.RANGELINT_BOUNDS); this
-    mutant drops the reset so rangelint must see the escape."""
-    import jax.numpy as jnp
-
-    U32 = jnp.uint32
-
-    def fn(rounds, leaves):
-        base = rounds[0] * U32(1 << 20)  # unreset counter × window rows
-        return base + leaves
-
-    return fn, {"rounds": _sds(2), "leaves": _sds(8)}, {
-        # the counter bound a missing reset actually leaves you with:
-        # monotone across the run, not the declared [0, W] window
-        "rounds": (0, (1 << 32) - (1 << 16)),
-        "leaves": (0, (1 << 14) - 1),
-    }
 
 
 @_range_mutant("int32_byte_size_product", "overflow")

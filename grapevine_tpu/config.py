@@ -199,24 +199,6 @@ class GrapevineConfig:
                 f"pipeline_depth must be None (auto), 1 or 2, got "
                 f"{self.pipeline_depth!r}"
             )
-        ee = self.evict_every
-        if ee is not None and (not isinstance(ee, int) or ee < 1):
-            raise ValueError(
-                f"evict_every must be None (auto) or an int >= 1, got "
-                f"{ee!r}"
-            )
-        if self.commit == "op" and ee not in (None, 1):
-            raise ValueError(
-                "commit='op' (the differential-oracle engine) supports "
-                "only evict_every=1 — delayed batched eviction rides the "
-                "phase-major batched round"
-            )
-        ebs = self.evict_buffer_slots
-        if ebs is not None and (not isinstance(ebs, int) or ebs < 1):
-            raise ValueError(
-                f"evict_buffer_slots must be None (auto) or an int >= 1, "
-                f"got {ebs!r}"
-            )
         if self.commit == "op" and tc not in (None, 0):
             raise ValueError(
                 "commit='op' (the differential-oracle engine) supports "
@@ -234,7 +216,7 @@ class GrapevineConfig:
         if self.commit == "op" and sh != 1:
             raise ValueError(
                 "commit='op' (the differential-oracle engine) supports "
-                "only shards=1 — the sharded step/flush programs wrap "
+                "only shards=1 — the sharded step wraps "
                 "the phase-major batched round (parallel/mesh.py "
                 "make_sharded_step), and the op-major engine stays "
                 "single-chip as the differential oracle"
@@ -356,50 +338,12 @@ class GrapevineConfig:
     #: ``pipeline_ab``, PERF.md Round 11 has both numbers honestly).
     pipeline_depth: int | None = None
 
-    #: delayed batched eviction (ROADMAP item 1; Palermo arXiv:2411.05400
-    #: and the cloud-storage Path-ORAM line arXiv:1501.01721 both
-    #: decouple write-back from fetch): every E engine rounds the
-    #: scatter+encrypt half of the round runs ONCE as a batched flush
-    #: over the union of the window's fetched paths, and the steady-state
-    #: round is gather+decrypt+stash-update only. Fetched path contents
-    #: accumulate in a bounded, private per-tree **eviction buffer** with
-    #: the stash's standing (checkpointed, journaled, swept, re-keyed
-    #: like the stash; overflow rides the same sticky counter), and the
-    #: flush cadence is a pure function of the round counter — never of
-    #: the buffer's contents — so the write schedule stays
-    #: recipient-independent (CI-audited: tools/check_oblivious.py
-    #: evict axis + the E-round row accounting in
-    #: tools/check_tree_cache_oblivious.py). 1 = bit-for-bit the
-    #: pre-PR-15 evict-every-round program; E > 1 amortizes the
-    #: scatter+encrypt cost 1/E (bench.py ``evict_ab``). Responses and
-    #: final LOGICAL state (live blocks, positions, scalars) are
-    #: bit-identical at every E — physical tree placement legitimately
-    #: differs, which testing/compare.py:assert_logical_content_equal
-    #: normalizes. None = auto: currently 1 on every backend — on this
-    #: host-bound CPU the flush amortization is real but the on-chip
-    #: number (flush overlapped into the device-idle window the
-    #: bubble-ratio gauge prices) is not measured on the chip (the
-    #: vphases/sort/posmap flip-on-evidence playbook). Requires
-    #: commit="phase".
-    evict_every: int | None = None
-
-    #: eviction-buffer capacity (rows) for the payload trees under
-    #: evict_every > 1. None = auto per tree:
-    #: min(blocks, 2·Z·window·fetches + 4·fetches) — ~2 live blocks per
-    #: fetched path per round of headroom, clamped by the whole block
-    #: space (a buffer that can hold every live block can never
-    #: overflow). Sizing theory + the near-overflow canary are
-    #: OPERATIONS.md §19; overflow increments the same sticky counter
-    #: the stash uses and trips the health fold.
-    evict_buffer_slots: int | None = None
-
     #: bucket-tree shard count across the device mesh (parallel/mesh.py):
     #: 1 = single-chip (the default; no mesh machinery compiled), N > 1
     #: = both payload trees (+ nonce planes) shard as contiguous heap
     #: ranges over the first N devices, everything else replicated; the
-    #: engine's round AND flush dispatch through make_sharded_step /
-    #: make_sharded_flush (evict_every composes — the owner-masked
-    #: flush). Deliberately NOT part of EngineConfig and therefore NOT
+    #: engine's round dispatches through make_sharded_step.
+    #: Deliberately NOT part of EngineConfig and therefore NOT
     #: covered by the checkpoint/journal fingerprint: responses, final
     #: state, and the journal stream are bit-identical at every shard
     #: count (tests/test_parallel.py), so a journal written on one chip

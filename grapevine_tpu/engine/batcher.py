@@ -56,7 +56,7 @@ from .state import (
     init_engine,
 )
 from .metrics import EngineMetrics
-from .round_step import engine_flush_step, engine_round_step
+from .round_step import engine_round_step
 from .step import engine_step
 
 _log = logging.getLogger(__name__)
@@ -145,7 +145,7 @@ class PendingRound:
         #: dispatch: the earliest the device could have started it)
         self._t0 = t0
         self._t1 = t0 if t1 is None else t1
-        #: a flush or an expiry sweep went to the device since the round
+        #: an expiry sweep went to the device since the round
         #: before: its time lies between the two rounds' ready stamps,
         #: so this round's ``device`` span is an upper bound
         #: (device_exact 0)
@@ -249,8 +249,7 @@ class PendingRound:
         # order on one thread, so the previous ready stamp is the
         # engine's last. Exact when the device was still running each
         # time the host arrived to wait and ran no other program (a
-        # flush, a sweep) in between (device_exact), else an upper
-        # bound.
+        # sweep) in between (device_exact), else an upper bound.
         spans["inflight"] = (self._t0, t_dm - self._t0)
         prev_ready, prev_waited = eng._last_ready
         d0 = min(max(self._t1, prev_ready), t_dm)
@@ -313,10 +312,10 @@ class GrapevineEngine:
         self.config = config or GrapevineConfig()
         self.ecfg = EngineConfig.from_config(self.config)
         #: bucket-axis sharding (config.py ``shards``; parallel/mesh.py):
-        #: at shards > 1 the step/flush dispatch through the shard_map'd
+        #: at shards > 1 the step and sweep dispatch through the shard_map'd
         #: programs on a mesh over the first N devices. The adapters
         #: below keep the single-chip call signatures (ecfg, state, ...)
-        #: so every dispatch/replay/flush site stays shard-agnostic —
+        #: so every dispatch/replay site stays shard-agnostic —
         #: bit-identical results are the mesh contract, so nothing
         #: downstream (journal, checkpoint, leakmon, oracle suites) can
         #: tell the difference.
@@ -374,39 +373,13 @@ class GrapevineEngine:
             self._sweep = jax.jit(
                 expiry_sweep, static_argnums=(0,), donate_argnums=(1,)
             )
-        #: delayed batched eviction (PR 15, config.py evict_every): the
-        #: resolved cadence E and the jitted flush program. Flush fires
-        #: strictly every E dispatched rounds — a pure function of the
-        #: round counter, never of buffer contents or op mix (the
-        #: schedule-independence claim CI pins) — inside the same lock
-        #: hold as the E-th round, journaled (KIND_FLUSH) before it
-        #: dispatches like everything else. The counter itself is
-        #: recovered from state (rec.ebuf_rounds) so a crash can never
-        #: desynchronize cadence from content.
-        self.evict_every = self.ecfg.evict_every
-        if self.evict_every <= 1:
-            self._flush_step = None
-        elif self._mesh is not None:
-            from ..parallel import make_sharded_flush
-
-            sflush = make_sharded_flush(self.ecfg, self._mesh)
-            self._flush_step = lambda _ecfg, state: sflush(state)
-        else:
-            self._flush_step = jax.jit(
-                engine_flush_step, static_argnums=(0,), donate_argnums=(1,)
-            )
-        self._rounds_since_flush = 0
         #: (perf_counter when the last resolved round was observed
         #: ready, whether the host had to wait for it): what the next
         #: round's own ``device`` span starts from (PendingRound.resolve)
         self._last_ready: tuple[float, bool] = (0.0, False)
-        #: a flush or sweep program was enqueued since the last round's
+        #: a sweep program was enqueued since the last round's
         #: dispatch: the next round's ``device`` span is not its own time
         self._other_device_work = False
-        #: replay-time cadence audit (see _replay_record): rounds seen
-        #: since the last KIND_FLUSH record; None until the first
-        #: replayed record initializes it from the recovered state
-        self._replay_since: int | None = None
         self._lock = threading.Lock()
         #: resolved round-pipeline depth: the max dispatched-but-
         #: unresolved rounds a driver keeps in flight (config.py knob;
@@ -435,8 +408,6 @@ class GrapevineEngine:
             "perpath_bucket_rows per oram_round): %s",
             ", ".join(f"{t}={v}" for t, v in layout.items()),
         )
-        #: last sampled per-tree eviction-buffer occupancy (health view)
-        self._ebuf_counts: dict = {}
         #: streaming obliviousness auditor (obs/leakmon.py), attached by
         #: the serving layer when --leakmon is on; None = no monitoring
         self.leakmon = None
@@ -471,140 +442,20 @@ class GrapevineEngine:
                     self.state, self._replay_record
                 )
                 jax.block_until_ready(self.state.free_top)
-        if self.evict_every > 1:
-            # cadence counter recovered FROM STATE, never from a host
-            # mirror: the records tree runs exactly one fetch round per
-            # engine round, so its window counter IS rounds-since-flush
-            self._rounds_since_flush = int(self.state.rec.ebuf_rounds)
-            if self._rounds_since_flush >= self.evict_every:
-                # a crash landed between the E-th round's journal frame
-                # and its flush frame — complete the pending flush NOW
-                # (journaled), so the replayed journal keeps the exact
-                # [round_E, flush] adjacency an uninterrupted run writes
-                # and recovered placement stays bit-identical to it
-                with self._lock:
-                    self._flush_window_locked(min_rounds=self.evict_every)
-                jax.block_until_ready(self.state.free_top)
 
     def _replay_record(self, state: EngineState, rec) -> EngineState:
         """Apply one journal record through the same jitted programs the
         live path uses — replay IS re-execution, so recovered state is
-        bit-identical by the engine's own determinism.
+        bit-identical by the engine's own determinism."""
+        from .journal import KIND_ROUND
 
-        Cadence audit: the journal frames validate batch geometry but
-        not the eviction cadence (the checkpoint fingerprint covers E;
-        a journal-only recovery would not), so replay cross-checks it —
-        a KIND_FLUSH record on an evict_every=1 engine, or more rounds
-        than one window between flush records on an E>1 engine, means
-        the journal was written under a DIFFERENT cadence and silently
-        replaying it would corrupt the window ledger. Raise instead."""
-        from .journal import JournalError, KIND_FLUSH, KIND_ROUND
-
-        if self._flush_step is not None and self._replay_since is None:
-            # one device read at replay start: the recovered base
-            # state's window position anchors the cadence count
-            self._replay_since = int(state.rec.ebuf_rounds)
         if rec.kind == KIND_ROUND:
-            if self._flush_step is not None:
-                self._replay_since += 1
-                if self._replay_since > self.evict_every:
-                    raise JournalError(
-                        f"journal frame {rec.seq}: {self._replay_since} "
-                        f"rounds since the last flush record but this "
-                        f"engine flushes every {self.evict_every} — the "
-                        "journal was written under a different "
-                        "evict_every; replay requires the identical "
-                        "cadence"
-                    )
             state, _resp, _transcript = self._step(self.ecfg, state, rec.batch)
             return state
-        if rec.kind == KIND_FLUSH:
-            if self._flush_step is None:
-                raise JournalError(
-                    f"journal frame {rec.seq}: delayed-eviction flush "
-                    "record but this engine runs evict_every=1 — replay "
-                    "requires the cadence the journal was written under"
-                )
-            self._replay_since = 0
-            return self._flush_step(self.ecfg, state)
         return self._sweep(
             self.ecfg, state,
             np.uint32(rec.now), np.uint32(rec.period), np.uint32(rec.now_hi),
         )
-
-    # -- delayed batched eviction (PR 15; oram/round.py:oram_flush) -----
-
-    def _flush_window_locked(self, count_round: bool = False,
-                             min_rounds: int = 1) -> bool:
-        """Journal + dispatch one flush when the window is due; caller
-        holds the engine lock (every call site sits directly in a lock
-        region — analysis/locklint.py verifies it statically).
-
-        ``count_round=True`` counts one dispatched round first and
-        flushes only when the window closes (the steady-state cadence —
-        a pure function of the round counter, never of buffer
-        contents); ``count_round=False`` flushes iff at least
-        ``min_rounds`` rounds are buffered (recovery completion passes
-        ``min_rounds=evict_every`` so a crash mid-window never flushes
-        early; ``flush_now`` passes 1). The async dispatch is the
-        point: the flush rides the device queue behind the window's
-        last round, filling the idle window the bubble-ratio gauge
-        prices (the overlap is not measured on the chip) — the
-        ``flush`` phase series measures enqueue
-        cost; device time lands in the next round's ``evict`` wait
-        like all device work."""
-        if self._flush_step is None:
-            return False
-        if count_round:
-            self._rounds_since_flush += 1
-        due = self.evict_every if count_round else max(1, min_rounds)
-        if self._rounds_since_flush < due:
-            return False
-        if self.durability is not None:
-            with self.metrics.time_phase("journal"):
-                self.durability.append_flush()
-        if faults.active():
-            # the kill-at-flush window: the flush frame is durable but
-            # the flush itself has not dispatched
-            faults.crash("flush.pre_dispatch")
-        with self.metrics.time_phase("flush"):
-            self.state = self._flush_step(self.ecfg, self.state)
-        self._other_device_work = True
-        self.metrics.record_flush()
-        if faults.active():
-            faults.crash("flush.post_dispatch")
-        lm = self.leakmon
-        if lm is not None:
-            # flush-cadence audit (obs/leakmon.py note_flush): report
-            # the observed interval before the counter resets; only the
-            # automatic cadence is judged (count_round)
-            note = getattr(lm, "note_flush", None)
-            if note is not None:
-                note(self._rounds_since_flush, scheduled=count_round)
-        self._rounds_since_flush = 0
-        return True
-
-    def flush_now(self) -> bool:
-        """Operator/test hook: flush a partial window immediately
-        (journaled). Returns False when delayed eviction is off or the
-        window is empty. NOT part of the steady-state cadence — the
-        schedule-independence claim is about the automatic trigger."""
-        with self._lock:
-            return self._flush_window_locked()
-
-    def flush_bubble_pending(self) -> bool:
-        """True between a flush dispatch and the next round dispatch:
-        the NEXT collection window overlaps the flush's device time (the
-        bubble the scheduler's flush-aware stretch fills — server/
-        scheduler.py). A pure function of the cadence counter — which is
-        itself a pure function of the round count — never of buffer
-        contents or op mix, so the stretched window leaks nothing the
-        round counter does not (the schedule-independence claim;
-        analysis/mutants.py seeds the contents-dependent variant).
-        Engine start reads as a bubble too: the first window overlaps
-        compilation, which is the same trade. Benign unlocked int read.
-        """
-        return self._flush_step is not None and self._rounds_since_flush == 0
 
     def checkpoint_now(self) -> int | None:
         """Force a sealed checkpoint of the current state (the drain
@@ -773,16 +624,6 @@ class GrapevineEngine:
                 t0, t1, resp, transcript = self._dispatch_round(batch)
             if faults.active():
                 faults.crash("round.post_dispatch")
-            # delayed eviction: the E-th round's flush journals and
-            # dispatches in this same hold — the flush enqueues behind
-            # the round on the device and resolves inside the next
-            # round's evict wait (the overlap window). The span lands
-            # on THIS round's ledger (the window-closing round), so the
-            # tracer and flight recorder show which rounds paid a flush
-            # enqueue — the cadence is public (a pure round count)
-            t_f0 = time.perf_counter()
-            if self._flush_window_locked(count_round=True):
-                spans["flush"] = (t_f0, time.perf_counter() - t_f0)
             if self.durability is not None and self.durability.should_checkpoint():
                 # blocks this round's slot until the sealed state is on
                 # disk — the RTO/RPO trade --checkpoint-every-rounds
@@ -824,9 +665,7 @@ class GrapevineEngine:
             if self.durability is not None:  # same contract as the async path
                 self.durability.append_round(batch, len(reqs))
             self.state, resp, transcript = self._step(self.ecfg, self.state, batch)
-            out = unpack_responses(resp, len(reqs)), np.asarray(transcript)
-            self._flush_window_locked(count_round=True)
-            return out
+            return unpack_responses(resp, len(reqs)), np.asarray(transcript)
 
     def expire(self, now: int, period: int | None = None) -> int:
         """Run the expiry sweep; returns the number of records evicted."""
@@ -885,7 +724,7 @@ class GrapevineEngine:
         reduction every round would serialize the dispatch pipeline for
         a gauge that is only read between scrapes (it is also the
         /metrics endpoint's pre-scrape refresh hook, obs/httpd.py)."""
-        from ..oram.path_oram import evict_buffer_occupancy, stash_occupancy
+        from ..oram.path_oram import stash_occupancy
 
         with self._lock:
             state = self.state
@@ -901,23 +740,8 @@ class GrapevineEngine:
                 name: int(stash_occupancy(tree))
                 for name, tree in trees.items()
             }
-            ebuf = (
-                {
-                    name: int(evict_buffer_occupancy(tree))
-                    for name, tree in trees.items()
-                }
-                if self.evict_every > 1
-                else {}
-            )
-            self._ebuf_counts = ebuf
         for name, n in counts.items():
             self.metrics.observe_stash(name, n)
-        if ebuf:
-            # the buffer-occupancy canary (grapevine_evict_buffer_*):
-            # summed over trees at scrape cadence, high-water kept —
-            # approaching evict_buffer_slots means the sizing theory is
-            # being violated before overflow ever fires
-            self.metrics.observe_evict_buffer(sum(ebuf.values()))
         return counts
 
     def round_layout(self) -> dict:
@@ -956,19 +780,4 @@ class GrapevineEngine:
                 "stash_occupancy": occupancy,
                 **self.metrics.snapshot(),
             }
-            if self.evict_every > 1:
-                # delayed-eviction canary: per-tree buffer occupancy
-                # (sampled by sample_stash above) + capacity, so an
-                # operator sees near-overflow pressure before the shared
-                # sticky overflow counter ever fires. Buffer overflow
-                # rides stash_overflow — the buffer has the stash's
-                # standing, and a drop is a drop.
-                out["evict_buffer_occupancy"] = dict(
-                    getattr(self, "_ebuf_counts", {})
-                )
-                out["evict_buffer_slots"] = {
-                    "rec": self.ecfg.rec.evict_buffer_slots,
-                    "mb": self.ecfg.mb.evict_buffer_slots,
-                }
-                out["evict_rounds_since_flush"] = self._rounds_since_flush
             return out

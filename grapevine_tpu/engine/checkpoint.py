@@ -561,15 +561,6 @@ class DurabilityManager:
         self.note_applied_seq(seq)
         return seq
 
-    def append_flush(self) -> int:
-        """Delayed-eviction flush marker (engine/journal.py KIND_FLUSH);
-        counts toward the checkpoint cadence like rounds and sweeps."""
-        seq = self.journal.append_flush()
-        if self._c_records is not None:
-            self._c_records.inc()
-        self.note_applied_seq(seq)
-        return seq
-
     def append_raw_frame(self, seq: int, frame: bytes) -> int:
         """Follower path (engine/replication.py): persist one shipped
         journal frame verbatim. Counts in the records telemetry exactly

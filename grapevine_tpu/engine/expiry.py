@@ -140,34 +140,14 @@ def _chunked_tree_sweep(cfg: OramConfig, oram: OramState, carry0, body,
         else jnp.zeros((nch, rpc, 0), U32)
     )
 
-    delayed = cfg.delayed_eviction
-    if not delayed:
-        tag3 = jnp.zeros((nch, rpc), U32)
-    elif axis_name is None:
-        tag3 = oram.fetch_tag.reshape(nch, rpc)
-    else:  # the tag plane is replicated: take the owned rows' tags
-        tag3 = jax.lax.dynamic_slice(
-            oram.fetch_tag, (base,), (n,)
-        ).reshape(nch, rpc)
-
     def scan_body(carry, xs):
-        bid, ix, vl, ep, lf, tg = xs
+        bid, ix, vl, ep, lf = xs
         if cfg.encrypted:
             ks = row_keystream(
                 oram.cipher_key, bid, ep, cfg.row_words, cfg.cipher_rounds
             )
             ix = ix ^ ks[:, :z]
             vl = vl ^ ks[:, z:]
-        if delayed:
-            # delayed eviction (PR 15): buckets fetched since the last
-            # flush hold stale copies — their live rows moved to the
-            # eviction buffer (swept separately, like the stash).
-            # Masking them here keeps liveness/recipient counts exact
-            # AND performs the tree-side invalidation for free: the
-            # re-encrypt below writes the cleaned rows back, and the
-            # next flush overwrites these buckets anyway.
-            stale = tg == oram.ebuf_gen
-            ix = jnp.where(stale[:, None], SENTINEL, ix)
         carry, (ix, vl) = body(carry, (ix, vl))
         if cfg.encrypted:
             epn = jnp.broadcast_to(oram.epoch[None, :], (rpc, 2))
@@ -190,7 +170,7 @@ def _chunked_tree_sweep(cfg: OramConfig, oram: OramState, carry0, body,
         return carry, (ix, vl, lf)
 
     carry, (idx_o, val_o, leaf_o) = jax.lax.scan(
-        scan_body, carry0, (bids, idx3, val3, eps, leaf3, tag3)
+        scan_body, carry0, (bids, idx3, val3, eps, leaf3)
     )
     new = oram._replace(
         tree_idx=idx_o.reshape(-1), tree_val=val_o.reshape(n, z * v)
@@ -251,47 +231,26 @@ def expiry_sweep(
         # stash: no cipher, no re-key, same expire body.
         if rcfg.top_cache_levels:
             zc = rcfg.bucket_slots
-            cidx = rec.cache_idx.reshape(-1, zc)
-            if rcfg.delayed_eviction:
-                # stale cached buckets' live rows are in the buffer
-                stale_c = (
-                    rec.fetch_tag[: rcfg.cache_buckets] == rec.ebuf_gen
-                )
-                cidx = jnp.where(stale_c[:, None], SENTINEL, cidx)
             present, (cix, cvl) = rec_body(
                 present,
-                (cidx, rec.cache_val),
+                (rec.cache_idx.reshape(-1, zc), rec.cache_val),
             )
             rec = rec._replace(cache_idx=cix.reshape(-1), cache_val=cvl)
 
-    # stash (and, under delayed eviction, the eviction buffer — same
-    # plaintext private standing) rows sweep directly
-    def rec_private_sweep(pidx, pval):
-        live = pidx != SENTINEL
-        dead = live & _expired(
-            pval[:, REC_TS], pval[:, REC_TSH], now, now_hi, period
-        )
-        return jnp.where(dead, SENTINEL, pidx)
-
-    rec_stash_idx = rec_private_sweep(
-        state.rec.stash_idx, state.rec.stash_val
+    # stash rows are plaintext private state
+    st_live = state.rec.stash_idx != SENTINEL
+    st_dead = st_live & _expired(
+        state.rec.stash_val[:, REC_TS],
+        state.rec.stash_val[:, REC_TSH],
+        now, now_hi, period,
     )
+    rec_stash_idx = jnp.where(st_dead, SENTINEL, state.rec.stash_idx)
     safe = jnp.minimum(
         jnp.where(rec_stash_idx != SENTINEL, rec_stash_idx, U32(n_msgs)),
         U32(n_msgs),
     )
     present = present.at[safe].set(True, mode="drop")
     rec = rec._replace(stash_idx=rec_stash_idx)
-    if rcfg.delayed_eviction:
-        rec_ebuf_idx = rec_private_sweep(
-            state.rec.ebuf_idx, state.rec.ebuf_val
-        )
-        safe = jnp.minimum(
-            jnp.where(rec_ebuf_idx != SENTINEL, rec_ebuf_idx, U32(n_msgs)),
-            U32(n_msgs),
-        )
-        present = present.at[safe].set(True, mode="drop")
-        rec = rec._replace(ebuf_idx=rec_ebuf_idx)
 
     # --- mailbox ORAM: clear expired entries, drop empty mailboxes -----
     k, cap = ecfg.mb_slots, ecfg.mailbox_cap
@@ -343,13 +302,9 @@ def expiry_sweep(
         # the records cache sweep above)
         if ecfg.mb.top_cache_levels:
             zc = ecfg.mb.bucket_slots
-            mcidx = mb.cache_idx.reshape(-1, zc)
-            if ecfg.mb.delayed_eviction:
-                stale_c = (
-                    mb.fetch_tag[: ecfg.mb.cache_buckets] == mb.ebuf_gen
-                )
-                mcidx = jnp.where(stale_c[:, None], SENTINEL, mcidx)
-            mc_idx, mc_val, mc_keys = sweep_mb(mcidx, mb.cache_val)
+            mc_idx, mc_val, mc_keys = sweep_mb(
+                mb.cache_idx.reshape(-1, zc), mb.cache_val
+            )
             recips = recips + live_keys(mc_keys, mc_idx)
             mb = mb._replace(
                 cache_idx=mc_idx.reshape(-1), cache_val=mc_val
@@ -359,13 +314,6 @@ def expiry_sweep(
     )
     recipients = recips + live_keys(stash_keys, mb_stash_idx)
     mb = mb._replace(stash_idx=mb_stash_idx, stash_val=mb_stash_val)
-    if ecfg.mb.delayed_eviction:
-        # the mailbox eviction buffer sweeps exactly like the stash
-        mb_ebuf_idx, mb_ebuf_val, ebuf_keys = sweep_mb(
-            state.mb.ebuf_idx, state.mb.ebuf_val
-        )
-        recipients = recipients + live_keys(ebuf_keys, mb_ebuf_idx)
-        mb = mb._replace(ebuf_idx=mb_ebuf_idx, ebuf_val=mb_ebuf_val)
 
     # --- rebuild the free-block list from surviving record liveness ----
     # stable partition (free indices first, each side in index order):

@@ -19,10 +19,9 @@ segment is a concatenation of frames::
                     | req_type u32[B] | auth u32[B,8] | msg_id u32[B,4]
                     | recipient u32[B,8] | payload u32[B,PW]
              sweep: u8 2 | u32 now | u32 now_hi | u32 period
-             flush: u8 3   (delayed-eviction flush, PR 15 — carries no
-                    payload: the flush is deterministic given the state,
-                    and replay re-executes it in journal order exactly
-                    like rounds and sweeps)
+             (u8 3 is retired, never reused: the marker a
+                    delayed-eviction engine wrote between windows; the
+                    reader refuses it by name)
 
 A frame serializes the *whole* fixed-size batch (padding included)
 whatever the ops inside are — like the checkpoint, its size and write
@@ -65,7 +64,8 @@ _SEAL_OVERHEAD = 12 + 32  # nonce + tag
 
 KIND_ROUND = 1
 KIND_SWEEP = 2
-KIND_FLUSH = 3
+#: retired, never reused (module docstring)
+_KIND_RETIRED = 3
 
 #: round batch columns in serialization order, with their per-op widths
 _ROUND_COLS = (
@@ -284,12 +284,12 @@ class BatchJournal:
                 )
             now, now_hi, period = struct.unpack_from("<III", body, 1)
             return JournalRecord(seq, KIND_SWEEP, None, 0, now, now_hi, period)
-        if kind == KIND_FLUSH:
-            if len(body) != 1:
-                raise JournalError(
-                    f"journal frame {seq}: flush body is {len(body)} bytes"
-                )
-            return JournalRecord(seq, KIND_FLUSH, None, 0, 0, 0, 0)
+        if kind == _KIND_RETIRED:
+            raise JournalError(
+                f"journal frame {seq}: kind {kind} is the window marker of "
+                "a delayed-eviction engine, which this build no longer "
+                "has — the journal cannot be replayed here"
+            )
         if kind != KIND_ROUND:
             raise JournalError(f"journal frame {seq}: unknown kind {kind}")
         n_real, b, now, now_hi = struct.unpack_from("<IIII", body, 1)
@@ -693,12 +693,6 @@ class BatchJournal:
         return self._append(
             struct.pack("<BIII", KIND_SWEEP, now, now_hi, period)
         )
-
-    def append_flush(self) -> int:
-        """Delayed-eviction flush marker: no payload — the flush is a
-        deterministic function of the state, so the record only fixes
-        its position in the replay order."""
-        return self._append(struct.pack("<B", KIND_FLUSH))
 
     def append_raw(self, seq: int, frame: bytes) -> int:
         """Follower-side append of a shipped frame verbatim (the bytes

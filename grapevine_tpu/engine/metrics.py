@@ -68,11 +68,6 @@ class EngineMetrics:
             "grapevine_expiry_sweeps_total", "expiry sweeps run")
         self._c_evicted = r.counter(
             "grapevine_expired_records_total", "records evicted by expiry")
-        self._c_flushes = r.counter(
-            "grapevine_evict_flushes_total",
-            "delayed-eviction window flushes dispatched (cadence is a "
-            "pure function of the round counter — the fleet uniformity "
-            "monitor compares flush phase across shards)")
         self._c_verifies = r.counter(
             "grapevine_batch_verifies_total",
             "round-level batched signature verifications")
@@ -103,16 +98,6 @@ class EngineMetrics:
             "stash_size; overflow means the eviction invariant broke); "
             "rec_pm / mb_pm are a recursive position map's inner trees",
             labels=stashes)
-        self._g_ebuf = r.gauge(
-            "grapevine_evict_buffer_occupancy",
-            "sampled delayed-eviction buffer occupancy, summed over "
-            "trees (rows; batch-level — the buffer holds whole fetched "
-            "paths, never per-client state); 0 with evict_every=1")
-        self._g_ebuf_hw = r.gauge(
-            "grapevine_evict_buffer_high_water",
-            "max sampled delayed-eviction buffer occupancy (the "
-            "near-overflow canary: approaching evict_buffer_slots "
-            "means the window is undersized — OPERATIONS.md §19)")
         # the round's layout engages by geometry, so these are static
         # per engine: set once at construction (set_round_layout)
         trees = {"tree": ("rec", "mb")}
@@ -172,9 +157,6 @@ class EngineMetrics:
         self._c_sweeps.inc()
         self._c_evicted.inc(evicted)
 
-    def record_flush(self) -> None:
-        self._c_flushes.inc()
-
     def record_auth(self, failures: int = 0) -> None:
         self._c_verifies.inc()
         if failures:
@@ -183,12 +165,6 @@ class EngineMetrics:
     def observe_stash(self, tree: str, occupancy: int) -> None:
         self._g_stash_hw.set_max(occupancy, tree=tree)
         self._h_stash.observe(occupancy, tree=tree)
-
-    def observe_evict_buffer(self, occupancy: int) -> None:
-        """Sampled delayed-eviction buffer occupancy (rows, summed over
-        trees) — scrape-cadence like the stash gauge, never per round."""
-        self._g_ebuf.set(occupancy)
-        self._g_ebuf_hw.set_max(occupancy)
 
     def observe_phase(self, phase: str, seconds: float) -> None:
         self._h_phase.observe(seconds, phase=phase)

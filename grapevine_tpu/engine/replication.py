@@ -6,7 +6,7 @@ streams every sealed frame — verbatim bytes — to a
 :class:`StandbyReplica` over a length-prefixed TCP connection. The
 standby appends each frame to its OWN journal (same fsync discipline,
 ``BatchJournal.append_raw``) and immediately replays it through the
-same jitted step/sweep/flush programs crash recovery uses
+same jitted step/sweep programs crash recovery uses
 (``GrapevineEngine._replay_record``), so its warm state trails the
 primary by shipping latency alone and the existing
 ``grapevine_journal_applied_seq`` / fleet lag gauges price that gap
@@ -25,10 +25,9 @@ has exactly one winner) carrying the bumped journal epoch, so a revived
 (or still-running) stale primary's next append fails with a hard
 ``JournalError``; (2) drains the primary's durable journal tail
 straight off disk — RPO 0 for durable frames, because a SIGKILL leaves
-everything written in page cache; (3) completes a pending eviction
-flush exactly like the crash-recovery constructor; then serves from the
-warm state. RTO is therefore the tail drain + replay alone — measured,
-returned, and banked by ``bench.py failover_ab``.
+everything written in page cache; then serves from the warm state. RTO
+is therefore the tail drain + replay alone — measured, returned, and
+banked by ``bench.py failover_ab``.
 
 Knob interplay (the RPO/RTO table in OPERATIONS.md §23): the standby's
 local ``checkpoint_every_rounds`` bounds its own restart replay; the
@@ -135,7 +134,7 @@ def replication_fingerprint(config: GrapevineConfig) -> str:
     the tree-top cache only re-places bits (PR 14's equivalence
     suites), so replaying a k=4 primary's frames on a k=0 standby is
     legal (the rolling-upgrade drill). Everything else that the full
-    fingerprint covers (geometry, eviction cadence, posmap impl) still
+    fingerprint covers (geometry, posmap impl) still
     fences: frames are only replayable under the identical resolved
     program."""
     norm = dataclasses.replace(config, tree_top_cache_levels=0)
@@ -440,8 +439,6 @@ class StandbyReplica:
             if seq <= self.dm.seq:
                 return
             eng.state = self.dm.install_checkpoint(seq, blob)
-            # re-anchor the replay cadence audit at the new base
-            eng._replay_since = None
 
     # -- transport ------------------------------------------------------
 
@@ -536,12 +533,9 @@ class StandbyReplica:
            and apply it — RPO 0 for durable frames (page cache survives
            a SIGKILL; only un-fsynced frames lost to a *machine* crash
            are gone, bounded by the primary's ``journal_fsync_every``).
-        3. Complete a pending eviction flush exactly like the
-           crash-recovery constructor, so the promoted journal keeps
-           the [round_E, flush] adjacency an uninterrupted run writes.
-        4. Record the epoch locally and serve.
+        3. Record the epoch locally and serve.
 
-        RTO is the measured wall time of 1–3 (the jitted programs are
+        RTO is the measured wall time of 1–2 (the jitted programs are
         already warm — that is the point of a hot standby)."""
         import jax
 
@@ -568,20 +562,12 @@ class StandbyReplica:
                     with open(latest[1], "rb") as fh:
                         blob = fh.read()
                     eng.state = self.dm.install_checkpoint(latest[0], blob)
-                    eng._replay_since = None
                 reader = BatchJournal(
                     primary_state_dir, self.dm.root_key, self.dm.ecfg
                 )
                 for seq, frame in reader.follow_frames(after_seq=self.dm.seq):
                     self._apply_locked(seq, frame)
                     drained += 1
-            if eng.evict_every > 1:
-                # cadence counter from state, never a host mirror —
-                # then complete a flush the dead primary journaled
-                # rounds for but never got to (mid-window kill)
-                eng._rounds_since_flush = int(eng.state.rec.ebuf_rounds)
-                if eng._rounds_since_flush >= eng.evict_every:
-                    eng._flush_window_locked(min_rounds=eng.evict_every)
             jax.block_until_ready(eng.state.free_top)
             self.dm.journal.sync()
             write_epoch(self.dm.dcfg.state_dir, new_epoch)

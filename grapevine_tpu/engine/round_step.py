@@ -67,12 +67,9 @@ def _tree_secrets(prefix: str) -> tuple:
         for p in (
             "posmap", "stash_idx", "stash_val", "stash_leaf",
             "cache_idx", "cache_val", "cache_leaf",
-            "ebuf_idx", "ebuf_val", "ebuf_leaf", "cipher_key",
+            "cipher_key",
         )
     )
-    # NOT tainted: ebuf_paths / ebuf_rounds / ebuf_gen / fetch_tag — the
-    # flush-window bookkeeping is a pure function of the public
-    # transcript (oram/round.py OBLINT_SECRETS note)
 
 
 #: oblint taint anchors (analysis/oblint.py): the secret inputs of one
@@ -447,50 +444,3 @@ def engine_round_step(
         rng=k_next,
     )
     return new_state, responses, transcripts
-
-
-#: oblint taint anchors for one ``engine_flush_step(ecfg, state)`` — the
-#: flush consumes no batch; its secrets are exactly both trees' private
-#: planes plus the key material (the rng passes through untouched but a
-#: PRNG key is working key material either way). The flush's bucket
-#: targets derive ONLY from the untainted public window ledger
-#: (ebuf_paths) — that independence is the whole leak argument.
-FLUSH_OBLINT_SECRETS = (
-    ("state.freelist", "state.hash_key", "state.id_key", "state.rng")
-    + _tree_secrets("state.rec")
-    + _tree_secrets("state.mb")
-)
-
-
-def engine_flush_step(
-    ecfg: EngineConfig,
-    state: EngineState,
-    axis_name: str | None = None,
-) -> EngineState:
-    """One delayed-eviction flush over both trees (PR 15; ROADMAP item 1).
-
-    Called by the engine every ``evict_every`` rounds on the
-    round-counter cadence — an op-independent schedule; never triggered
-    by buffer occupancy. Deterministic given the state (no RNG), so
-    journal replay re-executes it bit-identically (KIND_FLUSH,
-    engine/journal.py). Under a recursive position map the internal
-    trees flush inside the same call (oram/round.py:oram_flush
-    recurses). A no-op-shaped pass at ``evict_every == 1`` is never
-    dispatched — the engine only compiles this program when delayed
-    eviction is on.
-
-    With ``axis_name`` set the call runs inside ``shard_map``
-    (parallel/mesh.py:make_sharded_flush): both trees' write-back
-    scatters are owner-masked to each chip's heap range and everything
-    else — eviction buffer, stash, dedup, the recursive inner trees —
-    stays the replicated axis-free program (the oram_flush docstring
-    carries the leak argument).
-    """
-    from ..oram.round import oram_flush
-
-    with device_phase("engine_flush"):
-        rec = oram_flush(ecfg.rec, state.rec, axis_name,
-                         sort_impl=ecfg.sort_impl)
-        mb = oram_flush(ecfg.mb, state.mb, axis_name,
-                        sort_impl=ecfg.sort_impl)
-    return state._replace(rec=rec, mb=mb)

@@ -91,14 +91,14 @@ class EngineConfig:
     #: by the checkpoint fingerprint via repr, so a cached checkpoint
     #: can never silently restore into a differently-cached engine)
     tree_top_cache_levels: int = 0
-    #: resolved delayed-eviction cadence E (config.py ``evict_every``;
-    #: 1 = per-round eviction, bit-for-bit pre-PR-15). Per-tree windows
-    #: live in rec/mb.evict_window (E and 2E — two mailbox rounds per
-    #: engine round) and the inner posmap specs; all covered by the
-    #: checkpoint fingerprint via repr, so a buffer-bearing checkpoint
-    #: can never silently restore into a differently-cadenced engine
-    #: (the buffer planes are state leaves with E-dependent shapes).
-    evict_every: int = 1
+    # Read-only stand-in for a deleted option: benchmarks/lib/harness.py:221
+    # prints it in every run's `init` line and
+    # benchmarks/tests/test_round_bytes.py:30 asserts it; no simplicity PR
+    # may edit those files. It goes when the next `benchmark` PR drops
+    # both readers.
+    @property
+    def evict_every(self) -> int:
+        return 1
 
     @property
     def id_bits(self) -> int:
@@ -153,32 +153,6 @@ class EngineConfig:
             tc = 4 if cfg.commit == "phase" else 0
         rec_tc = min(tc, cfg.records_height)
         mb_tc = min(tc, cfg.mailbox_height)
-        # delayed batched eviction (config.py evict_every): auto = 1 on
-        # every backend: the flush-overlap win is not measured on the
-        # chip (the vphases/sort/posmap/tree-cache flip-on-evidence
-        # playbook).
-        # Per-tree fetch-round windows: the records tree runs one round
-        # per engine round (window = E, F = B), the mailbox tree two
-        # (rounds A and C: window = 2E, F = B·D).
-        ee = cfg.evict_every if cfg.evict_every is not None else 1
-        d_choices = cfg.resolved_mailbox_choices
-        rec_w = ee
-        mb_w = 2 * ee
-        rec_f = cfg.batch_size if ee > 1 else 0
-        mb_f = cfg.batch_size * d_choices if ee > 1 else 0
-        rec_c = mb_c = 0
-        if ee > 1:
-            from ..oram.path_oram import derive_evict_buffer_slots
-
-            if cfg.evict_buffer_slots is not None:
-                rec_c = mb_c = cfg.evict_buffer_slots
-            else:
-                rec_c = derive_evict_buffer_slots(
-                    cfg.max_messages, rec_w, rec_f, cfg.bucket_slots
-                )
-                mb_c = derive_evict_buffer_slots(
-                    m, mb_w, mb_f, cfg.bucket_slots
-                )
         rec_pm = mb_pm = None
         if pimpl == "recursive":
             from ..oram.posmap import derive_posmap_spec
@@ -188,16 +162,12 @@ class EngineConfig:
                 stash_size=cfg.stash_size,
                 cipher_rounds=cfg.bucket_cipher_rounds,
                 top_cache_levels=tc,
-                evict_window=rec_w if ee > 1 else 1,
-                evict_fetch_count=rec_f,
             )
             mb_pm = derive_posmap_spec(
                 m,
                 stash_size=cfg.stash_size,
                 cipher_rounds=cfg.bucket_cipher_rounds,
                 top_cache_levels=tc,
-                evict_window=mb_w if ee > 1 else 1,
-                evict_fetch_count=mb_f,
             )
         return cls(
             max_messages=cfg.max_messages,
@@ -215,9 +185,6 @@ class EngineConfig:
                 n_blocks=cfg.max_messages,
                 posmap=rec_pm,
                 top_cache_levels=rec_tc,
-                evict_window=rec_w if ee > 1 else 1,
-                evict_fetch_count=rec_f,
-                evict_buffer_slots=rec_c,
             ),
             mb=OramConfig(
                 height=cfg.mailbox_height,
@@ -229,18 +196,14 @@ class EngineConfig:
                 n_blocks=m,
                 posmap=mb_pm,
                 top_cache_levels=mb_tc,
-                evict_window=mb_w if ee > 1 else 1,
-                evict_fetch_count=mb_f,
-                evict_buffer_slots=mb_c,
             ),
             mb_table_buckets=m,
             mb_slots=k,
-            mb_choices=d_choices,
+            mb_choices=cfg.resolved_mailbox_choices,
             vphases_impl=vimpl,
             sort_impl=simpl,
             posmap_impl=pimpl,
             tree_top_cache_levels=tc,
-            evict_every=ee,
         )
 
 

@@ -427,7 +427,7 @@ class ShardRoundDriver:
 
     def __init__(self, n_shards: int, monitor, policy: str = "uniform",
                  batch_size: int = 8, hot_threshold: int = 4,
-                 flush_every: int = 4, round_fn=None):
+                 round_fn=None):
         if policy not in self.POLICIES:
             raise ValueError(f"policy must be one of {self.POLICIES}")
         if n_shards < 2:
@@ -437,12 +437,10 @@ class ShardRoundDriver:
         self.policy = policy
         self.batch_size = int(batch_size)
         self.hot_threshold = int(hot_threshold)
-        self.flush_every = max(1, int(flush_every))
         self.round_fn = round_fn
         self.queue = [0] * self.n
         self.rounds = [0] * self.n
         self.fill_sum = [0.0] * self.n
-        self.flushes = [0] * self.n
         self.ticks = 0
 
     def tick(self, arrivals) -> None:
@@ -463,15 +461,12 @@ class ShardRoundDriver:
                 self.round_fn(i, n_real)
             self.rounds[i] += 1
             self.fill_sum[i] += n_real / self.batch_size
-            if self.rounds[i] % self.flush_every == 0:
-                self.flushes[i] += 1
         self.ticks += 1
         self.monitor.observe_tick([
             {
                 "rounds_total": float(self.rounds[i]),
                 "fill_sum": self.fill_sum[i],
                 "fill_count": float(self.rounds[i]),
-                "flushes_total": float(self.flushes[i]),
                 "queue_depth": float(self.queue[i]),
             }
             for i in range(self.n)

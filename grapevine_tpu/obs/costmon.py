@@ -6,7 +6,7 @@ cross-validated model — see tools/check_cost_model.py for the gate):
 
 - **startup info gauges** (``grapevine_cost_*``): the modeled per-phase
   HBM bytes / gather-scatter rows / cipher rows / sort key-volume and
-  the flush-amortized steady-state round total, set once at attach
+  the steady-state round total, set once at attach
   time. Pure functions of public geometry × knobs — the same numbers
   any observer could derive from the config — so they are trivially
   leak-free (tools/check_telemetry_policy.py audits the namespace:
@@ -88,8 +88,8 @@ class CostMonitor:
         g_bytes = registry.gauge(
             "grapevine_cost_phase_hbm_bytes",
             "Modeled HBM bytes one execution of this phase moves "
-            "(static geometry x knobs; flush/sweep are per flush/sweep "
-            "call, not per round)",
+            "(static geometry x knobs; sweep is per sweep call, not per "
+            "round)",
             labels=phase_labels,
         )
         g_grows = registry.gauge(
@@ -123,9 +123,8 @@ class CostMonitor:
 
         registry.gauge(
             "grapevine_cost_steady_round_hbm_bytes",
-            "Modeled flush-amortized HBM bytes per steady-state engine "
-            "round (fetch + write-back + flush/evict_every; sweep "
-            "excluded — operator-cadenced)",
+            "Modeled HBM bytes per steady-state engine round (fetch + "
+            "write-back; sweep excluded — operator-cadenced)",
         ).set(float(self.ledger.steady_round_bytes))
         registry.gauge(
             "grapevine_cost_bandwidth_gbps",
@@ -156,7 +155,7 @@ class CostMonitor:
         the host can know it (previous round ready, or this one's
         dispatch end, to this one ready): right at every pipeline
         depth, an upper bound while the host arrives after the device
-        finished, and on the round behind a flush or an expiry sweep,
+        finished, and on the round behind an expiry sweep,
         whose device time it can hold too (``device_exact`` 0 in the
         ledger's counts)."""
         dev = spans.get("device")

@@ -366,8 +366,6 @@ class FleetAggregator:
             "fill_count": _sample_value(
                 families, "grapevine_load_batch_fill",
                 "grapevine_load_batch_fill_count", 0.0),
-            "flushes_total": _sample_value(
-                families, "grapevine_evict_flushes_total", default=0.0),
             "queue_depth": _sample_value(
                 families, "grapevine_queue_depth", default=0.0),
         }

@@ -411,7 +411,6 @@ class EngineLeakMonitor:
         recorder: FlightRecorder | None = None,
         mb_pm_leaves: int | None = None,
         rec_pm_leaves: int | None = None,
-        flush_every: int | None = None,
     ):
         self.cfg = cfg or LeakMonitorConfig()
         self.mb_choices = mb_choices
@@ -455,19 +454,6 @@ class EngineLeakMonitor:
         #: content-sized byte on the wire is a SUSPECT exactly like an
         #: access-pattern detector tripping
         self._shipper = None
-        #: delayed-eviction flush cadence books (engine/batcher.py
-        #: _flush_window_locked): the schedule-independence claim says
-        #: the automatic flush fires strictly every ``flush_every``
-        #: dispatched rounds — a pure function of the round counter.
-        #: The engine reports each scheduled flush's observed interval
-        #: via note_flush(); any interval that deviates from the
-        #: declared cadence is content-modulated scheduling (the
-        #: flush_on_buffer_contents mutant's signature) and trips the
-        #: ``flush_cadence`` detector exactly like an access-pattern
-        #: detector. None = immediate eviction, detector absent.
-        self._flush_every = flush_every
-        self._flush_samples = 0
-        self._flush_illegal = 0
         self._worker = threading.Thread(
             target=self._run, daemon=True, name="grapevine-leakmon"
         )
@@ -479,7 +465,6 @@ class EngineLeakMonitor:
         into the engine's own telemetry registry (one merged /metrics)."""
         ecfg = engine.ecfg
         recursive = ecfg.rec.posmap is not None
-        delayed = getattr(engine, "_flush_step", None) is not None
         return cls(
             mb_leaves=ecfg.mb.leaves,
             rec_leaves=ecfg.rec.leaves,
@@ -488,7 +473,6 @@ class EngineLeakMonitor:
             registry=engine.metrics.registry,
             mb_pm_leaves=ecfg.mb.posmap.inner_leaves if recursive else None,
             rec_pm_leaves=ecfg.rec.posmap.inner_leaves if recursive else None,
-            flush_every=engine.evict_every if delayed else None,
         )
 
     # -- round-path API (must stay O(1) and non-blocking) ---------------
@@ -508,19 +492,6 @@ class EngineLeakMonitor:
             return False
         self._submitted += 1
         return True
-
-    def note_flush(self, interval_rounds: int, scheduled: bool = True) -> None:
-        """Record one delayed-eviction flush's observed interval (rounds
-        since the previous flush; engine/batcher.py calls this under the
-        engine lock just before the cadence counter resets). Only
-        ``scheduled`` flushes are audited — flush_now() and recovery
-        completion are operator/restart actions outside the steady-state
-        cadence claim. O(1), two int bumps."""
-        if not scheduled or self._flush_every is None:
-            return
-        self._flush_samples += 1
-        if int(interval_rounds) != int(self._flush_every):
-            self._flush_illegal += 1
 
     # -- verdict views --------------------------------------------------
 
@@ -548,19 +519,6 @@ class EngineLeakMonitor:
                 "verdict": PASS if rep["cadence_ok"] else SUSPECT,
             })
             if not rep["cadence_ok"]:
-                v["verdict"] = SUSPECT
-        if self._flush_every is not None:
-            illegal = self._flush_illegal
-            v["detectors"].append({
-                "name": "flush_cadence",
-                "tree": "evict",
-                "statistic": float(illegal),
-                "threshold": 0.0,
-                "samples": int(self._flush_samples),
-                "min_samples": 1,
-                "verdict": SUSPECT if illegal else PASS,
-            })
-            if illegal:
                 v["verdict"] = SUSPECT
         return v
 
@@ -711,8 +669,8 @@ class FleetUniformityConfig:
     window_ticks: int = 128
     #: minimum aligned ticks before the correlation detector may trip
     min_ticks: int = 24
-    #: minimum per-shard rounds in the window before the cadence and
-    #: flush detectors may trip (insufficient evidence reports PASS —
+    #: minimum per-shard rounds in the window before the cadence
+    #: detector may trip (insufficient evidence reports PASS —
     #: the PR-2 min-samples stance)
     min_rounds: int = 16
     #: |log cadence ratio| floor for the pairwise cadence detector: an
@@ -724,11 +682,8 @@ class FleetUniformityConfig:
     #: detector (honest uniform scheduling dispatches unconditionally,
     #: so the correlation is sampling noise: |z| = O(1))
     corr_z_threshold: float = 6.0
-    #: pairwise flush-per-round rate drift floor (honest shards all
-    #: flush at the declared 1/evict_every cadence)
-    flush_rate_floor: float = 0.1
-    #: sampling-noise margin in standard deviations for the cadence and
-    #: flush thresholds (the leakmon rate_z_margin analog)
+    #: sampling-noise margin in standard deviations for the cadence
+    #: threshold (the leakmon rate_z_margin analog)
     rate_z_margin: float = 8.0
 
 
@@ -743,7 +698,7 @@ class FleetUniformityMonitor:
     *which shard's recipients are busy* into the public round schedule,
     exactly the signal BOLT's fleet-level adversary reads. This monitor
     consumes only per-shard batch-level time series (round cadence,
-    batch fill, flush cadence, queue depth at round/scrape grain — all
+    batch fill, queue depth at round/scrape grain — all
     already public on each member's /metrics) and flags
     recipient-dependent skew:
 
@@ -753,9 +708,7 @@ class FleetUniformityMonitor:
     2. **dispatch/fill correlation with offered shard load** — a
        shard's round activity must not correlate with its own queue
        depth beyond the declared partition (honest scheduling is
-       unconditional; only a load-gated scheduler correlates);
-    3. **flush-phase alignment** — delayed-eviction flush-per-round
-       rates must match the declared cadence on every shard alike.
+       unconditional; only a load-gated scheduler correlates).
 
     Feeding: ``observe_tick(samples)`` with one aligned sample per
     shard. A tick with any shard missing (scrape failure) updates the
@@ -782,11 +735,11 @@ class FleetUniformityMonitor:
         self.n_shards = int(n_shards)
         self.cfg = cfg or FleetUniformityConfig()
         self._lock = threading.Lock()
-        #: last cumulative (rounds, fill_sum, fill_count, flushes) per
+        #: last cumulative (rounds, fill_sum, fill_count) per
         #: shard, None until first observed
         self._base: list = [None] * self.n_shards
         #: aligned tick window: each entry is (d_rounds, fill_mean,
-        #: d_flushes, queue_depth) arrays over shards
+        #: queue_depth) arrays over shards
         self._window: deque = deque(maxlen=self.cfg.window_ticks)
         self._g_stat = self._g_thr = self._g_suspect = None
         self._g_rounds = self._g_ticks = None
@@ -804,8 +757,6 @@ class FleetUniformityMonitor:
                 ("fill_load_correlation", "max per-shard Fisher |z| of "
                  "corr(round activity, own queue depth) — honest "
                  "unconditional dispatch gives sampling noise"),
-                ("flush_phase", "pairwise flush-per-round rate drift "
-                 "(honest shards all flush at the declared cadence)"),
             ):
                 self._g_stat[det] = registry.gauge(
                     f"grapevine_fleet_uniformity_{det}_statistic",
@@ -820,7 +771,7 @@ class FleetUniformityMonitor:
             self._g_rounds = registry.gauge(
                 "grapevine_fleet_uniformity_window_rounds",
                 "per-shard rounds in the current uniformity window "
-                "(cadence/flush detector sample size)",
+                "(cadence detector sample size)",
                 labels={"shard": shards})
             self._g_ticks = registry.gauge(
                 "grapevine_fleet_uniformity_window_ticks",
@@ -833,10 +784,9 @@ class FleetUniformityMonitor:
         """Feed one aligned fleet tick.
 
         ``samples``: sequence of length ``n_shards``; each element is a
-        dict with cumulative ``rounds_total``, ``flushes_total``,
-        optional cumulative ``fill_sum``/``fill_count``, and
-        instantaneous ``queue_depth`` — or None for a shard whose
-        scrape failed this tick."""
+        dict with cumulative ``rounds_total``, optional cumulative
+        ``fill_sum``/``fill_count``, and instantaneous ``queue_depth``
+        — or None for a shard whose scrape failed this tick."""
         if len(samples) != self.n_shards:
             raise ValueError(
                 f"tick has {len(samples)} samples for {self.n_shards} shards"
@@ -845,7 +795,6 @@ class FleetUniformityMonitor:
             complete = all(s is not None for s in samples)
             d_rounds = np.zeros(self.n_shards)
             fill_mean = np.zeros(self.n_shards)
-            d_flush = np.zeros(self.n_shards)
             qdepth = np.zeros(self.n_shards)
             for i, s in enumerate(samples):
                 if s is None:
@@ -854,7 +803,6 @@ class FleetUniformityMonitor:
                     float(s["rounds_total"]),
                     float(s.get("fill_sum", 0.0)),
                     float(s.get("fill_count", 0.0)),
-                    float(s.get("flushes_total", 0.0)),
                 )
                 base = self._base[i]
                 self._base[i] = cur
@@ -873,10 +821,9 @@ class FleetUniformityMonitor:
                 fill_mean[i] = (
                     (cur[1] - base[1]) / dfc if dfc > 0 else 0.0
                 )
-                d_flush[i] = max(0.0, cur[3] - base[3])
                 qdepth[i] = float(s.get("queue_depth", 0.0))
             if complete:
-                self._window.append((d_rounds, fill_mean, d_flush, qdepth))
+                self._window.append((d_rounds, fill_mean, qdepth))
             self._export_locked()
 
     def _export_locked(self) -> None:
@@ -903,12 +850,10 @@ class FleetUniformityMonitor:
             ticks = len(self._window)
             if ticks:
                 d_rounds = np.stack([w[0] for w in self._window])
-                d_flush = np.stack([w[2] for w in self._window])
-                qdepth = np.stack([w[3] for w in self._window])
+                qdepth = np.stack([w[2] for w in self._window])
             else:
-                d_rounds = d_flush = qdepth = np.zeros((0, self.n_shards))
+                d_rounds = qdepth = np.zeros((0, self.n_shards))
         R = d_rounds.sum(axis=0)  # per-shard rounds in window
-        F = d_flush.sum(axis=0)
         detectors = []
 
         # 1. pairwise cadence-ratio drift (max over pairs)
@@ -955,30 +900,6 @@ class FleetUniformityMonitor:
             "min_samples": cfg.min_ticks,
             "verdict": SUSPECT if (
                 ticks >= cfg.min_ticks and worst_z > cfg.corr_z_threshold
-            ) else PASS,
-        })
-
-        # 3. pairwise flush-per-round rate drift
-        f = (F + 0.5) / (R + 1.0)
-        fa, fb = (int(np.argmax(f)), int(np.argmin(f)))
-        stat = float(f[fa] - f[fb])
-        fbar = min(max(float(np.mean(f)), 1e-6), 1.0 - 1e-6)
-        samples = int(min(R[fa], R[fb])) if ticks else 0
-        thr = max(
-            cfg.flush_rate_floor,
-            cfg.rate_z_margin * math.sqrt(
-                fbar * (1.0 - fbar)
-                * (1.0 / (R[fa] + 1.0) + 1.0 / (R[fb] + 1.0))),
-        )
-        detectors.append({
-            "name": "flush_phase",
-            "pair": [fa, fb],
-            "statistic": round(stat, 4),
-            "threshold": round(thr, 4),
-            "samples": samples,
-            "min_samples": cfg.min_rounds,
-            "verdict": SUSPECT if (
-                samples >= cfg.min_rounds and stat > thr
             ) else PASS,
         })
 

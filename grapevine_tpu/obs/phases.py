@@ -22,7 +22,6 @@ Host-side phases (histograms + ``jax.profiler`` annotations):
 - ``journal``   — sealed batch-journal append + fsync (engine/journal.py)
 - ``checkpoint``— sealed whole-state checkpoint write (engine/checkpoint.py)
 - ``replay``    — startup journal replay (recovery; engine/batcher.py)
-- ``flush``     — delayed-eviction flush enqueue (engine/batcher.py)
 
 Device-side scopes (``device_phase``): ``jax.named_scope`` annotations
 compiled into the jit'd programs, so a profiler capture (the benchmark's
@@ -44,7 +43,7 @@ import time
 #: canonical phase label values — the registry declares exactly these,
 #: so a typo'd phase name raises instead of minting a new series
 PHASES = ("assembly", "verify", "dispatch", "evict", "demux", "sweep",
-          "journal", "checkpoint", "replay", "flush")
+          "journal", "checkpoint", "replay")
 
 #: canonical device scope names — ``device_phase`` refuses any other, so
 #: a typo'd or per-op scope name raises at trace time instead of minting
@@ -69,13 +68,13 @@ DEVICE_SCOPES = (
     "cache_read",          # tree-top cache planes -> working set
     # leaves of oram_evict
     "oram_evict_sort",     # the working set's sort by leaf
-    "stash_compact",       # leftover rows -> stash / eviction buffer
+    "stash_compact",       # leftover rows -> stash
     # leaves of oram_writeback
     "cipher_encrypt",
     "path_scatter",        # path working set -> tree rows
     "cache_write",         # working set -> tree-top cache planes
     # other programs
-    "engine_flush", "oram_flush", "sweep_records", "sweep_mailbox",
+    "sweep_records", "sweep_mailbox",
     # bounded-key sorts (oblivious/radix.py), one scope per digit pass
     "radix_rank", "radix_group_sort",
 ) + tuple(f"radix_pass_s{shift}" for shift in range(64))

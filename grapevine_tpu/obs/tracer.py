@@ -101,7 +101,7 @@ HOST_SPANS = (
 #: dispatch -> observed ready (rounds queued ahead on the device
 #: included); ``device`` = the round's own device time as the host can
 #: know it, from max(end of its dispatch, the previous round's observed
-#: ready) to its own observed ready (a flush or an expiry sweep the
+#: ready) to its own observed ready (an expiry sweep the
 #: device ran since the round before can fall inside it:
 #: ``device_exact`` is 0 then);
 #: ``round`` = collection window -> answers unpacked
@@ -122,7 +122,7 @@ ALLOWED_SPAN_NAMES = frozenset(STABLE_SPANS) | frozenset(PHASES)
 #: (dispatch - enqueue), ``rounds_ahead`` = rounds dispatched and
 #: unresolved at this dispatch, ``device_exact`` = 1 when the device was
 #: still running this round and the one before each time the host
-#: arrived to wait, and ran no flush or sweep between the two (so
+#: arrived to wait, and ran no sweep between the two (so
 #: ``device`` is this round's own device time, not an upper bound),
 #: ``verify_chunks`` = chunk checks the round's first signature pass
 #: ran side by side (1 = one inline call): a function of how many ops

@@ -96,21 +96,6 @@ def RANGELINT_BOUNDS(cfg: "OramConfig", prefix: str = "state") -> dict:
         # headroom is what certifies `overflow + dropped` wrap-free
         f"{prefix}.overflow": (0, 2**32 - 2**16),
     }
-    if cfg.delayed_eviction:
-        # delayed-eviction planes (evict_window > 1): ebuf_idx/ebuf_val
-        # are sentinel-bearing/opaque like stash_idx (full lane);
-        # ebuf_leaf carries leaf values like stash_leaf; the public
-        # window ledger holds former transcript leaves. The counters
-        # carry their window invariants — ebuf_rounds resets at every
-        # flush and the accumulate round increments within the declared
-        # [0, W] budget; ebuf_gen/fetch_tag are monotone generation
-        # counters with the sticky-counter increment budget (one bump
-        # per flush; 2^32−2^16 flushes ≫ any run).
-        b[f"{prefix}.ebuf_leaf"] = (0, lv)
-        b[f"{prefix}.ebuf_paths"] = (0, lv)
-        b[f"{prefix}.ebuf_rounds"] = (0, cfg.evict_window)
-        b[f"{prefix}.ebuf_gen"] = (0, 2**32 - 2**16)
-        b[f"{prefix}.fetch_tag"] = (0, 2**32 - 2**16)
     if not cfg.encrypted:
         # plaintext trees carry their leaf metadata un-ciphered
         b[f"{prefix}.tree_leaf"] = (0, lv)
@@ -127,16 +112,6 @@ def RANGELINT_BOUNDS(cfg: "OramConfig", prefix: str = "state") -> dict:
         b[f"{inner}.stash_val"] = (0, lv)
         b[f"{inner}.cache_val"] = (0, lv)
         b[f"{inner}.overflow"] = (0, 2**32 - 2**16)
-        if icfg.delayed_eviction:
-            # the internal tree's deferral planes: buffer values are
-            # packed OUTER leaf entries (like stash_val), its leaf
-            # mirror and window ledger hold INTERNAL leaves
-            b[f"{inner}.ebuf_val"] = (0, lv)
-            b[f"{inner}.ebuf_leaf"] = (0, icfg.leaves - 1)
-            b[f"{inner}.ebuf_paths"] = (0, icfg.leaves - 1)
-            b[f"{inner}.ebuf_rounds"] = (0, icfg.evict_window)
-            b[f"{inner}.ebuf_gen"] = (0, 2**32 - 2**16)
-            b[f"{inner}.fetch_tag"] = (0, 2**32 - 2**16)
         if not icfg.encrypted:
             b[f"{inner}.tree_val"] = (0, lv)
         b[f"{prefix}.posmap.dummy_entry"] = (0, lv)
@@ -232,26 +207,6 @@ class OramConfig:
     #: per-access cipher work shrinks by the same fraction. 0 = off,
     #: bit-for-bit the uncached program.
     top_cache_levels: int = 0
-    #: delayed batched eviction (ROADMAP item 1, PR 15; config.py
-    #: ``evict_every``): the number of ``oram_round`` fetch calls
-    #: between eviction flushes. 1 = evict+write-back every round,
-    #: bit-for-bit the pre-PR-15 program (the ``ebuf_*``/``fetch_tag``
-    #: planes are zero-length). > 1 = ``oram_round`` runs the
-    #: fetch-only program — gather+decrypt+stash/buffer update, ZERO
-    #: tree scatters and zero encrypt work — and :func:`oram_flush`
-    #: performs one batched eviction+write-back over the union of the
-    #: window's fetched paths. The engine maps its ``evict_every=E`` to
-    #: window E on the records tree and 2E on the mailbox tree (two
-    #: mailbox rounds per engine round).
-    evict_window: int = 1
-    #: paths fetched per ``oram_round`` call (B for the records tree,
-    #: B·D for the mailbox tree); sizes the public ``ebuf_paths`` plane.
-    #: Required > 0 iff ``evict_window > 1``.
-    evict_fetch_count: int = 0
-    #: eviction-buffer capacity in rows (the bounded private buffer
-    #: fetched path contents accumulate in between flushes — stash
-    #: standing). Required > 0 iff ``evict_window > 1``.
-    evict_buffer_slots: int = 0
 
     def __post_init__(self):
         k = self.top_cache_levels
@@ -259,17 +214,6 @@ class OramConfig:
             raise ValueError(
                 f"top_cache_levels must be in [0, height={self.height}] "
                 f"(at least the leaf level stays in the HBM tree), got {k}"
-            )
-        w = self.evict_window
-        if w < 1:
-            raise ValueError(f"evict_window must be >= 1, got {w}")
-        if w > 1 and (self.evict_fetch_count < 1
-                      or self.evict_buffer_slots < 1):
-            raise ValueError(
-                "evict_window > 1 (delayed batched eviction) needs "
-                "evict_fetch_count and evict_buffer_slots > 0, got "
-                f"fetch_count={self.evict_fetch_count}, "
-                f"buffer_slots={self.evict_buffer_slots}"
             )
         # rangelint certified-geometry guard (analysis/rangelint.py;
         # tools/check_ranges.py cites this refusal in its report): every
@@ -306,12 +250,6 @@ class OramConfig:
     @property
     def encrypted(self) -> bool:
         return self.cipher_rounds > 0
-
-    @property
-    def delayed_eviction(self) -> bool:
-        """True iff this tree accumulates fetches and flushes in batches
-        (``evict_window > 1``); False = the classic per-round program."""
-        return self.evict_window > 1
 
     @property
     def cache_buckets(self) -> int:
@@ -372,19 +310,14 @@ class OramConfig:
         paths holds level-dense (oram/round.py): read and written back
         whole, once, as a fixed heap range, where deeper levels stay
         per-path. The covered levels, and never fewer than the tree-top
-        cache (its planes are the first dense levels). The
-        delayed-eviction fetch round stays per-path: only its cache
-        levels are dense."""
-        if self.delayed_eviction:
-            return self.top_cache_levels
+        cache (its planes are the first dense levels)."""
         return max(self.covered_levels(accesses), self.top_cache_levels)
 
     def fetched_bucket_rows(self, accesses: int) -> int:
         """HBM bucket rows one ``oram_round`` of ``accesses`` paths
-        gathers and decrypts (and, evicting every round, encrypts and
-        scatters back): the dense heap range below the cache,
-        ``[2^k − 1, 2^Ld − 1)``, plus the per-path rows of the deeper
-        levels. A function of shapes only."""
+        gathers and decrypts, then encrypts and scatters back: the dense
+        heap range below the cache, ``[2^k − 1, 2^Ld − 1)``, plus the
+        per-path rows of the deeper levels. A function of shapes only."""
         ld = self.dense_levels(accesses)
         return ((1 << ld) - (1 << self.top_cache_levels)
                 + self.perpath_bucket_rows(accesses))
@@ -451,33 +384,6 @@ class OramState(NamedTuple):
     stash_val: jax.Array  # u32[S, V]
     #: stash mirror of tree_leaf (u32[S] recursive, u32[0] flat)
     stash_leaf: jax.Array
-    #: delayed-eviction buffer planes (cfg.evict_window = W > 1;
-    #: zero-length at W=1 — bit-for-bit the per-round-eviction layout):
-    #: live blocks pulled off fetched paths accumulate here between
-    #: flushes instead of being evicted back every round. Private
-    #: working state with the stash's standing — checkpointed, swept by
-    #: expiry exactly like the stash, recompacted buffer-first (the
-    #: stash stays the spill/pressure signal), overflow shared with the
-    #: stash's sticky counter. oram_flush drains it every W rounds.
-    ebuf_idx: jax.Array  # u32[C]; SENTINEL = empty row
-    ebuf_val: jax.Array  # u32[C, V]
-    #: buffer mirror of stash_leaf (u32[C] recursive, u32[0] flat)
-    ebuf_leaf: jax.Array
-    #: PUBLIC flush-window bookkeeping (all derivable from the public
-    #: transcript — the leaves fetched since the last flush and the
-    #: round count — so none of it is an oblint taint anchor):
-    #: the window's fetched leaves, row r·F.. holding round r's F leaves
-    ebuf_paths: jax.Array  # u32[W * F] (u32[0] at W=1)
-    #: fetch rounds since the last flush, in [0, W]
-    ebuf_rounds: jax.Array  # u32 scalar
-    #: flush generation counter (starts at 1); a bucket whose
-    #: ``fetch_tag`` equals the current generation was fetched since the
-    #: last flush — its HBM/cache copy is stale (the live rows moved to
-    #: the buffer) and is masked out of working sets and sweeps.
-    #: Bumping the generation at flush re-validates every bucket in O(1)
-    #: with no plane-wide clear.
-    ebuf_gen: jax.Array  # u32 scalar
-    fetch_tag: jax.Array  # u32[n_buckets_padded] (u32[0] at W=1)
     #: position map: u32[blocks + 1] private table under a flat map
     #: (last entry backs the dummy index), or a RecursivePosMapState
     #: pytree (oram/posmap.py) when cfg.posmap is a PosMapSpec
@@ -509,11 +415,6 @@ def init_oram(cfg: OramConfig, key: jax.Array) -> OramState:
     n_sleaf = cfg.stash_size if cfg.posmap is not None else 0
     cb = cfg.cache_buckets
     n_cleaf = cb * z if cfg.posmap is not None else 0
-    delayed = cfg.delayed_eviction
-    c = cfg.evict_buffer_slots if delayed else 0
-    n_eleaf = c if cfg.posmap is not None else 0
-    npaths = cfg.evict_window * cfg.evict_fetch_count if delayed else 0
-    ntag = cfg.n_buckets_padded if delayed else 0
     return OramState(
         tree_idx=jnp.full((cfg.n_buckets_padded * z,), SENTINEL, U32),
         tree_val=jnp.zeros((cfg.n_buckets_padded, z * v), U32),
@@ -524,14 +425,6 @@ def init_oram(cfg: OramConfig, key: jax.Array) -> OramState:
         stash_idx=jnp.full((cfg.stash_size,), SENTINEL, U32),
         stash_val=jnp.zeros((cfg.stash_size, v), U32),
         stash_leaf=jnp.zeros((n_sleaf,), U32),
-        ebuf_idx=jnp.full((c,), SENTINEL, U32),
-        ebuf_val=jnp.zeros((c, v), U32),
-        ebuf_leaf=jnp.zeros((n_eleaf,), U32),
-        ebuf_paths=jnp.zeros((npaths,), U32),
-        ebuf_rounds=jnp.zeros((), U32),
-        # generation 1 with an all-zero tag plane: nothing is stale
-        ebuf_gen=jnp.ones((), U32),
-        fetch_tag=jnp.zeros((ntag,), U32),
         posmap=init_posmap(cfg, k_pos),
         overflow=jnp.zeros((), U32),
         nonces=jnp.zeros((cfg.n_buckets_padded, 2), U32),
@@ -893,15 +786,6 @@ def oram_access(
         stash_idx=stash_idx,
         stash_val=stash_val,
         stash_leaf=stash_leaf,
-        # the op-major path never runs delayed eviction (config.py
-        # forbids commit='op' + evict_every>1): zero-length passthrough
-        ebuf_idx=state.ebuf_idx,
-        ebuf_val=state.ebuf_val,
-        ebuf_leaf=state.ebuf_leaf,
-        ebuf_paths=state.ebuf_paths,
-        ebuf_rounds=state.ebuf_rounds,
-        ebuf_gen=state.ebuf_gen,
-        fetch_tag=state.fetch_tag,
         posmap=posmap,
         overflow=overflow,
         nonces=nonces,
@@ -965,44 +849,9 @@ def tree_cache_private_bytes(cfg: OramConfig) -> int:
     return cfg.cache_buckets * 4 * (z + z * v + leaf)
 
 
-def derive_evict_buffer_slots(blocks: int, window: int, fetch_count: int,
-                              z: int) -> int:
-    """Auto buffer capacity for delayed eviction (OPERATIONS.md §19).
-
-    ~2·Z live blocks of headroom per fetched path per window round plus
-    insert slack, clamped by the whole block space: a buffer that can
-    hold every live block can never overflow, and at small geometries
-    the clamp is what fires. At production shapes the heuristic side
-    wins — steady-state Path ORAM carries ~density live blocks per
-    path (most mass at the leaves), so 2·Z ≈ 4·density is conservative;
-    the sticky overflow counter + health canary catch undersizing."""
-    return min(blocks, 2 * z * window * fetch_count + 4 * fetch_count)
-
-
-def evict_buffer_private_bytes(cfg: OramConfig) -> int:
-    """Resident plaintext bytes the eviction buffer pins for this tree
-    (stash standing; OPERATIONS.md §18 sizing): C rows of idx + val
-    (+ leaf under a recursive posmap), plus the public window
-    bookkeeping (paths plane + per-bucket fetch tags)."""
-    if not cfg.delayed_eviction:
-        return 0
-    c, v = cfg.evict_buffer_slots, cfg.value_words
-    leaf = 1 if cfg.posmap is not None else 0
-    rows = c * 4 * (1 + v + leaf)
-    public = 4 * (cfg.evict_window * cfg.evict_fetch_count
-                  + cfg.n_buckets_padded + 2)
-    return rows + public
-
-
 def stash_occupancy(state: OramState) -> jax.Array:
     """Number of live stash entries (test/metrics helper)."""
     return jnp.sum(state.stash_idx != SENTINEL)
-
-
-def evict_buffer_occupancy(state: OramState) -> jax.Array:
-    """Number of live eviction-buffer rows (health/metrics helper);
-    0 under evict_window=1 (zero-length planes)."""
-    return jnp.sum(state.ebuf_idx != SENTINEL)
 
 
 def tree_occupancy(state: OramState) -> jax.Array:

@@ -100,15 +100,6 @@ class PosMapSpec:
     #: touched every round too — path_oram.OramConfig.top_cache_levels,
     #: clamped to inner_height by derive_posmap_spec)
     inner_top_cache_levels: int = 0
-    #: delayed batched eviction for the INTERNAL bucket tree (PR 15;
-    #: path_oram.OramConfig.evict_window and friends): the internal
-    #: ORAM runs one fetch round per outer round, so its window and
-    #: per-round fetch count mirror the outer tree's; its buffer is
-    #: flushed by the same oram_flush pass (round.py recurses into the
-    #: inner state). Defaults keep the classic per-round eviction.
-    inner_evict_window: int = 1
-    inner_evict_fetch_count: int = 0
-    inner_evict_buffer_slots: int = 0
 
     @property
     def inner_leaves(self) -> int:
@@ -121,8 +112,6 @@ def derive_posmap_spec(
     cipher_rounds: int = 0,
     entries_per_block: int | None = None,
     top_cache_levels: int = 0,
-    evict_window: int = 1,
-    evict_fetch_count: int = 0,
 ) -> PosMapSpec:
     """Auto-derive recursion geometry from capacity.
 
@@ -150,13 +139,6 @@ def derive_posmap_spec(
             )
     inner_blocks = blocks // k
     ih = max(1, inner_blocks.bit_length() - 2)
-    ebs = 0
-    if evict_window > 1:
-        from .path_oram import derive_evict_buffer_slots
-
-        ebs = derive_evict_buffer_slots(
-            inner_blocks, evict_window, evict_fetch_count, 4
-        )
     return PosMapSpec(
         entries_per_block=k,
         inner_blocks=inner_blocks,
@@ -164,9 +146,6 @@ def derive_posmap_spec(
         inner_stash_size=stash_size,
         inner_cipher_rounds=cipher_rounds,
         inner_top_cache_levels=min(top_cache_levels, ih),
-        inner_evict_window=evict_window,
-        inner_evict_fetch_count=evict_fetch_count if evict_window > 1 else 0,
-        inner_evict_buffer_slots=ebs,
     )
 
 
@@ -185,9 +164,6 @@ def inner_oram_config(spec: PosMapSpec):
         cipher_impl="jnp",
         n_blocks=spec.inner_blocks,
         top_cache_levels=spec.inner_top_cache_levels,
-        evict_window=spec.inner_evict_window,
-        evict_fetch_count=spec.inner_evict_fetch_count,
-        evict_buffer_slots=spec.inner_evict_buffer_slots,
     )
 
 
@@ -553,15 +529,6 @@ def read_table(cfg, pm_state):
     rows = tval.reshape(-1, k)
     flat_idx = tidx.reshape(-1)
     live = flat_idx != int(SENTINEL)
-    # delayed eviction: buckets fetched since the last flush hold stale
-    # copies (the live rows moved to the eviction buffer) — mask their
-    # tree AND cache slots; the buffer is read below like the stash
-    stale_b = None
-    if icfg.delayed_eviction:
-        stale_b = np.asarray(inner.fetch_tag) == int(
-            np.asarray(inner.ebuf_gen)
-        )
-        live &= ~np.repeat(stale_b, z)
     # tree-top cache: cached buckets' HBM rows are stale (decrypt to
     # empty — never written while cached); the authoritative plaintext
     # rows live in the cache planes
@@ -569,9 +536,7 @@ def read_table(cfg, pm_state):
     if ncache:
         live[:ncache] = False
         crows = np.asarray(inner.cache_val).reshape(-1, k)
-        cidx = np.asarray(inner.cache_idx).copy()
-        if stale_b is not None:
-            cidx[np.repeat(stale_b[: ncache // z], z)] = int(SENTINEL)
+        cidx = np.asarray(inner.cache_idx)
         for slot in np.nonzero(cidx != int(SENTINEL))[0]:
             blk = int(cidx[slot])
             out[blk * k: (blk + 1) * k] = crows[slot]
@@ -580,15 +545,11 @@ def read_table(cfg, pm_state):
         blk = int(flat_idx[slot])
         out[blk * k: (blk + 1) * k] = rows[slot]
         seen[blk] = True
-    for pidx, pval in (
-        (inner.ebuf_idx, inner.ebuf_val),
-        (inner.stash_idx, inner.stash_val),
-    ):
-        sidx = np.asarray(pidx)
-        sval = np.asarray(pval)
-        for j in np.nonzero(sidx != int(SENTINEL))[0]:
-            blk = int(sidx[j])
-            out[blk * k: (blk + 1) * k] = sval[j]
-            seen[blk] = True
+    sidx = np.asarray(inner.stash_idx)
+    sval = np.asarray(inner.stash_val)
+    for j in np.nonzero(sidx != int(SENTINEL))[0]:
+        blk = int(sidx[j])
+        out[blk * k: (blk + 1) * k] = sval[j]
+        seen[blk] = True
     assert seen.all(), "recursive posmap lost internal blocks"
     return out

@@ -68,7 +68,6 @@ from .path_oram import (
     _path_scatter,
     cipher_rows,
     path_bucket_indices,
-    path_slot_indices,
     working_leaves,
 )
 
@@ -88,15 +87,8 @@ OBLINT_SECRETS = (
     "pm_new_leaves", "pm_dummy_leaves",
     "state.posmap", "state.stash_idx", "state.stash_val",
     "state.stash_leaf", "state.cache_idx", "state.cache_val",
-    "state.cache_leaf", "state.ebuf_idx", "state.ebuf_val",
-    "state.ebuf_leaf", "state.cipher_key",
+    "state.cache_leaf", "state.cipher_key",
 )
-# Deliberately NOT secret: ebuf_paths / ebuf_rounds / ebuf_gen /
-# fetch_tag — the flush-window bookkeeping is a pure function of the
-# public transcript (the fetched leaves and the round counter), and the
-# flush cadence must remain derivable from it alone (a flush that
-# consulted buffer *contents* would be the leak the seeded
-# flush_on_buffer_contents mutant pins).
 
 
 def occurrence_masks(idxs: jax.Array, dummy_index: int):
@@ -182,25 +174,18 @@ def _assign_evictions(
     bucket_map: jax.Array,  # u32[n_buckets_padded] heap bucket -> output row
     n_rows: int,  # output bucket rows; doubles as the "not fetched" sentinel
     sort_impl: str,
-    dense_levels: int = 0,  # levels whose buckets are their own output row
+    dense_levels: int,  # levels whose buckets are their own output row
 ):
     """Joint level-synchronous greedy eviction assignment (module
     docstring step 3): one sort of the working set by leaf, then per
     level a segmented rank caps each bucket at Z — O(W) work per level
     with no [W, n_rows] masks. Returns ``(slot_tgt, placed)`` in
     working-set order; ``slot_tgt`` = ``row·Z + rank`` indexes a flat
-    output of ``n_rows·Z`` slots (OOB = unplaced). ONE body serves both
-    write layouts — the placement itself (which entry lands in which
-    bucket) is the same greedy function either way, which the cross-E
-    bit-identity contract depends on:
-
-    - per-round eviction (oram_round): ``bucket_map`` sends a bucket
-      that a fetched path meets to its output row (`_bucket_owner_map`);
-      at the ``dense_levels`` — the levels the batch covers — every
-      bucket is a target and its own output row, so those levels skip
-      the lookup;
-    - delayed flush (oram_flush): ``bucket_map`` = deduplicated target
-      rows of the compacted window union.
+    output of ``n_rows·Z`` slots (OOB = unplaced). ``bucket_map`` sends
+    a bucket that a fetched path meets to its output row
+    (`_bucket_owner_map`); at the ``dense_levels`` — the levels the
+    batch covers — every bucket is a target and its own output row, so
+    those levels skip the lookup.
     """
     h, z = cfg.height, cfg.bucket_slots
     w = valid.shape[0]
@@ -347,16 +332,6 @@ def oram_round(
     the payload-tree transcript, column 1 the internal posmap ORAM's —
     exactly B internal accesses per round regardless of the indices.
     """
-    if cfg.delayed_eviction:
-        # evict_window > 1 (config.py evict_every): this round is
-        # fetch-only — gather+decrypt+stash/buffer update, ZERO tree
-        # writes; oram_flush drains the accumulated window every
-        # evict_window rounds on the round-counter cadence
-        return _oram_fetch_round(
-            cfg, state, idxs, new_leaves, dummy_leaves, apply_batch,
-            axis_name=axis_name, occ_impl=occ_impl, sort_impl=sort_impl,
-            pm_new_leaves=pm_new_leaves, pm_dummy_leaves=pm_dummy_leaves,
-        )
     from .posmap import lookup_remap_round
 
     b = idxs.shape[0]
@@ -683,16 +658,6 @@ def oram_round(
             stash_idx=stash_idx,
             stash_val=stash_val,
             stash_leaf=stash_leaf,
-            # evict_window == 1: the buffer planes are zero-length and the
-            # window bookkeeping never advances — bit-for-bit the pre-PR-15
-            # per-round-eviction program
-            ebuf_idx=state.ebuf_idx,
-            ebuf_val=state.ebuf_val,
-            ebuf_leaf=state.ebuf_leaf,
-            ebuf_paths=state.ebuf_paths,
-            ebuf_rounds=state.ebuf_rounds,
-            ebuf_gen=state.ebuf_gen,
-            fetch_tag=state.fetch_tag,
             posmap=posmap,
             overflow=state.overflow + stash_dropped,
             nonces=nonces,
@@ -702,541 +667,3 @@ def oram_round(
         if recursive:
             leaves = jnp.stack([leaves, inner_leaves], axis=1)
     return new_state, outs, leaves
-
-
-def _oram_fetch_round(
-    cfg: OramConfig,
-    state: OramState,
-    idxs: jax.Array,  # u32[B] block indices (cfg.dummy_index = dummy op)
-    new_leaves: jax.Array,  # u32[B] fresh uniform leaves (remap targets)
-    dummy_leaves: jax.Array,  # u32[B] fresh uniform leaves (dummy fetches)
-    apply_batch,
-    axis_name: str | None = None,
-    occ_impl: str = "dense",
-    sort_impl: str = "xla",
-    pm_new_leaves: jax.Array | None = None,
-    pm_dummy_leaves: jax.Array | None = None,
-):
-    """The delayed-eviction fetch round (``cfg.evict_window > 1``).
-
-    Identical contract to :func:`oram_round` — same dedup, position
-    resolution, gather+decrypt, and vectorized apply — but the
-    scatter+encrypt half of the round is GONE: instead of evicting back
-    into the fetched buckets, every live working-set row recompacts into
-    the private eviction buffer (buffer-first; the stash catches the
-    spill, keeping stash occupancy the pressure signal), the round's
-    leaves are appended to the public window ledger (``ebuf_paths``),
-    and the fetched buckets are tagged with the current flush
-    generation. Tagged buckets' HBM/cache copies are *stale* — their
-    live rows moved to the buffer at their fetch round — so re-fetches
-    within one window invalidate them from the working set exactly like
-    non-owner duplicates (each live block still enters the working set
-    at most once, which the block→row map's uniqueness relies on). The
-    tree arrays, cache planes, nonces, and the cipher epoch are
-    untouched: the steady-state round performs ZERO HBM tree scatters
-    and zero encrypt work (CI-audited row accounting,
-    tools/check_tree_cache_oblivious.py:check_evict_round_accounting).
-    :func:`oram_flush` drains the window.
-    """
-    from .posmap import lookup_remap_round
-
-    b = idxs.shape[0]
-    z, v, plen = cfg.bucket_slots, cfg.value_words, cfg.path_len
-    s, c = cfg.stash_size, cfg.evict_buffer_slots
-    nslots = b * plen * z
-    recursive = cfg.posmap is not None
-
-    with device_phase("oram_fetch"):
-        # --- 1. dedup, position-map read/remap, path fetch (as E=1) --------
-        with device_phase("dedup"):
-            if occ_impl == "scan":
-                first_occ, last_occ, _ = occurrence_masks_sorted(
-                    idxs, cfg.dummy_index, sort_impl=sort_impl,
-                    key_bits=max(1, cfg.dummy_index.bit_length()),
-                )
-            else:
-                first_occ, last_occ, _ = occurrence_masks(idxs, cfg.dummy_index)
-        posmap, leaves, inner_leaves = lookup_remap_round(
-            cfg, state.posmap, idxs, new_leaves, dummy_leaves,
-            first_occ, last_occ,
-            pm_new_leaves=pm_new_leaves, pm_dummy_leaves=pm_dummy_leaves,
-            occ_impl=occ_impl, sort_impl=sort_impl,
-        )
-
-        with device_phase("path_index"):
-            path_b = jax.vmap(lambda lf: path_bucket_indices(cfg, lf))(leaves)
-            flat_b = path_b.reshape(b * plen)
-            cols_flat = jnp.repeat(jnp.arange(b, dtype=U32), plen)
-            bmap = _bucket_owner_map(cfg, flat_b, cols_flat, b)
-            # keep = this round's owner copy of a bucket that is NOT stale: a
-            # bucket tagged earlier in this flush window already surrendered its
-            # live rows to the buffer, so its HBM/cache bytes are dead copies
-            fresh = state.fetch_tag[flat_b] != state.ebuf_gen
-            keep = (bmap[flat_b] == cols_flat) & fresh
-
-            # HBM slot planes are addressed on the bucket axis ([n, Z] reshape
-            # views) exactly as in oram_round — flat slot ids escape u32/int32
-            # one geometry doubling before bucket ids do (rangelint;
-            # OPERATIONS.md §18). The tiny cache planes keep flat addressing.
-            kc = cfg.top_cache_levels
-            nbot = plen - kc
-            bot_b = path_b[:, kc:].reshape(b * nbot)
-            # level ℓ < kc heap ids are < 2^kc − 1 = cache_buckets by
-            # construction; the min states that per-level invariant for
-            # interval reasoning (runtime identity, see oram_round)
-            top_b = jnp.minimum(
-                path_b[:, :kc].reshape(b * kc),
-                U32(max(cfg.cache_buckets, 1) - 1),
-        )
-        top_slots = path_slot_indices(cfg, top_b).reshape(-1)
-
-    fused = cfg.cipher_impl == "pallas_fused"
-    with device_phase("oram_fetch"):
-        if axis_name is None and fused and cfg.encrypted:
-            from ..oblivious.pallas_gather import gather_decrypt_rows
-
-            pidx, pval = gather_decrypt_rows(
-                state.cipher_key, state.tree_idx, state.tree_val, state.nonces,
-                bot_b, z=z, rounds=cfg.cipher_rounds,
-                interpret=not _on_tpu(),
-            )
-        else:
-            pidx = _path_gather(
-                state.tree_idx.reshape(-1, z), bot_b, axis_name
-            )  # [B*nbot, z]
-            pval = _path_gather(state.tree_val, bot_b, axis_name)
-            pnonce = _path_gather(state.nonces, bot_b, axis_name)
-            with device_phase("cipher_decrypt"):
-                pidx, pval = cipher_rows(
-                    cfg, state.cipher_key, bot_b, pnonce, pidx, pval
-                )
-        if kc:
-            with device_phase("cache_read"):
-                pidx = jnp.concatenate(
-                    [state.cache_idx[top_slots].reshape(b, kc, z),
-                     pidx.reshape(b, nbot, z)], axis=1,
-                ).reshape(b * plen, z)
-                pval = jnp.concatenate(
-                    [state.cache_val[top_b].reshape(b, kc, z * v),
-                     pval.reshape(b, nbot, z * v)], axis=1,
-                ).reshape(b * plen, z * v)
-        # non-owner copies AND stale copies are invalidated
-        pidx = jnp.where(keep[:, None], pidx, SENTINEL)
-        if recursive:
-            from .path_oram import leaf_plane_cipher
-
-            pleaf = _path_gather(
-                state.tree_leaf.reshape(-1, z), bot_b, axis_name
-            )
-            pnonce_l = _path_gather(state.nonces, bot_b, axis_name)
-            with device_phase("cipher_decrypt"):
-                pleaf = leaf_plane_cipher(
-                    cfg, state.cipher_key, bot_b, pnonce_l, pleaf,
-                )
-            if kc:
-                with device_phase("cache_read"):
-                    pleaf = jnp.concatenate(
-                        [state.cache_leaf[top_slots].reshape(b, kc, z),
-                         pleaf.reshape(b, nbot, z)], axis=1,
-                    )
-            pleaf = pleaf.reshape(-1)
-
-    with device_phase("oram_fetch"):
-        # working set = stash ∪ buffer ∪ fetched paths ∪ B insert rows
-        w = s + c + nslots + b
-        widx0 = jnp.concatenate(
-            [state.stash_idx, state.ebuf_idx, pidx.reshape(-1),
-             jnp.full((b,), SENTINEL, U32)]
-        )
-        wval0 = jnp.concatenate(
-            [state.stash_val, state.ebuf_val, pval.reshape(-1, v),
-             jnp.zeros((b, v), U32)], axis=0
-        )
-
-    with device_phase("oram_apply"):
-        # --- 2. vectorized slot-order apply (as E=1; see oram_round) -------
-        iota_w = jnp.arange(w, dtype=U32)
-        row_map = jnp.full((cfg.blocks + 2,), U32(w)).at[
-            jnp.where(widx0 < U32(cfg.blocks), widx0, U32(cfg.blocks + 2))
-        ].set(iota_w, mode="drop", unique_indices=True)
-        pos0 = row_map[jnp.minimum(idxs, U32(cfg.blocks))]
-        present0 = pos0 != U32(w)
-        pos0 = jnp.minimum(pos0, U32(w - 1))
-        vals0 = jnp.where(
-            present0[:, None], wval0[pos0.astype(jnp.int32)], 0
-        )
-
-    with device_phase("oram_apply"):
-        outs, final_val, final_alive = apply_batch(vals0, present0)
-
-    with device_phase("oram_apply"):
-        upd = last_occ & present0
-        ins = last_occ & ~present0 & final_alive
-
-        slot_iota = jnp.arange(b, dtype=U32)
-        row_tgt = jnp.where(
-            upd, pos0, jnp.where(ins, U32(s + c + nslots) + slot_iota, U32(w))
-        )
-        widx = widx0.at[row_tgt].set(
-            jnp.where(final_alive, idxs, SENTINEL), mode="drop"
-        )
-        wval = wval0.at[row_tgt.astype(jnp.int32)].set(final_val, mode="drop")
-
-        if recursive:
-            # the only consumer of leaf assignments in the fetch round is
-            # the recursive per-row leaf plane below (flat maps resolve
-            # leaves from the posmap at FLUSH time — no eviction happens
-            # here, so tracing a working_leaves gather would add a dead
-            # secret-indexed access for the analyzers to walk)
-            wleaf = jnp.concatenate(
-                [state.stash_leaf, state.ebuf_leaf, pleaf, jnp.zeros((b,), U32)]
-            ).at[row_tgt].set(new_leaves, mode="drop")
-
-    # --- 3. recompact EVERYTHING into buffer ∪ stash (no eviction) -----
-    # buffer-first: the buffer is where window contents are expected to
-    # live, the stash is the spill — so stash occupancy remains the
-    # overflow-pressure signal the health fold watches. One rank + two
-    # split scatters; total live past C+S drops into the shared sticky
-    # overflow counter (the buffer-occupancy canary).
-    with device_phase("oram_evict"):
-        valid = widx != SENTINEL
-        crank = rank_of(valid)
-        ctarget = jnp.where(valid, crank, c + s)  # OOB = dropped
-        comb_idx = jnp.full((c + s,), SENTINEL, U32).at[ctarget].set(
-            widx, mode="drop", unique_indices=True
-        )
-        comb_val = jnp.zeros((c + s, v), U32).at[ctarget].set(
-            wval, mode="drop", unique_indices=True
-        )
-        ebuf_idx, stash_idx = comb_idx[:c], comb_idx[c:]
-        ebuf_val, stash_val = comb_val[:c], comb_val[c:]
-        if recursive:
-            comb_leaf = jnp.zeros((c + s,), U32).at[ctarget].set(
-                wleaf, mode="drop", unique_indices=True
-            )
-            ebuf_leaf, stash_leaf = comb_leaf[:c], comb_leaf[c:]
-        else:
-            ebuf_leaf, stash_leaf = state.ebuf_leaf, state.stash_leaf
-        n_live = jnp.sum(valid.astype(jnp.int32))
-        # == n_live - min(n_live, c+s), in the interval-transparent
-        # form (rangelint; the sticky counter's 2^16 budget absorbs it)
-        dropped = jnp.maximum(n_live - (c + s), 0).astype(U32)
-
-    with device_phase("oram_evict"):
-        # --- 4. window bookkeeping; the tree/cache/nonces are UNTOUCHED ----
-        # the append row: rounds < W whenever a fetch round runs (the
-        # batcher flushes at W and resets the counter); the min states that
-        # schedule invariant, which the declared [0, W] state bound cannot
-        # carry by itself (runtime identity — without it the slice start
-        # could reach the plane's end and XLA would clamp the write)
-        ebuf_paths = jax.lax.dynamic_update_slice(
-            state.ebuf_paths, leaves,
-            ((jnp.minimum(state.ebuf_rounds, U32(cfg.evict_window - 1))
-              * U32(b)).astype(jnp.int32),),
-        )
-        # monotone generations make scatter-max exact for duplicate buckets
-        fetch_tag = state.fetch_tag.at[flat_b].max(state.ebuf_gen)
-
-        new_state = OramState(
-            tree_idx=state.tree_idx,
-            tree_val=state.tree_val,
-            cache_idx=state.cache_idx,
-            cache_val=state.cache_val,
-            cache_leaf=state.cache_leaf,
-            tree_leaf=state.tree_leaf,
-            stash_idx=stash_idx,
-            stash_val=stash_val,
-            stash_leaf=stash_leaf,
-            ebuf_idx=ebuf_idx,
-            ebuf_val=ebuf_val,
-            ebuf_leaf=ebuf_leaf,
-            ebuf_paths=ebuf_paths,
-            ebuf_rounds=state.ebuf_rounds + U32(1),
-            ebuf_gen=state.ebuf_gen,
-            fetch_tag=fetch_tag,
-            posmap=posmap,
-            overflow=state.overflow + dropped,
-            nonces=state.nonces,
-            cipher_key=state.cipher_key,
-            epoch=state.epoch,
-        )
-        if recursive:
-            leaves = jnp.stack([leaves, inner_leaves], axis=1)
-    return new_state, outs, leaves
-
-
-def flush_target_slots(cfg: OramConfig) -> int:
-    """Static write-target count of one flush: the window's fetched
-    buckets deduplicated — at most ``window·fetch_count·path_len``
-    path slots, and never more than the whole (padded) heap. The
-    ``min`` is THE amortization: once ``E·F`` paths cover the tree,
-    each extra window round adds fetch traffic but no write traffic,
-    so the amortized scatter+encrypt cost per round falls as 1/E
-    toward ``n_buckets/(E·F)`` rows (bench.py ``evict_ab`` measures
-    the curve; the row-accounting gate pins the shape)."""
-    return min(cfg.evict_window * cfg.evict_fetch_count * cfg.path_len,
-               cfg.n_buckets_padded)
-
-
-def oram_flush(
-    cfg: OramConfig,
-    state: OramState,
-    axis_name: str | None = None,
-    sort_impl: str = "xla",
-) -> OramState:
-    """Batched eviction + write-back of one accumulated flush window.
-
-    Runs every ``evict_window`` fetch rounds on the round-counter
-    cadence (never on buffer contents — the schedule must stay
-    recipient-independent; the seeded flush_on_buffer_contents mutant
-    pins the failure mode). One pass:
-
-    1. the window's fetched paths (the public ``ebuf_paths`` ledger —
-       ``window·fetch_count`` leaves, rounds beyond ``ebuf_rounds``
-       masked inactive) expand to bucket ids and DEDUPLICATE into a
-       static ``flush_target_slots`` array: every bucket fetched this
-       window appears exactly once, so the window's shared buckets —
-       the whole top of the tree, re-fetched every round — are written
-       once per window instead of once per round. The dedup sort runs
-       on PUBLIC data (bucket ids are the past transcript);
-    2. the working set — eviction buffer ∪ stash — is greedily assigned
-       to the deepest target bucket on each entry's own path
-       (the SAME ``_assign_evictions`` body the per-round eviction
-       runs, with the compacted [target, slot] output layout);
-    3. one scatter+encrypt writes every target bucket back under the
-       current epoch — ``flush_target_slots`` rows, cached top buckets
-       peeled off to the plaintext cache planes by the heap-prefix
-       mask;
-    4. leftovers recompact into the stash, the buffer empties, and the
-       flush generation bumps (re-validating every tagged bucket in
-       O(1)).
-
-    Every tagged bucket MUST be rewritten here: its HBM bytes are a
-    stale copy of rows that moved to the buffer at fetch time, and a
-    later window would re-fetch them as fresh after the generation
-    bump. Deterministic given the state (no RNG), so journal replay
-    re-executes it bit-identically (engine/journal.py KIND_FLUSH).
-    Recursive position maps flush their internal tree in the same call.
-
-    **Sharded (``axis_name`` set, inside shard_map).** The dedup, the
-    eviction assignment, and the stash/buffer recompaction all run on
-    the replicated private working set — identical on every chip, no
-    collective — and only the final tree/nonce scatters change: the
-    ``_path_scatter`` sharded branch ANDs the ``tree_tgt`` owner mask
-    with each chip's contiguous heap range, so every chip writes
-    exactly the target rows it owns and the union across the mesh is
-    the single-chip flush bit for bit. The per-chip scatter still
-    carries all ``t`` compacted rows (uniform static shape — row
-    counts stay a pure function of geometry, never contents); non-owned
-    rows drop out of bounds. Cache planes and the recursive inner tree
-    are replicated private state and always take the axis-free path.
-    """
-    from .posmap import inner_oram_config
-
-    z, v, plen = cfg.bucket_slots, cfg.value_words, cfg.path_len
-    s, c = cfg.stash_size, cfg.evict_buffer_slots
-    ncols = cfg.evict_window * cfg.evict_fetch_count
-    f = cfg.evict_fetch_count
-    pad = cfg.n_buckets_padded
-    t = flush_target_slots(cfg)
-    recursive = cfg.posmap is not None
-
-    posmap = state.posmap
-    if recursive:
-        icfg = inner_oram_config(cfg.posmap)
-        # the INNER tree is replicated private state (mesh.py P() specs),
-        # never sharded — its flush must run the axis-free program on
-        # every chip (the same convention oram_round uses for inner
-        # accesses). Passing the outer axis_name here would owner-mask a
-        # replicated plane against its FULL size: shard 0 would own
-        # everything and every other replica nothing, silently diverging
-        # the replicas on the first recursive flush.
-        posmap = posmap._replace(
-            inner=oram_flush(icfg, posmap.inner, None, sort_impl)
-        )
-
-    with device_phase("oram_flush"):
-        leaves = state.ebuf_paths  # u32[ncols], public window ledger
-        active = (jnp.arange(ncols, dtype=U32) // U32(f)) < state.ebuf_rounds
-        path_b = jax.vmap(lambda lf: path_bucket_indices(cfg, lf))(leaves)
-        flat_b = path_b.reshape(ncols * plen)
-        active_flat = jnp.repeat(active, plen)
-        # -- 1. public dedup: window bucket set → t compacted targets
-        sb = jnp.sort(jnp.where(active_flat, flat_b, U32(pad)))
-        first = jnp.concatenate(
-            [jnp.ones((1,), jnp.bool_), sb[1:] != sb[:-1]]
-        ) & (sb < U32(pad))
-        fi = first.astype(U32)
-        # compacted slot of each unique run: the exclusive count of
-        # earlier firsts, as the shifted inclusive cumsum (the
-        # interval-transparent form, see primitives.rank_of — cumsum−fi
-        # reads as a full-lane u32 subtraction to interval reasoning)
-        crank = jnp.concatenate(
-            [jnp.zeros((1,), U32), jnp.cumsum(fi)[:-1]]
-        )
-        # target slot → bucket id (pad = unused slot, dropped on write)
-        tgt_b = jnp.full((t,), U32(pad)).at[
-            jnp.where(first, crank, U32(t))
-        ].set(sb, mode="drop", unique_indices=True)
-        # dense bucket id → target slot (t = not a target this window)
-        dmap = jnp.full((pad,), U32(t)).at[
-            jnp.where(first, sb, U32(pad))
-        ].set(crank, mode="drop", unique_indices=True)
-
-        # working set = buffer ∪ stash (buffer-first, the fetch-round
-        # recompaction order)
-        widx = jnp.concatenate([state.ebuf_idx, state.stash_idx])
-        wval = jnp.concatenate([state.ebuf_val, state.stash_val], axis=0)
-        if recursive:
-            wleaf = jnp.concatenate([state.ebuf_leaf, state.stash_leaf])
-        else:
-            wleaf = working_leaves(posmap, cfg, widx)
-
-        valid = widx != SENTINEL
-        # [target, slot] layout over the compacted window union
-        slot_tgt, placed = _assign_evictions(
-            cfg, valid, wleaf, dmap, t, sort_impl
-        )
-        new_pidx = jnp.full((t * z,), SENTINEL, U32).at[slot_tgt].set(
-            widx, mode="drop", unique_indices=True
-        )
-        new_pval = jnp.zeros((t * z, v), U32).at[slot_tgt].set(
-            wval, mode="drop", unique_indices=True
-        )
-        if recursive:
-            new_pleaf = jnp.zeros((t * z,), U32).at[slot_tgt].set(
-                wleaf, mode="drop", unique_indices=True
-            )
-
-        # leftovers recompact into the stash; the buffer empties
-        leftover = valid & ~placed
-        srank = rank_of(leftover)
-        starget = jnp.where(leftover, srank, s)  # OOB = dropped
-        stash_idx = jnp.full((s,), SENTINEL, U32).at[starget].set(
-            widx, mode="drop", unique_indices=True
-        )
-        stash_val = jnp.zeros((s, v), U32).at[starget].set(
-            wval, mode="drop", unique_indices=True
-        )
-        stash_leaf = (
-            jnp.zeros((s,), U32).at[starget].set(
-                wleaf, mode="drop", unique_indices=True
-            )
-            if recursive
-            else state.stash_leaf
-        )
-        n_left = jnp.sum(leftover.astype(jnp.int32))
-        # == n_left - min(n_left, s), in the interval-transparent form
-        stash_dropped = jnp.maximum(n_left - s, 0).astype(U32)
-
-        # --- write-back: every target bucket once, cached top buckets
-        # (a heap-id prefix) peeled off to the plaintext cache planes.
-        # Shapes are t rows per plane; masked slots drop out of bounds.
-        # HBM slot planes are addressed on the bucket axis ([n, Z]
-        # reshape views) as in oram_round — flat slot ids escape
-        # u32/int32 one geometry doubling before bucket ids (rangelint,
-        # OPERATIONS.md §18); the tiny cache planes keep flat slot
-        # addressing over CLAMPED bucket ids (cached targets are < cb
-        # by the is_cached mask; the min states it for the intervals).
-        kc = cfg.top_cache_levels
-        cb = cfg.cache_buckets
-        valid_tgt = tgt_b < U32(pad)
-        is_cached = tgt_b < U32(cb)  # kc=0 → cb=0 → all False
-        tree_tgt = valid_tgt & ~is_cached
-        cache_tgt_slots = path_slot_indices(
-            cfg, jnp.minimum(tgt_b, U32(max(cb, 1) - 1))
-        ).reshape(-1)  # [t*z] flat cache-plane slots
-        pidx2 = new_pidx.reshape(t, z)
-        pval2 = new_pval.reshape(t, z * v)
-        epochs_w = jnp.broadcast_to(state.epoch[None, :], (t, 2))
-        fused = cfg.cipher_impl == "pallas_fused"
-        if axis_name is None and fused and cfg.encrypted:
-            from ..oblivious.pallas_gather import scatter_encrypt_rows
-
-            tree_idx_new, tree_val_new, nonces = scatter_encrypt_rows(
-                state.cipher_key, state.tree_idx, state.tree_val,
-                state.nonces, tgt_b, tree_tgt, state.epoch,
-                pidx2, pval2,
-                z=z, rounds=cfg.cipher_rounds,
-                interpret=not _on_tpu(),
-            )
-        else:
-            with device_phase("cipher_encrypt"):
-                enc_pidx, enc_pval = cipher_rows(
-                    cfg, state.cipher_key, tgt_b, epochs_w, pidx2, pval2
-                )
-            tree_idx_new = _path_scatter(
-                state.tree_idx.reshape(-1, z), tgt_b, enc_pidx, axis_name,
-                tree_tgt,
-            ).reshape(-1)
-            tree_val_new = _path_scatter(
-                state.tree_val, tgt_b, enc_pval, axis_name, tree_tgt
-            )
-            nonces = (
-                _path_scatter(
-                    state.nonces, tgt_b, epochs_w, axis_name, tree_tgt
-                )
-                if cfg.encrypted
-                else state.nonces
-            )
-        if kc:
-            # cache planes are indexed by heap id directly (a heap
-            # prefix), so the clamped tgt_b slots address them; only
-            # cached targets land, the rest drop out of bounds
-            with device_phase("cache_write"):
-                cache_idx_new = _path_scatter(
-                    state.cache_idx, cache_tgt_slots, new_pidx, None,
-                    jnp.repeat(is_cached, z),
-                )
-                cache_val_new = _path_scatter(
-                    state.cache_val, tgt_b, pval2, None, is_cached
-                )
-        else:
-            cache_idx_new = state.cache_idx
-            cache_val_new = state.cache_val
-        cache_leaf_new = state.cache_leaf
-        if recursive:
-            from .path_oram import leaf_plane_cipher
-
-            pleaf2 = new_pleaf.reshape(t, z)
-            with device_phase("cipher_encrypt"):
-                enc_pleaf = leaf_plane_cipher(
-                    cfg, state.cipher_key, tgt_b, epochs_w, pleaf2
-                )
-            tree_leaf_new = _path_scatter(
-                state.tree_leaf.reshape(-1, z), tgt_b, enc_pleaf, axis_name,
-                tree_tgt,
-            ).reshape(-1)
-            if kc:
-                with device_phase("cache_write"):
-                    cache_leaf_new = _path_scatter(
-                        state.cache_leaf, cache_tgt_slots, new_pleaf, None,
-                        jnp.repeat(is_cached, z),
-                    )
-        else:
-            tree_leaf_new = state.tree_leaf
-
-    with device_phase("oram_flush"):
-        return OramState(
-            tree_idx=tree_idx_new,
-            tree_val=tree_val_new,
-            cache_idx=cache_idx_new,
-            cache_val=cache_val_new,
-            cache_leaf=cache_leaf_new,
-            tree_leaf=tree_leaf_new,
-            stash_idx=stash_idx,
-            stash_val=stash_val,
-            stash_leaf=stash_leaf,
-            ebuf_idx=jnp.full((c,), SENTINEL, U32),
-            ebuf_val=jnp.zeros((c, v), U32),
-            ebuf_leaf=jnp.zeros_like(state.ebuf_leaf),
-            ebuf_paths=state.ebuf_paths,  # inactive at rounds=0; public
-            ebuf_rounds=jnp.zeros((), U32),
-            ebuf_gen=state.ebuf_gen + U32(1),
-            fetch_tag=state.fetch_tag,  # generation bump re-validates all
-            posmap=posmap,
-            overflow=state.overflow + stash_dropped,
-            nonces=nonces,
-            cipher_key=state.cipher_key,
-            epoch=epoch_next(state.epoch),
-        )

@@ -12,7 +12,6 @@ from .mesh import (
     engine_state_specs,
     init_sharded_engine,
     make_mesh,
-    make_sharded_flush,
     make_sharded_step,
     make_sharded_sweep,
     shard_engine_state,
@@ -24,7 +23,6 @@ __all__ = [
     "engine_state_specs",
     "init_sharded_engine",
     "make_mesh",
-    "make_sharded_flush",
     "make_sharded_step",
     "make_sharded_sweep",
     "shard_engine_state",

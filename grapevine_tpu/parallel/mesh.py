@@ -34,7 +34,7 @@ import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..engine.expiry import expiry_sweep
-from ..engine.round_step import engine_flush_step, engine_round_step
+from ..engine.round_step import engine_round_step
 from ..engine.state import EngineConfig, EngineState
 from ..oram.path_oram import OramState
 
@@ -65,26 +65,6 @@ def _oram_specs() -> OramState:
         stash_idx=P(),
         stash_val=P(),
         stash_leaf=P(),
-        # delayed-eviction buffer + window bookkeeping (PR 15):
-        # REPLICATED private state, the stash's standing — decided, not
-        # defaulted. Every chip's fetch round psums the identical full
-        # working set (_path_gather), then runs the identical branchless
-        # accumulation into these planes, so the replicas stay
-        # bit-identical with zero extra collectives; sharding them would
-        # buy back KBs of HBM (the buffer is E·F·≈4 entries, not the
-        # GB-scale trees) at the price of a collective in the flush's
-        # eviction assignment. The flush (make_sharded_flush →
-        # engine_flush_step(axis_name=...) → oram_flush) reads the
-        # replicated buffer ∪ stash everywhere and owner-masks only the
-        # final tree/nonce scatters per chip, so the union across the
-        # mesh is the single-chip flush bit for bit.
-        ebuf_idx=P(),
-        ebuf_val=P(),
-        ebuf_leaf=P(),
-        ebuf_paths=P(),
-        ebuf_rounds=P(),
-        ebuf_gen=P(),
-        fetch_tag=P(),
         # flat: one replicated array. Recursive: a RecursivePosMapState
         # pytree — the P() prefix replicates the whole internal ORAM
         # (its own bucket tree included; sharding the *inner* tree along
@@ -171,9 +151,8 @@ def validate_sharded_geometry(ecfg: EngineConfig, mesh: Mesh) -> None:
     """Directed refusal for knob combinations the sharded programs do
     not cover: raise a precise error naming the combination, or return.
 
-    Everything the sharded step/flush pair DOES cover is silent here:
-    evict_every >= 1 (the owner-masked flush), recursive position maps
-    (inner trees replicated), tree-top caching (cache planes
+    Everything the sharded step DOES cover is silent here: recursive
+    position maps (inner trees replicated), tree-top caching (cache planes
     replicated), all cipher impls ("pallas_fused" runs as "pallas"
     inside shard_map — gather, psum, then the Pallas cipher kernel —
     and GrapevineEngine says so once at WARNING when it is built), both
@@ -199,10 +178,7 @@ def make_sharded_step(ecfg: EngineConfig, mesh: Mesh):
     engine, i.e. the same commit schedule the single-chip production path
     uses (bit-identical results — tested in tests/test_parallel.py, the
     analog of the reference's SGX_MODE=SW simulation testing, reference
-    .github/workflows/ci.yaml:15-16). Delayed eviction (``evict_every >
-    1``) composes: fetch-only rounds accumulate into the REPLICATED
-    eviction buffer (see ``_oram_specs``) and the owner-masked flush
-    (:func:`make_sharded_flush`) drains the window.
+    .github/workflows/ci.yaml:15-16).
     """
     validate_sharded_geometry(ecfg, mesh)
     specs = engine_state_specs()
@@ -214,37 +190,6 @@ def make_sharded_step(ecfg: EngineConfig, mesh: Mesh):
         check_vma=False,
     )
     return _jit_sharded(step, mesh, 1, 2)
-
-
-def make_sharded_flush(ecfg: EngineConfig, mesh: Mesh):
-    """Jit-compiled delayed-eviction flush with the trees sharded.
-
-    Same signature and semantics as ``engine_flush_step(ecfg, state)``:
-    drains the accumulated window into both trees. Inside shard_map the
-    dedup + eviction assignment run replicated (the buffer ∪ stash
-    working set is replicated private state) and each chip's
-    scatter+encrypt pass is owner-masked to its contiguous heap range
-    via the same ``_path_scatter`` machinery the sharded round uses —
-    the per-chip write still carries all ``flush_target_slots`` rows
-    (uniform static shape; the leak argument in oram/round.py), but
-    only owned rows land, so the union across the mesh is exactly the
-    single-chip flush.
-    """
-    if ecfg.evict_every <= 1:
-        raise ValueError(
-            "make_sharded_flush: evict_every=1 has no flush program — "
-            "the per-round sharded step already writes back every path"
-        )
-    validate_sharded_geometry(ecfg, mesh)
-    specs = engine_state_specs()
-    flush = jax.shard_map(
-        functools.partial(engine_flush_step, ecfg, axis_name=TREE_AXIS),
-        mesh=mesh,
-        in_specs=(specs,),
-        out_specs=specs,
-        check_vma=False,
-    )
-    return _jit_sharded(flush, mesh, 0, 0)
 
 
 def make_sharded_sweep(ecfg: EngineConfig, mesh: Mesh):

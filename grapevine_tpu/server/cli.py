@@ -89,41 +89,15 @@ def build_parser() -> argparse.ArgumentParser:
         "only — the frontend has no round pipeline",
     )
     p.add_argument(
-        "--evict-every",
-        type=int,
-        default=None,
-        help="delayed batched eviction cadence E (oram/round.py, "
-        "OPERATIONS.md §19): fetched path contents accumulate in a "
-        "bounded private buffer and the scatter+encrypt half of the "
-        "round runs ONCE per E rounds over the window's deduplicated "
-        "bucket union — the steady-state round is gather+decrypt+"
-        "stash-update only. Responses and logical state are "
-        "bit-identical at every E; the flush cadence is a pure round "
-        "count, never buffer contents (CI-audited). 1 = per-round "
-        "eviction, bit for bit; unset = auto (currently 1; the "
-        "on-chip flush overlap is not measured on the chip). "
-        "Device-owning roles only",
-    )
-    p.add_argument(
-        "--evict-buffer-slots",
-        type=int,
-        default=None,
-        help="eviction-buffer capacity override (rows per payload "
-        "tree) under --evict-every > 1; unset = auto sizing "
-        "(OPERATIONS.md §19 — min(blocks, 2·Z·window·fetches + "
-        "slack)). Watch grapevine_evict_buffer_high_water before "
-        "lowering it. Device-owning roles only",
-    )
-    p.add_argument(
         "--shards",
         type=int,
         default=1,
         help="bucket-tree shard count across the local device mesh "
-        "(parallel/mesh.py, OPERATIONS.md §22): each of the first N JAX "
+        "(parallel/mesh.py, OPERATIONS.md §5): each of the first N JAX "
         "devices owns a contiguous heap range of both bucket trees; the "
-        "round gathers over ICI and the delayed-eviction flush "
-        "owner-masks its scatters per chip. Responses, transcripts, and "
-        "logical state are bit-identical at every shard count, and "
+        "round gathers over ICI and owner-masks its scatters per chip. "
+        "Responses, transcripts, and logical state are bit-identical "
+        "at every shard count, and "
         "journals/checkpoints replay across shard counts (the knob is "
         "outside the durability fingerprint, like --pipeline-depth). "
         "Power of two dividing both trees' padded bucket counts; "
@@ -376,19 +350,6 @@ def build_parser() -> argparse.ArgumentParser:
         "(server/adaptive.py has the obliviousness argument). Default: "
         "the static --batch-wait-ms window",
     )
-    p.add_argument(
-        "--flush-window",
-        dest="flush_window_ms",
-        type=float,
-        default=None,
-        metavar="MS",
-        help="flush-aware collection: when the delayed-eviction flush "
-        "(--evict-every) occupies the device, stretch the overlapping "
-        "collection window by MS milliseconds to harvest a fuller "
-        "round. The flush cadence itself stays strictly every "
-        "--evict-every rounds — this knob only retimes host-side "
-        "collection, a pure function of the public round counter",
-    )
     p.add_argument("-v", "--verbose", action="store_true")
     return p
 
@@ -419,11 +380,10 @@ _TRACE_SLO_FLAGS = {"trace_ring_size", "slo_commit_p99_ms",
 
 #: device-engine geometry/execution knobs: only roles that build an
 #: engine take them — a frontend supplying --posmap-impl,
-#: --tree-top-cache-levels, --pipeline-depth, or --evict-every would
+#: --tree-top-cache-levels or --pipeline-depth would
 #: silently configure nothing (its engine lives in another process)
 _ENGINE_GEOM_FLAGS = {"posmap_impl", "tree_top_cache_levels",
-                      "pipeline_depth", "evict_every",
-                      "evict_buffer_slots", "shards"}
+                      "pipeline_depth", "shards"}
 
 #: fleet-aggregator topology/cadence: only the fleet role scrapes —
 #: any other role supplied --fleet-members would silently aggregate
@@ -448,11 +408,11 @@ _STANDBY_FLAGS = {"standby_listen", "promote_from"}
 #: aggregator and the pre-promotion standby touch neither
 _HOSTPIPE_FLAGS = {"host_workers"}
 
-#: adaptive/flush-aware collection shapes the device round window, so
+#: adaptive collection shapes the device round window, so
 #: only roles that own a BatchScheduler over an in-process engine take
-#: them — a frontend supplying --adaptive-batch would silently shape
+#: it — a frontend supplying --adaptive-batch would silently shape
 #: nothing (its rounds are collected in the engine tier)
-_ADAPTIVE_FLAGS = {"adaptive_batch", "flush_window_ms"}
+_ADAPTIVE_FLAGS = {"adaptive_batch"}
 
 _ROLE_FLAGS = {
     "mono": {"listen", "tls_cert", "tls_key", "expiry_period",
@@ -607,8 +567,6 @@ def main(argv=None) -> int:
         posmap_impl=args.posmap_impl,
         tree_top_cache_levels=args.tree_top_cache_levels,
         pipeline_depth=args.pipeline_depth,
-        evict_every=args.evict_every,
-        evict_buffer_slots=args.evict_buffer_slots,
         shards=args.shards,
     )
     identity = None
@@ -672,7 +630,7 @@ def main(argv=None) -> int:
             print(f"metrics endpoint on port {mport}", flush=True)
         # SIGUSR1 = the operator's (or orchestrator's) promotion order;
         # the handler only sets an event — the takeover itself (fence,
-        # tail drain, flush completion) runs on the main thread
+        # tail drain) runs on the main thread
         promote_wake = threading.Event()
         signal.signal(signal.SIGUSR1, lambda s, f: promote_wake.set())
         _install_drain_handlers(replica.close)
@@ -696,7 +654,6 @@ def main(argv=None) -> int:
             trace_ring_size=args.trace_ring_size, slo=_slo_config(args),
             profile_enable=args.profile_enable,
             adaptive_batch=args.adaptive_batch,
-            flush_window_ms=args.flush_window_ms,
         )
         eport = server.start(args.engine_listen)
         print(f"promoted engine tier listening on port {eport}",
@@ -724,8 +681,7 @@ def main(argv=None) -> int:
                               replicate_to=args.replicate_to,
                               ship_every=args.ship_every,
                               host_workers=args.host_workers,
-                              adaptive_batch=args.adaptive_batch,
-                              flush_window_ms=args.flush_window_ms)
+                              adaptive_batch=args.adaptive_batch)
         port = engine.start(args.engine_listen)
         print(f"grapevine-tpu engine tier listening on port {port}",
               flush=True)
@@ -769,7 +725,6 @@ def main(argv=None) -> int:
             ship_every=args.ship_every,
             host_workers=args.host_workers,
             adaptive_batch=args.adaptive_batch,
-            flush_window_ms=args.flush_window_ms,
         )
     tls_cert = open(args.tls_cert, "rb").read() if args.tls_cert else None
     tls_key = open(args.tls_key, "rb").read() if args.tls_key else None

@@ -103,7 +103,6 @@ class BatchScheduler:
         scheme=None,
         restart_on_crash: bool = False,
         pipeline_depth: int | None = None,
-        flush_window_ms: float | None = None,
     ):
         self.engine = engine
         self.max_wait = max_wait_ms / 1000.0
@@ -139,32 +138,10 @@ class BatchScheduler:
         #: planted by the serving layer after observability attaches;
         #: None = the static max_wait/idle_gap/full-batch window
         self.adaptive = None
-        #: flush-aware collection (server/adaptive.py module docstring
-        #: has the obliviousness argument): when the engine reports a
-        #: delayed-eviction flush is on the device (flush_bubble_pending
-        #: — a pure function of the round counter), the next collection
-        #: window may stretch by this declared extra wait, harvesting
-        #: arrivals into a fuller round instead of dispatching a thin
-        #: round that queues behind the flush anyway. None/0 = off.
-        self.flush_window = (flush_window_ms or 0.0) / 1000.0
-        if self.flush_window < 0:
-            raise ValueError("flush_window_ms must be >= 0")
         #: batch-level telemetry sink (engine/metrics.py on an
         #: obs.TelemetryRegistry); the scheduler records into the
         #: engine's registry so /metrics serves one merged view
         self.metrics = getattr(engine, "metrics", None)
-        self._c_flush_stretch = None
-        registry = getattr(self.metrics, "registry", None)
-        if self.flush_window > 0 and registry is not None:
-            # successive schedulers over one engine (bench arms, standby
-            # promotion) share the counter instead of re-registering
-            existing = registry.get(
-                "grapevine_host_flush_window_stretches_total")
-            self._c_flush_stretch = existing if existing is not None \
-                else registry.counter(
-                "grapevine_host_flush_window_stretches_total",
-                "collection windows stretched into a delayed-eviction "
-                "flush bubble (--flush-window; round-count cadence only)")
         #: (request, auth, future, perf_counter enqueue time)
         self._queue: list[
             tuple[QueryRequest, AuthItem | None, Future, float]
@@ -358,24 +335,12 @@ class BatchScheduler:
             # scans and registry samples must never extend the
             # collector's critical section — the note_arrival stance).
             # Inputs are public aggregates only: the queue DEPTH (an
-            # integer), the arrival EWMA, the SLO burn rates, and the
-            # engine's round-counter flush cadence — never queue or
-            # buffer contents (server/adaptive.py; CI seeds the
+            # integer), the arrival EWMA and the SLO burn rates — never
+            # queue contents (server/adaptive.py; CI seeds the
             # contents-dependent mutants).
             w_wait, w_gap, w_target = self.max_wait, self.idle_gap, bs
-            if has_work:
-                if self.adaptive is not None:
-                    w_wait, w_gap, w_target = self.adaptive.decide(depth0)
-                if self.flush_window > 0 and getattr(
-                    self.engine, "flush_bubble_pending", lambda: False
-                )():
-                    # the device is busy with the delayed-eviction flush
-                    # (a round-count fact): stretch this window into the
-                    # bubble and harvest a fuller round
-                    w_wait += self.flush_window
-                    w_target = bs
-                    if self._c_flush_stretch is not None:
-                        self._c_flush_stretch.inc()
+            if has_work and self.adaptive is not None:
+                w_wait, w_gap, w_target = self.adaptive.decide(depth0)
             with self._cv:
                 chunk = []
                 if self._queue:

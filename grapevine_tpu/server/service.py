@@ -110,7 +110,6 @@ class GrapevineServer:
         ship_every: int = 1,
         host_workers: int = 0,
         adaptive_batch: bool = False,
-        flush_window_ms: float | None = None,
     ):
         self.config = config or GrapevineConfig()
         if scheduler is not None and replicate_to is not None:
@@ -126,11 +125,11 @@ class GrapevineServer:
                     "durability needs the device engine in-process (the "
                     "frontend role has no state to checkpoint)"
                 )
-            if adaptive_batch or flush_window_ms:
+            if adaptive_batch:
                 raise ValueError(
-                    "adaptive/flush-aware batching shapes the device "
-                    "round collection window — only the engine owner "
-                    "has one (the frontend forwards ops unbatched)"
+                    "adaptive batching shapes the device round "
+                    "collection window — only the engine owner has one "
+                    "(the frontend forwards ops unbatched)"
                 )
             self.engine = None
             self.scheduler = scheduler
@@ -150,7 +149,6 @@ class GrapevineServer:
                 clock=clock,
                 scheme=get_signature_scheme(self.config.signature_scheme),
                 restart_on_crash=worker_restart,
-                flush_window_ms=flush_window_ms,
                 **sched_kwargs,
             )
         self.attestation = attestation or chan.NullAttestation()

@@ -68,8 +68,7 @@ class EngineServer:
                  trace_ring_size: int = 512, slo=None,
                  profile_enable: bool = False, engine=None,
                  replicate_to: str | None = None, ship_every: int = 1,
-                 host_workers: int = 0, adaptive_batch: bool = False,
-                 flush_window_ms: float | None = None):
+                 host_workers: int = 0, adaptive_batch: bool = False):
         from ..engine.batcher import GrapevineEngine
         from ..session import get_signature_scheme
         from .scheduler import BatchScheduler
@@ -123,7 +122,6 @@ class EngineServer:
             clock=clock,
             scheme=get_signature_scheme(self.config.signature_scheme),
             restart_on_crash=worker_restart,
-            flush_window_ms=flush_window_ms,
             **kwargs,
         )
         if adaptive_batch:

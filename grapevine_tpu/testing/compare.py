@@ -10,7 +10,6 @@ __all__ = [
     "logical_tree_planes",
     "assert_logical_state_equal",
     "logical_block_map",
-    "assert_logical_content_equal",
 ]
 
 
@@ -100,25 +99,13 @@ def logical_tree_planes(cfg, oram):
         val[:cb] = np.asarray(oram.cache_val)
         if leaf is not None:
             leaf[:cb] = np.asarray(oram.cache_leaf).reshape(cb, z)
-    if cfg.delayed_eviction:
-        # delayed eviction (PR 15): buckets fetched since the last flush
-        # hold stale copies — the live rows moved to the eviction buffer
-        # (a separate private plane, like the stash, not part of the
-        # tree view). Mask them so the logical planes show only
-        # authoritative tree content.
-        from ..oblivious.primitives import SENTINEL
-
-        stale = np.asarray(oram.fetch_tag) == int(np.asarray(oram.ebuf_gen))
-        idx[stale] = int(SENTINEL)
     return idx, val, leaf
 
 
 def logical_block_map(cfg, oram) -> dict:
     """{block index: value bytes} of every live block in one ORAM —
-    tree planes (cache overlaid, stale buckets masked) ∪ eviction
-    buffer ∪ stash. Placement-free: the canonical content view the
-    delayed-eviction bit-identity contract compares (host-side; never
-    on the round path)."""
+    tree planes (cache overlaid) ∪ stash. Placement-free (host-side;
+    never on the round path)."""
     from ..oblivious.primitives import SENTINEL
 
     z, v = cfg.bucket_slots, cfg.value_words
@@ -128,53 +115,16 @@ def logical_block_map(cfg, oram) -> dict:
     flat = idx.reshape(-1)
     for slot in np.nonzero(flat != int(SENTINEL))[0]:
         out[int(flat[slot])] = rows[slot].tobytes()
-    for pidx, pval in ((oram.ebuf_idx, oram.ebuf_val),
-                       (oram.stash_idx, oram.stash_val)):
-        sidx = np.asarray(pidx)
-        sval = np.asarray(pval)
-        for j in np.nonzero(sidx != int(SENTINEL))[0]:
-            blk = int(sidx[j])
-            assert blk not in out, (
-                f"block {blk} lives in two places — the "
-                "tree/buffer/stash partition invariant broke"
-            )
-            out[blk] = sval[j].tobytes()
-    return out
-
-
-def assert_logical_content_equal(ecfg_a, sa, ecfg_b, sb, ctx=""):
-    """Cross-``evict_every`` final-state contract (PR 15): the two
-    engines hold the SAME live blocks with the SAME values, positions,
-    and scalars — physical placement (which bucket/stash/buffer row a
-    block occupies) legitimately differs, because E=1 evicts every
-    round while E>1 evicts each window's union of paths at once. The
-    position maps, freelist, and every engine scalar must still be
-    bit-identical (the RNG chain and remap draws are E-independent)."""
-    from ..oram.posmap import read_table
-
-    for tree in ("rec", "mb"):
-        ca, cb_ = getattr(ecfg_a, tree), getattr(ecfg_b, tree)
-        oa, ob = getattr(sa, tree), getattr(sb, tree)
-        ma, mb_ = logical_block_map(ca, oa), logical_block_map(cb_, ob)
-        assert set(ma) == set(mb_), (
-            f"{ctx}: {tree} live-block sets diverge "
-            f"(only-a={sorted(set(ma) - set(mb_))[:8]}, "
-            f"only-b={sorted(set(mb_) - set(ma))[:8]})"
+    sidx = np.asarray(oram.stash_idx)
+    sval = np.asarray(oram.stash_val)
+    for j in np.nonzero(sidx != int(SENTINEL))[0]:
+        blk = int(sidx[j])
+        assert blk not in out, (
+            f"block {blk} lives in two places — the "
+            "tree/stash partition invariant broke"
         )
-        bad = [k for k in ma if ma[k] != mb_[k]]
-        assert not bad, f"{ctx}: {tree} block values diverge at {bad[:8]}"
-        assert np.array_equal(
-            read_table(ca, oa.posmap), read_table(cb_, ob.posmap)
-        ), f"{ctx}: {tree} logical position table diverges"
-        for f in ("overflow", "cipher_key"):
-            assert np.array_equal(
-                np.asarray(getattr(oa, f)), np.asarray(getattr(ob, f))
-            ), f"{ctx}: {tree}.{f} diverges"
-    for f in ("freelist", "free_top", "recipients", "seq", "hash_key",
-              "id_key", "rng"):
-        assert np.array_equal(
-            np.asarray(getattr(sa, f)), np.asarray(getattr(sb, f))
-        ), f"{ctx}: {f} diverges"
+        out[blk] = sval[j].tobytes()
+    return out
 
 
 def assert_logical_state_equal(ecfg_a, sa, ecfg_b, sb, ctx=""):

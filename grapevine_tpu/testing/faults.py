@@ -35,13 +35,6 @@ Instrumented points (grep ``faults.crash`` / ``faults.hit``):
                                round k+1 is durable but round k is still
                                mid-flight on the device
 - ``round.post_dispatch``      round journaled + dispatched, before resolve
-- ``flush.pre_dispatch``       delayed-eviction flush frame journaled +
-                               fsynced, before the flush dispatches — the
-                               kill-at-flush window: the E-th round is
-                               durable and possibly mid-flight, the flush
-                               is durable but not applied
-- ``flush.post_dispatch``      flush journaled + dispatched, before any
-                               resolve
 """
 
 from __future__ import annotations
@@ -64,8 +57,6 @@ ALL_POINTS = (
     "checkpoint.post_rename",
     "round.pre_dispatch",
     "round.post_dispatch",
-    "flush.pre_dispatch",
-    "flush.post_dispatch",
 )
 
 

@@ -1,9 +1,9 @@
-"""SLO-adaptive + flush-aware round collection (server/adaptive.py,
+"""SLO-adaptive round collection (server/adaptive.py,
 server/scheduler.py).
 
 The policy's contract: every decision is a function of PUBLIC load
-aggregates — queue depth (an integer), the arrival-rate EWMA, the SLO
-burn rates, and the round-counter flush cadence. The unit tests pin
+aggregates — queue depth (an integer), the arrival-rate EWMA and the
+SLO burn rates. The unit tests pin
 each decision kind; the scheduler tests prove the decisions actually
 shape the collection window; the obliviousness teeth live in
 test_oblint.py (the seeded adaptive_batch_from_contents mutant must
@@ -230,145 +230,21 @@ def test_adaptive_sparse_beats_static_window_latency():
         sched.close()
 
 
-def test_flush_window_stretch_harvests_fuller_round():
-    """With the engine reporting a flush bubble, the collection window
-    stretches past max_wait and a straggler lands in the same round
-    instead of paying a thin round that queues behind the flush."""
-
-    class _FlushingEngine(_StubEngine):
-        def flush_bubble_pending(self):
-            return True
-
-    eng = _FlushingEngine()
-    sched = BatchScheduler(
-        eng, max_wait_ms=150.0, idle_gap_ms=5_000.0,
-        flush_window_ms=2_000.0,
-    )
-    try:
-        t1 = threading.Thread(target=sched.submit, args=(_req(),))
-        t1.start()
-        time.sleep(0.6)  # past the 150ms base window, inside the stretch
-        t2 = threading.Thread(target=sched.submit, args=(_req(),))
-        t2.start()
-        t1.join(timeout=15)
-        t2.join(timeout=15)
-        assert eng.rounds == [2], (
-            f"straggler missed the stretched window: {eng.rounds}"
-        )
-        c = eng.metrics.registry.get(
-            "grapevine_host_flush_window_stretches_total"
-        )
-        assert c.get() >= 1
-    finally:
-        sched.close()
-
-
-def test_flush_window_ignored_without_engine_support():
-    # stub engines without flush_bubble_pending must not crash the
-    # collector — the getattr default reads "no bubble"
-    eng = _StubEngine()
-    sched = BatchScheduler(eng, max_wait_ms=50.0, idle_gap_ms=10.0,
-                           flush_window_ms=1_000.0)
-    try:
-        t = threading.Thread(target=sched.submit, args=(_req(),))
-        t0 = time.perf_counter()
-        t.start()
-        t.join(timeout=10)
-        assert eng.rounds == [1]
-        assert time.perf_counter() - t0 < 3.0
-    finally:
-        sched.close()
-
-
-def test_negative_flush_window_rejected():
-    with pytest.raises(ValueError):
-        BatchScheduler(_StubEngine(), flush_window_ms=-1.0)
-
-
 def test_frontend_role_rejects_adaptive_knobs():
     from grapevine_tpu.server.service import GrapevineServer
 
     with pytest.raises(ValueError):
         GrapevineServer(scheduler=object(), adaptive_batch=True)
-    with pytest.raises(ValueError):
-        GrapevineServer(scheduler=object(), flush_window_ms=5.0)
 
 
-# -- flush-cadence leak detector (obs/leakmon.py note_flush) -----------
+# -- the pop-heavy soak: adaptive windows stay oblivious ---------------
 
 
-def _flush_monitor(flush_every):
-    from grapevine_tpu.obs.leakmon import EngineLeakMonitor
-
-    return EngineLeakMonitor(
-        mb_leaves=8, rec_leaves=8, mb_choices=2, flush_every=flush_every
-    )
-
-
-def _detector(verdict, name):
-    hits = [d for d in verdict["detectors"] if d["name"] == name]
-    assert hits, f"{name} detector missing: {verdict['detectors']}"
-    return hits[0]
-
-
-def test_flush_cadence_detector_passes_on_strict_cadence():
-    mon = _flush_monitor(4)
-    try:
-        for _ in range(6):
-            mon.note_flush(4)
-        d = _detector(mon.verdict(), "flush_cadence")
-        assert d["verdict"] == "PASS" and d["samples"] == 6
-    finally:
-        mon.close()
-
-
-def test_flush_cadence_detector_teeth():
-    # one off-cadence scheduled flush is content-modulated scheduling
-    # (the flush_on_buffer_contents signature) — SUSPECT immediately
-    mon = _flush_monitor(4)
-    try:
-        mon.note_flush(4)
-        mon.note_flush(3)
-        v = mon.verdict()
-        assert v["verdict"] == "SUSPECT"
-        assert _detector(v, "flush_cadence")["verdict"] == "SUSPECT"
-    finally:
-        mon.close()
-
-
-def test_flush_cadence_ignores_operator_flushes():
-    # flush_now()/recovery completion pass scheduled=False — operator
-    # actions are outside the steady-state cadence claim
-    mon = _flush_monitor(4)
-    try:
-        mon.note_flush(2, scheduled=False)
-        d = _detector(mon.verdict(), "flush_cadence")
-        assert d["verdict"] == "PASS" and d["samples"] == 0
-    finally:
-        mon.close()
-
-
-def test_flush_cadence_detector_absent_without_delayed_eviction():
-    mon = _flush_monitor(None)
-    try:
-        names = [d["name"] for d in mon.verdict()["detectors"]]
-        assert "flush_cadence" not in names
-    finally:
-        mon.close()
-
-
-# -- the pop-heavy soak: adaptive + flush windows stay oblivious -------
-
-
-@pytest.mark.slow  # ~11 s soak; tier-1 keeps the flush-stretch round
-# test + the flush_cadence detector/mutant units for the same surface
-def test_pop_heavy_soak_with_flush_windows_passes_leak_audit():
+def test_pop_heavy_soak_with_adaptive_windows_passes_leak_audit():
     """The acceptance soak: the PR-9 pop-heavy drain scenario through a
-    scheduler running BOTH new knobs (adaptive window + flush-aware
-    stretch) over a delayed-eviction engine. Every leak detector —
-    including the new flush_cadence books — must PASS: the stretched
-    windows retime host-side collection only, and the flush cadence
-    stays strictly every E dispatched rounds."""
+    scheduler running the adaptive window over a live engine. Every
+    leak detector must PASS: the adaptive windows retime host-side
+    collection only."""
     from grapevine_tpu.config import GrapevineConfig
     from grapevine_tpu.engine.batcher import GrapevineEngine
     from grapevine_tpu.load import ScenarioRunner, pop_heavy_drain
@@ -379,7 +255,7 @@ def test_pop_heavy_soak_with_flush_windows_passes_leak_audit():
     engine = GrapevineEngine(
         GrapevineConfig(
             bucket_cipher_rounds=0, max_messages=256, max_recipients=32,
-            mailbox_cap=8, batch_size=8, stash_size=96, evict_every=4,
+            mailbox_cap=8, batch_size=8, stash_size=96,
         ),
         seed=9,
     )
@@ -389,11 +265,8 @@ def test_pop_heavy_soak_with_flush_windows_passes_leak_audit():
     mon = EngineLeakMonitor.for_engine(
         engine, LeakMonitorConfig(window_rounds=64)
     )
-    assert mon._flush_every == 4  # for_engine sized it from the config
     engine.attach_leakmon(mon)
-    sched = BatchScheduler(
-        engine, clock=lambda: 1_700_000_000, flush_window_ms=4.0
-    )
+    sched = BatchScheduler(engine, clock=lambda: 1_700_000_000)
     sched.adaptive = AdaptiveBatchPolicy(
         engine.ecfg.batch_size, sched.max_wait, sched.idle_gap,
         workload=engine.workload, slo=slo,
@@ -408,11 +281,6 @@ def test_pop_heavy_soak_with_flush_windows_passes_leak_audit():
         engine.attach_leakmon(None)
     v = mon.verdict()
     assert v["verdict"] == PASS, v
-    fc = _detector(v, "flush_cadence")
-    assert fc["samples"] >= 1, "soak never crossed a flush window"
-    assert fc["verdict"] == "PASS"
-    # the bubble predicate is the cadence counter, nothing else
-    assert engine.flush_bubble_pending() == (engine._rounds_since_flush == 0)
     # the adaptive policy actually decided rounds, from public inputs
     dec = engine.metrics.registry.get("grapevine_host_adaptive_decisions_total")
     assert sum(dec.get(phase=k) for k in DECISION_KINDS) >= 1

@@ -3,7 +3,6 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
-import pytest
 
 from grapevine_tpu.oblivious.bucket_cipher import (
     chacha_blocks,
@@ -145,11 +144,6 @@ def test_engine_trees_encrypted_at_rest():
     assert not row_sets_equal, "rewritten rows kept identical ciphertext"
 
 
-@pytest.mark.slow  # ~75 s randomized cipher+sweep campaign (chunked
-# ChaCha re-encryption of whole trees on a scalar backend); the
-# always-on cipher coverage stays: trees-encrypted-at-rest, nonce
-# rotation, keystream unit equality above. Tier-1 budget: ROADMAP.md
-# tier-1 note (PR 5).
 def test_expiry_sweep_with_cipher_evicts_and_reencrypts():
     from grapevine_tpu.config import GrapevineConfig
     from grapevine_tpu.engine.batcher import GrapevineEngine

@@ -119,8 +119,11 @@ def test_the_2p16_recipient_deployment_resolves_to_what_its_file_says():
     # index row, value row and nonce of one bucket at rest
     assert 4 * (mb.row_words + 2) == want["bucket_bytes"] == 24344
     state = jax.eval_shape(lambda: init_engine(ecfg, 0))
+    # the file's figure predates PR 30, which took four u32 scalars of
+    # delayed-eviction book-keeping (two a tree) out of the state; the
+    # file is the benchmark's, for a `benchmark` PR to bring up to date
     assert sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(state)) \
-        == said["state_bytes"] == 5_127_466_624
+        == said["state_bytes"] - 16 == 5_127_466_608
     assert cfg.mailbox_cap == spec["guarantees"]["mailbox_cap"] == 62
     assert spec["guarantees"]["max_recipients"] == cfg.max_recipients
     # its parent's mailbox tree is one the batch covers whole

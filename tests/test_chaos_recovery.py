@@ -1,4 +1,4 @@
-"""Kill-at-every-phase chaos loop (slow): SIGKILL the engine process at
+"""Kill-at-every-phase chaos loop: SIGKILL the engine process at
 every instrumented fault site in the journal/checkpoint protocol — plus
 randomized wall-clock kills — restart, and assert recovery is
 bit-identical to an uninterrupted run with the leak monitor PASS
@@ -6,16 +6,13 @@ throughout.
 
 Drives tools/chaos_run.py (the standalone ≥50-trial acceptance harness:
 ``python tools/chaos_run.py --trials 50``) at a phase-exhaustive trial
-count that fits the slow bucket. Each trial spawns child processes, so
-this must never run inside tier-1's budget — hence ``-m slow``.
+count. Each trial spawns child processes; durability is a guarantee, so
+the file rides tier-1 (it is the longest file there: ``--dist
+loadfile`` keeps it on one worker).
 """
 
 import os
 import sys
-
-import pytest
-
-pytestmark = pytest.mark.slow
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -51,56 +48,6 @@ def test_randomized_kill_trials_recover_bit_identical():
     assert not failures, "\n".join(failures)
 
 
-def test_delayed_eviction_kill_trials_recover_bit_identical():
-    """ISSUE-15 chaos coverage: at ``--evict-every 4`` every fault site
-    runs again — mid-accumulation kills (``round.*``/``append.*``
-    landing with a part-filled eviction buffer and window ledger) AND
-    the flush-boundary windows (``flush.pre_dispatch`` with the flush
-    frame durable but undispatched, ``flush.post_dispatch`` before any
-    later frame), plus a randomized timer kill. Each trial is
-    multi-incarnation by construction (chaos_run relaunches until the
-    schedule completes, re-killing when the trigger re-arms), and every
-    incarnation's response hashes plus the final state must match the
-    uninterrupted E=4 oracle, with leakmon PASS on the recovered
-    engine — the buffer's stash-grade durability claim, end to end."""
-    chaos = _load_chaos()
-    from grapevine_tpu.testing.faults import ALL_POINTS
-
-    args = chaos.parse_args(
-        ["--events", "16", "--evict-every", "4", "--seed", "52",
-         "--checkpoint-every", "5"]
-    )
-    failures = chaos.run_trials(
-        0, args, modes=list(ALL_POINTS) + ["timer"]
-    )
-    assert not failures, "\n".join(failures)
-
-
-def test_sharded_flush_kill_trials_recover_bit_identical():
-    """ISSUE-18 chaos coverage: ``--shards 2 --evict-every 2`` runs the
-    child on a 2-device virtual CPU mesh with the owner-masked sharded
-    flush, and kills land at the flush boundaries —
-    ``flush.pre_dispatch`` (flush frame durable, owner-masked scatter
-    undispatched: recovery must replay the flush on the mesh) and
-    ``flush.post_dispatch`` (scatter landed on both shards' HBM ranges,
-    no later frame durable) — plus a mid-accumulation append kill and a
-    randomized timer kill. The oracle is the SINGLE-CHIP serial E=2
-    program, so bit-identical recovery proves the crash contract AND
-    sharded<->single-chip equivalence through a kill-restart cycle at
-    once, with leakmon PASS on the recovered engine."""
-    chaos = _load_chaos()
-
-    args = chaos.parse_args(
-        ["--events", "16", "--evict-every", "2", "--shards", "2",
-         "--seed", "64", "--checkpoint-every", "5"]
-    )
-    failures = chaos.run_trials(0, args, modes=[
-        "flush.pre_dispatch", "flush.post_dispatch",
-        "journal.append.post_fsync", "timer",
-    ])
-    assert not failures, "\n".join(failures)
-
-
 def test_pipelined_kill_trials_recover_bit_identical():
     """PR-10 chaos coverage: ``--pipeline-depth 2`` keeps a round
     mid-flight on the device while the next one journals + fsyncs, and
@@ -126,13 +73,12 @@ def test_pipelined_kill_trials_recover_bit_identical():
 
 def test_standby_kill_at_every_fault_point_promotes_bit_identical():
     """ISSUE-19 chaos acceptance: the hot-standby drill at every
-    instrumented fault site (plus a timer kill) at ``--evict-every 2
-    --pipeline-depth 2``. Each trial streams the primary's sealed
+    instrumented fault site (plus a timer kill) at
+    ``--pipeline-depth 2``. Each trial streams the primary's sealed
     frames to an in-parent StandbyReplica, SIGKILLs the primary at the
-    armed site — including ``flush.pre_dispatch``/``post_dispatch``
-    (flush frame durable, scatter undispatched / landed) and the
-    torn-frame window, which lands a half-written frame at the tail
-    the promote-time drain must treat as not-yet-durable — then
+    armed site — including the torn-frame window, which lands a
+    half-written frame at the tail the promote-time drain must treat as
+    not-yet-durable — then
     promotes, finishes the event schedule on the replica, and requires
     the final state to match the serial oracle bit-identically with
     leakmon (including the ship-cadence book) PASS, and the fenced
@@ -141,7 +87,7 @@ def test_standby_kill_at_every_fault_point_promotes_bit_identical():
     from grapevine_tpu.testing.faults import ALL_POINTS
 
     args = chaos.parse_args(
-        ["--standby", "--events", "16", "--evict-every", "2",
+        ["--standby", "--events", "16",
          "--pipeline-depth", "2", "--checkpoint-every", "5",
          "--seed", "43"]
     )
@@ -215,7 +161,7 @@ def test_live_flip_drill_zero_dropped_ops(tmp_path):
     env = {**os.environ, "JAX_PLATFORMS": "cpu"}
     geometry = [
         "--msg-capacity", "64", "--recipient-capacity", "8",
-        "--batch-size", "4", "--evict-every", "2",
+        "--batch-size", "4",
         "--tree-top-cache-levels", "0", "--pipeline-depth", "1",
         "--batch-wait-ms", "30",
     ]

@@ -200,6 +200,24 @@ def test_journal_roundtrip_rounds_and_sweeps(tmp_path, ecfg):
     assert [r.seq for r in j3.replay(after_seq=2)] == [3]
 
 
+def test_journal_refuses_the_retired_window_marker_kind(tmp_path, ecfg):
+    """Frame kind 3 is retired, never reused: it was the one-byte marker
+    a delayed-eviction engine journaled between windows. A journal that
+    holds one (sealed exactly as that engine sealed it) is refused by
+    name, and the frames before it still replay."""
+    import struct
+
+    j = _fresh_journal(tmp_path, ecfg)
+    j.append_round(*_round_batch(ecfg, 1))
+    j._append(struct.pack("<B", 3))
+    j.append_round(*_round_batch(ecfg, 2))
+    j.close()
+    replay = jr.BatchJournal(str(tmp_path), ROOT, ecfg).replay(after_seq=0)
+    assert next(replay).seq == 1
+    with pytest.raises(jr.JournalError, match="frame 2.*delayed-eviction"):
+        next(replay)
+
+
 def test_journal_torn_tail_discarded_everywhere_else_rejected(tmp_path, ecfg):
     j = _fresh_journal(tmp_path, ecfg)
     for t in range(3):

@@ -118,13 +118,12 @@ def test_bench_leaves_mesh_configs_out_on_one_device(
     monkeypatch.setattr(bench, "_device", lambda: dict(_TPU))
     monkeypatch.setattr(bench, "CONFIGS", [
         ("sharded", lambda smoke: ran.append("sharded") or {}),
-        ("sharded_evict_ab", lambda smoke: ran.append("evict") or {}),
         ("fine", lambda smoke: ran.append("fine") or {}),
     ])
     monkeypatch.setattr(sys, "argv", ["bench.py"])
     assert bench.main() == 0
     assert ran == ["fine"]
-    assert "['sharded', 'sharded_evict_ab']" in capsys.readouterr().err
+    assert "['sharded']" in capsys.readouterr().err
 
 
 def test_bench_starts_no_child_process():

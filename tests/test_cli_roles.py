@@ -41,12 +41,6 @@ def _check(argv):
     # satellite) — rejected even at the explicit serial value
     ["--role", "frontend", "--pipeline-depth", "2"],
     ["--role", "frontend", "--pipeline-depth", "1"],
-    # eviction deferral is engine geometry (ISSUE 15 satellite) — a
-    # frontend supplying it would silently defer nothing; rejected even
-    # at the explicit per-round value, and the buffer override with it
-    ["--role", "frontend", "--evict-every", "4"],
-    ["--role", "frontend", "--evict-every", "1"],
-    ["--role", "frontend", "--evict-buffer-slots", "4096"],
     # the bucket-tree shard count is engine geometry (ISSUE 18): a
     # frontend supplying it would silently shard nothing — rejected
     # even at the explicit single-chip value, and on the fleet role
@@ -106,11 +100,10 @@ def _check(argv):
     # pre-promotion standby touch neither (ISSUE 20)
     ["--role", "fleet", "--fleet-members", "h0:1", "--host-workers", "2"],
     ["--role", "standby", "--state-dir", "/x", "--host-workers", "2"],
-    # adaptive/flush-aware collection shapes the device round window —
-    # a frontend supplying it would silently shape nothing (its rounds
-    # are collected in the engine tier)
+    # adaptive collection shapes the device round window — a frontend
+    # supplying it would silently shape nothing (its rounds are
+    # collected in the engine tier)
     ["--role", "frontend", "--engine", "h:1", "--adaptive-batch"],
-    ["--role", "frontend", "--engine", "h:1", "--flush-window", "4"],
     ["--role", "fleet", "--fleet-members", "h0:1", "--adaptive-batch"],
 ])
 def test_misapplied_flags_rejected(argv):
@@ -150,17 +143,10 @@ def test_misapplied_flags_rejected(argv):
     ["--role", "mono", "--pipeline-depth", "2"],
     ["--role", "engine", "--engine-listen", "127.0.0.1:0",
      "--pipeline-depth", "1"],
-    # …and the eviction-deferral cadence + buffer override (ISSUE 15)
-    ["--role", "mono", "--evict-every", "4"],
-    ["--role", "engine", "--engine-listen", "127.0.0.1:0",
-     "--evict-every", "1"],
-    ["--role", "mono", "--evict-every", "4",
-     "--evict-buffer-slots", "4096"],
-    # …and the bucket-tree shard count, alone and composed with the
-    # eviction cadence — the ISSUE-18 pairing (sharded E>1 flush)
+    # …and the bucket-tree shard count (ISSUE 18)
     ["--role", "mono", "--shards", "2"],
     ["--role", "engine", "--engine-listen", "127.0.0.1:0",
-     "--shards", "4", "--evict-every", "4"],
+     "--shards", "4"],
     ["--role", "mono", "--shards", "1"],
     # the fleet role takes its topology/cadence flags + the bind
     # interface (ISSUE 16)
@@ -184,22 +170,20 @@ def test_misapplied_flags_rejected(argv):
      "--standby-listen", "127.0.0.1:0",
      "--promote-from", "/var/lib/grapevine",
      "--engine-listen", "127.0.0.1:0"],
-    ["--role", "standby", "--state-dir", "/x", "--evict-every", "4",
+    ["--role", "standby", "--state-dir", "/x",
      "--pipeline-depth", "1", "--tree-top-cache-levels", "0",
      "--metrics-port", "0"],
-    # the host pipeline + adaptive/flush knobs (ISSUE 20): every
+    # the host pipeline + adaptive knob (ISSUE 20): every
     # session-terminating or round-verifying role takes --host-workers;
     # the frontend also takes --worker-restart (hostpipe crash policy,
     # no durability implied); adaptive windows belong to roles owning
     # a BatchScheduler over an in-process engine (mono/engine/standby)
-    ["--role", "mono", "--host-workers", "2", "--adaptive-batch",
-     "--flush-window", "4"],
+    ["--role", "mono", "--host-workers", "2", "--adaptive-batch"],
     ["--role", "engine", "--engine-listen", "127.0.0.1:0",
      "--host-workers", "2", "--adaptive-batch"],
     ["--role", "frontend", "--engine", "127.0.0.1:4000",
      "--host-workers", "2", "--worker-restart"],
-    ["--role", "standby", "--state-dir", "/x", "--adaptive-batch",
-     "--flush-window", "4"],
+    ["--role", "standby", "--state-dir", "/x", "--adaptive-batch"],
 ])
 def test_valid_role_flag_combinations_accepted(argv):
     _check(argv)  # must not raise

@@ -49,10 +49,8 @@ def _load_tool(name):
 def test_round_ledger_matches_traced_census(name, cfg, b):
     """Analytic row model == traced jaxpr census, bit-exact per operand
     shape class, for every shipped oram_round knob combination (cache-k
-    x posmap x evict_every, cipher on/off)."""
+    x posmap, cipher on/off)."""
     cm.cross_validate_round(cfg, b)
-    if cfg.delayed_eviction:
-        cm.cross_validate_flush(cfg)
 
 
 @pytest.mark.parametrize(
@@ -61,25 +59,10 @@ def test_round_ledger_matches_traced_census(name, cfg, b):
 )
 def test_engine_ledger_matches_traced_census(name, ecfg):
     """Same identity at the composed engine level: the recipient-tree
-    round + the mailbox double-round (E=1 and the E=2 fetch/flush
-    split), the engine flush, and the expiry sweep's chunked scan."""
+    round + the mailbox double-round, and the expiry sweep's chunked
+    scan."""
     cm.cross_validate_engine_round(ecfg)
-    if ecfg.evict_every > 1:
-        cm.cross_validate_engine_flush(ecfg)
     cm.cross_validate_sweep(ecfg)
-
-
-@pytest.mark.parametrize(
-    "name,cfg,shards", cm.audit_sharded_flush_configs(),
-    ids=[n for n, _, _ in cm.audit_sharded_flush_configs()],
-)
-def test_sharded_flush_ledger_matches_traced_census(name, cfg, shards):
-    """ISSUE 18: the owner-masked sharded flush — shard-local analytic
-    rows (full uniform t-row scatters against local plane shapes,
-    replicated inner-posmap planes untouched) == the shard_map-traced
-    census, bit-exact per shape class. Trace-only."""
-    assert shards == 2  # conftest forces 8 virtual CPU devices
-    cm.cross_validate_sharded_flush(cfg, shards)
 
 
 def test_sharded_ledger_per_chip_bytes():
@@ -87,27 +70,21 @@ def test_sharded_ledger_per_chip_bytes():
     steady bytes exactly; at shards>1 only the owner-masked scatter
     half divides, and the aggregate across chips reconstructs the
     single-chip write bytes exactly (power-of-two binary division)."""
-    ecfg = cm.sweep_engine_ecfg(64, evict_every=2)
+    ecfg = cm.sweep_engine_ecfg(64)
     led1 = cm.engine_cost_ledger(ecfg)
     assert led1.per_shard_steady_round_bytes == led1.steady_round_bytes
     led4 = cm.engine_cost_ledger(ecfg, shards=4)
     assert led4.per_shard_steady_round_bytes < led1.steady_round_bytes
     # reconstruct: per-chip = gathers + repl scatters + sharded/4
-    fl1, fl4 = led1.phases["flush"], led4.phases["flush"]
-    assert fl1.sharded_scatter_bytes == fl4.sharded_scatter_bytes > 0
-    assert fl4.per_chip_bytes(4) * 4 == (
-        4 * (fl4.gather_bytes
-             + fl4.scatter_bytes - fl4.sharded_scatter_bytes)
-        + fl4.sharded_scatter_bytes
+    wb1, wb4 = led1.phases["writeback"], led4.phases["writeback"]
+    assert wb1.sharded_scatter_bytes == wb4.sharded_scatter_bytes > 0
+    assert wb4.per_chip_bytes(4) * 4 == (
+        4 * (wb4.gather_bytes
+             + wb4.scatter_bytes - wb4.sharded_scatter_bytes)
+        + wb4.sharded_scatter_bytes
     )
     with pytest.raises(ValueError, match="power of two"):
         cm.engine_cost_ledger(ecfg, shards=3)
-    # the isolated-ORAM helper agrees with its single-chip form
-    cfg = cm.machinery_oram_cfg(1 << 12, 64, e=2)
-    assert cm.oram_sharded_steady_bytes(cfg, 64, 1) == (
-        cm.oram_steady_bytes(cfg, 64))
-    assert cm.oram_sharded_steady_bytes(cfg, 64, 4) < (
-        cm.oram_steady_bytes(cfg, 64))
 
 
 def test_cost_mutants_all_caught():
@@ -151,42 +128,13 @@ def test_tree_cache_cuts_hbm_bytes_not_rows():
     assert b0 > b2 > b4
 
 
-def test_evict_amortized_bytes_tie_below_saturation():
-    """The PR-15 byte structure the verdict rule rides: below window
-    saturation the amortized flush writes every fetched row once a
-    round (min not clamping), so the delayed arms tie among themselves;
-    past saturation larger E strictly drops bytes. The E=1 round is
-    level-dense (PR 26): it moves each level the batch covers once
-    instead of once per path, so it sits under the per-path delayed
-    arms — by exactly the rows of the dense levels, both directions."""
-    cap_n, b = 1 << 16, 256  # unsaturated at these arms
-    c1 = cm.machinery_oram_cfg(cap_n, b, e=1)
-    e1 = cm.oram_steady_bytes(c1, b)
-    e2 = cm.oram_steady_bytes(cm.machinery_oram_cfg(cap_n, b, e=2), b)
-    e4 = cm.oram_steady_bytes(cm.machinery_oram_cfg(cap_n, b, e=4), b)
-    assert e2 == e4
-    ld = c1.dense_levels(b)
-    assert ld == 9 and c1.top_cache_levels == 0
-    row_bytes = 4 * (c1.row_words + 2)
-    assert e4 - e1 == 2 * (b * ld - ((1 << ld) - 1)) * row_bytes
-    cap_n, b = 1 << 16, 1024  # E=8 saturates: min clamps
-    e2 = cm.oram_steady_bytes(cm.machinery_oram_cfg(cap_n, b, e=2), b)
-    e8 = cm.oram_steady_bytes(cm.machinery_oram_cfg(cap_n, b, e=8), b)
-    assert e8 < e2
-
-
 def test_ab_verdicts_shape():
     """Every A/B kind yields a winner + per-arm modeled bytes (or a
     structural basis) — the dict bench.py embeds per config group."""
-    for kind in ("tree_cache", "evict"):
-        for scope in ("machinery", "sweep"):
-            v = cm.ab_verdict(kind, scope=scope, cap_n=1 << 12, batch=64)
-            assert v["winner"] in v["arms"]
-            assert all(d["modeled_bytes"] > 0 for d in v["arms"].values())
-    for s in (1, 2, 4):
-        v = cm.ab_verdict("sharded_evict", cap_n=1 << 12, batch=64,
-                          shards=s)
-        assert v["winner"] in v["arms"] and v["shards"] == s
+    for scope in ("machinery", "sweep"):
+        v = cm.ab_verdict("tree_cache", scope=scope, cap_n=1 << 12,
+                          batch=64)
+        assert v["winner"] in v["arms"]
         assert all(d["modeled_bytes"] > 0 for d in v["arms"].values())
     assert cm.ab_verdict("sort", backend="cpu")["winner"] == "xla"
     assert cm.ab_verdict("pipeline")["winner"] == "depth2"
@@ -198,12 +146,12 @@ def test_ab_verdicts_shape():
 
 
 def test_check_cost_model_grade_banked_trajectory():
-    """The gate's --grade replay covers all five banked A/B kinds and
-    the model's pick measures within the A/B's resolution
-    (``MEASURED_TIE``) of every banked winner. The A/Bs whose arms ran
-    the per-path E=1 round (PRs 8, 13, 15, 18) are marked
+    """The gate's --grade replay covers the three banked A/B kinds
+    whose programs still exist and the model's pick measures within
+    the A/B's resolution (``MEASURED_TIE``) of every banked winner. The
+    A/Bs whose arms ran the per-path round (PR 8) are marked
     ``superseded`` in the trajectory and are not graded: PR 26
-    re-measured all three kinds on the level-dense round (CPU sandbox).
+    re-measured the kind on the level-dense round (CPU sandbox).
     Tolerated disagreements are pinned by name; anything else
     disagreeing is a regression in the model or an unexplained machine
     regime, and should fail loudly here."""
@@ -211,9 +159,9 @@ def test_check_cost_model_grade_banked_trajectory():
     results, problems = tool.grade_trajectory()
     assert problems == []
     assert {r["kind"] for r in results} == {
-        "sort", "tree_cache", "evict", "pipeline", "sharded_evict"
+        "sort", "tree_cache", "pipeline"
     }
-    assert not any(r["config"].startswith(("PR8/", "PR13/", "PR15/", "PR18/"))
+    assert not any(r["config"].startswith("PR8/")
                    for r in results)
     disagreements = {r["config"] for r in results if r["agree"] is False}
     # The byte model prefers k=8 (255 of 3,327 HBM rows a round less);
@@ -221,8 +169,7 @@ def test_check_cost_model_grade_banked_trajectory():
     # k=2 in the banked run and 4.5 % behind in the run before it: the
     # cut does not show on this backend. The other five tree_cache
     # lines are measured ties (the model's pick 0-16 % behind, winners
-    # changing from run to run); every evict and sharded_evict line
-    # names E=1, as the model does.
+    # changing from run to run).
     assert disagreements <= {
         "PR26/machinery/round_cap1048576_b256",
     }, disagreements
@@ -237,15 +184,14 @@ def test_grade_reads_ties_and_skips_superseded_lines(tmp_path):
 
     tool = _load_tool("check_cost_model")
 
-    def arms(e2_ms):  # the model picks e1 here: unsaturated window
-        return {"e1": {"amortized_round_ms": 10.0},
-                "e2": {"amortized_round_ms": e2_ms}}
+    def arms(k2_ms):  # the model picks k0 here: a byte tie
+        return {"k0": {"round_ms": 10.0}, "k2": {"round_ms": k2_ms}}
 
     lines = [
-        {"pr": "PRx", "backend": "cpu", "configs": {"evict_ab": {
+        {"pr": "PRx", "backend": "cpu", "configs": {"tree_cache_ab": {
             "superseded": "PRy", "machinery": {
                 "round_cap65536_b256": arms(1.0)}}}},
-        {"pr": "PRy", "backend": "cpu", "configs": {"evict_ab": {
+        {"pr": "PRy", "backend": "cpu", "configs": {"tree_cache_ab": {
             "machinery": {"round_cap65536_b256": arms(9.0),
                           "round_cap65536_b1024": arms(5.0)}}}},
     ]
@@ -255,12 +201,12 @@ def test_grade_reads_ties_and_skips_superseded_lines(tmp_path):
     got = {r["config"]: (r["modeled"], r["measured"], r["agree"])
            for r in results}
     assert got == {
-        "PRy/machinery/round_cap65536_b256": ("e1", "e2", True),
-        "PRy/machinery/round_cap65536_b1024": ("e1", "e2", False),
+        "PRy/machinery/round_cap65536_b256": ("k0", "k2", True),
+        "PRy/machinery/round_cap65536_b1024": ("k0", "k2", False),
     }
     assert results[0]["lead"] == pytest.approx(10.0 / 9.0 - 1.0)
-    assert not any(" no evict_ab " in p for p in problems)
-    assert any(" no tree_cache_ab " in p for p in problems)
+    assert not any(" no tree_cache_ab " in p for p in problems)
+    assert any(" no sort_ab " in p for p in problems)
 
 
 def test_check_cost_model_smoke_gate():

@@ -79,9 +79,8 @@ def test_device_phase_refuses_a_name_outside_the_list():
 @pytest.mark.parametrize("knobs", [
     {},
     {"vphases_impl": "scan", "sort_impl": "radix"},
-    {"evict_every": 2},
     {"posmap_impl": "recursive"},
-], ids=["default", "scan-radix", "delayed-eviction", "recursive-posmap"])
+], ids=["default", "scan-radix", "recursive-posmap"])
 def test_every_equation_of_the_round_sits_under_a_device_scope(knobs):
     """Trace only, no compile: each leaf equation of the round program
     carries (itself or through the call it sits in) a ``grapevine/``
@@ -97,10 +96,9 @@ def test_every_equation_of_the_round_sits_under_a_device_scope(knobs):
     assert {"round_a_mailbox", "round_b_records", "round_c_mailbox",
             "oram_fetch", "oram_apply", "oram_evict", "request_unpack",
             "freelist_counters", "respond", "path_gather",
-            "cipher_decrypt", "posmap", "dedup"} <= used
-    if not knobs.get("evict_every"):
-        assert {"oram_writeback", "cipher_encrypt", "path_scatter",
-                "oram_evict_sort", "stash_compact"} <= used
+            "cipher_decrypt", "posmap", "dedup",
+            "oram_writeback", "cipher_encrypt", "path_scatter",
+            "oram_evict_sort", "stash_compact"} <= used
 
 
 def test_the_mesh_round_names_its_psum_assembly():

@@ -54,7 +54,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 # -- helpers ------------------------------------------------------------
 
 
-def member_text(rounds, qdepth=0, flushes=0, durable=None, applied=None,
+def member_text(rounds, qdepth=0, durable=None, applied=None,
                 fill_mean=0.5):
     """A minimal member /metrics body with the families the fleet
     consumes."""
@@ -64,8 +64,6 @@ def member_text(rounds, qdepth=0, flushes=0, durable=None, applied=None,
         f"grapevine_rounds_total {rounds}",
         "# TYPE grapevine_queue_depth gauge",
         f"grapevine_queue_depth {qdepth}",
-        "# TYPE grapevine_evict_flushes_total counter",
-        f"grapevine_evict_flushes_total {flushes}",
         "# TYPE grapevine_load_batch_fill histogram",
         f'grapevine_load_batch_fill_bucket{{le="+Inf"}} {rounds}',
         f"grapevine_load_batch_fill_sum {rounds * fill_mean}",
@@ -281,7 +279,7 @@ def test_leakaudit_folds_member_verdicts():
     assert v["members"][1]["verdict"] == "SUSPECT"
     # fleet detectors ride the same body
     assert {d["name"] for d in v["fleet_detectors"]} == {
-        "cadence_ratio", "fill_load_correlation", "flush_phase"}
+        "cadence_ratio", "fill_load_correlation"}
 
 
 def test_scrape_attempts_are_traffic_independent():
@@ -445,7 +443,7 @@ def test_insufficient_evidence_is_pass():
 
 def test_monitor_tolerates_missing_members_and_counter_resets():
     mon = FleetUniformityMonitor(2)
-    base = lambda r: {"rounds_total": float(r), "flushes_total": 0.0,  # noqa: E731
+    base = lambda r: {"rounds_total": float(r),  # noqa: E731
                       "fill_sum": 0.0, "fill_count": 0.0,
                       "queue_depth": 0.0}
     mon.observe_tick([base(1), base(1)])

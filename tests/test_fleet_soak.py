@@ -12,8 +12,6 @@ detector), recipient-partitioned across shards and binned onto the
 shared tick clock. Honest uniform scheduling must PASS under both
 (the false-positive budget at fleet grain); the seeded skewed mutant
 must SUSPECT within the ISSUE's 64-round bound.
-
-Excluded from the tier-1 gate (-m slow).
 """
 
 from __future__ import annotations
@@ -36,8 +34,6 @@ from grapevine_tpu.load.generators import (
 )
 from grapevine_tpu.load.harness import ShardRoundDriver
 from grapevine_tpu.obs.leakmon import FleetUniformityMonitor
-
-pytestmark = pytest.mark.slow
 
 N_SHARDS = 3
 BATCH = 4

@@ -301,35 +301,6 @@ def test_flight_recorder_dump_is_batch_level_only():
         assert forbidden not in text
 
 
-def test_flush_phase_schema_has_teeth():
-    """The delayed-eviction observability surface (ISSUE 15): ``flush``
-    is a declared phase across all three vocabularies — the flight
-    recorder's ``phase_s`` schema, the canonical PHASES tuple, and the
-    phase histogram's declared values — while window-positioned
-    variants (the shape a schedule channel would take) are rejected.
-    The pop-heavy E=4 soak that exercises this surface end-to-end is
-    tests/test_evict.py::test_evict_leakmon_pop_heavy_and_probe."""
-    from grapevine_tpu.engine.metrics import EngineMetrics
-    from grapevine_tpu.obs.phases import PHASES
-
-    assert "flush" in PHASES
-    fr = FlightRecorder(capacity=2)
-    fr.record({"seq": 1, "verdict": "PASS",
-               "phase_s": {"flush": 0.002, "round": 0.01}})
-    with pytest.raises(TelemetryLeakError):
-        fr.record({"seq": 2, "verdict": "PASS",
-                   "phase_s": {"flush_w3": 0.002}})
-    em = EngineMetrics()
-    em.observe_phase("flush", 0.001)
-    with pytest.raises(TelemetryLeakError):
-        em.observe_phase("flush_w3", 0.001)
-    # the buffer canaries are label-free scrape-cadence sums by policy
-    for name in ("grapevine_evict_buffer_occupancy",
-                 "grapevine_evict_buffer_high_water"):
-        m = em.registry.get(name)
-        assert m is not None and not m.label_keys
-
-
 def test_flight_recorder_ring_wraps():
     fr = FlightRecorder(capacity=3)
     for i in range(7):

@@ -18,8 +18,8 @@ Fast always-on coverage (one tiny engine compile, shared module-wide):
   what the honest-scenario FP gate pins);
 - capacity knee math on synthetic steps (no engine).
 
-Scenario breadth (every honest generator soaked, the no-false-positive
-budget under bursty/diurnal/pop-heavy timing) rides ``-m slow``.
+Scenario breadth: every honest generator soaked, the no-false-positive
+budget under bursty/diurnal/pop-heavy timing.
 """
 
 import threading
@@ -337,7 +337,7 @@ def test_probe_campaign_without_leak_stays_pass(loaded_engine):
 
 
 # ---------------------------------------------------------------------
-# scenario breadth: the full honest soak + an end-to-end knee (-m slow)
+# scenario breadth: the full honest soak + an end-to-end knee
 # ---------------------------------------------------------------------
 
 
@@ -350,7 +350,6 @@ HONEST_SOAKS = {
 }
 
 
-@pytest.mark.slow
 @pytest.mark.parametrize("name", sorted(HONEST_SOAKS))
 def test_honest_soak_stays_pass(loaded_engine, name):
     """ISSUE 9 satellite: the false-positive gate for the scale-aware
@@ -366,7 +365,6 @@ def test_honest_soak_stays_pass(loaded_engine, name):
     mon.close()
 
 
-@pytest.mark.slow
 def test_ramp_finds_a_knee_end_to_end(loaded_engine):
     engine, _ = loaded_engine
     mon = _fresh_monitor(engine)

@@ -169,13 +169,6 @@ def test_round_layout_gauges_say_what_the_shapes_resolve_to():
                 f'{rows}') in text
         assert (f'grapevine_round_perpath_bucket_rows{{tree="{tree}"}} '
                 f'{perpath}') in text
-    # the delayed-eviction fetch round stays per-path: only the cache
-    import dataclasses
-
-    d = dataclasses.replace(rec, evict_window=2, evict_fetch_count=b,
-                            evict_buffer_slots=64)
-    assert d.dense_levels(b) == d.top_cache_levels
-    assert d.fetched_bucket_rows(b) == b * (d.path_len - d.top_cache_levels)
 
 
 def test_round_layout_of_a_mailbox_tree_taller_than_the_batch_covers(caplog):

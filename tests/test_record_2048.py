@@ -11,8 +11,6 @@ import os
 import subprocess
 import sys
 
-import pytest
-
 _CHILD = r"""
 import os
 assert os.environ["GRAPEVINE_RECORD_SIZE"] == "2048"
@@ -66,10 +64,6 @@ print("RECORD2048_OK")
 """
 
 
-@pytest.mark.slow  # ~64 s whole-engine subprocess campaign at the 2 KB
-# record size (fresh jit compile of the doubled geometry each run);
-# directed 2 KB layout-constant checks stay always-on above. Tier-1
-# budget: ROADMAP.md tier-1 note (PR 5).
 def test_2048_byte_record_mode():
     env = dict(os.environ)
     env["GRAPEVINE_RECORD_SIZE"] = "2048"

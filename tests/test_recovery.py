@@ -4,7 +4,7 @@ worker-crash handling, and the durability health surface.
 The in-process half of the PR-4 acceptance: checkpoint → restore →
 bit-identical state on tier-1; the SIGKILL half (randomized kill points,
 multi-incarnation recovery, leakmon-PASS-across-recovery) lives in
-tests/test_chaos_recovery.py (-m slow) and tools/chaos_run.py.
+tests/test_chaos_recovery.py and tools/chaos_run.py.
 """
 
 import os
@@ -119,8 +119,6 @@ def test_checkpoint_restore_state_bit_equality(durable_run, tmp_path):
     engine.close()
 
 
-@pytest.mark.slow  # a full replay = one more ~8 s jit compile; the
-# property is also implied by the core test + the chaos suite
 def test_recovery_with_wrong_seed_still_bit_identical(durable_run, tmp_path):
     """The recovered state comes from disk, not from the init seed —
     restoring under a different seed must not matter."""
@@ -134,8 +132,6 @@ def test_recovery_with_wrong_seed_still_bit_identical(durable_run, tmp_path):
     engine.close()
 
 
-@pytest.mark.slow  # another full-replay jit compile; the torn-tail
-# contract itself is tier-1-covered (no-compile) in test_checkpoint.py
 def test_torn_journal_tail_recovers_to_previous_record(durable_run, tmp_path):
     """Truncating mid-way into the journal's final frame loses exactly
     that record (it never dispatched durably) — recovery succeeds at

@@ -51,7 +51,6 @@ def _cfg(**kw):
 
 
 SMALL = _cfg()
-SMALL_E2 = _cfg(evict_every=2)
 
 
 def _plant_key(d: str) -> None:
@@ -260,16 +259,14 @@ def test_fenced_journal_refuses_stale_appends_and_reopen(tmp_path, ecfg):
 def test_replication_fingerprint_normalizes_placement_knobs_only():
     """Frames replay across tree-top-cache depths and host-side round
     scheduling (the rolling-upgrade surface), but never across frame
-    geometry or eviction cadence."""
-    base = SMALL_E2
+    geometry."""
+    base = SMALL
     # k is placement-only: normalized out
     assert replication_fingerprint(base) == replication_fingerprint(
         dataclasses.replace(base, tree_top_cache_levels=4))
     # pipeline depth is host-side scheduling: outside the frame format
     assert replication_fingerprint(base) == replication_fingerprint(
         dataclasses.replace(base, pipeline_depth=2))
-    # eviction cadence changes the frame stream itself: fences
-    assert replication_fingerprint(base) != replication_fingerprint(SMALL)
     # geometry changes the frame sizes: fences
     assert replication_fingerprint(base) != replication_fingerprint(
         dataclasses.replace(base, max_messages=128))
@@ -309,12 +306,12 @@ def test_ship_promote_fence_cycle_bit_identical(tmp_path):
     _plant_key(primary_dir)
     _plant_key(standby_dir)
 
-    primary = GrapevineEngine(SMALL_E2, seed=0,
+    primary = GrapevineEngine(SMALL, seed=0,
                               durability=_dcfg(primary_dir))
     monitor = EngineLeakMonitor.for_engine(
         primary, LeakMonitorConfig(window_rounds=64))
     primary.attach_leakmon(monitor)
-    replica = StandbyReplica(SMALL_E2, seed=0,
+    replica = StandbyReplica(SMALL, seed=0,
                              durability=_dcfg(standby_dir))
     port = replica.listen()
     shipper = JournalShipper(primary, ("127.0.0.1", port))
@@ -337,8 +334,8 @@ def test_ship_promote_fence_cycle_bit_identical(tmp_path):
         ship = [d for d in v["detectors"] if d["name"] == "ship_cadence"]
         assert ship and ship[0]["verdict"] == "PASS"
         assert v["replication"]["cadence_ok"]
-        # 4 rounds + 2 flush frames (E=2) + 1 sweep
-        assert v["replication"]["frames_shipped"] == 7
+        # 4 rounds + 1 sweep
+        assert v["replication"]["frames_shipped"] == 5
 
         # link cut; the primary's final rounds reach disk only
         shipper.close()
@@ -353,7 +350,7 @@ def test_ship_promote_fence_cycle_bit_identical(tmp_path):
         assert res["epoch"] == 1
         assert res["rpo_durable_frames"] == 0
         assert res["applied_seq"] == dead_seq
-        assert res["drained_frames"] == dead_seq - 7
+        assert res["drained_frames"] == dead_seq - 5
         assert state_to_bytes(replica.engine.ecfg,
                               replica.engine.state) == dead_bytes
         healthy, detail = replica.healthz()
@@ -371,12 +368,12 @@ def test_ship_promote_fence_cycle_bit_identical(tmp_path):
         # door 2: the revived stale primary dies in open_for_append,
         # before truncating the tail the replica drained
         with pytest.raises(jr.JournalError, match="fenced"):
-            GrapevineEngine(SMALL_E2, seed=0, durability=_dcfg(primary_dir))
+            GrapevineEngine(SMALL, seed=0, durability=_dcfg(primary_dir))
 
         # door 3: a double-promote has exactly one winner
         loser_dir = str(tmp_path / "loser")
         _plant_key(loser_dir)
-        loser = StandbyReplica(SMALL_E2, seed=0,
+        loser = StandbyReplica(SMALL, seed=0,
                                durability=_dcfg(loser_dir))
         try:
             with pytest.raises(jr.JournalError, match="already fenced"):
@@ -400,8 +397,8 @@ def test_cross_knob_standby_promotes_under_k4_depth2_primary(tmp_path):
     k=4/depth-2 primary from genesis (same frame fingerprint — k and
     pipeline depth are placement/scheduling, not frame format) and
     promotes to the logically identical store."""
-    pcfg = _cfg(tree_top_cache_levels=4, pipeline_depth=2, evict_every=2)
-    scfg = SMALL_E2
+    pcfg = _cfg(tree_top_cache_levels=4, pipeline_depth=2)
+    scfg = SMALL
     assert replication_fingerprint(pcfg) == replication_fingerprint(scfg)
 
     primary_dir = str(tmp_path / "primary")
@@ -441,14 +438,14 @@ def test_cross_knob_standby_promotes_under_k4_depth2_primary(tmp_path):
 
 
 def test_cross_geometry_ship_refused_with_fingerprint_error(tmp_path):
-    """evict_every changes the frame stream itself: the handshake
-    refuses, permanently (reconnects can never fix it)."""
+    """Capacity changes the frames' geometry: the handshake refuses,
+    permanently (reconnects can never fix it)."""
     primary_dir = str(tmp_path / "primary")
     standby_dir = str(tmp_path / "standby")
     _plant_key(primary_dir)
     _plant_key(standby_dir)
     primary = GrapevineEngine(SMALL, seed=0, durability=_dcfg(primary_dir))
-    replica = StandbyReplica(SMALL_E2, seed=0,
+    replica = StandbyReplica(_cfg(max_messages=128), seed=0,
                              durability=_dcfg(standby_dir))
     port = replica.listen()
     shipper = JournalShipper(primary, ("127.0.0.1", port))
@@ -467,17 +464,16 @@ def test_cross_geometry_ship_refused_with_fingerprint_error(tmp_path):
 # -- chaos --standby smoke (full sweep is -m slow) ----------------------
 
 
-def test_chaos_standby_smoke_flush_boundary_kill():
-    """One --standby trial at the nastiest site (flush.pre_dispatch at
-    E=2: flush frame durable, flush never dispatched): SIGKILL the
+def test_chaos_standby_smoke_round_boundary_kill():
+    """One --standby trial at the nastiest site (round.pre_dispatch:
+    round frame durable, round never dispatched): SIGKILL the
     primary, promote the parent's replica, finish the event schedule,
     and match the serial oracle bit-identically with leakmon PASS."""
     sys.path.insert(0, os.path.join(REPO, "tools"))
     import chaos_run as chaos
 
     args = chaos.parse_args(
-        ["--standby", "--events", "10", "--evict-every", "2",
-         "--seed", "11"]
+        ["--standby", "--events", "10", "--seed", "11"]
     )
-    failures = chaos.run_trials(0, args, modes=["flush.pre_dispatch"])
+    failures = chaos.run_trials(0, args, modes=["round.pre_dispatch"])
     assert not failures, "\n".join(failures)

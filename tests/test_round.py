@@ -357,11 +357,6 @@ def test_round_engine_matches_batch_oracle():
     _run_engine_vs_oracle(SMALL, n_steps=30)
 
 
-@pytest.mark.slow  # heaviest randomized campaign of the suite (~77 s:
-# ChaCha keystream on a scalar backend dominates); the plaintext
-# campaigns above/below stay always-on and the cipher layer keeps its
-# directed always-on coverage in test_bucket_cipher.py. Tier-1 budget:
-# ROADMAP.md tier-1 note (PR 5).
 def test_round_engine_matches_batch_oracle_with_bucket_cipher():
     """Same harness with the at-rest bucket cipher enabled (the shipped
     default): randomized CRUD through encrypted trees must stay
@@ -420,7 +415,6 @@ def test_round_engine_matches_batch_oracle_on_a_tall_mailbox_tree():
     _run_tall_mailbox_campaign(TALL_MAILBOX)
 
 
-@pytest.mark.slow  # the cipher-on twin, as its sibling above is
 def test_round_engine_matches_batch_oracle_on_a_tall_mailbox_tree_with_cipher():
     import dataclasses
 

@@ -417,10 +417,6 @@ def test_profiler_gate_captures_with_the_python_tracer_off(monkeypatch,
     assert kw["profiler_options"].python_tracer_level == 0
 
 
-@pytest.mark.slow  # ~67 s: the first capture pays jax.profiler's lazy
-# init, and the test is wall-clock-flaky under concurrent load (socket
-# timeout mid-init). Moved in the PR-9 tier-1 re-budget; the capture
-# path stays covered here in slow.
 def test_profile_endpoint_gated_capture(tier):
     """/profile?ms=N runs a live jax.profiler capture (enabled in this
     fixture) and refuses a concurrent one with 409."""

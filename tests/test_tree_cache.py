@@ -22,11 +22,12 @@ The contract of ``GrapevineConfig.tree_top_cache_levels = k``
    differently-cached engine (geometry fingerprint covers k);
 5. the leak monitor stays PASS on a live soak with caching enabled.
 
-Always-on cost: ONE cached + ONE uncached engine compile (plaintext
-BASE geometry, reused across every fast assertion) + small
-directed-ORAM compiles + trace-only audits — the ≤2-engine-compile
-budget (ROADMAP tier-1 note). Cipher pairs, recursive-posmap pairs,
-regime breadth, and chaos ride ``-m slow``.
+The first half shares ONE cached + ONE uncached engine compile
+(plaintext BASE geometry) + small directed-ORAM compiles + trace-only
+audits; the second half builds fresh pairs: the cipher pair (every
+configuration runs ChaCha8 at rest), regime breadth and chaos.
+Recursive-posmap and scan/radix pairs ride ``-m slow`` until those
+forks are decided.
 """
 
 from __future__ import annotations
@@ -172,10 +173,6 @@ def test_tree_cache_campaign_with_sweep_soak_and_leakmon():
     mon.close()
 
 
-@pytest.mark.slow  # ~35 s of oram-level cached/uncached equality
-# breadth. Moved in the PR-9 tier-1 re-budget: the engine-level
-# campaign above (sweep+soak+leakmon, logical-state equality) and the
-# access-schedule CI audit keep the cache contract always-on.
 def test_tree_cache_oram_level_directed():
     """Directed small-ORAM checks with NO engine compile: single
     ``oram_access`` CRUD against cached and uncached trees stays
@@ -413,10 +410,9 @@ def test_tree_cache_config_validation():
     assert tree_cache_private_bytes(c) == 7 * 4 * (4 + 4 * 8)
 
 
-# -- slow: breadth, cipher, recursive posmap, geometry, chaos ----------
+# -- breadth, cipher, geometry, chaos (fresh engine pairs) -------------
 
 
-@pytest.mark.slow
 def test_randomized_tree_cache_campaigns_full():
     """Regime breadth: steady-state, saturation fallback, single-op
     batches — fresh pairs + oracle per campaign, k varied."""
@@ -426,7 +422,6 @@ def test_randomized_tree_cache_campaigns_full():
                          k=(1, 2, 4)[i % 3])
 
 
-@pytest.mark.slow
 def test_tree_cache_campaign_cipher_on():
     """The at-rest cipher pair: cached levels skip cipher entirely while
     bottom levels re-key per round — the mixed regime must preserve the
@@ -453,7 +448,6 @@ def test_tree_cache_campaign_scan_radix():
     _run_tc_campaign(cfg, seed=5500, n_batches=3)
 
 
-@pytest.mark.slow
 def test_tree_cache_single_op_batch_geometry():
     """batch_size=1 end to end: the B=1 cached round (degenerate owner
     map, single path) stays bit-identical and oracle-true."""
@@ -463,7 +457,6 @@ def test_tree_cache_single_op_batch_geometry():
                          k=3)
 
 
-@pytest.mark.slow
 def test_tree_cache_saturation_fallback_bitequal():
     """Bus saturation: rounds resolve through _admission_slow with the
     cache in the loop and must stay bit-identical, including
@@ -493,7 +486,6 @@ def test_tree_cache_recursive_audit():
     check_tree_cache_schedule(b=8, height=5, recursive=True)
 
 
-@pytest.mark.slow
 def test_chaos_recovery_with_tree_cache():
     """SIGKILL trials with the tree-top cache on: sealed checkpoints
     cover the cache planes (they are ordinary state leaves), so

@@ -3,7 +3,6 @@ creates-per-lifetime were conscious-but-narrow u32 bounds; both are now
 two u32 lanes end to end — device layouts, responses, expiry)."""
 
 import numpy as np
-import pytest
 
 from grapevine_tpu.config import GrapevineConfig
 from grapevine_tpu.engine.batcher import GrapevineEngine
@@ -67,15 +66,11 @@ def _post_2106_round_trip(commits):
 def test_post_2106_timestamps_round_trip():
     """CREATE at a post-2106 clock returns the full u64 timestamp, READ
     echoes it, and the wire codec carries it (timestamp is u64 on the
-    wire, reference README.md:135). Always-on on the production phase
-    engine; the op-major arm rides ``-m slow`` below (PR-10 tier-1
-    re-budget: the op engine's compile was half of this test's ~25 s,
-    and the u32-boundary semantics both engines share stay covered by
-    the sibling always-on tests)."""
+    wire, reference README.md:135). On the production phase engine;
+    the op-major arm is the test below."""
     _post_2106_round_trip(("phase",))
 
 
-@pytest.mark.slow  # the op-major engine compile (~12 s) — breadth arm
 def test_post_2106_timestamps_round_trip_op_commit():
     _post_2106_round_trip(("op",))
 

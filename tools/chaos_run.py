@@ -61,7 +61,6 @@ MAX_RESTARTS = 60
 def _config(posmap_impl: str | None = None,
             tree_top_cache_levels: int | None = None,
             pipeline_depth: int | None = None,
-            evict_every: int | None = None,
             shards: int | None = None):
     from grapevine_tpu.config import GrapevineConfig
 
@@ -71,7 +70,6 @@ def _config(posmap_impl: str | None = None,
         posmap_impl=posmap_impl,
         tree_top_cache_levels=tree_top_cache_levels,
         pipeline_depth=pipeline_depth,
-        evict_every=evict_every,
         shards=shards or 1,
     )
 
@@ -120,36 +118,6 @@ def build_schedule(seed: int, n_events: int):
 
 def _resp_hash(resps) -> str:
     return hashlib.sha256(b"".join(r.pack() for r in resps)).hexdigest()
-
-
-def _events_done(events, durable_seq: int, evict_every: int) -> int:
-    """Events covered by the durable journal prefix.
-
-    At evict_every=1 journal seq IS the event count (the original
-    identity). At E>1 every E-th round appends a KIND_FLUSH frame of
-    its own, so the mapping is seq(n) = n + floor(rounds(n)/E) —
-    walked forward here. Recovery completes a pending flush before the
-    child reads ``durability.seq`` (engine/batcher.py), so the durable
-    seq always lands on an event boundary; anything else is journal
-    corruption and must raise, never silently re-run or skip events."""
-    if evict_every <= 1:
-        return durable_seq
-    seq = rounds = 0
-    if seq == durable_seq:
-        return 0
-    for n, ev in enumerate(events):
-        seq += 1  # the event's own frame
-        if ev[0] == "round":
-            rounds += 1
-            if rounds % evict_every == 0:
-                seq += 1  # its flush frame
-        if seq == durable_seq:
-            return n + 1
-    raise RuntimeError(
-        f"durable journal seq {durable_seq} does not land on an event "
-        f"boundary of the {len(events)}-event schedule at "
-        f"evict_every={evict_every}"
-    )
 
 
 def _run_events(engine, events, start: int, progress=None):
@@ -208,7 +176,7 @@ def run_child(args) -> int:
     )
     engine = GrapevineEngine(
         _config(args.posmap_impl, args.tree_top_cache_levels,
-                args.pipeline_depth, args.evict_every, args.shards),
+                args.pipeline_depth, args.shards),
         seed=ENGINE_SEED, durability=dcfg,
     )
     shipper = None
@@ -238,9 +206,8 @@ def run_child(args) -> int:
     )
     engine.attach_slo(SloTracker(registry=engine.metrics.registry))
     events = build_schedule(args.schedule_seed, args.events)
-    # events[:start] are already durable (flush frames excluded from
-    # the count — they are cadence bookkeeping, not schedule events)
-    start = _events_done(events, engine.durability.seq, engine.evict_every)
+    # events[:start] are already durable: one journal frame per event
+    start = engine.durability.seq
     with open(args.progress, "a") as pf:
         _run_events(engine, events, start, pf)
         monitor.close()  # drain the detector queue before the verdict
@@ -258,8 +225,7 @@ def run_child(args) -> int:
 
 
 def oracle(schedule_seed: int, n_events: int, posmap_impl: str | None = None,
-           tree_top_cache_levels: int | None = None,
-           evict_every: int | None = None):
+           tree_top_cache_levels: int | None = None):
     """Uninterrupted in-process run: per-seq hashes + final state hash.
 
     Always serial (pipeline_depth=1) and single-chip (shards=1): the
@@ -272,8 +238,7 @@ def oracle(schedule_seed: int, n_events: int, posmap_impl: str | None = None,
     from grapevine_tpu.engine.checkpoint import state_to_bytes
 
     engine = GrapevineEngine(
-        _config(posmap_impl, tree_top_cache_levels, pipeline_depth=1,
-                evict_every=evict_every),
+        _config(posmap_impl, tree_top_cache_levels, pipeline_depth=1),
         seed=ENGINE_SEED,
     )
     events = build_schedule(schedule_seed, n_events)
@@ -316,17 +281,6 @@ def run_trial(trial: int, mode: str, rng: random.Random, args,
               oracle_hashes, oracle_final) -> list[str]:
     """One kill-recover-verify trial; returns a list of failure strings."""
     errors: list[str] = []
-    if mode.startswith("flush.") and (args.evict_every or 1) <= 1:
-        # the flush crash sites only exist under delayed eviction: at
-        # E=1 the engine never reaches them and the "trial" would be a
-        # clean run masquerading as kill coverage — say so instead
-        print(
-            f"trial {trial:3d} [{mode:>26s}]: SKIP "
-            "(evict_every=1 — no flush sites; rerun with "
-            "--evict-every > 1 for kill-at-flush coverage)",
-            flush=True,
-        )
-        return errors
     with tempfile.TemporaryDirectory(prefix=f"chaos{trial}-") as state_dir:
         progress = os.path.join(state_dir, "progress.log")
         child_cmd = [
@@ -343,8 +297,6 @@ def run_trial(trial: int, mode: str, rng: random.Random, args,
                           str(args.tree_top_cache_levels)]
         if args.pipeline_depth is not None:
             child_cmd += ["--pipeline-depth", str(args.pipeline_depth)]
-        if args.evict_every is not None:
-            child_cmd += ["--evict-every", str(args.evict_every)]
         if args.shards is not None:
             child_cmd += ["--shards", str(args.shards)]
         base_env = dict(os.environ, JAX_PLATFORMS="cpu")
@@ -369,13 +321,10 @@ def run_trial(trial: int, mode: str, rng: random.Random, args,
                     timer_kill = rng.uniform(1.0, args.timer_max_s)
                 else:
                     # checkpoint sites fire once per --checkpoint-every
-                    # records, flush sites once per evict_every rounds,
-                    # append sites once per record — scale the trigger
-                    # count so the fault actually lands mid-run
+                    # records, append sites once per record — scale the
+                    # trigger count so the fault actually lands mid-run
                     if mode.startswith("checkpoint."):
                         cap = max(2, args.events // args.checkpoint_every)
-                    elif mode.startswith("flush."):
-                        cap = max(2, args.events // max(1, args.evict_every or 1))
                     else:
                         cap = max(2, args.events // 2)
                     env["GRAPEVINE_FAULTS"] = f"{mode}={rng.randrange(1, cap)}"
@@ -442,8 +391,8 @@ def run_standby_trial(trial: int, mode: str, rng: random.Random, args,
     programs are already warm from the oracle run, which is the hot
     part of "hot standby"). The child is the PRIMARY: it runs the
     schedule with ``--replicate-to`` pointed at the replica and is
-    SIGKILLed ONCE at the armed fault site — including mid-flush and
-    mid-fsync — with no restart. The parent then promotes the replica
+    SIGKILLed ONCE at the armed fault site — including mid-fsync —
+    with no restart. The parent then promotes the replica
     (fencing the dead primary's state dir, draining its durable tail
     off disk), drives the REMAINING schedule on the promoted engine,
     and holds the whole run to the uninterrupted serial oracle:
@@ -451,14 +400,6 @@ def run_standby_trial(trial: int, mode: str, rng: random.Random, args,
     RPO 0 for durable frames and RTO = the measured promote() wall
     time, printed per trial."""
     errors: list[str] = []
-    if mode.startswith("flush.") and (args.evict_every or 1) <= 1:
-        print(
-            f"trial {trial:3d} [{mode:>26s}]: SKIP "
-            "(evict_every=1 — no flush sites; rerun with "
-            "--evict-every > 1 for kill-at-flush coverage)",
-            flush=True,
-        )
-        return errors
     from grapevine_tpu.config import DurabilityConfig
     from grapevine_tpu.engine.checkpoint import state_to_bytes
     from grapevine_tpu.engine.journal import BatchJournal, JournalError
@@ -485,8 +426,7 @@ def run_standby_trial(trial: int, mode: str, rng: random.Random, args,
             os.chmod(kp, 0o600)
         replica = StandbyReplica(
             _config(args.posmap_impl, args.tree_top_cache_levels,
-                    pipeline_depth=1, evict_every=args.evict_every,
-                    shards=1),
+                    pipeline_depth=1, shards=1),
             seed=ENGINE_SEED,
             durability=DurabilityConfig(
                 state_dir=standby_dir,
@@ -511,8 +451,6 @@ def run_standby_trial(trial: int, mode: str, rng: random.Random, args,
                               str(args.tree_top_cache_levels)]
             if args.pipeline_depth is not None:
                 child_cmd += ["--pipeline-depth", str(args.pipeline_depth)]
-            if args.evict_every is not None:
-                child_cmd += ["--evict-every", str(args.evict_every)]
             if args.shards is not None:
                 child_cmd += ["--shards", str(args.shards)]
             env = dict(os.environ, JAX_PLATFORMS="cpu")
@@ -530,8 +468,6 @@ def run_standby_trial(trial: int, mode: str, rng: random.Random, args,
             else:
                 if mode.startswith("checkpoint."):
                     cap = max(2, args.events // args.checkpoint_every)
-                elif mode.startswith("flush."):
-                    cap = max(2, args.events // max(1, args.evict_every or 1))
                 else:
                     cap = max(2, args.events // 2)
                 env["GRAPEVINE_FAULTS"] = f"{mode}={rng.randrange(1, cap)}"
@@ -555,16 +491,14 @@ def run_standby_trial(trial: int, mode: str, rng: random.Random, args,
                 return errors
             killed = rc == -signal.SIGKILL
             # fenced takeover: plant the epoch fence in the dead
-            # primary's dir, drain its durable tail, complete any
-            # pending flush — the measured RTO
+            # primary's dir, drain its durable tail — the measured RTO
             info = replica.promote(primary_state_dir=primary_dir)
             eng = replica.engine
             monitor = EngineLeakMonitor.for_engine(
                 eng, LeakMonitorConfig(window_rounds=64)
             )
             eng.attach_leakmon(monitor)
-            start = _events_done(events, eng.durability.seq,
-                                 eng.evict_every)
+            start = eng.durability.seq
             with open(progress, "a") as pf:
                 _run_events(eng, events, start, pf)
                 monitor.close()
@@ -635,7 +569,7 @@ def run_trials(n_trials: int, args=None, modes=None) -> list[str]:
     t0 = time.monotonic()
     oracle_hashes, oracle_final = oracle(
         args.schedule_seed, args.events, args.posmap_impl,
-        args.tree_top_cache_levels, args.evict_every,
+        args.tree_top_cache_levels,
     )
     print(f"oracle: {len(oracle_hashes)} events in "
           f"{time.monotonic() - t0:.1f}s", flush=True)
@@ -686,28 +620,15 @@ def parse_args(argv):
     p.add_argument("--tree-top-cache-levels", type=int, default=None,
                    help="tree-top cache depth under test "
                    "(oram/path_oram.py); default = the engine auto")
-    p.add_argument("--evict-every", type=int, default=None,
-                   help="delayed-eviction cadence E under test (engine/"
-                   "batcher.py; oram/round.py:oram_flush): fetch rounds "
-                   "accumulate in the private buffer and the flush "
-                   "journals (KIND_FLUSH) + dispatches with the E-th "
-                   "round — the flush.pre/post_dispatch crash sites are "
-                   "the kill-at-flush windows. The oracle runs the SAME "
-                   "E (serial), so trials prove crash recovery, not "
-                   "cross-E equivalence (that is tests/test_evict.py's "
-                   "logical-content contract). Default = engine auto (1)")
     p.add_argument("--shards", type=int, default=None,
                    help="bucket-axis shard count under test (parallel/"
                    "mesh.py via engine/batcher.py): the child runs the "
-                   "sharded step/flush programs on a virtual CPU mesh "
+                   "sharded step on a virtual CPU mesh "
                    "(the parent exports the device-count XLA flag), "
                    "while the ORACLE stays single-chip — so every "
                    "trial proves crash recovery AND sharded<->single-"
                    "chip bit-equivalence in one gate (the pipeline-"
-                   "depth discipline). Combine with --evict-every > 1 "
-                   "to land the flush.pre/post_dispatch kills on the "
-                   "owner-masked sharded flush. Default = engine auto "
-                   "(1)")
+                   "depth discipline). Default = engine auto (1)")
     p.add_argument("--pipeline-depth", type=int, default=None,
                    choices=[1, 2],
                    help="round-pipeline depth under test (engine/"

@@ -8,18 +8,17 @@ observatory (grapevine_tpu/analysis/costmodel.py, obs/costmon.py):
    analytic row model — a pure function of geometry × knobs — must
    agree **bit-exactly per operand shape class** with the traced
    census accounting (the shared ``jaxpr_walk`` reduction) across the
-   shipped knob matrix: cache-k × posmap × evict_every for
-   ``oram_round``/``oram_flush``, the composed engine round at E=1 and
-   E=2 (the fetch/flush split), the engine flush, and the expiry
-   sweep's chunked scan. Trace-only — zero engine compiles.
+   shipped knob matrix: cache-k × posmap for ``oram_round``, the
+   composed engine round, and the expiry sweep's chunked scan.
+   Trace-only — zero engine compiles.
 2. **Mutant teeth**: every seeded undercount mutant (a dropped plane, a
    halved fetch, a forgotten second nonce gather, a missed mailbox
    double-round, …) must trip ``CostModelMismatch``, reported through
    the shared ``mutants.control_failures`` runner — a checker that
    cannot catch a planted defect is vacuous.
 3. **Trajectory grading** (``--grade``): replay every banked
-   BENCH_trajectory.jsonl A/B line (sort_ab / tree_cache_ab /
-   evict_ab / sharded_evict_ab / pipeline_ab, machinery and sweep
+   BENCH_trajectory.jsonl A/B line of a program that still exists
+   (sort_ab / tree_cache_ab / pipeline_ab, machinery and sweep
    scopes) and report the
    modeled winner next to the measured winner (a pick within
    ``MEASURED_TIE`` of the measured best agrees; an A/B marked
@@ -67,20 +66,9 @@ def run_identity_matrix(verbose: bool = False) -> list:
 
     for name, cfg, b in cm.audit_oram_configs():
         _run(f"round/{name}", cm.cross_validate_round, cfg, b)
-        if cfg.delayed_eviction:
-            _run(f"flush/{name}", cm.cross_validate_flush, cfg)
     for name, ecfg in cm.audit_engine_configs():
         _run(f"{name}/round", cm.cross_validate_engine_round, ecfg)
-        if ecfg.evict_every > 1:
-            _run(f"{name}/flush", cm.cross_validate_engine_flush, ecfg)
         _run(f"{name}/sweep", cm.cross_validate_sweep, ecfg)
-    # the owner-masked sharded flush (ISSUE 18): shard-local analytic
-    # rows vs the shard_map-traced census, on whatever mesh slice the
-    # process actually has (main() forces >=2 virtual CPU devices when
-    # it owns the jax init)
-    for name, cfg, shards in cm.audit_sharded_flush_configs():
-        _run(f"{name}/s{shards}", cm.cross_validate_sharded_flush,
-             cfg, shards)
     return problems
 
 
@@ -149,15 +137,6 @@ def _parse_cap_b(group_name: str):
     return cap, b
 
 
-def _parse_cap_b_s(group_name: str):
-    """'round_cap4096_b64_s2' -> (4096, 64, 2) — the sharded_evict_ab
-    group key (geometry: capacity x batch x mesh width)."""
-    cap = int(group_name.split("cap")[1].split("_")[0])
-    rest = group_name.split("_b")[1]
-    b, s = rest.split("_s")
-    return cap, int(b), int(s)
-
-
 def grade_trajectory(path: str = TRAJECTORY) -> tuple:
     """Grade the model against every banked A/B line.
 
@@ -214,43 +193,6 @@ def grade_trajectory(path: str = TRAJECTORY) -> tuple:
                              v["winner"], measured, v["basis"],
                              numeric, "round_ms")
 
-        ab = _live(configs, "evict_ab")
-        if ab:
-            kinds_seen.add("evict")
-            for gname, arms in ab.get("machinery", {}).items():
-                cap, b = _parse_cap_b(gname)
-                es = sorted(int(a[1:]) for a in arms if a[1:].isdigit())
-                v = cm.ab_verdict("evict", scope="machinery",
-                                  cap_n=cap, batch=b, arms=es)
-                measured = _measured_winner(arms, "amortized_round_ms")
-                _grade_entry(results, "evict",
-                             f"{pr}/machinery/{gname}",
-                             v["winner"], measured, v["basis"],
-                             arms, "amortized_round_ms")
-            for bstr, arms in ab.get("sweep", {}).items():
-                es = sorted(int(a[1:]) for a in arms if a[1:].isdigit())
-                v = cm.ab_verdict("evict", scope="sweep",
-                                  batch=int(bstr), arms=es)
-                measured = _measured_winner(arms, "amortized_round_ms")
-                _grade_entry(results, "evict",
-                             f"{pr}/sweep/b{bstr}",
-                             v["winner"], measured, v["basis"],
-                             arms, "amortized_round_ms")
-
-        ab = _live(configs, "sharded_evict_ab")
-        if ab:
-            kinds_seen.add("sharded_evict")
-            for gname, arms in ab.get("machinery", {}).items():
-                cap, b, s = _parse_cap_b_s(gname)
-                es = sorted(int(a[1:]) for a in arms if a[1:].isdigit())
-                v = cm.ab_verdict("sharded_evict", scope="machinery",
-                                  cap_n=cap, batch=b, arms=es, shards=s)
-                measured = _measured_winner(arms, "amortized_round_ms")
-                _grade_entry(results, "sharded_evict",
-                             f"{pr}/machinery/{gname}",
-                             v["winner"], measured, v["basis"],
-                             arms, "amortized_round_ms")
-
         if "pipeline_ab" in configs:
             kinds_seen.add("pipeline")
             ab = configs["pipeline_ab"]
@@ -261,8 +203,7 @@ def grade_trajectory(path: str = TRAJECTORY) -> tuple:
             _grade_entry(results, "pipeline", f"{pr}/pipeline_ab",
                          v["winner"], measured, v["basis"])
 
-    for kind in ("sort", "tree_cache", "evict", "pipeline",
-                 "sharded_evict"):
+    for kind in ("sort", "tree_cache", "pipeline"):
         if kind not in kinds_seen:
             problems.append(
                 f"banked trajectory has no {kind}_ab line to grade — "
@@ -304,16 +245,6 @@ def main(argv=None) -> int:
     do_grade = args.grade or not args.smoke
 
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    # the sharded-flush audit wants a real (if virtual) mesh slice; the
-    # flag only takes effect if jax has not initialized its backend yet
-    # (the check_tree_cache_oblivious.py recipe) — when it has, the
-    # audit degrades to a 1-way mesh rather than skipping
-    if "jax" not in sys.modules:
-        flags = os.environ.get("XLA_FLAGS", "")
-        if "xla_force_host_platform_device_count" not in flags:
-            os.environ["XLA_FLAGS"] = (
-                flags + " --xla_force_host_platform_device_count=2"
-            ).strip()
     problems: list = []
 
     if do_smoke:

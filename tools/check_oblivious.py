@@ -26,7 +26,7 @@ the production allowlists on every invocation and must each FAIL — the
 seven leak classes (position-dependent branch, key-indexed gather,
 data-dependent early exit, secret-shaped output, un-allowlisted
 scatter, leaky debug print, python-level branch) AND, since ISSUE 14,
-the six overflow classes through the rangelint sibling analyzer (one
+the five overflow classes through the rangelint sibling analyzer (one
 shared runner proves both analyzers alive from this one tier-1 gate;
 tools/check_ranges.py is the overflow analyzer's own driver). A
 passing mutant fails this gate.
@@ -49,35 +49,30 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if REPO not in sys.path:
     sys.path.insert(0, REPO)
 
-#: shipped auto-reachable knob combinations (vphases, sort, posmap, k,
-#: evict_every): chosen so every allowlist entry is reachable —
-#: dense+scan, xla+radix, flat+recursive, cached+uncached, and
-#: per-round vs delayed eviction all appear, in the pairings the `auto`
-#: resolution ships (config.py: dense/xla is the measured CPU default;
-#: scan/radix the TPU-leaning pairing; recursive and delayed eviction
-#: ride both). E > 1 combos additionally audit the standalone flush
-#: program (engine_flush_step / oram_flush) — the write half of the
-#: delayed round.
+#: shipped auto-reachable knob combinations (vphases, sort, posmap, k):
+#: chosen so every allowlist entry is reachable — dense+scan,
+#: xla+radix, flat+recursive and cached+uncached all appear, in the
+#: pairings the `auto` resolution ships (config.py: dense/xla is the
+#: measured CPU default; scan/radix the TPU-leaning pairing; recursive
+#: rides both).
 DEFAULT_COMBOS = (
-    ("dense", "xla", "flat", 0, 1),
-    ("scan", "xla", "recursive", 2, 2),
-    ("scan", "radix", "flat", 2, 4),
-    ("dense", "radix", "recursive", 0, 2),
+    ("dense", "xla", "flat", 0),
+    ("scan", "xla", "recursive", 2),
+    ("scan", "radix", "flat", 2),
+    ("dense", "radix", "recursive", 0),
 )
-#: tier-1 budget: ONE combo — pinned at E=2 so the fetch-only round
-#: (the steady-state program a delayed-eviction server runs) always has
-#: an always-on taint census
-SMOKE_COMBO = ("dense", "xla", "flat", 0, 2)
+#: tier-1 budget: ONE combo
+SMOKE_COMBO = ("dense", "xla", "flat", 0)
 
 
-def _small_engine(vp: str, srt: str, pmi: str, k: int, ee: int = 1):
+def _small_engine(vp: str, srt: str, pmi: str, k: int):
     from grapevine_tpu.config import GrapevineConfig
     from grapevine_tpu.engine.state import EngineConfig
 
     cfg = GrapevineConfig(
         max_messages=32, max_recipients=16, batch_size=4,
         vphases_impl=vp, sort_impl=srt, posmap_impl=pmi,
-        tree_top_cache_levels=k, evict_every=ee,
+        tree_top_cache_levels=k,
     )
     return EngineConfig.from_config(cfg)
 
@@ -139,111 +134,21 @@ def audit_expiry_sweep(ecfg, allowlist, name: str):
     )
 
 
-def audit_engine_flush(ecfg, allowlist, name: str):
-    """Taint-audit the standalone delayed-eviction flush program — the
-    write half of the E-round schedule (engine_flush_step; E > 1
-    engines only). Its bucket targets must derive ONLY from the
-    untainted public window ledger."""
-    import jax
-
-    from grapevine_tpu.analysis.oblint import analyze
-    from grapevine_tpu.engine import round_step
-    from grapevine_tpu.engine.state import init_engine
-
-    state = jax.eval_shape(lambda: init_engine(ecfg, 0))
-    return analyze(
-        lambda st: round_step.engine_flush_step(ecfg, st),
-        {"state": state},
-        secrets=round_step.FLUSH_OBLINT_SECRETS,
-        allowlist=allowlist,
-        name=f"engine_flush/{name}",
-    )
-
-
-def _small_oram_cfg(recursive: bool, k: int, ee: int = 1, b: int = 4):
+def _small_oram_cfg(recursive: bool, k: int):
     from grapevine_tpu.oram.path_oram import OramConfig
     from grapevine_tpu.oram.posmap import derive_posmap_spec
 
-    pm = (
-        derive_posmap_spec(16, top_cache_levels=k,
-                           evict_window=ee, evict_fetch_count=b)
-        if recursive
-        else None
-    )
+    pm = derive_posmap_spec(16, top_cache_levels=k) if recursive else None
     return OramConfig(
         height=4, value_words=4, n_blocks=16, cipher_rounds=8,
         posmap=pm, top_cache_levels=k,
-        evict_window=ee, evict_fetch_count=b if ee > 1 else 0,
-        evict_buffer_slots=16 if ee > 1 else 0,
-    )
-
-
-def audit_oram_flush(allowlist, sort_impl: str, recursive: bool, k: int,
-                     ee: int = 2):
-    """Taint-audit oram_flush standalone against the round's anchors
-    (state-plane secrets only — flush takes no batch)."""
-    import jax
-
-    from grapevine_tpu.analysis.oblint import analyze
-    from grapevine_tpu.oram import round as oround
-    from grapevine_tpu.oram.path_oram import init_oram
-
-    cfg = _small_oram_cfg(recursive, k, ee=ee)
-    state = jax.eval_shape(lambda: init_oram(cfg, jax.random.PRNGKey(0)))
-    return analyze(
-        lambda state: oround.oram_flush(cfg, state, sort_impl=sort_impl),
-        {"state": state},
-        secrets=oround.OBLINT_SECRETS,
-        allowlist=allowlist,
-        name=f"oram_flush/{sort_impl}_"
-             f"{'rec' if recursive else 'flat'}_k{k}_e{ee}",
-    )
-
-
-def audit_sharded_oram_flush(allowlist, sort_impl: str, recursive: bool,
-                             k: int, ee: int = 2, shards: int = 2):
-    """Taint-audit the owner-masked sharded flush (ISSUE 18): the same
-    ``oram_flush`` wrapped in ``shard_map`` over a bucket-axis mesh.
-    The certified claim extends per chip: every chip's scatter targets
-    derive ONLY from the untainted public window ledger plus its own
-    (public) mesh coordinate — the owner mask narrows which rows LAND,
-    never which rows are DISPATCHED, so the per-chip transcript stays
-    the uniform static-shape drop-mode scatter (the leak argument in
-    parallel/mesh.py make_sharded_flush)."""
-    import jax
-
-    from grapevine_tpu.analysis.oblint import analyze
-    from grapevine_tpu.oram import round as oround
-    from grapevine_tpu.oram.path_oram import init_oram
-    from grapevine_tpu.parallel.mesh import (
-        TREE_AXIS, _oram_specs, make_mesh,
-    )
-
-    cfg = _small_oram_cfg(recursive, k, ee=ee)
-    state = jax.eval_shape(lambda: init_oram(cfg, jax.random.PRNGKey(0)))
-    mesh = make_mesh(jax.devices()[:shards])
-    specs = _oram_specs()
-    fn = jax.shard_map(
-        lambda st: oround.oram_flush(cfg, st, TREE_AXIS,
-                                     sort_impl=sort_impl),
-        mesh=mesh, in_specs=(specs,), out_specs=specs,
-        check_vma=False,
-    )
-    return analyze(
-        fn,
-        {"state": state},
-        secrets=oround.OBLINT_SECRETS,
-        allowlist=allowlist,
-        name=f"sharded_oram_flush/{sort_impl}_"
-             f"{'rec' if recursive else 'flat'}_k{k}_e{ee}_s{shards}",
     )
 
 
 def audit_oram_round(allowlist, occ_impl: str, sort_impl: str,
-                     recursive: bool, k: int, ee: int = 1):
+                     recursive: bool, k: int):
     """Taint-audit the library sub-rounds standalone: oram_round (and
-    through it lookup_remap_round) at a small geometry; ``ee > 1``
-    traces the delayed-eviction fetch-only round instead."""
+    through it lookup_remap_round) at a small geometry."""
     import jax
     import jax.numpy as jnp
 
@@ -251,7 +156,7 @@ def audit_oram_round(allowlist, occ_impl: str, sort_impl: str,
     from grapevine_tpu.oram import round as oround
     from grapevine_tpu.oram.path_oram import init_oram
 
-    cfg = _small_oram_cfg(recursive, k, ee=ee)
+    cfg = _small_oram_cfg(recursive, k)
     state = jax.eval_shape(lambda: init_oram(cfg, jax.random.PRNGKey(0)))
     b = 4
 
@@ -278,7 +183,7 @@ def audit_oram_round(allowlist, occ_impl: str, sort_impl: str,
         secrets=oround.OBLINT_SECRETS,
         allowlist=allowlist,
         name=f"oram_round/{occ_impl}_{sort_impl}_"
-             f"{'rec' if recursive else 'flat'}_k{k}_e{ee}",
+             f"{'rec' if recursive else 'flat'}_k{k}",
     )
 
 
@@ -444,54 +349,27 @@ def run_audit(combos, allowlist=None, with_census="first",
             print(rep.summary())
         problems.extend(f"{rep.name}: {v}" for v in rep.violations)
 
-    for vp, srt, pmi, k, ee in combos:
-        name = f"{vp}_{srt}_{pmi}_k{k}_e{ee}"
-        absorb(audit_engine_round(_small_engine(vp, srt, pmi, k, ee),
+    for vp, srt, pmi, k in combos:
+        name = f"{vp}_{srt}_{pmi}_k{k}"
+        absorb(audit_engine_round(_small_engine(vp, srt, pmi, k),
                                   allowlist, name))
-        absorb(audit_expiry_sweep(_small_engine(vp, srt, pmi, k, ee),
+        absorb(audit_expiry_sweep(_small_engine(vp, srt, pmi, k),
                                   allowlist, name))
-        if ee > 1:
-            # the write half of the delayed round: the flush program
-            # audits standalone (it runs as its own dispatch)
-            absorb(audit_engine_flush(_small_engine(vp, srt, pmi, k, ee),
-                                      allowlist, name))
         if with_subrounds:
             absorb(audit_oram_round(
                 allowlist, occ_impl=vp, sort_impl=srt,
-                recursive=(pmi == "recursive"), k=k, ee=ee,
+                recursive=(pmi == "recursive"), k=k,
             ))
             absorb(audit_lookup_remap(
                 allowlist, occ_impl=vp, sort_impl=srt,
                 recursive=(pmi == "recursive"),
             ))
-            if ee > 1:
-                absorb(audit_oram_flush(
-                    allowlist, sort_impl=srt,
-                    recursive=(pmi == "recursive"), k=k, ee=ee,
-                ))
-                import jax
-
-                if len(jax.devices()) >= 2:
-                    # the mesh composition of the same flush (ISSUE
-                    # 18): owner-masked scatter on a 2-shard mesh
-                    absorb(audit_sharded_oram_flush(
-                        allowlist, sort_impl=srt,
-                        recursive=(pmi == "recursive"), k=k, ee=ee,
-                        shards=2,
-                    ))
-                else:  # pragma: no cover - bootstrap in main()
-                    problems.append(
-                        "sharded flush audit needs >= 2 devices (got "
-                        "1) — run standalone (main() forces a virtual "
-                        "2-device CPU mesh) or under the test "
-                        "harness's 8-device conftest"
-                    )
     if with_census:
         census_combos = combos if with_census == "all" else combos[:1]
-        for vp, srt, pmi, k, ee in census_combos:
+        for vp, srt, pmi, k in census_combos:
             for v in census_equal_engine(
-                _small_engine(vp, srt, pmi, k, ee),
-                f"{vp}_{srt}_{pmi}_k{k}_e{ee}",
+                _small_engine(vp, srt, pmi, k),
+                f"{vp}_{srt}_{pmi}_k{k}",
             ):
                 problems.append(str(v))
     return problems, hits
@@ -514,17 +392,6 @@ def main(argv=None) -> int:
     import argparse
     import itertools
 
-    # the sharded flush audit traces a 2-device shard_map: force a
-    # virtual CPU mesh if jax has not initialized yet (standalone
-    # invocation; in-process the test conftest already forces 8)
-    if ("jax" not in sys.modules
-            and "xla_force_host_platform_device_count"
-            not in os.environ.get("XLA_FLAGS", "")):
-        os.environ["XLA_FLAGS"] = (
-            os.environ.get("XLA_FLAGS", "")
-            + " --xla_force_host_platform_device_count=2"
-        ).strip()
-
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--smoke", action="store_true",
                     help="tier-1 budget: one combo, engine trace + "
@@ -541,10 +408,10 @@ def main(argv=None) -> int:
 
     problems: list = []
     if args.smoke:
-        vp, srt, pmi, k, ee = SMOKE_COMBO
+        vp, srt, pmi, k = SMOKE_COMBO
         rep = audit_engine_round(
-            _small_engine(vp, srt, pmi, k, ee), ENGINE_ALLOWLIST,
-            f"{vp}_{srt}_{pmi}_k{k}_e{ee}",
+            _small_engine(vp, srt, pmi, k), ENGINE_ALLOWLIST,
+            f"{vp}_{srt}_{pmi}_k{k}",
         )
         print(rep.summary())
         problems.extend(f"{rep.name}: {v}" for v in rep.violations)
@@ -552,7 +419,7 @@ def main(argv=None) -> int:
         combos = (
             tuple(itertools.product(
                 ("dense", "scan"), ("xla", "radix"),
-                ("flat", "recursive"), (0, 2), (1, 2),
+                ("flat", "recursive"), (0, 2),
             ))
             if args.full else DEFAULT_COMBOS
         )

@@ -64,7 +64,7 @@ LOWER_BETTER = ("_ms", "_ms_per_op", "_s")
 GEOMETRY_KEYS = ("batch", "capacity_log2", "mesh", "clients",
                  "tree_density", "key_bits", "radix_bits_per_pass",
                  "rounds", "slo_target_ms", "pipeline_depth",
-                 "evict_every", "shard_count", "tail_frames",
+                 "shard_count", "tail_frames",
                  "worker_count", "adaptive_batch", "crypto_backend",
                  "host_cores", "verify_items")
 
@@ -287,27 +287,6 @@ def selftest(factor: float) -> None:
     assert n == 0 and not regs, (
         "sentinel self-test: a depth-keyed capacity line was compared "
         "against the auto-depth baseline"
-    )
-    # evict_every is GEOMETRY (PR 15): an E-keyed line (delayed batched
-    # eviction — amortized flush, a different round program whose
-    # steady-state cost is legitimately ~the fetch half) must never
-    # grade against the E=1 series, in either direction; same-E lines
-    # must still gate each other.
-    a = mk_cap(200.0, 40.0, 3250.7)
-    b = mk_cap(200.0 * factor * 4.0, 40.0 / (factor * 4.0), 3250.7)
-    b["configs"]["load_scenarios"]["evict_every"] = 4
-    regs, n = compare_latest(extract_series([a, b]), factor)
-    assert n == 0 and not regs, (
-        "sentinel self-test: an evict_every-keyed line was compared "
-        "against the E=1 baseline"
-    )
-    c = mk_cap(200.0 * factor * 4.0, 40.0 / (factor * 4.0), 3250.7)
-    d = mk_cap(200.0, 40.0, 3250.7)
-    c["configs"]["load_scenarios"]["evict_every"] = 4
-    d["configs"]["load_scenarios"]["evict_every"] = 4
-    regs, n = compare_latest(extract_series([c, d]), factor)
-    assert n == 3 and len(regs) == 3, (
-        f"sentinel self-test: same-E series not gated ({n=}, {regs})"
     )
     # shard_count is GEOMETRY (PR 16, bench fleet_loopback): an N=2
     # fleet capacity line sums two shard knees over two engines — a

@@ -15,14 +15,14 @@ mixers, u64 two-lane carries) pass through the reviewed RANGE_ALLOWLIST,
 each entry with its one-line range argument; dead entries fail the run.
 
 Sweep: the shipped knob combinations over {vphases_impl, sort_impl,
-posmap_impl, tree_top_cache_levels, evict_every} at the declared
+posmap_impl, tree_top_cache_levels} at the declared
 ``--geometry`` (log2 records; default 30 — the max certified per-tree
 capacity, where every allowlist entry genuinely fires), engine round +
 expiry sweep + standalone oram_round/lookup_remap_round per combo, plus
-the standalone flush programs (engine_flush_step / oram_flush — the
-write half of the delayed round) on every E > 1 combo. ``--full``
-sweeps the 2x2x2x2x2 cross-product (the -m slow tier). ``--smoke`` is the tier-1 budget: one
-combo at toy geometry, traces only, zero engine compiles.
+the write-back scatter under a 2-shard ``shard_map`` (the lanes only
+the mesh has). ``--full`` sweeps the 2x2x2x2 cross-product (the -m slow
+tier). ``--smoke`` is the tier-1 budget: one combo at toy geometry,
+traces only, zero engine compiles.
 
 Geometry certification: ``--geometry 30`` certifies today's capacity
 point clean; ``--geometry 36`` (the ROADMAP item 4 design point) must be
@@ -36,8 +36,7 @@ constructs WITHOUT refusing fails this gate.
 
 Teeth: the seeded overflow mutants (grapevine_tpu/analysis/mutants.py
 _RANGE_REGISTRY — u32 leaf-arith wrap, truncating cast, off-by-one axis
-bound, unbounded scan counter, eviction-buffer index overflow, int32
-byte-size product) run under the
+bound, unbounded scan counter, int32 byte-size product) run under the
 production range allowlist on every invocation and must each FAIL.
 
 Standalone: ``python tools/check_ranges.py [--smoke|--full]
@@ -57,15 +56,13 @@ if REPO not in sys.path:
 #: shipped auto-reachable knob combinations — the check_oblivious set,
 #: so the two analyzers certify the identical program matrix
 DEFAULT_COMBOS = (
-    ("dense", "xla", "flat", 0, 1),
-    ("scan", "xla", "recursive", 2, 2),
-    ("scan", "radix", "flat", 2, 4),
-    ("dense", "radix", "recursive", 0, 2),
+    ("dense", "xla", "flat", 0),
+    ("scan", "xla", "recursive", 2),
+    ("scan", "radix", "flat", 2),
+    ("dense", "radix", "recursive", 0),
 )
-#: tier-1 budget: ONE combo — pinned at E=2 (matching check_oblivious's
-#: smoke) so the delayed-eviction fetch round and its buffer-index
-#: arithmetic always have an always-on interval census
-SMOKE_COMBO = ("dense", "xla", "flat", 0, 2)
+#: tier-1 budget: ONE combo (check_oblivious's smoke)
+SMOKE_COMBO = ("dense", "xla", "flat", 0)
 
 #: default certification geometry (log2 records) for the standalone
 #: sweep: the max certified per-tree capacity — several allowlist
@@ -83,7 +80,7 @@ MAX_CERTIFIED_GEOMETRY = 30
 
 
 def _engine(log2_msgs: int, vp: str, srt: str, pmi: str, k: int,
-            ee: int = 1, batch: int = 4):
+            batch: int = 4):
     from grapevine_tpu.config import GrapevineConfig
     from grapevine_tpu.engine.state import EngineConfig
 
@@ -92,7 +89,7 @@ def _engine(log2_msgs: int, vp: str, srt: str, pmi: str, k: int,
         max_recipients=max(16, 1 << min(log2_msgs, 20)),
         batch_size=batch,
         vphases_impl=vp, sort_impl=srt, posmap_impl=pmi,
-        tree_top_cache_levels=k, evict_every=ee,
+        tree_top_cache_levels=k,
     )
     return EngineConfig.from_config(cfg)
 
@@ -135,28 +132,6 @@ def audit_engine_round(ecfg, allowlist, name: str):
     )
 
 
-def audit_engine_flush(ecfg, allowlist, name: str):
-    """Interval-audit the standalone delayed-eviction flush program —
-    the write half of the E-round schedule (engine_flush_step; E > 1
-    only). Its inputs are the state planes alone (the flush consumes no
-    batch), so the bounds are the round's state.* anchors — the same
-    dict, batch keys simply unmatched-by-construction."""
-    import jax
-
-    from grapevine_tpu.analysis.rangelint import analyze_ranges
-    from grapevine_tpu.engine import round_step
-    from grapevine_tpu.engine.state import init_engine
-
-    state = jax.eval_shape(lambda: init_engine(ecfg, 0))
-    return analyze_ranges(
-        lambda st: round_step.engine_flush_step(ecfg, st),
-        {"state": state},
-        bounds=round_step.RANGELINT_BOUNDS(ecfg),
-        allowlist=allowlist,
-        name=f"engine_flush/{name}",
-    )
-
-
 def audit_expiry_sweep(ecfg, allowlist, name: str):
     import jax
     import numpy as np
@@ -176,109 +151,61 @@ def audit_expiry_sweep(ecfg, allowlist, name: str):
     )
 
 
-def _oram_cfg(log2_blocks: int, recursive: bool, k: int, ee: int = 1,
-              b: int = 4):
+def _oram_cfg(log2_blocks: int, recursive: bool, k: int):
     from grapevine_tpu.oram.path_oram import OramConfig
     from grapevine_tpu.oram.posmap import derive_posmap_spec
 
     blocks = 1 << log2_blocks
-    pm = (
-        derive_posmap_spec(blocks, top_cache_levels=k,
-                           evict_window=ee, evict_fetch_count=b)
-        if recursive
-        else None
-    )
+    pm = (derive_posmap_spec(blocks, top_cache_levels=k)
+          if recursive else None)
     return OramConfig(
         height=max(1, log2_blocks - 1), value_words=4, n_blocks=blocks,
         cipher_rounds=8, posmap=pm, top_cache_levels=k,
-        evict_window=ee, evict_fetch_count=b if ee > 1 else 0,
-        evict_buffer_slots=min(blocks, 64) if ee > 1 else 0,
     )
 
 
-def audit_oram_flush(allowlist, log2_blocks: int, sort_impl: str,
-                     recursive: bool, k: int, ee: int):
-    """Interval-audit oram_flush standalone (the library write half of
-    the delayed round) against the tree's state-plane anchors."""
-    import jax
-
-    from grapevine_tpu.analysis.rangelint import analyze_ranges
-    from grapevine_tpu.oram import posmap as pmod
-    from grapevine_tpu.oram import round as oround
-    from grapevine_tpu.oram.path_oram import (
-        RANGELINT_BOUNDS as tree_bounds, init_oram,
-    )
-
-    cfg = _oram_cfg(log2_blocks, recursive, k, ee=ee)
-    state = jax.eval_shape(lambda: init_oram(cfg, jax.random.PRNGKey(0)))
-    bounds = {
-        **tree_bounds(cfg, prefix="state"),
-        **pmod.RANGELINT_BOUNDS(cfg, prefix="state.posmap"),
-    }
-    bounds = {k2: v for k2, v in bounds.items()
-              if not k2.startswith("pm_state")}
-    return analyze_ranges(
-        lambda state: oround.oram_flush(cfg, state, sort_impl=sort_impl),
-        {"state": state},
-        bounds=bounds,
-        allowlist=allowlist,
-        name=f"oram_flush/2^{log2_blocks}_{sort_impl}_"
-             f"{'rec' if recursive else 'flat'}_k{k}_e{ee}",
-    )
-
-
-def audit_sharded_oram_flush(allowlist, log2_blocks: int, sort_impl: str,
-                             recursive: bool, k: int, ee: int,
-                             shards: int):
-    """Interval-audit the owner-masked sharded flush (ISSUE 18): the
-    same ``oram_flush`` program wrapped in ``shard_map`` over a
-    ``shards``-device bucket-axis mesh. New arithmetic vs the
-    single-chip flush: ``axis_index`` (bounded [0, shards-1] by the
-    rangelint mesh rule) and the per-chip rebase in ``_path_scatter``
-    — non-owned lanes wrap mod 2^32 by construction and land on the
+def audit_sharded_path_scatter(allowlist, log2_blocks: int,
+                               shards: int = 2):
+    """Interval-audit the owner-masked write-back of the sharded round
+    (``_path_scatter`` under ``shard_map`` over a bucket-axis mesh):
+    the lanes only the mesh has — ``axis_index`` (bounded
+    [0, shards-1] by the rangelint mesh rule) and the per-chip rebase,
+    whose non-owned lanes wrap mod 2^32 by construction and land on the
     drop sentinel, a reviewed RANGE_ALLOWLIST pair. Trace-only, like
     every audit here."""
     import jax
+    import jax.numpy as jnp
+    from jax.sharding import PartitionSpec as P
 
     from grapevine_tpu.analysis.rangelint import analyze_ranges
-    from grapevine_tpu.oram import posmap as pmod
-    from grapevine_tpu.oram import round as oround
-    from grapevine_tpu.oram.path_oram import (
-        RANGELINT_BOUNDS as tree_bounds, init_oram,
-    )
-    from grapevine_tpu.parallel.mesh import (
-        TREE_AXIS, _oram_specs, make_mesh,
-    )
+    from grapevine_tpu.oram.path_oram import _path_scatter
+    from grapevine_tpu.parallel.mesh import TREE_AXIS, make_mesh
 
-    cfg = _oram_cfg(log2_blocks, recursive, k, ee=ee)
-    state = jax.eval_shape(lambda: init_oram(cfg, jax.random.PRNGKey(0)))
-    mesh = make_mesh(jax.devices()[:shards])
-    specs = _oram_specs()
+    cfg = _oram_cfg(log2_blocks, False, 0)
+    n, w, rows = cfg.n_buckets_padded, cfg.bucket_slots, 4 * cfg.path_len
+
+    def sds(shape, dtype=jnp.uint32):
+        return jax.ShapeDtypeStruct(shape, dtype)
+
     fn = jax.shard_map(
-        lambda st: oround.oram_flush(cfg, st, TREE_AXIS,
-                                     sort_impl=sort_impl),
-        mesh=mesh, in_specs=(specs,), out_specs=specs,
+        lambda tree, path_b, vals, owner: _path_scatter(
+            tree, path_b, vals, TREE_AXIS, owner),
+        mesh=make_mesh(jax.devices()[:shards]),
+        in_specs=(P(TREE_AXIS), P(), P(), P()), out_specs=P(TREE_AXIS),
         check_vma=False,
     )
-    bounds = {
-        **tree_bounds(cfg, prefix="state"),
-        **pmod.RANGELINT_BOUNDS(cfg, prefix="state.posmap"),
-    }
-    bounds = {k2: v for k2, v in bounds.items()
-              if not k2.startswith("pm_state")}
     return analyze_ranges(
         fn,
-        {"state": state},
-        bounds=bounds,
+        {"tree": sds((n, w)), "path_b": sds((rows,)),
+         "vals": sds((rows, w)), "owner": sds((rows,), jnp.bool_)},
+        bounds={"path_b": (0, n - 1)},
         allowlist=allowlist,
-        name=f"sharded_oram_flush/2^{log2_blocks}_{sort_impl}_"
-             f"{'rec' if recursive else 'flat'}_k{k}_e{ee}_s{shards}",
+        name=f"sharded_path_scatter/2^{log2_blocks}_s{shards}",
     )
 
 
 def audit_oram_round(allowlist, log2_blocks: int, occ_impl: str,
-                     sort_impl: str, recursive: bool, k: int,
-                     ee: int = 1):
+                     sort_impl: str, recursive: bool, k: int):
     import jax
     import jax.numpy as jnp
 
@@ -289,7 +216,7 @@ def audit_oram_round(allowlist, log2_blocks: int, occ_impl: str,
         RANGELINT_BOUNDS as tree_bounds, init_oram,
     )
 
-    cfg = _oram_cfg(log2_blocks, recursive, k, ee=ee)
+    cfg = _oram_cfg(log2_blocks, recursive, k)
     state = jax.eval_shape(lambda: init_oram(cfg, jax.random.PRNGKey(0)))
     b = 4
 
@@ -325,7 +252,7 @@ def audit_oram_round(allowlist, log2_blocks: int, occ_impl: str,
         bounds=bounds,
         allowlist=allowlist,
         name=f"oram_round/2^{log2_blocks}_{occ_impl}_{sort_impl}_"
-             f"{'rec' if recursive else 'flat'}_k{k}_e{ee}",
+             f"{'rec' if recursive else 'flat'}_k{k}",
     )
 
 
@@ -402,47 +329,33 @@ def run_audit(combos, geometry: int, allowlist=None, verbose=False,
 
     # engine geometry: max_messages = 2^geometry; sub-round geometry:
     # the same block count standalone
-    for vp, srt, pmi, k, ee in combos:
-        name = f"2^{geometry}_{vp}_{srt}_{pmi}_k{k}_e{ee}"
-        ecfg = _engine(geometry, vp, srt, pmi, k, ee)
+    for vp, srt, pmi, k in combos:
+        name = f"2^{geometry}_{vp}_{srt}_{pmi}_k{k}"
+        ecfg = _engine(geometry, vp, srt, pmi, k)
         absorb(audit_engine_round(ecfg, allowlist, name))
         absorb(audit_expiry_sweep(ecfg, allowlist, name))
-        if ee > 1:
-            # the write half of the delayed round: the flush program
-            # audits standalone (it runs as its own dispatch)
-            absorb(audit_engine_flush(ecfg, allowlist, name))
         if with_subrounds:
             absorb(audit_oram_round(
                 allowlist, geometry, occ_impl=vp, sort_impl=srt,
-                recursive=(pmi == "recursive"), k=k, ee=ee,
+                recursive=(pmi == "recursive"), k=k,
             ))
             absorb(audit_lookup_remap(
                 allowlist, geometry, occ_impl=vp, sort_impl=srt,
                 recursive=(pmi == "recursive"),
             ))
-            if ee > 1:
-                absorb(audit_oram_flush(
-                    allowlist, geometry, sort_impl=srt,
-                    recursive=(pmi == "recursive"), k=k, ee=ee,
-                ))
-                import jax
+    if with_subrounds:
+        import jax
 
-                if len(jax.devices()) >= 2:
-                    # the mesh composition of the same flush (ISSUE
-                    # 18): 2 shards is where every sharded-only lane
-                    # (axis_index, the _path_scatter rebase) exists
-                    absorb(audit_sharded_oram_flush(
-                        allowlist, geometry, sort_impl=srt,
-                        recursive=(pmi == "recursive"), k=k, ee=ee,
-                        shards=2,
-                    ))
-                else:  # pragma: no cover - bootstrap in main()
-                    problems.append(
-                        "sharded flush audit needs >= 2 devices (got "
-                        "1) — run standalone (main() forces a virtual "
-                        "2-device CPU mesh) or under the test "
-                        "harness's 8-device conftest"
-                    )
+        if len(jax.devices()) >= 2:
+            # 2 shards is where every sharded-only lane (axis_index,
+            # the _path_scatter rebase) exists
+            absorb(audit_sharded_path_scatter(allowlist, geometry))
+        else:  # pragma: no cover - bootstrap in main()
+            problems.append(
+                "sharded scatter audit needs >= 2 devices (got 1) — "
+                "run standalone (main() forces a virtual 2-device CPU "
+                "mesh) or under the test harness's 8-device conftest"
+            )
     return problems, hits
 
 
@@ -479,7 +392,7 @@ def certify_design_point(log2_records: int) -> "tuple[list, str]":
 def main(argv=None) -> int:
     import argparse
 
-    # the sharded flush audit traces a 2-device shard_map: force a
+    # the sharded scatter audit traces a 2-device shard_map: force a
     # virtual CPU mesh if jax has not initialized yet (standalone
     # invocation; in-process the test conftest already forces 8)
     if ("jax" not in sys.modules
@@ -496,7 +409,7 @@ def main(argv=None) -> int:
                          "trace + range mutants + design-point refusal; "
                          "zero compiles")
     ap.add_argument("--full", action="store_true",
-                    help="full 2x2x2x2x2 knob cross-product (the -m slow "
+                    help="full 2x2x2x2 knob cross-product (the -m slow "
                          "tier)")
     ap.add_argument("--geometry", type=int, default=None, metavar="LOG2",
                     help=f"records capacity to certify (log2; default "
@@ -515,15 +428,10 @@ def main(argv=None) -> int:
     )
 
     if args.smoke:
-        vp, srt, pmi, k, ee = SMOKE_COMBO
-        ecfg = _engine(5, vp, srt, pmi, k, ee)
+        vp, srt, pmi, k = SMOKE_COMBO
+        ecfg = _engine(5, vp, srt, pmi, k)
         rep = audit_engine_round(
-            ecfg, RANGE_ALLOWLIST, f"smoke_{vp}_{srt}_{pmi}_k{k}_e{ee}",
-        )
-        print(rep.summary())
-        problems.extend(f"{rep.name}: {f}" for f in rep.findings)
-        rep = audit_engine_flush(
-            ecfg, RANGE_ALLOWLIST, f"smoke_{vp}_{srt}_{pmi}_k{k}_e{ee}",
+            ecfg, RANGE_ALLOWLIST, f"smoke_{vp}_{srt}_{pmi}_k{k}",
         )
         print(rep.summary())
         problems.extend(f"{rep.name}: {f}" for f in rep.findings)
@@ -531,11 +439,8 @@ def main(argv=None) -> int:
 
         if len(jax.devices()) >= 2:
             # always-on sharded lane coverage (trace-only): the
-            # owner-masked flush's rebase arithmetic at toy geometry
-            rep = audit_sharded_oram_flush(
-                RANGE_ALLOWLIST, 5, sort_impl=srt,
-                recursive=(pmi == "recursive"), k=k, ee=ee, shards=2,
-            )
+            # owner-masked write-back's rebase arithmetic at toy geometry
+            rep = audit_sharded_path_scatter(RANGE_ALLOWLIST, 5)
             print(rep.summary())
             problems.extend(f"{rep.name}: {f}" for f in rep.findings)
         dp, refusal = certify_design_point(DESIGN_POINT)
@@ -567,7 +472,7 @@ def main(argv=None) -> int:
 
             combos = tuple(itertools.product(
                 ("dense", "scan"), ("xla", "radix"),
-                ("flat", "recursive"), (0, 2), (1, 2),
+                ("flat", "recursive"), (0, 2),
             ))
         swept, hits = run_audit(
             combos or DEFAULT_COMBOS, sweep_geometry,

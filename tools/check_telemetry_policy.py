@@ -309,67 +309,6 @@ def audit_workload_registry() -> dict:
     return report
 
 
-def audit_evict_registry() -> dict:
-    """Runtime pass over the delayed-eviction observability surface
-    (ISSUE-15 satellite — the eviction-buffer occupancy stream plus the
-    ``flush`` phase):
-
-    - the ``grapevine_evict_buffer_occupancy`` / ``_high_water``
-      gauges exist and carry NO label keys — the canary is a per-tree
-      SUM at scrape cadence; any dimension (tree, client, key) would
-      be a finer-grained channel than the reviewed policy admits;
-    - ``flush`` is in the canonical PHASES vocabulary, so the phase
-      histogram, the tracer span allowlist, and the flight recorder's
-      ``phase_s`` schema all admit it (one vocabulary, three surfaces);
-    - schema teeth: the phase histogram accepts ``flush`` and rejects
-      a per-window variant (``flush_w3``) with TelemetryLeakError —
-      a window-numbered phase name is how a schedule-position channel
-      would ride the declared-values contract.
-    """
-    sys.path.insert(0, REPO)
-    from grapevine_tpu.engine.metrics import EngineMetrics
-    from grapevine_tpu.obs.flightrec import ALLOWED_PHASE_KEYS
-    from grapevine_tpu.obs.phases import PHASES
-    from grapevine_tpu.obs.registry import TelemetryLeakError
-
-    if "flush" not in PHASES:
-        raise SystemExit(
-            "'flush' missing from obs.phases.PHASES — the delayed-"
-            "eviction dispatch would time under an undeclared name"
-        )
-    if "flush" not in ALLOWED_PHASE_KEYS:
-        raise SystemExit(
-            "'flush' missing from the flight recorder's phase schema"
-        )
-    em = EngineMetrics()
-    report = em.registry.audit()  # raises on any violation
-    for name in ("grapevine_evict_buffer_occupancy",
-                 "grapevine_evict_buffer_high_water"):
-        m = em.registry.get(name)
-        if m is None:
-            raise SystemExit(
-                f"eviction canary {name!r} not registered — the "
-                "overflow runbook (OPERATIONS.md §19) has no signal"
-            )
-        if m.label_keys:
-            raise SystemExit(
-                f"eviction canary {name!r} carries label keys "
-                f"{sorted(m.label_keys)} — the occupancy stream is a "
-                "label-free scrape-cadence sum by policy"
-            )
-    em.observe_phase("flush", 0.001)  # declared value: fine
-    try:
-        em.observe_phase("flush_w3", 0.001)
-    except TelemetryLeakError:
-        pass
-    else:
-        raise SystemExit(
-            "phase histogram accepted the window-numbered phase "
-            "'flush_w3' — the declared-values contract has no teeth"
-        )
-    return report
-
-
 def audit_fleet_registry() -> dict:
     """Runtime pass over the fleet observatory's metric namespace
     (ISSUE-16 satellite — the ``grapevine_fleet_*`` families the
@@ -423,7 +362,7 @@ def audit_fleet_registry() -> dict:
                 )
     # the uniformity detector exports: statistic/threshold pairs per
     # detector plus the verdict gauge, all label-free scalars
-    for det in ("cadence_ratio", "fill_load_correlation", "flush_phase"):
+    for det in ("cadence_ratio", "fill_load_correlation"):
         for kind in ("statistic", "threshold"):
             name = f"grapevine_fleet_uniformity_{det}_{kind}"
             m = agg.registry.get(name)
@@ -552,13 +491,12 @@ def audit_cost_registry() -> dict:
 def audit_host_registry() -> dict:
     """Runtime pass over the host serving pipeline's metric namespace
     (ISSUE-20 satellite — the ``grapevine_host_*`` families from the
-    multiprocess verify/codec pool, the SLO-adaptive window policy, and
-    the flush-aware collection stretch):
+    multiprocess verify/codec pool and the SLO-adaptive window policy):
 
     - builds the registry exactly as the serving layer does — a real
-      ``HostPipeline`` (worker processes spawned, then closed), a real
-      ``AdaptiveBatchPolicy``, and a flush-windowed ``BatchScheduler``
-      all registering into one merged registry, as /metrics serves it;
+      ``HostPipeline`` (worker processes spawned, then closed) and a
+      real ``AdaptiveBatchPolicy`` registering into one merged
+      registry, as /metrics serves it;
     - the ONLY label keys anywhere in the namespace are ``phase``
       (declared task kinds / decision kinds — fixed vocabularies) and
       ``worker`` (pool indices declared at registration from the
@@ -579,7 +517,6 @@ def audit_host_registry() -> dict:
         AdaptiveBatchPolicy,
     )
     from grapevine_tpu.server.hostpipe import TASK_KINDS, HostPipeline
-    from grapevine_tpu.server.scheduler import BatchScheduler
     from grapevine_tpu.obs.registry import (
         TelemetryLeakError,
         TelemetryRegistry,
@@ -587,31 +524,16 @@ def audit_host_registry() -> dict:
 
     reg = TelemetryRegistry()
     pipe = HostPipeline(workers=2, registry=reg)
-    sched = None
     try:
         AdaptiveBatchPolicy(8, 0.008, 0.002, registry=reg)
-
-        class _Ecfg:
-            batch_size = 8
-
-        class _Metrics:
-            registry = reg
-
-        class _Engine:
-            ecfg = _Ecfg()
-            metrics = _Metrics()
-
-        sched = BatchScheduler(_Engine(), flush_window_ms=4.0)
     finally:
-        if sched is not None:
-            sched.close()
         pipe.close()
     report = reg.audit()  # raises on any violation
 
     families = [
         m for m in reg.collect() if m.name.startswith("grapevine_host_")
     ]
-    if len(families) < 9:
+    if len(families) < 8:
         raise SystemExit(
             "host namespace missing: serving layer registered only "
             f"{[m.name for m in families]}"
@@ -681,7 +603,6 @@ def main() -> int:
     lm_report = audit_leakmon_registry()
     ts_report = audit_trace_slo_registry()
     wl_report = audit_workload_registry()
-    audit_evict_registry()
     fl_report = audit_fleet_registry()
     cost_report = audit_cost_registry()
     host_report = audit_host_registry()
@@ -693,8 +614,7 @@ def main() -> int:
         f"{lm_report['series']} series incl. engine); trace/slo audit "
         f"ok ({ts_report['trace_slo_families']} families, ring schema "
         f"enforced); workload audit ok ({wl_report['workload_families']} "
-        "families, fixed buckets, depth-field teeth); evict audit ok "
-        "(label-free buffer canaries, flush phase declared, teeth); "
+        "families, fixed buckets, depth-field teeth); "
         f"fleet audit ok ({fl_report['fleet_families']} families, "
         "shard-only integer labels, teeth); cost audit ok "
         f"({cost_report['cost_families']} families, phase-only labels, "
