@@ -103,9 +103,6 @@ _POSMAP = (
 #: oblivious sort/scan machinery (bit-identity with argsort is
 #: separately pinned by tests/test_radix.py, test_segmented.py)
 _SORTS = (
-    _A("gather", "oblivious/primitives.py:lex_argsort",
-       "two-pass stable 64-bit argsort: take_along_axis by the first "
-       "pass's permutation — every row moves exactly once per pass"),
     _A("gather", "oblivious/radix.py:_rank_pass",
        "counting-sort rank pass: per-digit histogram reads, all B rows "
        "touched exactly once per pass"),
@@ -249,10 +246,14 @@ RANGE_ALLOWLIST: tuple = (
        "free_top - n_allocs: phase-A admission never allocates more "
        "blocks than the freelist holds (quota invariant, oracle-"
        "pinned); the adjacent min re-bounds the result for downstream"),
-    _A("reduce_sum", "engine/vphases.py:apply_batch",
-       "masked one-hot row selects (recipient-key slot match, at most "
+    _A("reduce_sum", "engine/vphases.py:_oldest_first",
+       "masked one-hot row select (recipient-key slot match, at most "
        "one key matches per bucket — mailbox uniqueness invariant): "
-       "the sum IS the selected row, never an accumulation"),
+       "the sum IS the selected slot's entries, never an accumulation"),
+    _A("reduce_sum", "engine/vphases.py:_pth_entry",
+       "position-equality one-hot select over the cap axis: exactly "
+       "one position equals the clipped p, so the masked sum is that "
+       "entry"),
     _A("reduce_sum", "engine/vphases.py:select_by_rank",
        "rank-equality one-hot select: at most one lane of a group has "
        "rank q, so the masked sum is a private row select"),

@@ -7,7 +7,7 @@ import numpy as np
 from grapevine_tpu.config import GrapevineConfig
 from grapevine_tpu.engine.batcher import GrapevineEngine
 from grapevine_tpu.oblivious.primitives import (
-    lex_argsort,
+    sort_rows_by_u64,
     u64_add_u32,
     u64_sub,
 )
@@ -131,5 +131,7 @@ def test_u64_lane_helpers():
     # lexicographic sort: (hi, lo) pairs
     lo_a = jnp.asarray([5, 1, 9], jnp.uint32)
     hi_a = jnp.asarray([0, 2, 0], jnp.uint32)
-    order = [int(x) for x in lex_argsort(lo_a, hi_a)]
-    assert order == [0, 2, 1]  # (0,5) < (0,9) < (2,1)
+    rows = jnp.asarray([[10, 11], [20, 21], [30, 31]], jnp.uint32)
+    by_key = sort_rows_by_u64(lo_a, hi_a, rows, axis=0)
+    # (0,5) < (0,9) < (2,1)
+    assert by_key.tolist() == [[10, 11], [30, 31], [20, 21]]

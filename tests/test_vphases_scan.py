@@ -29,12 +29,22 @@ from grapevine_tpu.config import GrapevineConfig
 from grapevine_tpu.engine.batcher import GrapevineEngine
 from grapevine_tpu.engine.round_step import engine_round_step
 from grapevine_tpu.engine.state import (
+    ENT_SEQ,
+    ENT_SEQH,
+    ENTRY_WORDS,
     EngineConfig,
     ID_WORDS,
     KEY_WORDS,
     PAYLOAD_WORDS,
     init_engine,
 )
+from grapevine_tpu.engine.vphases import (
+    _drop_oldest,
+    _oldest_first,
+    _pth_entry,
+    phase_a_batch,
+)
+from grapevine_tpu.oblivious.primitives import shift_down
 from grapevine_tpu.testing.reference import ReferenceEngine
 from grapevine_tpu.wire import constants as C
 from grapevine_tpu.wire.records import QueryRequest, RequestRecord
@@ -372,6 +382,220 @@ def test_dense_jaxpr_audit_positive_control():
     actually detects the intermediates the scan test asserts away."""
     bad = _quadratic_avals(_trace_engine_jaxpr("dense"), JAXPR_B)
     assert bad, "audit found no [B,B] intermediates even in the dense impl"
+
+
+# ----------------------------------------------------------------------
+# order along a mailbox's cap axis (PR 31): one sort that carries the
+# entries, masked reductions and a barrel shift, against the gathers
+# they replaced — kept here, in the parent's words, as the reference
+# ----------------------------------------------------------------------
+
+
+def _ref_lex_argsort(lo, hi, axis):
+    p1 = jnp.argsort(lo, axis=axis, stable=True)
+    hi_p = jnp.take_along_axis(hi, p1, axis=axis)
+    p2 = jnp.argsort(hi_p, axis=axis, stable=True)
+    return jnp.take_along_axis(p1, p2, axis=axis)
+
+
+def _ref_mailbox_order(entries0, slot_match0, p, popped):
+    """Round A's ordering as the parent of PR 31 wrote it: two-pass
+    argsorts composed by gathers, rows moved by ``take_along_axis``."""
+    u32, i32 = jnp.uint32, jnp.int32
+    cap = entries0.shape[2]
+    inf = u32(0xFFFFFFFF)
+    ent_r = jnp.sum(
+        entries0 * slot_match0[:, :, None, None].astype(u32), axis=1
+    )
+    ent_valid = (ent_r[:, :, ENT_SEQ] | ent_r[:, :, ENT_SEQH]) != 0
+    sk_lo = jnp.where(ent_valid, ent_r[:, :, ENT_SEQ], inf)
+    sk_hi = jnp.where(ent_valid, ent_r[:, :, ENT_SEQH], inf)
+    order = _ref_lex_argsort(sk_lo, sk_hi, axis=1)
+    sorted_ent = jnp.take_along_axis(ent_r, order[:, :, None], axis=1)
+    pi = jnp.clip(p, 0, cap - 1)
+    init_sel = jnp.take_along_axis(
+        sorted_ent, pi[:, None, None], axis=1
+    )[:, 0, :]
+    valid_all = (
+        entries0[:, :, :, ENT_SEQ] | entries0[:, :, :, ENT_SEQH]
+    ) != 0
+    icount_sl = jnp.sum(valid_all, axis=2).astype(i32)
+    sk_lo_all = jnp.where(valid_all, entries0[:, :, :, ENT_SEQ], inf)
+    sk_hi_all = jnp.where(valid_all, entries0[:, :, :, ENT_SEQH], inf)
+    order_all = _ref_lex_argsort(sk_lo_all, sk_hi_all, axis=2)
+    sorted_all = jnp.take_along_axis(
+        entries0, order_all[:, :, :, None], axis=2
+    )
+    e_iota = jnp.arange(cap, dtype=i32)[None, None, :]
+    src = e_iota + popped[:, :, None]
+    keepm = src < icount_sl[:, :, None]
+    ents_fin = jnp.where(
+        keepm[:, :, :, None],
+        jnp.take_along_axis(
+            sorted_all, jnp.clip(src, 0, cap - 1)[:, :, :, None], axis=2
+        ),
+        u32(0),
+    )
+    return icount_sl, sorted_all, sorted_ent, init_sel, ents_fin
+
+
+def _new_mailbox_order(entries0, slot_match0, p, popped):
+    icount_sl, sorted_all, sorted_ent = _oldest_first(entries0, slot_match0)
+    return (
+        icount_sl,
+        sorted_all,
+        sorted_ent,
+        _pth_entry(sorted_ent, p),
+        _drop_oldest(sorted_all, icount_sl, popped),
+    )
+
+
+def _mailbox_cases(cap, seed):
+    """entries u32[b,K,cap,W], slot_match bool[b,K], p i32[b], popped
+    i32[b,K]: rows 0..cap shift slot 0 by their own index (every shift
+    0..cap, beside random ones in the other slots); mailboxes random
+    with holes, all holes and full; holes carry sequence 0 and
+    duplicate garbage in their other words; sequence numbers distinct
+    per mailbox, some with only the high lane set, some only the low."""
+    rng = np.random.default_rng(seed)
+    k, w = 4, ENTRY_WORDS
+    b = cap + 1 + 7
+    entries = rng.integers(1, 1 << 32, (b, k, cap, w), dtype=np.uint64)
+    garbage = rng.integers(0, 3, (b, k, cap, w), dtype=np.uint64) * 0xABCD
+    seq = np.stack(
+        [rng.permutation(cap) + 1 for _ in range(b * k)]
+    ).reshape(b, k, cap).astype(np.uint64)
+    lane = rng.integers(0, 3, (b, k, 1))  # 0: low only, 1: high only, 2: both
+    entries[..., ENT_SEQ] = np.where(
+        lane == 1, 0, seq * np.uint64(0x01000193) % (1 << 32) + 1
+    )
+    entries[..., ENT_SEQH] = np.where(lane == 0, 0, seq)
+    fill = rng.integers(0, 3, (b, k, 1))  # 0: all holes, 1: random, 2: full
+    valid = np.where(
+        fill == 1, rng.random((b, k, cap)) < 0.6, fill == 2
+    )
+    entries = np.where(valid[..., None], entries, garbage)
+    entries[..., ENT_SEQ] *= valid
+    entries[..., ENT_SEQH] *= valid
+    match = np.zeros((b, k), bool)
+    hit = rng.integers(0, k + 1, b)  # k: no slot holds my recipient
+    match[np.arange(b)[hit < k], hit[hit < k]] = True
+    popped = rng.integers(0, cap + 1, (b, k))
+    popped[: cap + 1, 0] = np.arange(cap + 1)
+    p = rng.integers(0, cap + 3, b)
+    return (
+        jnp.asarray(entries, jnp.uint32),
+        jnp.asarray(match),
+        jnp.asarray(p, jnp.int32),
+        jnp.asarray(popped, jnp.int32),
+    )
+
+
+@pytest.mark.parametrize("cap", [8, 62])
+def test_mailbox_order_equals_the_gather_formulation(cap):
+    for seed in range(3):
+        case = _mailbox_cases(cap, seed)
+        want = jax.jit(_ref_mailbox_order)(*case)
+        got = jax.jit(_new_mailbox_order)(*case)
+        names = "icount_sl sorted_all sorted_ent init_sel ents_fin".split()
+        for name, g, r in zip(names, got, want):
+            assert g.dtype == r.dtype and g.shape == r.shape, name
+            assert np.array_equal(np.asarray(g), np.asarray(r)), (
+                f"{name} differs from the gather formulation "
+                f"(cap={cap}, seed={seed})"
+            )
+
+
+def test_shift_down_every_shift_and_width():
+    for n in (1, 2, 7, 8, 62, 64):
+        x = jnp.arange(1, n + 1, dtype=jnp.uint32)[None, :] + jnp.zeros(
+            (n + 1, 1), jnp.uint32
+        )
+        s = jnp.arange(n + 1, dtype=jnp.int32)[:, None]
+        got = np.asarray(shift_down(x, s, axis=1))
+        want = np.zeros((n + 1, n), np.uint32)
+        for r in range(n + 1):
+            want[r, : n - r] = np.arange(r + 1, n + 1)
+        assert np.array_equal(got, want), n
+
+
+def _per_element_gathers(jaxpr, min_slices, max_slice_words):
+    """(result shape, slice sizes) of every ``gather`` that fetches
+    ``min_slices`` or more slices of at most ``max_slice_words`` words
+    each: the form a TPU runs one element at a time."""
+    bad = []
+    for jx in _iter_jaxprs(jaxpr):
+        for eqn in jx.eqns:
+            if eqn.primitive.name != "gather":
+                continue
+            words = int(np.prod(eqn.params["slice_sizes"]))
+            shape = tuple(eqn.outvars[0].aval.shape)
+            if (
+                words <= max_slice_words
+                and int(np.prod(shape)) // words >= min_slices
+            ):
+                bad.append((shape, tuple(eqn.params["slice_sizes"])))
+    return bad
+
+
+def _trace_round_a_apply(impl):
+    """The jaxpr of round A's callback alone: ``phase_a_batch``'s
+    precomputation and its ``apply_batch`` over [B*D] fetched rows."""
+    ecfg = EngineConfig.from_config(
+        GrapevineConfig(**{**BASE, "batch_size": JAXPR_B, "mailbox_cap": 8},
+                        vphases_impl=impl)
+    )
+    state = jax.eval_shape(lambda: init_engine(ecfg, 0))
+    b, d = JAXPR_B, ecfg.mb_choices
+    s = jax.ShapeDtypeStruct
+    u32 = jnp.uint32
+    flag = s((b,), jnp.bool_)
+    ctx = dict(
+        {n: flag for n in ("is_real", "is_create", "is_read", "is_update",
+                           "is_delete", "id_zero", "zero_recip")},
+        ka=s((b, KEY_WORDS), u32), idxs_mb2=s((b, d), u32),
+        cand_idx=s((b,), u32), id_rand=s((b, 3), u32),
+        id_key=state.id_key, free_top0=state.free_top,
+        recipients0=state.recipients, seq0=state.seq,
+        now=s((), u32), now_hi=s((), u32),
+    )
+    jaxpr = jax.make_jaxpr(
+        lambda ctx, vals0, present0: phase_a_batch(ecfg, ctx)(vals0, present0)
+    )(ctx, s((b * d, ecfg.mb.value_words), u32), s((b * d,), jnp.bool_)).jaxpr
+    return ecfg, jaxpr
+
+
+@pytest.mark.parametrize("impl", ["dense", "scan"])
+def test_round_a_apply_has_no_per_element_gather(impl):
+    """Both vphases impls share the ordering body; on a CPU-only PR a
+    ``take_along_axis`` over [B,K,cap] or [B,cap] would cost nothing
+    here and 12 ns an element on the chip. B*cap slices is the smallest
+    of the gathers PR 31 removed; what the callback still gathers by
+    index is B*D slices or fewer, or whole rows of V words."""
+    ecfg, jaxpr = _trace_round_a_apply(impl)
+    assert ecfg.mailbox_cap > ecfg.mb_choices
+    bad = _per_element_gathers(
+        jaxpr, JAXPR_B * ecfg.mailbox_cap, ENTRY_WORDS
+    )
+    assert not bad, f"{impl}: per-element gathers in round A: {bad[:6]}"
+
+
+def test_per_element_gather_audit_positive_control():
+    """The audit finds the seven gathers of the parent's formulation:
+    two of indices and one of rows for each sorted view, one of rows
+    for the shift (the p-th entry's is B slices: under the threshold)
+    — and none at all in what replaced them."""
+    case = _mailbox_cases(8, 0)
+    b, k, cap = case[0].shape[:3]
+    jaxpr = jax.make_jaxpr(_ref_mailbox_order)(*case).jaxpr
+    bad = _per_element_gathers(jaxpr, b * cap, ENTRY_WORDS)
+    shapes = sorted(s for s, _ in bad)
+    assert shapes == sorted(
+        [(b, cap)] * 2 + [(b, cap, ENTRY_WORDS)]
+        + [(b, k, cap)] * 2 + [(b, k, cap, ENTRY_WORDS)] * 2
+    ), shapes
+    clean = jax.make_jaxpr(_new_mailbox_order)(*case).jaxpr
+    assert not _per_element_gathers(clean, 1, 1 << 30)
 
 
 def test_vphases_impl_knob_validation():
