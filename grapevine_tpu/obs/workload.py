@@ -110,13 +110,18 @@ class WorkloadTelemetry:
             "scheduler queue depth at round dispatch (ops left waiting "
             "after the round's chunk was taken; round cadence)",
             buckets=DEPTH_BUCKETS)
+        # the arrival path runs once per op in the in-process ingress:
+        # its three unlabeled series are bound once, not looked up per
+        # sample (``.child()``)
         self._c_arrivals = registry.counter(
             "grapevine_load_arrivals_total",
-            "ops enqueued into the scheduler (count only, never keyed)")
+            "ops enqueued into the scheduler (count only, never keyed)"
+        ).child()
         self._g_rate = registry.gauge(
             "grapevine_load_arrival_rate_ops_s",
             "EWMA arrival rate (decayed event weight / tau; tau = "
-            f"{ewma_tau_s:g}s by default)")
+            f"{ewma_tau_s:g}s by default)"
+        ).child()
         self._g_util = registry.gauge(
             "grapevine_load_phase_utilization",
             "windowed mean fraction of each round's wall clock spent in "
@@ -130,28 +135,31 @@ class WorkloadTelemetry:
         self._c_backpressure = registry.counter(
             "grapevine_load_backpressure_arrivals_total",
             "arrivals that found the queue already >= one full batch "
-            "deep (the op will wait at least one extra round)")
+            "deep (the op will wait at least one extra round)"
+        ).child()
 
     # -- arrival path (scheduler submit; any thread) --------------------
 
-    def note_arrival(self, queue_depth: int) -> None:
-        """Record one enqueue; ``queue_depth`` is the depth *after* the
-        op joined the queue."""
+    def note_arrival(self, queue_depth: int, n: int = 1) -> None:
+        """Record ``n`` ops enqueued by one call; ``queue_depth`` is the
+        depth *after* they joined the queue."""
         now = self._clock()
         with self._lock:
             if self._t_last is not None:
                 dt = max(0.0, now - self._t_last)
                 self._weight *= math.exp(-dt / self._tau)
-            self._weight += 1.0
+            self._weight += n
             self._t_last = now
             rate = self._weight / self._tau
-        self._c_arrivals.inc()
+        self._c_arrivals.inc(n)
         self._g_rate.set(rate)
         # pre-join depth: an op joining at exactly batch_size depth
         # (itself included) still rides the very next round — only a
-        # queue ALREADY a full batch deep costs it an extra round
-        if queue_depth - 1 >= self.batch_size:
-            self._c_backpressure.inc()
+        # queue ALREADY a full batch deep costs it an extra round. Of
+        # the n that joined, the last ``depth - batch_size`` met one.
+        late = queue_depth - self.batch_size
+        if late > 0:
+            self._c_backpressure.inc(late if late < n else n)
 
     def arrival_rate(self) -> float:
         """Current decayed arrival-rate estimate (ops/s)."""

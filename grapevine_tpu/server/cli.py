@@ -125,7 +125,7 @@ def build_parser() -> argparse.ArgumentParser:
         "with cross-shard uniformity detectors (obs/fleet.py); "
         "standby = hot replica replaying a primary's shipped journal "
         "(engine/replication.py, OPERATIONS.md §23) — SIGUSR1 "
-        "promotes it and it starts serving the Submit API on "
+        "promotes it and it starts serving the EngineAPI on "
         "--engine-listen",
     )
     p.add_argument(
@@ -153,12 +153,12 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument(
         "--engine-listen",
         default="127.0.0.1:0",
-        help="(role=engine) internal host:port for the Submit API — "
+        help="(role=engine) internal host:port for the EngineAPI — "
         "keep it on localhost or a private interface",
     )
     p.add_argument(
         "--engine",
-        help="(role=frontend) host:port of the engine tier's Submit API",
+        help="(role=frontend) host:port of the engine tier's EngineAPI",
     )
     p.add_argument(
         "--replicate-to",
@@ -437,7 +437,7 @@ _ROLE_FLAGS = {
     # non-fleet flag it takes is the bind interface
     "fleet": {"role", "verbose", "metrics_host"} | _FLEET_FLAGS,
     # the standby owns a durable device engine (it replays into one)
-    # and, after promotion, serves the internal Submit API — so it
+    # and, after promotion, serves the internal EngineAPI — so it
     # takes geometry + durability + the engine tier's listener, but no
     # client-facing session flags and no --replicate-to (it is the
     # replication *target*; chaining standbys is not supported)
@@ -538,7 +538,7 @@ def _reject_misapplied_flags(parser, args, argv):
     if bad:
         raise SystemExit(
             f"--role {args.role} does not take {', '.join(sorted(bad))} "
-            "(engine = internal plaintext Submit API only; frontend = "
+            "(engine = internal plaintext EngineAPI only; frontend = "
             "client-facing sessions forwarding to --engine; see "
             "server/tier.py)"
         )

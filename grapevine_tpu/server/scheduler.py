@@ -32,11 +32,16 @@ import threading
 import time
 from collections import deque
 from concurrent.futures import Future
+from typing import TYPE_CHECKING
 
-from ..engine.batcher import GrapevineEngine
 from ..obs.phases import trace_span
 from ..session import schnorrkel
 from ..wire.records import QueryRequest, QueryResponse
+
+if TYPE_CHECKING:
+    # a frontend imports this module for its exceptions; importing the
+    # engine would start a JAX backend in a process that owns no chip
+    from ..engine.batcher import GrapevineEngine
 
 #: (pub, context, message, signature) as taken by the scheme's verify
 AuthItem = tuple[bytes, bytes, bytes, bytes]
@@ -96,7 +101,7 @@ class SchedulerShutdown(RuntimeError):
 class BatchScheduler:
     def __init__(
         self,
-        engine: GrapevineEngine,
+        engine: "GrapevineEngine",
         max_wait_ms: float = 8.0,
         idle_gap_ms: float = 2.0,
         clock=None,
@@ -188,7 +193,8 @@ class BatchScheduler:
     def submit_nowait(
         self, req: QueryRequest, auth: AuthItem | None = None
     ) -> Future:
-        """Enqueue one op and return its Future without waiting.
+        """Enqueue one op and return its Future without waiting: the
+        one-item case of :meth:`submit_many` (both are ``_enqueue``).
 
         The open-loop entry point (grapevine_tpu/load): an arrival
         joins the queue at its scheduled time regardless of how earlier
@@ -198,18 +204,39 @@ class BatchScheduler:
         op's QueryResponse, or raises AuthFailure / SchedulerShutdown /
         the round's error exactly as ``submit`` would."""
         fut: Future = Future()
-        # perf_counter enqueue stamp: the SLO's enqueue→settle anchor,
-        # the ledger's queue wait and the window's idle-gap deadline
-        # (one clock domain with the batcher's round spans)
         t_enq = time.perf_counter()
+        self._enqueue(((req, auth, fut, t_enq),), t_enq)
+        return fut
+
+    def submit_many(self, items) -> list[Future]:
+        """Enqueue ``items`` — ``(req, auth)`` pairs, ``auth`` an
+        AuthItem or None — in order and return their Futures in the
+        same order, without waiting. What arrives as one message (the
+        engine tier's batched ingress, server/tier.py) enters as one
+        call: one lock take, one notify, one depth sample and one
+        arrival note for all of it, where a call per op paid each of
+        them per op. Every op of the call carries the same enqueue
+        stamp, taken before the lock as one op's is."""
+        t_enq = time.perf_counter()
+        entries = [(req, auth, Future(), t_enq) for req, auth in items]
+        if entries:
+            self._enqueue(entries, t_enq)
+        return [e[2] for e in entries]
+
+    def _enqueue(self, entries, t_enq: float) -> None:
+        """Queue ``(request, auth, future, t_enq)`` entries in order
+        under one take of the lock. ``t_enq``, the caller's stamp for
+        all of them, is perf_counter: the SLO's enqueue→settle anchor,
+        the ledger's queue wait and the window's idle-gap deadline (one
+        clock domain with the batcher's round spans)."""
         with self._cv:
             if self._closed:
                 raise SchedulerShutdown("scheduler closed")
-            self._queue.append((req, auth, fut, t_enq))
+            if not self._queue:
+                self._head_enqueue = t_enq
+            self._queue += entries
             depth = len(self._queue)
             self._last_enqueue = t_enq
-            if depth == 1:
-                self._head_enqueue = self._last_enqueue
             if self.metrics is not None:
                 self.metrics.observe_queue_depth(depth)
             self._cv.notify()
@@ -217,8 +244,7 @@ class BatchScheduler:
         if wl is not None:
             # outside the cv: a couple of registry samples must never
             # extend the collector's critical section
-            wl.note_arrival(depth)
-        return fut
+            wl.note_arrival(depth, len(entries))
 
     # -- health probes (obs/httpd.py's /healthz) ------------------------
 

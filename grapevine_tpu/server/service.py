@@ -27,7 +27,6 @@ from concurrent import futures
 import grpc
 
 from ..config import GrapevineConfig
-from ..engine.batcher import GrapevineEngine, validate_request
 
 # the channel layer selects its backend itself: the cryptography wheel
 # when present, else the stdlib port (session/stdcrypto.py) — this
@@ -38,6 +37,7 @@ from ..testing.reference import HardProtocolError
 from ..wire import constants as C
 from ..wire import protowire as pw
 from ..wire.records import QueryRequest
+from ..wire.validate import validate_request
 from .scheduler import AuthFailure, BatchScheduler, SchedulerShutdown
 
 log = logging.getLogger("grapevine_tpu.server")
@@ -129,13 +129,17 @@ class GrapevineServer:
                 raise ValueError(
                     "adaptive batching shapes the device round "
                     "collection window — only the engine owner has one "
-                    "(the frontend forwards ops unbatched)"
+                    "(a frontend forwards its ops to the engine tier)"
                 )
             self.engine = None
             self.scheduler = scheduler
         else:
             # constructing a durable engine runs recovery (checkpoint
-            # load + journal replay) before the listener ever binds
+            # load + journal replay) before the listener ever binds.
+            # Imported here: the frontend role shares this module and
+            # must never start a JAX backend (it owns no chip)
+            from ..engine.batcher import GrapevineEngine
+
             self.engine = GrapevineEngine(
                 self.config, seed=seed, durability=durability
             )
@@ -361,7 +365,9 @@ class GrapevineServer:
         took the request (None on the hostpipe path, whose open and
         seal run in a worker process and are not timed here). The
         scheduler stamps the round's settle time once, before its
-        ``set_result`` loop, and leaves it on the future."""
+        ``set_result`` loop, and leaves it on the future; a frontend's
+        stub (server/tier.py) does the same when the engine's answers
+        reach it, so the stages mean the same in both roles."""
         auth = (
             req.auth_identity,
             C.GRAPEVINE_CHALLENGE_SIGNING_CONTEXT,
@@ -369,15 +375,9 @@ class GrapevineServer:
             req.auth_signature,
         )
         t_submit = time.perf_counter()
-        nowait = getattr(self.scheduler, "submit_nowait", None)
-        if nowait is None:
-            # the frontend role's remote scheduler: one blocking call to
-            # the engine tier, which stamps nothing here
-            resp, t_settled = self.scheduler.submit(req, auth=auth), None
-        else:
-            fut = nowait(req, auth)
-            resp = fut.result()
-            t_settled = getattr(fut, "settled_at", None)
+        fut = self.scheduler.submit_nowait(req, auth)
+        resp = fut.result()
+        t_settled = getattr(fut, "settled_at", None)
         t_awake = time.perf_counter()
         if t_settled is None:
             t_settled = t_awake

@@ -1,9 +1,14 @@
 """Split frontend/engine serving tier — the horizontal host-path story.
 
-One CPython process is GIL-bound at ~10k ops/s of session crypto +
-codec work (PERF.md host table), while the device engine targets
-~10-100× that. The reference never faced this split (its frontend was
-C-core gRPC + Rust); here it is explicit: N **frontend** processes
+One CPython process terminates only so many sessions a second: on the
+chip's 13-core host a frontend's GIL saturates at ~680 ops/s (1.53 ms
+of CPU an op, of which AEAD open and seal, challenge lockstep, codec
+and validation are ``frontend_work_us`` 139 and the rest the
+synchronous gRPC handler thread per Query; cell ``backlog-grpc-1chip``,
+builder's chip runs, PR 32; OPERATIONS.md §4), while the device engine
+targets many times that. The reference never
+faced this split (its frontend was C-core gRPC + Rust); here it is
+explicit: N **frontend** processes
 terminate client sessions (IX handshake, channel AEAD, challenge
 lockstep, request unpack + validation) and forward validated ops to ONE
 **engine** process, which batch-verifies sr25519 signatures ACROSS
@@ -19,13 +24,29 @@ compromised frontend cannot forge ops for identities it has never seen
 sign (it can only replay what the session layer already allows — same
 as the reference's host).
 
-Wire (internal, raw-bytes gRPC like the public API):
-    /grapevine.EngineAPI/Submit
-    request  = packed QueryRequest (wire codec, constant size)
-               ‖ challenge (32 B) — the auth identity and signature
+Wire (internal, raw-bytes gRPC like the public API): ops reach the
+engine in batches, one message per frontend carrying what gathered
+there, never one RPC per op — a Python gRPC handler costs the engine's
+GIL more than the session crypto the split took off it (batched, an op
+costs the engine's listener ``engine_ingress_us`` 24 at
+``submit_batch_ops`` 128 a message; same cell, same runs).
+
+    /grapevine.EngineAPI/SubmitBatch
+    request  = n (u32 LE) ‖ n × (packed QueryRequest, constant size
+               ‖ challenge, 32 B) — the auth identity and signature
                already travel inside the packed request
-    response = packed QueryResponse, or gRPC UNAUTHENTICATED /
-               INVALID_ARGUMENT mirroring the public service.
+    response = n × (status, 1 B ‖ packed QueryResponse, constant size,
+               zeros unless the status is OK), in the request's order
+
+A status is gRPC's own code for what the per-op RPC used to answer: OK,
+INVALID_ARGUMENT (the entry does not decode or validate: that entry
+alone), UNAUTHENTICATED (its signature does not verify: that entry
+alone; the op never reaches the engine), UNAVAILABLE (the scheduler is
+draining; the op was not admitted). Only a message whose framing cannot
+be read fails as a whole. A batch of one is the idle case. Nothing
+configures the batching: a frontend's one sender ships what has gathered
+whenever it is free to send, so an op that arrives at an idle frontend
+leaves at once and batches grow only because the sender was busy.
 
 The public-facing frontend behaves byte-identically to the monolithic
 ``GrapevineServer`` (same Auth/Query surface), so clients need no
@@ -35,30 +56,181 @@ changes and a load balancer can spread them across frontends.
 from __future__ import annotations
 
 import logging
+import random
+import struct
 import threading
+import time
 from concurrent import futures
+from concurrent.futures import Future
 
 import grpc
 
 from ..config import GrapevineConfig
-from ..engine.batcher import validate_request
+from ..obs.phases import trace_span
 from ..testing.reference import HardProtocolError
 from ..wire import constants as C
 from ..wire.records import QueryRequest, QueryResponse
+from ..wire.validate import validate_request
 from .scheduler import AuthFailure, SchedulerShutdown
 
 log = logging.getLogger("grapevine_tpu.tier")
 
 ENGINE_SERVICE_NAME = "grapevine.EngineAPI"
+ENGINE_METHOD = "SubmitBatch"
+
+#: one entry of a batch on the wire, in and out
+ENTRY_IN_SIZE = C.QUERY_REQUEST_WIRE_SIZE + C.CHALLENGE_SIZE
+ENTRY_OUT_SIZE = 1 + C.QUERY_RESPONSE_WIRE_SIZE
+#: an entry's status byte is gRPC's code for the same outcome
+ENTRY_OK = grpc.StatusCode.OK.value[0]
+ENTRY_INVALID = grpc.StatusCode.INVALID_ARGUMENT.value[0]
+ENTRY_UNAVAILABLE = grpc.StatusCode.UNAVAILABLE.value[0]
+ENTRY_UNAUTHENTICATED = grpc.StatusCode.UNAUTHENTICATED.value[0]
+_NO_RESPONSE = bytes(C.QUERY_RESPONSE_WIRE_SIZE)
+
+#: a frontend holds one op per session at most, so a message is bounded
+#: by its session cap (4,096 x 1.1 KB by default): above gRPC's 4 MB
+#: default, far under this
+_MAX_MESSAGE_BYTES = 64 << 20
+_CHANNEL_OPTIONS = (
+    ("grpc.max_receive_message_length", _MAX_MESSAGE_BYTES),
+    ("grpc.max_send_message_length", _MAX_MESSAGE_BYTES),
+)
+#: one handler thread per batch in flight, and a frontend keeps one
+#: batch in flight: this many frontends are served side by side
+_LISTENER_THREADS = 64
+
+
+def pack_batch(entries) -> bytes:
+    """``entries``: (packed QueryRequest, challenge) pairs -> the
+    request message."""
+    return struct.pack("<I", len(entries)) + b"".join(
+        req + challenge for req, challenge in entries)
+
+
+def unpack_answers(data: bytes, n: int) -> list[tuple[int, bytes]]:
+    """The response message -> (status, packed QueryResponse) per entry;
+    ValueError unless it answers exactly ``n`` entries."""
+    if len(data) != n * ENTRY_OUT_SIZE:
+        raise ValueError(
+            f"engine answered {len(data)} bytes to a batch of {n}")
+    return [(data[o], data[o + 1:o + ENTRY_OUT_SIZE])
+            for o in range(0, len(data), ENTRY_OUT_SIZE)]
+
+
+class EngineListener:
+    """Serves ``EngineAPI`` for any owner of a ``BatchScheduler``: the
+    engine's ingress, told once. One message is one ``submit_many``, so
+    what a frontend gathered costs the scheduler one lock take and the
+    collector's GIL one handler, whatever it holds. ``registry`` (the
+    scheduler owner's, so /metrics serves it) gets the ingress counters:
+    batch-level sums, never a per-op or per-frontend series."""
+
+    def __init__(self, scheduler, registry):
+        self.scheduler = scheduler
+        self._c_batches = registry.counter(
+            "grapevine_engine_submit_batches_total",
+            "SubmitBatch messages taken by the engine's listener",
+        )
+        self._c_ops = registry.counter(
+            "grapevine_engine_submit_ops_total",
+            "entries of those messages (ops per batch = ops / batches)",
+        )
+        self._c_ingress_s = registry.counter(
+            "grapevine_engine_ingress_seconds_total",
+            "handler-thread seconds from a SubmitBatch message's entry "
+            "to submit_many returned: framing, unpack, validate, enqueue",
+        )
+        self._grpc_server: grpc.Server | None = None
+
+    def start(self, address: str = "127.0.0.1:0") -> int:
+        """Bind the internal listener (plain host:port — deployment-
+        internal; keep it on localhost or a private interface);
+        returns the bound port."""
+        identity = lambda b: b  # noqa: E731
+        handler = grpc.method_handlers_generic_handler(
+            ENGINE_SERVICE_NAME,
+            {ENGINE_METHOD: grpc.unary_unary_rpc_method_handler(
+                self._submit_batch, request_deserializer=identity,
+                response_serializer=identity)},
+        )
+        self._grpc_server = grpc.server(
+            futures.ThreadPoolExecutor(max_workers=_LISTENER_THREADS),
+            options=_CHANNEL_OPTIONS,
+        )
+        self._grpc_server.add_generic_rpc_handlers((handler,))
+        port = self._grpc_server.add_insecure_port(address)
+        if port == 0:
+            raise RuntimeError(f"failed to bind engine listener {address}")
+        self._grpc_server.start()
+        return port
+
+    def stop(self, grace: float = 1.0) -> None:
+        if self._grpc_server is not None:
+            self._grpc_server.stop(grace).wait()
+            self._grpc_server = None
+
+    def _submit_batch(self, message: bytes,
+                      context: grpc.ServicerContext) -> bytes:
+        t_in = time.perf_counter()
+        n = struct.unpack_from("<I", message)[0] if len(message) >= 4 else -1
+        if len(message) != 4 + n * ENTRY_IN_SIZE:
+            context.abort(grpc.StatusCode.INVALID_ARGUMENT,
+                          "bad batch framing")
+        status = bytearray([ENTRY_INVALID]) * n
+        items, slots = [], []
+        with trace_span("ingress"):
+            for i in range(n):
+                o = 4 + i * ENTRY_IN_SIZE
+                try:
+                    req = QueryRequest.unpack(
+                        message[o:o + C.QUERY_REQUEST_WIRE_SIZE])
+                    validate_request(req)
+                except (ValueError, HardProtocolError):
+                    # same exception scope as the public service's
+                    # fail-fast — anything else is an engine bug and
+                    # must crash loudly, not masquerade as malformed
+                    # traffic
+                    continue
+                items.append((req, (
+                    req.auth_identity,
+                    C.GRAPEVINE_CHALLENGE_SIGNING_CONTEXT,
+                    message[o + C.QUERY_REQUEST_WIRE_SIZE:o + ENTRY_IN_SIZE],
+                    req.auth_signature,
+                )))
+                slots.append(i)
+            try:
+                futs = self.scheduler.submit_many(items)
+            except SchedulerShutdown:
+                # drain: nothing of this message was admitted
+                futs = None
+        self._c_batches.inc()
+        self._c_ops.inc(n)
+        self._c_ingress_s.inc(time.perf_counter() - t_in)
+        answers = [_NO_RESPONSE] * n
+        for k, i in enumerate(slots):
+            if futs is None:
+                status[i] = ENTRY_UNAVAILABLE
+                continue
+            try:
+                answers[i] = futs[k].result().pack()
+                status[i] = ENTRY_OK
+            except AuthFailure:
+                status[i] = ENTRY_UNAUTHENTICATED
+            except SchedulerShutdown:
+                # drain settle: UNAVAILABLE is what the frontend stub's
+                # bounded retry keys on (and never auth/protocol errors)
+                status[i] = ENTRY_UNAVAILABLE
+        return b"".join(bytes((st,)) + a for st, a in zip(status, answers))
 
 
 class EngineServer:
     """The engine tier: one device engine + cross-frontend batching.
 
-    Exposes ``Submit`` (one validated op per RPC). Concurrent RPCs from
-    many frontends land in the shared BatchScheduler, which fills
-    device rounds and batch-verifies each round's signatures with one
-    MSM — exactly the path the monolithic server uses, so every
+    Exposes ``SubmitBatch`` through an :class:`EngineListener`. The
+    batches of many frontends land in the shared BatchScheduler, which
+    fills device rounds and batch-verifies each round's signatures with
+    one MSM — exactly the path the monolithic server uses, so every
     scheduler/engine test covers this tier too.
     """
 
@@ -72,8 +244,6 @@ class EngineServer:
         from ..engine.batcher import GrapevineEngine
         from ..session import get_signature_scheme
         from .scheduler import BatchScheduler
-
-        import time as _time
 
         self.config = (engine.config if engine is not None
                        else config or GrapevineConfig())
@@ -152,62 +322,17 @@ class EngineServer:
                 registry=self.engine.metrics.registry,
             )
             self.scheduler.hostpipe = self.hostpipe
-        self._grpc_server: grpc.Server | None = None
-        self.clock = clock or (lambda: int(_time.time()))
+        self.listener = EngineListener(
+            self.scheduler, self.engine.metrics.registry)
+        self.clock = clock or (lambda: int(time.time()))
         self._expiry_stop = threading.Event()
         self._expiry_thread: threading.Thread | None = None
         self._metrics_server = None
 
-    def _submit(self, request_bytes: bytes, context: grpc.ServicerContext) -> bytes:
-        if len(request_bytes) != C.QUERY_REQUEST_WIRE_SIZE + C.CHALLENGE_SIZE:
-            context.abort(grpc.StatusCode.INVALID_ARGUMENT, "bad submit size")
-        challenge = request_bytes[C.QUERY_REQUEST_WIRE_SIZE:]
-        try:
-            req = QueryRequest.unpack(request_bytes[: C.QUERY_REQUEST_WIRE_SIZE])
-            validate_request(req)
-        except (ValueError, HardProtocolError) as exc:
-            # same exception scope as the public service's fail-fast —
-            # anything else is an engine bug and must crash loudly, not
-            # masquerade as malformed client traffic
-            context.abort(grpc.StatusCode.INVALID_ARGUMENT, str(exc))
-        try:
-            resp: QueryResponse = self.scheduler.submit(
-                req,
-                auth=(
-                    req.auth_identity,
-                    C.GRAPEVINE_CHALLENGE_SIGNING_CONTEXT,
-                    challenge,
-                    req.auth_signature,
-                ),
-            )
-        except AuthFailure:
-            context.abort(grpc.StatusCode.UNAUTHENTICATED,
-                          "bad challenge signature")
-        except SchedulerShutdown as exc:
-            # drain settle: UNAVAILABLE is what the frontend stub's
-            # bounded retry keys on (and never auth/protocol errors)
-            context.abort(grpc.StatusCode.UNAVAILABLE, str(exc))
-        return resp.pack()
-
     def start(self, address: str = "127.0.0.1:0") -> int:
-        """Bind the internal listener (plain host:port — deployment-
-        internal; keep it on localhost or a private interface)."""
-        identity = lambda b: b  # noqa: E731
-        handler = grpc.method_handlers_generic_handler(
-            ENGINE_SERVICE_NAME,
-            {"Submit": grpc.unary_unary_rpc_method_handler(
-                self._submit, request_deserializer=identity,
-                response_serializer=identity)},
-        )
-        self._grpc_server = grpc.server(
-            futures.ThreadPoolExecutor(
-                max_workers=max(8, 2 * self.config.batch_size))
-        )
-        self._grpc_server.add_generic_rpc_handlers((handler,))
-        port = self._grpc_server.add_insecure_port(address)
-        if port == 0:
-            raise RuntimeError(f"failed to bind engine listener {address}")
-        self._grpc_server.start()
+        """Bind the internal listener and start the expiry sweep;
+        returns the listener's port."""
+        port = self.listener.start(address)
         if self.config.expiry_period > 0:
             # the engine tier owns the device, so it owns the sweep —
             # the same loop the monolithic server runs (service.py)
@@ -299,8 +424,7 @@ class EngineServer:
         if self._metrics_server is not None:
             self._metrics_server.stop()
             self._metrics_server = None
-        if self._grpc_server is not None:
-            self._grpc_server.stop(grace).wait()
+        self.listener.stop(grace)
         if self.shipper is not None:
             self.shipper.close()
         self.scheduler.close()
@@ -314,75 +438,172 @@ class EngineServer:
 
 
 class _EngineStub:
-    """Scheduler-shaped adapter over the engine tier's Submit RPC, so
-    the frontend can reuse GrapevineServer._query verbatim.
+    """Scheduler-shaped adapter over the engine tier's SubmitBatch RPC,
+    so the frontend can reuse GrapevineServer._query verbatim: handler
+    threads hand their op to ``submit_nowait`` and wait on its future,
+    and ONE sender thread ships what has gathered whenever it is free
+    to send — at once when idle, as a batch of everything that arrived
+    meanwhile when the last message was still out. No timer holds an op
+    for company.
 
-    Every RPC carries a deadline (a wedged engine must fail the client's
-    call, not hang the frontend handler thread forever), and UNAVAILABLE
-    — the engine restarting, draining, or unreachable — is retried a
-    bounded number of times with jittered exponential backoff. Nothing
-    else is retried: UNAUTHENTICATED / INVALID_ARGUMENT are deliberate
+    Every RPC carries a deadline (a wedged engine must fail the clients'
+    calls, not hang the frontend's handler threads forever), and
+    UNAVAILABLE — the engine restarting, draining, or unreachable — is
+    retried a bounded number of times with jittered exponential backoff,
+    and only where no entry of the batch was admitted. Nothing else is
+    retried: UNAUTHENTICATED / INVALID_ARGUMENT are deliberate
     rejections (retrying them re-spends a challenge), and
-    DEADLINE_EXCEEDED is ambiguous — the op may have committed, and
-    Submit is not idempotent."""
+    DEADLINE_EXCEEDED is ambiguous — the ops may have committed, and
+    SubmitBatch is not idempotent."""
 
     def __init__(self, address: str, deadline_s: float = 30.0,
                  max_retries: int = 3, backoff_s: float = 0.05,
                  backoff_cap_s: float = 2.0):
-        self._grpc = grpc.insecure_channel(address)
+        self._grpc = grpc.insecure_channel(address, options=_CHANNEL_OPTIONS)
         identity = lambda b: b  # noqa: E731
-        self._submit = self._grpc.unary_unary(
-            f"/{ENGINE_SERVICE_NAME}/Submit",
+        self._rpc = self._grpc.unary_unary(
+            f"/{ENGINE_SERVICE_NAME}/{ENGINE_METHOD}",
             request_serializer=identity, response_deserializer=identity,
         )
         self.deadline_s = deadline_s
         self.max_retries = max_retries
         self.backoff_s = backoff_s
         self.backoff_cap_s = backoff_cap_s
-        self._c_retries = None
+        self._c_retries = self._c_batches = self._c_ops = None
+        #: ((packed request, challenge), future) of the ops that arrived
+        #: since the sender last took the list
+        self._gathered: list[tuple[tuple[bytes, bytes], Future]] = []
+        self._cv = threading.Condition()
+        self._closed = False
+        self._sender = threading.Thread(target=self._send_loop, daemon=True)
+        self._sender.start()
 
     def bind_registry(self, registry) -> None:
-        """Register the retry counter on the frontend's telemetry
+        """Register the stub's counters on the frontend's telemetry
         registry (counts only — batch-level by construction)."""
         self._c_retries = registry.counter(
             "grapevine_engine_rpc_retries_total",
-            "engine-tier Submit RPCs retried after UNAVAILABLE",
+            "engine-tier SubmitBatch RPCs retried after UNAVAILABLE",
+        )
+        self._c_batches = registry.counter(
+            "grapevine_engine_rpc_batches_total",
+            "SubmitBatch messages this frontend sent (retries excluded)",
+        )
+        self._c_ops = registry.counter(
+            "grapevine_engine_rpc_ops_total",
+            "ops those messages carried (ops per batch = ops / batches)",
         )
 
     def submit(self, req: QueryRequest, auth=None) -> QueryResponse:
-        import random
-        import time as _time
+        return self.submit_nowait(req, auth).result()
 
-        challenge = auth[2] if auth else b"\x00" * C.CHALLENGE_SIZE
-        payload = req.pack() + challenge
+    def submit_nowait(self, req: QueryRequest, auth=None) -> Future:
+        """Hand one op to the sender; the Future resolves to its
+        QueryResponse or raises AuthFailure / SchedulerShutdown (the
+        engine is draining) / the RPC's error, as the scheduler's own
+        would. ``settled_at`` on it is when the engine's answer reached
+        this process."""
+        entry = req.pack(), auth[2] if auth else bytes(C.CHALLENGE_SIZE)
+        fut: Future = Future()
+        with self._cv:
+            if self._closed:
+                raise SchedulerShutdown("engine stub closed")
+            self._gathered.append((entry, fut))
+            self._cv.notify()
+        return fut
+
+    def _send_loop(self):
+        while True:
+            with self._cv:
+                while not self._gathered and not self._closed:
+                    self._cv.wait()
+                if not self._gathered:
+                    return
+                batch, self._gathered = self._gathered, []
+            try:
+                self._ship(batch)
+            except Exception as exc:  # noqa: BLE001 — answer, never strand
+                if not isinstance(exc, grpc.RpcError):
+                    log.exception("engine SubmitBatch: the sender failed")
+                for _, fut in batch:
+                    if not fut.done():
+                        fut.set_exception(exc)
+
+    def _ship(self, batch) -> None:
+        """One message and its answers; on UNAVAILABLE the same entries
+        again, while none of them was admitted."""
+        if self._c_batches is not None:
+            self._c_batches.inc()
+            self._c_ops.inc(len(batch))
+        message = pack_batch([entry for entry, _ in batch])
         attempt = 0
         while True:
+            refused, why = batch, None
             try:
-                data = self._submit(payload, timeout=self.deadline_s)
+                data = self._rpc(message, timeout=self.deadline_s)
             except grpc.RpcError as e:
-                if e.code() == grpc.StatusCode.UNAUTHENTICATED:
-                    raise AuthFailure(str(e.details())) from None
-                if (
-                    e.code() != grpc.StatusCode.UNAVAILABLE
-                    or attempt >= self.max_retries
-                ):
+                if e.code() != grpc.StatusCode.UNAVAILABLE:
                     raise
-                attempt += 1
-                if self._c_retries is not None:
-                    self._c_retries.inc()
-                delay = min(
-                    self.backoff_cap_s,
-                    self.backoff_s * (2 ** (attempt - 1)),
-                ) * random.uniform(0.5, 1.5)
-                log.warning(
-                    "engine Submit UNAVAILABLE (%s); retry %d/%d in %.0f ms",
-                    e.details(), attempt, self.max_retries, delay * 1e3,
-                )
-                _time.sleep(delay)
-                continue
-            return QueryResponse.unpack(data)
+                why = e
+            else:
+                refused = self._answer(batch, unpack_answers(data, len(batch)))
+            if not refused:
+                return
+            if len(refused) < len(batch) or attempt >= self.max_retries:
+                # part of the batch was admitted, or the retries are
+                # spent: the rest is told what the scheduler would say
+                exc = why or SchedulerShutdown("engine tier draining")
+                for _, fut in refused:
+                    fut.set_exception(exc)
+                return
+            attempt += 1
+            if self._c_retries is not None:
+                self._c_retries.inc()
+            delay = min(
+                self.backoff_cap_s,
+                self.backoff_s * (2 ** (attempt - 1)),
+            ) * random.uniform(0.5, 1.5)
+            log.warning(
+                "engine SubmitBatch UNAVAILABLE (%s); retry %d/%d in %.0f ms",
+                why.details() if why else "draining", attempt,
+                self.max_retries, delay * 1e3,
+            )
+            time.sleep(delay)
+
+    @staticmethod
+    def _answer(batch, answers) -> list:
+        """Settle every entry the engine answered for good; returns the
+        entries it did not admit (UNAVAILABLE)."""
+        refused = []
+        # one stamp for the message's answers, left on each future: what
+        # a handler waits after it is the wake-up, as on the monolithic
+        # server (scheduler._settle)
+        t_settled = time.perf_counter()
+        for entry, (status, body) in zip(batch, answers):
+            fut = entry[1]
+            fut.settled_at = t_settled
+            if status == ENTRY_OK:
+                fut.set_result(QueryResponse.unpack(body))
+            elif status == ENTRY_UNAVAILABLE:
+                refused.append(entry)
+            elif status == ENTRY_UNAUTHENTICATED:
+                fut.set_exception(AuthFailure("bad challenge signature"))
+            else:
+                fut.set_exception(
+                    ValueError("the engine refused the entry as malformed"))
+        return refused
 
     def close(self):
+        """Stop the sender once it has shipped what gathered, then fail
+        anything that raced the close."""
+        with self._cv:
+            self._closed = True
+            self._cv.notify()
+        self._sender.join(timeout=self.deadline_s)
+        with self._cv:
+            stranded, self._gathered = self._gathered, []
+        for _, fut in stranded:
+            fut.set_exception(SchedulerShutdown("engine stub closed"))
         self._grpc.close()
 
 

@@ -394,13 +394,20 @@ def test_engine_tier_drain_maps_to_unavailable_and_health_surfaces():
         assert healthy
         assert detail["durability"]["last_checkpoint_seq"] == 0
         assert detail["durability"]["last_durable_seq"] == 0
-        # drain: close the scheduler, then submits map to UNAVAILABLE
+        # drain: close the scheduler, then every entry of a batch is
+        # answered UNAVAILABLE; the stub retries (nothing was admitted)
+        # and then says what the scheduler itself would: the frontend's
+        # Query handler maps SchedulerShutdown to gRPC UNAVAILABLE
         server.scheduler.close()
+        from grapevine_tpu.obs import TelemetryRegistry
+
         stub = _EngineStub(f"127.0.0.1:{port}", deadline_s=5.0,
                            max_retries=1, backoff_s=0.01)
-        with pytest.raises(grpc.RpcError) as exc_info:
+        reg = TelemetryRegistry()
+        stub.bind_registry(reg)
+        with pytest.raises(SchedulerShutdown):
             stub.submit(_req(C.REQUEST_TYPE_READ, _key(1)))
-        assert exc_info.value.code() == grpc.StatusCode.UNAVAILABLE
+        assert reg.get("grapevine_engine_rpc_retries_total").get() == 1
         stub.close()
         server.stop(checkpoint=True)
         # the final drain checkpoint sealed the (untouched) state
