@@ -158,6 +158,33 @@ def _adaptive_batch_from_contents():
     return fn, {"payloads": _sds(16), "wait": _sds(1)}, ("payloads",)
 
 
+@_mutant("hold_from_contents", "cond-predicate")
+def _hold_from_contents():
+    """The dispatch rule's failure mode (ISSUE 33): a hold that ends on
+    what the queued ops ARE. The production rule (server/scheduler.py
+    ``hold``) keeps a short queue open behind a round in flight and
+    ends on two integers — the queue's length against the batch size
+    and the number of rounds in flight. This mutant lets the held ops'
+    payload bits release the round early: the cadence, visible on the
+    wire, then says which kind of op was waiting. Pins that a contents
+    branch cannot slip into the hold unflagged."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    def fn(payloads, queued, in_flight):
+        urgent = jnp.sum(payloads >> jnp.uint32(31))  # reads op contents
+        return lax.cond(
+            urgent > 0,  # "a queued op looks urgent: do not hold it"
+            lambda: jnp.uint32(0),
+            lambda: (in_flight[0] > 0).astype(jnp.uint32)
+            * (queued[0] < 16).astype(jnp.uint32),
+        )
+
+    return (fn,
+            {"payloads": _sds(16), "queued": _sds(1), "in_flight": _sds(1)},
+            ("payloads",))
+
+
 @_mutant("python_level_branch", "trace-dependence")
 def _python_level_branch():
     """A host-Python `if` on a traced secret — different Python paths

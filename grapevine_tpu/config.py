@@ -327,15 +327,22 @@ class GrapevineConfig:
     #: order is journal order at every depth — never completion order
     #: (the chaos invariant; tools/chaos_run.py --pipeline-depth 2).
     #: Responses and final state are bit-identical at both depths
-    #: (tests/test_pipeline.py). None = auto: 2 on the TPU — the
-    #: device round is the long pole there, overlap is the whole win
-    #: (not measured on the chip) — and 1 on the CPU: host-bound
-    #: (bubble ratio ≈ 0.0002) the second in-flight round has no device
-    #: window to hide work behind, and under open-loop sustained load
-    #: every op's round dispatches behind one extra unfinished device
-    #: round (+1 round of p99, measured; closed-loop bursty traffic
-    #: instead sees a modest fsync-overlap win — bench.py
-    #: ``pipeline_ab``, PERF.md Round 11 has both numbers honestly).
+    #: (tests/test_pipeline.py). None = auto: 2 on the TPU, where with
+    #: full rounds the host's ~42 ms a round (verify, dispatch, demux,
+    #: settle) hide behind the device's 54-150 ms (PERF.md §5, the
+    #: backlog cells), and 1 on the CPU: host-bound (bubble ratio ≈
+    #: 0.0002) the second in-flight round has no device window to hide
+    #: work behind. The depth bounds FULL rounds only. Part-empty
+    #: rounds queued two deep cost an open-loop op 3.6 rounds for one
+    #: round's work on the chip (`trickle-1chip`, 448 ops/s:
+    #: ``rounds_ahead`` 2.0, ``round_ms`` 151 for 54.2 ms of device
+    #: time, ``commit_p50_ms`` 196; ledger, PR 32), so since PR 33 the
+    #: scheduler holds a short queue while a round is in flight
+    #: (server/scheduler.py ``hold``): there the same cell reads
+    #: ``rounds_ahead`` 0 and ``commit_p50_ms`` 134-139 (builder's
+    #: chip runs, PR 33, PERF.md §5-6), and the rule gives way to the
+    #: full depth by itself where a batch gathers within one serial
+    #: period, at 20,000-24,000 ops/s offered in process.
     pipeline_depth: int | None = None
 
     #: bucket-tree shard count across the device mesh (parallel/mesh.py):

@@ -211,6 +211,13 @@ class PendingRound:
         if tracer is not None and self._seq is not None:
             tracer.amend_round(self._seq, {"settle": (start_s, dur_s)})
 
+    def ready(self) -> bool:
+        """True once the device has finished this round, so that
+        ``resolve()`` would not wait for it; never blocks. What the
+        scheduler polls while it holds a short queue behind this
+        round."""
+        return not _still_running(self._resp)
+
     def resolve(self) -> list[QueryResponse]:
         eng = self._engine
         m = eng.metrics
@@ -284,10 +291,11 @@ class PendingRound:
         if lm is not None and self._transcript is not None:
             # one non-blocking queue put; detectors run on the monitor's
             # own thread (obs/leakmon.py), never on the round path.
-            # the derived windows stay tracer-only — the flightrec phase
-            # schema is the canonical PHASES (+ round)
+            # the derived windows and the scheduler's hold stay
+            # tracer-only — the flightrec phase schema is the canonical
+            # PHASES (+ round)
             phases = {k: d for k, (_, d) in spans.items()
-                      if k not in ("device", "inflight", "queue")}
+                      if k not in ("device", "inflight", "queue", "hold")}
             lm.submit_round(self._batch, self._transcript, self._n, bs,
                             phases, queue_depth=self._qdepth)
         return out

@@ -6,10 +6,10 @@ doing (fill, detector stats); this module answers *where the time went*
 rounds) before anyone builds them. Each committed round contributes one
 span ledger assembled from the phase timers the engine already runs
 (assembly/verify/dispatch/journal/checkpoint/evict/demux, the
-scheduler's queue wait and settle fan-out, and the two device windows:
-``device``, the round's own device time as the host can know it, and
-``inflight``, dispatch to observed ready with the rounds queued ahead
-of it), plus a handful of per-round counts, kept in a fixed ring like
+scheduler's queue wait, hold and settle fan-out, and the two device
+windows: ``device``, the round's own device time as the host can know
+it, and ``inflight``, dispatch to observed ready with the rounds queued
+ahead of it), plus a handful of per-round counts, kept in a fixed ring like
 the flight recorder and exported two ways:
 
 - ``chrome_trace()`` — Chrome trace-event JSON (the ``/trace`` endpoint,
@@ -45,7 +45,8 @@ the flight recorder and exported two ways:
 Leak stance — the PR-1/2 contract, enforced structurally: a span is a
 *phase*, never an operation. ``record_round()`` validates every ledger
 against the fixed span-name allowlist (the canonical phases plus the
-derived ``device``/``inflight``/``queue``/``settle``/``round`` windows)
+scheduler's ``hold``/``settle`` and the derived
+``device``/``inflight``/``queue``/``round`` windows)
 and rejects anything else with :class:`TelemetryLeakError`; a span
 value is exactly a ``(start, duration)`` pair of floats. A count is one
 of :data:`ROUND_COUNTS` and a sum over the whole round (how many ops,
@@ -89,10 +90,13 @@ from .phases import PHASES
 from .registry import TelemetryLeakError, TelemetryRegistry
 
 #: spans assembled on the host side of every round (obs/phases.py
-#: names, plus the scheduler's ``settle``: the ``set_result`` fan-out
-#: and bookkeeping after ``resolve()`` returned)
+#: names, plus the scheduler's own two: ``hold``, the time the dispatch
+#: rule deferred the round behind one in flight, from the close of its
+#: window or its first op's arrival to the moment its ops were taken, 0
+#: for a round that was not held; and ``settle``, the ``set_result``
+#: fan-out and bookkeeping after ``resolve()`` returned)
 HOST_SPANS = (
-    "assembly", "verify", "dispatch", "journal", "checkpoint",
+    "assembly", "hold", "verify", "dispatch", "journal", "checkpoint",
     "evict", "demux", "settle",
 )
 

@@ -23,6 +23,18 @@ batch verification, and journal fsync all overlap rounds k and k+1 on
 the device (engine/batcher.py module docstring has the stage contract;
 OPERATIONS.md §16 the ordering/durability argument). Depth 1 is
 bit-for-bit the pre-PR-10 dispatch-then-settle loop.
+
+The depth is for FULL rounds. A window that closes short of a batch
+while a round is in flight does not dispatch (``hold`` in
+``_run_inner``): a part-empty round queued behind another answers
+nobody sooner, it only puts a whole device round between the next ops
+and theirs. The queue stays open until it holds a batch (dispatched at
+once, the rounds in flight staying in flight) or until the last round
+in flight has settled (what has gathered then leaves without a further
+window). So the cadence is a function of two public aggregates and
+nothing else: the queue's depth against the batch size and the number
+of rounds in flight — load, never content (SECURITY.md, "round cadence
+and size").
 """
 
 from __future__ import annotations
@@ -85,6 +97,14 @@ def round_counts(enqueued: list[float], taken: int, t_dispatch: float,
         "rounds_ahead": rounds_ahead,
         "verify_chunks": verify_chunks,
     }
+
+
+def _round_ready(pending) -> bool:
+    """Whether ``resolve()`` would find the device done with this
+    round. A handle that cannot say (a test's bare fake) is taken to be
+    ready: its ``resolve()`` is then the wait, as it was."""
+    probe = getattr(pending, "ready", None)
+    return True if probe is None else bool(probe())
 
 
 class AuthFailure(Exception):
@@ -347,6 +367,30 @@ class BatchScheduler:
             self._crash_streak = 0  # a settled round = recovered
             self._inflight_since = ledger[0][2] if ledger else None
 
+        def hold(w_gap, w_target):
+            """The dispatch rule for a short queue behind a round in
+            flight: keep the queue open until it holds ``w_target`` ops
+            (the caller dispatches at once, the ledger as it is) or
+            until the ledger has settled empty (the caller dispatches
+            what has gathered, without a further window). Watches both
+            at the window's own idle gap: an arrival notifies ``_cv``,
+            the head round's readiness is polled. With nothing queued
+            no op is deferred and there is nothing to watch: the settle
+            is the wait, as it always was, and the wave that follows a
+            full round does not wake the collector once per op. Reads
+            the queue's LENGTH and the ledger's, never an entry of
+            either."""
+            with trace_span("hold"):
+                while ledger:
+                    with self._cv:
+                        while (0 < len(self._queue) < w_target
+                               and not self._closed
+                               and not _round_ready(ledger[0][0])):
+                            self._cv.wait(timeout=w_gap)
+                        if len(self._queue) >= w_target or self._closed:
+                            return
+                    settle_head()
+
         while True:
             with self._cv:
                 while not self._queue and not self._closed:
@@ -367,8 +411,9 @@ class BatchScheduler:
             w_wait, w_gap, w_target = self.max_wait, self.idle_gap, bs
             if has_work and self.adaptive is not None:
                 w_wait, w_gap, w_target = self.adaptive.decide(depth0)
+            t_asm0 = time.perf_counter()
+            asm_s, hit_cap = 0.0, False
             with self._cv:
-                chunk = []
                 if self._queue:
                     # Quiescence-based collection: a client wave
                     # re-arrives staggered over several ms after the
@@ -381,9 +426,7 @@ class BatchScheduler:
                     # still commits after the idle gap. The wait runs
                     # while the device executes the previous round (see
                     # below), so it costs no device idle time under load.
-                    t_asm0 = time.perf_counter()
                     deadline = t_asm0 + w_wait
-                    hit_cap = False
                     with trace_span("assembly"):
                         while (len(self._queue) < w_target
                                and not self._closed):
@@ -395,22 +438,31 @@ class BatchScheduler:
                                 hit_cap = now >= deadline
                                 break
                             self._cv.wait(timeout=wait_until - now)
-                    chunk, self._queue = self._queue[:bs], self._queue[bs:]
-                    backlog = len(self._queue)
-                    t_asm1 = time.perf_counter()
-                    asm_s = t_asm1 - t_asm0
-                    if self._queue:
-                        # remaining head has been waiting since roughly
-                        # now (it arrived during this window)
-                        self._head_enqueue = t_asm1
+                    asm_s = time.perf_counter() - t_asm0
                     if self.metrics is not None:
-                        self.metrics.observe_queue_depth(len(self._queue))
                         self.metrics.observe_phase("assembly", asm_s)
-                        if hit_cap and len(chunk) < bs:
+                        if hit_cap and len(self._queue) < bs:
                             # window closed by the max_wait cap, not by
                             # quiescence or a full batch: arrivals are
                             # starving mid-wave (the stall signal)
                             self.metrics.record_stall()
+                # the dispatch rule: a short queue behind a round in
+                # flight waits for that round (hold's docstring); with
+                # none in flight, or a batch in the queue, nothing is
+                # deferred and the window's own lock take is the pop's
+                held = bool(ledger and len(self._queue) < w_target
+                            and not self._closed)
+                t_h0 = t_take = time.perf_counter()
+                if not held:
+                    chunk, backlog = self._take(bs, t_take)
+            if held:
+                hold(w_gap, w_target)
+                with self._cv:
+                    t_take = time.perf_counter()
+                    # the deferred ops have waited since the hold began
+                    # or since the first of them came
+                    t_h0 = max(t_h0, self._head_enqueue)
+                    chunk, backlog = self._take(bs, t_take)
 
             # everything the death-guard must fail if we crash from here:
             # the rounds still in flight on the device plus the chunk
@@ -444,6 +496,9 @@ class BatchScheduler:
                         # (getattr: test fakes return bare objects)
                         if getattr(pending, "note_span", None) is not None:
                             pending.note_span("assembly", t_asm0, asm_s)
+                            # how long the dispatch rule deferred this
+                            # round; 0 for a round that was not held
+                            pending.note_span("hold", t_h0, t_take - t_h0)
                             pending.note_span("verify", t_v0, ver_s)
                             # post-dispatch backlog: the queue-depth
                             # sample obs/workload.py histograms at
@@ -482,10 +537,23 @@ class BatchScheduler:
                 while len(ledger) > depth:
                     settle_head()
             elif ledger:
-                # nothing dispatched this pass (idle tail, drain, or an
-                # all-rejected chunk): settle the oldest round so its
-                # clients are answered promptly and close() can drain
+                # nothing dispatched this pass (drain, or an all-rejected
+                # chunk; an idle tail settles inside the hold): settle
+                # the oldest round so its clients are answered promptly
+                # and close() can drain
                 settle_head()
+
+    def _take(self, bs: int, now: float):
+        """Pop the next round's entries, at most ``bs``, off the queue
+        (the caller holds ``_cv``); returns them and how many stay."""
+        chunk, self._queue = self._queue[:bs], self._queue[bs:]
+        if self._queue:
+            # remaining head has been waiting since roughly now (it
+            # arrived while this round was collected)
+            self._head_enqueue = now
+        if chunk and self.metrics is not None:
+            self.metrics.observe_queue_depth(len(self._queue))
+        return chunk, len(self._queue)
 
     def _batch_verify_fanout(self, items) -> bool:
         """First-pass batch verify: the round's items as k contiguous

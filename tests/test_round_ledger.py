@@ -225,6 +225,106 @@ def test_settle_lands_on_its_own_round(clocked):
         assert {next(by_round) for _ in range(e["counts"]["ops"])} == {s0}
 
 
+def test_a_held_round_carries_its_hold_on_the_ledger(clocked, monkeypatch):
+    """The real handle under the dispatch rule: ``ready()`` is what the
+    hold polls (kept False here until the test has seen the queue looked
+    at and left alone), the ops that gathered behind the round in flight
+    ride one round with nothing ahead of it, and that round's ledger has
+    a ``hold`` span between its window and its verification; the round
+    that was not held has one of 0."""
+    _, tracer, sched = clocked
+    released = threading.Event()
+    polls = []
+    really_ready = batcher_mod.PendingRound.ready
+
+    def ready(self):
+        polls.append(1)
+        return released.is_set() and really_ready(self)
+
+    monkeypatch.setattr(batcher_mod.PendingRound, "ready", ready)
+    # the first dispatch waits inside the engine until the two ops that
+    # will be held are queued behind it
+    dispatching, go = threading.Event(), threading.Event()
+    dispatch = sched.engine.handle_queries_async
+
+    def gated(reqs, now):
+        if not dispatching.is_set():
+            dispatching.set()
+            assert go.wait(timeout=60)
+        return dispatch(reqs, now)
+
+    monkeypatch.setattr(sched.engine, "handle_queries_async", gated)
+    auth = (b"p", b"c", b"m", b"ok")
+    futs = [sched.submit_nowait(_req(0), auth)]
+    assert dispatching.wait(timeout=60)
+    futs += [sched.submit_nowait(_req(n), auth) for n in (1, 2)]
+    go.set()
+    deadline = time.monotonic() + 60
+    seen = len(polls)
+    while len(polls) < seen + 3:
+        assert time.monotonic() < deadline
+        time.sleep(0.002)
+    with sched._cv:
+        assert len(sched._queue) == 2
+    released.set()
+    for f in futs:
+        f.result(timeout=120)  # the module's engine may be at a cap
+    first, second = _ledgers(tracer)
+    assert [e["counts"]["ops"] for e in (first, second)] == [1, 2]
+    assert [e["counts"]["rounds_ahead"] for e in (first, second)] == [0, 0]
+    assert first["spans"]["hold"][1] == 0
+    h0, hdur = second["spans"]["hold"]
+    a0, adur = second["spans"]["assembly"]
+    assert hdur > 0
+    assert a0 + adur <= h0 and h0 + hdur <= second["spans"]["verify"][0]
+    assert second["spans"]["queue"][1] >= hdur
+    # the round in flight was settled inside the hold, before the held
+    # round was dispatched: no answer waited for another round
+    assert (first["spans"]["settle"][0]
+            < second["spans"]["dispatch"][0])
+
+
+@pytest.mark.parametrize("metric,cell", [
+    ("hold_ms", "backlog-grpc-1chip"), ("hold_ms.trickle", "trickle-1chip")])
+def test_the_benchmark_reads_the_hold_span(metric, cell):
+    """``benchmarks/layer_metrics/hold_ms*.json`` through the reader the
+    benchmark has (``ledger_span``), on a synthetic ledger: the median
+    of the rounds' ``hold`` spans in ms, 0 where no round was held, and
+    nothing from a program that keeps no such span (the parent)."""
+    from benchmarks.lib.manifest import Benchmark, load_kind
+
+    (entry, spec), = [(m, f) for m, f in Benchmark.load().per_layer(cell)
+                      if m["name"] == metric]
+    assert entry["layer"] == "scheduler" and entry["unit"] == "ms"
+    read = load_kind("readers", spec["reader"]).read
+    tr = RoundTracer(capacity=8)
+    for k, hold_s in enumerate((0.0, 0.048, 0.052)):
+        t0 = 10.0 + k
+        tr.record_round({"assembly": (t0, 0.004),
+                         "hold": (t0 + 0.004, hold_s),
+                         "verify": (t0 + 0.06, 0.002),
+                         "round": (t0, 0.13)})
+    ledger = tr.chrome_trace()["traceEvents"]
+    assert read(spec["params"], {"ledger": ledger, "window": (9.0, 20.0)}) \
+        == pytest.approx(48.0)
+    # only the first round began inside this window: it was not held
+    assert read(spec["params"], {"ledger": ledger, "window": (9.0, 10.5)}) \
+        == 0.0
+    old_program = [ev for ev in ledger if ev.get("name") != "grapevine/hold"]
+    assert read(spec["params"],
+                {"ledger": old_program, "window": (9.0, 20.0)}) is None
+
+
+def test_a_round_handle_says_when_the_device_is_done(engine):
+    pending = engine.handle_queries_async([_req(3)], 1_700_000_002)
+    deadline = time.monotonic() + 60
+    while not pending.ready():  # never blocks; the device finishes
+        assert time.monotonic() < deadline
+        time.sleep(0.002)
+    assert len(pending.resolve()) == 1
+    assert pending.ready()
+
+
 def test_rejected_ops_are_counted_not_admitted(clocked):
     _, tracer, sched = clocked
     out, _ = _drive(sched, 1, bad=frozenset({2}))

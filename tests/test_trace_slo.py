@@ -151,7 +151,8 @@ def test_allowed_span_names_stay_inside_phase_vocabulary():
 
     from grapevine_tpu.obs.tracer import DERIVED_SPANS
 
-    assert ALLOWED_SPAN_NAMES <= set(PHASES) | set(DERIVED_SPANS) | {"settle"}
+    assert ALLOWED_SPAN_NAMES <= (
+        set(PHASES) | set(DERIVED_SPANS) | {"hold", "settle"})
 
 
 def test_tracer_gauges_export():

@@ -210,10 +210,10 @@ def audit_trace_slo_registry() -> dict:
                 "scalars with no dimensions by design"
             )
 
-    # the derived windows and the scheduler's settle fan-out are whole-
-    # round spans too (obs/tracer.py DERIVED_SPANS)
+    # the derived windows and the scheduler's hold and settle fan-out
+    # are whole-round spans too (obs/tracer.py DERIVED_SPANS)
     stray = (ALLOWED_SPAN_NAMES - set(PHASES)
-             - {"device", "inflight", "queue", "round", "settle"})
+             - {"device", "inflight", "queue", "round", "hold", "settle"})
     if stray:
         raise SystemExit(
             f"tracer span allowlist drifted outside the phase "
