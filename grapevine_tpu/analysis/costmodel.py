@@ -246,25 +246,23 @@ def engine_round_rows(ecfg) -> dict:
 
 
 def sweep_chunk_planes(cfg, prefix: str = "") -> dict:
-    """The chunk shapes one tree's expiry sweep streams through its
+    """The planes one tree's expiry sweep streams through its
     ``lax.scan`` (engine/expiry.py ``_chunked_tree_sweep``): plane name
-    -> (chunk shape, rows per full pass). The scan consumes each plane
-    reshaped to ``[n_chunks, rows_per_chunk, ·]`` — whole-plane
-    passes, not gathers, so the traced check reduces scan operands
-    (:func:`traced_scan_rows`) instead of access primitives."""
-    from ..engine.expiry import _chunk_rows
-
+    -> (shape as the scan holds it, rows per full pass). The scan
+    carries the idx / val (and recursive leaf) planes whole, cutting
+    each chunk out and writing it back over itself, and reads the nonce
+    plane as a constant — whole-plane passes, not gathers, so the
+    traced check reduces scan operands (:func:`traced_scan_rows`)
+    instead of access primitives."""
     z, v = cfg.bucket_slots, cfg.value_words
     n = cfg.n_buckets_padded
-    rpc = _chunk_rows(cfg, cfg.n_buckets_padded)
-    nch = n // rpc
     planes = {
-        f"{prefix}tree_idx": ((nch, rpc, z), n),
-        f"{prefix}tree_val": ((nch, rpc, z * v), n),
-        f"{prefix}nonces": ((nch, rpc, 2), n),
+        f"{prefix}tree_idx": ((n * z,), n),
+        f"{prefix}tree_val": ((n, z * v), n),
+        f"{prefix}nonces": ((n, 2), n),
     }
     if cfg.posmap is not None and cfg.encrypted:
-        planes[f"{prefix}tree_leaf"] = ((nch, rpc, z), n)
+        planes[f"{prefix}tree_leaf"] = ((n * z,), n)
     return planes
 
 
@@ -327,25 +325,33 @@ def predicted_access_rows(rows: dict) -> dict:
 
 
 def traced_scan_rows(jaxpr, chunk_planes: dict) -> dict:
-    """Sweep derivation #2: rows streamed per chunk-shape class through
+    """Sweep derivation #2: rows streamed per plane-shape class through
     ``lax.scan`` equations — a scan operand (read) or output (write)
-    whose aval matches a declared chunk shape accounts one full pass of
-    that many rows. Returns ``{chunk_shape: (read_rows, write_rows)}``."""
+    whose aval matches a declared plane shape accounts one full pass of
+    that many rows. Returns ``{plane_shape: (read_rows, write_rows)}``."""
     classes = {}
     for _, (chunk_shape, pass_rows) in chunk_planes.items():
         classes[tuple(chunk_shape)] = int(pass_rows)
     out = {shape: [0, 0] for shape in classes}
+
+    def plane_shape(var):
+        # a plane is u32 words: the records pass also carries a bool
+        # per message id, which a small tree's slot plane can match in
+        # length
+        aval = var.aval
+        shape = tuple(getattr(aval, "shape", ()))
+        if shape in classes and str(aval.dtype) == "uint32":
+            return shape
+        return None
+
     for eqn in walk_eqns(jaxpr):
         if eqn.primitive.name != "scan":
             continue
-        for var in eqn.invars:
-            shape = tuple(getattr(var.aval, "shape", ()))
-            if shape in classes:
-                out[shape][0] += classes[shape]
-        for var in eqn.outvars:
-            shape = tuple(getattr(var.aval, "shape", ()))
-            if shape in classes:
-                out[shape][1] += classes[shape]
+        for side, variables in enumerate((eqn.invars, eqn.outvars)):
+            for var in variables:
+                shape = plane_shape(var)
+                if shape is not None:
+                    out[shape][side] += classes[shape]
     return {shape: (g, s) for shape, (g, s) in out.items()}
 
 

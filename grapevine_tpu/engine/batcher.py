@@ -40,6 +40,7 @@ import jax
 import numpy as np
 
 from ..config import DurabilityConfig, GrapevineConfig
+from ..obs.phases import trace_span
 from ..testing import faults
 from ..wire import constants as C
 from ..wire.records import QueryRequest, QueryResponse, Record
@@ -307,6 +308,17 @@ def _still_running(resp) -> bool:
     return not all(x.is_ready() for x in jax.tree.leaves(resp))
 
 
+def _build_state(build) -> tuple[EngineState, float]:
+    """``build()``'s state, waited for, under the host span
+    ``grapevine/state_init``, and the seconds it took. A bus of 2^21
+    messages zeroes 10 GB of trees here; a server's set-up is mostly
+    this and the first compile."""
+    t0 = time.perf_counter()
+    with trace_span("state_init"):
+        state = jax.block_until_ready(build())
+    return state, time.perf_counter() - t0
+
+
 class GrapevineEngine:
     """The in-process oblivious engine: the TPU analog of the enclave.
 
@@ -348,9 +360,8 @@ class GrapevineEngine:
             self._mesh = make_mesh(devs[: self.config.shards])
             # created directly sharded: a mesh exists because one chip
             # cannot hold the trees, so they must never be staged on one
-            self.state: EngineState = init_sharded_engine(
-                self.ecfg, self._mesh, seed
-            )
+            self.state, state_init_s = _build_state(
+                lambda: init_sharded_engine(self.ecfg, self._mesh, seed))
             state_shardings = jax.tree.map(lambda x: x.sharding, self.state)
             if self.config.bucket_cipher_impl == "pallas_fused":
                 # said once, at build: the fused gather/scatter kernels
@@ -369,7 +380,8 @@ class GrapevineEngine:
             ssweep = make_sharded_sweep(self.ecfg, self._mesh)
             self._sweep = lambda _ecfg, state, *clock: ssweep(state, *clock)
         else:
-            self.state = init_engine(self.ecfg, seed)
+            self.state, state_init_s = _build_state(
+                lambda: init_engine(self.ecfg, seed))
             step_fn = (engine_round_step if self.config.commit == "phase"
                        else engine_step)
             # donate the state: trees update in place (no per-round copy,
@@ -411,6 +423,9 @@ class GrapevineEngine:
         self.metrics = EngineMetrics()
         layout = self.round_layout()
         self.metrics.set_round_layout(layout)
+        self.metrics.set_state_size(
+            state_init_s,
+            sum(x.nbytes for x in jax.tree.leaves(self.state)))
         _log.info(
             "round layout (dense_levels, fetched_bucket_rows, "
             "perpath_bucket_rows per oram_round): %s",
@@ -750,6 +765,8 @@ class GrapevineEngine:
             }
         for name, n in counts.items():
             self.metrics.observe_stash(name, n)
+        self.metrics.observe_device_memory(
+            d.memory_stats() or {} for d in state.rec.tree_val.devices())
         return counts
 
     def round_layout(self) -> dict:

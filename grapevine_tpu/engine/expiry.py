@@ -120,35 +120,43 @@ def _chunked_tree_sweep(cfg: OramConfig, oram: OramState, carry0, body,
     never change (expiry only kills blocks; dead slots are masked by
     the SENTINEL idx), but its ciphertext epoch must follow the bucket.
     """
-    z, v = cfg.bucket_slots, cfg.value_words
+    z = cfg.bucket_slots
     n = oram.tree_val.shape[0]  # == n_buckets_padded off the mesh
     rpc = _chunk_rows(cfg, n)
     nch = n // rpc
-    bids = jnp.arange(n, dtype=U32)
-    if axis_name is not None:
-        # global heap ids of the owned rows: the keystream is keyed by them
-        base = jax.lax.axis_index(axis_name).astype(U32) * U32(n)
-        bids = bids + base
-    bids = bids.reshape(nch, rpc)
-    idx3 = oram.tree_idx.reshape(nch, rpc, z)
-    val3 = oram.tree_val.reshape(nch, rpc, z * v)
-    eps = oram.nonces.reshape(nch, rpc, 2)
+    # global heap id of this chip's first row: the keystream is keyed
+    # by heap ids
+    base = (U32(0) if axis_name is None
+            else jax.lax.axis_index(axis_name).astype(U32) * U32(n))
     recrypt_leaf = cfg.posmap is not None and cfg.encrypted
-    leaf3 = (
-        oram.tree_leaf.reshape(nch, rpc, z)
-        if recrypt_leaf
-        else jnp.zeros((nch, rpc, 0), U32)
-    )
 
-    def scan_body(carry, xs):
-        bid, ix, vl, ep, lf = xs
+    # The planes ride the scan's carry and each chunk is cut out of them
+    # and written back over itself, so the program holds one copy of a
+    # tree. Handed to the scan as stacked inputs and outputs they would
+    # be two, and the records plane of 2^21 messages is 8 GiB: a second
+    # copy does not fit the chip beside the state (PERF.md section 6,
+    # PR 36). Slots are cut from the flat slot planes, rows from the
+    # value plane.
+    def cut(plane, i, width):
+        return jax.lax.dynamic_slice_in_dim(plane, i * U32(width), width)
+
+    def paste(plane, i, width, chunk):
+        return jax.lax.dynamic_update_slice_in_dim(
+            plane, chunk, i * U32(width), 0)
+
+    def scan_body(carry, i):
+        acc, idx_p, val_p, leaf_p = carry
+        bid = base + i * U32(rpc) + jnp.arange(rpc, dtype=U32)
+        ix = cut(idx_p, i, rpc * z).reshape(rpc, z)
+        vl = cut(val_p, i, rpc)
+        ep = cut(oram.nonces, i, rpc)
         if cfg.encrypted:
             ks = row_keystream(
                 oram.cipher_key, bid, ep, cfg.row_words, cfg.cipher_rounds
             )
             ix = ix ^ ks[:, :z]
             vl = vl ^ ks[:, z:]
-        carry, (ix, vl) = body(carry, (ix, vl))
+        acc, (ix, vl) = body(acc, (ix, vl))
         if cfg.encrypted:
             epn = jnp.broadcast_to(oram.epoch[None, :], (rpc, 2))
             ks = row_keystream(
@@ -161,22 +169,27 @@ def _chunked_tree_sweep(cfg: OramConfig, oram: OramState, carry0, body,
                 # offset by n_buckets_padded (path_oram.leaf_plane_cipher
                 # domain separation)
                 boff = bid + U32(cfg.n_buckets_padded)
+                lf = cut(leaf_p, i, rpc * z).reshape(rpc, z)
                 lf = lf ^ row_keystream(
                     oram.cipher_key, boff, ep, z, cfg.cipher_rounds
                 )
                 lf = lf ^ row_keystream(
                     oram.cipher_key, boff, epn, z, cfg.cipher_rounds
                 )
-        return carry, (ix, vl, lf)
+                leaf_p = paste(leaf_p, i, rpc * z, lf.reshape(-1))
+        idx_p = paste(idx_p, i, rpc * z, ix.reshape(-1))
+        val_p = paste(val_p, i, rpc, vl)
+        return (acc, idx_p, val_p, leaf_p), None
 
-    carry, (idx_o, val_o, leaf_o) = jax.lax.scan(
-        scan_body, carry0, (bids, idx3, val3, eps, leaf3)
+    # the leaf plane rides along only where it is re-keyed
+    leaf0 = oram.tree_leaf if recrypt_leaf else jnp.zeros((0,), U32)
+    (carry, idx_o, val_o, leaf_o), _ = jax.lax.scan(
+        scan_body, (carry0, oram.tree_idx, oram.tree_val, leaf0),
+        jnp.arange(nch, dtype=U32),
     )
-    new = oram._replace(
-        tree_idx=idx_o.reshape(-1), tree_val=val_o.reshape(n, z * v)
-    )
+    new = oram._replace(tree_idx=idx_o, tree_val=val_o)
     if recrypt_leaf:
-        new = new._replace(tree_leaf=leaf_o.reshape(-1))
+        new = new._replace(tree_leaf=leaf_o)
     if cfg.encrypted:
         new = new._replace(
             nonces=jnp.broadcast_to(oram.epoch[None, :], oram.nonces.shape),

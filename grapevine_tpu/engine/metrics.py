@@ -116,6 +116,24 @@ class EngineMetrics:
             "of those rows, the ones at levels the batch does not cover "
             "(one per path and level below the dense ones): 0 says the "
             "round moves its tree whole, level by level", labels=trees)
+        self._g_state_init = r.gauge(
+            "grapevine_state_init_seconds",
+            "wall time the engine took to build its state on the device "
+            "(both trees zeroed, position maps drawn; before any "
+            "recovery), the host span grapevine/state_init")
+        self._g_state_bytes = r.gauge(
+            "grapevine_state_bytes",
+            "bytes of the engine's device-resident state, summed over "
+            "its arrays (over all shards on a mesh)")
+        self._g_hbm_peak = r.gauge(
+            "grapevine_hbm_peak_bytes",
+            "the device's peak bytes in use since the process started "
+            "(memory_stats; on a mesh the chip with the largest peak); "
+            "sampled with the stashes, 0 where the backend reports none")
+        self._g_hbm_limit = r.gauge(
+            "grapevine_hbm_limit_bytes",
+            "the bytes that device lets the process use (memory_stats "
+            "bytes_limit): what grapevine_hbm_peak_bytes is a share of")
         self._h_phase = r.histogram(
             "grapevine_phase_seconds",
             "wall time per round phase (batch-level; obs/phases.py)",
@@ -152,6 +170,21 @@ class EngineMetrics:
             self._g_dense.set(dense, tree=tree)
             self._g_rows.set(rows, tree=tree)
             self._g_perpath.set(perpath, tree=tree)
+
+    def set_state_size(self, init_seconds: float, nbytes: int) -> None:
+        """Static per engine, like the round layout: what building the
+        state took and what it holds."""
+        self._g_state_init.set(init_seconds)
+        self._g_state_bytes.set(nbytes)
+
+    def observe_device_memory(self, stats) -> None:
+        """``memory_stats()`` of each device the state lives on: the
+        one with the largest peak is the one that runs out first."""
+        peak, limit = max(
+            ((s.get("peak_bytes_in_use", 0), s.get("bytes_limit", 0))
+             for s in stats), default=(0, 0))
+        self._g_hbm_peak.set(peak)
+        self._g_hbm_limit.set(limit)
 
     def record_sweep(self, evicted: int) -> None:
         self._c_sweeps.inc()

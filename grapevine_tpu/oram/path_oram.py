@@ -70,6 +70,14 @@ MAX_U32_HEIGHT = 29
 MAX_U32_BLOCKS = 1 << 30
 
 
+#: the most one round may add to a tree's sticky ``overflow`` counter,
+#: as :func:`RANGELINT_BOUNDS` declares it. A round can drop no more
+#: rows than its working set holds: 86,012 by rangelint's trace at
+#: B = 2048 and 2^21 messages (tests/test_rangelint.py holds the
+#: served geometries to it); 2^24 covers B = 2^16 on a 30-level tree.
+OVERFLOW_ROUND_BUDGET = 1 << 24
+
+
 def RANGELINT_BOUNDS(cfg: "OramConfig", prefix: str = "state") -> dict:
     """Rangelint input-interval anchors (analysis/rangelint.py) for one
     ``OramState`` pytree under ``prefix`` — the declared invariants of
@@ -92,9 +100,9 @@ def RANGELINT_BOUNDS(cfg: "OramConfig", prefix: str = "state") -> dict:
         f"{prefix}.stash_leaf": (0, lv),
         f"{prefix}.cache_leaf": (0, lv),
         # sticky diagnostic counter with a declared per-run increment
-        # budget (2^16 ≫ any round's possible drops): the budgeted
-        # headroom is what certifies `overflow + dropped` wrap-free
-        f"{prefix}.overflow": (0, 2**32 - 2**16),
+        # budget: the budgeted headroom is what certifies
+        # `overflow + dropped` wrap-free
+        f"{prefix}.overflow": (0, 2**32 - OVERFLOW_ROUND_BUDGET),
     }
     if not cfg.encrypted:
         # plaintext trees carry their leaf metadata un-ciphered
@@ -111,7 +119,7 @@ def RANGELINT_BOUNDS(cfg: "OramConfig", prefix: str = "state") -> dict:
         b[f"{inner}.posmap"] = (0, icfg.leaves - 1)
         b[f"{inner}.stash_val"] = (0, lv)
         b[f"{inner}.cache_val"] = (0, lv)
-        b[f"{inner}.overflow"] = (0, 2**32 - 2**16)
+        b[f"{inner}.overflow"] = (0, 2**32 - OVERFLOW_ROUND_BUDGET)
         if not icfg.encrypted:
             b[f"{inner}.tree_val"] = (0, lv)
         b[f"{prefix}.posmap.dummy_entry"] = (0, lv)

@@ -312,6 +312,8 @@ def RANGELINT_BOUNDS(cfg, prefix: str = "pm_state") -> dict:
     if cfg.posmap is None:
         b[prefix] = (0, lv)
     else:
+        from .path_oram import OVERFLOW_ROUND_BUDGET
+
         icfg = inner_oram_config(cfg.posmap)
         il = icfg.leaves - 1
         b["pm_new_leaves"] = (0, il)
@@ -319,7 +321,7 @@ def RANGELINT_BOUNDS(cfg, prefix: str = "pm_state") -> dict:
         b[f"{prefix}.inner.posmap"] = (0, il)
         b[f"{prefix}.inner.stash_val"] = (0, lv)
         b[f"{prefix}.inner.cache_val"] = (0, lv)
-        b[f"{prefix}.inner.overflow"] = (0, 2**32 - 2**16)
+        b[f"{prefix}.inner.overflow"] = (0, 2**32 - OVERFLOW_ROUND_BUDGET)
         if not icfg.encrypted:
             b[f"{prefix}.inner.tree_val"] = (0, lv)
         b[f"{prefix}.dummy_entry"] = (0, lv)

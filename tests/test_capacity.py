@@ -80,12 +80,11 @@ def test_pod_capacity_geometry():
     assert ecfg.rec.n_buckets * ecfg.rec.bucket_slots >= 1 << 24
 
 
-def test_the_2p16_recipient_deployment_resolves_to_what_its_file_says():
-    """``benchmarks/configs/chipshare-2p20-r2p16.json`` (the bus whose
-    mailboxes can fill its message store) states what its
-    ``grapevine_config`` resolves to; shapes only, no tree allocated:
-    a mailbox tree of 15 levels of which 4,096 accesses a pass cover
-    13, so 8,192 of its 16,368 rows a pass are per-path rows."""
+def _held_to_its_file(config_name: str):
+    """One ``benchmarks/configs/<name>.json`` and what its
+    ``grapevine_config`` resolves to, shapes only (no tree allocated):
+    both trees' round layout is held to the file's ``resolves_to``.
+    Returns ``(spec, cfg, ecfg, state shapes, state bytes)``."""
     import json
 
     import jax
@@ -94,23 +93,36 @@ def test_the_2p16_recipient_deployment_resolves_to_what_its_file_says():
 
     path = os.path.join(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))), "benchmarks", "configs",
-        "chipshare-2p20-r2p16.json")
+        f"{config_name}.json")
     with open(path) as f:
         spec = json.load(f)
-    assert spec["grapevine_config"] == {
-        "max_messages": 1 << 20, "max_recipients": 1 << 16,
-        "batch_size": 2048, "tree_density": 2}
     cfg = GrapevineConfig(**spec["grapevine_config"])
     ecfg = EngineConfig.from_config(cfg)
-    said = spec["resolves_to"]
     for tree, oram, accesses in (
             ("records", ecfg.rec, cfg.batch_size),
             ("mailbox", ecfg.mb, cfg.batch_size * ecfg.mb_choices)):
-        want = said[tree]
+        want = spec["resolves_to"][tree]
         assert oram.path_len == want["path_len"]
         assert oram.dense_levels(accesses) == want["dense_levels"]
         assert oram.fetched_bucket_rows(accesses) == want["fetched_bucket_rows"]
         assert oram.perpath_bucket_rows(accesses) == want["perpath_bucket_rows"]
+    state = jax.eval_shape(lambda: init_engine(ecfg, 0))
+    nbytes = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(state))
+    return spec, cfg, ecfg, state, nbytes
+
+
+def test_the_2p16_recipient_deployment_resolves_to_what_its_file_says():
+    """``benchmarks/configs/chipshare-2p20-r2p16.json`` (the bus whose
+    mailboxes can fill its message store) states what its
+    ``grapevine_config`` resolves to; shapes only, no tree allocated:
+    a mailbox tree of 15 levels of which 4,096 accesses a pass cover
+    13, so 8,192 of its 16,368 rows a pass are per-path rows."""
+    spec, cfg, ecfg, _, state_bytes = _held_to_its_file(
+        "chipshare-2p20-r2p16")
+    assert spec["grapevine_config"] == {
+        "max_messages": 1 << 20, "max_recipients": 1 << 16,
+        "batch_size": 2048, "tree_density": 2}
+    said = spec["resolves_to"]
     mb, want = ecfg.mb, said["mailbox"]
     assert (mb.path_len, mb.dense_levels(4096)) == (15, 13)
     assert mb.n_buckets == want["buckets"] == 32767
@@ -118,12 +130,10 @@ def test_the_2p16_recipient_deployment_resolves_to_what_its_file_says():
     assert want["accesses_per_pass"] == 4096 < mb.leaves
     # index row, value row and nonce of one bucket at rest
     assert 4 * (mb.row_words + 2) == want["bucket_bytes"] == 24344
-    state = jax.eval_shape(lambda: init_engine(ecfg, 0))
     # the file's figure predates PR 30, which took four u32 scalars of
     # delayed-eviction book-keeping (two a tree) out of the state; the
     # file is the benchmark's, for a `benchmark` PR to bring up to date
-    assert sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(state)) \
-        == said["state_bytes"] - 16 == 5_127_466_608
+    assert state_bytes == said["state_bytes"] - 16 == 5_127_466_608
     assert cfg.mailbox_cap == spec["guarantees"]["mailbox_cap"] == 62
     assert spec["guarantees"]["max_recipients"] == cfg.max_recipients
     # its parent's mailbox tree is one the batch covers whole
@@ -131,6 +141,43 @@ def test_the_2p16_recipient_deployment_resolves_to_what_its_file_says():
         spec["grapevine_config"], max_recipients=1 << 12)))
     assert parent.mb.perpath_bucket_rows(4096) == 0
     assert parent.mb.fetched_bucket_rows(4096) == 2032
+
+
+def test_the_chips_real_share_resolves_to_what_its_file_says():
+    """``benchmarks/configs/chipshare-2p21-r2p17.json`` is one chip of
+    the v5e-8 bus at its own share (2^24 buckets over 8 chips), with
+    nothing cut; shapes only, no tree allocated. It is the shape no
+    smaller configuration has: nine per-path record levels under twelve
+    dense ones, three per-path mailbox levels, a records value plane of
+    exactly 2^31 words, 10.25 GB of state."""
+    spec, cfg, ecfg, state, state_bytes = _held_to_its_file(
+        "chipshare-2p21-r2p17")
+    assert spec["reduced"] == {} and spec["chips"] == 1
+    assert spec["grapevine_config"] == {
+        "max_messages": (1 << 24) // MESH, "max_recipients": 1 << 17,
+        "batch_size": 2048, "tree_density": 2}
+    said = spec["resolves_to"]
+    rec, mb = ecfg.rec, ecfg.mb
+    assert (rec.path_len, rec.dense_levels(2048)) == (21, 12)
+    assert rec.perpath_bucket_rows(2048) == 9 * 2048
+    assert (mb.path_len, mb.dense_levels(4096)) == (16, 13)
+    assert mb.perpath_bucket_rows(4096) == 3 * 4096
+    assert mb.n_buckets == said["mailbox"]["buckets"] == 65535
+    assert ecfg.mb_table_buckets == 1 << 16 and mb.leaves == 1 << 15
+    assert said["mailbox"]["accesses_per_pass"] == 4096 < mb.leaves
+    assert 4 * (mb.row_words + 2) == said["mailbox"]["bucket_bytes"] == 24344
+    # the first buffer of the program that holds 2^31 elements
+    assert state.rec.tree_val.shape == (1 << 21, 1024)
+    assert state.rec.tree_val.size == 1 << 31
+    assert state.mb.tree_val.shape == (1 << 16, 6080)
+    assert state_bytes == said["state_bytes"] == 10_253_823_600
+    g = spec["guarantees"]
+    assert cfg.mailbox_cap == g["mailbox_cap"] == 62
+    assert (g["max_messages"], g["max_recipients"]) == (
+        cfg.max_messages, cfg.max_recipients)
+    # recipients in its sibling's ratio, and the cap can fill the store
+    assert cfg.max_messages // cfg.max_recipients == (1 << 20) // (1 << 16)
+    assert (cfg.max_recipients // 2) * cfg.mailbox_cap >= cfg.max_messages
 
 
 def test_init_sharded_engine_matches_staged_init():

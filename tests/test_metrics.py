@@ -233,3 +233,69 @@ def test_stash_gauges_are_kept_per_tree():
         assert snap[f"grapevine_stash_high_water{{tree={tree}}}"] == n
         assert snap[f"grapevine_stash_occupancy{{tree={tree}}}_count"] == 1
     assert snap["grapevine_stash_occupancy{tree=mb_pm}_count"] == 0
+
+
+def test_state_size_and_device_memory_gauges():
+    """What building the state took and what it holds are set once, at
+    construction; the device's peak and limit are sampled with the
+    stashes (health and scrape cadence, never per round) from
+    ``memory_stats()``, and read 0 on a backend that reports none."""
+    import jax
+
+    m = EngineMetrics()
+    m.observe_device_memory([{"peak_bytes_in_use": 5, "bytes_limit": 16},
+                             {"peak_bytes_in_use": 9, "bytes_limit": 12},
+                             {}])
+    reg = m.registry
+    # the chip with the largest peak, and that chip's limit
+    assert reg.get("grapevine_hbm_peak_bytes").get() == 9
+    assert reg.get("grapevine_hbm_limit_bytes").get() == 12
+    m.observe_device_memory(iter(()))
+    assert reg.get("grapevine_hbm_peak_bytes").get() == 0
+
+    cfg = GrapevineConfig(
+        bucket_cipher_rounds=0, max_messages=64, max_recipients=16,
+        mailbox_cap=4, batch_size=4, stash_size=96,
+    )
+    e = GrapevineEngine(cfg, seed=1)
+    reg = e.metrics.registry
+    assert reg.get("grapevine_state_bytes").get() == sum(
+        x.nbytes for x in jax.tree.leaves(e.state)) > 0
+    assert 0 < reg.get("grapevine_state_init_seconds").get() < 60
+    e.health()  # the CPU backend reports no memory statistics
+    want = e.state.rec.tree_val.devices().pop().memory_stats() or {}
+    assert reg.get("grapevine_hbm_peak_bytes").get() == want.get(
+        "peak_bytes_in_use", 0)
+    assert reg.get("grapevine_hbm_limit_bytes").get() == want.get(
+        "bytes_limit", 0)
+    for name in ("grapevine_state_bytes", "grapevine_state_init_seconds",
+                 "grapevine_hbm_peak_bytes", "grapevine_hbm_limit_bytes"):
+        assert reg.get(name).label_keys == ()
+    assert reg.audit()["ok"]
+
+
+def test_state_init_is_a_host_span_of_a_capture(tmp_path):
+    """``grapevine/state_init`` is a TraceAnnotation around the state's
+    building: a profiler capture that covers an engine's construction
+    holds it among its host spans."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    cfg = GrapevineConfig(
+        bucket_cipher_rounds=0, max_messages=64, max_recipients=16,
+        mailbox_cap=4, batch_size=4, stash_size=96,
+    )
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        GrapevineEngine(cfg, seed=1)
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                          / "*.xplane.pb"))
+    names = {ev.name for plane in ProfileData.from_file(path).planes
+             for line in plane.lines for ev in line.events}
+    assert "grapevine/state_init" in names

@@ -380,6 +380,74 @@ def test_full_certification_at_max_certified_geometry():
     assert gate.main(["--geometry", "36"]) == 0
 
 
+def _served_ecfg(config_name: str):
+    """The engine geometry of one benchmark configuration file, with
+    the value-phase knob a TPU resolves (``dense``; a CPU's auto is
+    ``scan``), so that the lanes certified are the ones the chip runs."""
+    import json
+
+    from grapevine_tpu.config import GrapevineConfig
+    from grapevine_tpu.engine.state import EngineConfig
+
+    with open(os.path.join(REPO, "benchmarks", "configs",
+                           f"{config_name}.json")) as f:
+        spec = json.load(f)
+    return EngineConfig.from_config(GrapevineConfig(
+        **spec["grapevine_config"], vphases_impl="dense"))
+
+
+@pytest.mark.parametrize("config_name", [
+    "chipshare-2p20", "chipshare-2p20-r2p16", "chipshare-2p21-r2p17"])
+def test_a_served_geometry_certifies_at_its_own_batch(config_name):
+    """The one-chip deployments at B = 2048, traced, not compiled: the
+    round and the sweep are interval-clean under the production
+    allowlist. ``chipshare-2p21-r2p17`` is the chip's whole share of
+    the bus (2^21 messages, 2^17 recipients): its records value plane
+    holds 2^31 words. Before PR 36 the gate traced B = 4 only, and the
+    declared per-round budget of the ``overflow`` counter (2^16) was
+    smaller than the rows a B = 2048 round could drop."""
+    import check_ranges as gate
+
+    ecfg = _served_ecfg(config_name)
+    assert ecfg.batch_size == 2048
+    for audit in (gate.audit_engine_round, gate.audit_expiry_sweep):
+        rep = audit(ecfg, RANGE_ALLOWLIST, config_name)
+        assert rep.ok, rep.summary()
+        assert rep.n_eqns > 1000
+
+
+def test_overflow_mutants_keep_their_teeth_at_the_chips_real_share():
+    """At 2^21 messages a records value row is 4,096 B and the plane
+    2^21 rows: the byte-size product in int32 is the seeded mutant that
+    this geometry makes real, and it must still be caught there, as
+    must a round whose ``overflow`` counter is given no headroom."""
+    import check_ranges as gate
+    from grapevine_tpu.analysis import mutants
+    from grapevine_tpu.engine import round_step
+    from grapevine_tpu.engine.state import init_engine
+
+    ecfg = _served_ecfg("chipshare-2p21-r2p17")
+    rec = ecfg.rec
+    assert rec.n_buckets_padded == 1 << 21
+    assert rec.n_buckets_padded * rec.bucket_slots * rec.value_words == 1 << 31
+    builder, kind = mutants._RANGE_REGISTRY["int32_byte_size_product"]
+    fn, args, _ = builder()
+    assert 4 * rec.bucket_slots * rec.value_words == 4096  # fn's factor
+    rep = analyze_ranges(fn, args, {"rows": (0, rec.n_buckets_padded)},
+                         allowlist=RANGE_ALLOWLIST)
+    assert kind in _kinds(rep)
+    # the lane this geometry found: the sticky counter with the old
+    # 2^16 of headroom under a round that can drop 86,012 rows
+    bounds = dict(round_step.RANGELINT_BOUNDS(ecfg))
+    bounds["state.rec.overflow"] = (0, 2**32 - 2**16)
+    state = jax.eval_shape(lambda: init_engine(ecfg, 0))
+    rep = analyze_ranges(
+        lambda st, ba: round_step.engine_round_step(ecfg, st, ba),
+        {"state": state, "batch": gate._batch_spec(ecfg)},
+        bounds=bounds, allowlist=RANGE_ALLOWLIST)
+    assert [f.kind for f in rep.findings] == ["overflow"]
+
+
 @pytest.mark.slow
 def test_full_knob_cross_product():
     import check_ranges as gate
