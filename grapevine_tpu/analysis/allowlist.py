@@ -72,13 +72,18 @@ _ORAM_CORE = (
        "sorted dedup: permutation/boundary gathers over fixed [B] "
        "arrays — oblivious-sort data movement, schedule fixed by B"),
     _A("gather", "oram/round.py:_assign_evictions",
-       "eviction assignment: sort-permutation and bucket-map gathers "
-       "over the fixed working set — oblivious permutation plumbing "
-       "(bucket -> output row over [W]: covered buckets their own id "
-       "without a lookup, deeper buckets their owner copy)"),
+       "eviction assignment: the bucket-map lookup of a per-path level "
+       "and nothing else — one fixed [W]-shaped read of the private "
+       "owner map per level under the covered ones (bucket -> output "
+       "row: covered buckets are their own id without a lookup, deeper "
+       "buckets their owner copy); ranks come from scans and the "
+       "sorted keys from the sort's own payload, never from a gather "
+       "(the radix sort path, which returns a permutation only, reads "
+       "its keys through it once: permutation plumbing over [W])"),
     _A("scatter", "oram/round.py:_assign_evictions",
-       "eviction assignment: inverse-permutation scatters over the "
-       "fixed working set — every row written exactly once per pass"),
+       "eviction assignment: the one inverse-permutation scatter back "
+       "to working-set order — every row of the fixed working set "
+       "written exactly once"),
 )
 
 #: position-map resolution (flat table and recursive internal ORAM)

@@ -632,7 +632,7 @@ class CostLedger:
 
 
 def _round_sort_keys(cfg, b: int, sort_impl: str, occ_impl: str) -> int:
-    """Sort key-volume of one oram_round: the eviction leaf argsort over
+    """Sort key-volume of one oram_round: the eviction leaf sort over
     the working set plus the dedup group sorts under the scan occurrence
     machinery, composed recursively for the internal map round."""
     z = cfg.bucket_slots

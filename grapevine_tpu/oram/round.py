@@ -41,9 +41,17 @@ PAPERS.md and SURVEY.md §7 "hard parts" 6):
    entry to the deepest fetched bucket on its own path — every bucket of
    a level the batch covers, the buckets the B paths meet below — jointly (an
    entry's path meets each level in exactly one bucket, so levels
-   vectorize with no conflicts). Leftovers recompact into the stash; one
-   scatter writes the dense range and all owned buckets back (write
-   transcript ≡ read transcript).
+   vectorize with no conflicts). The working set is sorted by leaf once
+   (keys and permutation from the one sort), so at every level a
+   bucket's candidates are a contiguous run, and an entry's rank within
+   its bucket is formed by scans alone: the running count of eligible
+   entries (a cumsum) less that count at the run's first row (a running
+   max over the run boundaries — the count never falls). The first Z
+   ranks of a bucket take its slots. The only per-entry read is a
+   per-path level's lookup of its bucket's output row; one scatter takes
+   the slots back to working-set order. Leftovers recompact into the
+   stash; one scatter writes the dense range and all owned buckets back
+   (write transcript ≡ read transcript).
 
 Net effect per round: 2 large HBM transfers (gather + scatter) per tree
 array instead of 2·B small dependent ones, with all decision logic in
@@ -186,37 +194,50 @@ def _assign_evictions(
     (`_bucket_owner_map`); at the ``dense_levels`` — the levels the
     batch covers — every bucket is a target and its own output row, so
     those levels skip the lookup.
+
+    No per-element gather that a scan or the sort can stand in for: on
+    a v5e a [W] gather or scatter costs ~7 ns an element, serial — 0.56
+    ms at W = 84,060, where a level's two scans and all its elementwise
+    work are 2 us and the whole sort 0.09 ms (PERF.md §5, PR 38). So the
+    sorted keys are the sort's own first operand, validity is read off
+    them, a level's rank base is a running max, not a read at each
+    row's segment start, and ``placed`` is read off the one array that
+    is scattered back.
     """
     h, z = cfg.height, cfg.bucket_slots
     w = valid.shape[0]
     nslots = n_rows * z
-    skey = jnp.where(valid, wleaf, U32(0xFFFFFFFF))
+    no_leaf = U32(0xFFFFFFFF)  # sorts after every leaf: invalid rows go last
+    skey = jnp.where(valid, wleaf, no_leaf)
     with device_phase("oram_evict_sort"):
         if sort_impl == "radix":
             # leaves are h bits; invalid rows sort last under the 2^h
             # sentinel exactly as they do under 0xFFFFFFFF (both stable
             # sorts keep equal keys in working-set order), so the
-            # permutation is bit-identical to the argsort — at h+1
-            # declared key bits instead of a 32-bit comparison sort
+            # permutation is bit-identical to the comparison sort's — at
+            # h+1 declared key bits instead of 32. A rank pass returns
+            # the permutation alone, so the keys are read through it
             eperm = radix_rank(
                 jnp.where(valid, wleaf, U32(1) << U32(h)), h + 1
             )
+            sleaf = skey[eperm]
         else:
-            eperm = jnp.argsort(skey)
-    sleaf = skey[eperm]
-    svalid = valid[eperm]
-    iota_w = jnp.arange(w, dtype=jnp.int32)
+            # the keys and the permutation from one stable sort (what
+            # jnp.argsort runs, which then drops the sorted keys)
+            sleaf, eperm = jax.lax.sort(
+                (skey, jnp.arange(w, dtype=jnp.int32)), num_keys=1
+            )
+    svalid = sleaf != no_leaf  # a live row's leaf is < cfg.leaves
     placed = jnp.zeros((w,), jnp.bool_)  # sorted order
     slot_tgt_s = jnp.full((w,), nslots, U32)  # sorted order; OOB = unplaced
-    # invalid rows carry the sort sentinel (0xFFFFFFFF / 2^h) in
-    # sleaf; clamp to the real leaf range BEFORE the heap-id
-    # arithmetic so `hb` provably fits u32 at every certified
-    # geometry (the unclamped sentinel wrapped hb mod 2^32 —
-    # harmless only because svalid masked those rows downstream;
-    # rangelint flags exactly that kind of masked wraparound).
-    # Clamped sentinel rows merge into the last real segment; they
-    # are a sorted suffix and never eligible, so real rows' segment
-    # starts and ranks are unchanged.
+    # invalid rows carry the sort sentinel 0xFFFFFFFF in sleaf; clamp
+    # to the real leaf range BEFORE the heap-id arithmetic so `hb`
+    # provably fits u32 at every certified geometry (the unclamped
+    # sentinel wrapped hb mod 2^32 — harmless only because svalid
+    # masked those rows downstream; rangelint flags exactly that kind
+    # of masked wraparound). Clamped sentinel rows merge into the last
+    # real segment; they are a sorted suffix and never eligible, so
+    # real rows' ranks are unchanged.
     bleaf = jnp.minimum(sleaf, U32(cfg.leaves - 1))
     for level in range(h, -1, -1):
         shift = U32(h - level)
@@ -227,7 +248,8 @@ def _assign_evictions(
             tgt = hb  # always fetched, and its own output row
         else:
             # one gather answers both "was my bucket fetched" (row !=
-            # n_rows) and which output row holds it
+            # n_rows) and which output row holds it — the one per-entry
+            # read a level has
             tgt = bucket_map[jnp.minimum(hb, U32(cfg.n_buckets_padded - 1))]
             elig = elig & (tgt != U32(n_rows))
         bnd = jnp.concatenate(
@@ -239,23 +261,24 @@ def _assign_evictions(
         ecum = jnp.concatenate(
             [jnp.zeros((1,), jnp.int32), jnp.cumsum(ei)[:-1]]
         )
-        start = jax.lax.cummax(jnp.where(bnd, iota_w, 0))  # my segment start
-        # exclusive rank within my bucket: >= 0 because ecum is
-        # monotone and start[i] <= i; the max states that invariant
-        # for interval reasoning (identity at runtime)
-        rank = jnp.maximum(ecum - ecum[start], 0)
+        # the count at my bucket's first row: ecum never falls and row 0
+        # is a boundary, so the latest boundary's count is the largest
+        # one so far — a running max, no read at a segment start
+        base = jax.lax.cummax(jnp.where(bnd, ecum, 0))
+        # exclusive rank within my bucket: >= 0 because base[i] is
+        # ecum at a row <= i; the max states that invariant for
+        # interval reasoning (identity at runtime)
+        rank = jnp.maximum(ecum - base, 0)
         chosen = elig & (rank < z)
         slot = tgt * U32(z) + rank.astype(U32)
         slot_tgt_s = jnp.where(chosen, slot, slot_tgt_s)
         placed = placed | chosen
-    # back to working-set order (a [W] scatter, so values need no permute)
+    # back to working-set order (a [W] scatter, so values need no
+    # permute); a row is placed iff it left with a slot
     slot_tgt = (
         jnp.full((w,), nslots, U32).at[eperm].set(slot_tgt_s, unique_indices=True)
     )
-    placed = (
-        jnp.zeros((w,), jnp.bool_).at[eperm].set(placed, unique_indices=True)
-    )
-    return slot_tgt, placed
+    return slot_tgt, slot_tgt != U32(nslots)
 
 
 def oram_round(
@@ -522,7 +545,7 @@ def oram_round(
             wleaf = working_leaves(posmap, cfg, widx)
 
     # --- 3. joint level-synchronous greedy eviction --------------------
-    # One argsort of the working set by leaf, then per level: entries
+    # One sort of the working set by leaf, then per level: entries
     # destined to one bucket are contiguous in sorted order (a bucket at
     # level L is a leaf prefix, and sorting by leaf sorts by every
     # prefix), so within-bucket ranks are segmented cumsums — O(W) work

@@ -17,13 +17,17 @@ import numpy as np
 
 from grapevine_tpu.config import GrapevineConfig
 from grapevine_tpu.engine.batcher import GrapevineEngine
+from grapevine_tpu.oblivious.radix import radix_rank
 from grapevine_tpu.oram.path_oram import (
     OramConfig,
     init_oram,
     oram_access_batch,
+    path_bucket_indices,
     stash_occupancy,
 )
 from grapevine_tpu.oram.round import (
+    _assign_evictions,
+    _bucket_owner_map,
     occurrence_masks,
     occurrence_masks_sorted,
     oram_round,
@@ -312,6 +316,148 @@ def test_occurrence_masks_sorted_bit_identical():
         np.testing.assert_array_equal(np.asarray(f1), np.asarray(f2), trial)
         np.testing.assert_array_equal(np.asarray(l1), np.asarray(l2), trial)
         np.testing.assert_array_equal(np.asarray(c1), np.asarray(c2), trial)
+
+
+def _assign_evictions_by_gathers(cfg, valid, wleaf, bucket_map, n_rows,
+                                 sort_impl, dense_levels):
+    """The placement as it stood before PR 38, kept as the reference
+    `_assign_evictions` is held to bit for bit: an argsort and two
+    gathers through its permutation, per level the rank base read back
+    by a gather at each row's segment start (``ecum[start]``), two
+    inverse-permutation scatters. Same greedy pass, same result; the
+    per-element gathers and the second scatter are what the round no
+    longer pays for."""
+    h, z = cfg.height, cfg.bucket_slots
+    w = valid.shape[0]
+    nslots = n_rows * z
+    skey = jnp.where(valid, wleaf, U32(0xFFFFFFFF))
+    if sort_impl == "radix":
+        eperm = radix_rank(jnp.where(valid, wleaf, U32(1) << U32(h)), h + 1)
+    else:
+        eperm = jnp.argsort(skey)
+    sleaf = skey[eperm]
+    svalid = valid[eperm]
+    iota_w = jnp.arange(w, dtype=jnp.int32)
+    placed = jnp.zeros((w,), jnp.bool_)
+    slot_tgt_s = jnp.full((w,), nslots, U32)
+    bleaf = jnp.minimum(sleaf, U32(cfg.leaves - 1))
+    for level in range(h, -1, -1):
+        bid = bleaf >> U32(h - level)
+        hb = (U32(1) << U32(level)) - U32(1) + bid
+        elig = svalid & ~placed
+        if level < dense_levels:
+            tgt = hb
+        else:
+            tgt = bucket_map[jnp.minimum(hb, U32(cfg.n_buckets_padded - 1))]
+            elig = elig & (tgt != U32(n_rows))
+        bnd = jnp.concatenate([jnp.ones((1,), jnp.bool_), bid[1:] != bid[:-1]])
+        ecum = jnp.concatenate(
+            [jnp.zeros((1,), jnp.int32), jnp.cumsum(elig.astype(jnp.int32))[:-1]]
+        )
+        start = jax.lax.cummax(jnp.where(bnd, iota_w, 0))
+        rank = jnp.maximum(ecum - ecum[start], 0)
+        chosen = elig & (rank < z)
+        slot_tgt_s = jnp.where(chosen, tgt * U32(z) + rank.astype(U32), slot_tgt_s)
+        placed = placed | chosen
+    slot_tgt = jnp.full((w,), nslots, U32).at[eperm].set(
+        slot_tgt_s, unique_indices=True
+    )
+    placed = jnp.zeros((w,), jnp.bool_).at[eperm].set(placed, unique_indices=True)
+    return slot_tgt, placed
+
+
+_EVICT_H = 4
+
+
+def _eviction_case(z, dense_levels, rows):
+    """(cfg, valid, wleaf, bucket_map, n_rows) of one placement: a
+    height-4 tree, six fetched paths under ``dense_levels`` levels held
+    whole (the owner map and output rows as `oram_round` lays them out
+    with no cache), and a 160-row working set of the named kind."""
+    cfg = OramConfig(height=_EVICT_H, value_words=1, bucket_slots=z)
+    rng = np.random.default_rng(38)
+    b, w, le = 6, 160, dense_levels
+    nd, nsp = (1 << le) - 1, cfg.path_len - le
+    n_rows = nd + b * nsp
+    path_leaves = rng.integers(0, cfg.leaves, b)
+    paths = jax.vmap(lambda lf: path_bucket_indices(cfg, lf))(
+        jnp.asarray(path_leaves, U32)
+    )
+    bucket_map = _bucket_owner_map(
+        cfg, paths[:, le:].reshape(-1),
+        U32(nd) + jnp.arange(b * nsp, dtype=U32), n_rows,
+    )
+    wleaf = rng.integers(0, cfg.leaves, w)
+    valid = rng.random(w) < 0.6
+    if rows == "one_leaf":
+        # duplicates of one leaf: one long run in sorted order, far more
+        # candidates than the Z slots of any bucket on that path
+        wleaf[: w // 2] = path_leaves[0]
+        valid[: w // 2] = True
+    elif rows == "crowded":
+        # every row live on four leaves: more than Z candidates a bucket
+        # at every level, the leaf level included
+        wleaf = rng.choice(path_leaves[:4], w)
+        valid[:] = True
+    elif rows == "none_valid":
+        valid[:] = False
+    elif rows == "all_valid":
+        valid[:] = True
+    else:
+        assert rows == "mixed", rows
+    return cfg, jnp.asarray(valid), jnp.asarray(wleaf, U32), bucket_map, n_rows
+
+
+@pytest.mark.parametrize("sort_impl", ["xla", "radix"])
+@pytest.mark.parametrize(
+    "rows", ["mixed", "one_leaf", "crowded", "none_valid", "all_valid"]
+)
+@pytest.mark.parametrize("z", [2, 4])
+@pytest.mark.parametrize("dense_levels", [0, 2, _EVICT_H + 1])
+def test_assign_evictions_bit_identical_to_gather_form(
+    dense_levels, z, rows, sort_impl
+):
+    """PR 38: ranks from scans alone, the sorted keys from the sort's
+    own payload and one back-scatter place every row exactly where the
+    gather formulation did: ``slot_tgt`` and ``placed`` bit for bit."""
+    cfg, valid, wleaf, bucket_map, n_rows = _eviction_case(z, dense_levels, rows)
+    args = (cfg, valid, wleaf, bucket_map, n_rows, sort_impl, dense_levels)
+    want_tgt, want_placed = _assign_evictions_by_gathers(*args)
+    got_tgt, got_placed = _assign_evictions(*args)
+    np.testing.assert_array_equal(np.asarray(got_tgt), np.asarray(want_tgt))
+    np.testing.assert_array_equal(np.asarray(got_placed), np.asarray(want_placed))
+    # the case is the one its name says, and the placement is a placement
+    n_valid, n_placed = int(valid.sum()), int(want_placed.sum())
+    tgt = np.asarray(want_tgt)[np.asarray(want_placed)]
+    assert len(set(tgt.tolist())) == n_placed and (tgt < n_rows * z).all()
+    if rows == "none_valid":
+        assert n_placed == 0
+    elif rows in ("crowded", "one_leaf"):
+        assert 0 < n_placed < n_valid  # some bucket was over-subscribed
+    if rows == "crowded" and dense_levels == _EVICT_H + 1:
+        # every bucket on the four leaves' paths is full at every level
+        assert n_placed % z == 0 and n_placed >= (cfg.path_len + 3) * z
+
+
+@pytest.mark.parametrize("dense_levels", [0, 2, _EVICT_H + 1])
+def test_assign_evictions_holds_no_gather_a_scan_can_replace(dense_levels):
+    """The traced placement (xla sort) holds exactly one ``gather`` per
+    per-path level, the ``bucket_map`` lookup, and none where every
+    level is dense; one ``scatter``, the inverse permutation of the
+    epilogue; one ``sort``. A per-element gather through the sort's
+    permutation or at a segment start cannot come back unnoticed."""
+    from grapevine_tpu.analysis.jaxpr_walk import census
+
+    cfg, valid, wleaf, bucket_map, n_rows = _eviction_case(4, dense_levels, "mixed")
+    counts = census(jax.make_jaxpr(
+        lambda v, lf, m: _assign_evictions(
+            cfg, v, lf, m, n_rows, "xla", dense_levels)
+    )(valid, wleaf, bucket_map))
+    assert counts["gather"] == cfg.path_len - dense_levels
+    assert counts["scatter"] == 1
+    assert counts["sort"] == 1
+    assert counts["cumsum"] == counts["cummax"] == cfg.path_len
+    assert not any(name.startswith("dynamic") for name in counts)
 
 
 # ---- phase-major engine vs oracle -------------------------------------
