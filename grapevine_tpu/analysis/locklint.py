@@ -182,7 +182,7 @@ class _MethodVisitor(ast.NodeVisitor):
             self.visit(stmt)
         for _ in lock_items:
             self.held.pop()
-        # visit the context expressions too (e.g. time_phase(...) calls)
+        # visit the context expressions too (e.g. metrics.span(...) calls)
         for item in node.items:
             if _self_attr(item.context_expr) not in self.cls.locks:
                 self.visit(item.context_expr)

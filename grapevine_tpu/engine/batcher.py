@@ -40,7 +40,7 @@ import jax
 import numpy as np
 
 from ..config import DurabilityConfig, GrapevineConfig
-from ..obs.phases import trace_span
+from ..obs.phases import PHASES, trace_span
 from ..testing import faults
 from ..wire import constants as C
 from ..wire.records import QueryRequest, QueryResponse, Record
@@ -188,7 +188,7 @@ class PendingRound:
         self._qdepth = int(depth)
 
     def note_span(self, name: str, start_s: float, dur_s: float) -> None:
-        """Add a collector-side span (assembly/verify) to this round's
+        """Add a collector-side span (assembly/verify/stage) to this round's
         ledger — exact pairing even under the pipelined scheduler, where
         a staged hand-off would attach round k+1's window to round k.
         Must be called before ``resolve()``."""
@@ -208,45 +208,86 @@ class PendingRound:
         """Add the scheduler's ``settle`` span, which ends after
         ``resolve()`` recorded the ledger: the recorded round is amended
         by its seq, so there is one ledger per round, still."""
+        self._amend({"settle": (start_s, dur_s)})
+
+    def note_cycle(self, start_s: float, dur_s: float, counts: dict) -> None:
+        """Add the collector's ``cycle`` this round was dispatched in,
+        and the cycle's counts. The cycle ends at the top of the
+        collector's next pass: before ``resolve()`` at depth 2 (noted on
+        the handle), after it at depth 1 (the recorded round is
+        amended)."""
+        if self._seq is None:
+            self.note_span("cycle", start_s, dur_s)
+            self.note_counts(**counts)
+        else:
+            self._amend({"cycle": (start_s, dur_s)}, counts)
+
+    def _amend(self, spans: dict, counts: dict | None = None) -> None:
         tracer = self._engine.tracer
         if tracer is not None and self._seq is not None:
-            tracer.amend_round(self._seq, {"settle": (start_s, dur_s)})
+            tracer.amend_round(self._seq, spans, counts)
 
     def ready(self) -> bool:
         """True once the device has finished this round, so that
         ``resolve()`` would not wait for it; never blocks. What the
         scheduler polls while it holds a short queue behind this
         round."""
-        return not _still_running(self._resp)
+        return self._resp is None or not _still_running(self._resp)
 
     def resolve(self) -> list[QueryResponse]:
-        eng = self._engine
-        m = eng.metrics
+        m = self._engine.metrics
         # "evict" = device round completion measured from the host: the
         # jit'd fetch/apply/evict/write-back program finishes inside this
         # wait (per-stage device splits come from a profiler capture
         # reduced by obs/phases.py DEVICE_SCOPES — the host cannot time
         # inside one XLA program)
         waited = _still_running(self._resp)
-        t_ev = time.perf_counter()
-        with m.time_phase("evict"):
+        spans = dict(self._spans or {})
+        with m.span("evict", spans):
             jax.block_until_ready(self._resp)
-        t_dm = time.perf_counter()
-        with m.time_phase("demux"):
+        with m.span("demux", spans):
             out = unpack_responses(self._resp, self._n)
-        t_done = time.perf_counter()
+        # everything below is the observability's own cost on the
+        # collector thread: one span, added to the recorded round by seq
+        with m.span("observe") as observed:
+            self._observe(spans, waited)
+        self._amend({"observe": (observed.start, observed.wall)})
+        return out
+
+    def release(self) -> None:
+        """Drop the round's device arrays (its answers are unpacked),
+        under the ``release`` span: the scheduler calls it where the
+        handle's last reference used to die (server/scheduler.py
+        ``_run_inner``), so the deletion has a name and no older span
+        or window changed its meaning for it. Each deletion lets go of
+        the GIL, and beside awake ingress threads the collector waits a
+        switch interval to get it back, several times a round: 16-17 ms
+        of a 70 ms cycle on the chip after the settle has woken them,
+        0.1 ms before it has (PERF.md §5-6)."""
+        with self._engine.metrics.span("release") as released:
+            self._resp = None
+        self._amend({"release": (released.start, released.wall)})
+
+    def _observe(self, spans: dict, waited: bool) -> None:
+        """Derive the round's windows from its spans and hand the round
+        to whatever watches rounds: metrics, the tracer's ring, the SLO,
+        workload and cost monitors, the leak monitor's queue."""
+        eng = self._engine
+        # observed ready: where the wait for the device ended
+        t_dm = sum(spans["evict"])
+        t_done = sum(spans["demux"])
         # recorded duration = dispatch → results delivered. Under the
         # pipelined scheduler this includes the next round's collection
         # window (resolve runs after the next dispatch), i.e. it is the
         # round *commit latency* a client observes, not pure device time
-        bs = self._engine.ecfg.batch_size
-        m.record_round(self._n, bs, t_done - self._t0)
-        spans = dict(self._spans or {})
-        spans["evict"] = (t_ev, t_dm - t_ev)
-        spans["demux"] = (t_dm, t_done - t_dm)
-        # the collection window opens the round; the queue wait of its
-        # oldest op may reach back before it and stays out of the span
-        r0 = min(s for k, (s, _) in spans.items() if k != "queue")
+        bs = eng.ecfg.batch_size
+        eng.metrics.record_round(self._n, bs, t_done - self._t0)
+        # the collection window opens the round (its dispatch, where no
+        # scheduler opened one); the queue wait of its oldest op may
+        # reach back before it and stays out of the span, and so do the
+        # collector's cycle and its look at the queue (``stage``), which
+        # come a little before the window
+        r0 = spans.get("assembly", spans["dispatch"])[0]
         # the two device windows (obs/tracer.py DERIVED_SPANS), emitted
         # on EVERY config so the trace JSON shape is stable. "inflight"
         # = async enqueue → readiness OBSERVED at resolve, the rounds
@@ -271,35 +312,34 @@ class PendingRound:
         if tracer is not None:
             # a few dict ops + schema check; the ring is lock-cheap
             self._seq = tracer.record_round(spans, counts)
-        slo = self._engine.slo
+        slo = eng.slo
         if slo is not None:
             # enqueue→settle commit latency, worst op in the batch: the
             # scheduler stamped the oldest op's enqueue; the direct path
             # anchors at dispatch start (no queue wait to account)
             slo.observe(t_done - (self._enq if self._enq is not None else r0))
-        wl = getattr(self._engine, "workload", None)
+        wl = getattr(eng, "workload", None)
         if wl is not None:
             # batch fill + dispatch-time backlog + per-phase utilization
             # from this round's span ledger (obs/workload.py) — a few
             # histogram/gauge samples on the collector thread
             wl.observe_round(self._n, bs, self._qdepth, spans)
-        cmn = getattr(self._engine, "costmon", None)
+        cmn = getattr(eng, "costmon", None)
         if cmn is not None:
             # device span vs the modeled roofline floor (obs/costmon.py)
             # — two gauge sets per round
             cmn.observe_round(spans)
-        lm = self._engine.leakmon
+        lm = eng.leakmon
         if lm is not None and self._transcript is not None:
             # one non-blocking queue put; detectors run on the monitor's
             # own thread (obs/leakmon.py), never on the round path.
-            # the derived windows and the scheduler's hold stay
-            # tracer-only — the flightrec phase schema is the canonical
-            # PHASES (+ round)
+            # the flightrec phase schema is the canonical PHASES
+            # (+ round): the derived windows and the collector's
+            # further spans stay tracer-only
             phases = {k: d for k, (_, d) in spans.items()
-                      if k not in ("device", "inflight", "queue", "hold")}
+                      if k in PHASES or k == "round"}
             lm.submit_round(self._batch, self._transcript, self._n, bs,
                             phases, queue_depth=self._qdepth)
-        return out
 
 
 def _still_running(resp) -> bool:
@@ -460,7 +500,7 @@ class GrapevineEngine:
                 durability, self.ecfg, registry=self.metrics.registry,
                 state_shardings=state_shardings,
             )
-            with self.metrics.time_phase("replay"):
+            with self.metrics.span("replay"):
                 self.state = self.durability.recover(
                     self.state, self._replay_record
                 )
@@ -487,7 +527,7 @@ class GrapevineEngine:
         if self.durability is None:
             return None
         with self._lock:
-            with self.metrics.time_phase("checkpoint"):
+            with self.metrics.span("checkpoint"):
                 return self.durability.checkpoint(self.state)
 
     def attach_leakmon(self, monitor) -> None:
@@ -590,10 +630,8 @@ class GrapevineEngine:
         device execution instead of serializing with it — the PR-10
         point; the "journal" series isolates what it costs."""
         if self.durability is not None:
-            t_j0 = time.perf_counter()
-            with self.metrics.time_phase("journal"):
+            with self.metrics.span("journal", spans):
                 self.durability.append_round(batch, n_real)
-            spans["journal"] = (t_j0, time.perf_counter() - t_j0)
         if faults.active():
             # the pipelined crash window: this round is durable (its
             # frame is fsynced) but not yet dispatched, while the
@@ -627,38 +665,39 @@ class GrapevineEngine:
         dispatch/compute overlap PERF.md's cost model calls for).
         Rounds are serialized by the engine lock; ``resolve()`` blocks
         for the results."""
-        batch = self._assemble_round(reqs, now)
+        span = self.metrics.span
+        spans: dict = {}
+        with span("pack", spans):
+            batch = self._assemble_round(reqs, now)
         lm = self.leakmon
         with self._lock:
             # "dispatch" = async device enqueue (JAX returns at
             # enqueue; the device round itself lands in "evict"); the
-            # host pack now runs in stage 1 OUTSIDE the lock, where the
-            # pipeline can overlap it. With durability on, dispatch
-            # also spans the journal barrier — append-before-dispatch
-            # is the crash-safety contract, and its fsync is genuinely
-            # part of the commit latency (the "journal" series
-            # isolates it).
-            t_d0 = time.perf_counter()
-            spans: dict = {}
+            # host pack runs in stage 1 OUTSIDE the lock, where the
+            # pipeline can overlap it, under its own span. With
+            # durability on, dispatch also spans the journal barrier —
+            # append-before-dispatch is the crash-safety contract, and
+            # its fsync is genuinely part of the commit latency (the
+            # "journal" series isolates it) — and the checkpoint when
+            # one falls due.
             behind_other = self._other_device_work
             self._other_device_work = False
-            with self.metrics.time_phase("dispatch"):
+            with span("dispatch", spans):
                 self._journal_round(batch, len(reqs), spans)
                 t0, t1, resp, transcript = self._dispatch_round(batch)
-            if faults.active():
-                faults.crash("round.post_dispatch")
-            if self.durability is not None and self.durability.should_checkpoint():
-                # blocks this round's slot until the sealed state is on
-                # disk — the RTO/RPO trade --checkpoint-every-rounds
-                # buys. state_to_bytes waits for every dispatched round
-                # (this one included), so the sealed state is exactly
-                # the journal's seq even with the pipeline full — the
-                # checkpoint is itself a pipeline barrier.
-                t_c0 = time.perf_counter()
-                with self.metrics.time_phase("checkpoint"):
-                    self.durability.checkpoint(self.state)
-                spans["checkpoint"] = (t_c0, time.perf_counter() - t_c0)
-            spans["dispatch"] = (t_d0, time.perf_counter() - t_d0)
+                if faults.active():
+                    faults.crash("round.post_dispatch")
+                if (self.durability is not None
+                        and self.durability.should_checkpoint()):
+                    # blocks this round's slot until the sealed state is
+                    # on disk — the RTO/RPO trade
+                    # --checkpoint-every-rounds buys. state_to_bytes
+                    # waits for every dispatched round (this one
+                    # included), so the sealed state is exactly the
+                    # journal's seq even with the pipeline full — the
+                    # checkpoint is itself a pipeline barrier.
+                    with span("checkpoint", spans):
+                        self.durability.checkpoint(self.state)
         if lm is None:
             return PendingRound(self, resp, len(reqs), t0, spans=spans, t1=t1,
                                 behind_other=behind_other)
@@ -704,7 +743,7 @@ class GrapevineEngine:
                     int(now) & 0xFFFFFFFF, (int(now) >> 32) & 0xFFFFFFFF,
                     int(period),
                 )
-            with self.metrics.time_phase("sweep"):
+            with self.metrics.span("sweep"):
                 self.state = self._sweep(
                     self.ecfg,
                     self.state,
@@ -720,7 +759,7 @@ class GrapevineEngine:
                 # sweeps count against the cadence like rounds do — an
                 # idle server with expiry on must not grow the journal
                 # (and its replay-time RTO) without bound
-                with self.metrics.time_phase("checkpoint"):
+                with self.metrics.span("checkpoint"):
                     self.durability.checkpoint(self.state)
             return evicted
 

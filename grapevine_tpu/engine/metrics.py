@@ -40,7 +40,7 @@ import time
 
 import numpy as np
 
-from ..obs.phases import PHASE_BUCKETS, PHASES, STASH_BUCKETS, phase_timer
+from ..obs.phases import PHASE_BUCKETS, PHASES, STASH_BUCKETS, span
 from ..obs.registry import TelemetryRegistry
 
 
@@ -202,9 +202,12 @@ class EngineMetrics:
     def observe_phase(self, phase: str, seconds: float) -> None:
         self._h_phase.observe(seconds, phase=phase)
 
-    def time_phase(self, phase: str):
-        """Context manager timing one host-side phase (+ profiler span)."""
-        return phase_timer(self._h_phase, phase)
+    def span(self, name: str, ledger: dict | None = None):
+        """The host side's one span primitive (obs/phases.py ``span``)
+        with this registry's ``grapevine_phase_seconds`` behind it.
+        ``ledger`` is the round's span dict where the span belongs to a
+        round; a sweep, a replay or a forced checkpoint has none."""
+        return span(name, ledger, self._h_phase)
 
     def observe_queue_depth(self, depth: int) -> None:
         self._g_qdepth.set(depth)

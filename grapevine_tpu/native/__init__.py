@@ -136,7 +136,8 @@ def _bind(handle):
     handle.r255_verify1.argtypes = [ctypes.c_char_p] * 4
     handle.r255_round_check.restype = ctypes.c_int
     handle.r255_round_check.argtypes = (
-        [ctypes.c_size_t] * 2 + [ctypes.c_char_p] * 7)
+        [ctypes.c_size_t] * 2 + [ctypes.c_char_p] * 7
+        + [ctypes.POINTER(ctypes.c_double)])
     handle.r255_chunk_scalars.restype = ctypes.c_int
     handle.r255_chunk_scalars.argtypes = (
         [ctypes.c_size_t] + [ctypes.c_char_p] * 7
@@ -217,11 +218,28 @@ def chunk_check(pubs, sigs, rand: bytes, *, prefix: bytes | None = None,
 
     No module lock, and the GIL is released for the length of the call:
     a chunk check writes only its own stack and heap arena, so any
-    number of calls and chunks run side by side."""
-    args = _chunk_args(pubs, sigs, rand, prefix, msgs, ks)
+    number of calls and chunks run side by side.
+
+    Two spans of the calling thread (obs/phases.py): ``verify_prep``,
+    the arguments joined under the GIL, and ``verify_native``, the
+    foreign call itself, which is also told the call's own seconds so
+    that the wait to get the GIL back is known. Inside a scheduler's
+    ``verify`` they land in that round's ledger; elsewhere they cost two
+    clock reads each and record nothing."""
+    # imported here: a client process that only signs never loads the
+    # observability package
+    from ..obs.phases import span
+
+    with span("verify_prep"):
+        args = _chunk_args(pubs, sigs, rand, prefix, msgs, ks)
     if args is None:
         return -1
-    return lib.r255_round_check(args[0], chunks, *args[1:])
+    elapsed = ctypes.c_double()
+    with span("verify_native") as crossing:
+        rc = lib.r255_round_check(args[0], chunks, *args[1:],
+                                  ctypes.byref(elapsed))
+        crossing.native_s = elapsed.value
+    return rc
 
 
 def chunk_scalars(pubs, sigs, rand: bytes, *, prefix: bytes | None = None,

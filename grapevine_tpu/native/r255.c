@@ -13,7 +13,8 @@
  * Exposed via ctypes (grapevine_tpu/native/__init__.py):
  *   r255_init()                     build the basepoint table (idempotent)
  *   r255_verify1(pub, R, s, k)      s*B == R + k*A          -> 1/0/-1
- *   r255_round_check(n, k, pubs, sigs, rand, prefix, msgs, mlens, ks)
+ *   r255_round_check(n, k, pubs, sigs, rand, prefix, msgs, mlens, ks,
+ *                    elapsed_s)
  *       the n items as k contiguous chunks; per chunk: parse,
  *       challenges k_i, z_i from rand, then
  *       fixed(sum z_i*s_i) == sum z_i*R_i + (z_i*k_i)*A_i   -> 1/0/-1
@@ -37,6 +38,7 @@
 #include <stddef.h>
 #include <stdlib.h>
 #include <string.h>
+#include <time.h>
 
 typedef uint64_t u64;
 typedef unsigned __int128 u128;
@@ -1045,10 +1047,10 @@ static void *chunk_job_run(void *arg) {
     return NULL;
 }
 
-int r255_round_check(size_t n, size_t k, const uint8_t *pubs,
-                     const uint8_t *sigs, const uint8_t *rand16,
-                     const uint8_t *prefix_blob, const uint8_t *msgs,
-                     const uint32_t *mlens, const uint8_t *ks) {
+static int round_check(size_t n, size_t k, const uint8_t *pubs,
+                       const uint8_t *sigs, const uint8_t *rand16,
+                       const uint8_t *prefix_blob, const uint8_t *msgs,
+                       const uint32_t *mlens, const uint8_t *ks) {
     if (!prefix_blob && !ks) return -1;
     if (k < 1) k = 1;
     if (k > ROUND_MAX_CHUNKS) k = ROUND_MAX_CHUNKS;
@@ -1086,4 +1088,25 @@ int r255_round_check(size_t n, size_t k, const uint8_t *pubs,
     for (size_t c = 0; c < njobs; c++)
         if (jobs[c].rc != 1) return jobs[c].rc;
     return 1;
+}
+
+/* The round's check, and through elapsed_s (NULL: not wanted) the
+ * seconds it took by its own reading of CLOCK_MONOTONIC (the clock of
+ * Python's perf_counter on Linux): what the caller's stamps around the
+ * crossing read beyond this is the wait to get the GIL back
+ * (obs/phases.py, span verify_native). */
+int r255_round_check(size_t n, size_t k, const uint8_t *pubs,
+                     const uint8_t *sigs, const uint8_t *rand16,
+                     const uint8_t *prefix_blob, const uint8_t *msgs,
+                     const uint32_t *mlens, const uint8_t *ks,
+                     double *elapsed_s) {
+    struct timespec a, b;
+    clock_gettime(CLOCK_MONOTONIC, &a);
+    int rc = round_check(n, k, pubs, sigs, rand16, prefix_blob, msgs, mlens,
+                         ks);
+    clock_gettime(CLOCK_MONOTONIC, &b);
+    if (elapsed_s)
+        *elapsed_s = (double)(b.tv_sec - a.tv_sec)
+                     + (double)(b.tv_nsec - a.tv_nsec) * 1e-9;
+    return rc;
 }

@@ -55,7 +55,7 @@ from .registry import (  # noqa: F401
     TelemetryLeakError,
     TelemetryRegistry,
 )
-from .phases import PHASES, device_phase, phase_timer  # noqa: F401
+from .phases import PHASES, device_phase, span  # noqa: F401
 from .exporter import render_prometheus  # noqa: F401
 from .httpd import MetricsServer  # noqa: F401
 from .flightrec import FlightRecorder  # noqa: F401

@@ -4,12 +4,17 @@ The flight recorder (obs/flightrec.py) answers *what* the engine was
 doing (fill, detector stats); this module answers *where the time went*
 — the question that sizes ROADMAP items 1-2 (tree-top caching, pipelined
 rounds) before anyone builds them. Each committed round contributes one
-span ledger assembled from the phase timers the engine already runs
-(assembly/verify/dispatch/journal/checkpoint/evict/demux, the
-scheduler's queue wait, hold and settle fan-out, and the two device
+span ledger assembled from the spans the host side takes with its one
+primitive (obs/phases.py ``span``: assembly, verify with ``verify_prep``
+and ``verify_native`` inside it, stage, pack, dispatch with journal and
+checkpoint inside it, evict, demux, observe, settle, release, and
+``cycle``, the
+collector's whole pass that holds all of them), the scheduler's queue
+wait and hold, and the two device
 windows: ``device``, the round's own device time as the host can know
 it, and ``inflight``, dispatch to observed ready with the rounds queued
-ahead of it), plus a handful of per-round counts, kept in a fixed ring like
+ahead of it), plus a handful of per-round and per-cycle counts, kept in
+a fixed ring like
 the flight recorder and exported two ways:
 
 - ``chrome_trace()`` — Chrome trace-event JSON (the ``/trace`` endpoint,
@@ -32,8 +37,9 @@ the flight recorder and exported two ways:
   collection window to its answers, two rounds later, so ``b`` reads
   about a third of what a serial round would show, and the balance is
   ``device`` (the round's own device time, which tiles the wall clock
-  when the device sets the pace) against ``verify`` + ``dispatch`` +
-  ``demux`` + ``settle`` (OPERATIONS.md §12). With one device,
+  when the device sets the pace) against ``cycle`` less ``cycle_wait_s``
+  (the collector's pass less its waiting; OPERATIONS.md §12). With one
+  device,
   double-buffered rounds take
   ``max(host, device)`` instead of today's ``host + device``, so the
   steady-state speedup is ``1 / max(b, 1-b)`` — maximal (≈2×) at
@@ -44,8 +50,8 @@ the flight recorder and exported two ways:
 
 Leak stance — the PR-1/2 contract, enforced structurally: a span is a
 *phase*, never an operation. ``record_round()`` validates every ledger
-against the fixed span-name allowlist (the canonical phases plus the
-scheduler's ``hold``/``settle`` and the derived
+against the fixed span-name allowlist (obs/phases.py ``SPAN_NAMES``:
+the canonical phases and the collector's further spans; plus the derived
 ``device``/``inflight``/``queue``/``round`` windows)
 and rejects anything else with :class:`TelemetryLeakError`; a span
 value is exactly a ``(start, duration)`` pair of floats. A count is one
@@ -66,13 +72,17 @@ Timestamps are ``time.perf_counter`` seconds (one clock domain across
 the scheduler and batcher call sites); the Chrome export converts to
 microseconds as the trace-event format requires.
 
-Span pairing: collector-side spans (assembly/verify/queue) are stamped
-onto the round's own handle (engine/batcher.py PendingRound.note_span),
-so a ledger always describes exactly one round even under the pipelined
-scheduler — there is no cross-round staging here. ``settle`` ends after
-``resolve()`` has recorded the ledger, so the scheduler adds it to the
-recorded round by its ``seq`` (:meth:`RoundTracer.amend_round`): one
-ledger per round, still.
+Span pairing: collector-side spans (assembly/verify/stage/queue) are
+stamped onto the round's own handle (engine/batcher.py
+PendingRound.note_span), so a ledger always describes exactly one round
+even under the pipelined scheduler — there is no cross-round staging
+here. ``observe``, ``settle`` and ``release`` end after ``resolve()``
+has recorded the ledger, and at depth 1 so does the ``cycle`` the round was
+dispatched in, with its counts: they are added to the recorded round by
+its ``seq`` (:meth:`RoundTracer.amend_round`): one ledger per round,
+still. A ledger's spans are the ROUND's; a cycle's counts are sums over
+the collector's pass that dispatched the round, in which an older
+round's evict, demux, observe and settle ran.
 
 Thread-safety: one lock around the ring; ``record_round()`` and
 ``amend_round()`` run on the collector thread (PendingRound.resolve,
@@ -86,19 +96,32 @@ import json
 import math
 import threading
 
-from .phases import PHASES
+from .phases import PHASES, ROUND_SPANS
 from .registry import TelemetryLeakError, TelemetryRegistry
 
-#: spans assembled on the host side of every round (obs/phases.py
-#: names, plus the scheduler's own two: ``hold``, the time the dispatch
-#: rule deferred the round behind one in flight, from the close of its
-#: window or its first op's arrival to the moment its ops were taken, 0
-#: for a round that was not held; and ``settle``, the ``set_result``
-#: fan-out and bookkeeping after ``resolve()`` returned)
-HOST_SPANS = (
-    "assembly", "hold", "verify", "dispatch", "journal", "checkpoint",
-    "evict", "demux", "settle",
-)
+#: spans assembled on the host side of every round, in the order a
+#: round meets them (obs/phases.py ``ROUND_SPANS``, the one list of
+#: them). ``hold`` is the
+#: time the dispatch rule deferred the round behind one in flight, from
+#: the close of its window or its first op's arrival to the moment its
+#: ops were taken, 0 for a round that was not held: a window from
+#: stamps, which holds the settle of the round it waited for (the
+#: collector's own ``hold`` state, the waiting and the poll alone, is in
+#: ``cycle_wait_s``). ``verify_prep`` / ``verify_native``, inside
+#: ``verify``: the call's arguments joined under the GIL, and the
+#: foreign call. ``stage``: the scheduler's list work between its other
+#: spans (the round's ops taken off the queue, the death-guard's list,
+#: the requests, the enqueue stamps and counts). ``pack``: validation
+#: and ``pack_batch``, before the engine lock. ``observe``: ``resolve()``
+#: from the end of ``demux`` to its return, the observability's own cost
+#: on the collector thread. ``release``: the settled round's device
+#: arrays dropped, after ``settle``, where the handle's last reference
+#: used to die (each deletion releases the GIL: the span is mostly the
+#: wait to get it back from the threads the settle woke). ``settle``: the ``set_result`` fan-out after
+#: ``resolve()`` returned. ``cycle``: the collector's pass that
+#: dispatched the round, top of the loop to top of the loop; consecutive
+#: rounds' cycles tile the collector's time
+HOST_SPANS = ROUND_SPANS
 
 #: windows derived from stamps rather than timed in place: ``queue`` =
 #: enqueue of the round's oldest admitted op -> dispatch; ``inflight`` =
@@ -130,9 +153,26 @@ ALLOWED_SPAN_NAMES = frozenset(STABLE_SPANS) | frozenset(PHASES)
 #: ``device`` is this round's own device time, not an upper bound),
 #: ``verify_chunks`` = chunk checks the round's first signature pass
 #: ran side by side (1 = one inline call): a function of how many ops
-#: the round took and of the host's cores, never of an op
+#: the round took and of the host's cores, never of an op.
+#: The ``cycle_*`` counts are sums over the collector's pass that
+#: dispatched the round (obs/phases.py ``Span.cycle_counts``), each a
+#: function of the round's shape and the host: ``cycle_wait_s`` = own
+#: wall of the states that wait by design (assembly, hold, evict);
+#: ``cycle_cpu_s`` = the collector thread's CPU seconds over the cycle;
+#: ``cycle_blocked_s`` = over every other state but the native
+#: crossing, own wall less CPU (the cycle's CPU less what was read over
+#: its waits and the crossing), plus what the crossing's stamps read
+#: beyond the call's own clock: what the collector waited for the GIL
+#: or a lock; ``cycle_native_wait_s`` = the part of that spent getting
+#: the GIL back after the native call (the crossing's stamps less the
+#: call's own clock); ``cycle_unspanned_s`` = the cycle's wall that no span
+#: inside it names. So ``cycle`` = ``cycle_wait_s`` + working wall +
+#: ``cycle_unspanned_s``, and working wall = CPU + ``cycle_blocked_s`` +
+#: the native crossing
 ROUND_COUNTS = ("ops", "rejected", "queue_wait_sum_s", "rounds_ahead",
-                "device_exact", "verify_chunks")
+                "device_exact", "verify_chunks", "cycle_wait_s",
+                "cycle_cpu_s", "cycle_blocked_s", "cycle_native_wait_s",
+                "cycle_unspanned_s")
 
 
 def _check_span(name: str, value) -> tuple[float, float]:
@@ -248,17 +288,23 @@ class RoundTracer:
             self._g_bubble.set(bubble)
         return seq
 
-    def amend_round(self, seq: int, spans: dict) -> bool:
-        """Add spans that end after the ledger was recorded (the
-        scheduler's ``settle``) to round ``seq``, under the same schema.
-        False when the ring has already let that round go."""
+    def amend_round(self, seq: int, spans: dict | None = None,
+                    counts: dict | None = None) -> bool:
+        """Add spans that end after the ledger was recorded (``observe``,
+        the scheduler's ``settle``, a depth-1 round's ``cycle``) and
+        counts known only then (the cycle's) to round ``seq``, under
+        the same schema. False when the ring has already let that round
+        go."""
         checked = {name: _check_span(name, value)
-                   for name, value in spans.items()}
+                   for name, value in (spans or {}).items()}
+        counted = {name: _check_count(name, value)
+                   for name, value in (counts or {}).items()}
         with self._lock:
             entry = self._ring[(seq - 1) % self.capacity]
             if entry is None or entry["seq"] != seq:
                 return False
             entry["spans"].update(checked)
+            entry["counts"].update(counted)
         return True
 
     # -- derived signals ------------------------------------------------
@@ -294,25 +340,6 @@ class RoundTracer:
         with self._lock:
             return self._bubble_locked()
 
-    def span_durations_ms(self, name: str) -> list[float]:
-        """Non-zero durations (ms) of one phase span across the
-        retained ledgers, oldest first — the A/B tooling's accessor
-        (bench.py ``pipeline_ab``), kept here so the banked
-        journal-span methodology has one definition. Phase-level by
-        construction: the ring holds nothing finer."""
-        if name not in ALLOWED_SPAN_NAMES:
-            raise ValueError(
-                f"{name!r} is not a round span "
-                f"(allowed: {sorted(ALLOWED_SPAN_NAMES)})"
-            )
-        with self._lock:
-            entries = self._recent_locked(self.capacity)
-        return [
-            e["spans"][name][1] * 1e3
-            for e in entries
-            if e["spans"].get(name, (0.0, 0.0))[1] > 0.0
-        ]
-
     # -- export ---------------------------------------------------------
 
     #: rounds alternate across this many lanes per track: the pipelined
@@ -322,16 +349,21 @@ class RoundTracer:
     #: adjacent rounds overlap, alternate rounds cannot
     _LANES = 2
     #: which track a span rides: 0 host phases, 1 device windows
-    #: (``inflight`` holds ``device``), 2 queue wait
-    _TRACK = {"device": 1, "inflight": 1, "queue": 2}
+    #: (``inflight`` holds ``device``), 2 queue wait, 3 the collector's
+    #: cycles (round k's cycle holds round k-2's evict and settle, so it
+    #: cannot ride a lane of round spans)
+    _TRACK = {"device": 1, "inflight": 1, "queue": 2, "cycle": 3}
+    _TRACK_NAMES = ("host round phases", "device window", "queue wait",
+                    "collector cycle")
 
     def chrome_trace(self) -> dict:
         """The retained rounds as Chrome trace-event JSON (Perfetto-
         loadable): complete ("X") events in microseconds, host spans on
         tids 1-2, the device windows (``inflight`` holding ``device``)
-        on tids 3-4 and the queue wait on tids 5-6 of one process (round
-        seq picks the lane). The ``grapevine/round`` event's ``args``
-        carry the round's counts beside its ``seq``."""
+        on tids 3-4, the queue wait on tids 5-6 and the collector's
+        cycles on tids 7-8 of one process (round seq picks the lane).
+        The ``grapevine/round`` event's ``args`` carry the round's
+        counts beside its ``seq``."""
         with self._lock:
             entries = self._recent_locked(self.capacity)
             bubble = self._bubble_locked()
@@ -341,18 +373,11 @@ class RoundTracer:
              "args": {"name": "grapevine-engine"}},
         ]
         for lane in range(self._LANES):
-            events.append(
-                {"name": "thread_name", "ph": "M", "pid": 1,
-                 "tid": 1 + lane,
-                 "args": {"name": f"host round phases (lane {lane})"}})
-            events.append(
-                {"name": "thread_name", "ph": "M", "pid": 1,
-                 "tid": 1 + self._LANES + lane,
-                 "args": {"name": f"device window (lane {lane})"}})
-            events.append(
-                {"name": "thread_name", "ph": "M", "pid": 1,
-                 "tid": 1 + 2 * self._LANES + lane,
-                 "args": {"name": f"queue wait (lane {lane})"}})
+            for track, label in enumerate(self._TRACK_NAMES):
+                events.append(
+                    {"name": "thread_name", "ph": "M", "pid": 1,
+                     "tid": 1 + track * self._LANES + lane,
+                     "args": {"name": f"{label} (lane {lane})"}})
         for entry in entries:
             seq = entry["seq"]
             lane = seq % self._LANES

@@ -46,7 +46,9 @@ from collections import deque
 from concurrent.futures import Future
 from typing import TYPE_CHECKING
 
-from ..obs.phases import trace_span
+from ..obs.phases import (
+    COLLECTOR_THREAD, name_thread, reset_thread_spans, span as _plain_span,
+    trace_span)
 from ..session import schnorrkel
 from ..wire.records import QueryRequest, QueryResponse
 
@@ -105,6 +107,14 @@ def _round_ready(pending) -> bool:
     ready: its ``resolve()`` is then the wait, as it was."""
     probe = getattr(pending, "ready", None)
     return True if probe is None else bool(probe())
+
+
+def _release(pending) -> None:
+    """Let a settled round's device arrays go, under the ``release``
+    span (a test's bare fake holds none)."""
+    release = getattr(pending, "release", None)
+    if release is not None:
+        release()
 
 
 class AuthFailure(Exception):
@@ -167,6 +177,10 @@ class BatchScheduler:
         #: obs.TelemetryRegistry); the scheduler records into the
         #: engine's registry so /metrics serves one merged view
         self.metrics = getattr(engine, "metrics", None)
+        #: the collector's one way of timing anything (obs/phases.py
+        #: ``span``): behind the engine's phase histogram where there is
+        #: one (a test's stub engine has none)
+        self._span = getattr(self.metrics, "span", None) or _plain_span
         #: (request, auth, future, perf_counter enqueue time)
         self._queue: list[
             tuple[QueryRequest, AuthItem | None, Future, float]
@@ -357,6 +371,23 @@ class BatchScheduler:
         #: order), so responses, tracer ledgers, and leakmon hand-offs
         #: stay in round order at every depth.
         ledger: deque = deque()
+        span = self._span
+        name_thread(COLLECTOR_THREAD)
+        reset_thread_spans()
+        #: the open ``cycle`` span — one pass of this loop, top to top,
+        #: holding every other span the collector takes — and the round
+        #: dispatched in it. A pass that dispatches nothing (a drain, an
+        #: all-rejected chunk) leaves its cycle open, so its time folds
+        #: into the next round's; consecutive rounds' cycles tile the
+        #: collector's time. Asleep with nothing queued and nothing in
+        #: flight the collector is in no cycle.
+        cycle, cycle_round = span("cycle").begin(), None
+        #: the round dispatched last. Its device arrays are let go
+        #: (``release``) once it is settled AND the collector has taken
+        #: the next round's ops: where the handle's last reference used
+        #: to die, so no span and no window changed its meaning when the
+        #: deletion got a name. An older round's go as it is settled.
+        pending = None
 
         def settle_head():
             pending_h, live_h, t_h = ledger.popleft()
@@ -366,6 +397,8 @@ class BatchScheduler:
             self._settle(pending_h, live_h)
             self._crash_streak = 0  # a settled round = recovered
             self._inflight_since = ledger[0][2] if ledger else None
+            if pending_h is not pending:
+                _release(pending_h)
 
         def hold(w_gap, w_target):
             """The dispatch rule for a short queue behind a round in
@@ -379,28 +412,54 @@ class BatchScheduler:
             is the wait, as it always was, and the wave that follows a
             full round does not wake the collector once per op. Reads
             the queue's LENGTH and the ledger's, never an entry of
-            either."""
-            with trace_span("hold"):
-                while ledger:
-                    with self._cv:
-                        while (0 < len(self._queue) < w_target
-                               and not self._closed
-                               and not _round_ready(ledger[0][0])):
-                            self._cv.wait(timeout=w_gap)
-                        if len(self._queue) >= w_target or self._closed:
-                            return
-                    settle_head()
+            either. The ``hold`` span is the waiting and the poll: it
+            closes before the head round is settled."""
+            while ledger:
+                with span("hold"), self._cv:
+                    while (0 < len(self._queue) < w_target
+                           and not self._closed
+                           and not _round_ready(ledger[0][0])):
+                        self._cv.wait(timeout=w_gap)
+                    if len(self._queue) >= w_target or self._closed:
+                        return
+                settle_head()
 
         while True:
+            if cycle_round is not None:
+                # the pass that dispatched a round has ended: so has its
+                # cycle, and the next begins at the same instant
+                done, cycle = cycle.end(), span("cycle").begin()
+                if getattr(cycle_round, "note_cycle", None) is not None:
+                    cycle_round.note_cycle(done.start, done.wall,
+                                           done.cycle_counts())
+                cycle_round = None
+            #: this pass's spans of the round it dispatches, handed to
+            #: the round's handle after the dispatch
+            staged: dict = {}
+            # the look at the queue is staging: behind a wave of
+            # per-op submits the collector waits for this lock as long
+            # as the wave lasts (each submit takes it, and none yields)
+            look = span("stage", staged).begin()
             with self._cv:
-                while not self._queue and not self._closed:
-                    if ledger:
-                        break  # drain the in-flight pipeline, then sleep
-                    self._cv.wait()
-                if self._closed and not self._queue and not ledger:
-                    return
+                if not (self._queue or self._closed or ledger):
+                    # asleep with nothing queued and nothing in flight
+                    # the collector is in no cycle, and in no span
+                    look.end()
+                    cycle.end()
+                    # (in a capture the sleep still has a name)
+                    with trace_span("asleep"):
+                        while not self._queue and not self._closed:
+                            self._cv.wait()
+                    staged.clear()
+                    cycle = span("cycle").begin()
+                    look = span("stage", staged).begin()
+                drained = self._closed and not self._queue and not ledger
                 has_work = bool(self._queue)
                 depth0 = len(self._queue)
+            look.end()
+            if drained:
+                cycle.end()
+                return
             # per-round window decision OUTSIDE the cv (the burn-rate
             # scans and registry samples must never extend the
             # collector's critical section — the note_arrival stance).
@@ -411,10 +470,17 @@ class BatchScheduler:
             w_wait, w_gap, w_target = self.max_wait, self.idle_gap, bs
             if has_work and self.adaptive is not None:
                 w_wait, w_gap, w_target = self.adaptive.decide(depth0)
-            t_asm0 = time.perf_counter()
-            asm_s, hit_cap = 0.0, False
+            # the window's span opens before the lock is asked for and
+            # closes with the window, inside the lock
+            window = None
+            if has_work:
+                window = span("assembly", staged).begin()
+            else:
+                # no window was opened (a drain pass, or ops that came
+                # while the collector held): the round still starts here
+                staged["assembly"] = (time.perf_counter(), 0.0)
             with self._cv:
-                if self._queue:
+                if window is not None:
                     # Quiescence-based collection: a client wave
                     # re-arrives staggered over several ms after the
                     # previous round's responses land (decrypt → decode
@@ -426,35 +492,37 @@ class BatchScheduler:
                     # still commits after the idle gap. The wait runs
                     # while the device executes the previous round (see
                     # below), so it costs no device idle time under load.
-                    deadline = t_asm0 + w_wait
-                    with trace_span("assembly"):
-                        while (len(self._queue) < w_target
-                               and not self._closed):
-                            now = time.perf_counter()
-                            wait_until = min(
-                                deadline, self._last_enqueue + w_gap
-                            )
-                            if now >= wait_until:
-                                hit_cap = now >= deadline
-                                break
-                            self._cv.wait(timeout=wait_until - now)
-                    asm_s = time.perf_counter() - t_asm0
-                    if self.metrics is not None:
-                        self.metrics.observe_phase("assembly", asm_s)
-                        if hit_cap and len(self._queue) < bs:
-                            # window closed by the max_wait cap, not by
-                            # quiescence or a full batch: arrivals are
-                            # starving mid-wave (the stall signal)
-                            self.metrics.record_stall()
+                    deadline = window.start + w_wait
+                    hit_cap = False
+                    while (len(self._queue) < w_target
+                           and not self._closed):
+                        now = time.perf_counter()
+                        wait_until = min(
+                            deadline, self._last_enqueue + w_gap
+                        )
+                        if now >= wait_until:
+                            hit_cap = now >= deadline
+                            break
+                        self._cv.wait(timeout=wait_until - now)
+                    window.end()
+                    if (self.metrics is not None and hit_cap
+                            and len(self._queue) < bs):
+                        # window closed by the max_wait cap, not by
+                        # quiescence or a full batch: arrivals are
+                        # starving mid-wave (the stall signal)
+                        self.metrics.record_stall()
                 # the dispatch rule: a short queue behind a round in
                 # flight waits for that round (hold's docstring); with
                 # none in flight, or a batch in the queue, nothing is
                 # deferred and the window's own lock take is the pop's
                 held = bool(ledger and len(self._queue) < w_target
                             and not self._closed)
+                # the ledger's ``hold`` is a window from these two
+                # stamps and the queue's, not a span of the collector
                 t_h0 = t_take = time.perf_counter()
                 if not held:
-                    chunk, backlog = self._take(bs, t_take)
+                    with span("stage", staged):
+                        chunk, backlog = self._take(bs, t_take)
             if held:
                 hold(w_gap, w_target)
                 with self._cv:
@@ -462,26 +530,29 @@ class BatchScheduler:
                     # the deferred ops have waited since the hold began
                     # or since the first of them came
                     t_h0 = max(t_h0, self._head_enqueue)
-                    chunk, backlog = self._take(bs, t_take)
+                    with span("stage", staged):
+                        chunk, backlog = self._take(bs, t_take)
 
-            # everything the death-guard must fail if we crash from here:
-            # the rounds still in flight on the device plus the chunk
-            # just popped off the queue (no longer reachable from _queue)
-            self._inflight = [
-                f for _, lv, _ in ledger for _, f in lv
-            ] + [f for _, _, f, _ in chunk]
+            if pending is not None and not (ledger
+                                            and ledger[-1][0] is pending):
+                # the last round was settled inside this pass's hold
+                _release(pending)
             pending, live = (None, [])
+            with span("stage", staged):
+                # everything the death-guard must fail if we crash from
+                # here: the rounds still in flight on the device plus
+                # the chunk just popped off the queue (no longer
+                # reachable from _queue)
+                self._inflight = [
+                    f for _, lv, _ in ledger for _, f in lv
+                ] + [f for _, _, f, _ in chunk]
             if chunk:
-                t_v0 = time.perf_counter()
-                if self.metrics is not None:
-                    with self.metrics.time_phase("verify"):
-                        live = self._verify_chunk(chunk)
-                else:
+                with span("verify", staged):
                     live = self._verify_chunk(chunk)
-                ver_s = time.perf_counter() - t_v0
                 if live:
-                    reqs = [r for r, _ in live]
                     try:
+                        with span("stage", staged):
+                            reqs = [r for r, _ in live]
                         # async dispatch: the device starts this round
                         # while we resolve the previous one and collect
                         # the next — PERF.md's dispatch/compute overlap
@@ -489,40 +560,18 @@ class BatchScheduler:
                         pending = self.engine.handle_queries_async(
                             reqs, self.clock()
                         )
-                        # collector-side spans + the oldest op's enqueue
-                        # stamp ride the round handle itself, so the
-                        # tracer/SLO pair them with THIS round even
-                        # while the pipeline overlaps the next window
+                        cycle_round = pending
                         # (getattr: test fakes return bare objects)
                         if getattr(pending, "note_span", None) is not None:
-                            pending.note_span("assembly", t_asm0, asm_s)
-                            # how long the dispatch rule deferred this
-                            # round; 0 for a round that was not held
-                            pending.note_span("hold", t_h0, t_take - t_h0)
-                            pending.note_span("verify", t_v0, ver_s)
-                            # post-dispatch backlog: the queue-depth
-                            # sample obs/workload.py histograms at
-                            # round cadence (and flightrec records)
-                            pending.set_queue_depth(backlog)
-                            # anchor on the ops that actually entered
-                            # the round: an auth-rejected op's queue
-                            # wait is not a commit latency, and letting
-                            # it in would hand an attacker (garbage
-                            # signatures are their cheapest input) a
-                            # lever on the SLO burn rate
-                            enq_by_fut = {f: t for _, _, f, t in chunk}
-                            enqs = [enq_by_fut[f] for _, f in live]
-                            oldest = min(enqs)
-                            pending.set_enqueued_at(oldest)
-                            # the ledger's queue wait: the oldest
-                            # admitted op's as a span, every admitted
-                            # op's as one sum — a round's aggregate,
-                            # never one op's wait (obs/tracer.py)
-                            pending.note_span(
-                                "queue", oldest, max(0.0, t_disp - oldest))
-                            pending.note_counts(**round_counts(
-                                enqs, len(chunk), t_disp, len(ledger),
-                                self._verify_chunks))
+                            with span("stage", staged):
+                                # how long the dispatch rule deferred
+                                # this round; 0 for one that was not held
+                                staged["hold"] = (t_h0, t_take - t_h0)
+                                self._stamp_round(
+                                    pending, staged, chunk, live, backlog,
+                                    t_disp, len(ledger))
+                            for name, (start, dur) in staged.items():
+                                pending.note_span(name, start, dur)
                     except Exception as exc:  # pragma: no cover - defensive
                         for _, fut in live:
                             if not fut.done():
@@ -542,6 +591,31 @@ class BatchScheduler:
                 # the oldest round so its clients are answered promptly
                 # and close() can drain
                 settle_head()
+
+    def _stamp_round(self, pending, staged: dict, chunk, live, backlog: int,
+                     t_disp: float, rounds_ahead: int) -> None:
+        """What the collector knows of a round rides the round's handle
+        itself, so the tracer and the SLO pair it with THIS round even
+        while the pipeline overlaps the next window: the backlog, the
+        oldest admitted op's enqueue stamp, the ``queue`` span (into
+        ``staged``, with the pass's other spans) and the counts."""
+        # post-dispatch backlog: the queue-depth sample obs/workload.py
+        # histograms at round cadence (and flightrec records)
+        pending.set_queue_depth(backlog)
+        # anchor on the ops that actually entered the round: an
+        # auth-rejected op's queue wait is not a commit latency, and
+        # letting it in would hand an attacker (garbage signatures are
+        # their cheapest input) a lever on the SLO burn rate
+        enq_by_fut = {f: t for _, _, f, t in chunk}
+        enqs = [enq_by_fut[f] for _, f in live]
+        oldest = min(enqs)
+        pending.set_enqueued_at(oldest)
+        # the ledger's queue wait: the oldest admitted op's as a span,
+        # every admitted op's as one sum — a round's aggregate, never
+        # one op's wait (obs/tracer.py)
+        staged["queue"] = (oldest, max(0.0, t_disp - oldest))
+        pending.note_counts(**round_counts(
+            enqs, len(chunk), t_disp, rounds_ahead, self._verify_chunks))
 
     def _take(self, bs: int, now: float):
         """Pop the next round's entries, at most ``bs``, off the queue
@@ -583,11 +657,12 @@ class BatchScheduler:
     def _verify_chunk(self, chunk):
         """Batch signature verification; returns surviving (req, fut)."""
         # --- one multi-scalar multiplication for the round ------------
-        authed = [i for i, (_, a, _, _) in enumerate(chunk) if a is not None]
+        with self._span("verify_prep"):
+            authed = [i for i, (_, a, _, _) in enumerate(chunk)
+                      if a is not None]
+            items = [chunk[i][1] for i in authed]
         rejected: set[int] = set()
-        if authed and not self._batch_verify_fanout(
-            [chunk[i][1] for i in authed]
-        ):
+        if authed and not self._batch_verify_fanout(items):
             # bisect to the offenders: O(bad · log n) batch checks, so
             # one client spraying garbage signatures cannot force
             # per-item verification of every honest request
@@ -622,19 +697,21 @@ class BatchScheduler:
         ``settle`` span covers the ``set_result`` fan-out (each call
         wakes one waiting handler thread); it ends after ``resolve()``
         recorded the round's ledger, so it is added to that ledger
-        afterwards (PendingRound.note_settle)."""
+        afterwards (PendingRound.note_settle). The round's device arrays
+        are the collector loop's to let go (``release``)."""
         try:
             resps = pending.resolve()
-            # the round's settle stamp, taken once and handed to every
-            # handler on its future: what a handler waits after it is
-            # the wake-up, not the round (server/service.py)
-            t_s0 = time.perf_counter()
-            with trace_span("settle"):
+            with self._span("settle") as settled:
+                # the round's settle stamp, taken once and handed to
+                # every handler on its future: what a handler waits
+                # after it is the wake-up, not the round
+                # (server/service.py)
+                t_s0 = settled.start
                 for (_, fut), resp in zip(live, resps):
                     fut.settled_at = t_s0
                     fut.set_result(resp)
             if getattr(pending, "note_settle", None) is not None:
-                pending.note_settle(t_s0, time.perf_counter() - t_s0)
+                pending.note_settle(t_s0, settled.wall)
         except Exception as exc:  # pragma: no cover - defensive
             for _, fut in live:
                 if not fut.done():

@@ -435,7 +435,7 @@ def test_library_lacking_an_export_is_rebuilt_once(tmp_path, monkeypatch,
         assert native.load_error is None
         assert so.stat().st_ino != stale_inode
         assert handle.r255_round_check(0, 1, None, None, None, b"p", None,
-                                       None, None) == 1
+                                       None, None, None) == 1
     else:
         assert handle is None
         assert "no-such-cc" in native.load_error

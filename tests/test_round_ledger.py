@@ -4,6 +4,7 @@ engine/batcher.py, server/scheduler.py): one clock, the round's own
 and the allowlisted per-round counts — on a depth-2 engine whose clock
 is a counter, so every stamp is known exactly."""
 
+import os
 import sys
 import threading
 import time
@@ -13,6 +14,7 @@ import pytest
 from grapevine_tpu.config import GrapevineConfig
 from grapevine_tpu.engine import batcher as batcher_mod
 from grapevine_tpu.engine.batcher import GrapevineEngine
+from grapevine_tpu.obs import phases as phases_mod
 from grapevine_tpu.obs.registry import TelemetryLeakError
 from grapevine_tpu.obs.tracer import (
     DERIVED_SPANS,
@@ -50,19 +52,25 @@ def _ledgers(tracer) -> list[dict]:
 
 
 class CountingClock:
-    """Stands in for the ``time`` module in the scheduler and the
-    batcher: ``perf_counter`` is a counter (one tick a call, from any
-    thread) that remembers which function took each stamp; there is no
-    ``monotonic`` to take a second clock from."""
+    """Stands in for the ``time`` module in the scheduler, the batcher
+    and the span primitive: ``perf_counter`` is a counter (one tick a
+    call, from any thread) that remembers which function took each
+    stamp (for a span's two, the function that opened the span); there
+    is no ``monotonic`` to take a second clock from. ``thread_time`` is
+    the real one: CPU seconds are no stamps."""
 
     def __init__(self):
         self._lock = threading.Lock()
         self._n = 0
         self.stamps: list[tuple[float, str]] = []
         self.time, self.sleep = time.time, time.sleep
+        self.thread_time = time.thread_time
 
     def perf_counter(self) -> float:
-        caller = sys._getframe(1).f_code.co_name
+        frame = sys._getframe(1)
+        while frame.f_code.co_filename == phases_mod.__file__:
+            frame = frame.f_back
+        caller = frame.f_code.co_name
         with self._lock:
             self._n += 1
             t = self._n * TICK
@@ -111,6 +119,7 @@ def clocked(engine, monkeypatch):
     clock = CountingClock()
     monkeypatch.setattr(batcher_mod, "time", clock)
     monkeypatch.setattr(scheduler_mod, "time", clock)
+    monkeypatch.setattr(phases_mod, "time", clock)
     tracer = RoundTracer(capacity=64, registry=None)
     engine.attach_tracer(tracer)
     engine._last_ready = (0.0, False)
@@ -282,6 +291,10 @@ def test_a_held_round_carries_its_hold_on_the_ledger(clocked, monkeypatch):
     # round was dispatched: no answer waited for another round
     assert (first["spans"]["settle"][0]
             < second["spans"]["dispatch"][0])
+    # its device arrays went where its handle used to die, after the
+    # held ops were taken: the hold window does not hold the release
+    r0, rdur = first["spans"]["release"]
+    assert h0 + hdur <= r0 and r0 + rdur <= second["spans"]["verify"][0]
 
 
 @pytest.mark.parametrize("metric,cell", [
@@ -398,3 +411,304 @@ def test_a_round_behind_a_sweep_is_not_exact(engine, monkeypatch):
         engine.attach_tracer(None)
     assert [e["counts"]["device_exact"]
             for e in _ledgers(tracer)] == [0, 1, 0, 1]
+
+
+# -- the collector's cycle, accounted whole (obs/phases.py span) ---------
+
+#: the collector's states at the top level of a cycle that never wait by
+#: design (verify holds verify_prep and verify_native, dispatch holds
+#: journal and checkpoint); the waiting ones are in cycle_wait_s
+WORKING = ("verify", "stage", "pack", "dispatch", "demux", "observe",
+           "release", "settle")
+NEW_SPANS = ("cycle", "stage", "pack", "verify_prep", "verify_native",
+             "observe", "release")
+CYCLE_COUNTS = ("cycle_wait_s", "cycle_cpu_s", "cycle_blocked_s",
+                "cycle_native_wait_s", "cycle_unspanned_s")
+
+
+def _cycle_accounts(ledgers):
+    """For every round whose cycle is known: (the cycle's wall, the
+    wall of the working spans that began inside it — its own round's
+    verify to dispatch, an older round's demux to settle —, its
+    counts)."""
+    working = [(start, dur) for e in ledgers for name in WORKING
+               for start, dur in [e["spans"][name]] if dur]
+    out = []
+    for e in ledgers:
+        c0, cdur = e["spans"]["cycle"]
+        if cdur:
+            inside = sum(d for s, d in working if c0 <= s < c0 + cdur)
+            out.append((cdur, inside, e["counts"]))
+    return out
+
+
+@pytest.fixture
+def real_clock(engine):
+    """(tracer, make_scheduler) on the module's engine and the real
+    clocks; the schedulers made are closed afterwards."""
+    tracer = RoundTracer(capacity=256, registry=None)
+    engine.attach_tracer(tracer)
+    engine._last_ready = (0.0, False)
+    made = []
+
+    def make(depth):
+        made.append(BatchScheduler(engine, max_wait_ms=50.0, idle_gap_ms=20.0,
+                                   scheme=_Scheme, pipeline_depth=depth))
+        return made[-1]
+
+    yield tracer, make
+    for sched in made:
+        sched.close()
+    engine.attach_tracer(None)
+
+
+@pytest.mark.parametrize("depth", [1, 2])
+def test_a_cycle_is_partitioned_by_its_spans(real_clock, depth):
+    """cycle = cycle_wait_s + the working spans' wall + cycle_unspanned_s
+    round by round, at both depths, and consecutive rounds' cycles tile
+    the collector's time."""
+    tracer, make = real_clock
+    sched = make(depth)
+    _drive(sched, 12)
+    sched.close()  # the last cycle has ended and is on its round
+    ledgers = _ledgers(tracer)
+    assert len(ledgers) >= 12
+    accounts = _cycle_accounts(ledgers)
+    assert len(accounts) >= 11
+    for cycle, working, counts in accounts:
+        assert set(CYCLE_COUNTS) <= set(counts)
+        whole = counts["cycle_wait_s"] + working + counts["cycle_unspanned_s"]
+        # chrome_trace() floors every start and duration to a microsecond
+        assert whole == pytest.approx(cycle, rel=0.01, abs=40e-6)
+        assert counts["cycle_unspanned_s"] <= cycle
+        assert counts["cycle_cpu_s"] <= cycle + 1e-3
+    for prev, cur in zip(ledgers, ledgers[1:]):
+        p0, pd = prev["spans"]["cycle"]
+        c0, _ = cur["spans"]["cycle"]
+        if pd and cur["spans"]["cycle"][1] and cur["counts"]["ops"] == BATCH \
+                and prev["counts"]["ops"] == BATCH:
+            # full rounds back to back: no sleep between their cycles
+            assert c0 == pytest.approx(p0 + pd, abs=200e-6)
+
+
+def test_a_hold_with_a_settle_inside_it_is_counted_once(clocked, monkeypatch):
+    """The held round's ledger ``hold`` window holds the settle of the
+    round it waited for; the cycle's account gives that time to the
+    settle's own spans and only the waiting to ``cycle_wait_s``: on the
+    counting clock the partition is exact."""
+    _, tracer, sched = clocked
+    released = threading.Event()
+    really_ready = batcher_mod.PendingRound.ready
+    monkeypatch.setattr(
+        batcher_mod.PendingRound, "ready",
+        lambda self: released.is_set() and really_ready(self))
+    dispatching, go = threading.Event(), threading.Event()
+    dispatch = sched.engine.handle_queries_async
+
+    def gated(reqs, now):
+        if not dispatching.is_set():
+            dispatching.set()
+            assert go.wait(timeout=60)
+        return dispatch(reqs, now)
+
+    monkeypatch.setattr(sched.engine, "handle_queries_async", gated)
+    auth = (b"p", b"c", b"m", b"ok")
+    futs = [sched.submit_nowait(_req(0), auth)]
+    assert dispatching.wait(timeout=60)
+    futs += [sched.submit_nowait(_req(n), auth) for n in (1, 2)]
+    go.set()
+    time.sleep(0.1)  # the collector holds the two behind the first
+    released.set()
+    for f in futs:
+        f.result(timeout=120)
+    sched.close()
+    first, second = _ledgers(tracer)
+    h0, hdur = second["spans"]["hold"]
+    s0, sdur = first["spans"]["settle"]
+    assert hdur > 0 and h0 <= s0 and s0 + sdur <= h0 + hdur
+    (_, _, _), (cycle, working, counts) = _cycle_accounts([first, second])
+    # the first round's evict, demux, observe and settle ran inside the
+    # second's cycle, under its hold window, and are counted once
+    assert working >= sum(first["spans"][n][1]
+                          for n in ("demux", "observe", "release", "settle"))
+    assert counts["cycle_wait_s"] + working + counts["cycle_unspanned_s"] \
+        == cycle
+    assert counts["cycle_wait_s"] < hdur
+
+
+def test_a_thread_spinning_on_the_gil_shows_as_blocked_not_as_cpu(real_clock):
+    """A second thread that holds the GIL makes the collector wait for
+    it inside its working states: ``cycle_blocked_s`` rises and
+    ``cycle_cpu_s`` does not."""
+    tracer, make = real_clock
+    sched = make(1)
+
+    def sums(waves):
+        """Over all the cycles of ``waves`` rounds: one cycle's CPU can
+        come in 10 ms ticks, and consecutive cycles tile the thread's
+        time, so their sum is off by a tick at most."""
+        before = len(_ledgers(tracer))
+        _drive(sched, waves)
+        time.sleep(0.05)  # the last round's cycle is amended on
+        counts = [c for _, _, c in _cycle_accounts(_ledgers(tracer)[before:])]
+        assert len(counts) >= waves - 1
+        return tuple(sum(c[k] for c in counts)
+                     for k in ("cycle_blocked_s", "cycle_cpu_s"))
+
+    sums(4)  # warm
+    calm_blocked, calm_cpu = sums(32)
+    stop, spinning = threading.Event(), threading.Event()
+
+    def spin():
+        n = 0
+        while not stop.is_set():
+            n += 1
+            if n == 100_000:
+                spinning.set()
+
+    spinner = threading.Thread(target=spin, daemon=True)
+    spinner.start()
+    try:
+        # on a loaded machine the thread may take a while to get going
+        assert spinning.wait(timeout=60)
+        busy_blocked, busy_cpu = sums(32)
+    finally:
+        stop.set()
+        spinner.join()
+    # each GIL hand-back costs the collector up to the 5 ms switch
+    # interval, tens of ms a cycle and a second or so over 32 here; its
+    # own CPU time hardly moves beside that (contended locks and a
+    # shared core cost it a little, the clock a tick or two)
+    rose = busy_blocked - calm_blocked
+    assert rose > 0.1, (calm_blocked, busy_blocked)
+    assert busy_cpu - calm_cpu < 0.25 * rose + 0.02, (calm_cpu, busy_cpu,
+                                                      rose)
+
+
+def test_the_span_primitive_nests_and_partitions(monkeypatch):
+    """One enter and one leave: wall, CPU, the ledger entry (a name
+    entered twice adds up; a child with no ledger writes to its
+    parent's), the histogram for a PHASES name only, own time = wall
+    less children's, and a native span's wait for the GIL."""
+    clock = CountingClock()
+    monkeypatch.setattr(phases_mod, "time", clock)
+    phases_mod.reset_thread_spans()
+
+    class Histogram:
+        seen = []
+
+        def observe(self, value, phase):
+            self.seen.append((phase, value))
+
+    hist, ledger = Histogram(), {}
+    cycle = phases_mod.span("cycle").begin()                      # tick 1
+    with phases_mod.span("verify", ledger, hist):                 # 2
+        with phases_mod.span("verify_prep"):                      # 3, 4
+            pass
+        with phases_mod.span("verify_native") as crossing:        # 5
+            clock.perf_counter()                                  # 6
+            clock.perf_counter()                                  # 7
+            crossing.native_s = 1 * TICK                          # 8
+        with phases_mod.span("verify_prep"):                      # 9, 10
+            pass
+    with phases_mod.span("stage", ledger, hist):                  # 11: 12, 13
+        pass
+    cycle.end()                                                   # 14
+    assert ledger == {"verify": (2 * TICK, 9 * TICK),
+                      "verify_prep": (3 * TICK, 2 * TICK),
+                      "verify_native": (5 * TICK, 3 * TICK),
+                      "stage": (12 * TICK, 1 * TICK)}
+    assert hist.seen == [("verify", 9 * TICK)]  # stage is no PHASES name
+    assert cycle.wall == 13 * TICK
+    own = cycle.own
+    assert own == {"verify": 4 * TICK, "verify_prep": 2 * TICK,
+                   "verify_native": 3 * TICK, "stage": 1 * TICK}
+    counts = cycle.cycle_counts()
+    assert counts["cycle_wait_s"] == 0
+    assert counts["cycle_unspanned_s"] == 3 * TICK
+    assert sum(own.values()) + counts["cycle_unspanned_s"] == cycle.wall
+    # the crossing's stamps read 3 ticks, the call's own clock 1: two
+    # ticks waiting to get the GIL back; the rest of blocked is the
+    # working spans' fake wall less their real (tiny) CPU
+    assert counts["cycle_native_wait_s"] == 2 * TICK
+    assert 2 * TICK <= counts["cycle_blocked_s"] <= 9 * TICK
+    # the CPU clock is read for the cycle, its waits and its native
+    # call, and for no working state
+    assert cycle.cpu is not None and crossing.cpu is not None
+    assert phases_mod.span("hold").begin().end().cpu is not None
+    assert phases_mod.span("pack").begin().end().cpu is None
+    assert phases_mod._tls.top is None
+
+
+def test_the_new_names_pass_the_allowlist_and_no_other_does():
+    tr = RoundTracer(capacity=4)
+    seq = tr.record_round({name: (1.0, 0.5) for name in NEW_SPANS},
+                          {name: 0.25 for name in CYCLE_COUNTS})
+    for name in NEW_SPANS:
+        assert name in STABLE_SPANS
+        phases_mod.span(name)
+    assert set(CYCLE_COUNTS) <= set(ROUND_COUNTS)
+    for bad in ("op_read", "cycle_7", "client", "verify_native_op"):
+        with pytest.raises(TelemetryLeakError):
+            phases_mod.span(bad)
+        with pytest.raises(TelemetryLeakError):
+            tr.record_round({bad: (0.0, 1.0)})
+        with pytest.raises(TelemetryLeakError):
+            tr.amend_round(seq, {bad: (0.0, 1.0)})
+    # the tracer takes what the primitive takes
+    assert phases_mod.SPAN_NAMES <= set(STABLE_SPANS) | set(phases_mod.PHASES)
+
+
+def test_annotations_outside_a_round_are_an_allowlist_too():
+    for name in ("state_init", "ingress", "asleep"):
+        with phases_mod.trace_span(name):
+            pass
+    for bad in ("op_read", "asleep_7", "cycle"):
+        with pytest.raises(TelemetryLeakError):
+            with phases_mod.trace_span(bad):
+                pass
+    # no name is both a span of the ledger and a bare annotation
+    assert not phases_mod.ANNOTATION_NAMES & phases_mod.SPAN_NAMES
+
+
+@pytest.mark.skipif(not os.path.exists("/proc/self/task"),
+                    reason="thread names are read from /proc")
+def test_the_collector_names_its_thread_and_no_other(real_clock):
+    """The profiler names a capture's line after the thread: the
+    collector's is its own, which is how a reader of the capture tells
+    its annotations from a handler's or the expiry timer's."""
+    _, make = real_clock
+    sched = make(1)
+    _drive(sched, 1)
+
+    def comm(tid):
+        with open(f"/proc/self/task/{tid}/comm") as f:
+            return f.read().strip()
+
+    assert comm(sched._worker.native_id) == phases_mod.COLLECTOR_THREAD
+    assert len(phases_mod.COLLECTOR_THREAD.encode()) <= 15
+    assert comm(threading.main_thread().native_id) != \
+        phases_mod.COLLECTOR_THREAD
+
+
+def test_amend_round_takes_counts():
+    """A depth-1 round's cycle ends after its ledger was recorded: span
+    and counts are added by seq, under the same schema."""
+    tr = RoundTracer(capacity=4)
+    seq = tr.record_round({"round": (1.0, 2.0)}, {"ops": 3})
+    assert tr.amend_round(seq, {"cycle": (0.5, 3.0)},
+                          {"cycle_wait_s": 1.25, "cycle_cpu_s": 0.5,
+                           "cycle_blocked_s": 0.0,
+                           "cycle_unspanned_s": 0.125})
+    (ledger,) = _ledgers(tr)
+    assert ledger["spans"]["cycle"] == (0.5, 3.0)
+    assert ledger["counts"] == {"ops": 3, "cycle_wait_s": 1.25,
+                                "cycle_cpu_s": 0.5, "cycle_blocked_s": 0.0,
+                                "cycle_unspanned_s": 0.125}
+    assert tr.amend_round(seq, counts={"cycle_wait_s": 2.0})
+    assert _ledgers(tr)[0]["counts"]["cycle_wait_s"] == 2.0
+    for bad in ({"cycle_ops": 1}, {"cycle_wait_s": -1.0},
+                {"cycle_cpu_s": "much"}):
+        with pytest.raises(TelemetryLeakError):
+            tr.amend_round(seq, counts=bad)
+    assert not tr.amend_round(9, counts={"cycle_wait_s": 0.0})

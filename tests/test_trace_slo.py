@@ -93,7 +93,7 @@ def test_chrome_trace_is_valid_and_loadable_shape():
     # the device windows and the queue wait ride their own thread
     # tracks (seq 1 = lane 1)
     tids = {e["tid"] for e in parsed["traceEvents"] if e["ph"] == "X"}
-    assert tids == {2, 4, 6}
+    assert tids == {2, 4, 6, 8}
     by_name = {e["name"]: e["tid"] for e in parsed["traceEvents"]
                if e["ph"] == "X"}
     assert by_name["grapevine/device"] == by_name["grapevine/inflight"] == 4
@@ -147,12 +147,11 @@ def test_span_schema_has_teeth():
 
 
 def test_allowed_span_names_stay_inside_phase_vocabulary():
-    from grapevine_tpu.obs.phases import PHASES
+    from grapevine_tpu.obs.phases import SPAN_NAMES
 
     from grapevine_tpu.obs.tracer import DERIVED_SPANS
 
-    assert ALLOWED_SPAN_NAMES <= (
-        set(PHASES) | set(DERIVED_SPANS) | {"hold", "settle"})
+    assert ALLOWED_SPAN_NAMES == SPAN_NAMES | set(DERIVED_SPANS)
 
 
 def test_tracer_gauges_export():

@@ -182,10 +182,11 @@ def audit_trace_slo_registry() -> dict:
     """
     sys.path.insert(0, REPO)
     from grapevine_tpu.engine.metrics import EngineMetrics
-    from grapevine_tpu.obs.phases import PHASES
+    from grapevine_tpu.obs.phases import SPAN_NAMES
     from grapevine_tpu.obs.registry import TelemetryLeakError
     from grapevine_tpu.obs.slo import SloTracker
-    from grapevine_tpu.obs.tracer import ALLOWED_SPAN_NAMES, RoundTracer
+    from grapevine_tpu.obs.tracer import (
+        ALLOWED_SPAN_NAMES, DERIVED_SPANS, RoundTracer)
 
     em = EngineMetrics()
     tracer = RoundTracer(capacity=8, registry=em.registry)
@@ -210,10 +211,10 @@ def audit_trace_slo_registry() -> dict:
                 "scalars with no dimensions by design"
             )
 
-    # the derived windows and the scheduler's hold and settle fan-out
-    # are whole-round spans too (obs/tracer.py DERIVED_SPANS)
-    stray = (ALLOWED_SPAN_NAMES - set(PHASES)
-             - {"device", "inflight", "queue", "round", "hold", "settle"})
+    # a ledger takes the names the span primitive takes (obs/phases.py
+    # SPAN_NAMES: the phases and the collector's further spans, all
+    # whole-round) and the derived windows, and no other
+    stray = ALLOWED_SPAN_NAMES - SPAN_NAMES - set(DERIVED_SPANS)
     if stray:
         raise SystemExit(
             f"tracer span allowlist drifted outside the phase "
