@@ -212,7 +212,7 @@ RANGE_ALLOWLIST: tuple = (
     _A("shift_left", "oblivious/bucket_cipher.py:_rotl",
        "rotate-left: the bits shifted past 32 re-enter via the OR'd "
        "logical right shift — no information leaves the lane"),
-    _A("add", "oblivious/bucket_cipher.py:chacha_blocks",
+    _A("add", "oblivious/bucket_cipher.py:chacha_words",
        "the state+init feedforward of the ChaCha block function, "
        "mod-2^32 by RFC 7539"),
     _A("add", "oblivious/bucket_cipher.py:epoch_next",

@@ -112,14 +112,19 @@ class GrapevineConfig:
     #: analog (oblivious/bucket_cipher.py). 8 = ChaCha8 (default),
     #: 20 = RFC ChaCha20, 0 = plaintext trees.
     bucket_cipher_rounds: int = 8
-    #: cipher implementation: "jnp" (XLA, keystream materialized in
-    #: HBM), "pallas" (fused VMEM keystream+XOR kernel,
-    #: oblivious/pallas_cipher.py), "pallas_fused" ("pallas" plus the
-    #: path fetch fused into the decrypt — one HBM pass per fetched row,
-    #: oblivious/pallas_gather.py; single-chip fetches only, the sharded
-    #: path keeps decrypt-after-psum so plaintext never transits ICI).
-    #: Interpret mode on the CPU; bit-identical ciphertext in all three.
-    bucket_cipher_impl: str = "jnp"
+    #: cipher implementation: "jnp" (XLA: the compiler splits the rounds
+    #: over a dozen fusions and hands state words and keystream through
+    #: HBM — the reference and the CPU path), "pallas" (one pass: the
+    #: keystream made in VMEM on the row's own lane tiles and XORed
+    #: where it is made, oblivious/pallas_cipher.py), "pallas_fused"
+    #: ("pallas" plus the path fetch fused into the decrypt,
+    #: oblivious/pallas_gather.py: one row a grid step, not made fast;
+    #: single-chip fetches only, the sharded path keeps
+    #: decrypt-after-psum so plaintext never transits ICI). Interpret
+    #: mode on the CPU; bit-identical ciphertext in all three. None =
+    #: by backend: "pallas" on a TPU, "jnp" on the CPU (PERF.md §5/§6,
+    #: PR 40: the chip's A/B in backlog-1chip-r2p16).
+    bucket_cipher_impl: str | None = None
     #: per-request signature scheme: "schnorrkel" (sr25519, byte-compatible
     #: with the reference's sign_schnorrkel clients — README.md:193-199,
     #: session/schnorrkel.py) or "rfc9496" (the same-shape plain Schnorr
@@ -141,9 +146,11 @@ class GrapevineConfig:
             raise ValueError(
                 f"bucket_cipher_rounds must be 0 or an even value >= 8, got {r}"
             )
-        if self.bucket_cipher_impl not in ("jnp", "pallas", "pallas_fused"):
+        if self.bucket_cipher_impl not in (
+            None, "jnp", "pallas", "pallas_fused"
+        ):
             raise ValueError(
-                f"bucket_cipher_impl must be 'jnp', 'pallas' or "
+                f"bucket_cipher_impl must be None, 'jnp', 'pallas' or "
                 f"'pallas_fused', got {self.bucket_cipher_impl!r}"
             )
         if self.signature_scheme not in ("schnorrkel", "rfc9496"):

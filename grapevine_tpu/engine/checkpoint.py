@@ -49,7 +49,10 @@ from ..testing import faults
 from .state import EngineConfig, EngineState, state_spec
 
 MAGIC = b"GVCKPT1\0"
-VERSION = 1
+#: 2 since PR 40: the bucket cipher's keystream order changed
+#: (oblivious/bucket_cipher.py), so trees sealed under version 1 would
+#: decrypt to noise. Refused, not migrated: re-initialise the state.
+VERSION = 2
 
 _CKPT_RE = re.compile(r"^ckpt-(\d{16})\.sealed$")
 

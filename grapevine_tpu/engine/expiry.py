@@ -32,7 +32,11 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from ..oblivious.bucket_cipher import epoch_next, row_keystream
+from ..oblivious.bucket_cipher import (
+    epoch_next,
+    row_keystream,
+    row_plane_keystreams,
+)
 from ..oblivious.primitives import SENTINEL, is_zero_words, u64_le, u64_sub
 from ..oblivious.radix import partition_rank
 from ..obs.phases import device_phase
@@ -151,19 +155,19 @@ def _chunked_tree_sweep(cfg: OramConfig, oram: OramState, carry0, body,
         vl = cut(val_p, i, rpc)
         ep = cut(oram.nonces, i, rpc)
         if cfg.encrypted:
-            ks = row_keystream(
-                oram.cipher_key, bid, ep, cfg.row_words, cfg.cipher_rounds
+            ks_ix, ks_vl = row_plane_keystreams(
+                oram.cipher_key, bid, ep, z, cfg.row_words, cfg.cipher_rounds
             )
-            ix = ix ^ ks[:, :z]
-            vl = vl ^ ks[:, z:]
+            ix = ix ^ ks_ix
+            vl = vl ^ ks_vl
         acc, (ix, vl) = body(acc, (ix, vl))
         if cfg.encrypted:
             epn = jnp.broadcast_to(oram.epoch[None, :], (rpc, 2))
-            ks = row_keystream(
-                oram.cipher_key, bid, epn, cfg.row_words, cfg.cipher_rounds
+            ks_ix, ks_vl = row_plane_keystreams(
+                oram.cipher_key, bid, epn, z, cfg.row_words, cfg.cipher_rounds
             )
-            ix = ix ^ ks[:, :z]
-            vl = vl ^ ks[:, z:]
+            ix = ix ^ ks_ix
+            vl = vl ^ ks_vl
             if recrypt_leaf:
                 # leaf-plane stream: same (bucket, epoch), bucket word
                 # offset by n_buckets_padded (path_oram.leaf_plane_cipher

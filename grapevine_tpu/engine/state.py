@@ -112,7 +112,8 @@ class EngineConfig:
         mb_value_words = k * (KEY_WORDS + ENTRY_WORDS * cfg.mailbox_cap)
         vimpl = cfg.vphases_impl
         simpl = cfg.sort_impl
-        if vimpl is None or simpl is None:
+        cimpl = cfg.bucket_cipher_impl
+        if vimpl is None or simpl is None or cimpl is None:
             # per-backend defaults: the MXU eats the [B,B] masks and
             # lowers lax.sort to a parallel bitonic network; scalar
             # backends pay O(B²) masks and *serial* comparison sorts
@@ -133,6 +134,11 @@ class EngineConfig:
                 # the decision belongs to a `sort_perf` A/B on a real
                 # chip: not measured on the chip.
                 simpl = "xla"
+            if cimpl is None:
+                # the one-pass Pallas kernel where Mosaic compiles it;
+                # the CPU would run it in interpret mode, a step a
+                # row tile (PERF.md §6, PR 40: the chip's A/B)
+                cimpl = "pallas" if on_tpu() else "jnp"
         # position-map impl: auto resolves to "flat" on every backend —
         # the recursive map trades ~2× HBM path traffic per round for a
         # ~sqrt(blocks)× smaller resident footprint, a win only once
@@ -181,7 +187,7 @@ class EngineConfig:
                 bucket_slots=cfg.bucket_slots,
                 stash_size=cfg.stash_size,
                 cipher_rounds=cfg.bucket_cipher_rounds,
-                cipher_impl=cfg.bucket_cipher_impl,
+                cipher_impl=cimpl,
                 n_blocks=cfg.max_messages,
                 posmap=rec_pm,
                 top_cache_levels=rec_tc,
@@ -192,7 +198,7 @@ class EngineConfig:
                 bucket_slots=cfg.bucket_slots,
                 stash_size=cfg.stash_size,
                 cipher_rounds=cfg.bucket_cipher_rounds,
-                cipher_impl=cfg.bucket_cipher_impl,
+                cipher_impl=cimpl,
                 n_blocks=m,
                 posmap=mb_pm,
                 top_cache_levels=mb_tc,

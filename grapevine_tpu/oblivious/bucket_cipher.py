@@ -15,16 +15,42 @@ heap index, and a per-write epoch nonce, so
   already reveals.
 
 Cipher: RFC 7539 ChaCha block function on the 16-word state
-``[consts | key(8) | block_ctr | bucket | epoch | 0]`` — i.e. standard
-ChaCha with counter = in-row block index and nonce = (bucket, epoch, 0),
-vectorized over rows and blocks in pure jnp (fully fused by XLA; the
-MXU is untouched, this rides the VPU). ``rounds`` is configurable:
+``[consts | key(8) | block_ctr | bucket | epoch_lo | epoch_hi]`` — i.e.
+standard ChaCha with counter = in-row block index and nonce = (bucket,
+epoch), vectorized over rows and blocks (the MXU is untouched, this
+rides the VPU). ``rounds`` is configurable:
 20 = RFC ChaCha20; the engine default is 8 (ChaCha8, unbroken, standard
 in perf-sensitive deployments) because keystream cost scales linearly
 with rounds. SURVEY.md §7 hard-part 3 names AES-CTR with a documented
 fallback: this is that documented fallback — AES without AES-NI/VPU
 byte-shuffles would be a bitsliced Pallas project for strictly worse
 throughput at no security gain over ChaCha.
+
+Stream order (the ONE definition; :func:`stream_tiles` and
+:func:`group_words`, which the jnp path below and the Pallas kernels of
+pallas_cipher.py / pallas_gather.py all go through): stream position ``p``
+of a row is **state word ``(p // 128) % 16`` of the block whose counter
+is ``(p // 2048) * 128 + p % 128``**. So every 128-lane tile ``q`` of
+the stream is one whole state word (``q % 16``) of one group of 128
+blocks (``q // 16``): a ``[rows, 128]`` block computation yields sixteen
+whole lane tiles, and nothing is ever interleaved, concatenated off a
+tile boundary or relaid. A bucket row spends its stream on its ``Z*V``
+value words first and its ``Z`` slot-index words after them
+(:func:`row_plane_keystreams`), so the wide value plane starts on
+tile 0. A fixed permutation of the same ChaCha output — PRF security
+does not depend on the order — and the at-rest format since checkpoint
+version 2 (version 1 took state word ``m // n_blocks`` of block
+``m % n_blocks``, index words first).
+
+What the compiler makes of the jnp path (described v5e, PERF.md §5,
+PR 40): it is NOT fused into one pass. XLA splits the rounds over
+multi-output fusions that hand the state words to each other through
+HBM and assembles the keystream there before the XOR reads it; the
+tile-aligned order spares the relayout and the masked copy, not the
+keystream's round trip. On a TPU the engine therefore resolves
+``bucket_cipher_impl`` to the Pallas kernel (pallas_cipher.py), which
+makes the keystream in VMEM and XORs it where it is made; this module
+stays the reference, the CPU path and what the tests compare against.
 
 Epoch-0 convention: ``nonce == 0`` marks a never-written bucket and maps
 to the identity keystream (the all-zero initial tree is its own
@@ -65,19 +91,23 @@ def _qr(s, a, b, c, d):
     s[b] = _rotl(s[b] ^ s[c], 7)
 
 
-def chacha_blocks(
-    key: jax.Array,  # u32[8]
-    counter: jax.Array,  # u32[...] block counter per lane
-    n1: jax.Array,  # u32[...] nonce word 1 (bucket heap index)
-    n2: jax.Array,  # u32[...] nonce word 2 (write epoch, low word)
-    n3: jax.Array | None = None,  # u32[...] nonce word 3 (epoch, high word)
-    rounds: int = 8,
-) -> jax.Array:
-    """ChaCha block function, vectorized: → u32[..., 16] keystream."""
-    zero = jnp.zeros_like(counter) if n3 is None else jnp.broadcast_to(n3, counter.shape)
-    init = [jnp.broadcast_to(U32(c), counter.shape) for c in _SIGMA]
-    init += [jnp.broadcast_to(key[i], counter.shape) for i in range(8)]
-    init += [counter, n1, n2, zero]
+#: stream positions that one state word of a block group covers: the
+#: TPU's lane count, so a state word of 128 blocks is one lane tile
+LANES = 128
+#: stream positions per group of LANES blocks (16 state words each)
+GROUP_WORDS = 16 * LANES
+
+
+def chacha_words(key, counter, n1, n2, n3, rounds: int = 8) -> list:
+    """ChaCha block function → the 16 output words, each ``counter``'s
+    shape. ``key`` is anything ``key[i]`` indexes into eight u32 scalars
+    (an array here, words read from a kernel's ref in Pallas): the ONE
+    copy of the round schedule and the feedforward every
+    implementation runs."""
+    shape = counter.shape
+    init = [jnp.full(shape, c, U32) for c in _SIGMA]
+    init += [jnp.broadcast_to(key[i], shape) for i in range(8)]
+    init += [counter] + [jnp.broadcast_to(n, shape) for n in (n1, n2, n3)]
     s = list(init)
     for _ in range(rounds // 2):
         _qr(s, 0, 4, 8, 12)
@@ -94,7 +124,58 @@ def chacha_blocks(
     out = []
     for a, b in zip(s, init):
         out.append(a + b)
-    return jnp.stack(out, axis=-1)
+    return out
+
+
+def chacha_blocks(
+    key: jax.Array,  # u32[8]
+    counter: jax.Array,  # u32[...] block counter per lane
+    n1: jax.Array,  # u32[...] nonce word 1 (bucket heap index)
+    n2: jax.Array,  # u32[...] nonce word 2 (write epoch, low word)
+    n3: jax.Array | None = None,  # u32[...] nonce word 3 (epoch, high word)
+    rounds: int = 8,
+) -> jax.Array:
+    """ChaCha block function, vectorized: → u32[..., 16] keystream."""
+    n3 = U32(0) if n3 is None else n3
+    return jnp.stack(chacha_words(key, counter, n1, n2, n3, rounds), axis=-1)
+
+
+def stream_tiles(n_words: int):
+    """The stream order, tile by tile: ``(group, word, start, width)``
+    for every lane tile of an ``n_words`` stream — positions
+    ``[start, start + width)`` are lanes ``[0, width)`` of state word
+    ``word`` of block group ``group`` (:func:`group_words`). Only the
+    last tile can be narrower than ``LANES``."""
+    for q in range(-(-n_words // LANES)):
+        start = q * LANES
+        yield q // 16, q % 16, start, min(LANES, n_words - start)
+
+
+def group_words(key, lane, n1, n2, n3, group: int, rounds: int) -> list:
+    """The 16 state words of block group ``group``: lane ``l`` of each
+    is that word of the block whose counter is ``group * LANES + l``.
+    ``lane`` is the u32 lane index, ``[rows, lanes]``; the nonce words
+    broadcast against it."""
+    return chacha_words(key, lane + U32(group * LANES), n1, n2, n3, rounds)
+
+
+def keystream_tile(key, n1, n2, n3, rows: int, n_words: int, rounds: int):
+    """ChaCha keystream u32[rows, n_words] in stream order, unmasked,
+    for rows whose nonce words are ``n1/n2/n3`` ([rows, 1] or scalars)
+    and ``key`` eight u32 scalars: the stream as ONE array, for the jnp
+    path and for the kernels that want it so (pallas_gather.py's one-row
+    fetch and write-back; pallas_cipher.py's XOR kernel stores tile by
+    tile instead). A stream under one lane tile computes only its own
+    blocks of state word 0."""
+    lanes = min(LANES, n_words)
+    # 2-D iota: the one form Mosaic takes, and jnp alike
+    lane = jax.lax.broadcasted_iota(U32, (rows, lanes), 1)
+    tiles = []
+    for group, word, _, width in stream_tiles(n_words):
+        if word == 0:
+            words = group_words(key, lane, n1, n2, n3, group, rounds)
+        tiles.append(words[word][:, :width])
+    return jnp.concatenate(tiles, axis=1)
 
 
 def row_keystream(
@@ -104,7 +185,8 @@ def row_keystream(
     n_words: int,
     rounds: int = 8,
 ) -> jax.Array:
-    """Keystream rows u32[R, n_words]; zero rows where epoch == 0.
+    """Keystream rows u32[R, n_words] in stream order (module
+    docstring); zero rows where epoch == 0.
 
     The epoch is 64 bits across two nonce words, so the per-round write
     counter cannot wrap within any feasible bus lifetime — a u32 epoch
@@ -112,21 +194,28 @@ def row_keystream(
     one access in plaintext (epoch 0) and replaying every historical
     (bucket, epoch) pair into a two-time pad for a snapshot-diffing
     operator."""
-    r = bucket.shape[0]
-    n_blocks = (n_words + 15) // 16
-    ctr = jnp.broadcast_to(
-        jnp.arange(n_blocks, dtype=U32)[None, :], (r, n_blocks)
+    ks = keystream_tile(
+        key, bucket[:, None], epoch[:, 0:1], epoch[:, 1:2],
+        bucket.shape[0], n_words, rounds,
     )
-    ks = chacha_blocks(
-        key, ctr, bucket[:, None], epoch[:, None, 0], epoch[:, None, 1], rounds
-    )  # [r, n_blocks, 16]
-    # j-major stream order: all blocks' word 0, then word 1, … — a fixed
-    # permutation of the stream (PRF security is order-independent) that
-    # keeps each of the 16 state words contiguous along the lane axis,
-    # matching the Pallas kernel's layout (concatenate, no interleave)
-    ks = jnp.swapaxes(ks, -1, -2).reshape(r, n_blocks * 16)[:, :n_words]
     written = (epoch[:, 0] != 0) | (epoch[:, 1] != 0)
     return jnp.where(written[:, None], ks, U32(0))
+
+
+def row_plane_keystreams(
+    key: jax.Array,  # u32[8]
+    bucket: jax.Array,  # u32[R]
+    epoch: jax.Array,  # u32[R, 2]
+    index_words: int,  # Z
+    n_words: int,  # Z + Z*V, the whole bucket row
+    rounds: int = 8,
+):
+    """``(ks_idx u32[R, Z], ks_val u32[R, Z*V])`` of bucket rows: the
+    value words take the head of the row's stream, so the wide plane is
+    tile-aligned, and the slot-index words follow them. Where a row's
+    two planes meet their keystream is said here and nowhere else."""
+    ks = row_keystream(key, bucket, epoch, n_words, rounds)
+    return ks[:, n_words - index_words:], ks[:, : n_words - index_words]
 
 
 def epoch_next(epoch: jax.Array) -> jax.Array:

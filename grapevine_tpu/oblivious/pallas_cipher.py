@@ -1,27 +1,33 @@
-"""Pallas TPU kernel: fused ChaCha keystream + XOR over bucket rows.
+"""Pallas TPU kernel: ChaCha keystream made and XORed in one pass.
 
-The jnp cipher path (bucket_cipher.row_keystream) materializes the full
-keystream in HBM — at B=2048 on the records tree that is an extra
-~170 MB written and re-read per round, pure HBM-bandwidth overhead
-(PERF.md "next levers" 2). This kernel generates the keystream in VMEM
-tile by tile and XORs it into the row data in the same pass: one HBM
-read + one HBM write per row, no keystream traffic. The slot-index and
-value arrays are separate kernel refs, so no concatenated staging copy
-is made either.
+The jnp cipher path (bucket_cipher.row_keystream) hands the ChaCha
+state and then the whole keystream through HBM (bucket_cipher.py's
+docstring says what the compiler makes of it). This kernel makes the
+keystream in VMEM, one lane tile at a time, and XORs each tile into the
+row block where it is made: one HBM read + one HBM write per row, no
+keystream, state plane, concatenate, reshape or masked copy in HBM.
+The slot-index and value arrays are separate kernel refs, so no
+concatenated staging copy is made either, and the grid's last step is
+a partial block where the row tile does not divide the row count — no
+operand is padded.
 
-Layout: the keystream uses the j-major stream order defined by
-``row_keystream`` — word ``m`` of a row comes from ChaCha state word
-``m // n_blocks`` of block ``m % n_blocks`` — so each of the 16 output
-state arrays ([rows, n_blocks]) is a *contiguous lane range* of the
-keystream tile and assembly is a concatenate, not a 16-way interleave.
-The ChaCha core itself (quarter-round, constants, round schedule) is
-imported from bucket_cipher so the two implementations cannot drift;
+Layout: the stream order defined once in bucket_cipher.py
+(``stream_tiles`` / ``group_words``): lane tile ``q`` of a row's stream
+is state word ``q % 16`` of the 128 blocks of group ``q // 16``, the
+value plane takes the head of the stream and the slot-index words the
+positions after it. So per block group the kernel computes sixteen
+``[rows, 128]`` state arrays and stores each straight into its own lane
+tile of the value block; the index words come out of the tile the value
+plane ends in (lanes 64-67 of tile 47 for the 6,080-word mailbox row,
+lanes 0-3 of tile 8 for the 1,024-word records row). The ChaCha core
+itself (quarter-round, constants, round schedule, feedforward) is
+bucket_cipher's ``chacha_words``, so the implementations cannot drift;
 bit-identical ciphertext is asserted by tests/test_pallas_cipher.py,
 making engine states interchangeable between impls.
 
 Off-TPU the kernel runs in Pallas interpret mode (CI's CPU backend —
-the SGX_MODE=SW analog), so the selection knob is safe everywhere;
-``cipher_impl="pallas"`` on real TPU compiles the Mosaic kernel.
+the SGX_MODE=SW analog), so the selection knob is safe everywhere; on a
+TPU Mosaic compiles it and the engine resolves to it by default.
 """
 
 from __future__ import annotations
@@ -31,58 +37,61 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from .bucket_cipher import _SIGMA, _qr
+from .bucket_cipher import LANES, group_words, stream_tiles
 
 U32 = jnp.uint32
 
-#: VMEM budget per input/output tile (bytes) used to pick the row tile
-_TILE_BYTES = 1 << 21
-
-
-def keystream_tile(key_ref, n1, n2, n3, nb, rounds):
-    """ChaCha keystream for a [TR, nb]-shaped tile of rows, j-major.
-
-    ``n1/n2/n3`` are the per-row nonce words broadcast to [TR, nb];
-    the counter word is the block index within the row. The ONE copy
-    of the in-kernel ChaCha block shared by every Pallas cipher kernel
-    (this module's XOR kernel and pallas_gather.py's fused fetch and
-    write-back) — the round schedule and state layout cannot drift
-    between them."""
-    tr = n1.shape[0]
-    ctr = jax.lax.broadcasted_iota(U32, (tr, nb), 1)
-    init = [jnp.full((tr, nb), U32(c)) for c in _SIGMA]
-    init += [jnp.broadcast_to(key_ref[0, i], (tr, nb)) for i in range(8)]
-    init += [ctr, n1, n2, n3]
-    s = list(init)
-    for _ in range(rounds // 2):
-        _qr(s, 0, 4, 8, 12)
-        _qr(s, 1, 5, 9, 13)
-        _qr(s, 2, 6, 10, 14)
-        _qr(s, 3, 7, 11, 15)
-        _qr(s, 0, 5, 10, 15)
-        _qr(s, 1, 6, 11, 12)
-        _qr(s, 2, 7, 8, 13)
-        _qr(s, 3, 4, 9, 14)
-    # j-major assembly: 16 contiguous [TR, nb] lane ranges
-    return jnp.concatenate([a + b for a, b in zip(s, init)], axis=1)
+#: rows per grid step: one DMA block each way (64 mailbox rows are
+#: 1.5 MB; in + out, double-buffered, 6.2 MB of VMEM). On the chip
+#: (PERF.md §5, PR 40) 16 / 32 / 64 / 128 rows read 1.75 / 1.60 / 1.54 /
+#: 1.54 ms a pass of 20,464 mailbox rows
+_ROW_TILE = 64
+#: rows the ChaCha state is held for at a time: [16, 128] is two vregs
+#: a state word, the sixteen words 32 of the 64. 8 rows read the same
+#: on the mailbox row (the DMA is the limit there) and 0.79 against
+#: 0.52 ms on the records row, whose one group uses nine words of its
+#: sixteen: there the vector unit is the limit
+_SUB_ROWS = 16
 
 
 def _cipher_kernel(
     key_ref, bucket_ref, epoch_ref, idx_ref, val_ref, oidx_ref, oval_ref,
-    *, nb, z, n_words, rounds,
+    *, sub, z, zv, rounds,
 ):
-    """One row tile: (idx [TR, z], val [TR, W-z]) ^= keystream rows."""
-    tr = idx_ref.shape[0]
-    n1 = jnp.broadcast_to(bucket_ref[:, 0][:, None], (tr, nb))
-    n2 = jnp.broadcast_to(epoch_ref[:, 0][:, None], (tr, nb))
-    n3 = jnp.broadcast_to(epoch_ref[:, 1][:, None], (tr, nb))
-    ks = keystream_tile(key_ref, n1, n2, n3, nb, rounds)
-    written = ((epoch_ref[:, 0] != U32(0)) | (epoch_ref[:, 1] != U32(0)))[:, None]
-    oidx_ref[:, :] = idx_ref[:, :] ^ jnp.where(written, ks[:, :z], U32(0))
-    oval_ref[:, :] = val_ref[:, :] ^ jnp.where(
-        written, ks[:, z:n_words], U32(0)
-    )
+    """One row block: (idx [TR, z], val [TR, zv]) ^= keystream rows,
+    ``sub`` rows at a time."""
+    key = [key_ref[i] for i in range(8)]
+    lane = jax.lax.broadcasted_iota(U32, (sub, LANES), 1)
+
+    def sub_tile(s, carry):
+        rows = pl.ds(pl.multiple_of(s * sub, sub), sub)
+        n1 = bucket_ref[rows, :]
+        n2 = epoch_ref[rows, 0:1]
+        n3 = epoch_ref[rows, 1:2]
+        # epoch 0 = never written = identity; computed all the same, so
+        # the work is content-independent
+        written = jnp.broadcast_to((n2 != U32(0)) | (n3 != U32(0)), (sub, LANES))
+        for group, word, start, width in stream_tiles(zv + z):
+            if word == 0:
+                words = group_words(key, lane, n1, n2, n3, group, rounds)
+            ks = jnp.where(written, words[word], U32(0))
+            end = start + width
+            if start < zv:  # this tile's value words
+                cols = slice(start, min(end, zv))
+                oval_ref[rows, cols] = (
+                    val_ref[rows, cols] ^ ks[:, : cols.stop - start]
+                )
+            if end > zv:  # and its slot-index words
+                lo = max(start, zv)
+                cols = slice(lo - zv, end - zv)
+                oidx_ref[rows, cols] = (
+                    idx_ref[rows, cols] ^ ks[:, lo - start: width]
+                )
+        return carry
+
+    jax.lax.fori_loop(0, val_ref.shape[0] // sub, sub_tile, 0)
 
 
 @functools.partial(jax.jit, static_argnames=("rounds", "interpret"))
@@ -91,49 +100,36 @@ def cipher_rows_pallas(
     bucket: jax.Array,  # u32[R]
     epoch: jax.Array,  # u32[R, 2]; 0 = identity (never written)
     pidx: jax.Array,  # u32[R, z] slot-index words
-    pval: jax.Array,  # u32[R, W-z] value words
+    pval: jax.Array,  # u32[R, zv] value words
     rounds: int = 8,
     interpret: bool = False,
 ):
     """Fused ``row ^ keystream``; returns (pidx', pval'), both u32."""
     r, z = pidx.shape
-    w = z + pval.shape[1]
-    nb = (w + 15) // 16
-    # Mosaic tiling: the row tile is the second-minor block dim of every
-    # rank-2 operand, so it must be a multiple of 8 (the u32 sublane
-    # count); the budget-derived value is rounded down to keep VMEM
-    # bounded, with 8 as the floor
-    tr = max(8, min(512, _TILE_BYTES // max(1, 16 * nb * 4)) // 8 * 8)
-    # pad rows to a tile multiple; padded rows carry epoch 0 (identity)
-    r_pad = -(-r // tr) * tr
-    if r_pad != r:
-        pad = r_pad - r
-        bucket = jnp.pad(bucket, (0, pad))
-        epoch = jnp.pad(epoch, ((0, pad), (0, 0)))
-        pidx = jnp.pad(pidx, ((0, pad), (0, 0)))
-        pval = jnp.pad(pval, ((0, pad), (0, 0)))
-    oidx, oval = pl.pallas_call(
+    zv = pval.shape[1]
+    sub = _SUB_ROWS
+    # the row tile is the second-minor block dim of every operand: a
+    # multiple of the u32 sublane count, no larger than the rows need
+    tr = min(_ROW_TILE, -(-r // sub) * sub)
+    row_block = lambda width: pl.BlockSpec((tr, width), lambda i: (i, 0))  # noqa: E731
+    return pl.pallas_call(
         functools.partial(
-            _cipher_kernel, nb=nb, z=z, n_words=w, rounds=rounds
+            _cipher_kernel, sub=sub, z=z, zv=zv, rounds=rounds
         ),
-        grid=(r_pad // tr,),
+        grid=(pl.cdiv(r, tr),),
         in_specs=[
-            pl.BlockSpec((1, 8), lambda i: (0, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             # rank-1 blocks must tile by 128 on TPU; carry the bucket id
             # as a [rows, 1] column instead so tr only needs 8-alignment
-            pl.BlockSpec((tr, 1), lambda i: (i, 0)),
-            pl.BlockSpec((tr, 2), lambda i: (i, 0)),
-            pl.BlockSpec((tr, z), lambda i: (i, 0)),
-            pl.BlockSpec((tr, w - z), lambda i: (i, 0)),
+            row_block(1),
+            row_block(2),
+            row_block(z),
+            row_block(zv),
         ],
-        out_specs=[
-            pl.BlockSpec((tr, z), lambda i: (i, 0)),
-            pl.BlockSpec((tr, w - z), lambda i: (i, 0)),
-        ],
+        out_specs=[row_block(z), row_block(zv)],
         out_shape=[
-            jax.ShapeDtypeStruct((r_pad, z), U32),
-            jax.ShapeDtypeStruct((r_pad, w - z), U32),
+            jax.ShapeDtypeStruct((r, z), U32),
+            jax.ShapeDtypeStruct((r, zv), U32),
         ],
         interpret=interpret,
-    )(key[None, :], bucket[:, None], epoch, pidx, pval)
-    return oidx[:r], oval[:r]
+    )(key, bucket[:, None], epoch, pidx, pval)

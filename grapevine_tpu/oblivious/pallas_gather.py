@@ -37,9 +37,14 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .pallas_cipher import keystream_tile
+from .bucket_cipher import keystream_tile
 
 U32 = jnp.uint32
+
+
+def _key_words(key_ref):
+    """The eight key words of a u32[1, 1, 8] ref, as scalars."""
+    return [key_ref[0, 0, i] for i in range(8)]
 
 
 def _gather_kernel(
@@ -51,7 +56,6 @@ def _gather_kernel(
     oidx_ref,  # u32[1, 1, z]
     oval_ref,  # u32[1, 1, z*v]
     *,
-    nb,
     z,
     n_words,
     rounds,
@@ -60,20 +64,16 @@ def _gather_kernel(
     # dims be 8/128-divisible or equal to the array dims, and a gather
     # block is one non-contiguous row — so rows live on a leading
     # (untiled) axis and the trailing (1, width) plane equals the array
-    i = pl.program_id(0)
-    bid = bucket_ref[i]
-    n1 = jnp.full((1, nb), bid, U32)
-    n2 = jnp.broadcast_to(nonce_row_ref[0, 0, 0], (1, nb))
-    n3 = jnp.broadcast_to(nonce_row_ref[0, 0, 1], (1, nb))
-    ks = keystream_tile(key_ref[0], n1, n2, n3, nb, rounds)
-    written = (
-        (nonce_row_ref[0, 0, 0] != U32(0)) | (nonce_row_ref[0, 0, 1] != U32(0))
+    bid = bucket_ref[pl.program_id(0)]
+    lo, hi = nonce_row_ref[0, 0, 0], nonce_row_ref[0, 0, 1]
+    ks = keystream_tile(_key_words(key_ref), bid, lo, hi, 1, n_words, rounds)
+    written = (lo != U32(0)) | (hi != U32(0))
+    # stream order (bucket_cipher.py): the value words, then the index
+    oval_ref[0, 0, :] = val_row_ref[0, 0, :] ^ jnp.where(
+        written, ks[0, : n_words - z], U32(0)
     )
     oidx_ref[0, 0, :] = idx_row_ref[0, 0, :] ^ jnp.where(
-        written, ks[0, :z], U32(0)
-    )
-    oval_ref[0, 0, :] = val_row_ref[0, 0, :] ^ jnp.where(
-        written, ks[0, z:n_words], U32(0)
+        written, ks[0, n_words - z:], U32(0)
     )
 
 
@@ -99,7 +99,6 @@ def gather_decrypt_rows(
     zv = tree_val.shape[1]
     r = flat_b.shape[0]
     w = z + zv
-    nb = (w + 15) // 16
     idx_rows = tree_idx.reshape(n_padded, z)
     if rounds == 0:
         # no cipher: plain dynamic-slice gather (XLA emits one pass)
@@ -127,7 +126,7 @@ def gather_decrypt_rows(
     )
     oidx, oval = pl.pallas_call(
         functools.partial(
-            _gather_kernel, nb=nb, z=z, n_words=w, rounds=rounds
+            _gather_kernel, z=z, n_words=w, rounds=rounds
         ),
         grid_spec=grid_spec,
         out_shape=[
@@ -153,20 +152,18 @@ def _scatter_kernel(
     otree_val_ref,  # u32[1, 1, zv]  aliased tree_val row bucket_ref[i]
     ononce_ref,  # u32[1, 1, 2]     aliased nonce row bucket_ref[i]
     *,
-    nb,
     z,
     n_words,
     rounds,
 ):
     # rank-3 refs for the same Mosaic tiling reason as _gather_kernel
-    i = pl.program_id(0)
-    bid = bucket_ref[i]
-    n1 = jnp.full((1, nb), bid, U32)
-    n2 = jnp.broadcast_to(epoch_ref[0, 0, 0], (1, nb))
-    n3 = jnp.broadcast_to(epoch_ref[0, 0, 1], (1, nb))
-    ks = keystream_tile(key_ref[0], n1, n2, n3, nb, rounds)
-    otree_idx_ref[0, 0, :] = idx_new_ref[0, 0, :] ^ ks[0, :z]
-    otree_val_ref[0, 0, :] = val_new_ref[0, 0, :] ^ ks[0, z:n_words]
+    bid = bucket_ref[pl.program_id(0)]
+    ks = keystream_tile(
+        _key_words(key_ref), bid, epoch_ref[0, 0, 0], epoch_ref[0, 0, 1],
+        1, n_words, rounds,
+    )
+    otree_val_ref[0, 0, :] = val_new_ref[0, 0, :] ^ ks[0, : n_words - z]
+    otree_idx_ref[0, 0, :] = idx_new_ref[0, 0, :] ^ ks[0, n_words - z:]
     # the write epoch rides the same pass — the separate XLA nonce
     # scatter the jnp path pays (round.py) has no fused-path cost at all
     ononce_ref[0, 0, :] = epoch_ref[0, 0, :]
@@ -211,7 +208,6 @@ def scatter_encrypt_rows(
     zv = tree_val.shape[1]
     r = flat_b.shape[0]
     w = z + zv
-    nb = (w + 15) // 16
     idx_rows = tree_idx.reshape(n_padded, z)
     # non-owners write the junk row (n_padded - 1: heap indices stop at
     # n_buckets = n_padded - 1, see OramConfig.n_buckets_padded)
@@ -246,7 +242,7 @@ def scatter_encrypt_rows(
     )
     oidx, oval, ononce = pl.pallas_call(
         functools.partial(
-            _scatter_kernel, nb=nb, z=z, n_words=w, rounds=rounds
+            _scatter_kernel, z=z, n_words=w, rounds=rounds
         ),
         grid_spec=grid_spec,
         out_shape=[

@@ -57,7 +57,11 @@ import jax
 from ..config import on_tpu as _on_tpu
 import jax.numpy as jnp
 
-from ..oblivious.bucket_cipher import epoch_next, row_keystream  # noqa: F401  (row_keystream used by cipher_rows)
+from ..oblivious.bucket_cipher import (  # noqa: F401  (row_keystream: leaf plane)
+    epoch_next,
+    row_keystream,
+    row_plane_keystreams,
+)
 from ..oblivious.primitives import SENTINEL, first_true_onehot, onehot_select, rank_of
 from ..obs.phases import device_phase
 
@@ -136,9 +140,10 @@ def cipher_rows(
 ):
     """XOR bucket rows with their keystream (encrypt ≡ decrypt).
 
-    One ChaCha stream per (bucket, epoch) covers the Z slot-index words
-    followed by the Z*V value words — a memory snapshot of the tree
-    arrays reveals neither slot occupancy nor contents.
+    One ChaCha stream per (bucket, epoch) covers the Z*V value words
+    followed by the Z slot-index words (the stream order of
+    oblivious/bucket_cipher.py) — a memory snapshot of the tree arrays
+    reveals neither slot occupancy nor contents.
 
     ``cfg.cipher_impl == "pallas"`` routes through the fused Pallas
     kernel (keystream generated in VMEM and XORed in one pass — no HBM
@@ -170,8 +175,10 @@ def cipher_rows(
             key, buckets, epochs, pidx, pval, cfg.cipher_rounds,
             interpret=interpret,
         )
-    ks = row_keystream(key, buckets, epochs, cfg.row_words, cfg.cipher_rounds)
-    return pidx ^ ks[:, :z], pval ^ ks[:, z:]
+    ks_idx, ks_val = row_plane_keystreams(
+        key, buckets, epochs, z, cfg.row_words, cfg.cipher_rounds
+    )
+    return pidx ^ ks_idx, pval ^ ks_val
 
 
 @dataclasses.dataclass(frozen=True)
@@ -193,8 +200,10 @@ class OramConfig:
     #: ChaCha rounds for at-rest bucket encryption; 0 disables the
     #: cipher (oblivious/bucket_cipher.py — the EPC-encryption analog)
     cipher_rounds: int = 0
-    #: "jnp" or "pallas" (fused VMEM keystream+XOR kernel; see
-    #: cipher_rows and oblivious/pallas_cipher.py)
+    #: "jnp", "pallas" (the one-pass VMEM keystream+XOR kernel; see
+    #: cipher_rows and oblivious/pallas_cipher.py) or "pallas_fused";
+    #: EngineConfig.from_config resolves the engine's trees to "pallas"
+    #: on a TPU; posmap.py pins the recursive map's inner tree to "jnp"
     cipher_impl: str = "jnp"
     #: logical block index space [0, n_blocks); None = leaves
     n_blocks: int | None = None

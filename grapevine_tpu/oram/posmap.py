@@ -509,7 +509,7 @@ def read_table(cfg, pm_state):
 
     if cfg.posmap is None:
         return np.asarray(pm_state)[: cfg.blocks].copy()
-    from ..oblivious.bucket_cipher import row_keystream
+    from ..oblivious.bucket_cipher import row_plane_keystreams
     from ..oblivious.primitives import SENTINEL
 
     spec = cfg.posmap
@@ -520,12 +520,12 @@ def read_table(cfg, pm_state):
     tval = np.asarray(inner.tree_val)
     if icfg.encrypted:
         buckets = jnp.arange(icfg.n_buckets_padded, dtype=U32)
-        ks = np.asarray(row_keystream(
-            inner.cipher_key, buckets, inner.nonces, icfg.row_words,
+        ks_idx, ks_val = row_plane_keystreams(
+            inner.cipher_key, buckets, inner.nonces, z, icfg.row_words,
             icfg.cipher_rounds,
-        ))
-        tidx = tidx ^ ks[:, :z]
-        tval = tval ^ ks[:, z:]
+        )
+        tidx = tidx ^ np.asarray(ks_idx)
+        tval = tval ^ np.asarray(ks_val)
     out = np.zeros((cfg.blocks,), np.uint32)
     seen = np.zeros((spec.inner_blocks,), bool)
     rows = tval.reshape(-1, k)

@@ -63,7 +63,7 @@ def logical_tree_planes(cfg, oram):
     scalars are equal (ciphertext at cached levels legitimately
     diverges — the cached run never re-encrypts them).
     """
-    from ..oblivious.bucket_cipher import row_keystream
+    from ..oblivious.bucket_cipher import row_keystream, row_plane_keystreams
     import jax.numpy as jnp
 
     z, v = cfg.bucket_slots, cfg.value_words
@@ -77,14 +77,12 @@ def logical_tree_planes(cfg, oram):
     )
     if cfg.encrypted:
         buckets = jnp.arange(n, dtype=jnp.uint32)
-        ks = np.asarray(
-            row_keystream(
-                oram.cipher_key, buckets, oram.nonces, cfg.row_words,
-                cfg.cipher_rounds,
-            )
+        ks_idx, ks_val = row_plane_keystreams(
+            oram.cipher_key, buckets, oram.nonces, z, cfg.row_words,
+            cfg.cipher_rounds,
         )
-        idx ^= ks[:, :z]
-        val ^= ks[:, z:]
+        idx ^= np.asarray(ks_idx)
+        val ^= np.asarray(ks_val)
         if leaf is not None:
             ksl = np.asarray(
                 row_keystream(

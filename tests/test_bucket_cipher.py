@@ -3,11 +3,13 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from grapevine_tpu.oblivious.bucket_cipher import (
     chacha_blocks,
     epoch_next,
     row_keystream,
+    row_plane_keystreams,
 )
 from grapevine_tpu.session.chacha import ChaCha20
 
@@ -66,6 +68,56 @@ def test_row_keystream_roundtrip_and_epoch0_identity():
     # the high epoch word matters too (64-bit counter; wrap safety)
     ks3 = row_keystream(key, buckets, epochs.at[:, 1].add(U32(1)), 100)
     assert (np.asarray(ks[1]) != np.asarray(ks3[1])).mean() > 0.99
+
+
+@pytest.mark.parametrize(
+    "n_words",
+    [
+        4 + 6080,  # the mailbox row: three block groups, a 64-lane last tile
+        4 + 1024,  # the records row: one group, eight tiles and four lanes
+        4 + 380,   # the row the first chip window refused
+        4 + 96,    # a row under one lane tile
+    ],
+)
+def test_stream_order_is_the_stated_rule(n_words):
+    """The at-rest format (checkpoint version 2), stated against
+    ``chacha_blocks`` itself: stream position p is state word
+    (p // 128) % 16 of the block whose counter is
+    (p // 2048) * 128 + p % 128; a bucket row's value words take the
+    head of the stream and its slot-index words follow."""
+    z = 4
+    zv = n_words - z
+    key = jax.random.bits(jax.random.PRNGKey(7), (8,), U32)
+    buckets = jnp.array([0, 5, 0xFFFF0001], U32)
+    epochs = jnp.array([[3, 0], [0, 0], [9, 2]], U32)  # row 1: never written
+    p = np.arange(n_words)
+    counter, word = (p // 2048) * 128 + p % 128, (p // 128) % 16
+    # every (row, word) has a (block, state word) of its own
+    assert len(set(zip(counter.tolist(), word.tolist()))) == n_words
+    blocks = np.asarray(chacha_blocks(
+        key,
+        jnp.broadcast_to(jnp.asarray(counter, U32)[None, :], (3, n_words)),
+        buckets[:, None], epochs[:, 0:1], epochs[:, 1:2],
+    ))  # [3, n_words, 16]
+    want = blocks[:, p, word]
+    want[1] = 0  # epoch 0 = the identity
+    ks = np.asarray(row_keystream(key, buckets, epochs, n_words))
+    np.testing.assert_array_equal(ks, want)
+    ks_idx, ks_val = row_plane_keystreams(key, buckets, epochs, z, n_words)
+    np.testing.assert_array_equal(np.asarray(ks_val), want[:, :zv])
+    np.testing.assert_array_equal(np.asarray(ks_idx), want[:, zv:])
+    # lane tile q of the value plane is one whole state word, q % 16,
+    # of the 128 blocks [(q // 16) * 128, +128): nothing starts off a
+    # 128-lane boundary
+    lane = jnp.arange(128, dtype=U32)
+    for q in range(zv // 128):
+        tile = np.asarray(chacha_blocks(
+            key, lane + U32((q // 16) * 128), buckets[2], epochs[2, 0],
+            epochs[2, 1],
+        ))[:, q % 16]
+        np.testing.assert_array_equal(
+            np.asarray(ks_val)[2, q * 128:(q + 1) * 128], tile
+        )
 
 
 def test_epoch_next_carries():

@@ -101,6 +101,22 @@ def test_checkpoint_write_load(tmp_path, ecfg, state):
     assert cp.state_to_bytes(ecfg, state2) == cp.state_to_bytes(ecfg, state)
 
 
+def test_version_1_payload_is_refused(tmp_path, ecfg, state, monkeypatch):
+    """Version 2 is the tile-aligned keystream order (PR 40): a tree
+    sealed under version 1 would decrypt to noise, so both checks — the
+    sealed file's header and the state payload's manifest — refuse it
+    and say which version they met."""
+    assert cp.VERSION == 2
+    monkeypatch.setattr(cp, "VERSION", 1)
+    old_payload = cp.state_to_bytes(ecfg, state)
+    old_file = cp.write_checkpoint(str(tmp_path), ROOT, ecfg, state, seq=3)
+    monkeypatch.undo()
+    with pytest.raises(cp.CheckpointError, match="version 1, want 2"):
+        cp.bytes_to_state(ecfg, old_payload)
+    with pytest.raises(cp.CheckpointError, match="version 1, want 2"):
+        cp.load_checkpoint(old_file, ROOT, ecfg)
+
+
 def test_checkpoint_geometry_fingerprint_rejected(tmp_path, ecfg, state):
     path = cp.write_checkpoint(str(tmp_path), ROOT, ecfg, state, seq=1)
     other = EngineConfig.from_config(

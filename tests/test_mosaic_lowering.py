@@ -110,6 +110,53 @@ def test_cipher_kernel_compiles_for_v5e(one_chip, tree):
     assert "tpu_custom_call" in text
 
 
+@pytest.mark.parametrize("zv", [6080, 1024])
+def test_cipher_kernel_compiles_at_the_2p17_mailbox_shape(one_chip, zv):
+    """20,464 fetched rows (a mailbox pass at 2^21 messages / 2^17
+    recipients, no multiple of the 64-row tile: the last grid step is a
+    partial block) of 4 + 6080 words, three block groups with the index
+    words on lanes 64-67 of the last tile, and of 4 + 1024."""
+    text = _compile_for(
+        one_chip, cipher_rows_pallas, _s(8), _s(20464), _s(20464, 2),
+        _s(20464, 4), _s(20464, zv), rounds=8, interpret=False,
+    )
+    assert "tpu_custom_call" in text
+
+
+def test_cipher_rows_on_a_tpu_is_one_kernel_and_no_keystream_buffer(
+    one_chip, monkeypatch
+):
+    """``cipher_rows`` as a TPU engine resolves it: the Mosaic kernel
+    and nothing else of the rows' size — no ``u32[R,381,16]`` state
+    planes or keystream (the j-major order's), no ``[R,6096]`` relayout,
+    no ``[R,6084]`` masked copy. (At the jit boundary of this lone call
+    the compiler transposes the value plane in and out, as it does for
+    any ``u32[n,6080]`` parameter; inside the round the row gathers
+    hand the kernel its ``{1,0}`` operand: PERF.md §5.)"""
+    import re
+
+    from grapevine_tpu.config import GrapevineConfig
+    from grapevine_tpu.engine.state import EngineConfig
+    from grapevine_tpu.oram.path_oram import cipher_rows
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    ecfg = EngineConfig.from_config(GrapevineConfig(
+        max_messages=1 << 21, max_recipients=1 << 17, batch_size=2048,
+        tree_density=2,
+    ))
+    assert ecfg.mb.cipher_impl == "pallas" and ecfg.rec.cipher_impl == "pallas"
+    z, zv = ecfg.mb.bucket_slots, ecfg.mb.bucket_slots * ecfg.mb.value_words
+    assert (z, zv) == (4, 6080)
+    text = _compile_for(
+        one_chip, functools.partial(cipher_rows, ecfg.mb), _s(8), _s(20464),
+        _s(20464, 2), _s(20464, z), _s(20464, zv),
+    )
+    assert "tpu_custom_call" in text
+    assert not re.search(r"u32\[\d+,381[,\]]", text)
+    assert not re.search(r"u32\[\d+,60(?:96|84)\]", text)
+    assert " fusion(" not in text
+
+
 def test_cipher_kernel_compiles_at_the_row_count_the_chip_refused(one_chip):
     """172 rows of 4+380 words: the first chip window's rejection
     (TPURUN_r5.jsonl ``mosaic`` stage), kept as a regression case."""

@@ -9,7 +9,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from grapevine_tpu.oblivious.bucket_cipher import row_keystream
+from grapevine_tpu.oblivious.bucket_cipher import row_plane_keystreams
 from grapevine_tpu.oblivious.pallas_cipher import cipher_rows_pallas
 
 U32 = jnp.uint32
@@ -21,6 +21,9 @@ U32 = jnp.uint32
         (5, 100, 8),     # ragged rows, non-multiple-of-16 words
         (37, 1024, 8),   # records-tree row shape (Z + Z*V = 4 + 4*255)
         (16, 4100, 20),  # mailbox-like wide row, ChaCha20
+        (24, 6084, 8),   # the mailbox row, 4 + 6080: three block groups,
+        # a 64-lane last value tile, index words on lanes 64-67 of it
+        (16, 130, 8),    # index words straddle a tile boundary (126 + 4)
     ],
 )
 def test_fused_kernel_matches_jnp_keystream(r, w, rounds):
@@ -33,7 +36,8 @@ def test_fused_kernel_matches_jnp_keystream(r, w, rounds):
         axis=1,
     )  # includes epoch-0 (identity) rows
     z = 4  # slot-index words, as in the ORAM bucket rows
-    want = data ^ row_keystream(key, bucket, epoch, w, rounds)
+    ks_idx, ks_val = row_plane_keystreams(key, bucket, epoch, z, w, rounds)
+    want = data ^ jnp.concatenate([ks_idx, ks_val], axis=1)
     gi, gv = cipher_rows_pallas(
         key, bucket, epoch, data[:, :z], data[:, z:], rounds, interpret=True
     )

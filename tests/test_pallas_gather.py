@@ -12,7 +12,7 @@ import pytest
 
 from grapevine_tpu.config import GrapevineConfig
 from grapevine_tpu.engine.batcher import GrapevineEngine
-from grapevine_tpu.oblivious.bucket_cipher import row_keystream
+from grapevine_tpu.oblivious.bucket_cipher import row_plane_keystreams
 from grapevine_tpu.oblivious.pallas_gather import gather_decrypt_rows
 from grapevine_tpu.wire import constants as C
 from grapevine_tpu.wire.records import QueryRequest, RequestRecord
@@ -20,9 +20,16 @@ from grapevine_tpu.wire.records import QueryRequest, RequestRecord
 NOW = 1_700_000_000
 
 
-def test_kernel_matches_gather_then_xor():
+@pytest.mark.parametrize(
+    "n,v",
+    [
+        (64, 6),     # a row under one lane tile
+        (8, 1520),   # the mailbox row, 4 + 6080: three block groups
+    ],
+)
+def test_kernel_matches_gather_then_xor(n, v):
     rng = np.random.default_rng(2)
-    n, z, v = 64, 4, 6
+    z = 4
     zv = z * v
     tree_idx = jnp.asarray(rng.integers(0, 2**31, (n * z,)), jnp.uint32)
     tree_val = jnp.asarray(rng.integers(0, 2**31, (n, zv)), jnp.uint32)
@@ -36,14 +43,10 @@ def test_kernel_matches_gather_then_xor():
     pidx = tree_idx.reshape(n, z)[flat_b]
     pval = tree_val[flat_b]
     pn = nonces[flat_b]
-    ks = row_keystream(key, flat_b, pn, z + zv, 8)
-    written = ((pn[:, 0] != 0) | (pn[:, 1] != 0))[:, None]
-    assert np.array_equal(
-        np.asarray(oi), np.asarray(pidx ^ jnp.where(written, ks[:, :z], 0))
-    )
-    assert np.array_equal(
-        np.asarray(ov), np.asarray(pval ^ jnp.where(written, ks[:, z:], 0))
-    )
+    # epoch-0 rows come back as they are: the keystream rows are zero
+    ks_idx, ks_val = row_plane_keystreams(key, flat_b, pn, z, z + zv, 8)
+    assert np.array_equal(np.asarray(oi), np.asarray(pidx ^ ks_idx))
+    assert np.array_equal(np.asarray(ov), np.asarray(pval ^ ks_val))
 
 
 def test_plaintext_rounds0_is_plain_gather():
@@ -175,14 +178,14 @@ def test_scatter_encrypt_matches_encrypt_then_scatter():
     oi = np.asarray(oi).reshape(n, z)
     ov = np.asarray(ov)
     on = np.asarray(on)
-    ks = row_keystream(
-        key, flat_b, jnp.broadcast_to(epoch[None, :], (4, 2)), z + zv, 8
+    ks_idx, ks_val = row_plane_keystreams(
+        key, flat_b, jnp.broadcast_to(epoch[None, :], (4, 2)), z, z + zv, 8
     )
     ref_i, ref_v = orig_i.copy(), orig_v.copy()
     for j in range(4):
         if bool(owner[j]):
-            ref_i[int(flat_b[j])] = np.asarray(new_pidx[j] ^ ks[j, :z])
-            ref_v[int(flat_b[j])] = np.asarray(new_pval[j] ^ ks[j, z:])
+            ref_i[int(flat_b[j])] = np.asarray(new_pidx[j] ^ ks_idx[j])
+            ref_v[int(flat_b[j])] = np.asarray(new_pval[j] ^ ks_val[j])
     for row in range(n - 1):  # row n-1 is the junk pad bucket
         if row in (3, 9, 20):
             assert np.array_equal(oi[row], ref_i[row]), f"idx row {row}"
