@@ -11,7 +11,18 @@ each window the whole log is replayed twice: once as ``run.py`` does
 in the program's place. The system states no precision, so the control
 breaks one guarantee the configuration states: it is the plain oracle
 with the 62-message mailbox cap not enforced. It must come out as not
-correct on every seed (``ops_wrong`` > 0). Exit 0 only if both hold.
+correct on every seed (``ops_wrong`` > 0).
+
+A cell whose log holds expiry sweeps gets a third replay, with a second
+control in the program's place: the plain oracle whose ``expire`` does
+nothing, which breaks the configuration's ``expiry`` guarantee (a
+drained mailbox's slot is free after the sweep; a record older than the
+TTL is gone). It too must come out as not correct on every seed: its
+recipient count stands where the engine's would (``recipient_count_gap``
+> 0), beside its answers and the records it removed (where the cell's
+driver makes records come due behind the window, ``sweep_evicted_gap``,
+``message_count_gap`` and ``ops_wrong`` > 0 too). Exit 0 only if all of
+it holds.
 """
 
 from __future__ import annotations
@@ -25,23 +36,63 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
 
 
-def control_in_place(guarantees: dict):
-    """``answered`` for ``compare.replay``: the uncapped oracle's answers
-    where the engine's would stand."""
-    from benchmarks.lib import wire as W
-    from benchmarks.lib.oracle import Oracle
+class InPlace:
+    """``answered`` for ``compare.replay``: a control oracle where the
+    engine would stand. Its answers to a round (under the ids the
+    engine gave), the records it removes at a sweep, and at the end its
+    own counts of messages and recipients (``oracle``)."""
 
-    ctl = Oracle(guarantees["max_messages"], guarantees["max_recipients"],
-                 mailbox_cap=1 << 62)
+    def __init__(self, oracle):
+        self.oracle = oracle
 
-    def answered(entry):
+    def __call__(self, entry):
+        from benchmarks.lib import wire as W
+
+        if entry.get("kind") == "sweep":
+            return self.oracle.expire(entry["now"], entry["period"])
         forced = [d.record.msg_id if r.request_type == W.CREATE
                   and d.status_code == W.SUCCESS else None
                   for r, d in zip(entry["reqs"], entry["resps"])]
         forced += [None] * (len(entry["reqs"]) - len(forced))
-        return ctl.handle_batch(entry["reqs"], entry["now"], forced)
+        return self.oracle.handle_batch(entry["reqs"], entry["now"], forced)
 
-    return answered
+
+def control_in_place(guarantees: dict) -> InPlace:
+    """The oracle with the mailbox cap not enforced."""
+    from benchmarks.lib.oracle import Oracle
+
+    return InPlace(Oracle(guarantees["max_messages"],
+                          guarantees["max_recipients"], mailbox_cap=1 << 62))
+
+
+def no_reclaim_in_place(guarantees: dict) -> InPlace:
+    """The oracle whose ``expire`` does nothing: no record leaves with
+    its TTL and no drained mailbox gives its slot back."""
+    from benchmarks.lib.oracle import Oracle
+
+    class NoReclaim(Oracle):
+        def expire(self, now, period):
+            return 0
+
+    return InPlace(NoReclaim(guarantees["max_messages"],
+                             guarantees["max_recipients"],
+                             guarantees["mailbox_cap"]))
+
+
+def no_reclaim_numbers(entries, guarantees: dict) -> dict:
+    """The replay with the no-reclaim control in the program's place:
+    the numbers of ``correct`` that a sweep can move, the control's
+    counts standing where ``engine.health()``'s would."""
+    from benchmarks.lib import compare
+
+    ctl = no_reclaim_in_place(guarantees)
+    rep = compare.replay(entries, guarantees, answered=ctl)
+    return {"ops_wrong": rep["ops_wrong"],
+            "message_count_gap":
+                abs(len(ctl.oracle.records) - rep["oracle_messages"]),
+            "recipient_count_gap":
+                abs(len(ctl.oracle.mailboxes) - rep["oracle_recipients"]),
+            "sweep_evicted_gap": rep["sweep_evicted_gap"]}
 
 
 def main() -> int:
@@ -63,7 +114,7 @@ def main() -> int:
         return 2
     harness.prepare_process()
     seeds = [int(s) for s in args.seeds.split(",")]
-    sound = caught = 0
+    sound = caught = swept = reclaim_caught = 0
     with harness.scratch_dir("control-" + args.workload) as scratch:
         cell = harness.Cell(bench, args.workload, seeds[0], scratch)
         try:
@@ -79,17 +130,30 @@ def main() -> int:
                 harness.say(phase="control", seed=seed,
                             program_correct=correct, program_failed=failed,
                             program_ops_compared=rep["ops_compared"],
+                            program_compared=cell.compared,
                             control_ops_wrong=wrong, limit=0,
                             control_first_wrong=ctl["first_wrong"],
-                            rounds_in_window=len(obs["rounds"]))
+                            rounds_in_window=len(obs["rounds"]),
+                            sweeps_in_window=len(obs["sweeps"]))
                 sound += bool(correct)
                 caught += wrong > 0
+                if rep["sweeps"]:
+                    numbers = no_reclaim_numbers(cell.log.entries,
+                                                 cell.config["guarantees"])
+                    not_correct = not compare.verdict(numbers)[0]
+                    harness.say(phase="control_no_reclaim", seed=seed,
+                                sweeps=rep["sweeps"], limit=0, **numbers)
+                    swept += 1
+                    reclaim_caught += not_correct
         finally:
             cell.close()
     print(json.dumps({"seeds": len(seeds), "program_correct": sound,
                       "control_not_correct": caught,
+                      "seeds_with_sweeps": swept,
+                      "no_reclaim_control_not_correct": reclaim_caught,
                       "device": harness.device_info()}), flush=True)
-    return 0 if sound == caught == len(seeds) else 1
+    return 0 if (sound == caught == len(seeds)
+                 and reclaim_caught == swept) else 1
 
 
 if __name__ == "__main__":

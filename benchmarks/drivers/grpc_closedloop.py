@@ -239,7 +239,7 @@ def finish(ctx, state) -> dict:
         proc.stdin.close()
         proc.wait(timeout=30)
     engine: collections.Counter = collections.Counter()
-    for e in ctx.log.entries[state["first_round"]:]:
+    for e in ctx.log.rounds(state["first_round"]):
         if e["resps"] is not None:
             # kept on the round: ``end_to_end`` pairs them with the
             # oracle's verdicts without hashing 85 MB of answers again

@@ -115,7 +115,7 @@ def finish(ctx, state) -> dict:
     unanswered = state["n_ops"] - len(records) + len(errors)
     engine = collections.Counter(
         hashlib.sha256(r.pack()).hexdigest()
-        for e in ctx.log.entries[state["first_round"]:]
+        for e in ctx.log.rounds(state["first_round"])
         if e["resps"] is not None for r in e["resps"])
     client = collections.Counter(r[3] for r in records if r[3] is not None)
     differing = (sum((client - engine).values())

@@ -3,7 +3,9 @@
 Every round the engine ran, in the engine's own order and slot
 composition, is replayed on the plain oracle (lib/oracle.py) and each
 answer must be equal: status, id, sender, recipient, timestamp and
-payload. Every comparison is exact, so every limit is 0.
+payload. Every expiry sweep is replayed where the engine ran it among
+the rounds, and must have removed as many records as the oracle's.
+Every comparison is exact, so every limit is 0.
 """
 
 from __future__ import annotations
@@ -23,17 +25,25 @@ def answers_equal(resp, ans) -> bool:
             and rec.payload == ans.payload)
 
 
-def replay(rounds, guarantees: dict, answered=None) -> dict:
-    """Replay ``rounds`` (RoundLog entries: ``reqs``, ``now``, ``resps``)
-    on a fresh oracle. Marks each entry with ``ok`` (per-slot booleans)
-    and returns the counts the verdict needs. ``answered`` lets the
-    control put another oracle's answers in the engine's place."""
+def replay(entries, guarantees: dict, answered=None) -> dict:
+    """Replay ``entries`` (RoundLog's: a round has ``reqs``, ``now``,
+    ``resps``; a sweep ``kind`` ``"sweep"``, ``now``, ``period``,
+    ``evicted``) on a fresh oracle, in their order. Marks each round
+    with ``ok`` (per-slot booleans) and returns the counts the verdict
+    needs. ``answered`` lets a control put another oracle in the
+    engine's place: called with each entry, it gives that oracle's
+    answers to a round and its count of records removed by a sweep."""
     oracle = Oracle(guarantees["max_messages"], guarantees["max_recipients"],
                     guarantees["mailbox_cap"])
-    compared = wrong = unresolved = 0
+    compared = wrong = unresolved = sweeps = evicted_gap = 0
     statuses: dict[int, int] = {}
     first_wrong = None
-    for i, e in enumerate(rounds):
+    for i, e in enumerate(entries):
+        if e.get("kind") == "sweep":
+            evicted = e["evicted"] if answered is None else answered(e)
+            sweeps += 1
+            evicted_gap += abs(evicted - oracle.expire(e["now"], e["period"]))
+            continue
         resps = e["resps"] if answered is None else answered(e)
         if resps is None:
             # never resolved: its requests still happened as far as the
@@ -68,6 +78,7 @@ def replay(rounds, guarantees: dict, answered=None) -> dict:
             statuses[o.status] = statuses.get(o.status, 0) + 1
     return {"ops_compared": compared, "ops_wrong": wrong,
             "ops_unresolved": unresolved, "first_wrong": first_wrong,
+            "sweeps": sweeps, "sweep_evicted_gap": evicted_gap,
             "status_counts": {str(k): v for k, v in sorted(statuses.items())},
             "oracle_messages": len(oracle.records),
             "oracle_recipients": len(oracle.mailboxes)}

@@ -1,6 +1,7 @@
 """One run of one cell: build the served bus from the configuration,
 warm it, let the traffic's driver load it for the window, then replay
-every round on the oracle and reduce what was observed to metrics.
+every round and every expiry sweep on the oracle and reduce what was
+observed to metrics.
 
 ``run.py`` looks for the chip and then calls :func:`run_cell`; the
 tests call it directly on the CPU at a toy geometry.
@@ -220,7 +221,7 @@ class Cell:
                           engine.ecfg.tree_top_cache_levels,
                       "evict_every": engine.ecfg.evict_every,
                       "pipeline_depth": engine.pipeline_depth,
-                      "bucket_cipher_impl": self.cfg.bucket_cipher_impl,
+                      "bucket_cipher_impl": engine.ecfg.rec.cipher_impl,
                       "scheduler_max_wait_ms":
                           self.server.scheduler.max_wait * 1e3,
                       "scheduler_idle_gap_ms":
@@ -263,7 +264,7 @@ class Cell:
                 t0 = time.perf_counter()
                 driver.ready(ctx, state)
                 say(phase="ready", ready_s=time.perf_counter() - t0)
-            first_round = len(self.log.entries)
+            first_entry = len(self.log.entries)
             gc.collect()
             gc.freeze()  # set-up's objects leave the collector's sight
             watch = gcwatch.GcWatch()
@@ -283,10 +284,13 @@ class Cell:
             gc.unfreeze()
             if state is not None:
                 driver.stop(ctx, state)
-        rounds = self.log.entries[first_round:]
+        rounds = self.log.rounds(first_entry)
         return {"ctx": ctx, "observed": observed, "all_rounds": rounds,
                 "rounds": [e for e in rounds if e["t_resolved"] is not None
                            and t_open <= e["t_resolved"] <= t_end],
+                # the expiry sweeps since the window opened, in a cell
+                # whose driver sweeps
+                "sweeps": self.log.sweeps(first_entry),
                 # the driver closes its window: at ``seconds``, or at
                 # the first answers to arrive after that
                 "window": (t_open, t_end), "seconds": seconds,
@@ -299,8 +303,9 @@ class Cell:
                 "trace": plain_trace, "trace_window": (tr0, tr1)}
 
     def judge(self, obs: dict) -> tuple[bool, int, dict]:
-        """The oracle over every round since the server started, and
-        the verdict on this window: (correct, failed ops, replay)."""
+        """The oracle over every round and sweep since the server
+        started, and the verdict on this window: (correct, failed ops,
+        replay)."""
         t0 = time.perf_counter()
         rep = compare.replay(self.log.entries, self.config["guarantees"])
         health = self.engine.health()
@@ -317,6 +322,7 @@ class Cell:
                 abs(health["recipients"] - rep["oracle_recipients"]),
             "trees_not_in_equal_shards":
                 shard_layout_faults(self.engine, self.cfg.shards),
+            "sweep_evicted_gap": rep["sweep_evicted_gap"],
         }
         correct, lines = compare.verdict(numbers)
         #: each number compared beside its limit, for the result line
@@ -325,7 +331,8 @@ class Cell:
         for line in lines:
             say(phase="compare", **line)
         say(phase="oracle", replay_s=time.perf_counter() - t0,
-            rounds=len(self.log.entries), first_wrong=rep["first_wrong"],
+            rounds=len(self.log.entries) - rep["sweeps"],
+            sweeps=rep["sweeps"], first_wrong=rep["first_wrong"],
             status_counts=rep["status_counts"],
             oracle_messages=rep["oracle_messages"],
             oracle_recipients=rep["oracle_recipients"],

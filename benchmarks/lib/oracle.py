@@ -8,6 +8,12 @@ program: requests are read by attribute (``request_type``,
 answers are plain :class:`Answer` tuples, and the capacities and the
 mailbox cap come from the configuration file's ``guarantees``.
 
+``expire`` is the TTL sweep as the upstream README gives it (its lines
+86-98, SURVEY.md section 3.4: ``expired = now - ts > expiry_period``): every
+record older than the period leaves, with its mailbox entry, and then
+every mailbox that is empty releases its recipient slot. It is the one
+place a slot is released.
+
 Message ids are engine-private PRP outputs, so the oracle is handed the
 id the engine returned for each successful CREATE; everything else —
 which record an id names, who may see it, zero-id order, the cap — is
@@ -59,7 +65,25 @@ class Oracle:
     def _unlist(self, recipient: bytes, mid: bytes) -> None:
         box = self.mailboxes.get(recipient)
         if box is not None and mid in box:
-            box.remove(mid)  # a drained mailbox keeps its recipient slot
+            # a drained mailbox keeps its recipient slot until a sweep
+            box.remove(mid)
+
+    def expire(self, now: int, period: int) -> int:
+        """One sweep at the clock ``now``: a record leaves, with its
+        mailbox entry, when ``now - timestamp > period`` (one exactly
+        ``period`` old stays); then every empty mailbox releases its
+        recipient slot. ``period <= 0`` is a bus without a TTL: nothing
+        happens. Returns the records removed."""
+        if period <= 0:
+            return 0
+        now = int(now)
+        due = [mid for mid, rec in self.records.items()
+               if now - rec[2] > period]
+        for mid in due:
+            self._unlist(self.records.pop(mid)[1], mid)
+        self.mailboxes = {rcp: box for rcp, box in self.mailboxes.items()
+                          if box}
+        return len(due)
 
     def handle_batch(self, reqs, now: int, forced_ids) -> list[Answer]:
         """One round: mailbox effects for the whole batch (A), then

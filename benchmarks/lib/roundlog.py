@@ -5,9 +5,24 @@ scheduler -> engine boundary, as ``chip_smoke.py``'s RoundLog does):
 every round's requests in slot order, its clock, its responses and the
 host-clock times of dispatch and of the resolved answers. Round
 composition is decided by the scheduler, not by the seed, so this is
-what the oracle replay needs. Dispatch and resolve run inside
-benchmark-owned ``TraceAnnotation`` spans, which put the host's side of
-each round on the profiler's clock.
+what the oracle replay needs. It wraps ``expire`` too: an expiry sweep
+is an event of the log like a round (``kind`` ``"sweep"``), in the order
+the engine ran it among the rounds. Dispatch, resolve and sweep run
+inside benchmark-owned ``TraceAnnotation`` spans, which put the host's
+side of each on the profiler's clock.
+
+The order is exact by construction. The engine applies rounds and sweeps
+one at a time, each under its own lock, in the order they get it; a
+round's dispatch is called from the collector thread and a sweep from
+another. The log has one lock of its own, held around each wrapped call
+*and* the append of its entry, so whichever call gets the log's lock
+first also gets the engine's first (the engine's nests inside, and
+nothing else calls either method) and is appended first. An entry
+appended after the call returned, outside any lock, can land behind the
+round that was dispatched the moment the engine's lock came free. A
+dispatch that meets a running sweep waits at the log's lock for what it
+would have waited at the engine's; what it did before that wait without
+the log, packing its batch (``pack_ms``), it now does after it.
 
 ``SubmitLog`` wraps the scheduler's ``submit_nowait`` and records each
 op's enqueue -> settle seconds (the part of a client's latency that is
@@ -40,28 +55,58 @@ class RoundLog:
             e = self._entry
             e["t_resolved"] = time.perf_counter()
             e["resps"] = resps
+            for watch in self._log.watchers:
+                watch(e)
             if self._log.on_resolved is not None:
                 self._log.on_resolved(e)
             return resps
 
     def __init__(self, engine):
-        #: rounds in the engine's order: kind, reqs, now, resps,
-        #: t_dispatch, t_resolved (perf_counter seconds)
+        #: rounds and sweeps in the engine's order (perf_counter seconds).
+        #: A round: kind "round", reqs, now, resps, t_dispatch,
+        #: t_resolved. A sweep: kind "sweep", now, period (as the engine
+        #: resolved it), evicted, t_start (expire called), t_end (returned)
         self.entries: list[dict] = []
-        #: called on the collector thread with each resolved entry
+        #: called on the collector thread with each resolved round: the
+        #: driver's loop, and before it whatever else a driver hangs here
         self.on_resolved = None
-        inner = engine.handle_queries_async
+        self.watchers: list = []
+        self._order = threading.Lock()
+        dispatch, expire = engine.handle_queries_async, engine.expire
 
         def recorded(reqs, now):
             entry = {"kind": "round", "reqs": list(reqs), "now": int(now),
                      "resps": None, "t_dispatch": time.perf_counter(),
                      "t_resolved": None}
-            with annotation("bench/dispatch"):
-                pending = inner(reqs, now)
-            self.entries.append(entry)
+            with self._order:
+                with annotation("bench/dispatch"):
+                    pending = dispatch(reqs, now)
+                self.entries.append(entry)
             return self._Pending(pending, entry, self)
 
+        def swept(now, period=None):
+            entry = {"kind": "sweep", "now": int(now),
+                     "period": int(engine.config.expiry_period
+                                   if period is None else period),
+                     "evicted": None, "t_start": time.perf_counter(),
+                     "t_end": None}
+            with self._order:
+                with annotation("bench/sweep"):
+                    entry["evicted"] = int(expire(now, period))
+                entry["t_end"] = time.perf_counter()
+                self.entries.append(entry)
+            return entry["evicted"]
+
         engine.handle_queries_async = recorded
+        engine.expire = swept
+
+    def rounds(self, since: int = 0) -> list[dict]:
+        """The rounds among ``entries[since:]``."""
+        return [e for e in self.entries[since:] if e["kind"] == "round"]
+
+    def sweeps(self, since: int = 0) -> list[dict]:
+        """The sweeps among ``entries[since:]``."""
+        return [e for e in self.entries[since:] if e["kind"] == "sweep"]
 
 
 class SubmitLog:

@@ -44,7 +44,7 @@ def test_every_cell_runs_and_is_correct(cell, trace, tmp_path):
     r = _run(cell, tmp_path, trace=trace)
     assert r["correct"] is True and r["failed"] == 0 and r["attempted"] > 0
     # each number compared beside its limit, as the result's last key
-    assert list(r)[-1] == "compared" and len(r["compared"]) == 7
+    assert list(r)[-1] == "compared" and len(r["compared"]) == 8
     assert all(x == {"value": 0, "limit": 0} for x in r["compared"].values())
     group = "per_layer" if trace else "end_to_end"
     declared = {m["name"] for m in bench._metrics_for(group, cell)}
@@ -90,6 +90,93 @@ def test_a_backlog_cell_accounts_for_its_loader_and_its_collections(
     assert 0.5 < traffic["prebuilt_ops"] / traffic["presigned_ops"] < 0.7
     init = next(x for x in lines if x.get("phase") == "init")
     assert init["geometry"]["trees"]["mailbox"]["rows_per_pass"] == 16
+
+
+SWEEP_CELL = "backlog-sweep-1chip-2p21"
+
+
+def _drive_the_sweep_cell(tmp_path, tamper=None):
+    """Three seconds of the sweep cell through a ``Cell``, as
+    ``control.py`` drives one; ``tamper`` gets the cell before the
+    window. Returns (cell, what was observed, the verdict)."""
+    cell = harness.Cell(toy_bench(tmp_path / "base"), SWEEP_CELL,
+                        2**31 + 41, str(tmp_path))
+    try:
+        if tamper is not None:
+            tamper(cell)
+        obs = cell.drive(2**31 + 41, 3.0, False, time.perf_counter())
+    finally:
+        cell.close()
+    return cell, obs, cell.judge(obs)
+
+
+def test_a_sweep_that_returns_its_state_unchanged_is_not_correct(tmp_path):
+    """The engine's sweep runs and forgets what it did: no record
+    leaves and no slot comes free, where the oracle's do."""
+    def forgetful(cell):
+        cell.engine._sweep = lambda ecfg, state, *clock: state
+
+    cell, obs, (correct, _, _) = _drive_the_sweep_cell(tmp_path, forgetful)
+    assert correct is False
+    assert sum(s["evicted"] for s in obs["sweeps"]) == 0
+    assert cell.compared["sweep_evicted_gap"]["value"] > 0
+    assert cell.compared["recipient_count_gap"]["value"] > 0
+
+
+def test_the_sweep_cell_expires_reclaims_and_catches_its_controls(tmp_path):
+    """Three seconds of the toy TTL of one second: records come due on
+    the server's clock, the engine's sweeps and the oracle's agree on
+    every one, and the log's order and the ``expiry`` guarantee are
+    each held by a number that fails when they are broken."""
+    from benchmarks import control
+    from benchmarks.lib import compare
+
+    cell, obs, (correct, failed, rep) = _drive_the_sweep_cell(tmp_path)
+    assert correct and failed == 0
+    assert cell.compared["sweep_evicted_gap"] == {"value": 0, "limit": 0}
+    assert cell.compared["recipient_count_gap"] == {"value": 0, "limit": 0}
+    # the sweeps since the window opened: the window's, then the one
+    # the driver makes due once the window's ops are answered
+    *sweeps, due = obs["sweeps"]
+    assert len(sweeps) >= 5 and rep["sweeps"] == len(sweeps) + 2  # set-up's
+    assert sum(s["evicted"] for s in sweeps) > 0
+    assert all(s["period"] == 1 for s in sweeps)
+    summary = obs["observed"]["summary"]  # the driver's, for ``samples``
+    assert summary["sweeps"] == len(sweeps)
+    assert summary["records_evicted"] == sum(s["evicted"] for s in sweeps)
+    # the due sweep: its clock is one TTL past the clock of the window's
+    # middle round, it stands in the log behind every round of the
+    # window, and whole rounds of the script follow it
+    cut = summary["due_sweep"]["cut"]
+    assert due["now"] == cut + 1 and due["t_start"] > obs["window"][1]
+    assert summary["due_sweep"]["evicted"] == due["evicted"]
+    rounds = [e["now"] for e in obs["all_rounds"]]
+    assert min(rounds) <= cut <= max(rounds)
+    after = cell.log.entries[cell.log.entries.index(due) + 1:]
+    assert sum(len(e["reqs"]) for e in after) == 2 * cell.cfg.batch_size
+    assert all(e["kind"] == "round" and all(e["ok"]) for e in after)
+    stalls = cell.driver.stalls_ms(obs)
+    assert len(stalls) >= len(sweeps) - 2 and min(stalls) > 0
+    guarantees = cell.config["guarantees"]
+    # the second control: an oracle that neither expires nor reclaims
+    numbers = control.no_reclaim_numbers(cell.log.entries, guarantees)
+    assert numbers["recipient_count_gap"] > 0
+    assert not compare.verdict(numbers)[0]
+    # the first, on a log with sweeps in it
+    ctl = compare.replay(cell.log.entries, guarantees,
+                         answered=control.control_in_place(guarantees))
+    assert ctl["sweep_evicted_gap"] == 0 or ctl["ops_wrong"] > 0
+    # a sweep one round later in the log than the engine ran it
+    entries = cell.log.entries
+    moved = caught = 0
+    for i, e in enumerate(entries[:-1]):
+        if e["kind"] == "sweep" and e["evicted"]:
+            late = list(entries)
+            late[i], late[i + 1] = late[i + 1], late[i]
+            r = compare.replay(late, guarantees)
+            moved += 1
+            caught += r["ops_wrong"] + r["sweep_evicted_gap"] > 0
+    assert moved >= 1 and caught >= 1
 
 
 #: what the next configuration, ``chipshare-2p20-r65536``, brings as
