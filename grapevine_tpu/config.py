@@ -512,5 +512,18 @@ class DurabilityConfig:
         if self.journal_fsync_every < 1:
             raise ValueError("journal_fsync_every must be >= 1")
 
+    @classmethod
+    def coerce(cls, value) -> "DurabilityConfig | None":
+        """``value`` as a DurabilityConfig: one as it is, None as None,
+        and a mapping of the fields (what a JSON configuration file
+        holds) built, its ``state_dir`` resolved against the working
+        directory as the CLI's ``--state-dir`` is."""
+        if value is None or isinstance(value, cls):
+            return value
+        fields = dict(value)
+        if fields.get("state_dir"):
+            fields["state_dir"] = _os.path.abspath(fields["state_dir"])
+        return cls(**fields)
+
 
 DEFAULT_CONFIG = GrapevineConfig()

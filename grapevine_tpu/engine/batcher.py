@@ -35,6 +35,7 @@ import logging
 import threading
 import time
 from collections import deque
+from collections.abc import Mapping
 
 import jax
 import numpy as np
@@ -137,7 +138,7 @@ class PendingRound:
                  "_behind_other")
 
     def __init__(self, engine, resp, n, t0, transcript=None, batch=None,
-                 spans=None, t1=None, behind_other=False):
+                 spans=None, t1=None, behind_other=False, counts=None):
         self._engine = engine
         self._resp = resp
         self._n = n
@@ -163,9 +164,10 @@ class PendingRound:
         #: phase durations derive from it
         self._spans = spans
         #: per-round counts for the tracer ledger (obs/tracer.py
-        #: ROUND_COUNTS), stamped by the scheduler (note_counts), and
-        #: the ledger's seq once resolve() has recorded it
-        self._counts = None
+        #: ROUND_COUNTS): the journal's from dispatch, the rest stamped
+        #: by the scheduler (note_counts); and the ledger's seq once
+        #: resolve() has recorded it
+        self._counts = counts
         self._seq = None
         #: perf_counter enqueue time of the round's OLDEST op, stamped
         #: by the scheduler (set_enqueued_at) — the SLO's enqueue→settle
@@ -342,6 +344,10 @@ class PendingRound:
                             phases, queue_depth=self._qdepth)
 
 
+#: the journal's counts of a round on an engine with no state directory
+_NO_JOURNAL = {"seal_s": 0.0, "fsync_s": 0.0, "bytes": 0}
+
+
 def _still_running(resp) -> bool:
     """True when the device has not finished the round yet: the host
     arrived first and its wait is the device's remaining time."""
@@ -368,7 +374,9 @@ class GrapevineEngine:
     """
 
     def __init__(self, config: GrapevineConfig | None = None, seed: int = 0,
-                 durability: DurabilityConfig | None = None):
+                 durability: DurabilityConfig | Mapping | None = None):
+        """``durability``: a ``DurabilityConfig``, or a mapping of its
+        fields (what a JSON configuration file holds)."""
         self.config = config or GrapevineConfig()
         self.ecfg = EngineConfig.from_config(self.config)
         #: bucket-axis sharding (config.py ``shards``; parallel/mesh.py):
@@ -400,8 +408,9 @@ class GrapevineEngine:
             self._mesh = make_mesh(devs[: self.config.shards])
             # created directly sharded: a mesh exists because one chip
             # cannot hold the trees, so they must never be staged on one
-            self.state, state_init_s = _build_state(
-                lambda: init_sharded_engine(self.ecfg, self._mesh, seed))
+            self._init_state = lambda: init_sharded_engine(
+                self.ecfg, self._mesh, seed)
+            self.state, state_init_s = _build_state(self._init_state)
             state_shardings = jax.tree.map(lambda x: x.sharding, self.state)
             if self.config.bucket_cipher_impl == "pallas_fused":
                 # said once, at build: the fused gather/scatter kernels
@@ -420,8 +429,10 @@ class GrapevineEngine:
             ssweep = make_sharded_sweep(self.ecfg, self._mesh)
             self._sweep = lambda _ecfg, state, *clock: ssweep(state, *clock)
         else:
-            self.state, state_init_s = _build_state(
-                lambda: init_engine(self.ecfg, seed))
+            #: builds the empty state: at construction, and again when a
+            #: restart in place (:meth:`recover`) finds none on the device
+            self._init_state = lambda: init_engine(self.ecfg, seed)
+            self.state, state_init_s = _build_state(self._init_state)
             step_fn = (engine_round_step if self.config.commit == "phase"
                        else engine_step)
             # donate the state: trees update in place (no per-round copy,
@@ -498,13 +509,44 @@ class GrapevineEngine:
 
             self.durability = DurabilityManager(
                 durability, self.ecfg, registry=self.metrics.registry,
-                state_shardings=state_shardings,
+                state_shardings=state_shardings, span=self.metrics.span,
             )
+            self.recover()
+
+    def recover(self) -> None:
+        """The path a restart takes, whole: the state directory's newest
+        checkpoint loaded into the state that is on the device (the
+        empty one, which is built first where there is none), then the
+        journal's tail replayed through the jitted round and sweep. The
+        constructor calls it, once; it can be called again on an engine
+        that was :meth:`abandon`-ed, which is how a run that cannot let
+        its process die exercises a restart on the engine it timed.
+
+        One owner of the device state: the engine's lock is held
+        throughout, the state goes into the load and comes back out of
+        the replay, and between the two the engine holds none. So the
+        device never holds a second state, and a load or a replay that
+        raises leaves an engine with no state: nothing half-loaded can
+        serve."""
+        with self._lock:
+            state, self.state = self.state, None
+            if state is None:
+                state, _ = _build_state(self._init_state)
             with self.metrics.span("replay"):
-                self.state = self.durability.recover(
-                    self.state, self._replay_record
-                )
-                jax.block_until_ready(self.state.free_top)
+                state = self.durability.recover(state, self._replay_record)
+                jax.block_until_ready(state.free_top)
+            self.state = state
+
+    def abandon(self) -> None:
+        """What a SIGKILL leaves of a durable engine, without the
+        process dying: the journal's handle dropped with no sync, no
+        final checkpoint, no drain, and the device state deleted. The
+        engine serves nothing until :meth:`recover`."""
+        with self._lock:
+            self.durability.abandon()
+            state, self.state = self.state, None
+            for leaf in jax.tree.leaves(state):
+                leaf.delete()
 
     def _replay_record(self, state: EngineState, rec) -> EngineState:
         """Apply one journal record through the same jitted programs the
@@ -620,7 +662,8 @@ class GrapevineEngine:
             raise ValueError("async path is one round at a time")
         return pack_batch(reqs, bs, now)
 
-    def _journal_round(self, batch: dict, n_real: int, spans: dict) -> None:
+    def _journal_round(self, batch: dict, n_real: int, spans: dict,
+                       counts: dict) -> None:
         """Stage 2 — journal: sealed append + fsync barrier (per
         ``journal_fsync_every``) BEFORE the round may dispatch — the
         crash-safety contract. Runs under the engine lock in the same
@@ -628,10 +671,17 @@ class GrapevineEngine:
         order is journal order at every pipeline depth. With a round
         already in flight (pipeline_depth=2) the fsync overlaps its
         device execution instead of serializing with it — the PR-10
-        point; the "journal" series isolates what it costs."""
+        point; the "journal" series isolates what it costs, and
+        ``counts`` takes its parts (obs/tracer.py ``journal_*``; zeros
+        without a state directory, so every ledger has them)."""
+        cost = _NO_JOURNAL
         if self.durability is not None:
             with self.metrics.span("journal", spans):
                 self.durability.append_round(batch, n_real)
+            cost = self.durability.journal.last_append
+        counts.update(journal_seal_s=cost["seal_s"],
+                      journal_fsync_s=cost["fsync_s"],
+                      journal_bytes=cost["bytes"])
         if faults.active():
             # the pipelined crash window: this round is durable (its
             # frame is fsynced) but not yet dispatched, while the
@@ -667,6 +717,7 @@ class GrapevineEngine:
         for the results."""
         span = self.metrics.span
         spans: dict = {}
+        counts: dict = {}
         with span("pack", spans):
             batch = self._assemble_round(reqs, now)
         lm = self.leakmon
@@ -683,7 +734,7 @@ class GrapevineEngine:
             behind_other = self._other_device_work
             self._other_device_work = False
             with span("dispatch", spans):
-                self._journal_round(batch, len(reqs), spans)
+                self._journal_round(batch, len(reqs), spans, counts)
                 t0, t1, resp, transcript = self._dispatch_round(batch)
                 if faults.active():
                     faults.crash("round.post_dispatch")
@@ -691,16 +742,16 @@ class GrapevineEngine:
                         and self.durability.should_checkpoint()):
                     # blocks this round's slot until the sealed state is
                     # on disk — the RTO/RPO trade
-                    # --checkpoint-every-rounds buys. state_to_bytes
-                    # waits for every dispatched round (this one
-                    # included), so the sealed state is exactly the
-                    # journal's seq even with the pipeline full — the
-                    # checkpoint is itself a pipeline barrier.
+                    # --checkpoint-every-rounds buys. Copying a leaf
+                    # to the host waits for every dispatched round
+                    # (this one included), so the sealed state is
+                    # exactly the journal's seq even with the pipeline
+                    # full — the checkpoint is itself a pipeline barrier.
                     with span("checkpoint", spans):
-                        self.durability.checkpoint(self.state)
+                        self.durability.checkpoint(self.state, spans)
         if lm is None:
             return PendingRound(self, resp, len(reqs), t0, spans=spans, t1=t1,
-                                behind_other=behind_other)
+                                behind_other=behind_other, counts=counts)
         # hand the monitor only the key-material columns: retaining the
         # full batch dict would pin the (B, PAYLOAD_WORDS) payload array
         # in the monitor queue for grouping that never reads it
@@ -710,7 +761,7 @@ class GrapevineEngine:
         return PendingRound(
             self, resp, len(reqs), t0,
             transcript=transcript, batch=key_cols, spans=spans, t1=t1,
-            behind_other=behind_other,
+            behind_other=behind_other, counts=counts,
         )
 
     def handle_queries_with_transcript(self, reqs, now):

@@ -11,9 +11,18 @@ round itself:
   authenticated encrypt-then-MAC with HMAC-SHA256. A torn, truncated,
   or tampered file fails the tag check and is *rejected whole* — there
   is no partial load. Pure stdlib + the in-repo RFC 7539 stream (the
-  ``cryptography`` wheel is optional in this container), with the bulk
-  keystream vectorized in numpy (the session-layer block function is a
-  per-32-byte-draw path; a checkpoint is megabytes).
+  ``cryptography`` wheel is optional in this container). The stream
+  runs in the native session library (``native/r255.c``
+  ``r255_chacha20_xor``, :func:`stream_xor`): a round's journal frame
+  is 2 MB and a checkpoint of the 2^21 bus 10 GB, sealed under the
+  engine's lock. The numpy keystream (:func:`chacha20_xor`) is the
+  plain reference the native one is pinned to, and what runs where the
+  library did not build.
+- **Streaming**: a checkpoint is written and read block by block
+  (:data:`STREAM_BLOCK_BYTES`): device to host, seal, MAC, write, and
+  back. The host holds a few blocks, never a copy of the state, and the
+  device never a second state: a load writes into the state it is
+  given, plane by plane, in place (:func:`load_checkpoint`).
 - **Obliviousness**: a checkpoint serializes the *entire*
   ``EngineState`` every time, and a journal frame serializes the
   *entire* fixed-size batch every round — both are constant-shape
@@ -33,6 +42,7 @@ the protocol-critical spots.
 
 from __future__ import annotations
 
+import functools
 import hashlib
 import hmac
 import json
@@ -44,7 +54,9 @@ import time
 import jax
 import numpy as np
 
+from .. import native
 from ..config import DurabilityConfig
+from ..obs.phases import span as _plain_span
 from ..testing import faults
 from .state import EngineConfig, EngineState, state_spec
 
@@ -145,11 +157,32 @@ def chacha20_xor(key: bytes, nonce: bytes, data: bytes, counter: int = 0) -> byt
     ).tobytes()
 
 
+#: threads of one native call on a block of a checkpoint; a journal
+#: frame (2 MB) is sealed on the caller's alone
+SEAL_THREADS = 4
+
+
+def stream_xor(key: bytes, nonce: bytes, data, counter: int = 0) -> bytes:
+    """:func:`chacha20_xor` at the speed of the memory it reads: the
+    native library's ChaCha20 (``native.chacha20_xor``), or the numpy
+    reference where the library did not build. Same bytes either way
+    (tests/test_seal_stream.py)."""
+    if native.lib is None:
+        return chacha20_xor(key, nonce, bytes(data), counter)
+    return bytes(native.chacha20_xor(key, nonce, counter, data))
+
+
 def derive_key(root_key: bytes, label: bytes) -> bytes:
     """Per-domain 32-byte subkey: HMAC-SHA256(root, label)."""
     if len(root_key) != 32:
         raise ValueError("root key must be 32 bytes")
     return hmac.new(root_key, label, hashlib.sha256).digest()
+
+
+def _seal_keys(root_key: bytes, domain: bytes) -> tuple[bytes, bytes]:
+    """(encryption key, MAC key) of one sealing domain."""
+    return (derive_key(root_key, b"grapevine-seal-enc:" + domain),
+            derive_key(root_key, b"grapevine-seal-mac:" + domain))
 
 
 def seal(root_key: bytes, domain: bytes, plaintext: bytes,
@@ -159,12 +192,12 @@ def seal(root_key: bytes, domain: bytes, plaintext: bytes,
     ``domain`` separates key schedules (checkpoint vs journal);
     ``aad`` binds plaintext headers (magic, seq) into the tag without
     encrypting them."""
-    enc = derive_key(root_key, b"grapevine-seal-enc:" + domain)
-    mac = derive_key(root_key, b"grapevine-seal-mac:" + domain)
+    enc, mac = _seal_keys(root_key, domain)
     nonce = os.urandom(12)
-    ct = chacha20_xor(enc, nonce, plaintext)
-    tag = hmac.new(mac, aad + nonce + ct, hashlib.sha256).digest()
-    return nonce + ct + tag
+    ct = stream_xor(enc, nonce, plaintext)
+    tag = hmac.new(mac, aad + nonce, hashlib.sha256)
+    tag.update(ct)
+    return nonce + ct + tag.digest()
 
 
 def unseal(root_key: bytes, domain: bytes, blob: bytes,
@@ -173,16 +206,18 @@ def unseal(root_key: bytes, domain: bytes, blob: bytes,
     truncation or integrity failure — never returns partial plaintext."""
     if len(blob) < 12 + 32:
         raise SealError("sealed blob truncated (shorter than nonce + tag)")
-    nonce, ct, tag = blob[:12], blob[12:-32], blob[-32:]
-    mac = derive_key(root_key, b"grapevine-seal-mac:" + domain)
-    want = hmac.new(mac, aad + nonce + ct, hashlib.sha256).digest()
-    if not hmac.compare_digest(tag, want):
-        raise SealError(
-            "sealed blob failed integrity check (torn, truncated, "
-            "tampered, or sealed under a different root key)"
-        )
-    enc = derive_key(root_key, b"grapevine-seal-enc:" + domain)
-    return chacha20_xor(enc, nonce, ct)
+    blob = memoryview(blob)
+    nonce, ct, tag = bytes(blob[:12]), blob[12:-32], blob[-32:]
+    enc, mac = _seal_keys(root_key, domain)
+    want = hmac.new(mac, aad + nonce, hashlib.sha256)
+    want.update(ct)
+    if not hmac.compare_digest(tag, want.digest()):
+        raise SealError(_INTEGRITY)
+    return stream_xor(enc, nonce, ct)
+
+
+_INTEGRITY = ("sealed blob failed integrity check (torn, truncated, "
+              "tampered, or sealed under a different root key)")
 
 
 def load_or_create_root_key(path: str) -> bytes:
@@ -217,27 +252,79 @@ def engine_fingerprint(ecfg: EngineConfig) -> str:
     return hashlib.sha256(repr(ecfg).encode()).hexdigest()
 
 
-def state_to_bytes(ecfg: EngineConfig, state: EngineState) -> bytes:
-    """Serialize a (host-synced) EngineState: JSON manifest + raw leaf
-    buffers in pytree order. Blocks until the device state is ready."""
-    leaves = jax.tree_util.tree_leaves(state)
-    arrays = [np.asarray(leaf) for leaf in leaves]
+def _manifest(ecfg: EngineConfig, leaves) -> bytes:
+    """The state payload's JSON head for ``leaves`` (anything with a
+    ``dtype`` and a ``shape``: arrays, or ``state_spec``'s structs)."""
     manifest = {
         "version": VERSION,
         "fingerprint": engine_fingerprint(ecfg),
-        "leaves": [[a.dtype.str, list(a.shape)] for a in arrays],
+        "leaves": [[np.dtype(x.dtype).newbyteorder("<").str, list(x.shape)]
+                   for x in leaves],
     }
-    head = json.dumps(manifest, separators=(",", ":")).encode()
+    return json.dumps(manifest, separators=(",", ":")).encode()
+
+
+def state_to_bytes(ecfg: EngineConfig, state: EngineState) -> bytes:
+    """Serialize a (host-synced) EngineState: JSON manifest + raw leaf
+    buffers in pytree order, in one piece. Blocks until the device state
+    is ready. The plaintext of a checkpoint, and the plain reference of
+    :func:`write_checkpoint`'s stream; tests and tools compare states by
+    it. It holds the whole state on the host, several times over."""
+    arrays = [np.asarray(leaf) for leaf in jax.tree_util.tree_leaves(state)]
+    head = _manifest(ecfg, arrays)
     parts = [struct.pack("<I", len(head)), head]
     for a in arrays:
-        # copy=False: a no-op on little-endian hosts — tobytes() is the
-        # single unavoidable copy per leaf (this runs under the engine
-        # lock; every avoided full-state copy shortens the round stall)
         le = np.ascontiguousarray(a).astype(
             a.dtype.newbyteorder("<"), copy=False
         )
         parts.append(le.tobytes())
     return b"".join(parts)
+
+
+def _check_manifest(ecfg: EngineConfig, data) -> tuple[int, list]:
+    """The checks on a state payload's head: returns (offset of the
+    first leaf, the geometry's leaf specs) or raises CheckpointError for
+    a truncated, unparseable, other-version or other-geometry one."""
+    if len(data) < 4:
+        raise CheckpointError("state payload truncated (no manifest)")
+    (head_len,) = struct.unpack_from("<I", data, 0)
+    if len(data) < 4 + head_len:
+        raise CheckpointError("state payload truncated (manifest cut short)")
+    try:
+        manifest = json.loads(bytes(data[4 : 4 + head_len]))
+    except ValueError as exc:
+        raise CheckpointError(f"state manifest unparseable: {exc}") from None
+    if not isinstance(manifest, dict):
+        raise CheckpointError("state manifest unparseable: not an object")
+    if manifest.get("version") != VERSION:
+        raise CheckpointError(
+            f"state payload version {manifest.get('version')!r}, "
+            f"want {VERSION}"
+        )
+    if manifest.get("fingerprint") != engine_fingerprint(ecfg):
+        raise CheckpointError(
+            "checkpoint geometry fingerprint does not match this engine "
+            "config — restore requires the identical GrapevineConfig "
+            "(capacities, heights, batch size, cipher) it was taken under"
+        )
+    _, spec = state_spec(ecfg)
+    decl = manifest.get("leaves", [])
+    if len(decl) != len(spec):
+        raise CheckpointError(
+            f"state payload has {len(decl)} leaves, geometry wants "
+            f"{len(spec)}"
+        )
+    for (dt_str, shape), want in zip(decl, spec):
+        dt = np.dtype(dt_str)
+        if tuple(shape) != tuple(want.shape) or dt.newbyteorder(
+            "="
+        ) != np.dtype(want.dtype):
+            raise CheckpointError(
+                f"state leaf mismatch: payload {dt_str}{tuple(shape)}, "
+                f"geometry wants {np.dtype(want.dtype).str}"
+                f"{tuple(want.shape)}"
+            )
+    return 4 + head_len, spec
 
 
 def bytes_to_state(
@@ -250,57 +337,17 @@ def bytes_to_state(
     leaf (a mesh engine's live placement). Each leaf then goes from host
     memory straight to its shards' devices; without it, to the default
     device."""
-    if len(data) < 4:
-        raise CheckpointError("state payload truncated (no manifest)")
-    (head_len,) = struct.unpack_from("<I", data, 0)
-    if len(data) < 4 + head_len:
-        raise CheckpointError("state payload truncated (manifest cut short)")
-    try:
-        manifest = json.loads(data[4 : 4 + head_len])
-    except ValueError as exc:
-        raise CheckpointError(f"state manifest unparseable: {exc}") from None
-    if manifest.get("version") != VERSION:
-        raise CheckpointError(
-            f"state payload version {manifest.get('version')!r}, "
-            f"want {VERSION}"
-        )
-    if manifest.get("fingerprint") != engine_fingerprint(ecfg):
-        raise CheckpointError(
-            "checkpoint geometry fingerprint does not match this engine "
-            "config — restore requires the identical GrapevineConfig "
-            "(capacities, heights, batch size, cipher) it was taken under"
-        )
-    treedef, spec = state_spec(ecfg)
-    decl = manifest.get("leaves", [])
-    if len(decl) != len(spec):
-        raise CheckpointError(
-            f"state payload has {len(decl)} leaves, geometry wants "
-            f"{len(spec)}"
-        )
-    off = 4 + head_len
+    off, spec = _check_manifest(ecfg, data)
+    treedef, _ = state_spec(ecfg)
     leaves = []
-    places = (jax.tree_util.tree_leaves(shardings)
-              if shardings is not None else [None] * len(spec))
-    if len(places) != len(spec):
-        raise ValueError(
-            f"shardings has {len(places)} leaves, state has {len(spec)}"
-        )
-    for (dt_str, shape), want, place in zip(decl, spec, places):
-        dt = np.dtype(dt_str)
-        shape = tuple(shape)
-        if shape != tuple(want.shape) or dt.newbyteorder("=") != np.dtype(
-            want.dtype
-        ):
-            raise CheckpointError(
-                f"state leaf mismatch: payload {dt_str}{shape}, geometry "
-                f"wants {np.dtype(want.dtype).str}{tuple(want.shape)}"
-            )
-        nbytes = dt.itemsize * int(np.prod(shape, dtype=np.int64))
+    for want, place in zip(spec, _places(shardings, len(spec))):
+        dt = np.dtype(want.dtype).newbyteorder("<")
+        nbytes = dt.itemsize * int(np.prod(want.shape, dtype=np.int64))
         if off + nbytes > len(data):
             raise CheckpointError("state payload truncated (leaf cut short)")
         arr = np.frombuffer(data, dt, count=nbytes // dt.itemsize, offset=off)
         leaves.append(jax.device_put(
-            arr.reshape(shape).astype(dt.newbyteorder("=")), place
+            arr.reshape(want.shape).astype(dt.newbyteorder("=")), place
         ))
         off += nbytes
     if off != len(data):
@@ -310,73 +357,397 @@ def bytes_to_state(
     return jax.tree_util.tree_unflatten(treedef, leaves)
 
 
-# -- sealed checkpoint files -------------------------------------------
+def _places(shardings, n: int) -> list:
+    places = (jax.tree_util.tree_leaves(shardings)
+              if shardings is not None else [None] * n)
+    if len(places) != n:
+        raise ValueError(
+            f"shardings has {len(places)} leaves, state has {n}"
+        )
+    return places
+
+
+# -- sealed checkpoint files, as streams --------------------------------
+
+#: plaintext is staged, sealed, MACed and written (and read back) in
+#: blocks of this many bytes; a multiple of ChaCha20's 64. A stream
+#: owns one staging block; with the block a device transfer lands in
+#: (or leaves from) and the copy the transfer itself may make, a
+#: checkpoint's write or load holds at most :data:`STREAM_HOST_BLOCKS`
+#: blocks on the host, whatever the state's size
+#: (tests/test_seal_stream.py holds the writer and the loader to it)
+STREAM_BLOCK_BYTES = 32 << 20
+STREAM_HOST_BLOCKS = 3
+
+
+def _xor_in_place(key: bytes, nonce: bytes, counter: int, view) -> None:
+    """``view`` (u8 array) XOR the keystream from block ``counter``."""
+    if native.lib is not None:
+        native.chacha20_xor(key, nonce, counter, view, view,
+                            threads=SEAL_THREADS)
+    else:
+        view[:] = np.frombuffer(
+            chacha20_xor(key, nonce, view.tobytes(), counter), np.uint8)
+
+
+def _as_bytes(data) -> np.ndarray:
+    """A flat u8 view of a contiguous array or a bytes-like."""
+    if isinstance(data, np.ndarray):
+        return np.ascontiguousarray(data).reshape(-1).view(np.uint8)
+    return np.frombuffer(data, np.uint8)
+
+
+class _SealedWriter:
+    """:func:`seal` as a stream into ``fd``: ``nonce | ct | tag`` for
+    the plaintext handed to :meth:`write`, the same bytes ``seal`` gives
+    for the same key, nonce and plaintext. One staging block: filled,
+    encrypted with the running block counter and MACed (span
+    ``checkpoint_seal``), written (``checkpoint_write``), filled
+    again."""
+
+    def __init__(self, fd: int, root_key: bytes, domain: bytes, aad: bytes,
+                 span):
+        self._enc, mac = _seal_keys(root_key, domain)
+        self._nonce = os.urandom(12)
+        self._mac = hmac.new(mac, aad + self._nonce, hashlib.sha256)
+        self._fd, self._span = fd, span
+        if STREAM_BLOCK_BYTES <= 0 or STREAM_BLOCK_BYTES % 64:
+            raise ValueError(
+                "STREAM_BLOCK_BYTES must be a positive multiple of 64")
+        self._buf, self._fill = np.empty(STREAM_BLOCK_BYTES, np.uint8), 0
+        self._blocks = 0  # ChaCha20 blocks sealed so far
+        #: plaintext bytes taken so far
+        self.taken = 0
+        write_all(fd, self._nonce)
+
+    def write(self, data) -> None:
+        src = _as_bytes(data)
+        off = 0
+        while off < src.size:
+            with self._span("checkpoint_seal"):
+                n = min(src.size - off, self._buf.size - self._fill)
+                self._buf[self._fill:self._fill + n] = src[off:off + n]
+                self._fill += n
+                off += n
+            if self._fill == self._buf.size:
+                self.flush()
+        self.taken += src.size
+
+    def flush(self) -> None:
+        """Seal and write what is staged. Only the stream's last block
+        may be short of a whole one."""
+        block = self._buf[:self._fill]
+        if not block.size:
+            return
+        with self._span("checkpoint_seal"):
+            _xor_in_place(self._enc, self._nonce, self._blocks, block)
+            self._mac.update(block)
+        with self._span("checkpoint_write"):
+            write_all(self._fd, block)
+        self._blocks += -(-block.size // 64)
+        self._fill = 0
+
+    def close(self) -> None:
+        """The last block, then the tag."""
+        self.flush()
+        with self._span("checkpoint_write"):
+            write_all(self._fd, self._mac.digest())
+
+
+def _block_rows(n_rows: int, nbytes: int, block_bytes: int) -> int:
+    """Rows of a plane that make one block: whole sublane tiles of 8
+    where a block holds that many, so that a slice starts on one."""
+    rows = max(1, block_bytes // max(1, nbytes // max(1, n_rows)))
+    return rows - rows % 8 if rows > 8 else rows
+
+
+@functools.partial(jax.jit, static_argnums=(2,))
+def _rows(plane, start, n: int):
+    return jax.lax.dynamic_slice_in_dim(plane, start, n, axis=0)
+
+
+@functools.partial(jax.jit, donate_argnums=(0,))
+def _put_rows(plane, block, start):
+    return jax.lax.dynamic_update_slice_in_dim(plane, block, start, axis=0)
+
+
+def _single_device_parts(leaf) -> list:
+    """Arrays on one device each that, laid end to end along axis 0,
+    are ``leaf``: the leaf itself, or a mesh engine's row shards. A
+    leaf sharded any other way comes back as one host array."""
+    if (not isinstance(leaf, jax.Array) or leaf.ndim == 0
+            or len(leaf.sharding.device_set) == 1):
+        return [leaf]
+    if leaf.is_fully_replicated:
+        return [leaf.addressable_shards[0].data]
+    by_start: dict[int, jax.Array] = {}
+    for shard in leaf.addressable_shards:
+        rows, *rest = shard.index
+        if any(sl != slice(None) and (sl.start or 0, sl.stop) != (0, dim)
+               for sl, dim in zip(rest, leaf.shape[1:])):
+            return [np.asarray(leaf)]
+        by_start.setdefault(rows.start or 0, shard.data)
+    return [by_start[k] for k in sorted(by_start)]
+
+
+def _host_blocks(leaf, block_bytes: int):
+    """``leaf`` on the host, little-endian, in pytree byte order, in
+    pieces of about ``block_bytes``: whole where it is smaller, else
+    row block by row block, each sliced on its device and copied from
+    there, so that neither side ever holds a second copy of a plane."""
+    for part in _single_device_parts(leaf):
+        n_rows = part.shape[0] if part.ndim else 1
+        rows = _block_rows(n_rows, part.nbytes, block_bytes)
+        if part.nbytes <= block_bytes or isinstance(part, np.ndarray):
+            pieces = [part]
+        else:
+            pieces = (_rows(part, r0, min(rows, n_rows - r0))
+                      for r0 in range(0, n_rows, rows))
+        for piece in pieces:
+            a = np.asarray(piece)
+            yield np.ascontiguousarray(a).astype(
+                a.dtype.newbyteorder("<"), copy=False)
 
 
 def checkpoint_path(state_dir: str, seq: int) -> str:
     return os.path.join(state_dir, f"ckpt-{seq:016d}.sealed")
 
 
+def _fsync_dir(path: str) -> None:
+    dfd = os.open(path, os.O_RDONLY)
+    try:
+        os.fsync(dfd)
+    finally:
+        os.close(dfd)
+
+
 def write_checkpoint(
     state_dir: str, root_key: bytes, ecfg: EngineConfig,
-    state: EngineState, seq: int,
+    state: EngineState, seq: int, *, span=_plain_span,
 ) -> str:
     """Atomically write the sealed checkpoint for journal seq ``seq``.
 
     tmp + fsync + rename + directory fsync: a crash at any point leaves
     either the previous checkpoint set or the new file complete — never
-    a half-written ``ckpt-*.sealed``."""
-    payload = struct.pack("<Q", seq) + state_to_bytes(ecfg, state)
+    a half-written ``ckpt-*.sealed``.
+
+    The file is ``MAGIC | version | seal(seq | state_to_bytes(state))``
+    byte for byte, written as a stream: leaf by leaf and, for a plane,
+    row block by row block, each copied from the device (span
+    ``checkpoint_read``), staged and encrypted with the running block
+    counter and MACed (``checkpoint_seal``), written
+    (``checkpoint_write``: the writes, then fsync, rename and directory
+    fsync), on the caller's thread, one after the other. ``span(name)``
+    opens a span of the caller's."""
+    leaves = jax.tree_util.tree_leaves(state)
+    manifest = _manifest(ecfg, leaves)
+    total = 8 + 4 + len(manifest) + sum(x.nbytes for x in leaves)
+    if total > 64 << 32:
+        # one nonce, one 32-bit block counter (RFC 7539): 256 GiB
+        raise CheckpointError(
+            f"a state of {total} bytes is past one ChaCha20 stream")
     head = MAGIC + struct.pack("<I", VERSION)
-    blob = head + seal(root_key, b"checkpoint", payload, aad=head)
     path = checkpoint_path(state_dir, seq)
     tmp = path + f".tmp.{os.getpid()}"
+    torn = faults.active() and faults.hit("checkpoint.tmp.torn")
     fd = os.open(tmp, os.O_WRONLY | os.O_CREAT | os.O_TRUNC, 0o600)
     try:
-        if faults.active() and faults.hit("checkpoint.tmp.torn"):
-            write_all(fd, blob[: len(blob) // 2])
+        write_all(fd, head)
+        out = _SealedWriter(fd, root_key, b"checkpoint", head, span)
+        out.write(struct.pack("<QI", seq, len(manifest)))
+        out.write(manifest)
+        for leaf in leaves:
+            blocks = _host_blocks(leaf, STREAM_BLOCK_BYTES)
+            while True:
+                with span("checkpoint_read"):
+                    block = next(blocks, None)
+                if block is None:
+                    break
+                out.write(block)
+                if torn and out.taken >= total // 2:
+                    out.flush()
+                    os.fsync(fd)
+                    faults.die()
+        out.close()
+        with span("checkpoint_write"):
             os.fsync(fd)
-            faults.die()
-        write_all(fd, blob)
-        os.fsync(fd)
     finally:
         os.close(fd)
     if faults.active():
         faults.crash("checkpoint.pre_rename")
-    os.replace(tmp, path)
-    dfd = os.open(state_dir, os.O_RDONLY)
-    try:
-        os.fsync(dfd)
-    finally:
-        os.close(dfd)
+    with span("checkpoint_write"):
+        os.replace(tmp, path)
+        _fsync_dir(state_dir)
     if faults.active():
         faults.crash("checkpoint.post_rename")
     return path
 
 
+class _SealedReader:
+    """:func:`unseal` as a stream from ``fh``, positioned at the nonce,
+    with ``ct_len`` bytes of ciphertext and the tag behind it: read,
+    MACed and decrypted block by block into one staging block, handed
+    on in order by :meth:`readinto`. The tag is known good or bad only
+    at :meth:`finish`: until then what was read is bytes to move, not
+    to believe."""
+
+    def __init__(self, fh, root_key: bytes, domain: bytes, aad: bytes,
+                 ct_len: int):
+        self._enc, mac = _seal_keys(root_key, domain)
+        self._nonce = fh.read(12)
+        self._mac = hmac.new(mac, aad + self._nonce, hashlib.sha256)
+        self._fh, self._left = fh, ct_len
+        self._buf = np.empty(STREAM_BLOCK_BYTES, np.uint8)
+        self._n = self._off = 0  # plaintext staged, and handed on
+        self._blocks = 0
+
+    def _next_block(self) -> None:
+        n = min(self._buf.size, self._left)
+        if not n:
+            raise CheckpointError("state payload truncated (leaf cut short)")
+        block = self._buf[:n]
+        if self._fh.readinto(memoryview(block)) != n:
+            raise CheckpointError("sealed file cut short")
+        self._mac.update(block)
+        _xor_in_place(self._enc, self._nonce, self._blocks, block)
+        self._blocks += -(-n // 64)
+        self._left -= n
+        self._n, self._off = n, 0
+
+    def readinto(self, dst: np.ndarray) -> None:
+        """Fill ``dst`` (flat u8) with the next plaintext bytes."""
+        off = 0
+        while off < dst.size:
+            if self._off == self._n:
+                self._next_block()
+            n = min(dst.size - off, self._n - self._off)
+            dst[off:off + n] = self._buf[self._off:self._off + n]
+            off += n
+            self._off += n
+
+    def finish(self) -> bool:
+        """Whether every byte of ciphertext was taken (the caller sized
+        the stream: ``ct_len`` is what it reads) and the tag verifies."""
+        return (self._left == 0 and self._off == self._n
+                and hmac.compare_digest(self._fh.read(32),
+                                        self._mac.digest()))
+
+
+def _refuse_unfit(fh, path: str, root_key: bytes, ecfg: EngineConfig,
+                  head: bytes, ct_len: int, want_len: int) -> None:
+    """A checkpoint whose size is not this geometry's: authenticate it
+    (a stream of MAC updates, no plaintext), then say what it is. Always
+    raises CheckpointError."""
+    enc, mac = _seal_keys(root_key, b"checkpoint")
+    nonce = fh.read(12)
+    tag = hmac.new(mac, head + nonce, hashlib.sha256)
+    first, left = b"", ct_len
+    while left:
+        chunk = fh.read(min(left, 1 << 20))
+        if not chunk:
+            break
+        first = first or chunk
+        tag.update(chunk)
+        left -= len(chunk)
+    if left or not hmac.compare_digest(fh.read(32), tag.digest()):
+        raise CheckpointError(f"{path}: {_INTEGRITY}")
+    if ct_len < 8:
+        raise CheckpointError(f"{path}: payload truncated")
+    _check_manifest(ecfg, stream_xor(enc, nonce, first)[8:])
+    raise CheckpointError(
+        "state payload truncated (leaf cut short)" if ct_len < want_len
+        else f"state payload has {ct_len - want_len} trailing bytes")
+
+
+def _load_leaf(reader: _SealedReader, want, place, old, block_bytes: int):
+    """One leaf from the stream to its device. A plane on one device is
+    written row block by row block into the array that is there (``old``,
+    donated to each update, or zeros): never two of it. Anything else is
+    staged whole on the host and takes ``old``'s place."""
+    dt = np.dtype(want.dtype).newbyteorder("<")
+    shape = tuple(want.shape)
+    nbytes = dt.itemsize * int(np.prod(shape, dtype=np.int64))
+    one_device = place is None or len(place.device_set) == 1
+    if nbytes <= block_bytes or not shape or not one_device:
+        arr = np.empty(shape, dt)
+        reader.readinto(arr.reshape(-1).view(np.uint8))
+        if old is not None and nbytes > block_bytes:
+            old.delete()  # a mesh engine's plane: gone before its heir
+        return jax.device_put(
+            arr.astype(dt.newbyteorder("="), copy=False), place)
+    plane = old if old is not None else jax.device_put(
+        jax.numpy.zeros(shape, want.dtype), place)
+    rows = _block_rows(shape[0], nbytes, block_bytes)
+    staged = np.empty((rows,) + shape[1:], dt)
+    for r0 in range(0, shape[0], rows):
+        block = staged[: min(rows, shape[0] - r0)]
+        reader.readinto(block.reshape(-1).view(np.uint8))
+        # waited for, so that the one staging block can be filled again
+        plane = jax.block_until_ready(
+            _put_rows(plane, block.astype(dt.newbyteorder("="), copy=False),
+                      r0))
+    return plane
+
+
 def load_checkpoint(
-    path: str, root_key: bytes, ecfg: EngineConfig, shardings=None
+    path: str, root_key: bytes, ecfg: EngineConfig, shardings=None,
+    into: EngineState | None = None,
 ) -> tuple[int, EngineState]:
     """Load a sealed checkpoint; returns ``(seq, state)``. Any
-    truncation, tamper, or geometry mismatch raises CheckpointError —
-    the state is never half-loaded. ``shardings``: see
-    :func:`bytes_to_state`."""
-    with open(path, "rb") as fh:
-        blob = fh.read()
+    truncation, tamper, or geometry mismatch raises CheckpointError and
+    no state comes back.
+
+    The file is read once, as a stream (:class:`_SealedReader`), and
+    its leaves go to the device as they arrive, laid out by *this
+    engine's* geometry (``state_spec``), which also fixes the file's
+    length to the byte: a file of any other length is authenticated and
+    refused before a byte of it is decrypted for use. ``into``: a state
+    of this geometry that the load consumes, writing each plane into the
+    array that is there, so that the device never holds a second state;
+    without it the planes are made here. Until the tag has verified,
+    nothing that was read is looked at (the manifest, the seq) or
+    handed out; a load that fails has consumed ``into`` all the same,
+    which is why a failed recovery leaves no engine (``GrapevineEngine.
+    recover``). ``shardings``: see :func:`bytes_to_state`; a leaf that
+    lies on several devices is staged whole on the host."""
     head = MAGIC + struct.pack("<I", VERSION)
-    if len(blob) < len(head) or blob[: len(MAGIC)] != MAGIC:
-        raise CheckpointError(f"{path}: not a grapevine checkpoint")
-    if blob[len(MAGIC) : len(head)] != head[len(MAGIC) :]:
-        (ver,) = struct.unpack_from("<I", blob, len(MAGIC))
-        raise CheckpointError(f"{path}: version {ver}, want {VERSION}")
-    try:
-        payload = unseal(root_key, b"checkpoint", blob[len(head):], aad=head)
-    except SealError as exc:
-        raise CheckpointError(f"{path}: {exc}") from None
-    if len(payload) < 8:
-        raise CheckpointError(f"{path}: payload truncated")
-    (seq,) = struct.unpack_from("<Q", payload, 0)
-    return seq, bytes_to_state(ecfg, payload[8:], shardings)
+    treedef, spec = state_spec(ecfg)
+    manifest = _manifest(ecfg, spec)
+    want_len = 8 + 4 + len(manifest) + sum(
+        np.dtype(x.dtype).itemsize * int(np.prod(x.shape, dtype=np.int64))
+        for x in spec)
+    olds = (jax.tree_util.tree_leaves(into) if into is not None
+            else [None] * len(spec))
+    with open(path, "rb") as fh:
+        got = fh.read(len(head))
+        if len(got) < len(head) or got[: len(MAGIC)] != MAGIC:
+            raise CheckpointError(f"{path}: not a grapevine checkpoint")
+        if got != head:
+            (ver,) = struct.unpack_from("<I", got, len(MAGIC))
+            raise CheckpointError(f"{path}: version {ver}, want {VERSION}")
+        ct_len = os.fstat(fh.fileno()).st_size - len(head) - 12 - 32
+        if ct_len < 0:
+            raise CheckpointError(
+                f"{path}: sealed blob truncated (shorter than nonce + tag)")
+        if ct_len != want_len:
+            _refuse_unfit(fh, path, root_key, ecfg, head, ct_len, want_len)
+        reader = _SealedReader(fh, root_key, b"checkpoint", head, ct_len)
+        first = np.empty(12 + len(manifest), np.uint8)
+        reader.readinto(first)
+        leaves = [
+            _load_leaf(reader, want, place, old, STREAM_BLOCK_BYTES)
+            for want, place, old in zip(
+                spec, _places(shardings, len(spec)), olds)
+        ]
+        if not reader.finish():
+            raise CheckpointError(f"{path}: {_INTEGRITY}")
+    # authenticated: now the plaintext may say what it is
+    seq, head_len = struct.unpack_from("<QI", first, 0)
+    if head_len != len(manifest) or first[12:].tobytes() != manifest:
+        _check_manifest(ecfg, first[8:].tobytes())
+        raise CheckpointError(
+            f"{path}: state manifest is not this engine's geometry's")
+    return seq, jax.tree_util.tree_unflatten(treedef, leaves)
 
 
 def find_latest_checkpoint(state_dir: str) -> tuple[int, str] | None:
@@ -425,12 +796,13 @@ class DurabilityManager:
     construction). Telemetry is batch-level only: sequence numbers,
     counts, and durations — never content."""
 
-    def __init__(self, dcfg: DurabilityConfig, ecfg: EngineConfig,
-                 registry=None, state_shardings=None):
-        from .journal import BatchJournal
-
-        self.dcfg = dcfg
+    def __init__(self, dcfg, ecfg: EngineConfig,
+                 registry=None, state_shardings=None, span=_plain_span):
+        self.dcfg = dcfg = DurabilityConfig.coerce(dcfg)
         self.ecfg = ecfg
+        #: ``span(name, ledger)``: the engine's span primitive with its
+        #: phase histogram behind it (``EngineMetrics.span``)
+        self._span = span
         #: a mesh engine's per-leaf placement (bytes_to_state); None =
         #: restored checkpoints go to the default device
         self.state_shardings = state_shardings
@@ -441,7 +813,8 @@ class DurabilityManager:
         self.root_key = load_or_create_root_key(key_path)
         self._c_records = self._c_fsyncs = self._c_ckpts = None
         self._g_durable = self._g_ckpt = self._g_replayed = None
-        self._g_recovery_s = self._g_applied = None
+        self._g_recovery_s = self._g_applied = self._g_load_s = None
+        self._g_ckpt_s = self._g_ckpt_bytes = None
         if registry is not None:
             self._c_records = registry.counter(
                 "grapevine_journal_records_total",
@@ -464,6 +837,17 @@ class DurabilityManager:
             self._g_recovery_s = registry.gauge(
                 "grapevine_recovery_seconds",
                 "wall time of the last startup recovery")
+            self._g_load_s = registry.gauge(
+                "grapevine_recovery_load_seconds",
+                "the part of the last recovery spent loading the "
+                "checkpoint, file to device, waited for (0: none found)")
+            self._g_ckpt_s = registry.gauge(
+                "grapevine_checkpoint_seconds",
+                "wall time of the last sealed checkpoint, journal sync "
+                "to journal roll: how long it held the engine's lock")
+            self._g_ckpt_bytes = registry.gauge(
+                "grapevine_checkpoint_bytes",
+                "size of the last sealed checkpoint's file")
             self._g_applied = registry.gauge(
                 "grapevine_journal_applied_seq",
                 "highest journal sequence applied to engine state (on "
@@ -472,11 +856,7 @@ class DurabilityManager:
                 "frontier — the fleet aggregator derives "
                 "grapevine_fleet_journal_lag_seq from it; ROADMAP "
                 "item 4, OPERATIONS.md §20)")
-        self.journal = BatchJournal(
-            dcfg.state_dir, self.root_key, ecfg,
-            fsync_every=dcfg.journal_fsync_every,
-            on_fsync=self._note_fsync,
-        )
+        self.journal = self._new_journal()
         self.ckpt_seq = 0  # journal seq covered by the newest checkpoint
         #: highest journal seq applied to engine state. On the primary
         #: this tracks journal.seq (each record is applied as part of
@@ -485,6 +865,27 @@ class DurabilityManager:
         #: lag the fleet aggregator prices (obs/fleet.py).
         self.applied_seq = 0
         self.replayed = 0
+        self.recovered_from_checkpoint = False
+
+    def _new_journal(self):
+        from .journal import BatchJournal
+
+        return BatchJournal(
+            self.dcfg.state_dir, self.root_key, self.ecfg,
+            fsync_every=self.dcfg.journal_fsync_every,
+            on_fsync=self._note_fsync,
+        )
+
+    def abandon(self) -> None:
+        """What a SIGKILL leaves of this manager: the journal's handle
+        dropped with no sync and no checkpoint, and every count of
+        this process forgotten. :meth:`recover` then starts from the
+        state directory alone, as a new process would."""
+        doorbell = self.journal.on_append
+        self.journal.abandon()
+        self.journal = self._new_journal()
+        self.journal.on_append = doorbell
+        self.ckpt_seq = self.applied_seq = self.replayed = 0
         self.recovered_from_checkpoint = False
 
     # journal callback — runs under the engine lock with the append
@@ -501,14 +902,23 @@ class DurabilityManager:
         ``apply_fn(state, record)`` applies one journal record and
         returns the next state (the engine's jitted step/sweep).
         Corrupt checkpoints and mid-journal corruption raise — only a
-        torn *tail* frame (the crash-mid-append case) is discarded."""
+        torn *tail* frame (the crash-mid-append case) is discarded.
+
+        ``init_state`` is consumed: a checkpoint's planes are written
+        into its arrays (:func:`load_checkpoint` ``into``), so the
+        device holds one state throughout. After a raise it is not to
+        be used."""
         t0 = time.monotonic()
         state = init_state
+        load_s = 0.0
         latest = find_latest_checkpoint(self.dcfg.state_dir)
         if latest is not None:
             seq, state = load_checkpoint(
-                latest[1], self.root_key, self.ecfg, self.state_shardings
+                latest[1], self.root_key, self.ecfg, self.state_shardings,
+                into=init_state,
             )
+            jax.block_until_ready(state)
+            load_s = time.monotonic() - t0
             if seq != latest[0]:
                 # the filename seq picks which file to load; the sealed
                 # payload seq is what replay trusts — a renamed file
@@ -528,10 +938,14 @@ class DurabilityManager:
             if self._g_replayed is not None:
                 self._g_replayed.set(self.replayed)
         self.journal.open_for_append()
+        # the replay is dispatched, not yet applied: the recovery ends
+        # when the device has it
+        jax.block_until_ready(state)
         if self._g_ckpt is not None:
             self._g_ckpt.set(self.ckpt_seq)
             self._g_durable.set(self.journal.seq)
             self._g_recovery_s.set(round(time.monotonic() - t0, 6))
+            self._g_load_s.set(round(load_s, 6))
         return state
 
     # -- steady state ---------------------------------------------------
@@ -590,11 +1004,7 @@ class DurabilityManager:
         finally:
             os.close(fd)
         os.replace(tmp, path)
-        dfd = os.open(self.dcfg.state_dir, os.O_RDONLY)
-        try:
-            os.fsync(dfd)
-        finally:
-            os.close(dfd)
+        _fsync_dir(self.dcfg.state_dir)
         got_seq, state = load_checkpoint(
             path, self.root_key, self.ecfg, self.state_shardings
         )
@@ -623,19 +1033,24 @@ class DurabilityManager:
             >= self.dcfg.checkpoint_every_rounds
         )
 
-    def checkpoint(self, state: EngineState) -> int:
+    def checkpoint(self, state: EngineState, ledger: dict | None = None) -> int:
         """Seal the current state at the current journal seq, then roll
         the journal and prune files the new checkpoint covers. Returns
         the checkpointed seq (also when skipped because nothing new was
-        journaled)."""
+        journaled). ``ledger``: the span ledger of the round whose
+        dispatch the checkpoint fell due in; its parts
+        (``checkpoint_read`` / ``_seal`` / ``_write``) land there beside
+        the caller's own ``checkpoint`` span."""
         seq = self.journal.seq
         if seq == self.ckpt_seq and self.recovered_from_checkpoint:
             return seq  # nothing journaled since the last checkpoint
+        t0 = time.monotonic()
         # make the journal tail durable first: if the checkpoint crashes
         # half-way, recovery must still reach seq via the old chain
         self.journal.sync()
-        write_checkpoint(
-            self.dcfg.state_dir, self.root_key, self.ecfg, state, seq
+        path = write_checkpoint(
+            self.dcfg.state_dir, self.root_key, self.ecfg, state, seq,
+            span=lambda name: self._span(name, ledger),
         )
         self.ckpt_seq = seq
         self.recovered_from_checkpoint = True
@@ -644,6 +1059,8 @@ class DurabilityManager:
         if self._c_ckpts is not None:
             self._c_ckpts.inc()
             self._g_ckpt.set(seq)
+            self._g_ckpt_s.set(round(time.monotonic() - t0, 6))
+            self._g_ckpt_bytes.set(os.path.getsize(path))
         return seq
 
     def status(self) -> dict:

@@ -226,6 +226,11 @@ class BatchJournal:
         #: engine lock with the append, so it must only enqueue/signal,
         #: never block on I/O (engine/replication.py JournalShipper).
         self.on_append = None
+        #: the last append's cost, for the round's ledger (obs/tracer.py
+        #: ROUND_COUNTS ``journal_*``): seconds sealing the frame,
+        #: seconds in the fsync barrier (0.0 when none fell due), and
+        #: the frame's bytes on disk
+        self.last_append = {"seal_s": 0.0, "fsync_s": 0.0, "bytes": 0}
         #: the only two legal blob lengths for this geometry (round
         #: bodies are constant-size given B; sweeps are fixed). Replay
         #: uses this to tell a corrupted length field (raise) from a
@@ -663,7 +668,9 @@ class BatchJournal:
         seq = self.seq + 1
         blob_len = len(body) + _SEAL_OVERHEAD
         header = _HEADER.pack(FRAME_MAGIC, seq, blob_len)
+        t0 = time.perf_counter()
         frame = header + seal(self.root_key, b"journal", body, aad=header)
+        t1 = time.perf_counter()
         if faults.active():
             faults.crash("journal.append.pre")
             if faults.hit("journal.append.torn"):
@@ -680,8 +687,12 @@ class BatchJournal:
             # keeps the standby at most the fsync batch behind
             self.on_append(seq, frame)
         self._since_fsync += 1
+        t2 = time.perf_counter()
         if self._since_fsync >= self.fsync_every:
             self.sync()
+        self.last_append = {"seal_s": t1 - t0,
+                            "fsync_s": time.perf_counter() - t2,
+                            "bytes": len(frame)}
         if faults.active():
             faults.crash("journal.append.post_fsync")
         return seq
@@ -765,5 +776,11 @@ class BatchJournal:
     def close(self) -> None:
         if self._fd is not None:
             self.sync()
+            os.close(self._fd)
+            self._fd = None
+
+    def abandon(self) -> None:
+        """Drop the handle as a killed process does: no sync first."""
+        if self._fd is not None:
             os.close(self._fd)
             self._fd = None

@@ -169,12 +169,45 @@ def _bind(handle):
         ctypes.c_char_p, ctypes.c_char_p, ctypes.c_size_t,
         ctypes.c_char_p, ctypes.c_char_p, ctypes.POINTER(ctypes.c_char),
     ]
+    handle.r255_chacha20_xor.restype = ctypes.c_int
+    handle.r255_chacha20_xor.argtypes = [
+        ctypes.c_char_p, ctypes.c_char_p, ctypes.c_uint64, ctypes.c_void_p,
+        ctypes.c_void_p, ctypes.c_size_t, ctypes.c_size_t,
+    ]
     if handle.r255_init() != 0:
         return None
     return handle
 
 
 lib = _load()
+
+
+def chacha20_xor(key: bytes, nonce: bytes, counter: int, src, dst=None,
+                 threads: int = 1):
+    """``src`` XOR the RFC 7539 ChaCha20 keystream from block
+    ``counter`` on, written to ``dst``: any writable buffer of
+    ``src``'s length (``src`` itself seals in place), or a new
+    ``bytearray``, which is returned either way. On up to ``threads``
+    threads inside the call, with no GIL held and no module lock (the C
+    function writes its stack and ``dst``). Requires ``lib is not
+    None``; ``engine/checkpoint.py`` holds the numpy reference this is
+    pinned to. ValueError for a wrong key or nonce length, buffers of
+    different lengths, or a counter that would pass 2^32 blocks."""
+    import numpy as np
+
+    if len(key) != 32 or len(nonce) != 12:
+        raise ValueError("key must be 32 bytes, nonce 12")
+    src_a = np.frombuffer(src, np.uint8)
+    if dst is None:
+        dst = bytearray(src_a.size)
+    dst_a = np.frombuffer(dst, np.uint8)
+    if dst_a.size != src_a.size or not dst_a.flags.writeable:
+        raise ValueError("dst must be a writable buffer of src's length")
+    if src_a.size and lib.r255_chacha20_xor(
+            bytes(key), bytes(nonce), counter, src_a.ctypes.data,
+            dst_a.ctypes.data, src_a.size, threads) != 0:
+        raise ValueError("ChaCha20 block counter would pass 2^32")
+    return dst
 
 
 def verify1(pub: bytes, r_enc: bytes, s: bytes, k: bytes) -> int:

@@ -21,10 +21,17 @@
  *       REENTRANT: every byte a chunk check writes is on its stack or
  *       in its own heap arena, so the k chunks run on k threads for
  *       the length of the call, and any number of calls side by side
+ *   r255_chacha20_xor(key, nonce, counter, src, dst, n, threads)
+ *       dst = src XOR the RFC 7539 ChaCha20 keystream from block
+ *       `counter` on: the seal of the journal and of checkpoints
+ *       (engine/checkpoint.py), eight blocks a pass, on up to `threads`
+ *       threads for the length of the call                  -> 0/-1
  *
- * Verification-only: nothing here handles secrets, so variable-time
- * arithmetic is fine (same stance as the pure-Python path it
- * accelerates, session/ristretto.py).
+ * The group code is verification-only: it handles no secrets, so
+ * variable-time arithmetic is fine (same stance as the pure-Python path
+ * it accelerates, session/ristretto.py). ChaCha20 does take a secret
+ * key, and is add / rotate / xor on registers throughout: no branch and
+ * no address depends on key or data.
  *
  * Built by `cc -O2 -shared -fPIC -pthread` at first import; correctness is
  * pinned by cross-checking against the pure-Python implementation over
@@ -1109,4 +1116,142 @@ int r255_round_check(size_t n, size_t k, const uint8_t *pubs,
         *elapsed_s = (double)(b.tv_sec - a.tv_sec)
                      + (double)(b.tv_nsec - a.tv_nsec) * 1e-9;
     return rc;
+}
+
+
+/* ---- ChaCha20 (RFC 7539), the seal's stream cipher --------------------
+ *
+ * engine/checkpoint.py keeps the numpy version as the plain reference
+ * and pins this one to it byte for byte (tests/test_seal_stream.py).
+ * Eight blocks a pass, one per lane of a 256-bit vector, written with
+ * the compiler's vector extension: with AVX2 one instruction a step,
+ * without it two SSE2 ones (the build has no -march, so the AVX2 body
+ * is a clone the loader picks on a CPU that has it). */
+
+typedef uint32_t v8u __attribute__((vector_size(32)));
+
+#define CC_ROT(v, n) (((v) << (n)) | ((v) >> (32 - (n))))
+#define CC_QR(a, b, c, d)                                             \
+    a += b; d ^= a; d = CC_ROT(d, 16);                                \
+    c += d; b ^= c; b = CC_ROT(b, 12);                                \
+    a += b; d ^= a; d = CC_ROT(d, 8);                                 \
+    c += d; b ^= c; b = CC_ROT(b, 7);
+
+static uint32_t load32_le(const uint8_t *p) {
+    return (uint32_t)p[0] | (uint32_t)p[1] << 8 | (uint32_t)p[2] << 16
+           | (uint32_t)p[3] << 24;
+}
+
+/* the keystream of blocks counter .. counter+7, 512 bytes, block after
+ * block; the counter wraps at 2^32 as the reference's does */
+#if defined(__x86_64__) && defined(__linux__) && defined(__GNUC__) \
+    && !defined(__clang__)
+__attribute__((target_clones("avx2", "default")))
+#endif
+static void chacha20_ks8(const uint32_t key[8], const uint32_t nonce[3],
+                         uint32_t counter, uint8_t out[512]) {
+    static const uint32_t sigma[4] = {0x61707865, 0x3320646e, 0x79622d32,
+                                      0x6b206574};
+    v8u init[16], x[16];
+    for (int i = 0; i < 4; i++) init[i] = (v8u){0} + sigma[i];
+    for (int i = 0; i < 8; i++) init[4 + i] = (v8u){0} + key[i];
+    init[12] = (v8u){0, 1, 2, 3, 4, 5, 6, 7} + counter;
+    for (int i = 0; i < 3; i++) init[13 + i] = (v8u){0} + nonce[i];
+    for (int i = 0; i < 16; i++) x[i] = init[i];
+    for (int r = 0; r < 10; r++) {
+        CC_QR(x[0], x[4], x[8], x[12])
+        CC_QR(x[1], x[5], x[9], x[13])
+        CC_QR(x[2], x[6], x[10], x[14])
+        CC_QR(x[3], x[7], x[11], x[15])
+        CC_QR(x[0], x[5], x[10], x[15])
+        CC_QR(x[1], x[6], x[11], x[12])
+        CC_QR(x[2], x[7], x[8], x[13])
+        CC_QR(x[3], x[4], x[9], x[14])
+    }
+    for (int i = 0; i < 16; i++) {
+        x[i] += init[i];
+        for (int lane = 0; lane < 8; lane++) {
+            uint32_t w = x[i][lane];
+            uint8_t *o = out + 64 * lane + 4 * i;
+            o[0] = (uint8_t)w; o[1] = (uint8_t)(w >> 8);
+            o[2] = (uint8_t)(w >> 16); o[3] = (uint8_t)(w >> 24);
+        }
+    }
+}
+
+typedef struct {
+    const uint32_t *key, *nonce;
+    uint32_t counter;
+    const uint8_t *src;
+    uint8_t *dst;
+    size_t n;
+} chacha_job;
+
+static void *chacha_job_run(void *arg) {
+    chacha_job *j = arg;
+    uint8_t ks[512];
+    uint32_t counter = j->counter;
+    for (size_t off = 0; off < j->n; off += 512, counter += 8) {
+        size_t m = j->n - off < 512 ? j->n - off : 512;
+        chacha20_ks8(j->key, j->nonce, counter, ks);
+        const uint8_t *s = j->src + off;
+        uint8_t *d = j->dst + off;
+        size_t i = 0;
+        for (; i + 8 <= m; i += 8) {  /* words: dst may be src */
+            uint64_t a, b;
+            memcpy(&a, s + i, 8);
+            memcpy(&b, ks + i, 8);
+            a ^= b;
+            memcpy(d + i, &a, 8);
+        }
+        for (; i < m; i++) d[i] = s[i] ^ ks[i];
+    }
+    return NULL;
+}
+
+#define CHACHA_MAX_THREADS 16
+/* below this a part is not worth a thread of its own */
+#define CHACHA_MIN_PART (1u << 20)
+
+/* dst[0..n) = src[0..n) XOR keystream(key, nonce) from block `counter`
+ * on. dst may be src; otherwise the two do not overlap. The bytes are
+ * cut into at most `threads` parts of whole 512-byte passes, one on the
+ * caller's thread and the rest on threads that live for this call, as
+ * round_check's are. -1 when the counter would pass 2^32 (a keystream
+ * block met twice), 0 otherwise. */
+int r255_chacha20_xor(const uint8_t key[32], const uint8_t nonce[12],
+                      uint64_t counter, const uint8_t *src, uint8_t *dst,
+                      size_t n, size_t threads) {
+    uint32_t k[8], nc[3];
+    if (counter + (n + 63) / 64 > ((uint64_t)1 << 32)) return -1;
+    for (int i = 0; i < 8; i++) k[i] = load32_le(key + 4 * i);
+    for (int i = 0; i < 3; i++) nc[i] = load32_le(nonce + 4 * i);
+    if (threads < 1) threads = 1;
+    if (threads > CHACHA_MAX_THREADS) threads = CHACHA_MAX_THREADS;
+    if (threads > n / CHACHA_MIN_PART) threads = n / CHACHA_MIN_PART;
+    if (threads < 1) threads = 1;
+    size_t step = ((n + threads - 1) / threads + 511) / 512 * 512;
+    chacha_job jobs[CHACHA_MAX_THREADS];
+    pthread_t tids[CHACHA_MAX_THREADS];
+    int started[CHACHA_MAX_THREADS] = {0};
+    size_t njobs = 0;
+    for (size_t off = 0; off < n; off += step, njobs++) {
+        chacha_job *j = &jobs[njobs];
+        j->key = k; j->nonce = nc;
+        j->counter = (uint32_t)(counter + off / 64);
+        j->src = src + off; j->dst = dst + off;
+        j->n = n - off < step ? n - off : step;
+    }
+    sigset_t all, old;
+    sigfillset(&all);
+    pthread_sigmask(SIG_SETMASK, &all, &old);
+    for (size_t c = 1; c < njobs; c++)
+        started[c] = pthread_create(&tids[c], NULL, chacha_job_run,
+                                    &jobs[c]) == 0;
+    pthread_sigmask(SIG_SETMASK, &old, NULL);
+    for (size_t c = 0; c < njobs; c++) {
+        if (started[c]) pthread_join(tids[c], NULL);
+        else chacha_job_run(&jobs[c]);  /* the caller's own, or no thread */
+    }
+    return 0;
 }

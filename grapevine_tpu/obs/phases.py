@@ -24,6 +24,11 @@ Host-side phases (histograms + ``jax.profiler`` annotations):
 - ``sweep``     — expiry sweep (engine/expiry.py)
 - ``journal``   — sealed batch-journal append + fsync (engine/journal.py)
 - ``checkpoint``— sealed whole-state checkpoint write (engine/checkpoint.py)
+                  and, inside it on the same thread, its three parts:
+                  ``checkpoint_read`` (a block, device to host),
+                  ``checkpoint_seal`` (staged and encrypted) and
+                  ``checkpoint_write`` (the waits for the file thread's
+                  MAC + write, then fsync, rename, directory fsync)
 - ``replay``    — startup journal replay (recovery; engine/batcher.py)
 
 The collector thread's further spans (``SPAN_NAMES``; no histogram, the
@@ -66,7 +71,8 @@ from .registry import TelemetryLeakError
 #: canonical phase label values — the registry declares exactly these,
 #: so a typo'd phase name raises instead of minting a new series
 PHASES = ("assembly", "verify", "dispatch", "evict", "demux", "sweep",
-          "journal", "checkpoint", "replay")
+          "journal", "checkpoint", "replay",
+          "checkpoint_read", "checkpoint_seal", "checkpoint_write")
 
 #: canonical device scope names — ``device_phase`` refuses any other, so
 #: a typo'd or per-op scope name raises at trace time instead of minting

@@ -168,11 +168,16 @@ ALLOWED_SPAN_NAMES = frozenset(STABLE_SPANS) | frozenset(PHASES)
 #: call's own clock); ``cycle_unspanned_s`` = the cycle's wall that no span
 #: inside it names. So ``cycle`` = ``cycle_wait_s`` + working wall +
 #: ``cycle_unspanned_s``, and working wall = CPU + ``cycle_blocked_s`` +
-#: the native crossing
+#: the native crossing. The ``journal_*`` counts are the parts of the
+#: round's ``journal`` span (engine/journal.py ``last_append``):
+#: ``journal_seal_s`` sealing the frame, ``journal_fsync_s`` in the
+#: fsync barrier, ``journal_bytes`` the frame on disk, a function of
+#: the batch size alone; all 0 on an engine with no state directory
 ROUND_COUNTS = ("ops", "rejected", "queue_wait_sum_s", "rounds_ahead",
                 "device_exact", "verify_chunks", "cycle_wait_s",
                 "cycle_cpu_s", "cycle_blocked_s", "cycle_native_wait_s",
-                "cycle_unspanned_s")
+                "cycle_unspanned_s", "journal_seal_s", "journal_fsync_s",
+                "journal_bytes")
 
 
 def _check_span(name: str, value) -> tuple[float, float]:

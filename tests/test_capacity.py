@@ -180,6 +180,64 @@ def test_the_chips_real_share_resolves_to_what_its_file_says():
     assert (cfg.max_recipients // 2) * cfg.mailbox_cap >= cfg.max_messages
 
 
+def test_the_durable_deployment_resolves_to_what_its_file_says(tmp_path):
+    """``benchmarks/configs/chipshare-2p21-r2p17-durable.json`` is
+    ``chipshare-2p21-r2p17`` with ``--state-dir``; shapes only, no tree
+    allocated. The round is its parent's key for key; what it adds is
+    held here: the mapping the server's ``durability`` takes, the
+    journal frame every round writes (the whole batch, 2048 x 1,020 B,
+    plus header and seal), the checkpoint's bytes (the state, plus
+    head, seq, manifest and seal), and the cadence's arithmetic."""
+    import json
+
+    from grapevine_tpu.config import DurabilityConfig
+    from grapevine_tpu.engine import checkpoint as cp
+    from grapevine_tpu.engine.journal import _HEADER, BatchJournal
+    from grapevine_tpu.engine.state import state_spec
+
+    spec, cfg, ecfg, _, state_bytes = _held_to_its_file(
+        "chipshare-2p21-r2p17-durable")
+    with open(os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "benchmarks", "configs",
+            "chipshare-2p21-r2p17.json")) as f:
+        parent = json.load(f)
+    assert spec["grapevine_config"] == parent["grapevine_config"]
+    assert spec["reduced"] == {} and spec["chips"] == 1
+    said = spec["resolves_to"]
+    for tree in ("records", "mailbox"):
+        assert said[tree] == parent["resolves_to"][tree]
+    assert state_bytes == said["state_bytes"] == 10_253_823_600
+    for k, v in parent["guarantees"].items():
+        assert spec["guarantees"][k] == v
+    assert "fsynced before it dispatches" in spec["guarantees"]["durability"]
+    # the mapping builds the deployment OPERATIONS.md 11 documents
+    fields = spec["server"]["durability"]
+    dcfg = DurabilityConfig.coerce(fields)
+    assert dcfg.journal_fsync_every == 1 and dcfg.seal_key_file is None
+    assert dcfg.state_dir == os.path.abspath(fields["state_dir"])
+    assert "/.scratch/backlog-durable-1chip-2p21/" in dcfg.state_dir
+    # no cadence checkpoint can fall into a run: N is a power of two,
+    # never under 512, and the least with stall / (N x period) <= 5 %
+    n = dcfg.checkpoint_every_rounds
+    sizing = spec["assumed"]["checkpoint_every_rounds"]
+    assert n >= 512 and n & (n - 1) == 0 and sizing["N"] == n
+    stall, period = sizing["checkpoint_stall_s"], sizing["round_period_s"]
+    assert stall / (n * period) <= 0.05
+    assert n == 512 or stall / (n // 2 * period) > 0.05
+    # a round's frame: header, nonce, the whole batch, tag
+    body = 17 + cfg.batch_size * 1020
+    journal = BatchJournal(str(tmp_path), bytes(32), ecfg)
+    assert max(journal._valid_blob_lens) == 12 + body + 32
+    assert said["journal_frame_bytes"] == _HEADER.size + 12 + body + 32
+    assert said["journal_frame_bytes"] == 2_089_037
+    # the checkpoint: head, nonce, seq, manifest length, manifest, every
+    # leaf, tag
+    manifest = cp._manifest(ecfg, state_spec(ecfg)[1])
+    assert said["checkpoint_bytes"] == (
+        len(cp.MAGIC) + 4 + 12 + 8 + 4 + len(manifest) + state_bytes + 32)
+    assert said["checkpoint_bytes"] == 10_253_824_277
+
+
 def test_init_sharded_engine_matches_staged_init():
     """Shard-aware init is bit-identical to init-then-shard (threefry is
     deterministic under jit), at a shape small enough to stage both."""

@@ -376,7 +376,8 @@ def test_counts_ride_the_round_event_and_unlisted_ones_raise():
 
 def test_a_round_resolved_without_a_scheduler_keeps_the_shape(engine):
     """The direct path (no queue, no settle): zero-duration spans and
-    only the engine's own count."""
+    only the engine's own counts (the journal's read 0: no state
+    directory)."""
     tracer = RoundTracer(capacity=4)
     engine.attach_tracer(tracer)
     try:
@@ -386,7 +387,9 @@ def test_a_round_resolved_without_a_scheduler_keeps_the_shape(engine):
     (ledger,) = _ledgers(tracer)
     assert set(ledger["spans"]) == set(STABLE_SPANS)
     assert ledger["spans"]["queue"][1] == ledger["spans"]["settle"][1] == 0
-    assert set(ledger["counts"]) == {"device_exact"}
+    assert set(ledger["counts"]) == {
+        "device_exact", "journal_seal_s", "journal_fsync_s", "journal_bytes"}
+    assert ledger["counts"]["journal_bytes"] == 0
     d0, dd = ledger["spans"]["device"]
     i0, idur = ledger["spans"]["inflight"]
     assert i0 <= d0 and d0 + dd == pytest.approx(i0 + idur)

@@ -12,6 +12,13 @@ of them contains:
   files; the key lives only in its own 0600 key file, which the scan
   skips — it IS the key).
 
+The files are the ones the real write path leaves: a checkpoint
+written as a stream (engine/checkpoint.py ``write_checkpoint``; the
+fixture cuts its blocks to 4 KiB, so the state crosses some dozens of
+block boundaries and a slip of the running block counter would repeat
+keystream over marker-laden plaintext), and a journal whose frames were
+sealed by the native stream (``stream_xor``) behind that checkpoint.
+
 This is the durability analog of tools/check_telemetry_policy.py: the
 property OPERATIONS.md §11 promises ("sealed files are ciphertext —
 a stolen state volume without the key reveals sizes and cadence only"),
@@ -45,6 +52,7 @@ def run_fixture(state_dir: str) -> dict:
     """Rounds + a sweep + checkpoints against ``state_dir``; returns the
     byte patterns that must NOT appear in any sealed file."""
     from grapevine_tpu.config import DurabilityConfig, GrapevineConfig
+    from grapevine_tpu.engine import checkpoint
     from grapevine_tpu.engine.batcher import GrapevineEngine
     from grapevine_tpu.wire import constants as C
     from grapevine_tpu.wire.records import QueryRequest, RequestRecord
@@ -58,7 +66,8 @@ def run_fixture(state_dir: str) -> dict:
     reps = C.PAYLOAD_SIZE // len(PAYLOAD_MARKER) + 1
     payload = (PAYLOAD_MARKER * reps)[: C.PAYLOAD_SIZE]
     now = 1_700_000_000
-    for i in range(6):
+
+    def round_(i: int) -> None:
         reqs = [
             QueryRequest(
                 request_type=C.REQUEST_TYPE_CREATE,
@@ -73,8 +82,20 @@ def run_fixture(state_dir: str) -> dict:
             for _ in range(3)
         ]
         engine.handle_queries(reqs, now + i)
-    engine.expire(now + 10, period=10_000)
-    engine.checkpoint_now()
+
+    stream_block = checkpoint.STREAM_BLOCK_BYTES
+    checkpoint.STREAM_BLOCK_BYTES = 4096
+    try:
+        for i in range(6):
+            round_(i)
+        engine.expire(now + 10, period=10_000)
+        engine.checkpoint_now()
+    finally:
+        checkpoint.STREAM_BLOCK_BYTES = stream_block
+    # the checkpoint rolled the journal: two rounds more, so that the
+    # segment scanned holds sealed frames
+    for i in (7, 8):
+        round_(i)
     root_key = engine.durability.root_key
     engine.close()
     return {
@@ -111,7 +132,9 @@ def main() -> int:
             if os.path.isfile(os.path.join(state_dir, n))
         )
         if not any(n.startswith("ckpt-") for n in files) or not any(
-            n.startswith("journal-") for n in files
+            n.startswith("journal-")
+            and os.path.getsize(os.path.join(state_dir, n))
+            for n in files
         ):
             print(
                 f"SEAL GATE BROKEN: fixture wrote no checkpoint/journal "
