@@ -105,19 +105,19 @@ def oram_planes(cfg, prefix: str = "") -> dict:
     (name -> (shape, divisor)) — the tree-cache census's declarations
     plus the nonce plane's recursive alias and the internal posmap
     tree's planes (prefixed ``pm_``)."""
-    z, v = cfg.bucket_slots, cfg.value_words
+    z, sw = cfg.bucket_slots, cfg.stored_row_words
     n = cfg.n_buckets_padded
     cb = cfg.cache_buckets
     planes = {
         f"{prefix}tree_idx": ((n, z), 1),
-        f"{prefix}tree_val": ((n, z * v), 1),
+        f"{prefix}tree_val": ((n, sw), 1),
         f"{prefix}nonces": ((n, 2), 1),
     }
     if cfg.posmap is not None:
         planes[f"{prefix}tree_leaf"] = ((n, z), 1)
     if cb:
         planes[f"{prefix}cache_idx"] = ((cb * z,), z)
-        planes[f"{prefix}cache_val"] = ((cb, z * v), 1)
+        planes[f"{prefix}cache_val"] = ((cb, sw), 1)
         if cfg.posmap is not None:
             planes[f"{prefix}cache_leaf"] = ((cb * z,), z)
     if cfg.posmap is not None:
@@ -168,7 +168,7 @@ def oram_round_rows(cfg, b: int, prefix: str = "") -> dict:
       internal round of the same ``b`` (oram/posmap.py), composed here
       under the ``pm_`` prefix.
     """
-    z, v = cfg.bucket_slots, cfg.value_words
+    z, sw = cfg.bucket_slots, cfg.stored_row_words
     n = cfg.n_buckets_padded
     cb = cfg.cache_buckets
     recursive = cfg.posmap is not None
@@ -176,7 +176,7 @@ def oram_round_rows(cfg, b: int, prefix: str = "") -> dict:
 
     rows = {
         f"{prefix}tree_idx": PlaneRows((n, z), 1, z, R, R),
-        f"{prefix}tree_val": PlaneRows((n, z * v), 1, z * v, R, R),
+        f"{prefix}tree_val": PlaneRows((n, sw), 1, sw, R, R),
         # the fetch always gathers the nonce plane (the keystream input
         # precedes the encrypted? branch); the epoch commit scatter only
         # exists under the cipher. Recursive leaf decrypt re-gathers it.
@@ -192,7 +192,7 @@ def oram_round_rows(cfg, b: int, prefix: str = "") -> dict:
             (cb * z,), z, z, 0, 0, hbm=False
         )
         rows[f"{prefix}cache_val"] = PlaneRows(
-            (cb, z * v), 1, z * v, 0, 0, hbm=False
+            (cb, sw), 1, sw, 0, 0, hbm=False
         )
         if recursive:
             rows[f"{prefix}cache_leaf"] = PlaneRows(
@@ -254,11 +254,11 @@ def sweep_chunk_planes(cfg, prefix: str = "") -> dict:
     plane as a constant — whole-plane passes, not gathers, so the
     traced check reduces scan operands (:func:`traced_scan_rows`)
     instead of access primitives."""
-    z, v = cfg.bucket_slots, cfg.value_words
+    z, sw = cfg.bucket_slots, cfg.stored_row_words
     n = cfg.n_buckets_padded
     planes = {
         f"{prefix}tree_idx": ((n * z,), n),
-        f"{prefix}tree_val": ((n, z * v), n),
+        f"{prefix}tree_val": ((n, sw), n),
         f"{prefix}nonces": ((n, 2), n),
     }
     if cfg.posmap is not None and cfg.encrypted:
@@ -275,9 +275,9 @@ def expiry_sweep_rows(ecfg) -> dict:
     out = {}
     for prefix, cfg in (("rec_", ecfg.rec), ("mb_", ecfg.mb)):
         n = cfg.n_buckets_padded
-        z, v = cfg.bucket_slots, cfg.value_words
+        z, sw = cfg.bucket_slots, cfg.stored_row_words
         out[f"{prefix}tree_idx"] = PlaneRows((n, z), 1, z, n, n)
-        out[f"{prefix}tree_val"] = PlaneRows((n, z * v), 1, z * v, n, n)
+        out[f"{prefix}tree_val"] = PlaneRows((n, sw), 1, sw, n, n)
         out[f"{prefix}nonces"] = PlaneRows((n, 2), 1, 2, n, n)
         if cfg.posmap is not None and cfg.encrypted:
             out[f"{prefix}tree_leaf"] = PlaneRows((n, z), 1, z, n, n)

@@ -61,10 +61,14 @@ from ..testing import faults
 from .state import EngineConfig, EngineState, state_spec
 
 MAGIC = b"GVCKPT1\0"
-#: 2 since PR 40: the bucket cipher's keystream order changed
-#: (oblivious/bucket_cipher.py), so trees sealed under version 1 would
-#: decrypt to noise. Refused, not migrated: re-initialise the state.
-VERSION = 2
+#: 3 since PR 44: a value row of eight lane tiles or more is stored on
+#: whole tiles (OramConfig.stored_row_words: the mailbox row's 6,080
+#: words as 6,144), so the planes' shapes and the place of the
+#: slot-index words in a row's keystream both moved. 2 was PR 40's
+#: keystream order (oblivious/bucket_cipher.py); trees sealed under an
+#: older version would decrypt to noise. Refused, not migrated:
+#: re-initialise the state.
+VERSION = 3
 
 _CKPT_RE = re.compile(r"^ckpt-(\d{16})\.sealed$")
 

@@ -40,7 +40,7 @@ from ..oblivious.bucket_cipher import (
 from ..oblivious.primitives import SENTINEL, is_zero_words, u64_le, u64_sub
 from ..oblivious.radix import partition_rank
 from ..obs.phases import device_phase
-from ..oram.path_oram import OramConfig, OramState
+from ..oram.path_oram import OramConfig, OramState, logical_rows, stored_rows
 from .state import (
     ENT_SEQ,
     ENT_SEQH,
@@ -160,7 +160,10 @@ def _chunked_tree_sweep(cfg: OramConfig, oram: OramState, carry0, body,
             )
             ix = ix ^ ks_ix
             vl = vl ^ ks_vl
-        acc, (ix, vl) = body(acc, (ix, vl))
+        # the body sees the blocks' Z*V words; the stored row's pad
+        # words leave as the zeros they are in plaintext
+        acc, (ix, vl) = body(acc, (ix, logical_rows(cfg, vl)))
+        vl = stored_rows(cfg, vl)
         if cfg.encrypted:
             epn = jnp.broadcast_to(oram.epoch[None, :], (rpc, 2))
             ks_ix, ks_vl = row_plane_keystreams(
@@ -250,9 +253,12 @@ def expiry_sweep(
             zc = rcfg.bucket_slots
             present, (cix, cvl) = rec_body(
                 present,
-                (rec.cache_idx.reshape(-1, zc), rec.cache_val),
+                (rec.cache_idx.reshape(-1, zc),
+                 logical_rows(rcfg, rec.cache_val)),
             )
-            rec = rec._replace(cache_idx=cix.reshape(-1), cache_val=cvl)
+            rec = rec._replace(
+                cache_idx=cix.reshape(-1), cache_val=stored_rows(rcfg, cvl)
+            )
 
     # stash rows are plaintext private state
     st_live = state.rec.stash_idx != SENTINEL
@@ -320,11 +326,13 @@ def expiry_sweep(
         if ecfg.mb.top_cache_levels:
             zc = ecfg.mb.bucket_slots
             mc_idx, mc_val, mc_keys = sweep_mb(
-                mb.cache_idx.reshape(-1, zc), mb.cache_val
+                mb.cache_idx.reshape(-1, zc),
+                logical_rows(ecfg.mb, mb.cache_val),
             )
             recips = recips + live_keys(mc_keys, mc_idx)
             mb = mb._replace(
-                cache_idx=mc_idx.reshape(-1), cache_val=mc_val
+                cache_idx=mc_idx.reshape(-1),
+                cache_val=stored_rows(ecfg.mb, mc_val),
             )
     mb_stash_idx, mb_stash_val, stash_keys = sweep_mb(
         state.mb.stash_idx, state.mb.stash_val

@@ -34,13 +34,17 @@ is ``(p // 2048) * 128 + p % 128``**. So every 128-lane tile ``q`` of
 the stream is one whole state word (``q % 16``) of one group of 128
 blocks (``q // 16``): a ``[rows, 128]`` block computation yields sixteen
 whole lane tiles, and nothing is ever interleaved, concatenated off a
-tile boundary or relaid. A bucket row spends its stream on its ``Z*V``
-value words first and its ``Z`` slot-index words after them
+tile boundary or relaid. A bucket row spends its stream on its stored
+value row first (``OramConfig.stored_row_words``: the ``Z*V`` block
+words and, since PR 44, the zero words that bring a wide row to whole
+tiles) and its ``Z`` slot-index words after it
 (:func:`row_plane_keystreams`), so the wide value plane starts on
-tile 0. A fixed permutation of the same ChaCha output — PRF security
-does not depend on the order — and the at-rest format since checkpoint
-version 2 (version 1 took state word ``m // n_blocks`` of block
-``m % n_blocks``, index words first).
+tile 0 and, at a stored width, ends on a tile's last lane. A fixed
+permutation of the same ChaCha output — PRF security does not depend
+on the order — and the at-rest format since checkpoint version 2
+(version 1 took state word ``m // n_blocks`` of block
+``m % n_blocks``, index words first; version 3 is this order at the
+stored widths).
 
 What the compiler makes of the jnp path (described v5e, PERF.md §5,
 PR 40): it is NOT fused into one pass. XLA splits the rounds over

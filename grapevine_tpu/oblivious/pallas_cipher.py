@@ -17,9 +17,13 @@ is state word ``q % 16`` of the 128 blocks of group ``q // 16``, the
 value plane takes the head of the stream and the slot-index words the
 positions after it. So per block group the kernel computes sixteen
 ``[rows, 128]`` state arrays and stores each straight into its own lane
-tile of the value block; the index words come out of the tile the value
-plane ends in (lanes 64-67 of tile 47 for the 6,080-word mailbox row,
-lanes 0-3 of tile 8 for the 1,024-word records row). The ChaCha core
+tile of the value block; the index words come out of the tile after
+the value plane's last (lanes 0-3 of tile 48 for the mailbox row, 6,144
+words as it is stored since PR 44, and of tile 8 for the 1,024-word
+records row; a width off the tile boundary, 6,080 say, puts them on the
+lanes after its last value words). Plaintext handed over narrower than
+the stream's value plane is taken with zeros after it (``zv``): the
+kernel stores those words' keystream itself. The ChaCha core
 itself (quarter-round, constants, round schedule, feedforward) is
 bucket_cipher's ``chacha_words``, so the implementations cannot drift;
 bit-identical ciphertext is asserted by tests/test_pallas_cipher.py,
@@ -60,10 +64,13 @@ def _cipher_kernel(
     key_ref, bucket_ref, epoch_ref, idx_ref, val_ref, oidx_ref, oval_ref,
     *, sub, z, zv, rounds,
 ):
-    """One row block: (idx [TR, z], val [TR, zv]) ^= keystream rows,
-    ``sub`` rows at a time."""
+    """One row block: (idx [TR, z], val [TR, zin]) ^ keystream rows ->
+    (idx [TR, z], val [TR, zv]), ``sub`` rows at a time. ``zin <= zv``:
+    value words the input lacks are zeros, so their ciphertext is the
+    keystream itself."""
     key = [key_ref[i] for i in range(8)]
     lane = jax.lax.broadcasted_iota(U32, (sub, LANES), 1)
+    zin = val_ref.shape[1]
 
     def sub_tile(s, carry):
         rows = pl.ds(pl.multiple_of(s * sub, sub), sub)
@@ -78,10 +85,15 @@ def _cipher_kernel(
                 words = group_words(key, lane, n1, n2, n3, group, rounds)
             ks = jnp.where(written, words[word], U32(0))
             end = start + width
-            if start < zv:  # this tile's value words
-                cols = slice(start, min(end, zv))
+            if start < zin:  # this tile's value words
+                cols = slice(start, min(end, zin))
                 oval_ref[rows, cols] = (
                     val_ref[rows, cols] ^ ks[:, : cols.stop - start]
+                )
+            cols = slice(max(start, zin), min(end, zv))
+            if cols.start < cols.stop:  # and the pad words after them
+                oval_ref[rows, cols] = (
+                    ks[:, cols.start - start: cols.stop - start]
                 )
             if end > zv:  # and its slot-index words
                 lo = max(start, zv)
@@ -94,19 +106,26 @@ def _cipher_kernel(
     jax.lax.fori_loop(0, val_ref.shape[0] // sub, sub_tile, 0)
 
 
-@functools.partial(jax.jit, static_argnames=("rounds", "interpret"))
+@functools.partial(jax.jit, static_argnames=("rounds", "interpret", "zv"))
 def cipher_rows_pallas(
     key: jax.Array,  # u32[8]
     bucket: jax.Array,  # u32[R]
     epoch: jax.Array,  # u32[R, 2]; 0 = identity (never written)
     pidx: jax.Array,  # u32[R, z] slot-index words
-    pval: jax.Array,  # u32[R, zv] value words
+    pval: jax.Array,  # u32[R, zin] value words
     rounds: int = 8,
     interpret: bool = False,
+    zv: int | None = None,
 ):
-    """Fused ``row ^ keystream``; returns (pidx', pval'), both u32."""
+    """Fused ``row ^ keystream``; returns (pidx', pval'), both u32.
+
+    ``zv`` is the width of the value row as the stream lays it out and
+    as it is returned (``pval``'s own where None). Rows narrower than
+    that are plaintext without its zero pad: the kernel writes the pad
+    words' keystream itself, so no padded copy of the rows is made."""
     r, z = pidx.shape
-    zv = pval.shape[1]
+    zin = pval.shape[1]
+    zv = zin if zv is None else zv
     sub = _SUB_ROWS
     # the row tile is the second-minor block dim of every operand: a
     # multiple of the u32 sublane count, no larger than the rows need
@@ -124,7 +143,7 @@ def cipher_rows_pallas(
             row_block(1),
             row_block(2),
             row_block(z),
-            row_block(zv),
+            row_block(zin),
         ],
         out_specs=[row_block(z), row_block(zv)],
         out_shape=[

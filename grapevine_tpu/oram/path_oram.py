@@ -58,6 +58,7 @@ from ..config import on_tpu as _on_tpu
 import jax.numpy as jnp
 
 from ..oblivious.bucket_cipher import (  # noqa: F401  (row_keystream: leaf plane)
+    LANES,
     epoch_next,
     row_keystream,
     row_plane_keystreams,
@@ -80,6 +81,12 @@ MAX_U32_BLOCKS = 1 << 30
 #: B = 2048 and 2^21 messages (tests/test_rangelint.py holds the
 #: served geometries to it); 2^24 covers B = 2^16 on a 30-level tree.
 OVERFLOW_ROUND_BUDGET = 1 << 24
+
+#: a value row at least this many lane tiles wide is STORED on whole
+#: tiles (``OramConfig.stored_row_words``): the pad is then under an
+#: eighth of the row (the 6,080-word mailbox row pays 64 words, 1.05 %),
+#: where a toy row of a few words would be stored many times over
+PADDED_ROW_MIN_TILES = 8
 
 
 def RANGELINT_BOUNDS(cfg: "OramConfig", prefix: str = "state") -> dict:
@@ -136,21 +143,33 @@ def cipher_rows(
     buckets: jax.Array,  # u32[R] heap bucket ids
     epochs: jax.Array,  # u32[R, 2] per-row (lo, hi) nonce (0 = identity)
     pidx: jax.Array,  # u32[R, Z]
-    pval: jax.Array,  # u32[R, Z*V]
+    pval: jax.Array,  # u32[R, cfg.stored_row_words], or [R, Z*V] plaintext
 ):
     """XOR bucket rows with their keystream (encrypt ≡ decrypt).
 
-    One ChaCha stream per (bucket, epoch) covers the Z*V value words
-    followed by the Z slot-index words (the stream order of
-    oblivious/bucket_cipher.py) — a memory snapshot of the tree arrays
-    reveals neither slot occupancy nor contents.
+    One ChaCha stream per (bucket, epoch) covers the stored value row
+    (Z*V words and its pad) followed by the Z slot-index words (the
+    stream order of oblivious/bucket_cipher.py) — a memory snapshot of
+    the tree arrays reveals neither slot occupancy nor contents.
+
+    Only the Z*V block words of ``pval`` are read, at either width, and
+    the rows come back at the stored width with the pad words'
+    keystream after the block words: the pad is zeros in plaintext, so
+    on the way to the tree that is its ciphertext, and on the way from
+    it nothing anyone reads (the fetch cuts the rows to Z*V next). So
+    the write-back hands over its rows without a padded copy of them
+    (on a v5e an unfused pad of the mailbox pass is a 1.2 ms pass of
+    its own) and one kernel serves both directions (a second signature
+    of the mailbox kernel is a second of tracing at every start-up,
+    four beside the benchmark's signing workers: PERF.md §5, PR 44).
 
     ``cfg.cipher_impl == "pallas"`` routes through the fused Pallas
     kernel (keystream generated in VMEM and XORed in one pass — no HBM
     keystream materialization; oblivious/pallas_cipher.py). Both
     implementations produce bit-identical ciphertext."""
     if not cfg.encrypted:
-        return pidx, pval
+        return pidx, stored_rows(cfg, pval)
+    pval = logical_rows(cfg, pval)
     z = cfg.bucket_slots
     if cfg.cipher_impl in ("pallas", "pallas_fused"):
         from ..oblivious.pallas_cipher import cipher_rows_pallas
@@ -173,12 +192,12 @@ def cipher_rows(
             )
         return cipher_rows_pallas(
             key, buckets, epochs, pidx, pval, cfg.cipher_rounds,
-            interpret=interpret,
+            interpret=interpret, zv=cfg.stored_row_words,
         )
     ks_idx, ks_val = row_plane_keystreams(
         key, buckets, epochs, z, cfg.row_words, cfg.cipher_rounds
     )
-    return pidx ^ ks_idx, pval ^ ks_val
+    return pidx ^ ks_idx, stored_rows(cfg, pval) ^ ks_val
 
 
 @dataclasses.dataclass(frozen=True)
@@ -276,10 +295,29 @@ class OramConfig:
         return (1 << self.top_cache_levels) - 1
 
     @property
+    def val_row_words(self) -> int:
+        """Z*V: the value words of a bucket's Z blocks, the logical row."""
+        return self.bucket_slots * self.value_words
+
+    @property
+    def stored_row_words(self) -> int:
+        """Width of a ``tree_val`` / ``cache_val`` row as it is stored:
+        Z*V rounded up to whole 128-word lane tiles where the row is at
+        least ``PADDED_ROW_MIN_TILES`` of them wide, Z*V itself under
+        that. A function of the row's width alone. The words past Z*V
+        are zeros in plaintext and are enciphered with the row; why a
+        tile-clean row matters is the layout note on ``OramState``."""
+        zv = self.val_row_words
+        if zv < PADDED_ROW_MIN_TILES * LANES:
+            return zv
+        return -(-zv // LANES) * LANES
+
+    @property
     def row_words(self) -> int:
-        """Keystream width per bucket: Z slot-index words + Z*V value
-        words, enciphered as one row under one (bucket, epoch) nonce."""
-        return self.bucket_slots + self.bucket_slots * self.value_words
+        """Keystream width per bucket: the stored value row and the Z
+        slot-index words after it, enciphered as one row under one
+        (bucket, epoch) nonce."""
+        return self.bucket_slots + self.stored_row_words
 
     @property
     def leaves(self) -> int:
@@ -356,21 +394,42 @@ class OramState(NamedTuple):
     slices dominate the round; a fully packed ``[n, Z*(2+V)]`` row
     (1028 words) is not lane-aligned, padding every row to 1152 words
     and again relayout-copying the tree. The split below keeps the
-    value rows exactly ``Z*V`` words and the slot metadata 1-D, which
-    XLA never transposes. Tile-clean rows matter still: the records
-    tree's 1024 words are, the mailbox tree's 6080 (Z=4 x 1520) are
-    47.5 lanes of 128, so a v5e's DEFAULT layout for that plane is the
-    transposed ``{0,1}`` and each round copies it whole after its entry
-    and before its exit (``copy.1913``; 2 x 2.4 ms at 2^16 recipients,
-    my chip run, PR 28). Pinning ``{1,0}`` through jit's in/out
-    ``Format`` removes both copies on a cold compile, but an executable
-    that jax 0.9.0 loads from its persistent cache returns default
-    layouts again, so the pin cannot ship; what can is a row padded to
-    a multiple of 128 words (ROADMAP Speed 15).
+    slot metadata 1-D, which XLA never transposes, and the value rows
+    ``cfg.stored_row_words`` wide: ``Z*V`` words on whole 128-word lane
+    tiles. The records tree's 1,024 words are that as they are; the
+    mailbox tree's 6,080 (Z=4 x 1,520) are 47.5 tiles and are stored as
+    6,144, the 64 pad words zeros in plaintext.
+
+    What the padded row cured (my chip runs, PR 44, TPU v5 lite, 2^21
+    messages / 2^17 recipients): a v5e's DEFAULT layout for
+    ``u32[n,6080]`` is the transposed ``{0,1}``, the row gathers and
+    scatters want ``{1,0}``, so each round copied the 1.59 GB plane
+    whole after its entry and before its exit (4.90 + 4.76 ms of a
+    110.27 ms round; at 2^16 recipients 2.45 + 2.36 of 80.57). Stored
+    6,144 wide the plane enters and leaves ``{1,0}`` and is never
+    copied: ``device_busy_ms`` 110.27 -> 100.24, ``scope_ms.unscoped``
+    5.02 -> 0.12 (tests/test_mosaic_lowering.py holds the compiled
+    round to it). Pinning ``{1,0}`` through jit's in/out ``Format`` had
+    done the same on a cold compile (PR 28), but an executable that jax
+    0.9.0 loads from its persistent cache returns default layouts
+    again, so the pin could not ship. The cut back to ``Z*V`` after the
+    decrypt is a bitcast (a tiled 6,080-word row IS 48 tiles), and the
+    pad before the encrypt is the cipher kernel's own (``cipher_rows``).
+
+    What it did not cure: the two mailbox ``path_scatter`` fusions
+    still take 9.0 ms each, a read and a write of the whole plane at
+    ~360 GB/s. That is the compiler's choice and the better one: where
+    a scatter's rows are an eighth of the operand's or more (20,464 of
+    65,536 here; the described-chip compile switches between 12.5 and
+    18.75 %) it sorts the indices, permutes the updates (2.2 ms) and
+    streams the operand; made to scatter by rows instead, in chunks
+    under that share, the same write-back took 13.7 ms against 11.2
+    (0.67 us a 24.6 KB row, where the records tree's 4 KiB rows go at
+    0.16). ROADMAP Speed 7 (i) has what is left.
     """
 
     tree_idx: jax.Array  # u32[n_buckets * Z] flat; SENTINEL = empty slot
-    tree_val: jax.Array  # u32[n_buckets, Z*V]; one row per bucket
+    tree_val: jax.Array  # u32[n_buckets, stored_row_words]; a row a bucket
     #: tree-top cache planes (cfg.top_cache_levels = k > 0; zero-length
     #: otherwise): the decrypted-resident image of heap buckets
     #: [0, 2^k−1) — the authoritative copy; those buckets' HBM tree rows
@@ -382,7 +441,7 @@ class OramState(NamedTuple):
     #: Sealed checkpoints cover it like any other leaf (engine/
     #: checkpoint.py serializes the whole pytree).
     cache_idx: jax.Array  # u32[cache_buckets * Z] (or u32[0])
-    cache_val: jax.Array  # u32[cache_buckets, Z*V] (or u32[0, Z*V])
+    cache_val: jax.Array  # u32[cache_buckets, stored_row_words] (or [0, ·])
     #: cache mirror of tree_leaf (recursive posmap only; u32[0] else)
     cache_leaf: jax.Array
     #: per-slot leaf assignment plane, recursive posmap only (u32[0]
@@ -434,9 +493,9 @@ def init_oram(cfg: OramConfig, key: jax.Array) -> OramState:
     n_cleaf = cb * z if cfg.posmap is not None else 0
     return OramState(
         tree_idx=jnp.full((cfg.n_buckets_padded * z,), SENTINEL, U32),
-        tree_val=jnp.zeros((cfg.n_buckets_padded, z * v), U32),
+        tree_val=jnp.zeros((cfg.n_buckets_padded, cfg.stored_row_words), U32),
         cache_idx=jnp.full((cb * z,), SENTINEL, U32),
-        cache_val=jnp.zeros((cb, z * v), U32),
+        cache_val=jnp.zeros((cb, cfg.stored_row_words), U32),
         cache_leaf=jnp.zeros((n_cleaf,), U32),
         tree_leaf=jnp.zeros((n_leaf,), U32),
         stash_idx=jnp.full((cfg.stash_size,), SENTINEL, U32),
@@ -555,6 +614,22 @@ def _path_scatter(
         return tree.at[tgt].set(new_vals, mode="drop", unique_indices=True)
 
 
+def logical_rows(cfg: OramConfig, rows: jax.Array) -> jax.Array:
+    """Stored value rows ``[R, stored_row_words]`` cut to their ``Z*V``
+    block words (the rows themselves where they are that wide already:
+    a row without a pad adds no op to the program)."""
+    zv = cfg.val_row_words
+    return rows if rows.shape[1] == zv else rows[:, :zv]
+
+
+def stored_rows(cfg: OramConfig, rows: jax.Array) -> jax.Array:
+    """Plaintext value rows ``[R, Z*V]`` brought to the stored width:
+    zeros past the blocks (``logical_rows``' inverse; rows already that
+    wide are returned as they are)."""
+    pad = cfg.stored_row_words - rows.shape[1]
+    return jnp.pad(rows, ((0, 0), (0, pad))) if pad else rows
+
+
 def path_slot_indices(cfg: OramConfig, path_b: jax.Array) -> jax.Array:
     """Flat tree_idx slot indices for path buckets: [...,] → [..., Z]."""
     z = cfg.bucket_slots
@@ -662,7 +737,7 @@ def oram_access(
                 )
             pleaf = pleaf.reshape(-1)
     pidx = pidx.reshape(-1)
-    pval = pval.reshape(-1, v)
+    pval = logical_rows(cfg, pval).reshape(-1, v)
     widx = jnp.concatenate([state.stash_idx, pidx])
     wval = jnp.concatenate([state.stash_val, pval], axis=0)
     if recursive:
@@ -752,13 +827,14 @@ def oram_access(
     # --- write the path back (write transcript ≡ read transcript) ------
     with device_phase("oram_writeback"):
         epochs_w = jnp.broadcast_to(state.epoch[None, :], (plen - kc, 2))
+        new_rows = new_pval.reshape(plen, z * v)
         enc_pidx, enc_pval = cipher_rows(
             cfg,
             state.cipher_key,
             bot_b,
             epochs_w,
             new_pidx.reshape(plen, z)[kc:],
-            new_pval.reshape(plen, z * v)[kc:],
+            new_rows[kc:],
         )
         nonces = (
             _path_scatter(state.nonces, bot_b, epochs_w, axis_name)
@@ -772,7 +848,7 @@ def oram_access(
                 new_pidx[: kc * z], unique_indices=True
             )
             cache_val = state.cache_val.at[top_b].set(
-                new_pval.reshape(plen, z * v)[:kc], unique_indices=True
+                stored_rows(cfg, new_rows[:kc]), unique_indices=True
             )
         else:
             cache_idx, cache_val = state.cache_idx, state.cache_val
@@ -861,9 +937,9 @@ def tree_cache_private_bytes(cfg: OramConfig) -> int:
     (sizing helper for OPERATIONS.md §14 and bench.py tree_cache_ab):
     2^k−1 bucket rows of idx + val (+ leaf-metadata under a recursive
     posmap), all plaintext private state with the stash's standing."""
-    z, v = cfg.bucket_slots, cfg.value_words
+    z = cfg.bucket_slots
     leaf = z if cfg.posmap is not None else 0
-    return cfg.cache_buckets * 4 * (z + z * v + leaf)
+    return cfg.cache_buckets * 4 * (z + cfg.stored_row_words + leaf)
 
 
 def stash_occupancy(state: OramState) -> jax.Array:

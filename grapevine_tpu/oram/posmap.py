@@ -201,7 +201,7 @@ def init_posmap(cfg, key: jax.Array):
     HBM (epoch-0 plaintext would hand a snapshot the initial map)."""
     if cfg.posmap is None:
         return _flat_table(cfg, key)
-    from .path_oram import cipher_rows, init_oram
+    from .path_oram import cipher_rows, init_oram, stored_rows
 
     spec = cfg.posmap
     icfg = inner_oram_config(spec)
@@ -228,7 +228,9 @@ def init_posmap(cfg, key: jax.Array):
         .at[flat_slot]
         .set(vals[perm], unique_indices=True)
     )
-    tree_val = val_slots.reshape(icfg.n_buckets_padded, z * k)
+    tree_val = stored_rows(
+        icfg, val_slots.reshape(icfg.n_buckets_padded, z * k)
+    )
     pm = inner.posmap.at[perm].set(leaf_of_slot)
 
     nonces, epoch = inner.nonces, inner.epoch
@@ -528,7 +530,7 @@ def read_table(cfg, pm_state):
         tval = tval ^ np.asarray(ks_val)
     out = np.zeros((cfg.blocks,), np.uint32)
     seen = np.zeros((spec.inner_blocks,), bool)
-    rows = tval.reshape(-1, k)
+    rows = tval[:, : icfg.val_row_words].reshape(-1, k)
     flat_idx = tidx.reshape(-1)
     live = flat_idx != int(SENTINEL)
     # tree-top cache: cached buckets' HBM rows are stale (decrypt to
@@ -537,7 +539,8 @@ def read_table(cfg, pm_state):
     ncache = int(np.asarray(inner.cache_idx).size)
     if ncache:
         live[:ncache] = False
-        crows = np.asarray(inner.cache_val).reshape(-1, k)
+        crows = np.asarray(inner.cache_val)[:, : icfg.val_row_words].reshape(
+            -1, k)
         cidx = np.asarray(inner.cache_idx)
         for slot in np.nonzero(cidx != int(SENTINEL))[0]:
             blk = int(cidx[slot])

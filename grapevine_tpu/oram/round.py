@@ -75,7 +75,9 @@ from .path_oram import (
     _path_gather,
     _path_scatter,
     cipher_rows,
+    logical_rows,
     path_bucket_indices,
+    stored_rows,
     working_leaves,
 )
 
@@ -476,14 +478,14 @@ def oram_round(
                 [jnp.ones((nc,), jnp.bool_), bmap[nc:cb] != U32(nrows)]
             ), z)
             cidx = jnp.where(cache_met, state.cache_idx, SENTINEL)
-            cval = state.cache_val.reshape(cb * z, v)
+            cval = logical_rows(cfg, state.cache_val).reshape(cb * z, v)
         w = s + nslots + b  # + b reserved rows for net inserts
         widx0 = jnp.concatenate(
             [state.stash_idx, cidx, pidx.reshape(-1),
              jnp.full((b,), SENTINEL, U32)]
         )
         wval0 = jnp.concatenate(
-            [state.stash_val, cval, pval.reshape(-1, v),
+            [state.stash_val, cval, logical_rows(cfg, pval).reshape(-1, v),
              jnp.zeros((b, v), U32)], axis=0
         )
 
@@ -611,7 +613,7 @@ def oram_round(
             tree_idx_new, tree_val_new, nonces = scatter_encrypt_rows(
                 state.cipher_key, state.tree_idx, state.tree_val, state.nonces,
                 bot_b, owner_bot, state.epoch,
-                bot_pidx, bot_pval,
+                bot_pidx, stored_rows(cfg, bot_pval),
                 z=z, rounds=cfg.cipher_rounds,
                 interpret=not _on_tpu(),
             )
@@ -648,9 +650,9 @@ def oram_round(
             cache_idx_new = jnp.where(
                 cache_met, new_pidx[: cb * z], state.cache_idx
             )
-            cache_val_new = jnp.where(
+            cache_val_new = stored_rows(cfg, jnp.where(
                 cache_met[:, None], new_pval[: cb * z], cval
-            ).reshape(cb, z * v)
+            ).reshape(cb, z * v))
         if recursive:
             from .path_oram import leaf_plane_cipher
 

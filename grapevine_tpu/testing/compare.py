@@ -53,8 +53,10 @@ def logical_tree_planes(cfg, oram):
     """Decrypted logical content of one ORAM's bucket tree, with the
     tree-top cache overlaid (host-side; never on the round path).
 
-    Returns ``(idx [n, Z], val [n, Z*V], leaf [n, Z] | None)`` plaintext
-    planes. Under ``cfg.top_cache_levels = k > 0`` the top 2^k−1
+    Returns ``(idx [n, Z], val [n, stored_row_words], leaf [n, Z] |
+    None)`` plaintext planes (the value rows as stored: the blocks' Z*V
+    words and the zero pad after them).
+    Under ``cfg.top_cache_levels = k > 0`` the top 2^k−1
     buckets' HBM rows are stale (empty-at-init ciphertext, re-keyed but
     never read) and the authoritative plaintext lives in the cache
     planes — so rows [0, 2^k−1) are taken from the cache. This is the
@@ -109,7 +111,7 @@ def logical_block_map(cfg, oram) -> dict:
     z, v = cfg.bucket_slots, cfg.value_words
     idx, val, _leaf = logical_tree_planes(cfg, oram)
     out: dict = {}
-    rows = val.reshape(-1, v)
+    rows = val[:, : cfg.val_row_words].reshape(-1, v)
     flat = idx.reshape(-1)
     for slot in np.nonzero(flat != int(SENTINEL))[0]:
         out[int(flat[slot])] = rows[slot].tobytes()

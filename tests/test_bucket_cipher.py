@@ -73,14 +73,17 @@ def test_row_keystream_roundtrip_and_epoch0_identity():
 @pytest.mark.parametrize(
     "n_words",
     [
-        4 + 6080,  # the mailbox row: three block groups, a 64-lane last tile
+        4 + 6144,  # the mailbox row as stored (PR 44): three block groups,
+        # 48 whole value tiles, the index words on lanes 0-3 of tile 48
+        4 + 6080,  # its block words alone: a 64-lane last value tile
         4 + 1024,  # the records row: one group, eight tiles and four lanes
         4 + 380,   # the row the first chip window refused
         4 + 96,    # a row under one lane tile
     ],
 )
 def test_stream_order_is_the_stated_rule(n_words):
-    """The at-rest format (checkpoint version 2), stated against
+    """The at-rest format (the order of checkpoint version 2, at the
+    row widths of version 3), stated against
     ``chacha_blocks`` itself: stream position p is state word
     (p // 128) % 16 of the block whose counter is
     (p // 2048) * 128 + p % 128; a bucket row's value words take the

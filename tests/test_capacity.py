@@ -111,6 +111,16 @@ def _held_to_its_file(config_name: str):
     return spec, cfg, ecfg, state, nbytes
 
 
+def _mailbox_pad_bytes(ecfg) -> int:
+    """What PR 44's whole-tile mailbox row adds to the state: 64 pad
+    words a bucket row (6,080 stored as 6,144) of the tree plane and of
+    the tree-top cache plane. The records row, 1,024 words, has none."""
+    mb, rec = ecfg.mb, ecfg.rec
+    assert rec.stored_row_words == rec.val_row_words == 1024
+    assert (mb.val_row_words, mb.stored_row_words) == (6080, 6144)
+    return 4 * 64 * (mb.n_buckets_padded + mb.cache_buckets)
+
+
 def test_the_2p16_recipient_deployment_resolves_to_what_its_file_says():
     """``benchmarks/configs/chipshare-2p20-r2p16.json`` (the bus whose
     mailboxes can fill its message store) states what its
@@ -128,12 +138,17 @@ def test_the_2p16_recipient_deployment_resolves_to_what_its_file_says():
     assert mb.n_buckets == want["buckets"] == 32767
     assert ecfg.mb_table_buckets == 1 << 15 and mb.leaves == 1 << 14
     assert want["accesses_per_pass"] == 4096 < mb.leaves
-    # index row, value row and nonce of one bucket at rest
-    assert 4 * (mb.row_words + 2) == want["bucket_bytes"] == 24344
+    # index row, value row and nonce of one bucket: the block words the
+    # file counts, and the row at rest, stored on whole lane tiles
+    # (the file's figure predates PR 44; the file is the benchmark's)
+    assert 4 * (4 + mb.val_row_words + 2) == want["bucket_bytes"] == 24344
+    assert 4 * (mb.row_words + 2) == 24600
     # the file's figure predates PR 30, which took four u32 scalars of
-    # delayed-eviction book-keeping (two a tree) out of the state; the
-    # file is the benchmark's, for a `benchmark` PR to bring up to date
-    assert state_bytes == said["state_bytes"] - 16 == 5_127_466_608
+    # delayed-eviction book-keeping (two a tree) out of the state, and
+    # PR 44, which added the mailbox row's pad; the file is the
+    # benchmark's, for a `benchmark` PR to bring up to date
+    assert _mailbox_pad_bytes(ecfg) == 8_392_448
+    assert state_bytes == said["state_bytes"] - 16 + 8_392_448 == 5_135_859_056
     assert cfg.mailbox_cap == spec["guarantees"]["mailbox_cap"] == 62
     assert spec["guarantees"]["max_recipients"] == cfg.max_recipients
     # its parent's mailbox tree is one the batch covers whole
@@ -165,12 +180,20 @@ def test_the_chips_real_share_resolves_to_what_its_file_says():
     assert mb.n_buckets == said["mailbox"]["buckets"] == 65535
     assert ecfg.mb_table_buckets == 1 << 16 and mb.leaves == 1 << 15
     assert said["mailbox"]["accesses_per_pass"] == 4096 < mb.leaves
-    assert 4 * (mb.row_words + 2) == said["mailbox"]["bucket_bytes"] == 24344
+    # the block words the file counts, and the row at rest (the file's
+    # figure predates PR 44; the file is the benchmark's)
+    assert 4 * (4 + mb.val_row_words + 2) == (
+        said["mailbox"]["bucket_bytes"]) == 24344
+    assert 4 * (mb.row_words + 2) == 24600
     # the first buffer of the program that holds 2^31 elements
     assert state.rec.tree_val.shape == (1 << 21, 1024)
     assert state.rec.tree_val.size == 1 << 31
-    assert state.mb.tree_val.shape == (1 << 16, 6080)
-    assert state_bytes == said["state_bytes"] == 10_253_823_600
+    # 6,080 block words stored as 48 whole lane tiles (PR 44)
+    assert state.mb.tree_val.shape == (1 << 16, 6144)
+    assert state.mb.cache_val.shape == (15, 6144)
+    # the file's figure predates PR 44; the file is the benchmark's
+    assert _mailbox_pad_bytes(ecfg) == 16_781_056
+    assert state_bytes == said["state_bytes"] + 16_781_056 == 10_270_604_656
     g = spec["guarantees"]
     assert cfg.mailbox_cap == g["mailbox_cap"] == 62
     assert (g["max_messages"], g["max_recipients"]) == (
@@ -206,7 +229,10 @@ def test_the_durable_deployment_resolves_to_what_its_file_says(tmp_path):
     said = spec["resolves_to"]
     for tree in ("records", "mailbox"):
         assert said[tree] == parent["resolves_to"][tree]
-    assert state_bytes == said["state_bytes"] == 10_253_823_600
+    # the file's figures predate PR 44's mailbox pad; the file is the
+    # benchmark's, for a `benchmark` PR to bring up to date
+    pad = _mailbox_pad_bytes(ecfg)
+    assert state_bytes == said["state_bytes"] + pad == 10_270_604_656
     for k, v in parent["guarantees"].items():
         assert spec["guarantees"][k] == v
     assert "fsynced before it dispatches" in spec["guarantees"]["durability"]
@@ -233,7 +259,7 @@ def test_the_durable_deployment_resolves_to_what_its_file_says(tmp_path):
     # the checkpoint: head, nonce, seq, manifest length, manifest, every
     # leaf, tag
     manifest = cp._manifest(ecfg, state_spec(ecfg)[1])
-    assert said["checkpoint_bytes"] == (
+    assert said["checkpoint_bytes"] + pad == (
         len(cp.MAGIC) + 4 + 12 + 8 + 4 + len(manifest) + state_bytes + 32)
     assert said["checkpoint_bytes"] == 10_253_824_277
 

@@ -101,20 +101,53 @@ def test_checkpoint_write_load(tmp_path, ecfg, state):
     assert cp.state_to_bytes(ecfg, state2) == cp.state_to_bytes(ecfg, state)
 
 
-def test_version_1_payload_is_refused(tmp_path, ecfg, state, monkeypatch):
-    """Version 2 is the tile-aligned keystream order (PR 40): a tree
-    sealed under version 1 would decrypt to noise, so both checks — the
-    sealed file's header and the state payload's manifest — refuse it
-    and say which version they met."""
-    assert cp.VERSION == 2
-    monkeypatch.setattr(cp, "VERSION", 1)
+@pytest.mark.parametrize("old", [1, 2])
+def test_an_older_version_payload_is_refused(
+    tmp_path, ecfg, state, monkeypatch, old
+):
+    """Version 3 stores wide value rows on whole lane tiles (PR 44),
+    version 2 was the tile-aligned keystream order (PR 40): a tree
+    sealed under an older version would decrypt to noise, so both
+    checks — the sealed file's header and the state payload's manifest
+    — refuse it and say which version they met."""
+    assert cp.VERSION == 3
+    monkeypatch.setattr(cp, "VERSION", old)
     old_payload = cp.state_to_bytes(ecfg, state)
     old_file = cp.write_checkpoint(str(tmp_path), ROOT, ecfg, state, seq=3)
     monkeypatch.undo()
-    with pytest.raises(cp.CheckpointError, match="version 1, want 2"):
+    with pytest.raises(cp.CheckpointError, match=f"version {old}, want 3"):
         cp.bytes_to_state(ecfg, old_payload)
-    with pytest.raises(cp.CheckpointError, match="version 1, want 2"):
+    with pytest.raises(cp.CheckpointError, match=f"version {old}, want 3"):
         cp.load_checkpoint(old_file, ROOT, ecfg)
+
+
+def test_version_3_round_trips_the_padded_mailbox_row(tmp_path):
+    """The at-rest format of PR 44: a mailbox row of 6,080 block words
+    (cap 62) is stored, sealed and loaded 6,144 wide, ciphertext and
+    pad keystream alike, under a header that says version 3."""
+    import dataclasses
+    import struct
+
+    import jax
+
+    from grapevine_tpu.engine.round_step import engine_round_step
+
+    ecfg = EngineConfig.from_config(dataclasses.replace(
+        SMALL, mailbox_cap=62, bucket_cipher_rounds=8))
+    assert ecfg.mb.stored_row_words == 6144 == ecfg.mb.val_row_words + 64
+    state, _, _ = jax.jit(engine_round_step, static_argnums=(0,))(
+        ecfg, init_engine(ecfg, seed=5), _round_batch(ecfg, 7)[0])
+    assert state.mb.tree_val.shape == (ecfg.mb.n_buckets_padded, 6144)
+    # a written row's pad words are keystream at rest, not zeros
+    written = np.asarray(state.mb.nonces).any(axis=1)
+    assert np.asarray(state.mb.tree_val)[written][:, 6080:].all(axis=1).any()
+    path = cp.write_checkpoint(str(tmp_path), ROOT, ecfg, state, seq=9)
+    with open(path, "rb") as f:
+        assert f.read(12) == cp.MAGIC + struct.pack("<I", 3)
+    seq, state2 = cp.load_checkpoint(path, ROOT, ecfg)
+    assert seq == 9
+    assert state2.mb.tree_val.shape == state.mb.tree_val.shape
+    assert cp.state_to_bytes(ecfg, state2) == cp.state_to_bytes(ecfg, state)
 
 
 def test_checkpoint_geometry_fingerprint_rejected(tmp_path, ecfg, state):

@@ -80,8 +80,8 @@ def _s(*shape, dtype=U32):
 
 
 def _served_tree(tree):
-    """(padded buckets, fetched rows per round, Z, value words per row)
-    of one tree of the geometry chip_smoke.py serves: 2^20 messages,
+    """(padded buckets, fetched rows per round, Z, stored value words
+    per row) of one tree of the geometry chip_smoke.py serves: 2^20 messages,
     2^12 recipients, B=2048, density 2, the TPU's knob defaults."""
     from grapevine_tpu.config import GrapevineConfig
     from grapevine_tpu.engine.state import EngineConfig
@@ -97,7 +97,7 @@ def _served_tree(tree):
     )
     rows = fetches * (oc.path_len - oc.top_cache_levels)
     return (oc.n_buckets_padded, rows, oc.bucket_slots,
-            oc.bucket_slots * oc.value_words)
+            oc.stored_row_words)
 
 
 @pytest.mark.parametrize("tree", ["records", "mailbox"])
@@ -110,17 +110,36 @@ def test_cipher_kernel_compiles_for_v5e(one_chip, tree):
     assert "tpu_custom_call" in text
 
 
-@pytest.mark.parametrize("zv", [6080, 1024])
+@pytest.mark.parametrize("zv", [6144, 6080, 1024])
 def test_cipher_kernel_compiles_at_the_2p17_mailbox_shape(one_chip, zv):
     """20,464 fetched rows (a mailbox pass at 2^21 messages / 2^17
     recipients, no multiple of the 64-row tile: the last grid step is a
-    partial block) of 4 + 6080 words, three block groups with the index
-    words on lanes 64-67 of the last tile, and of 4 + 1024."""
+    partial block) of 4 + 6144 words, the row as it is stored since
+    PR 44 (48 whole value tiles, the index words on lanes 0-3 of tile
+    48); of 4 + 6080, a width the kernel must still compile (three block
+    groups with the index words on lanes 64-67 of the last tile); and
+    of 4 + 1024."""
     text = _compile_for(
         one_chip, cipher_rows_pallas, _s(8), _s(20464), _s(20464, 2),
         _s(20464, 4), _s(20464, zv), rounds=8, interpret=False,
     )
     assert "tpu_custom_call" in text
+
+
+def test_cipher_kernel_pads_the_mailbox_plaintext_itself(one_chip):
+    """The write-back's call: 6,080-word plaintext rows in, 6,144-word
+    stored rows out, the 64 pad words' keystream stored beside the last
+    value words, and no padded copy of the rows made around the
+    kernel."""
+    import re
+
+    text = _compile_for(
+        one_chip, cipher_rows_pallas, _s(8), _s(20464), _s(20464, 2),
+        _s(20464, 4), _s(20464, 6080), rounds=8, interpret=False, zv=6144,
+    )
+    assert "tpu_custom_call" in text
+    assert "u32[20464,6144]" in text
+    assert not re.search(r" pad\(", text)
 
 
 def test_cipher_rows_on_a_tpu_is_one_kernel_and_no_keystream_buffer(
@@ -132,7 +151,8 @@ def test_cipher_rows_on_a_tpu_is_one_kernel_and_no_keystream_buffer(
     no ``[R,6084]`` masked copy. (At the jit boundary of this lone call
     the compiler transposes the value plane in and out, as it does for
     any ``u32[n,6080]`` parameter; inside the round the row gathers
-    hand the kernel its ``{1,0}`` operand: PERF.md §5.)"""
+    hand the kernel its ``{1,0}`` operand: PERF.md §5. Since PR 44 the
+    plane is stored 6,144 words wide and is never transposed.)"""
     import re
 
     from grapevine_tpu.config import GrapevineConfig
@@ -145,16 +165,20 @@ def test_cipher_rows_on_a_tpu_is_one_kernel_and_no_keystream_buffer(
         tree_density=2,
     ))
     assert ecfg.mb.cipher_impl == "pallas" and ecfg.rec.cipher_impl == "pallas"
-    z, zv = ecfg.mb.bucket_slots, ecfg.mb.bucket_slots * ecfg.mb.value_words
-    assert (z, zv) == (4, 6080)
-    text = _compile_for(
-        one_chip, functools.partial(cipher_rows, ecfg.mb), _s(8), _s(20464),
-        _s(20464, 2), _s(20464, z), _s(20464, zv),
-    )
-    assert "tpu_custom_call" in text
-    assert not re.search(r"u32\[\d+,381[,\]]", text)
-    assert not re.search(r"u32\[\d+,60(?:96|84)\]", text)
-    assert " fusion(" not in text
+    mb = ecfg.mb
+    z = mb.bucket_slots
+    assert (z, mb.val_row_words, mb.stored_row_words) == (4, 6080, 6144)
+    # the fetch hands over stored rows, the write-back plaintext blocks
+    for zv in (mb.stored_row_words, mb.val_row_words):
+        text = _compile_for(
+            one_chip, functools.partial(cipher_rows, mb), _s(8), _s(20464),
+            _s(20464, 2), _s(20464, z), _s(20464, zv),
+        )
+        assert "tpu_custom_call" in text
+        assert "u32[20464,6144]" in text
+        assert not re.search(r"u32\[\d+,381[,\]]", text)
+        assert not re.search(r"u32\[\d+,6(?:0(?:96|84)|1(?:60|48))\]", text)
+        assert " fusion(" not in text and " pad(" not in text
 
 
 def test_cipher_kernel_compiles_at_the_row_count_the_chip_refused(one_chip):
@@ -286,3 +310,130 @@ def test_engine_round_with_fused_kernels_compiles_for_v5e(
         .lower(place(state), place(batch)).compile().as_text()
     )
     assert text.count("tpu_custom_call") >= 3
+
+
+# ----------------------------------------------------------------------
+# the round at the real mailbox row (PR 44): 6,080 block words stored
+# as 6,144, 48 whole lane tiles. What the chip's compiler makes of the
+# value plane is the point: for ``u32[n,6080]`` its default layout is
+# the transposed ``{0,1}``, so the round copied the plane whole after
+# its entry and before its exit (4.7 + 4.6 ms of a 110.3 ms round at
+# 2^21 / 2^17, ledger PR 43). One compile, read by four cases.
+# ----------------------------------------------------------------------
+
+
+@pytest.fixture(scope="module")
+def wide_row_round(one_chip):
+    """``(compiled text, ecfg)`` of ``jit(engine_round_step)``, state
+    donated, as a TPU engine resolves it (the Pallas cipher) at B = 2048
+    and the real mailbox width, on a mailbox tree of 14 levels: the
+    least that keeps a per-path level under the 13 dense ones (2^15
+    recipients; the plane is ``u32[16384,6144]``, 0.4 GB, described and
+    never allocated)."""
+    from grapevine_tpu.config import GrapevineConfig
+    from grapevine_tpu.engine.round_step import engine_round_step
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(jax, "default_backend", lambda: "tpu")
+        ecfg, state, batch = _round_specs(GrapevineConfig(
+            max_messages=1 << 16, max_recipients=1 << 15, batch_size=2048,
+            tree_density=2,
+        ))
+        assert ecfg.mb.cipher_impl == "pallas"
+        assert ecfg.mb.perpath_bucket_rows(4096) == 4096
+        place = lambda t: jax.tree.map(  # noqa: E731
+            lambda s: jax.ShapeDtypeStruct(
+                s.shape, s.dtype, sharding=one_chip),
+            t,
+        )
+        text = (
+            jax.jit(functools.partial(engine_round_step, ecfg),
+                    donate_argnums=(0,))
+            .lower(place(state), place(batch)).compile().as_text()
+        )
+    return text, ecfg
+
+
+def _entry_ops(text):
+    """``(name, result shape with layout, opcode, rest of the line)`` of
+    every instruction of the module's ENTRY computation."""
+    import re
+
+    body = re.search(r"\nENTRY [^\n]*\n(.*?)\n\}", text, re.S).group(1)
+    op = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = (\S+) ([\w\-]+)\((.*)$")
+    return [m.groups() for m in map(op.match, body.split("\n")) if m]
+
+
+def test_the_mailbox_plane_enters_and_leaves_the_round_row_major(
+    wide_row_round
+):
+    """The parameter ``state.mb.tree_val`` and its result are laid out
+    ``{1,0}``: the rows the gathers and scatters want, no transposed
+    default to copy out of and back into."""
+    import re
+
+    text, ecfg = wide_row_round
+    n, sw = ecfg.mb.n_buckets_padded, ecfg.mb.stored_row_words
+    assert (n, sw) == (1 << 14, 6144)
+    head = text.split("\n", 1)[0]
+    layouts = re.findall(rf"u32\[{n},{sw}\]\{{([\d,]+)", head)
+    assert layouts == ["1,0", "1,0"]  # parameter and result
+    assert f"u32[{n},{ecfg.mb.val_row_words}]" not in text
+
+
+def test_no_op_of_the_rounds_copies_or_relays_the_mailbox_plane(
+    wide_row_round
+):
+    text, ecfg = wide_row_round
+    plane = f"u32[{ecfg.mb.n_buckets_padded},{ecfg.mb.stored_row_words}]"
+    touching = [
+        (name, opcode) for name, shape, opcode, rest in _entry_ops(text)
+        if plane in shape or plane in rest.split(", metadata=")[0]
+    ]
+    assert touching  # the gathers and the two scatters are there
+    assert not [
+        t for t in touching
+        if t[1] in ("copy", "transpose", "reshape", "copy-start", "pad")
+    ]
+
+
+def test_both_mailbox_scatters_write_into_their_operand(wide_row_round):
+    """Each ``path_scatter`` of the value plane is a fusion whose output
+    is aliased onto operand 0 (the plane it was handed: the parameter
+    itself in round A, round A's result in round C), so a write-back
+    touches the rows it writes and not the plane."""
+    import re
+
+    text, ecfg = wide_row_round
+    plane = f"u32[{ecfg.mb.n_buckets_padded},{ecfg.mb.stored_row_words}]"
+    scatters = [
+        (name, rest) for name, shape, opcode, rest in _entry_ops(text)
+        if shape.startswith(plane) and opcode == "fusion"
+        and "path_scatter/scatter" in rest
+    ]
+    assert len(scatters) == 2
+    for _, rest in scatters:
+        assert re.search(
+            r'"aliasing_operands":\{"lists":\[\{"indices":\["0",', rest)
+    first, second = (rest.split(",")[0] for _, rest in scatters)
+    assert "state_mb_tree_val" in first
+    assert second.lstrip("%") == scatters[0][0]
+
+
+def test_the_stored_row_adds_no_pass_over_the_fetched_rows(wide_row_round):
+    """The cut after the decrypt is a bitcast (a ``[R,6080]`` row is 48
+    tiles already) and the pad before the encrypt is the kernel's own
+    (test_cipher_kernel_pads_the_mailbox_plaintext_itself): no ``pad``,
+    ``slice`` or ``copy`` of the fetched rows' size is in the round. An
+    unfused one is a 1.2 ms pass at 2^17 recipients, four a round."""
+    text, ecfg = wide_row_round
+    rows = ecfg.mb.fetched_bucket_rows(4096)
+    assert rows == (1 << 13) - (1 << 4) + 4096
+    wide = (f"u32[{rows},{ecfg.mb.stored_row_words}]",
+            f"u32[{rows},{ecfg.mb.val_row_words}]")
+    made = [
+        (name, opcode) for name, shape, opcode, _ in _entry_ops(text)
+        if shape.startswith(wide)
+    ]
+    assert made
+    assert not [m for m in made if m[1] in ("pad", "slice", "copy", "transpose")]

@@ -24,6 +24,9 @@ U32 = jnp.uint32
         (24, 6084, 8),   # the mailbox row, 4 + 6080: three block groups,
         # a 64-lane last value tile, index words on lanes 64-67 of it
         (16, 130, 8),    # index words straddle a tile boundary (126 + 4)
+        (16, 6148, 8),   # the mailbox row as it is stored since PR 44,
+        # 4 + 6144: the value plane ends on a tile boundary, the index
+        # words take lanes 0-3 of tile 48, no partial tile is stored
     ],
 )
 def test_fused_kernel_matches_jnp_keystream(r, w, rounds):
@@ -48,6 +51,40 @@ def test_fused_kernel_matches_jnp_keystream(r, w, rounds):
     np.testing.assert_array_equal(
         np.asarray(jnp.concatenate([bi, bv], axis=1)), np.asarray(data)
     )
+
+
+@pytest.mark.parametrize(
+    "zin,zv",
+    [
+        (6080, 6144),  # the mailbox row: 64 pad words in the last tile
+        (1000, 1024),  # a pad that shares its tile with value words
+        (896, 1152),   # whole pad tiles: the last value tile is full
+    ],
+)
+def test_kernel_pads_narrow_plaintext_as_the_jnp_path_does(zin, zv):
+    """Plaintext rows handed over without their zero pad (the write-back
+    does: ``cipher_rows``) come back at the stored width, bit for bit
+    what the jnp path makes of the padded rows; the pad words at rest
+    are the keystream, and decrypt to zeros."""
+    r, z, rounds = 16, 4, 8
+    key = jax.random.bits(jax.random.PRNGKey(0), (8,), U32)
+    idx = jax.random.bits(jax.random.PRNGKey(1), (r, z), U32)
+    val = jax.random.bits(jax.random.PRNGKey(2), (r, zin), U32)
+    bucket = jnp.arange(r, dtype=U32) * U32(7)
+    epoch = jnp.stack(
+        [jnp.arange(r, dtype=U32) % 3, jnp.zeros((r,), U32)], axis=1
+    )  # includes epoch-0 (identity) rows
+    ks_idx, ks_val = row_plane_keystreams(key, bucket, epoch, z, z + zv, rounds)
+    gi, gv = cipher_rows_pallas(
+        key, bucket, epoch, idx, val, rounds, interpret=True, zv=zv
+    )
+    assert gv.shape == (r, zv)
+    want = jnp.pad(val, ((0, 0), (0, zv - zin))) ^ ks_val
+    np.testing.assert_array_equal(np.asarray(gv), np.asarray(want))
+    np.testing.assert_array_equal(np.asarray(gi), np.asarray(idx ^ ks_idx))
+    _, back = cipher_rows_pallas(key, bucket, epoch, gi, gv, rounds, interpret=True)
+    np.testing.assert_array_equal(np.asarray(back[:, :zin]), np.asarray(val))
+    assert not np.asarray(back[:, zin:]).any()
 
 
 @pytest.mark.slow  # ~68 s interpret-mode whole-engine campaign; the

@@ -24,7 +24,8 @@ NOW = 1_700_000_000
     "n,v",
     [
         (64, 6),     # a row under one lane tile
-        (8, 1520),   # the mailbox row, 4 + 6080: three block groups
+        (8, 1520),   # the mailbox row's block words, 4 + 6080
+        (8, 1536),   # the mailbox row as stored, 4 + 6144 (PR 44)
     ],
 )
 def test_kernel_matches_gather_then_xor(n, v):

@@ -568,6 +568,54 @@ def test_round_engine_matches_batch_oracle_on_a_tall_mailbox_tree_with_cipher():
         dataclasses.replace(TALL_MAILBOX, bucket_cipher_rounds=8))
 
 
+def _assert_mailbox_pad_words_are_zero(engine):
+    """The stored mailbox row's words past its Z*V block words are
+    zeros in plaintext, in the tree plane (decrypted) and in the
+    tree-top cache plane."""
+    from grapevine_tpu.testing.compare import logical_tree_planes
+
+    mb = engine.ecfg.mb
+    assert (mb.val_row_words, mb.stored_row_words) == (6080, 6144)
+    _, val, _ = logical_tree_planes(mb, engine.state.mb)
+    assert val.shape == (mb.n_buckets_padded, 6144)
+    assert not val[:, 6080:].any()
+    cache = np.asarray(engine.state.mb.cache_val)
+    assert cache.shape == (mb.cache_buckets, 6144)
+    assert not cache[:, 6080:].any()
+
+
+@pytest.mark.parametrize(
+    "cipher_rounds,shards", [(0, 1), (8, 1), (0, 4), (8, 4)]
+)
+def test_round_engine_matches_batch_oracle_at_the_padded_mailbox_row(
+    cipher_rounds, shards
+):
+    """The tall mailbox tree at the real mailbox row (cap 62: 4 x 1,520
+    = 6,080 block words, stored as 6,144 since PR 44), cipher off and
+    on, on one device and on the CPU mesh (the local shard is
+    ``[n/4, 6144]``, the cipher runs after the psum): oracle equality,
+    and the pad words zero in plaintext after the rounds and after a
+    sweep that expires every record."""
+    import dataclasses
+
+    from grapevine_tpu.testing.compare import logical_tree_planes
+
+    cfg = dataclasses.replace(
+        TALL_MAILBOX, mailbox_cap=62, bucket_cipher_rounds=cipher_rounds,
+        shards=shards,
+    )
+    engine, oracle, t = _run_engine_vs_oracle(cfg, n_steps=16, n_idents=300)
+    assert engine.recipient_count() == oracle.recipient_count() > 15
+    _assert_mailbox_pad_words_are_zero(engine)
+    _, blocks, _ = logical_tree_planes(engine.ecfg.mb, engine.state.mb)
+    assert blocks[:, :6080].any()  # mailboxes rest in the tree
+    assert engine.expire(t + 5, period=3) == oracle.expire(t + 5, period=3)
+    assert engine.message_count() == oracle.message_count()
+    _assert_mailbox_pad_words_are_zero(engine)
+    engine.expire(t + 1000, period=10)
+    assert engine.message_count() == 0 and engine.recipient_count() == 0
+
+
 def _run_engine_vs_oracle(cfg, n_steps, n_idents=5):
     engine = GrapevineEngine(cfg, seed=3)
     oracle = ReferenceEngine(config=cfg, rng=random.Random(99))

@@ -104,7 +104,7 @@ def _trace_round(cfg, idxs, b: int):
 def _tree_planes(cfg) -> dict:
     """This geometry's HBM tree planes (and cache planes at k>0) in the
     shared ``plane_rows`` declaration format: name -> (shape, divisor)."""
-    z, v = cfg.bucket_slots, cfg.value_words
+    z, sw = cfg.bucket_slots, cfg.stored_row_words
     n = cfg.n_buckets_padded
     cb = cfg.cache_buckets
     # tree_idx/tree_leaf are stored flat [n·Z] but fetched/written
@@ -113,14 +113,14 @@ def _tree_planes(cfg) -> dict:
     # accounting matches on are the 2-D views at divisor 1
     planes = {
         "tree_idx": ((n, z), 1),
-        "tree_val": ((n, z * v), 1),
+        "tree_val": ((n, sw), 1),
         "nonces": ((n, 2), 1),
     }
     if cfg.posmap is not None:
         planes["tree_leaf"] = ((n, z), 1)
     if cb:
         planes["cache_idx"] = ((cb * z,), z)
-        planes["cache_val"] = ((cb, z * v), 1)
+        planes["cache_val"] = ((cb, sw), 1)
         if cfg.posmap is not None:
             planes["cache_leaf"] = ((cb * z,), z)
     return planes
