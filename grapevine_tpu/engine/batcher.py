@@ -426,6 +426,9 @@ class GrapevineEngine:
             sstep = make_sharded_step(self.ecfg, self._mesh)
             step_fn = lambda _ecfg, state, batch: sstep(state, batch)  # noqa: E731
             self._step = step_fn
+            #: the jit that holds the round's executable
+            #: (:meth:`compiled_round_memory`)
+            self._step_jit = sstep
             ssweep = make_sharded_sweep(self.ecfg, self._mesh)
             self._sweep = lambda _ecfg, state, *clock: ssweep(state, *clock)
         else:
@@ -438,7 +441,7 @@ class GrapevineEngine:
             # donate the state: trees update in place (no per-round copy,
             # and the fused pallas scatter's input/output aliasing would
             # otherwise force XLA to defensively copy both tree arrays)
-            self._step = jax.jit(
+            self._step = self._step_jit = jax.jit(
                 step_fn, static_argnums=(0,), donate_argnums=(1,)
             )
             self._sweep = jax.jit(
@@ -474,6 +477,14 @@ class GrapevineEngine:
         self.metrics = EngineMetrics()
         layout = self.round_layout()
         self.metrics.set_round_layout(layout)
+        self.metrics.set_mesh_psum_bytes(self.mesh_psum_bytes())
+        #: shapes of the first batch this engine dispatched, None until
+        #: then (the jit's own cache is shared by every engine of the
+        #: process and cannot say whose program it holds); the
+        #: compiler's memory report of the round is read once, at the
+        #: first health read after it
+        self._dispatched_shapes = None
+        self._compiled_memory_read = False
         self.metrics.set_state_size(
             state_init_s,
             sum(x.nbytes for x in jax.tree.leaves(self.state)))
@@ -698,6 +709,9 @@ class GrapevineEngine:
         self.state, resp, transcript = self._step(
             self.ecfg, self.state, batch
         )
+        if self._dispatched_shapes is None:
+            self._dispatched_shapes = jax.tree.map(
+                lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), batch)
         return t0, time.perf_counter(), resp, transcript
 
     def handle_queries_async(
@@ -853,11 +867,55 @@ class GrapevineEngine:
                 name: int(stash_occupancy(tree))
                 for name, tree in trees.items()
             }
+            if (self._dispatched_shapes is not None
+                    and not self._compiled_memory_read):
+                self._compiled_memory_read = True
+                self.metrics.set_compiled_memory(self.compiled_round_memory())
         for name, n in counts.items():
             self.metrics.observe_stash(name, n)
         self.metrics.observe_device_memory(
             d.memory_stats() or {} for d in state.rec.tree_val.devices())
         return counts
+
+    def compiled_round_memory(self):
+        """``memory_analysis()`` of the round executable this engine's
+        jit already holds: what the compiler counted for one chip
+        (arguments, outputs, what of them is aliased, temporaries,
+        generated code). Lowering the jit again for the operands it was
+        called with finds jax's own cached lowering and, on it, the
+        executable the first round compiled; where it finds none (jax
+        keeps it elsewhere, the operands miss the cache) this returns
+        None and compiles nothing. Call with the engine's lock held,
+        after a round has run."""
+        operands = (self.state, self._dispatched_shapes)
+        if self._mesh is None:  # the one-chip jit takes ecfg, statically
+            operands = (self.ecfg,) + operands
+        lowered = self._step_jit.lower(*operands)
+        held = getattr(getattr(lowered, "_lowering", None),
+                       "_executable", None)
+        if held is None:
+            return None
+        return lowered.compile().memory_analysis()
+
+    def mesh_psum_bytes(self) -> dict:
+        """``{tree: bytes}`` one round hands to ``psum`` for each tree
+        (oram/path_oram.py ``_path_gather``): every pass all-reduces a
+        full-size buffer of the rows it fetches, for the index plane,
+        the value plane at its stored width and the nonce plane and,
+        under a recursive position map, the leaf plane with the nonces
+        once more. The records tree makes one pass a round, the mailbox
+        tree two. 0 off a mesh, where nothing is reduced."""
+        if self._mesh is None:
+            return {"rec": 0, "mb": 0}
+        b, d = self.ecfg.batch_size, self.ecfg.mb_choices
+        out = {}
+        for tree, cfg, n, passes in (("rec", self.ecfg.rec, b, 1),
+                                     ("mb", self.ecfg.mb, b * d, 2)):
+            words = cfg.bucket_slots + cfg.stored_row_words + 2
+            if cfg.posmap is not None:
+                words += cfg.bucket_slots + 2
+            out[tree] = 4 * passes * cfg.fetched_bucket_rows(n) * words
+        return out
 
     def round_layout(self) -> dict:
         """``{tree: (dense_levels, fetched_bucket_rows,

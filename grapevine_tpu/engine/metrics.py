@@ -116,6 +116,11 @@ class EngineMetrics:
             "of those rows, the ones at levels the batch does not cover "
             "(one per path and level below the dense ones): 0 says the "
             "round moves its tree whole, level by level", labels=trees)
+        self._g_psum = r.gauge(
+            "grapevine_mesh_psum_bytes",
+            "bytes one round hands to the mesh's all-reduce for this "
+            "tree (index, value, nonce and leaf planes of every fetched "
+            "bucket row, every pass); 0 on one device", labels=trees)
         self._g_state_init = r.gauge(
             "grapevine_state_init_seconds",
             "wall time the engine took to build its state on the device "
@@ -134,6 +139,23 @@ class EngineMetrics:
             "grapevine_hbm_limit_bytes",
             "the bytes that device lets the process use (memory_stats "
             "bytes_limit): what grapevine_hbm_peak_bytes is a share of")
+        # the compiler's own count for the served round, a chip: set once
+        # the round has compiled (set_compiled_memory), 0 until then
+        self._g_compiled_args = r.gauge(
+            "grapevine_hbm_compiled_argument_bytes",
+            "bytes of the round executable's arguments on one chip "
+            "(memory_analysis of the executable the engine's jit holds): "
+            "the state and the batch")
+        self._g_compiled_temp = r.gauge(
+            "grapevine_hbm_compiled_temp_bytes",
+            "bytes of temporaries the compiler gave the round "
+            "executable on one chip")
+        self._g_compiled = r.gauge(
+            "grapevine_hbm_compiled_bytes",
+            "what the round executable needs of one chip while it runs, "
+            "as the compiler counted it: arguments + outputs not "
+            "aliased to them + temporaries + generated code; against "
+            "grapevine_hbm_limit_bytes it is the margin")
         self._h_phase = r.histogram(
             "grapevine_phase_seconds",
             "wall time per round phase (batch-level; obs/phases.py)",
@@ -170,6 +192,23 @@ class EngineMetrics:
             self._g_dense.set(dense, tree=tree)
             self._g_rows.set(rows, tree=tree)
             self._g_perpath.set(perpath, tree=tree)
+
+    def set_mesh_psum_bytes(self, nbytes: dict) -> None:
+        """``{tree: bytes}`` a round all-reduces, from the geometry."""
+        for tree, n in nbytes.items():
+            self._g_psum.set(n, tree=tree)
+
+    def set_compiled_memory(self, stats) -> None:
+        """A ``CompiledMemoryStats`` of the round executable; None
+        (jax had none to give) leaves the gauges at 0."""
+        if stats is None:
+            return
+        self._g_compiled_args.set(stats.argument_size_in_bytes)
+        self._g_compiled_temp.set(stats.temp_size_in_bytes)
+        self._g_compiled.set(
+            stats.argument_size_in_bytes + stats.output_size_in_bytes
+            - stats.alias_size_in_bytes + stats.temp_size_in_bytes
+            + stats.generated_code_size_in_bytes)
 
     def set_state_size(self, init_seconds: float, nbytes: int) -> None:
         """Static per engine, like the round layout: what building the

@@ -264,6 +264,90 @@ def test_the_durable_deployment_resolves_to_what_its_file_says(tmp_path):
     assert said["checkpoint_bytes"] == 10_253_824_277
 
 
+def test_the_four_chip_host_at_each_chips_share_resolves_to_what_its_file_says():
+    """``benchmarks/configs/host4-sharded-2p23.json`` is one four-chip
+    host of the v5e-8 bus, half of it, with each chip holding exactly
+    what ``chipshare-2p21-r2p17`` holds on one: nothing is cut. Shapes
+    only, no tree allocated: 23 record levels of which 12 are dense, 18
+    mailbox levels of which 13 are (five per-path ones under
+    ``shard_map``, where ``host4-sharded-2p22`` has none), 41 GB of
+    state in equal quarters, 1.5 GB of value rows all-reduced a round."""
+    import json
+
+    import jax
+    from jax.sharding import PartitionSpec as P
+
+    from grapevine_tpu.parallel.mesh import (
+        TREE_AXIS,
+        engine_state_specs,
+        make_mesh,
+        validate_sharded_geometry,
+    )
+
+    spec, cfg, ecfg, state, state_bytes = _held_to_its_file(
+        "host4-sharded-2p23")
+    assert spec["reduced"] == {} and spec["chips"] == cfg.shards == 4
+    assert spec["grapevine_config"] == {
+        "max_messages": (1 << 24) // 2, "max_recipients": 1 << 19,
+        "batch_size": 2048, "tree_density": 2, "shards": 4}
+    said = spec["resolves_to"]
+    rec, mb = ecfg.rec, ecfg.mb
+    assert (rec.path_len, rec.dense_levels(2048)) == (23, 12)
+    assert (rec.fetched_bucket_rows(2048),
+            rec.perpath_bucket_rows(2048)) == (26_608, 11 * 2048)
+    assert (mb.path_len, mb.dense_levels(4096)) == (18, 13)
+    assert (mb.fetched_bucket_rows(4096),
+            mb.perpath_bucket_rows(4096)) == (28_656, 5 * 4096)
+    assert rec.n_buckets == said["records"]["buckets"] == (1 << 23) - 1
+    assert mb.n_buckets == said["mailbox"]["buckets"] == 262_143
+    for tree, oram in (("records", rec), ("mailbox", mb)):
+        assert said[tree]["stored_row_words"] == oram.stored_row_words
+        assert said["value_planes"][tree] == [oram.n_buckets_padded,
+                                              oram.stored_row_words]
+    assert state.rec.tree_val.shape == (1 << 23, 1024)
+    assert state.mb.tree_val.shape == (1 << 18, 6144)
+    assert state_bytes == said["state_bytes"] == 41_079_078_256
+    # a quarter of each sharded plane a chip, and what is replicated
+    # whole: the file's figure for one chip
+    specs = engine_state_specs()
+    held = []
+    jax.tree.map(
+        lambda sp, x: held.append(
+            x.size * x.dtype.itemsize // (4 if sp == P(TREE_AXIS) else 1)),
+        specs, state, is_leaf=lambda sp: isinstance(sp, P))
+    assert sum(held) == said["state_bytes_held_by_a_chip"] == 10_321_722_736
+    for oram in (rec, mb):
+        assert oram.n_buckets_padded % 4 == 0
+    # each chip's share is the one-chip deployment's, plane for plane
+    with open(os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "benchmarks", "configs",
+            "chipshare-2p21-r2p17.json")) as f:
+        one = GrapevineConfig(**json.load(f)["grapevine_config"])
+    share = EngineConfig.from_config(one)
+    assert cfg.max_messages == 4 * one.max_messages
+    assert cfg.max_recipients == 4 * one.max_recipients
+    assert rec.n_buckets_padded == 4 * share.rec.n_buckets_padded
+    assert mb.n_buckets_padded == 4 * share.mb.n_buckets_padded
+    assert (rec.stored_row_words, mb.stored_row_words) == (
+        share.rec.stored_row_words, share.mb.stored_row_words)
+    assert (cfg.batch_size, cfg.mailbox_cap) == (one.batch_size,
+                                                 one.mailbox_cap)
+    # the rows a round hands to the all-reduce, value planes alone
+    assert said["value_plane_bytes_all_reduced_a_round"] == 4 * (
+        2 * 28_656 * 6144 + 26_608 * 1024) == 1_517_486_080
+    g = spec["guarantees"]
+    assert cfg.mailbox_cap == g["mailbox_cap"] == 62
+    assert (g["max_messages"], g["max_recipients"]) == (
+        cfg.max_messages, cfg.max_recipients)
+    assert g["layout"] == "both trees held in equal quarters, one per chip"
+    # the cap can fill the store from half of the recipient table, and
+    # 2^18 recipients is the least power of two whose cap does
+    assert (cfg.max_recipients // 2) * cfg.mailbox_cap >= cfg.max_messages
+    assert (cfg.max_recipients // 4) * cfg.mailbox_cap < cfg.max_messages
+    # the mesh takes it: shapes only, the devices stand in for chips
+    validate_sharded_geometry(ecfg, make_mesh(jax.devices()[:4]))
+
+
 def test_init_sharded_engine_matches_staged_init():
     """Shard-aware init is bit-identical to init-then-shard (threefry is
     deterministic under jit), at a shape small enough to stage both."""

@@ -274,6 +274,59 @@ def test_state_size_and_device_memory_gauges():
     assert reg.audit()["ok"]
 
 
+def test_compiled_memory_gauges_read_the_executable_the_engine_holds():
+    """``grapevine_hbm_compiled_*``: the compiler's own count for the
+    served round, read once at the first health read after the round
+    has compiled, from the executable the engine's jit already holds.
+    Nothing compiles for it: the jit's cache stays at one program and
+    no backend compile is seen while health is read; before a round has
+    run the gauges read 0 and nothing is lowered."""
+    import jax
+    import jax.monitoring
+
+    compiles = []
+    jax.monitoring.register_event_duration_secs_listener(
+        lambda name, *_a, **_k: compiles.append(name)
+        if name.endswith("backend_compile_duration") else None)
+
+    cfg = GrapevineConfig(
+        max_messages=64, max_recipients=16, mailbox_cap=4, batch_size=4,
+        stash_size=96,
+    )
+    e = GrapevineEngine(cfg, seed=1)
+    reg = e.metrics.registry
+    names = ("grapevine_hbm_compiled_argument_bytes",
+             "grapevine_hbm_compiled_temp_bytes",
+             "grapevine_hbm_compiled_bytes")
+    programs = e._step_jit._cache_size()
+    e.health()  # (compiles the stash reductions, once)
+    assert [reg.get(n).get() for n in names] == [0, 0, 0]
+    # no round program was made for it (the jit's cache is the
+    # process's: other engines' programs may sit in it)
+    assert e._step_jit._cache_size() == programs
+    e.handle_queries([_req(C.REQUEST_TYPE_CREATE, b"\x01" * 32,
+                           b"\x02" * 32)], NOW)
+    seen, programs = len(compiles), e._step_jit._cache_size()
+    assert programs >= 1
+    e.health()
+    assert len(compiles) == seen and e._step_jit._cache_size() == programs
+    args, temp, total = (reg.get(n).get() for n in names)
+    state_bytes = reg.get("grapevine_state_bytes").get()
+    # the arguments are the state and the batch; donated, so the
+    # outputs alias them and the total is little more than both parts
+    assert state_bytes <= args < state_bytes + (1 << 20)
+    assert temp > 0 and args + temp <= total < args + temp + (1 << 20)
+    stats = e.compiled_round_memory()
+    assert stats.argument_size_in_bytes == args
+    assert stats.temp_size_in_bytes == temp
+    for n in names:
+        assert reg.get(n).label_keys == ()
+    assert reg.audit()["ok"]
+    # read once: a later health read lowers nothing again
+    e.compiled_round_memory = None
+    e.health()
+
+
 def test_state_init_is_a_host_span_of_a_capture(tmp_path):
     """``grapevine/state_init`` is a TraceAnnotation around the state's
     building: a profiler capture that covers an engine's construction
