@@ -27,7 +27,9 @@ chip:
    identical statuses and records op for op, and zero stash overflow;
 5. kernel phase: a few rounds through each Pallas ``bucket_cipher_impl``
    at 2^16 messages / B=256, Mosaic-compiled (``tpu_custom_call`` in the
-   compiled text), state bit-identical to ``jnp`` rounds on the same ops.
+   compiled text), state bit-identical to ``jnp`` rounds on the same ops;
+   then the write-back's row-placement kernel alone against the jnp
+   scatter at the mailbox row's width (PR 46).
 
 ``--four-chips`` runs steps 2-4 with ``shards=4`` at 2^22 messages (one
 chip's share stays 2^20) and checks that every device holds a quarter
@@ -573,11 +575,21 @@ def kernel_phase(seed: int, cap: int = 1 << 16, batch: int = 256,
             engine_round_step, static_argnums=(0,), donate_argnums=(1,)
         ).lower(ecfg, state, first).compile()
         compile_s = time.perf_counter() - t0
-        n_kernels = compiled.as_text().count("tpu_custom_call")
+        calls = [line for line in compiled.as_text().split("\n")
+                 if 'custom_call_target="tpu_custom_call"' in line]
+        # the write-back's row placements (PR 46) are Mosaic kernels
+        # under every cipher impl but the fused one, whose write-back is
+        # its own kernel: three a round, one a tree pass
+        n_placed = sum("place_rows" in line for line in calls)
+        n_kernels = len(calls) - n_placed
         # off the chip (a rehearsal importing this function) the kernels
         # run interpreted and there is nothing Mosaic to count
         check(not on_tpu() or (n_kernels > 0) == (impl != "jnp"),
-              f"{impl}: {n_kernels} Mosaic kernels in the compiled round")
+              f"{impl}: {n_kernels} Mosaic cipher kernels in the compiled "
+              "round")
+        check(not on_tpu()
+              or n_placed == (0 if impl == "pallas_fused" else 3),
+              f"{impl}: {n_placed} placement kernels in the compiled round")
         t0 = time.perf_counter()
         outs = []
         for k in range(rounds):
@@ -597,7 +609,8 @@ def kernel_phase(seed: int, cap: int = 1 << 16, batch: int = 256,
                          for o in outs))
         say(phase=f"kernels.{impl}", ok=True, compile_s=round(compile_s, 2),
             run_s=round(time.perf_counter() - t0, 3), rounds=rounds,
-            mosaic_kernels_in_round=n_kernels, interpret=not on_tpu(),
+            mosaic_kernels_in_round=n_kernels,
+            placement_kernels_in_round=n_placed, interpret=not on_tpu(),
             successful_ops=ok_ops, capacity_log2=cap.bit_length() - 1,
             batch=batch)
     ref_outs, ref_state = results["jnp"]
@@ -612,6 +625,60 @@ def kernel_phase(seed: int, cap: int = 1 << 16, batch: int = 256,
         check(same, f"{impl}: state differs from jnp at {where}")
     say(phase="kernels.compare", ok=True,
         bit_identical_to_jnp=["pallas", "pallas_fused"])
+
+
+def placement_phase(seed: int, n: int = 1 << 13, tiles: int = 48,
+                    n_dense: int = 1008, n_paths: int = 1536) -> None:
+    """The write-back's row-placement kernel (oblivious/pallas_place.py)
+    against the jnp scatter it stands in for, at the mailbox row's width
+    (48 lane tiles, 24 KB): a dense range first, then per-path rows at
+    unique targets, a tenth of them dropped; same plane bit for bit."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from grapevine_tpu.config import on_tpu
+    from grapevine_tpu.oblivious.pallas_place import place_rows
+
+    rng = np.random.default_rng(seed)
+    sparse = rng.permutation(np.arange(15 + n_dense, n))[:n_paths]
+    tgt = np.concatenate([np.arange(15, 15 + n_dense), sparse])
+    tgt[n_dense:][rng.random(n_paths) < 0.1] = n  # not owned: no copy
+    tgt = jnp.asarray(tgt.astype(np.int32))
+    k_plane, k_rows = jax.random.split(jax.random.key(seed))
+    plane = jax.random.bits(k_plane, (n, tiles, 128), jnp.uint32)
+    rows = jax.random.bits(k_rows, (tgt.shape[0], tiles, 128), jnp.uint32)
+    scatter = jax.jit(
+        lambda p, t, v: p.at[t].set(v, mode="drop", unique_indices=True),
+        donate_argnums=(0,))
+    place = jax.jit(
+        lambda p, t, v: place_rows(p, t, v, interpret=not on_tpu()),
+        donate_argnums=(0,))
+    n_kernels = place.lower(plane, tgt, rows).compile().as_text().count(
+        "tpu_custom_call")
+    check(not on_tpu() or n_kernels == 1,
+          f"placement: {n_kernels} Mosaic kernels in the compiled call")
+
+    def timed(fn, calls: int = 5):
+        out = jax.block_until_ready(fn(jnp.copy(plane), tgt, rows))
+        t0 = time.perf_counter()
+        for _ in range(calls):
+            out = fn(out, tgt, rows)
+        jax.block_until_ready(out)
+        return out, (time.perf_counter() - t0) / calls * 1e3
+
+    want, scatter_ms = timed(scatter)
+    got, place_ms = timed(place)
+    check(bool(jnp.array_equal(got, want)),
+          "placement: the kernel's plane differs from the jnp scatter's")
+    written = np.asarray(tgt)[np.asarray(tgt) < n]
+    kept = np.setdiff1d(np.arange(n), written)[:256]
+    check(bool(jnp.array_equal(got[kept], plane[kept])),
+          "placement: a row no copy targets changed")
+    say(phase="kernels.placement", ok=True, plane_rows=n, row_words=tiles * 128,
+        rows=int(tgt.shape[0]), rows_written=int(written.shape[0]),
+        place_ms=round(place_ms, 3), jnp_scatter_ms=round(scatter_ms, 3),
+        mosaic_kernels=n_kernels, interpret=not on_tpu())
 
 
 # -- main ----------------------------------------------------------------
@@ -655,6 +722,7 @@ def main() -> int:
             ), args.seed, "served")
             gc.collect()  # the server's 4.4 GB of trees, before the next
             kernel_phase(args.seed)
+            placement_phase(args.seed)
         say(phase="done", total_s=round(time.perf_counter() - t_start, 1),
             compile_cache_entries=len(os.listdir(cache))
             if os.path.isdir(cache) else 0)

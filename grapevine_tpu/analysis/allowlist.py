@@ -49,7 +49,11 @@ _ORAM_CORE = (
        "write-back of exactly the fetched rows — the fixed dense heap "
        "range (all-true owner mask) and the fetched paths below it, "
        "owner-masked — the write transcript is identical to the read "
-       "transcript"),
+       "transcript. On a TPU a plane of wide rows is handed the SAME "
+       "target vector as one DMA a row (oblivious/pallas_place.py): "
+       "rows written, not a plane walked; a dropped target starts no "
+       "copy, and which targets drop is the owner mask and the chip's "
+       "index, functions of the public leaves alone"),
     _A("gather", "oram/path_oram.py:working_leaves",
        "leaf lookup in the flat position table, private working memory "
        "(one fixed [W]-shaped gather per round)"),
@@ -274,13 +278,16 @@ RANGE_ALLOWLIST: tuple = (
        "path_b - axis_index*n_local rebase: non-owned lanes wrap mod "
        "2^32 by construction and the owner mask routes exactly those "
        "lanes to the out-of-range drop sentinel — a wrapped value is "
-       "never a landing address (sharded==single-chip bit-equality, "
-       "tests/test_parallel.py)"),
+       "never a landing address, of the scatter or of the placement "
+       "kernel's DMAs, which skip a target past the shard "
+       "(sharded==single-chip bit-equality, tests/test_parallel.py, "
+       "tests/test_pallas_place.py)"),
     _A("convert_element_type", "oram/path_oram.py:_path_scatter",
        "drop-mode scatter target cast u32->int32: owned lanes are "
        "< n_local (fits, at every certified geometry) by the owner "
        "mask the interval domain cannot relate; non-owned lanes carry "
        "the wrapped rebase and drop out of bounds — write-drop is the "
        "documented masking idiom, so the cast only ever narrows the "
-       "drop sentinel"),
+       "drop sentinel (the placement kernel reads the same int32 "
+       "targets from SMEM and starts a copy only below n_local)"),
 )

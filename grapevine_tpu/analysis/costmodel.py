@@ -110,7 +110,7 @@ def oram_planes(cfg, prefix: str = "") -> dict:
     cb = cfg.cache_buckets
     planes = {
         f"{prefix}tree_idx": ((n, z), 1),
-        f"{prefix}tree_val": ((n, sw), 1),
+        f"{prefix}tree_val": ((n, *cfg.stored_row_shape), 1),
         f"{prefix}nonces": ((n, 2), 1),
     }
     if cfg.posmap is not None:
@@ -176,7 +176,8 @@ def oram_round_rows(cfg, b: int, prefix: str = "") -> dict:
 
     rows = {
         f"{prefix}tree_idx": PlaneRows((n, z), 1, z, R, R),
-        f"{prefix}tree_val": PlaneRows((n, sw), 1, sw, R, R),
+        f"{prefix}tree_val": PlaneRows(
+            (n, *cfg.stored_row_shape), 1, sw, R, R),
         # the fetch always gathers the nonce plane (the keystream input
         # precedes the encrypted? branch); the epoch commit scatter only
         # exists under the cipher. Recursive leaf decrypt re-gathers it.
@@ -258,7 +259,7 @@ def sweep_chunk_planes(cfg, prefix: str = "") -> dict:
     n = cfg.n_buckets_padded
     planes = {
         f"{prefix}tree_idx": ((n * z,), n),
-        f"{prefix}tree_val": ((n, sw), n),
+        f"{prefix}tree_val": ((n, *cfg.stored_row_shape), n),
         f"{prefix}nonces": ((n, 2), n),
     }
     if cfg.posmap is not None and cfg.encrypted:
@@ -277,7 +278,8 @@ def expiry_sweep_rows(ecfg) -> dict:
         n = cfg.n_buckets_padded
         z, sw = cfg.bucket_slots, cfg.stored_row_words
         out[f"{prefix}tree_idx"] = PlaneRows((n, z), 1, z, n, n)
-        out[f"{prefix}tree_val"] = PlaneRows((n, sw), 1, sw, n, n)
+        out[f"{prefix}tree_val"] = PlaneRows(
+            (n, *cfg.stored_row_shape), 1, sw, n, n)
         out[f"{prefix}nonces"] = PlaneRows((n, 2), 1, 2, n, n)
         if cfg.posmap is not None and cfg.encrypted:
             out[f"{prefix}tree_leaf"] = PlaneRows((n, z), 1, z, n, n)
@@ -975,11 +977,18 @@ def audit_oram_configs():
         top_cache_levels=2,
         posmap=derive_posmap_spec(32, top_cache_levels=2),
     )
+    # a value row of eight lane tiles: stored ``(8, 128)``, and on a TPU
+    # written back by the row-placement kernel, which the census counts
+    # as the scatter it stands in for (tests/test_pallas_place.py traces
+    # this geometry as a TPU does)
+    wide_row = OramConfig(height=5, value_words=256, n_blocks=32,
+                          cipher_rounds=8, top_cache_levels=2)
     return [
         ("flat_k0", flat, 8),
         ("flat_k2", cached, 8),
         ("flat_k2_plaintext", plaintext, 8),
         ("recursive_k2", recursive, 6),
+        ("flat_k2_wide_row", wide_row, 8),
     ]
 
 

@@ -12,6 +12,7 @@ exists to kill.
 
 from __future__ import annotations
 
+import contextlib
 from collections import Counter
 
 #: primitives that move data between HBM arrays — the access schedule
@@ -21,6 +22,21 @@ ACCESS_PRIMS = ("gather", "scatter", "scatter-add", "scatter-mul",
                 "dynamic_update_slice")
 #: data-dependent control flow: forbidden anywhere in a traced round
 CONTROL_PRIMS = ("cond", "while")
+
+
+@contextlib.contextmanager
+def as_a_tpu_traces():
+    """Trace-only: ``config.on_tpu()`` answers True inside, so an audit
+    on the CPU walks the program a TPU runs, its Pallas kernels
+    un-interpreted (traceable anywhere, lowerable only there). The
+    backend is the one thing the program asks about its host; there is
+    no option for it."""
+    from unittest import mock
+
+    import jax
+
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+        yield
 
 
 def _sub_jaxprs(eqn):
@@ -75,9 +91,32 @@ def site_of(eqn, pkg: str = "grapevine_tpu") -> str:
     return best or "<unknown>"
 
 
+def _placed_rows(eqn):
+    """``(plane shape, rows shape)`` of a row-placement kernel
+    (oblivious/pallas_place.py): a ``pallas_call`` that DMAs, whose one
+    aliased operand is the plane it writes into and whose operand of the
+    same row shape is the rows it places, one copy each. None for any
+    other kernel."""
+    aliases = eqn.params["input_output_aliases"]
+    if len(aliases) != 1 or not any(
+        e.primitive.name == "dma_start"
+        for e in walk_eqns(eqn.params["jaxpr"])
+    ):
+        return None
+    plane = tuple(eqn.invars[aliases[0][0]].aval.shape)
+    rows = [
+        tuple(v.aval.shape) for i, v in enumerate(eqn.invars)
+        if i != aliases[0][0] and len(v.aval.shape) == len(plane) > 1
+        and tuple(v.aval.shape[1:]) == plane[1:]
+    ]
+    return (plane, rows[0]) if len(rows) == 1 else None
+
+
 def plane_rows(jaxpr, planes: dict) -> dict:
     """Rows moved per named array plane by every gather/scatter in the
-    traced program.
+    traced program, and by the row-placement kernel that stands in for
+    a wide plane's scatter on a TPU (counted as the scatter it replaces:
+    rows written, one DMA each).
 
     ``planes`` maps name -> ``(shape, divisor)``: an operand whose aval
     shape equals ``shape`` is attributed to that plane; the moved leading
@@ -88,14 +127,20 @@ def plane_rows(jaxpr, planes: dict) -> dict:
     out: dict[str, list] = {k: [] for k in planes}
     for eqn in walk_eqns(jaxpr):
         name = eqn.primitive.name
-        if not name.startswith("scatter") and name != "gather":
+        if name == "pallas_call":
+            placed = _placed_rows(eqn)
+            if placed is None:
+                continue
+            op_shape, moved = placed
+        elif name.startswith("scatter") or name == "gather":
+            op_shape = tuple(eqn.invars[0].aval.shape)
+            moved = (
+                eqn.outvars[0].aval.shape
+                if name == "gather"
+                else eqn.invars[2].aval.shape
+            )
+        else:
             continue
-        op_shape = tuple(eqn.invars[0].aval.shape)
-        moved = (
-            eqn.outvars[0].aval.shape
-            if name == "gather"
-            else eqn.invars[2].aval.shape
-        )
         for pname, (pshape, div) in planes.items():
             if op_shape == tuple(pshape):
                 rows = (moved[0] if moved else 0) // div

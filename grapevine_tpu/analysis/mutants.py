@@ -10,7 +10,8 @@ into a blanket permission), and the audit run itself errors out.
 
 Obliviousness classes, per ISSUE 12: position-dependent branch,
 key-indexed gather, data-dependent early exit, secret-shaped output,
-un-allowlisted scatter, leaky debug print, python-level branch.
+un-allowlisted scatter, leaky debug print, python-level branch; and a
+kernel's DMA target taken from the rows it moves (PR 46).
 
 Overflow classes, per ISSUE 14 (``_RANGE_REGISTRY``, run through
 analysis/rangelint.py): u32 leaf-arithmetic wrap, truncating cast,
@@ -118,6 +119,23 @@ def _unallowlisted_scatter():
         return plane.at[secret[0] % 16].set(jnp.uint32(1))
 
     return fn, {"secret": _sds(4), "plane": _sds(16)}, ("secret",)
+
+
+@_mutant("dma_target_from_contents", "dma-index")
+def _dma_target_from_contents():
+    """The row-placement kernel (oblivious/pallas_place.py) handed a
+    target derived from the rows it places: a DMA address that depends
+    on a block's content, the leak ``_path_scatter``'s public targets
+    rule out."""
+    import jax.numpy as jnp
+
+    from ..oblivious.pallas_place import place_rows
+
+    def fn(rows, plane):
+        tgt = (rows[:, 0, 0] % 16).astype(jnp.int32)
+        return place_rows(plane, tgt, rows, interpret=True)
+
+    return fn, {"rows": _sds(4, 8, 128), "plane": _sds(16, 8, 128)}, ("rows",)
 
 
 @_mutant("leaky_debug_print", "callback")

@@ -11,6 +11,9 @@ sink:
 - a ``gather`` index operand or any ``scatter*`` index operand,
 - a ``dynamic_slice`` / ``dynamic_update_slice`` start index,
 - a ``cond`` branch predicate or a ``while`` loop predicate,
+- inside a Pallas kernel, the row a DMA leaves from or lands on
+  (``dma_start`` / ``dma_wait`` indices) and the index of a ref read or
+  write,
 - a host callback (``debug_callback`` & friends — a leaky debug print
   is an access pattern too: it reaches the operator's terminal).
 
@@ -49,7 +52,15 @@ from .jaxpr_walk import _sub_jaxprs, census, site_of
 _CALLBACK_PRIMS = ("debug_callback", "debug_print", "pure_callback",
                    "io_callback", "host_callback_call", "outside_call")
 
+#: ref primitives of a Pallas kernel body: a read, the writes, a DMA
+_REF_PRIMS = ("get", "swap", "addupdate", "dma_start", "dma_wait")
+
 EMPTY: frozenset = frozenset()
+
+
+def _is_ref(atom) -> bool:
+    """Whether a kernel-body atom is a ref (memory) and not a value."""
+    return hasattr(getattr(atom, "aval", None), "inner_aval")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -58,6 +69,7 @@ class Violation:
 
     kind: str  # gather-index | scatter-index | dynamic-slice-start |
     #            cond-predicate | while-predicate | callback |
+    #            dma-index | ref-index | ref-write |
     #            trace-dependence | program-mismatch
     site: str  # "file.py:function" (jaxpr_walk.site_of key)
     prim: str  # primitive name ("" for trace-level findings)
@@ -179,6 +191,25 @@ def _propagate(closed, in_taints: list, ctx: _Ctx) -> list:
         elif name in _CALLBACK_PRIMS:
             ctx.sink("callback", eqn, union,
                      "secret-derived value escapes to a host callback")
+        elif name in _REF_PRIMS:
+            # inside a Pallas kernel: operands are refs and, flattened
+            # after them, the indices of their windows
+            refs = [t for a, t in zip(eqn.invars, ins) if _is_ref(a)]
+            idx = [t for a, t in zip(eqn.invars, ins) if not _is_ref(a)]
+            if name in ("swap", "addupdate"):
+                idx, stored, held = idx[1:], idx[0], refs[0]
+            elif name == "dma_start":
+                stored, held = refs[0], refs[1]  # src -> dst
+            else:
+                stored = held = EMPTY
+            ctx.sink("dma-index" if name.startswith("dma") else "ref-index",
+                     eqn, frozenset().union(*idx) if idx else EMPTY,
+                     "kernel window placed by a secret-derived index")
+            # a ref holds the taint it entered the kernel with: a write
+            # that would add to it is refused, not modelled
+            ctx.sink("ref-write", eqn, stored - held,
+                     "kernel stores a secret into a ref the walk holds "
+                     "public")
 
         # ---- taint transfer --------------------------------------------
         if name == "cond":
@@ -224,6 +255,18 @@ def _propagate(closed, in_taints: list, ctx: _Ctx) -> list:
                     break
                 carry = merged
             outs = carry + ys
+        elif name == "pallas_call":
+            # the kernel's refs, in order: the operands (scalar prefetch
+            # first), the outputs, the scratch. An operand's ref holds
+            # that operand's taint; an output, a scratch ref, and an
+            # operand aliased onto an output (the kernel may read back
+            # what it wrote there) hold everything the kernel was handed
+            body = eqn.params["jaxpr"]
+            aliased = {i for i, _ in eqn.params["input_output_aliases"]}
+            held = [union if i in aliased else t for i, t in enumerate(ins)]
+            held += [union] * (len(body.invars) - len(ins))
+            _propagate(body, held, ctx)
+            outs = [union] * len(eqn.outvars)
         else:
             # the SAME sub-jaxpr discovery the census walk uses
             # (tuple/list params included — custom_linear_solve and

@@ -478,6 +478,7 @@ class GrapevineEngine:
         layout = self.round_layout()
         self.metrics.set_round_layout(layout)
         self.metrics.set_mesh_psum_bytes(self.mesh_psum_bytes())
+        self.metrics.set_dma_placed_rows(self.dma_placed_rows())
         #: shapes of the first batch this engine dispatched, None until
         #: then (the jit's own cache is shared by every engine of the
         #: process and cannot say whose program it holds); the
@@ -915,6 +916,28 @@ class GrapevineEngine:
             if cfg.posmap is not None:
                 words += cfg.bucket_slots + 2
             out[tree] = 4 * passes * cfg.fetched_bucket_rows(n) * words
+        return out
+
+    def dma_placed_rows(self) -> dict:
+        """``{tree: rows}`` of the value plane one round's write-back
+        places by DMA (oram/path_oram.py ``_path_scatter``): every row
+        the tree's passes fetch, where the plane stores its rows as
+        whole memory tiles (``OramConfig.stored_row_shape``) and the
+        backend is a TPU; 0 where the plane keeps XLA's scatter, and
+        under ``pallas_fused`` on one chip, whose write-back is the
+        fused kernel's. On a mesh each chip places the rows it owns
+        of them."""
+        from ..config import on_tpu
+
+        b, d = self.ecfg.batch_size, self.ecfg.mb_choices
+        out = {}
+        for tree, cfg, n, passes in (("rec", self.ecfg.rec, b, 1),
+                                     ("mb", self.ecfg.mb, b * d, 2)):
+            fused = (cfg.cipher_impl == "pallas_fused" and cfg.encrypted
+                     and self._mesh is None)
+            by_dma = (on_tpu() and len(cfg.stored_row_shape) == 2
+                      and not fused)
+            out[tree] = passes * cfg.fetched_bucket_rows(n) if by_dma else 0
         return out
 
     def round_layout(self) -> dict:

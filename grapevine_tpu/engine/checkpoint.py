@@ -61,14 +61,17 @@ from ..testing import faults
 from .state import EngineConfig, EngineState, state_spec
 
 MAGIC = b"GVCKPT1\0"
-#: 3 since PR 44: a value row of eight lane tiles or more is stored on
+#: 4 since PR 46: a value row stored on whole lane tiles is a
+#: ``(tiles, 128)`` row of its plane (OramConfig.stored_row_shape), so
+#: the manifest's shapes moved; the bytes are version 3's, row-major.
+#: 3 was PR 44's: a value row of eight lane tiles or more stored on
 #: whole tiles (OramConfig.stored_row_words: the mailbox row's 6,080
-#: words as 6,144), so the planes' shapes and the place of the
+#: words as 6,144), so the planes' widths and the place of the
 #: slot-index words in a row's keystream both moved. 2 was PR 40's
-#: keystream order (oblivious/bucket_cipher.py); trees sealed under an
-#: older version would decrypt to noise. Refused, not migrated:
-#: re-initialise the state.
-VERSION = 3
+#: keystream order (oblivious/bucket_cipher.py); trees sealed under 1
+#: or 2 would decrypt to noise. Refused, not migrated: re-initialise
+#: the state.
+VERSION = 4
 
 _CKPT_RE = re.compile(r"^ckpt-(\d{16})\.sealed$")
 

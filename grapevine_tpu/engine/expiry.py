@@ -152,7 +152,7 @@ def _chunked_tree_sweep(cfg: OramConfig, oram: OramState, carry0, body,
         acc, idx_p, val_p, leaf_p = carry
         bid = base + i * U32(rpc) + jnp.arange(rpc, dtype=U32)
         ix = cut(idx_p, i, rpc * z).reshape(rpc, z)
-        vl = cut(val_p, i, rpc)
+        vl = cut(val_p, i, rpc).reshape(rpc, -1)  # rows, however stored
         ep = cut(oram.nonces, i, rpc)
         if cfg.encrypted:
             ks_ix, ks_vl = row_plane_keystreams(
@@ -185,7 +185,7 @@ def _chunked_tree_sweep(cfg: OramConfig, oram: OramState, carry0, body,
                 )
                 leaf_p = paste(leaf_p, i, rpc * z, lf.reshape(-1))
         idx_p = paste(idx_p, i, rpc * z, ix.reshape(-1))
-        val_p = paste(val_p, i, rpc, vl)
+        val_p = paste(val_p, i, rpc, vl.reshape(rpc, *val_p.shape[1:]))
         return (acc, idx_p, val_p, leaf_p), None
 
     # the leaf plane rides along only where it is re-keyed

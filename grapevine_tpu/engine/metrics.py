@@ -121,6 +121,13 @@ class EngineMetrics:
             "bytes one round hands to the mesh's all-reduce for this "
             "tree (index, value, nonce and leaf planes of every fetched "
             "bucket row, every pass); 0 on one device", labels=trees)
+        self._g_dma_rows = r.gauge(
+            "grapevine_round_dma_placed_rows",
+            "bucket rows one round's write-back places in this tree's "
+            "value plane by DMA, one copy a row (oblivious/"
+            "pallas_place.py: a plane that stores its rows as whole "
+            "memory tiles, on a TPU); 0 where the plane keeps XLA's "
+            "scatter", labels=trees)
         self._g_state_init = r.gauge(
             "grapevine_state_init_seconds",
             "wall time the engine took to build its state on the device "
@@ -197,6 +204,12 @@ class EngineMetrics:
         """``{tree: bytes}`` a round all-reduces, from the geometry."""
         for tree, n in nbytes.items():
             self._g_psum.set(n, tree=tree)
+
+    def set_dma_placed_rows(self, rows: dict) -> None:
+        """``{tree: rows}`` a round places by DMA, set once: the
+        mechanism engages when the round is traced, not per request."""
+        for tree, n in rows.items():
+            self._g_dma_rows.set(n, tree=tree)
 
     def set_compiled_memory(self, stats) -> None:
         """A ``CompiledMemoryStats`` of the round executable; None

@@ -102,26 +102,48 @@ LANES = 128
 GROUP_WORDS = 16 * LANES
 
 
-def chacha_words(key, counter, n1, n2, n3, rounds: int = 8) -> list:
+def _double_round(s):
+    """One column round and one diagonal round on the state list."""
+    _qr(s, 0, 4, 8, 12)
+    _qr(s, 1, 5, 9, 13)
+    _qr(s, 2, 6, 10, 14)
+    _qr(s, 3, 7, 11, 15)
+    _qr(s, 0, 5, 10, 15)
+    _qr(s, 1, 6, 11, 12)
+    _qr(s, 2, 7, 8, 13)
+    _qr(s, 3, 4, 9, 14)
+    return s
+
+
+def chacha_words(key, counter, n1, n2, n3, rounds: int = 8,
+                 rolled: bool = False) -> list:
     """ChaCha block function → the 16 output words, each ``counter``'s
     shape. ``key`` is anything ``key[i]`` indexes into eight u32 scalars
     (an array here, words read from a kernel's ref in Pallas): the ONE
     copy of the round schedule and the feedforward every
-    implementation runs."""
+    implementation runs.
+
+    ``rolled`` traces the double rounds as a ``fori_loop`` over that
+    one copy, unrolled again where the program is lowered
+    (``unroll=True``): the same words and the same straight-line code
+    for the compiler, from a jaxpr a quarter the size. The Pallas
+    kernel asks for it, because its body is traced in Python once a
+    signature at every start-up, beside whatever else the host is
+    doing; left rolled for the compiler too, the kernel's mailbox pass
+    went from 1.66 to 2.07-2.29 ms on a v5e (PERF.md section 6, PR 46).
+    XLA's jnp path keeps the straight-line form."""
     shape = counter.shape
     init = [jnp.full(shape, c, U32) for c in _SIGMA]
     init += [jnp.broadcast_to(key[i], shape) for i in range(8)]
     init += [counter] + [jnp.broadcast_to(n, shape) for n in (n1, n2, n3)]
     s = list(init)
-    for _ in range(rounds // 2):
-        _qr(s, 0, 4, 8, 12)
-        _qr(s, 1, 5, 9, 13)
-        _qr(s, 2, 6, 10, 14)
-        _qr(s, 3, 7, 11, 15)
-        _qr(s, 0, 5, 10, 15)
-        _qr(s, 1, 6, 11, 12)
-        _qr(s, 2, 7, 8, 13)
-        _qr(s, 3, 4, 9, 14)
+    if rolled:
+        s = list(jax.lax.fori_loop(
+            0, rounds // 2, lambda _, st: tuple(_double_round(list(st))),
+            tuple(s), unroll=True))
+    else:
+        for _ in range(rounds // 2):
+            _double_round(s)
     # feedforward (state + init, mod 2^32 by RFC 7539) as a plain loop:
     # a listcomp would put the adds in a `<listcomp>` frame on py<=3.11,
     # making the rangelint allowlist site key python-version-dependent
@@ -155,12 +177,14 @@ def stream_tiles(n_words: int):
         yield q // 16, q % 16, start, min(LANES, n_words - start)
 
 
-def group_words(key, lane, n1, n2, n3, group: int, rounds: int) -> list:
+def group_words(key, lane, n1, n2, n3, group: int, rounds: int,
+                rolled: bool = False) -> list:
     """The 16 state words of block group ``group``: lane ``l`` of each
     is that word of the block whose counter is ``group * LANES + l``.
     ``lane`` is the u32 lane index, ``[rows, lanes]``; the nonce words
     broadcast against it."""
-    return chacha_words(key, lane + U32(group * LANES), n1, n2, n3, rounds)
+    return chacha_words(
+        key, lane + U32(group * LANES), n1, n2, n3, rounds, rolled)
 
 
 def keystream_tile(key, n1, n2, n3, rows: int, n_words: int, rounds: int):

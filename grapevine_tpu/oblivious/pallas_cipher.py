@@ -60,17 +60,28 @@ _ROW_TILE = 64
 _SUB_ROWS = 16
 
 
+def _words(ref, rows, cols):
+    """Index of ``[rows, cols]`` of a value row block: the plain 2-D
+    window, or, where the block holds its rows as ``(tiles, 128)`` (a
+    plane's stored rows, ``OramConfig.stored_row_shape``), the same
+    words: ``cols`` never crosses a lane tile."""
+    if len(ref.shape) == 2:
+        return rows, cols
+    q = cols.start // LANES
+    return rows, q, slice(cols.start - q * LANES, cols.stop - q * LANES)
+
+
 def _cipher_kernel(
     key_ref, bucket_ref, epoch_ref, idx_ref, val_ref, oidx_ref, oval_ref,
-    *, sub, z, zv, rounds,
+    *, sub, z, zin, zv, rounds,
 ):
     """One row block: (idx [TR, z], val [TR, zin]) ^ keystream rows ->
     (idx [TR, z], val [TR, zv]), ``sub`` rows at a time. ``zin <= zv``:
     value words the input lacks are zeros, so their ciphertext is the
-    keystream itself."""
+    keystream itself. Either value block may hold its rows as ``(tiles,
+    128)`` instead (:func:`_words`)."""
     key = [key_ref[i] for i in range(8)]
     lane = jax.lax.broadcasted_iota(U32, (sub, LANES), 1)
-    zin = val_ref.shape[1]
 
     def sub_tile(s, carry):
         rows = pl.ds(pl.multiple_of(s * sub, sub), sub)
@@ -82,17 +93,19 @@ def _cipher_kernel(
         written = jnp.broadcast_to((n2 != U32(0)) | (n3 != U32(0)), (sub, LANES))
         for group, word, start, width in stream_tiles(zv + z):
             if word == 0:
-                words = group_words(key, lane, n1, n2, n3, group, rounds)
+                words = group_words(
+                    key, lane, n1, n2, n3, group, rounds, rolled=True)
             ks = jnp.where(written, words[word], U32(0))
             end = start + width
             if start < zin:  # this tile's value words
                 cols = slice(start, min(end, zin))
-                oval_ref[rows, cols] = (
-                    val_ref[rows, cols] ^ ks[:, : cols.stop - start]
+                oval_ref[_words(oval_ref, rows, cols)] = (
+                    val_ref[_words(val_ref, rows, cols)]
+                    ^ ks[:, : cols.stop - start]
                 )
             cols = slice(max(start, zin), min(end, zv))
             if cols.start < cols.stop:  # and the pad words after them
-                oval_ref[rows, cols] = (
+                oval_ref[_words(oval_ref, rows, cols)] = (
                     ks[:, cols.start - start: cols.stop - start]
                 )
             if end > zv:  # and its slot-index words
@@ -103,37 +116,57 @@ def _cipher_kernel(
                 )
         return carry
 
-    jax.lax.fori_loop(0, val_ref.shape[0] // sub, sub_tile, 0)
+    jax.lax.fori_loop(0, idx_ref.shape[0] // sub, sub_tile, 0)
 
 
-@functools.partial(jax.jit, static_argnames=("rounds", "interpret", "zv"))
+@functools.partial(
+    jax.jit, static_argnames=("rounds", "interpret", "zv", "tiled_out"))
 def cipher_rows_pallas(
     key: jax.Array,  # u32[8]
     bucket: jax.Array,  # u32[R]
     epoch: jax.Array,  # u32[R, 2]; 0 = identity (never written)
     pidx: jax.Array,  # u32[R, z] slot-index words
-    pval: jax.Array,  # u32[R, zin] value words
+    pval: jax.Array,  # u32[R, zin] value words, or [R, zin / 128, 128]
     rounds: int = 8,
     interpret: bool = False,
     zv: int | None = None,
+    tiled_out: bool = False,
 ):
     """Fused ``row ^ keystream``; returns (pidx', pval'), both u32.
 
     ``zv`` is the width of the value row as the stream lays it out and
     as it is returned (``pval``'s own where None). Rows narrower than
     that are plaintext without its zero pad: the kernel writes the pad
-    words' keystream itself, so no padded copy of the rows is made."""
+    words' keystream itself, so no padded copy of the rows is made.
+
+    Value rows may come, and with ``tiled_out`` go, as a plane stores
+    them: ``[R, tiles, 128]``, each row whole memory tiles
+    (``OramConfig.stored_row_shape``). The kernel then reads or writes
+    those words where they lie, so neither direction of the round pays a
+    pass to bring rows cut from a plane to ``[R, zv]`` or back."""
     r, z = pidx.shape
-    zin = pval.shape[1]
+    tiled_in = pval.ndim == 3
+    zin = pval.shape[1] * (LANES if tiled_in else 1)
     zv = zin if zv is None else zv
     sub = _SUB_ROWS
     # the row tile is the second-minor block dim of every operand: a
     # multiple of the u32 sublane count, no larger than the rows need
     tr = min(_ROW_TILE, -(-r // sub) * sub)
     row_block = lambda width: pl.BlockSpec((tr, width), lambda i: (i, 0))  # noqa: E731
+
+    def val_block(width, tiled):
+        """(block, array shape) of value rows ``width`` words wide."""
+        if not tiled:
+            return row_block(width), (r, width)
+        tiles = width // LANES
+        return (pl.BlockSpec((tr, tiles, LANES), lambda i: (i, 0, 0)),
+                (r, tiles, LANES))
+
+    in_block, _ = val_block(zin, tiled_in)
+    out_block, out_shape = val_block(zv, tiled_out)
     return pl.pallas_call(
         functools.partial(
-            _cipher_kernel, sub=sub, z=z, zv=zv, rounds=rounds
+            _cipher_kernel, sub=sub, z=z, zin=zin, zv=zv, rounds=rounds
         ),
         grid=(pl.cdiv(r, tr),),
         in_specs=[
@@ -143,12 +176,12 @@ def cipher_rows_pallas(
             row_block(1),
             row_block(2),
             row_block(z),
-            row_block(zin),
+            in_block,
         ],
-        out_specs=[row_block(z), row_block(zv)],
+        out_specs=[row_block(z), out_block],
         out_shape=[
             jax.ShapeDtypeStruct((r, z), U32),
-            jax.ShapeDtypeStruct((r, zv), U32),
+            jax.ShapeDtypeStruct(out_shape, U32),
         ],
         interpret=interpret,
     )(key, bucket[:, None], epoch, pidx, pval)

@@ -96,6 +96,9 @@ def gather_decrypt_rows(
     single-chip fetch is one HBM pass either way.
     """
     n_padded = tree_val.shape[0]
+    # rows, whatever shape the plane stores them in (a wide row is
+    # stored as whole memory tiles: OramConfig.stored_row_shape)
+    tree_val = tree_val.reshape(n_padded, -1)
     zv = tree_val.shape[1]
     r = flat_b.shape[0]
     w = z + zv
@@ -205,6 +208,8 @@ def scatter_encrypt_rows(
     Returns the updated ``(tree_idx, tree_val, nonces)``.
     """
     n_padded = tree_val.shape[0]
+    stored = tree_val.shape
+    tree_val = tree_val.reshape(n_padded, -1)
     zv = tree_val.shape[1]
     r = flat_b.shape[0]
     w = z + zv
@@ -258,4 +263,4 @@ def scatter_encrypt_rows(
     )(tgt, key[None, None, :], new_pidx[:, None, :], new_pval[:, None, :],
       epoch[None, None, :], idx_rows[:, None, :], tree_val[:, None, :],
       nonces[:, None, :])
-    return oidx.reshape(-1), oval[:, 0, :], ononce[:, 0, :]
+    return oidx.reshape(-1), oval.reshape(stored), ononce[:, 0, :]

@@ -8,8 +8,9 @@ and SURVEY.md §2b) for TPU:
   behavior (each alternative was measured to force multi-GB relayout
   copies or pathological strided slices — see the layout note on
   ``OramState``): a flat 1-D slot-index array ``tree_idx[n*Z]`` and a
-  2-D value array ``tree_val[n, Z*V]`` whose 4080-byte rows match
-  upstream's PathORAM-4096 bucket granularity;
+  value array ``tree_val`` of one row a bucket, ``[n, Z*V]`` or, where
+  the row is eight lane tiles or more, ``[n, tiles, 128]``, whose
+  4080-byte rows match upstream's PathORAM-4096 bucket granularity;
 - per-block leaf assignments are **not** stored in the tree: the flat
   position map in private memory is authoritative, and working-set
   leaves are one private gather away. (Upstream stores leaves in bucket
@@ -143,7 +144,7 @@ def cipher_rows(
     buckets: jax.Array,  # u32[R] heap bucket ids
     epochs: jax.Array,  # u32[R, 2] per-row (lo, hi) nonce (0 = identity)
     pidx: jax.Array,  # u32[R, Z]
-    pval: jax.Array,  # u32[R, cfg.stored_row_words], or [R, Z*V] plaintext
+    pval: jax.Array,  # u32[R, *cfg.stored_row_shape], or [R, Z*V] plaintext
 ):
     """XOR bucket rows with their keystream (encrypt ≡ decrypt).
 
@@ -152,24 +153,30 @@ def cipher_rows(
     stream order of oblivious/bucket_cipher.py) — a memory snapshot of
     the tree arrays reveals neither slot occupancy nor contents.
 
-    Only the Z*V block words of ``pval`` are read, at either width, and
-    the rows come back at the stored width with the pad words'
-    keystream after the block words: the pad is zeros in plaintext, so
-    on the way to the tree that is its ciphertext, and on the way from
-    it nothing anyone reads (the fetch cuts the rows to Z*V next). So
-    the write-back hands over its rows without a padded copy of them
-    (on a v5e an unfused pad of the mailbox pass is a 1.2 ms pass of
-    its own) and one kernel serves both directions (a second signature
-    of the mailbox kernel is a second of tracing at every start-up,
-    four beside the benchmark's signing workers: PERF.md §5, PR 44).
+    The two directions are told apart by what is handed over. Rows cut
+    from a plane (``[R, *cfg.stored_row_shape]``) are the fetch: they
+    come back ``[R, stored_row_words]``, the pad words nothing anyone
+    reads (the fetch cuts the rows to Z*V next). Plaintext ``[R, Z*V]``
+    is the write-back: it comes back as the plane stores it, the pad
+    words' keystream after the block words (the pad is zeros in
+    plaintext, so that is its ciphertext). So the write-back hands over
+    its rows without a padded copy of them (on a v5e an unfused pad of
+    the mailbox pass is a 1.2 ms pass of its own), and where a plane
+    stores its rows as ``(tiles, 128)`` neither direction pays a pass
+    to relay them: the kernel reads and writes the words where they
+    lie. A narrow row is stored as it is computed on and the directions
+    are one signature.
 
     ``cfg.cipher_impl == "pallas"`` routes through the fused Pallas
     kernel (keystream generated in VMEM and XORed in one pass — no HBM
     keystream materialization; oblivious/pallas_cipher.py). Both
     implementations produce bit-identical ciphertext."""
+    r = pidx.shape[0]
+    to_store = pval.ndim == 2  # plaintext on its way to the plane
+    tiled = len(cfg.stored_row_shape) == 2
+    out_shape = (r, *cfg.stored_row_shape) if to_store else (r, -1)
     if not cfg.encrypted:
-        return pidx, stored_rows(cfg, pval)
-    pval = logical_rows(cfg, pval)
+        return pidx, stored_rows(cfg, pval.reshape(r, -1)).reshape(out_shape)
     z = cfg.bucket_slots
     if cfg.cipher_impl in ("pallas", "pallas_fused"):
         from ..oblivious.pallas_cipher import cipher_rows_pallas
@@ -190,14 +197,18 @@ def cipher_rows(
                 RuntimeWarning,
                 stacklevel=2,
             )
+        if to_store:
+            pval = logical_rows(cfg, pval)
         return cipher_rows_pallas(
             key, buckets, epochs, pidx, pval, cfg.cipher_rounds,
             interpret=interpret, zv=cfg.stored_row_words,
+            tiled_out=to_store and tiled,
         )
     ks_idx, ks_val = row_plane_keystreams(
         key, buckets, epochs, z, cfg.row_words, cfg.cipher_rounds
     )
-    return pidx ^ ks_idx, stored_rows(cfg, pval) ^ ks_val
+    pval = stored_rows(cfg, logical_rows(cfg, pval.reshape(r, -1)))
+    return pidx ^ ks_idx, (pval ^ ks_val).reshape(out_shape)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -313,6 +324,19 @@ class OramConfig:
         return -(-zv // LANES) * LANES
 
     @property
+    def stored_row_shape(self) -> tuple[int, ...]:
+        """One ``tree_val`` row as the plane holds it: a row stored on
+        whole lane tiles is ``(tiles, 128)``, so that a bucket is whole
+        ``(8, 128)`` memory tiles on an untiled leading axis, contiguous
+        in HBM, and one DMA places it (``_path_scatter``); a narrower
+        row is ``(Z*V,)``. Row-major the bytes are the same either way.
+        A function of the row's width alone, like the width itself."""
+        sw = self.stored_row_words
+        if sw < PADDED_ROW_MIN_TILES * LANES:
+            return (sw,)
+        return (sw // LANES, LANES)
+
+    @property
     def row_words(self) -> int:
         """Keystream width per bucket: the stored value row and the Z
         slot-index words after it, enciphered as one row under one
@@ -398,38 +422,53 @@ class OramState(NamedTuple):
     ``cfg.stored_row_words`` wide: ``Z*V`` words on whole 128-word lane
     tiles. The records tree's 1,024 words are that as they are; the
     mailbox tree's 6,080 (Z=4 x 1,520) are 47.5 tiles and are stored as
-    6,144, the 64 pad words zeros in plaintext.
+    6,144, the 64 pad words zeros in plaintext (PR 44: a v5e's DEFAULT
+    layout for ``u32[n,6080]`` is the transposed ``{0,1}``, and each
+    round copied the 1.59 GB plane whole after its entry and before
+    its exit, 4.90 + 4.76 ms of a 110.27 ms round at 2^21 messages /
+    2^17 recipients; pinning ``{1,0}`` through jit's ``Format`` did the
+    same on a cold compile, but an executable that jax 0.9.0 loads from
+    its persistent cache returns default layouts again).
 
-    What the padded row cured (my chip runs, PR 44, TPU v5 lite, 2^21
-    messages / 2^17 recipients): a v5e's DEFAULT layout for
-    ``u32[n,6080]`` is the transposed ``{0,1}``, the row gathers and
-    scatters want ``{1,0}``, so each round copied the 1.59 GB plane
-    whole after its entry and before its exit (4.90 + 4.76 ms of a
-    110.27 ms round; at 2^16 recipients 2.45 + 2.36 of 80.57). Stored
-    6,144 wide the plane enters and leaves ``{1,0}`` and is never
-    copied: ``device_busy_ms`` 110.27 -> 100.24, ``scope_ms.unscoped``
-    5.02 -> 0.12 (tests/test_mosaic_lowering.py holds the compiled
-    round to it). Pinning ``{1,0}`` through jit's in/out ``Format`` had
-    done the same on a cold compile (PR 28), but an executable that jax
-    0.9.0 loads from its persistent cache returns default layouts
-    again, so the pin could not ship. The cut back to ``Z*V`` after the
-    decrypt is a bitcast (a tiled 6,080-word row IS 48 tiles), and the
-    pad before the encrypt is the cipher kernel's own (``cipher_rows``).
+    Since PR 46 a row stored on whole tiles is a ``(tiles, 128)`` row
+    of a 3-D plane, ``cfg.stored_row_shape``: ``u32[n, 48, 128]`` for
+    that mailbox tree, ``u32[n, 8, 128]`` for the records tree, the
+    same bytes row-major. In HBM the last two dimensions are tiled
+    ``T(8,128)`` and the leading one is not, so a bucket is six (or
+    one) whole memory tiles, 24 KB (4 KB) contiguous, where a row of
+    the 2-D ``u32[n, 6144]`` was 48 pieces of 512 B at a stride of
+    4 KB, eight buckets interleaved in every tile. What that buys (my
+    chip runs, PR 46, TPU v5 lite, same size):
 
-    What it did not cure: the two mailbox ``path_scatter`` fusions
-    still take 9.0 ms each, a read and a write of the whole plane at
-    ~360 GB/s. That is the compiler's choice and the better one: where
-    a scatter's rows are an eighth of the operand's or more (20,464 of
-    65,536 here; the described-chip compile switches between 12.5 and
-    18.75 %) it sorts the indices, permutes the updates (2.2 ms) and
-    streams the operand; made to scatter by rows instead, in chunks
-    under that share, the same write-back took 13.7 ms against 11.2
-    (0.67 us a 24.6 KB row, where the records tree's 4 KiB rows go at
-    0.16). ROADMAP Speed 7 (i) has what is left.
+    - the write-back places each row by one DMA (``_path_scatter``,
+      oblivious/pallas_place.py): 1.58 ms a mailbox pass at ~600 GB/s
+      of rows read and written, against XLA's 11.26 ms on the 2-D
+      plane, which sorted the targets, permuted the rows (2.2 ms) and
+      streamed the whole 1.61 GB plane at ~360 GB/s wherever a
+      scatter's rows are an eighth of its operand's or more (20,464 of
+      65,536). Mosaic refuses a one-row window of the 2-D plane
+      ("Slice shape along dimension 0 must be aligned to tiling (8),
+      but is 1"), which is why the shape moved and not the kernel
+      alone. XLA's own scatter on the 3-D plane goes by rows at
+      3.37 ms, what a backend without the kernel gets;
+    - XLA's gather of the same rows, 2.23 -> 1.64 ms;
+    - ``device_busy_ms`` 100.25 -> 76.75, ``scope_ms.writeback``
+      45.50 -> 23.12 (PERF.md section 6, PR 46).
+
+    What it asks of the code: the rows cut from the plane are
+    ``[R, tiles, 128]`` and the eviction works on ``[R, Z*V]``; made by
+    XLA the step between them is a pass of its own (1.55 ms at the
+    mailbox width, four a round), so the cipher kernel reads and writes
+    the rows as the plane stores them and is flat on its other side
+    (``cipher_rows``). The cut back to ``Z*V`` after the decrypt is
+    still a bitcast and the pad before the encrypt the cipher kernel's
+    own. tests/test_mosaic_lowering.py holds the compiled round to all
+    of it: the plane never copied, transposed or relaid, two placement
+    kernels aliased onto it, no scatter, sort or permute of its rows.
     """
 
     tree_idx: jax.Array  # u32[n_buckets * Z] flat; SENTINEL = empty slot
-    tree_val: jax.Array  # u32[n_buckets, stored_row_words]; a row a bucket
+    tree_val: jax.Array  # u32[n_buckets, *stored_row_shape]; a row a bucket
     #: tree-top cache planes (cfg.top_cache_levels = k > 0; zero-length
     #: otherwise): the decrypted-resident image of heap buckets
     #: [0, 2^k−1) — the authoritative copy; those buckets' HBM tree rows
@@ -493,7 +532,8 @@ def init_oram(cfg: OramConfig, key: jax.Array) -> OramState:
     n_cleaf = cb * z if cfg.posmap is not None else 0
     return OramState(
         tree_idx=jnp.full((cfg.n_buckets_padded * z,), SENTINEL, U32),
-        tree_val=jnp.zeros((cfg.n_buckets_padded, cfg.stored_row_words), U32),
+        tree_val=jnp.zeros(
+            (cfg.n_buckets_padded, *cfg.stored_row_shape), U32),
         cache_idx=jnp.full((cb * z,), SENTINEL, U32),
         cache_val=jnp.zeros((cb, cfg.stored_row_words), U32),
         cache_leaf=jnp.zeros((n_cleaf,), U32),
@@ -583,6 +623,14 @@ def _path_gather(tree: jax.Array, path_b: jax.Array, axis_name: str | None):
             )
 
 
+def places_by_dma(tree: jax.Array) -> bool:
+    """Whether ``_path_scatter`` places this plane's rows by DMA
+    (oblivious/pallas_place.py): a plane that stores its rows as whole
+    memory tiles (``OramConfig.stored_row_shape``), on a TPU. Read off
+    what the function is handed; no tree's name and no option."""
+    return tree.ndim == 3 and _on_tpu()
+
+
 def _path_scatter(
     tree: jax.Array,
     path_b: jax.Array,
@@ -594,23 +642,40 @@ def _path_scatter(
     (every heap index has exactly one owner, so the global write is
     consistent with no collective). ``owner`` optionally masks out slots
     that must not be written at all (round.py's duplicate-bucket copies);
-    masked slots are dropped via out-of-range targets."""
+    masked slots are dropped via out-of-range targets.
+
+    Which rows are written is a function of the public path, of
+    ``owner`` (itself a function of the public leaves: the lowest row
+    that meets a bucket owns it) and of the chip's index: never of a
+    block's content. A plane of wide rows on a TPU has them placed by
+    one DMA each (:func:`places_by_dma`), where XLA's scatter of a
+    share of rows this large streams the whole plane through the core;
+    the targets, and so the addresses written, are the same either
+    way."""
     with device_phase("path_scatter"):
-        if axis_name is None:
-            if owner is None:
-                return tree.at[path_b].set(new_vals, unique_indices=True)
-            tgt = jnp.where(owner, path_b, U32(tree.shape[0]))
-            # in-bounds targets are unique by construction: the owner map
-            # gives every heap bucket exactly one owning column, so at most
-            # one write lands on any row (the rest drop out of bounds)
-            return tree.at[tgt].set(new_vals, mode="drop", unique_indices=True)
         n_local = tree.shape[0]
-        base = (jax.lax.axis_index(axis_name) * n_local).astype(U32)
-        loc = path_b - base
-        mine = (path_b >= base) & (path_b < base + U32(n_local))
-        if owner is not None:
-            mine = mine & owner
-        tgt = jnp.where(mine, loc, U32(n_local))  # out of range = dropped
+        new_vals = new_vals.reshape(new_vals.shape[:1] + tree.shape[1:])
+        keep = owner
+        if axis_name is not None:
+            base = (jax.lax.axis_index(axis_name) * n_local).astype(U32)
+            mine = (path_b >= base) & (path_b < base + U32(n_local))
+            keep = mine if owner is None else mine & owner
+            path_b = path_b - base
+        by_dma = places_by_dma(tree)
+        if keep is None and not by_dma:
+            return tree.at[path_b].set(new_vals, unique_indices=True)
+        # in-bounds targets are unique by construction: the owner map
+        # gives every heap bucket exactly one owning column and a heap
+        # index has one owning chip, so at most one write lands on any
+        # row (the rest drop out of bounds)
+        tgt = path_b if keep is None else jnp.where(
+            keep, path_b, U32(n_local))
+        if by_dma:
+            from ..oblivious.pallas_place import place_rows
+
+            return place_rows(
+                tree, tgt.astype(jnp.int32), new_vals,
+                interpret=not _on_tpu())
         return tree.at[tgt].set(new_vals, mode="drop", unique_indices=True)
 
 

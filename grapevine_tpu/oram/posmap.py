@@ -245,6 +245,7 @@ def init_posmap(cfg, key: jax.Array):
         )
         tree_idx, tree_val = enc_idx.reshape(-1), enc_val
         nonces, epoch = ep1, jnp.array([2, 0], U32)
+    tree_val = tree_val.reshape(inner.tree_val.shape)
 
     inner = inner._replace(
         tree_idx=tree_idx, tree_val=tree_val, posmap=pm,
@@ -519,7 +520,7 @@ def read_table(cfg, pm_state):
     k, z = spec.entries_per_block, icfg.bucket_slots
     inner = pm_state.inner
     tidx = np.asarray(inner.tree_idx).reshape(-1, z)
-    tval = np.asarray(inner.tree_val)
+    tval = np.asarray(inner.tree_val).reshape(icfg.n_buckets_padded, -1)
     if icfg.encrypted:
         buckets = jnp.arange(icfg.n_buckets_padded, dtype=U32)
         ks_idx, ks_val = row_plane_keystreams(

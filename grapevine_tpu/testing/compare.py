@@ -71,7 +71,7 @@ def logical_tree_planes(cfg, oram):
     z, v = cfg.bucket_slots, cfg.value_words
     n = cfg.n_buckets_padded
     idx = np.asarray(oram.tree_idx).reshape(n, z).copy()
-    val = np.asarray(oram.tree_val).copy()
+    val = np.asarray(oram.tree_val).reshape(n, -1).copy()
     leaf = (
         np.asarray(oram.tree_leaf).reshape(n, z).copy()
         if np.asarray(oram.tree_leaf).size

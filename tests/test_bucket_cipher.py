@@ -173,7 +173,9 @@ def test_engine_trees_encrypted_at_rest():
 
     # a read rewrites the same record content; the touched rows must not
     # repeat their previous ciphertext (epoch advances)
-    snap1 = np.asarray(engine.state.rec.tree_val).copy()
+    # a wide row is stored as (tiles, 128): rows, however stored
+    rows_of = lambda plane: np.asarray(plane).reshape(plane.shape[0], -1)  # noqa: E731
+    snap1 = rows_of(engine.state.rec.tree_val).copy()
     nz1 = snap1[snap1.any(axis=1)]
     rd = engine.handle_queries(
         [
@@ -192,7 +194,7 @@ def test_engine_trees_encrypted_at_rest():
     )[0]
     assert rd.status_code == C.STATUS_CODE_SUCCESS
     assert rd.record.payload == marker  # semantics intact through cipher
-    snap2 = np.asarray(engine.state.rec.tree_val)
+    snap2 = rows_of(engine.state.rec.tree_val)
     nz2 = snap2[snap2.any(axis=1)]
     assert nz1.shape[0] >= 1 and nz2.shape[0] >= 1
     row_sets_equal = {r.tobytes() for r in nz1} == {r.tobytes() for r in nz2}

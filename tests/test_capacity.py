@@ -186,10 +186,10 @@ def test_the_chips_real_share_resolves_to_what_its_file_says():
         said["mailbox"]["bucket_bytes"]) == 24344
     assert 4 * (mb.row_words + 2) == 24600
     # the first buffer of the program that holds 2^31 elements
-    assert state.rec.tree_val.shape == (1 << 21, 1024)
+    assert state.rec.tree_val.shape == (1 << 21, 8, 128)
     assert state.rec.tree_val.size == 1 << 31
     # 6,080 block words stored as 48 whole lane tiles (PR 44)
-    assert state.mb.tree_val.shape == (1 << 16, 6144)
+    assert state.mb.tree_val.shape == (1 << 16, 48, 128)
     assert state.mb.cache_val.shape == (15, 6144)
     # the file's figure predates PR 44; the file is the benchmark's
     assert _mailbox_pad_bytes(ecfg) == 16_781_056
@@ -259,7 +259,13 @@ def test_the_durable_deployment_resolves_to_what_its_file_says(tmp_path):
     # the checkpoint: head, nonce, seq, manifest length, manifest, every
     # leaf, tag
     manifest = cp._manifest(ecfg, state_spec(ecfg)[1])
-    assert said["checkpoint_bytes"] + pad == (
+    # the file's figure also predates PR 46's (tiles, 128) rows: the
+    # manifest spells the two value planes' shapes three bytes longer
+    spelled = len(manifest) - len(
+        manifest.replace(b"[2097152,8,128]", b"[2097152,1024]")
+        .replace(b"[65536,48,128]", b"[65536,6144]"))
+    assert spelled == 3
+    assert said["checkpoint_bytes"] + pad + spelled == (
         len(cp.MAGIC) + 4 + 12 + 8 + 4 + len(manifest) + state_bytes + 32)
     assert said["checkpoint_bytes"] == 10_253_824_277
 
@@ -304,8 +310,8 @@ def test_the_four_chip_host_at_each_chips_share_resolves_to_what_its_file_says()
         assert said[tree]["stored_row_words"] == oram.stored_row_words
         assert said["value_planes"][tree] == [oram.n_buckets_padded,
                                               oram.stored_row_words]
-    assert state.rec.tree_val.shape == (1 << 23, 1024)
-    assert state.mb.tree_val.shape == (1 << 18, 6144)
+    assert state.rec.tree_val.shape == (1 << 23, 8, 128)
+    assert state.mb.tree_val.shape == (1 << 18, 48, 128)
     assert state_bytes == said["state_bytes"] == 41_079_078_256
     # a quarter of each sharded plane a chip, and what is replicated
     # whole: the file's figure for one chip

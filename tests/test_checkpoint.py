@@ -101,30 +101,33 @@ def test_checkpoint_write_load(tmp_path, ecfg, state):
     assert cp.state_to_bytes(ecfg, state2) == cp.state_to_bytes(ecfg, state)
 
 
-@pytest.mark.parametrize("old", [1, 2])
+@pytest.mark.parametrize("old", [1, 2, 3])
 def test_an_older_version_payload_is_refused(
     tmp_path, ecfg, state, monkeypatch, old
 ):
-    """Version 3 stores wide value rows on whole lane tiles (PR 44),
-    version 2 was the tile-aligned keystream order (PR 40): a tree
-    sealed under an older version would decrypt to noise, so both
-    checks — the sealed file's header and the state payload's manifest
-    — refuse it and say which version they met."""
-    assert cp.VERSION == 3
+    """Version 4 stores a wide value row as ``(tiles, 128)`` (PR 46:
+    the planes' shapes in the manifest, the bytes are version 3's),
+    version 3 brought wide rows to whole lane tiles (PR 44), version 2
+    was the tile-aligned keystream order (PR 40): a tree sealed under
+    version 1 or 2 would decrypt to noise, so both checks — the sealed
+    file's header and the state payload's manifest — refuse an older
+    file and say which version they met."""
+    assert cp.VERSION == 4
     monkeypatch.setattr(cp, "VERSION", old)
     old_payload = cp.state_to_bytes(ecfg, state)
     old_file = cp.write_checkpoint(str(tmp_path), ROOT, ecfg, state, seq=3)
     monkeypatch.undo()
-    with pytest.raises(cp.CheckpointError, match=f"version {old}, want 3"):
+    with pytest.raises(cp.CheckpointError, match=f"version {old}, want 4"):
         cp.bytes_to_state(ecfg, old_payload)
-    with pytest.raises(cp.CheckpointError, match=f"version {old}, want 3"):
+    with pytest.raises(cp.CheckpointError, match=f"version {old}, want 4"):
         cp.load_checkpoint(old_file, ROOT, ecfg)
 
 
-def test_version_3_round_trips_the_padded_mailbox_row(tmp_path):
+def test_version_4_round_trips_the_padded_mailbox_row(tmp_path):
     """The at-rest format of PR 44: a mailbox row of 6,080 block words
-    (cap 62) is stored, sealed and loaded 6,144 wide, ciphertext and
-    pad keystream alike, under a header that says version 3."""
+    (cap 62) is stored, sealed and loaded 6,144 wide as 48 lane tiles
+    (PR 46), ciphertext and pad keystream alike, under a header that
+    says version 4."""
     import dataclasses
     import struct
 
@@ -137,13 +140,14 @@ def test_version_3_round_trips_the_padded_mailbox_row(tmp_path):
     assert ecfg.mb.stored_row_words == 6144 == ecfg.mb.val_row_words + 64
     state, _, _ = jax.jit(engine_round_step, static_argnums=(0,))(
         ecfg, init_engine(ecfg, seed=5), _round_batch(ecfg, 7)[0])
-    assert state.mb.tree_val.shape == (ecfg.mb.n_buckets_padded, 6144)
+    assert state.mb.tree_val.shape == (ecfg.mb.n_buckets_padded, 48, 128)
     # a written row's pad words are keystream at rest, not zeros
     written = np.asarray(state.mb.nonces).any(axis=1)
-    assert np.asarray(state.mb.tree_val)[written][:, 6080:].all(axis=1).any()
+    rows = np.asarray(state.mb.tree_val).reshape(-1, 6144)
+    assert rows[written][:, 6080:].all(axis=1).any()
     path = cp.write_checkpoint(str(tmp_path), ROOT, ecfg, state, seq=9)
     with open(path, "rb") as f:
-        assert f.read(12) == cp.MAGIC + struct.pack("<I", 3)
+        assert f.read(12) == cp.MAGIC + struct.pack("<I", 4)
     seq, state2 = cp.load_checkpoint(path, ROOT, ecfg)
     assert seq == 9
     assert state2.mb.tree_val.shape == state.mb.tree_val.shape

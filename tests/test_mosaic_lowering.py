@@ -35,6 +35,7 @@ from grapevine_tpu.oblivious.pallas_gather import (
     gather_decrypt_rows,
     scatter_encrypt_rows,
 )
+from grapevine_tpu.oblivious.pallas_place import place_rows
 
 U32 = jnp.uint32
 
@@ -64,15 +65,29 @@ def one_chip():
     compilation_cache.reset_cache()
 
 
-def _compile_for(chip, fn, *specs, **static):
+def _compile_for(chip, fn, *specs, donate=(), **static):
     """Compile ``fn`` for the described chip; return the compiled text."""
     specs = [
         jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=chip) for s in specs
     ]
     return (
-        jax.jit(functools.partial(fn, **static))
+        jax.jit(functools.partial(fn, **static), donate_argnums=donate)
         .lower(*specs).compile().as_text()
     )
+
+
+def _wide_row_copies(text, rows=20464):
+    """Ops that copy, pad or relay an array of ``rows`` wide value rows
+    (stored ``(48, 128)`` / ``(8, 128)`` or flat at the stored width).
+    At the jit boundary of a lone call the compiler relays its NARROW
+    parameters (``[R,4]``, ``[R,2]``, a ``u32[R,6080]`` whose default
+    layout is the transposed one); inside the round their producers
+    hand them over as the kernel wants them."""
+    import re
+
+    return re.findall(
+        rf"u32\[{rows},(?:\d+,128|6144|1024)\]\S* (?:pad|copy|transpose)\(",
+        text)
 
 
 def _s(*shape, dtype=U32):
@@ -126,6 +141,35 @@ def test_cipher_kernel_compiles_at_the_2p17_mailbox_shape(one_chip, zv):
     assert "tpu_custom_call" in text
 
 
+@pytest.mark.parametrize("zin,zv", [(6080, 6144), (1024, 1024)])
+def test_cipher_kernel_compiles_on_rows_as_the_plane_stores_them(
+    one_chip, zin, zv
+):
+    """Since PR 46 a wide value row is stored ``(tiles, 128)`` and the
+    kernel reads and writes it so: the fetch takes ``[R, tiles, 128]``
+    and returns flat rows, the write-back takes flat plaintext (the
+    mailbox row without its pad) and returns ``[R, tiles, 128]``; no
+    copy of the rows is made around either."""
+    import re
+
+    tiles = zv // 128
+    fetch = _compile_for(
+        one_chip, cipher_rows_pallas, _s(8), _s(20464), _s(20464, 2),
+        _s(20464, 4), _s(20464, tiles, 128), rounds=8, interpret=False,
+        zv=zv,
+    )
+    write = _compile_for(
+        one_chip, cipher_rows_pallas, _s(8), _s(20464), _s(20464, 2),
+        _s(20464, 4), _s(20464, zin), rounds=8, interpret=False, zv=zv,
+        tiled_out=True,
+    )
+    for text in (fetch, write):
+        assert "tpu_custom_call" in text
+        assert f"u32[20464,{tiles},128]" in text
+        assert not _wide_row_copies(text)
+        assert not re.search(r" pad\(", text)
+
+
 def test_cipher_kernel_pads_the_mailbox_plaintext_itself(one_chip):
     """The write-back's call: 6,080-word plaintext rows in, 6,144-word
     stored rows out, the 64 pad words' keystream stored beside the last
@@ -168,14 +212,19 @@ def test_cipher_rows_on_a_tpu_is_one_kernel_and_no_keystream_buffer(
     mb = ecfg.mb
     z = mb.bucket_slots
     assert (z, mb.val_row_words, mb.stored_row_words) == (4, 6080, 6144)
-    # the fetch hands over stored rows, the write-back plaintext blocks
-    for zv in (mb.stored_row_words, mb.val_row_words):
+    assert mb.stored_row_shape == (48, 128)
+    # the fetch hands over rows as the plane stores them and takes flat
+    # ones; the write-back hands over plaintext blocks and takes rows
+    # for the plane
+    for handed, back in (((48, 128), "u32[20464,6144]"),
+                         ((6080,), "u32[20464,48,128]")):
         text = _compile_for(
             one_chip, functools.partial(cipher_rows, mb), _s(8), _s(20464),
-            _s(20464, 2), _s(20464, z), _s(20464, zv),
+            _s(20464, 2), _s(20464, z), _s(20464, *handed),
         )
         assert "tpu_custom_call" in text
-        assert "u32[20464,6144]" in text
+        assert back in text.split("\n", 1)[0].split("->")[1]
+        assert not _wide_row_copies(text)
         assert not re.search(r"u32\[\d+,381[,\]]", text)
         assert not re.search(r"u32\[\d+,6(?:0(?:96|84)|1(?:60|48))\]", text)
         assert " fusion(" not in text and " pad(" not in text
@@ -318,7 +367,10 @@ def test_engine_round_with_fused_kernels_compiles_for_v5e(
 # value plane is the point: for ``u32[n,6080]`` its default layout is
 # the transposed ``{0,1}``, so the round copied the plane whole after
 # its entry and before its exit (4.7 + 4.6 ms of a 110.3 ms round at
-# 2^21 / 2^17, ledger PR 43). One compile, read by four cases.
+# 2^21 / 2^17, ledger PR 43). Since PR 46 the plane stores that row as
+# ``(48, 128)``, whole memory tiles on an untiled leading axis, so that
+# the write-back places each row by one DMA. One compile, read by five
+# cases.
 # ----------------------------------------------------------------------
 
 
@@ -328,8 +380,8 @@ def wide_row_round(one_chip):
     donated, as a TPU engine resolves it (the Pallas cipher) at B = 2048
     and the real mailbox width, on a mailbox tree of 14 levels: the
     least that keeps a per-path level under the 13 dense ones (2^15
-    recipients; the plane is ``u32[16384,6144]``, 0.4 GB, described and
-    never allocated)."""
+    recipients; the plane is ``u32[16384,48,128]``, 0.4 GB, described
+    and never allocated)."""
     from grapevine_tpu.config import GrapevineConfig
     from grapevine_tpu.engine.round_step import engine_round_step
 
@@ -364,76 +416,221 @@ def _entry_ops(text):
     return [m.groups() for m in map(op.match, body.split("\n")) if m]
 
 
+def _mb_plane(ecfg):
+    """The mailbox value plane as the compiled text names it: rows
+    stored ``(48, 128)`` since PR 46."""
+    n, shape = ecfg.mb.n_buckets_padded, ecfg.mb.stored_row_shape
+    assert (n, shape) == (1 << 14, (48, 128))
+    return f"u32[{n},48,128]"
+
+
+def _mb_rows(ecfg):
+    """Shapes the rows of a mailbox pass can take in the text: as the
+    plane stores them, flat at the stored width, flat as blocks."""
+    rows = ecfg.mb.fetched_bucket_rows(4096)
+    assert rows == (1 << 13) - (1 << 4) + 4096
+    return (f"u32[{rows},48,128]", f"u32[{rows},{ecfg.mb.stored_row_words}]",
+            f"u32[{rows},{ecfg.mb.val_row_words}]")
+
+
 def test_the_mailbox_plane_enters_and_leaves_the_round_row_major(
     wide_row_round
 ):
     """The parameter ``state.mb.tree_val`` and its result are laid out
-    ``{1,0}``: the rows the gathers and scatters want, no transposed
-    default to copy out of and back into."""
+    row-major, a bucket's 48 lane tiles six whole ``(8,128)`` memory
+    tiles on an untiled leading axis: the rows the gathers and the
+    placement kernel want, no transposed default to copy out of and
+    back into, and no 2-D twin of the plane anywhere."""
     import re
 
     text, ecfg = wide_row_round
-    n, sw = ecfg.mb.n_buckets_padded, ecfg.mb.stored_row_words
-    assert (n, sw) == (1 << 14, 6144)
+    plane = _mb_plane(ecfg)
     head = text.split("\n", 1)[0]
-    layouts = re.findall(rf"u32\[{n},{sw}\]\{{([\d,]+)", head)
-    assert layouts == ["1,0", "1,0"]  # parameter and result
+    layouts = re.findall(re.escape(plane) + r"\{([\d,]+:T\(8,128\))", head)
+    assert layouts == ["2,1,0:T(8,128)"] * 2  # parameter and result
+    n = ecfg.mb.n_buckets_padded
     assert f"u32[{n},{ecfg.mb.val_row_words}]" not in text
+    assert f"u32[{n},{ecfg.mb.stored_row_words}]" not in text
 
 
 def test_no_op_of_the_rounds_copies_or_relays_the_mailbox_plane(
     wide_row_round
 ):
     text, ecfg = wide_row_round
-    plane = f"u32[{ecfg.mb.n_buckets_padded},{ecfg.mb.stored_row_words}]"
+    plane = _mb_plane(ecfg)
     touching = [
         (name, opcode) for name, shape, opcode, rest in _entry_ops(text)
         if plane in shape or plane in rest.split(", metadata=")[0]
     ]
-    assert touching  # the gathers and the two scatters are there
+    assert touching  # the gathers and the two placements are there
     assert not [
         t for t in touching
         if t[1] in ("copy", "transpose", "reshape", "copy-start", "pad")
     ]
 
 
+def _placements(text, plane):
+    return [
+        (name, rest) for name, shape, opcode, rest in _entry_ops(text)
+        if shape.startswith(plane) and opcode == "custom-call"
+        and "path_scatter/jit(place_rows)" in rest
+    ]
+
+
 def test_both_mailbox_scatters_write_into_their_operand(wide_row_round):
-    """Each ``path_scatter`` of the value plane is a fusion whose output
-    is aliased onto operand 0 (the plane it was handed: the parameter
-    itself in round A, round A's result in round C), so a write-back
-    touches the rows it writes and not the plane."""
+    """Each ``path_scatter`` of the value plane is the placement
+    kernel (oblivious/pallas_place.py), a Mosaic custom call whose
+    output is aliased onto the plane it was handed, its last operand:
+    the parameter itself in round A, round A's result in round C. So a
+    write-back touches the rows it places and not the plane."""
     import re
 
     text, ecfg = wide_row_round
-    plane = f"u32[{ecfg.mb.n_buckets_padded},{ecfg.mb.stored_row_words}]"
-    scatters = [
-        (name, rest) for name, shape, opcode, rest in _entry_ops(text)
-        if shape.startswith(plane) and opcode == "fusion"
-        and "path_scatter/scatter" in rest
-    ]
-    assert len(scatters) == 2
-    for _, rest in scatters:
+    placed = _placements(text, _mb_plane(ecfg))
+    assert len(placed) == 2
+    for _, rest in placed:
+        assert 'custom_call_target="tpu_custom_call"' in rest
+        # output {} is operand 2 (targets 0, rows 1, the plane 2)
         assert re.search(
-            r'"aliasing_operands":\{"lists":\[\{"indices":\["0",', rest)
-    first, second = (rest.split(",")[0] for _, rest in scatters)
-    assert "state_mb_tree_val" in first
-    assert second.lstrip("%") == scatters[0][0]
+            r"output_to_operand_aliasing=\{\{\}: \(2, \{\}\)\}", rest)
+    planes = [rest.split(", custom_call_target")[0].split(", ")[-1].rstrip(")")
+              for _, rest in placed]
+    assert "state_mb_tree_val" in planes[0]
+    assert planes[1].lstrip("%") == placed[0][0]
+
+
+def test_no_scatter_sort_or_permute_of_the_value_plane_is_left(
+    wide_row_round
+):
+    """What PR 46 took out of the round: XLA's scatter of a share of
+    rows this large sorted the targets, permuted the rows into a second
+    copy and streamed the whole plane (9.0 + 2.2 ms a pass at 2^21 /
+    2^17). Nothing under ``path_scatter`` now makes an array the size
+    of the plane or of a pass's rows but the two placements, and the
+    records plane, stored ``(8, 128)`` by the same rule, goes the same
+    way."""
+    text, ecfg = wide_row_round
+    plane, rows = _mb_plane(ecfg), _mb_rows(ecfg)
+    under_scatter = [
+        (name, shape, opcode) for name, shape, opcode, rest in _entry_ops(text)
+        if "path_scatter" in rest and shape.startswith((plane,) + rows)
+    ]
+    assert [op for _, _, op in under_scatter] == ["custom-call"] * 2
+    assert not [
+        name for name, shape, opcode, rest in _entry_ops(text)
+        if opcode == "sort" and "path_scatter" in rest
+    ]
+    rec = (f"u32[{ecfg.rec.n_buckets_padded},"
+           f"{','.join(map(str, ecfg.rec.stored_row_shape))}]")
+    assert ecfg.rec.stored_row_shape == (8, 128)
+    assert len(_placements(text, rec)) == 1
+    assert not [
+        name for name, shape, opcode, rest in _entry_ops(text)
+        if shape.startswith(rec) and opcode == "fusion"
+        and "path_scatter" in rest
+    ]
 
 
 def test_the_stored_row_adds_no_pass_over_the_fetched_rows(wide_row_round):
     """The cut after the decrypt is a bitcast (a ``[R,6080]`` row is 48
-    tiles already) and the pad before the encrypt is the kernel's own
-    (test_cipher_kernel_pads_the_mailbox_plaintext_itself): no ``pad``,
-    ``slice`` or ``copy`` of the fetched rows' size is in the round. An
-    unfused one is a 1.2 ms pass at 2^17 recipients, four a round."""
+    tiles already), the pad before the encrypt is the kernel's own
+    (test_cipher_kernel_pads_the_mailbox_plaintext_itself), and the
+    rows go between ``[R,48,128]`` and ``[R,6144]`` inside the cipher
+    kernel (test_cipher_kernel_compiles_on_rows_as_the_plane_stores_
+    them): no ``pad``, ``slice``, ``copy`` or relaying fusion of the
+    fetched rows' size is in the round. One is a 1.2-1.6 ms pass at
+    2^17 recipients, four a round."""
     text, ecfg = wide_row_round
-    rows = ecfg.mb.fetched_bucket_rows(4096)
-    assert rows == (1 << 13) - (1 << 4) + 4096
-    wide = (f"u32[{rows},{ecfg.mb.stored_row_words}]",
-            f"u32[{rows},{ecfg.mb.val_row_words}]")
     made = [
         (name, opcode) for name, shape, opcode, _ in _entry_ops(text)
-        if shape.startswith(wide)
+        if shape.startswith(_mb_rows(ecfg))
     ]
     assert made
-    assert not [m for m in made if m[1] in ("pad", "slice", "copy", "transpose")]
+    assert not [
+        m for m in made
+        if m[1] in ("pad", "slice", "copy", "transpose")
+        or m[0].startswith(("copy", "transpose"))
+    ]
+
+
+# ----------------------------------------------------------------------
+# the placement kernel alone (PR 46), at the shapes of 2^21 messages /
+# 2^17 recipients. ISSUE 46's first gate.
+# ----------------------------------------------------------------------
+
+
+@pytest.mark.parametrize(
+    "n,tiles,rows", [(1 << 16, 48, 20464), (1 << 21, 8, 22512)],
+    ids=["mailbox", "records"],
+)
+def test_placement_kernel_compiles_for_v5e(one_chip, n, tiles, rows):
+    """One DMA a row into the donated plane: a Mosaic kernel whose
+    output is the plane it was handed, and nothing else the size of the
+    plane or of the rows in the program."""
+    text = _compile_for(
+        one_chip, place_rows, _s(n, tiles, 128), _s(rows),
+        _s(rows, tiles, 128), donate=(0,), interpret=False,
+    )
+    assert "tpu_custom_call" in text
+    assert "output_to_operand_aliasing={{}: (2, {})}" in text
+    assert " copy(" not in text and " fusion(" not in text
+
+
+def test_mosaic_refuses_a_one_row_window_of_a_2d_plane(one_chip):
+    """Why the plane is stored ``[n, tiles, 128]``: in HBM a 2-D
+    ``u32[n, W]`` is ``T(8,128)``-tiled, a bucket's row 48 pieces of
+    512 B at a stride of 4 KB, and Mosaic takes a window of a tiled
+    dimension only in whole tiles. If this compile ever passes, the
+    plane can go back to two dimensions (PERF.md section 6, PR 46)."""
+    with pytest.raises(Exception, match=r"aligned to tiling \(8\)"):
+        _compile_for(
+            one_chip, place_rows, _s(1 << 16, 6144), _s(20464),
+            _s(20464, 6144), interpret=False,
+        )
+
+
+def test_the_mesh_round_places_by_the_same_kernel(one_chip):
+    """Under ``shard_map`` over the described 2x2 the round is the
+    one-chip program on each chip's quarter of the planes: the same
+    placement kernel, aliased onto the local shard, three to a round
+    (two mailbox passes, one records pass), the ``mine`` predicate the
+    only difference (it is folded into the targets before the kernel),
+    and no scatter fusion over a value plane's shard."""
+    import re
+
+    from jax.experimental import topologies
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from grapevine_tpu.config import GrapevineConfig
+    from grapevine_tpu.parallel import mesh as pm
+
+    topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    mesh = Mesh(topo.devices, (pm.TREE_AXIS,))
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(jax, "default_backend", lambda: "tpu")
+        ecfg, state, batch = _round_specs(GrapevineConfig(
+            max_messages=1 << 16, max_recipients=1 << 15, batch_size=2048,
+            tree_density=2, shards=4,
+        ))
+        sharded = jax.tree.map(
+            lambda spec, s: jax.ShapeDtypeStruct(
+                s.shape, s.dtype, sharding=NamedSharding(mesh, spec)),
+            pm.engine_state_specs(), state,
+            is_leaf=lambda s: isinstance(s, P))
+        whole = jax.tree.map(
+            lambda s: jax.ShapeDtypeStruct(
+                s.shape, s.dtype, sharding=NamedSharding(mesh, P())),
+            batch)
+        text = pm.make_sharded_step(ecfg, mesh).lower(
+            sharded, whole).compile().as_text()
+    local = {"mb": f"u32[{ecfg.mb.n_buckets_padded // 4},48,128]",
+             "rec": f"u32[{ecfg.rec.n_buckets_padded // 4},8,128]"}
+    placed = re.findall(
+        r"= (u32\[[\d,]+\])\S* custom-call\([^\n]*"
+        r'custom_call_target="tpu_custom_call"[^\n]*'
+        r"output_to_operand_aliasing=\{\{\}: \(2, \{\}\)\}[^\n]*"
+        r"path_scatter/jit\(place_rows\)", text)
+    assert sorted(placed) == sorted([local["mb"]] * 2 + [local["rec"]])
+    assert not re.search(
+        r"= (?:%s)\S* fusion\([^\n]*path_scatter" % "|".join(
+            map(re.escape, local.values())), text)

@@ -233,6 +233,54 @@ def audit_lookup_remap(allowlist, occ_impl: str, sort_impl: str,
     )
 
 
+def audit_dma_write_back():
+    """The write-back of a wide value plane as a TPU traces it: the
+    row-placement kernel of oblivious/pallas_place.py under
+    ``_path_scatter``, on one chip and under ``shard_map``. With the
+    CONTENTS secret (the rows written and the plane written into) and
+    the allowlist empty, nothing may reach a sink: the kernel's DMA
+    targets and its skip predicate are functions of the path, the owner
+    mask and the chip's index alone. (That the path itself may decide
+    them is the scatter's reviewed argument, allowlist.py
+    ``scatter@oram/path_oram.py:_path_scatter``; the whole-round audits
+    above run on the CPU, which keeps the jnp scatter, and hold that
+    form to it.)"""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import PartitionSpec as P
+
+    from grapevine_tpu.analysis.jaxpr_walk import as_a_tpu_traces
+    from grapevine_tpu.analysis.oblint import analyze
+    from grapevine_tpu.oram.path_oram import _path_scatter
+    from grapevine_tpu.parallel.mesh import TREE_AXIS, make_mesh
+
+    def sds(shape, dtype=jnp.uint32):
+        return jax.ShapeDtypeStruct(shape, dtype)
+
+    n, rows = 64, 20
+    args = {"tree": sds((n, 8, 128)), "path_b": sds((rows,)),
+            "vals": sds((rows, 1024)), "owner": sds((rows,), jnp.bool_)}
+    forms = {"one_chip": lambda tree, path_b, vals, owner: _path_scatter(
+        tree, path_b, vals, None, owner)}
+    if len(jax.devices()) >= 2:
+        forms["sharded"] = jax.shard_map(
+            lambda tree, path_b, vals, owner: _path_scatter(
+                tree, path_b, vals, TREE_AXIS, owner),
+            mesh=make_mesh(jax.devices()[:2]),
+            in_specs=(P(TREE_AXIS), P(), P(), P()), out_specs=P(TREE_AXIS),
+            check_vma=False,
+        )
+    reports = []
+    with as_a_tpu_traces():
+        for form, fn in forms.items():
+            rep = analyze(fn, args, secrets=("tree", "vals"), allowlist=(),
+                          name=f"dma_write_back/{form}")
+            # else the kernel did not engage and the walk saw the scatter
+            assert rep.census.get("dma_start"), rep.census
+            reports.append(rep)
+    return reports
+
+
 def census_variants(ecfg):
     """Adversarially different CONCRETE batches for the program-equality
     check: the full engine round must trace to the identical program
@@ -364,6 +412,9 @@ def run_audit(combos, allowlist=None, with_census="first",
                 allowlist, occ_impl=vp, sort_impl=srt,
                 recursive=(pmi == "recursive"),
             ))
+    if with_subrounds:
+        for rep in audit_dma_write_back():
+            absorb(rep)
     if with_census:
         census_combos = combos if with_census == "all" else combos[:1]
         for vp, srt, pmi, k in census_combos:

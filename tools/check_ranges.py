@@ -165,14 +165,20 @@ def _oram_cfg(log2_blocks: int, recursive: bool, k: int):
 
 
 def audit_sharded_path_scatter(allowlist, log2_blocks: int,
-                               shards: int = 2):
+                               shards: int = 2, by_dma: bool = False):
     """Interval-audit the owner-masked write-back of the sharded round
     (``_path_scatter`` under ``shard_map`` over a bucket-axis mesh):
     the lanes only the mesh has — ``axis_index`` (bounded
     [0, shards-1] by the rangelint mesh rule) and the per-chip rebase,
     whose non-owned lanes wrap mod 2^32 by construction and land on the
     drop sentinel, a reviewed RANGE_ALLOWLIST pair. Trace-only, like
-    every audit here."""
+    every audit here.
+
+    ``by_dma`` audits the form a TPU traces for a wide value plane (a
+    row of eight lane tiles, stored ``(8, 128)``): the same rebase and
+    cast feed the row-placement kernel (oblivious/pallas_place.py),
+    whose own arithmetic (the semaphore a row's copy takes, the row it
+    waits for) must stay in range with no entry of its own."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
@@ -181,8 +187,14 @@ def audit_sharded_path_scatter(allowlist, log2_blocks: int,
     from grapevine_tpu.oram.path_oram import _path_scatter
     from grapevine_tpu.parallel.mesh import TREE_AXIS, make_mesh
 
+    import contextlib
+
+    from grapevine_tpu.analysis.jaxpr_walk import as_a_tpu_traces
+
     cfg = _oram_cfg(log2_blocks, False, 0)
     n, w, rows = cfg.n_buckets_padded, cfg.bucket_slots, 4 * cfg.path_len
+    stored = (8, 128) if by_dma else (w,)
+    w = 1024 if by_dma else w
 
     def sds(shape, dtype=jnp.uint32):
         return jax.ShapeDtypeStruct(shape, dtype)
@@ -194,14 +206,18 @@ def audit_sharded_path_scatter(allowlist, log2_blocks: int,
         in_specs=(P(TREE_AXIS), P(), P(), P()), out_specs=P(TREE_AXIS),
         check_vma=False,
     )
-    return analyze_ranges(
-        fn,
-        {"tree": sds((n, w)), "path_b": sds((rows,)),
-         "vals": sds((rows, w)), "owner": sds((rows,), jnp.bool_)},
-        bounds={"path_b": (0, n - 1)},
-        allowlist=allowlist,
-        name=f"sharded_path_scatter/2^{log2_blocks}_s{shards}",
-    )
+    with as_a_tpu_traces() if by_dma else contextlib.nullcontext():
+        rep = analyze_ranges(
+            fn,
+            {"tree": sds((n, *stored)), "path_b": sds((rows,)),
+             "vals": sds((rows, w)), "owner": sds((rows,), jnp.bool_)},
+            bounds={"path_b": (0, n - 1)},
+            allowlist=allowlist,
+            name=(f"sharded_path_scatter/2^{log2_blocks}_s{shards}"
+                  + ("_dma" if by_dma else "")),
+        )
+    assert bool(rep.census.get("dma_start")) == by_dma, rep.census
+    return rep
 
 
 def audit_oram_round(allowlist, log2_blocks: int, occ_impl: str,
@@ -350,6 +366,8 @@ def run_audit(combos, geometry: int, allowlist=None, verbose=False,
             # 2 shards is where every sharded-only lane (axis_index,
             # the _path_scatter rebase) exists
             absorb(audit_sharded_path_scatter(allowlist, geometry))
+            absorb(audit_sharded_path_scatter(
+                allowlist, geometry, by_dma=True))
         else:  # pragma: no cover - bootstrap in main()
             problems.append(
                 "sharded scatter audit needs >= 2 devices (got 1) — "
@@ -440,9 +458,11 @@ def main(argv=None) -> int:
         if len(jax.devices()) >= 2:
             # always-on sharded lane coverage (trace-only): the
             # owner-masked write-back's rebase arithmetic at toy geometry
-            rep = audit_sharded_path_scatter(RANGE_ALLOWLIST, 5)
-            print(rep.summary())
-            problems.extend(f"{rep.name}: {f}" for f in rep.findings)
+            for by_dma in (False, True):
+                rep = audit_sharded_path_scatter(
+                    RANGE_ALLOWLIST, 5, by_dma=by_dma)
+                print(rep.summary())
+                problems.extend(f"{rep.name}: {f}" for f in rep.findings)
         dp, refusal = certify_design_point(DESIGN_POINT)
         problems.extend(dp)
         if refusal:

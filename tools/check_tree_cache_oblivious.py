@@ -17,7 +17,10 @@ facts, not runtime sampling):
 
 2. **HBM row-count accounting.** Every gather/scatter whose operand is
    one of the big HBM tree planes (``tree_idx`` u32[n·Z], ``tree_val``
-   u32[n, Z·V], ``nonces`` u32[n, 2], ``tree_leaf`` u32[n·Z]) must move
+   u32[n, Z·V] or, a wide row, u32[n, tiles, 128], ``nonces`` u32[n, 2],
+   ``tree_leaf`` u32[n·Z]), and the row-placement kernel that stands in
+   for a wide plane's scatter on a TPU (counted as rows written, one
+   DMA each: analysis/jaxpr_walk.py ``plane_rows``), must move
    exactly ``(2^Ld − 2^k) + B·(path_len − Ld)`` bucket rows, where
    ``Ld = clamp(floor(log2 B) + 1, k, path_len)`` is the count of
    levels the batch covers (ISSUE 26: such a level is moved whole,
@@ -113,7 +116,7 @@ def _tree_planes(cfg) -> dict:
     # accounting matches on are the 2-D views at divisor 1
     planes = {
         "tree_idx": ((n, z), 1),
-        "tree_val": ((n, sw), 1),
+        "tree_val": ((n, *cfg.stored_row_shape), 1),
         "nonces": ((n, 2), 1),
     }
     if cfg.posmap is not None:
