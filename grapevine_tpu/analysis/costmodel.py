@@ -346,7 +346,9 @@ def traced_scan_rows(jaxpr, chunk_planes: dict) -> dict:
             return shape
         return None
 
-    for eqn in walk_eqns(jaxpr):
+    # a kernel's own loops run over blocks in VMEM, and a toy plane can
+    # be one block
+    for eqn in walk_eqns(jaxpr, into_kernels=False):
         if eqn.primitive.name != "scan":
             continue
         for side, variables in enumerate((eqn.invars, eqn.outvars)):
@@ -422,18 +424,24 @@ def trace_engine_round(ecfg):
     )(state, _engine_batch_spec(ecfg))
 
 
-def trace_expiry_sweep(ecfg):
+def trace_expiry_sweep(ecfg, kernel: bool = False):
+    """Jaxpr of one expiry sweep; ``kernel``: as a TPU traces it, the
+    chunk's two cipher passes the Pallas kernel (the value plane then
+    leaves and re-enters the scan's carry through the kernel's aliased
+    output, where the jnp form cuts and pastes it)."""
     import jax
     import numpy as np
 
     from ..engine.expiry import expiry_sweep
     from ..engine.state import init_engine
+    from .jaxpr_walk import cipher_form
 
     state = jax.eval_shape(lambda: init_engine(ecfg, 0))
     scalar = jax.ShapeDtypeStruct((), np.uint32)
-    return jax.make_jaxpr(
-        lambda st, now, per, nh: expiry_sweep(ecfg, st, now, per, nh)
-    )(state, scalar, scalar, scalar)
+    with cipher_form(ecfg, kernel) as ecfg:
+        return jax.make_jaxpr(
+            lambda st, now, per, nh: expiry_sweep(ecfg, st, now, per, nh)
+        )(state, scalar, scalar, scalar)
 
 
 # -- cross-validation: the two derivations must agree bit-exactly -------
@@ -500,11 +508,14 @@ def cross_validate_engine_round(ecfg, *, _corrupt=None) -> dict:
     )
 
 
-def cross_validate_sweep(ecfg, *, _corrupt=None) -> dict:
+def cross_validate_sweep(ecfg, *, kernel: bool = False,
+                         _corrupt=None) -> dict:
     """The expiry sweep: per chunk-shape class, the scan-streamed rows
     equal one full pass over each tree plane (reads) and one write pass
     over the idx/val/leaf planes (the nonce re-key is a broadcast store
-    outside the scan — priced in the ledger, not checkable here)."""
+    outside the scan — priced in the ledger, not checkable here).
+    ``kernel``: the trace a TPU makes, the cipher kernel in the scan's
+    body (:func:`trace_expiry_sweep`); the same planes, once each."""
     chunk = {**sweep_chunk_planes(ecfg.rec, "rec_"),
              **sweep_chunk_planes(ecfg.mb, "mb_")}
     pred_rows = expiry_sweep_rows(ecfg)
@@ -522,8 +533,8 @@ def cross_validate_sweep(ecfg, *, _corrupt=None) -> dict:
         predicted[tuple(chunk_shape)] = (g + pr.gather_rows, s + writes)
     return _compare(
         predicted,
-        traced_scan_rows(trace_expiry_sweep(ecfg), chunk),
-        "expiry_sweep",
+        traced_scan_rows(trace_expiry_sweep(ecfg, kernel), chunk),
+        "expiry_sweep" + ("/kernel" if kernel else ""),
     )
 
 

@@ -39,6 +39,27 @@ def as_a_tpu_traces():
         yield
 
 
+@contextlib.contextmanager
+def cipher_form(ecfg, kernel: bool):
+    """Trace-only: the engine config an audit traces a program under.
+    ``kernel`` false: ``ecfg`` as it is (on the CPU the jnp keystream).
+    True: both trees' at-rest cipher resolved as on a TPU
+    (``cipher_impl`` "pallas"), inside :func:`as_a_tpu_traces`, so the
+    program holds the kernel of oblivious/pallas_cipher.py
+    un-interpreted and the audit walks its body."""
+    if not kernel:
+        yield ecfg
+        return
+    import dataclasses
+
+    with as_a_tpu_traces():
+        yield dataclasses.replace(
+            ecfg,
+            rec=dataclasses.replace(ecfg.rec, cipher_impl="pallas"),
+            mb=dataclasses.replace(ecfg.mb, cipher_impl="pallas"),
+        )
+
+
 def _sub_jaxprs(eqn):
     """Every jaxpr-valued param of ``eqn`` (pjit bodies, scan/while/cond
     branches, custom-call wrappers), in a stable order."""
@@ -49,13 +70,17 @@ def _sub_jaxprs(eqn):
                 yield x
 
 
-def walk_eqns(jaxpr):
-    """Yield every equation, recursing into every sub-jaxpr."""
+def walk_eqns(jaxpr, into_kernels: bool = True):
+    """Yield every equation, recursing into every sub-jaxpr; not into a
+    Pallas kernel's body where ``into_kernels`` is false (its operands
+    are blocks in VMEM, which a count of HBM planes must not meet)."""
     inner = getattr(jaxpr, "jaxpr", jaxpr)
     for eqn in inner.eqns:
         yield eqn
+        if not into_kernels and eqn.primitive.name == "pallas_call":
+            continue
         for sub in _sub_jaxprs(eqn):
-            yield from walk_eqns(sub)
+            yield from walk_eqns(sub, into_kernels)
 
 
 def census(jaxpr) -> Counter:

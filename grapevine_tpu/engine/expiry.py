@@ -15,7 +15,18 @@ With the at-rest bucket cipher enabled, each tree is processed in row
 chunks under ``lax.scan``: decrypt chunk → expire → re-encrypt under the
 next epoch, all inside one scan body — at no point does more than one
 chunk of plaintext exist in HBM (a mid-sweep memory snapshot exposes at
-most ~8 M words, not the bus).
+most ~8 M words, not the bus). Both cipher passes of a chunk go through
+``path_oram.cipher_rows``, the one entry point of the at-rest cipher,
+as the rounds' fetch and write-back do: on a TPU that is the Pallas
+kernel of oblivious/pallas_cipher.py, which makes the keystream in VMEM,
+reads the chunk's rows where they lie in the value plane and writes the
+re-keyed rows back over them (the plane rides the scan's carry and is
+aliased through the kernel: one copy of a tree, and the chunk of
+plaintext between the two passes is all that is ever written beside
+it); on the CPU it is the jnp keystream, bit for bit the same state
+(tests/test_bucket_cipher.py). The recursive position map's leaf plane
+(``Z`` words a row, under a domain-separated bucket word) keeps the jnp
+``row_keystream``: 0.4 % of a tree's bytes.
 
 On a mesh (``axis_name`` set, under ``parallel.make_sharded_sweep``'s
 shard_map) each chip sweeps the contiguous heap range of buckets it
@@ -32,15 +43,17 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from ..oblivious.bucket_cipher import (
-    epoch_next,
-    row_keystream,
-    row_plane_keystreams,
-)
+from ..oblivious.bucket_cipher import epoch_next, row_keystream
 from ..oblivious.primitives import SENTINEL, is_zero_words, u64_le, u64_sub
 from ..oblivious.radix import partition_rank
 from ..obs.phases import device_phase
-from ..oram.path_oram import OramConfig, OramState, logical_rows, stored_rows
+from ..oram.path_oram import (
+    OramConfig,
+    OramState,
+    cipher_rows,
+    logical_rows,
+    stored_rows,
+)
 from .state import (
     ENT_SEQ,
     ENT_SEQH,
@@ -134,13 +147,13 @@ def _chunked_tree_sweep(cfg: OramConfig, oram: OramState, carry0, body,
             else jax.lax.axis_index(axis_name).astype(U32) * U32(n))
     recrypt_leaf = cfg.posmap is not None and cfg.encrypted
 
-    # The planes ride the scan's carry and each chunk is cut out of them
+    # The planes ride the scan's carry and each chunk is read out of them
     # and written back over itself, so the program holds one copy of a
     # tree. Handed to the scan as stacked inputs and outputs they would
     # be two, and the records plane of 2^21 messages is 8 GiB: a second
     # copy does not fit the chip beside the state (PERF.md section 6,
-    # PR 36). Slots are cut from the flat slot planes, rows from the
-    # value plane.
+    # PR 36). Slots are cut from the flat slot planes; the value plane's
+    # rows are cipher_rows' to read and write in place (``chunk``).
     def cut(plane, i, width):
         return jax.lax.dynamic_slice_in_dim(plane, i * U32(width), width)
 
@@ -151,41 +164,36 @@ def _chunked_tree_sweep(cfg: OramConfig, oram: OramState, carry0, body,
     def scan_body(carry, i):
         acc, idx_p, val_p, leaf_p = carry
         bid = base + i * U32(rpc) + jnp.arange(rpc, dtype=U32)
-        ix = cut(idx_p, i, rpc * z).reshape(rpc, z)
-        vl = cut(val_p, i, rpc).reshape(rpc, -1)  # rows, however stored
         ep = cut(oram.nonces, i, rpc)
-        if cfg.encrypted:
-            ks_ix, ks_vl = row_plane_keystreams(
-                oram.cipher_key, bid, ep, z, cfg.row_words, cfg.cipher_rounds
-            )
-            ix = ix ^ ks_ix
-            vl = vl ^ ks_vl
+        epn = jnp.broadcast_to(oram.epoch[None, :], (rpc, 2))
+        # the chunk's rows go in as they lie in the plane and flat
+        # plaintext comes back: cipher_rows' fetch direction, and the
+        # identity where the cipher is off
+        ix, vl = cipher_rows(
+            cfg, oram.cipher_key, bid, ep,
+            cut(idx_p, i, rpc * z).reshape(rpc, z), val_p, chunk=i,
+        )
         # the body sees the blocks' Z*V words; the stored row's pad
-        # words leave as the zeros they are in plaintext
+        # words leave as the zeros they are in plaintext, and
+        # cipher_rows' write-back direction stores their keystream,
+        # over the rows the chunk was read from
         acc, (ix, vl) = body(acc, (ix, logical_rows(cfg, vl)))
-        vl = stored_rows(cfg, vl)
-        if cfg.encrypted:
-            epn = jnp.broadcast_to(oram.epoch[None, :], (rpc, 2))
-            ks_ix, ks_vl = row_plane_keystreams(
-                oram.cipher_key, bid, epn, z, cfg.row_words, cfg.cipher_rounds
+        ix, val_p = cipher_rows(
+            cfg, oram.cipher_key, bid, epn, ix, vl, chunk=i, plane=val_p)
+        if recrypt_leaf:
+            # leaf-plane stream: same (bucket, epoch), bucket word
+            # offset by n_buckets_padded (path_oram.leaf_plane_cipher
+            # domain separation)
+            boff = bid + U32(cfg.n_buckets_padded)
+            lf = cut(leaf_p, i, rpc * z).reshape(rpc, z)
+            lf = lf ^ row_keystream(
+                oram.cipher_key, boff, ep, z, cfg.cipher_rounds
             )
-            ix = ix ^ ks_ix
-            vl = vl ^ ks_vl
-            if recrypt_leaf:
-                # leaf-plane stream: same (bucket, epoch), bucket word
-                # offset by n_buckets_padded (path_oram.leaf_plane_cipher
-                # domain separation)
-                boff = bid + U32(cfg.n_buckets_padded)
-                lf = cut(leaf_p, i, rpc * z).reshape(rpc, z)
-                lf = lf ^ row_keystream(
-                    oram.cipher_key, boff, ep, z, cfg.cipher_rounds
-                )
-                lf = lf ^ row_keystream(
-                    oram.cipher_key, boff, epn, z, cfg.cipher_rounds
-                )
-                leaf_p = paste(leaf_p, i, rpc * z, lf.reshape(-1))
+            lf = lf ^ row_keystream(
+                oram.cipher_key, boff, epn, z, cfg.cipher_rounds
+            )
+            leaf_p = paste(leaf_p, i, rpc * z, lf.reshape(-1))
         idx_p = paste(idx_p, i, rpc * z, ix.reshape(-1))
-        val_p = paste(val_p, i, rpc, vl.reshape(rpc, *val_p.shape[1:]))
         return (acc, idx_p, val_p, leaf_p), None
 
     # the leaf plane rides along only where it is re-keyed

@@ -254,7 +254,10 @@ def epoch_next(epoch: jax.Array) -> jax.Array:
 
 
 # NOTE: whole-tree passes (the expiry sweep) decrypt/re-encrypt entire
-# rows chunk-by-chunk via engine/expiry.py:_chunked_tree_sweep; there is
-# no partial-word decrypt API on purpose — CTR-mode random access would
+# rows chunk-by-chunk via engine/expiry.py:_chunked_tree_sweep, through
+# the same entry point as the rounds (oram/path_oram.py cipher_rows:
+# the Pallas kernel on a TPU, reading and writing each chunk where it
+# lies in the plane; this module's keystream on the CPU); there is no
+# partial-word decrypt API on purpose — CTR-mode random access would
 # permit one, but nothing uses it and the sweep's cost model is the
 # full-row recrypt documented there.

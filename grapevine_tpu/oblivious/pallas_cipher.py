@@ -29,6 +29,13 @@ bucket_cipher's ``chacha_words``, so the implementations cannot drift;
 bit-identical ciphertext is asserted by tests/test_pallas_cipher.py,
 making engine states interchangeable between impls.
 
+Callers, all through ``path_oram.cipher_rows``: the rounds' fetch and
+write-back (rows gathered from the planes, and rows on their way to the
+scatter) and, since PR 48, the expiry sweep's two passes over each
+chunk of a tree (engine/expiry.py), which name their rows by ``chunk``:
+the kernel then reads them from the plane itself and writes them back
+into it, the plane aliased onto the output (``cipher_rows_pallas``).
+
 Off-TPU the kernel runs in Pallas interpret mode (CI's CPU backend —
 the SGX_MODE=SW analog), so the selection knob is safe everywhere; on a
 TPU Mosaic compiles it and the engine resolves to it by default.
@@ -131,6 +138,8 @@ def cipher_rows_pallas(
     interpret: bool = False,
     zv: int | None = None,
     tiled_out: bool = False,
+    chunk: jax.Array | None = None,  # scalar: which R rows of a plane
+    into: jax.Array | None = None,  # the plane the value rows go into
 ):
     """Fused ``row ^ keystream``; returns (pidx', pval'), both u32.
 
@@ -143,7 +152,19 @@ def cipher_rows_pallas(
     them: ``[R, tiles, 128]``, each row whole memory tiles
     (``OramConfig.stored_row_shape``). The kernel then reads or writes
     those words where they lie, so neither direction of the round pays a
-    pass to bring rows cut from a plane to ``[R, zv]`` or back."""
+    pass to bring rows cut from a plane to ``[R, zv]`` or back.
+
+    With ``chunk`` the ``R`` rows are rows ``[chunk * R, (chunk + 1) *
+    R)`` of a whole plane, read and written where they lie (the expiry
+    sweep's pass over a tree, engine/expiry.py): a Pallas call takes no
+    ``dynamic_slice`` as a fused producer and no ``dynamic_update_slice``
+    as a fused consumer, so a chunk cut out and pasted back is a copy
+    each way. On the way in ``pval`` is the plane itself and the value
+    blocks' index map starts at the chunk's first row block (``chunk``
+    is scalar-prefetched). On the way out ``into`` is the plane: it is
+    aliased onto the value output, whose blocks land on the chunk's
+    rows, and comes back in place of the rows; every other row keeps its
+    contents through the aliasing and no step reads the plane."""
     r, z = pidx.shape
     tiled_in = pval.ndim == 3
     zin = pval.shape[1] * (LANES if tiled_in else 1)
@@ -152,36 +173,80 @@ def cipher_rows_pallas(
     # the row tile is the second-minor block dim of every operand: a
     # multiple of the u32 sublane count, no larger than the rows need
     tr = min(_ROW_TILE, -(-r // sub) * sub)
-    row_block = lambda width: pl.BlockSpec((tr, width), lambda i: (i, 0))  # noqa: E731
+    # an index map takes the grid step and, with ``chunk``, the
+    # prefetched scalar's ref after it; the row block of a step: in an
+    # array of the R rows, and in a whole plane
+    here = lambda i, *_: i  # noqa: E731
+    row_block = lambda width: pl.BlockSpec(  # noqa: E731
+        (tr, width), lambda i, *_: (i, 0))
+    if chunk is not None:
+        plane = pval if into is None else into
+        if r % tr and plane.shape[0] != r:
+            raise ValueError(
+                f"a chunk of {r} rows is not whole row tiles of {tr}")
+        there = lambda i, first: first[0] * (r // tr) + i  # noqa: E731
 
-    def val_block(width, tiled):
-        """(block, array shape) of value rows ``width`` words wide."""
+    def val_block(width, tiled, rows, at):
+        """(block, array shape) of value rows ``width`` words wide in
+        an array of ``rows`` rows; ``at``: a step's row block there."""
         if not tiled:
-            return row_block(width), (r, width)
+            return (pl.BlockSpec((tr, width), lambda *a: (at(*a), 0)),
+                    (rows, width))
         tiles = width // LANES
-        return (pl.BlockSpec((tr, tiles, LANES), lambda i: (i, 0, 0)),
-                (r, tiles, LANES))
+        return (pl.BlockSpec((tr, tiles, LANES), lambda *a: (at(*a), 0, 0)),
+                (rows, tiles, LANES))
 
-    in_block, _ = val_block(zin, tiled_in)
-    out_block, out_shape = val_block(zv, tiled_out)
+    in_block, _ = val_block(
+        zin, tiled_in, pval.shape[0],
+        there if chunk is not None and into is None else here)
+    if into is None:
+        out_block, out_shape = val_block(zv, tiled_out, r, here)
+    else:
+        out_block, out_shape = val_block(
+            zv, tiled_out, into.shape[0], there)
+    kernel = functools.partial(
+        _cipher_kernel, sub=sub, z=z, zin=zin, zv=zv, rounds=rounds
+    )
+    in_specs = [
+        pl.BlockSpec(memory_space=pltpu.SMEM),
+        # rank-1 blocks must tile by 128 on TPU; carry the bucket id
+        # as a [rows, 1] column instead so tr only needs 8-alignment
+        row_block(1),
+        row_block(2),
+        row_block(z),
+        in_block,
+    ]
+    out_specs = [row_block(z), out_block]
+    out_shapes = [
+        jax.ShapeDtypeStruct((r, z), U32),
+        jax.ShapeDtypeStruct(out_shape, U32),
+    ]
+    operands = (key, bucket[:, None], epoch, pidx, pval)
+    if chunk is None:
+        return pl.pallas_call(
+            kernel, grid=(pl.cdiv(r, tr),), in_specs=in_specs,
+            out_specs=out_specs, out_shape=out_shapes, interpret=interpret,
+        )(*operands)
+
+    def chunk_kernel(first_ref, *refs):
+        # the prefetched scalar is the index maps'; the plane handed in
+        # for the aliasing (``into``) is never read
+        del first_ref
+        kernel(*refs[:5], *refs[-2:])
+
+    aliases = {}
+    if into is not None:
+        in_specs.append(pl.BlockSpec(memory_space=pl.ANY))
+        operands += (into,)
+        # operand indices count the scalar prefetch: ``into`` is 6
+        aliases = {6: 1}
     return pl.pallas_call(
-        functools.partial(
-            _cipher_kernel, sub=sub, z=z, zin=zin, zv=zv, rounds=rounds
+        chunk_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(pl.cdiv(r, tr),),
+            in_specs=in_specs, out_specs=out_specs,
         ),
-        grid=(pl.cdiv(r, tr),),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            # rank-1 blocks must tile by 128 on TPU; carry the bucket id
-            # as a [rows, 1] column instead so tr only needs 8-alignment
-            row_block(1),
-            row_block(2),
-            row_block(z),
-            in_block,
-        ],
-        out_specs=[row_block(z), out_block],
-        out_shape=[
-            jax.ShapeDtypeStruct((r, z), U32),
-            jax.ShapeDtypeStruct(out_shape, U32),
-        ],
+        out_shape=out_shapes,
+        input_output_aliases=aliases,
         interpret=interpret,
-    )(key, bucket[:, None], epoch, pidx, pval)
+    )(jnp.asarray(chunk, jnp.int32).reshape(1), *operands)

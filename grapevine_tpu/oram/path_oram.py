@@ -145,6 +145,9 @@ def cipher_rows(
     epochs: jax.Array,  # u32[R, 2] per-row (lo, hi) nonce (0 = identity)
     pidx: jax.Array,  # u32[R, Z]
     pval: jax.Array,  # u32[R, *cfg.stored_row_shape], or [R, Z*V] plaintext
+    *,
+    chunk: jax.Array | None = None,  # scalar: the R rows' place in a plane
+    plane: jax.Array | None = None,  # the plane a chunk's rows go back to
 ):
     """XOR bucket rows with their keystream (encrypt ≡ decrypt).
 
@@ -167,18 +170,37 @@ def cipher_rows(
     lie. A narrow row is stored as it is computed on and the directions
     are one signature.
 
+    A whole-tree pass (the expiry sweep, engine/expiry.py) names its
+    rows by ``chunk``: rows ``[chunk * R, (chunk + 1) * R)`` of a value
+    plane. Its fetch hands over the plane itself as ``pval``; its
+    write-back hands over the plaintext and the ``plane``, and gets the
+    plane back with the rows written over that chunk. The kernel reads
+    and writes those rows where they lie in the plane (the jnp path
+    cuts them out and pastes them back, which XLA fuses with its XOR),
+    so a pass holds one copy of the plane and one chunk of plaintext.
+
     ``cfg.cipher_impl == "pallas"`` routes through the fused Pallas
     kernel (keystream generated in VMEM and XORed in one pass — no HBM
     keystream materialization; oblivious/pallas_cipher.py). Both
     implementations produce bit-identical ciphertext."""
     r = pidx.shape[0]
-    to_store = pval.ndim == 2  # plaintext on its way to the plane
+    # plaintext on its way to the plane?
+    to_store = pval.ndim == 2 if chunk is None else plane is not None
     tiled = len(cfg.stored_row_shape) == 2
+    kernel = cfg.encrypted and cfg.cipher_impl in ("pallas", "pallas_fused")
+    if chunk is not None and not kernel:
+        first = chunk * U32(r)
+        if not to_store:
+            pval = jax.lax.dynamic_slice_in_dim(pval, first, r)
+        pidx, rows = cipher_rows(cfg, key, buckets, epochs, pidx, pval)
+        if to_store:
+            rows = jax.lax.dynamic_update_slice_in_dim(plane, rows, first, 0)
+        return pidx, rows
     out_shape = (r, *cfg.stored_row_shape) if to_store else (r, -1)
     if not cfg.encrypted:
         return pidx, stored_rows(cfg, pval.reshape(r, -1)).reshape(out_shape)
     z = cfg.bucket_slots
-    if cfg.cipher_impl in ("pallas", "pallas_fused"):
+    if kernel:
         from ..oblivious.pallas_cipher import cipher_rows_pallas
 
         interpret = not _on_tpu()
@@ -202,7 +224,7 @@ def cipher_rows(
         return cipher_rows_pallas(
             key, buckets, epochs, pidx, pval, cfg.cipher_rounds,
             interpret=interpret, zv=cfg.stored_row_words,
-            tiled_out=to_store and tiled,
+            tiled_out=to_store and tiled, chunk=chunk, into=plane,
         )
     ks_idx, ks_val = row_plane_keystreams(
         key, buckets, epochs, z, cfg.row_words, cfg.cipher_rounds

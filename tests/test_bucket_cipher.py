@@ -1,5 +1,7 @@
 """BucketCipher (oblivious/bucket_cipher.py): RFC vectors + properties."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -249,3 +251,117 @@ def test_expiry_sweep_with_cipher_evicts_and_reencrypts():
         1_700_000_101,
     )[0]
     assert rd.status_code == C.STATUS_CODE_NOT_FOUND
+
+
+_T0 = 1_700_000_000
+
+#: the sweep's clocks: (now - _T0, period) and what they leave of the
+#: three waves written at _T0, _T0 + 100 and _T0 + 200
+_CLOCKS = {
+    "none": (205, 1000),
+    "some": (150, 100),  # the first wave is due; the third lies ahead
+    "all": (10_000, 10),
+}
+
+
+@functools.lru_cache(maxsize=None)
+def _populated(**geometry):
+    """(engine config resolved to jnp, the state after three waves of
+    CREATEs through a jnp engine, messages alive)."""
+    from grapevine_tpu.config import GrapevineConfig
+    from grapevine_tpu.engine.batcher import GrapevineEngine
+    from grapevine_tpu.wire import constants as C
+    from grapevine_tpu.wire.records import QueryRequest, RequestRecord
+
+    kw = dict(max_messages=64, max_recipients=8, mailbox_cap=4, batch_size=4,
+              bucket_cipher_rounds=8, bucket_cipher_impl="jnp",
+              expiry_period=10)
+    cfg = GrapevineConfig(**{**kw, **geometry})
+    engine = GrapevineEngine(cfg, seed=11)
+    for wave in range(3):
+        resps = engine.handle_queries(
+            [
+                QueryRequest(
+                    request_type=C.REQUEST_TYPE_CREATE,
+                    auth_identity=bytes([i + 1]) * 32,
+                    auth_signature=b"\x01" * C.SIGNATURE_SIZE,
+                    record=RequestRecord(
+                        msg_id=C.ZERO_MSG_ID,
+                        recipient=bytes([(i + wave) % 5 + 1]) * 32,
+                        payload=bytes([16 * wave + i]) * C.PAYLOAD_SIZE,
+                    ),
+                )
+                for i in range(cfg.batch_size)
+            ],
+            _T0 + 100 * wave,
+        )
+        assert all(r.status_code == C.STATUS_CODE_SUCCESS for r in resps)
+    return engine.ecfg, engine.state, engine.message_count()
+
+
+@pytest.mark.parametrize(
+    "geometry,clock,chunk_rows",
+    [
+        # records rows stored as (8, 128) tiles and a flat 512-word
+        # mailbox row, tree-top caches on (the resolved default), 64 and
+        # 16 rows a tree: under one row tile of the kernel's 64
+        ({}, "some", None),
+        ({}, "none", None),
+        ({}, "all", None),
+        ({"tree_top_cache_levels": 0}, "some", None),
+        ({"posmap_impl": "recursive"}, "some", None),
+        # the mailbox row with its pad: 6,080 words stored as (48, 128)
+        ({"mailbox_cap": 62}, "some", None),
+        # two chunks of 32 rows a tree: the kernel's blocks start at the
+        # chunk's rows of the plane (not 16 rows: XLA:CPU does not get
+        # through compiling the interpreted kernel on one 16-row block
+        # of (8, 128) rows written into a plane, in an hour)
+        ({"max_recipients": 128}, "some", 32),
+    ],
+    ids=["tiled-and-flat-rows-some-due", "none-due", "all-due",
+         "no-tree-top-cache", "recursive-posmap", "padded-mailbox-row",
+         "several-chunks"],
+)
+def test_swept_state_is_bit_identical_across_cipher_impls(
+    monkeypatch, geometry, clock, chunk_rows
+):
+    """``expiry_sweep`` under ``cipher_impl`` "jnp" and "pallas" (the
+    kernel reading and writing each chunk where it lies in the plane;
+    interpret mode here) leaves the same state, word for word: both
+    trees' planes, nonces and epochs, caches, stashes, the free list
+    and the counters."""
+    import dataclasses
+
+    from grapevine_tpu.engine import expiry
+
+    ecfg, state, alive = _populated(**geometry)
+    if chunk_rows is not None:
+        monkeypatch.setattr(expiry, "_chunk_rows", lambda cfg, n: chunk_rows)
+        assert ecfg.rec.n_buckets_padded > chunk_rows
+        assert ecfg.mb.n_buckets_padded > chunk_rows
+    now, period = _CLOCKS[clock]
+    swept = {}
+    for impl in ("jnp", "pallas"):
+        cfg = dataclasses.replace(
+            ecfg,
+            rec=dataclasses.replace(ecfg.rec, cipher_impl=impl),
+            mb=dataclasses.replace(ecfg.mb, cipher_impl=impl),
+        )
+        # a jit of its own: nothing traced under another impl or
+        # another chunking is found again
+        swept[impl] = jax.jit(
+            lambda st, cfg=cfg: expiry.expiry_sweep(
+                cfg, st, np.uint32(_T0 + now), np.uint32(period))
+        )(state)
+    left = ecfg.max_messages - int(swept["jnp"].free_top)
+    assert left == {"none": alive, "some": alive - 4, "all": 0}[clock]
+    # every bucket was re-keyed, under either executor
+    assert not np.array_equal(np.asarray(swept["jnp"].rec.tree_val),
+                              np.asarray(state.rec.tree_val))
+    want = jax.tree_util.tree_leaves_with_path(swept["jnp"])
+    got = jax.tree.leaves(swept["pallas"])
+    assert len(want) == len(got)
+    for (path, x), y in zip(want, got):
+        np.testing.assert_array_equal(
+            np.asarray(x), np.asarray(y),
+            err_msg=jax.tree_util.keystr(path))

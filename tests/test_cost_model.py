@@ -63,6 +63,8 @@ def test_engine_ledger_matches_traced_census(name, ecfg):
     scan."""
     cm.cross_validate_engine_round(ecfg)
     cm.cross_validate_sweep(ecfg)
+    # and as a TPU traces the sweep, the cipher kernel in the scan
+    cm.cross_validate_sweep(ecfg, kernel=True)
 
 
 def test_sharded_ledger_per_chip_bytes():

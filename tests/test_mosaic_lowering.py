@@ -170,6 +170,52 @@ def test_cipher_kernel_compiles_on_rows_as_the_plane_stores_them(
         assert not re.search(r" pad\(", text)
 
 
+@pytest.mark.parametrize(
+    "n,rpc,tiles,zin",
+    [(1 << 14, 4096, 8, 1024), (1 << 12, 1024, 48, 6080)],
+    ids=["records", "mailbox"],
+)
+def test_a_sweeps_chunk_is_rekeyed_where_it_lies_in_the_plane(
+    one_chip, n, rpc, tiles, zin
+):
+    """The expiry sweep's scan (engine/expiry.py, PR 48): each chunk of
+    ``rpc`` rows is decrypted out of the plane by the kernel (its blocks
+    offset by the scalar-prefetched chunk index) and the plaintext is
+    re-keyed into the same rows, the plane aliased through the kernel
+    and the scan's carry: at the published chunk shapes the compiled
+    loop holds the two kernels and no op that cuts a chunk out of the
+    plane, pastes one back or copies the plane (a second copy of the
+    8 GiB records plane does not fit the chip)."""
+    import re
+
+    zv = tiles * 128
+
+    def sweep(key, epochs, idx, plane):
+        def body(plane, i):
+            cut = lambda a: jax.lax.dynamic_slice_in_dim(a, i * rpc, rpc)  # noqa: E731
+            bid = i * U32(rpc) + jnp.arange(rpc, dtype=U32)
+            ix, vl = cipher_rows_pallas(
+                key, bid, cut(epochs), cut(idx), plane, rounds=8, zv=zv,
+                chunk=i)
+            ix, plane = cipher_rows_pallas(
+                key, bid, cut(epochs) + U32(1), ix, vl[:, :zin], rounds=8,
+                zv=zv, tiled_out=True, chunk=i, into=plane)
+            return plane, ix
+
+        return jax.lax.scan(body, plane, jnp.arange(n // rpc, dtype=U32))
+
+    text = _compile_for(
+        one_chip, sweep, _s(8), _s(n, 2), _s(n, 4), _s(n, tiles, 128),
+        donate=(3,),
+    )
+    assert text.count("tpu_custom_call") >= 2
+    assert "output_to_operand_aliasing" in text
+    assert f"u32[{rpc},{tiles},128]" not in text  # no chunk cut or pasted
+    assert not re.search(
+        rf"u32\[{n},{tiles},128\]\S* (?:copy|dynamic-update-slice|fusion)\(",
+        text)
+
+
 def test_cipher_kernel_pads_the_mailbox_plaintext_itself(one_chip):
     """The write-back's call: 6,080-word plaintext rows in, 6,144-word
     stored rows out, the 64 pad words' keystream stored beside the last

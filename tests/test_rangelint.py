@@ -22,6 +22,7 @@ Five suites:
 
 from __future__ import annotations
 
+import functools
 import os
 import sys
 
@@ -410,7 +411,8 @@ def test_a_served_geometry_certifies_at_its_own_batch(config_name):
 
     ecfg = _served_ecfg(config_name)
     assert ecfg.batch_size == 2048
-    for audit in (gate.audit_engine_round, gate.audit_expiry_sweep):
+    for audit in (gate.audit_engine_round, gate.audit_expiry_sweep,
+                  functools.partial(gate.audit_expiry_sweep, kernel=True)):
         rep = audit(ecfg, RANGE_ALLOWLIST, config_name)
         assert rep.ok, rep.summary()
         assert rep.n_eqns > 1000

@@ -69,6 +69,8 @@ def run_identity_matrix(verbose: bool = False) -> list:
     for name, ecfg in cm.audit_engine_configs():
         _run(f"{name}/round", cm.cross_validate_engine_round, ecfg)
         _run(f"{name}/sweep", cm.cross_validate_sweep, ecfg)
+        _run(f"{name}/sweep/kernel", cm.cross_validate_sweep, ecfg,
+             kernel=True)
     return problems
 
 

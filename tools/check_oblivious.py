@@ -115,23 +115,34 @@ def audit_engine_round(ecfg, allowlist, name: str):
     )
 
 
-def audit_expiry_sweep(ecfg, allowlist, name: str):
+def audit_expiry_sweep(ecfg, allowlist, name: str, kernel: bool = False):
+    """Taint-audit one expiry sweep (trace only). ``kernel``: the sweep
+    as a TPU runs it, both cipher passes of a chunk the Pallas kernel
+    (its body walked, the chunk's place in the plane a scalar-prefetched
+    iota), where the CPU's form holds the jnp keystream."""
     import jax
     import numpy as np
 
+    from grapevine_tpu.analysis.jaxpr_walk import cipher_form
     from grapevine_tpu.analysis.oblint import analyze
     from grapevine_tpu.engine import expiry
     from grapevine_tpu.engine.state import init_engine
 
     state = jax.eval_shape(lambda: init_engine(ecfg, 0))
     scalar = jax.ShapeDtypeStruct((), np.uint32)
-    return analyze(
-        lambda st, now, per, nh: expiry.expiry_sweep(ecfg, st, now, per, nh),
-        {"state": state, "now": scalar, "period": scalar, "now_hi": scalar},
-        secrets=expiry.OBLINT_SECRETS,
-        allowlist=allowlist,
-        name=f"expiry_sweep/{name}",
-    )
+    with cipher_form(ecfg, kernel) as ecfg:
+        rep = analyze(
+            lambda st, now, per, nh: expiry.expiry_sweep(
+                ecfg, st, now, per, nh),
+            {"state": state, "now": scalar, "period": scalar,
+             "now_hi": scalar},
+            secrets=expiry.OBLINT_SECRETS,
+            allowlist=allowlist,
+            name=f"expiry_sweep/{name}" + ("/kernel" if kernel else ""),
+        )
+    # else the walk saw the other form
+    assert bool(rep.census.get("pallas_call")) == kernel, rep.census
+    return rep
 
 
 def _small_oram_cfg(recursive: bool, k: int):
@@ -401,8 +412,9 @@ def run_audit(combos, allowlist=None, with_census="first",
         name = f"{vp}_{srt}_{pmi}_k{k}"
         absorb(audit_engine_round(_small_engine(vp, srt, pmi, k),
                                   allowlist, name))
-        absorb(audit_expiry_sweep(_small_engine(vp, srt, pmi, k),
-                                  allowlist, name))
+        for kernel in (False, True):
+            absorb(audit_expiry_sweep(_small_engine(vp, srt, pmi, k),
+                                      allowlist, name, kernel))
         if with_subrounds:
             absorb(audit_oram_round(
                 allowlist, occ_impl=vp, sort_impl=srt,

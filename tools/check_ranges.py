@@ -132,23 +132,33 @@ def audit_engine_round(ecfg, allowlist, name: str):
     )
 
 
-def audit_expiry_sweep(ecfg, allowlist, name: str):
+def audit_expiry_sweep(ecfg, allowlist, name: str, kernel: bool = False):
+    """Range-audit one expiry sweep (trace only). ``kernel``: the sweep
+    as a TPU runs it, the Pallas cipher kernel in the scan's body (its
+    block arithmetic, from the chunk's index, walked with the rest),
+    where the CPU's form holds the jnp keystream."""
     import jax
     import numpy as np
 
+    from grapevine_tpu.analysis.jaxpr_walk import cipher_form
     from grapevine_tpu.analysis.rangelint import analyze_ranges
     from grapevine_tpu.engine import expiry
     from grapevine_tpu.engine.state import init_engine
 
     state = jax.eval_shape(lambda: init_engine(ecfg, 0))
     scalar = jax.ShapeDtypeStruct((), np.uint32)
-    return analyze_ranges(
-        lambda st, now, per, nh: expiry.expiry_sweep(ecfg, st, now, per, nh),
-        {"state": state, "now": scalar, "period": scalar, "now_hi": scalar},
-        bounds=expiry.RANGELINT_BOUNDS(ecfg),
-        allowlist=allowlist,
-        name=f"expiry_sweep/{name}",
-    )
+    with cipher_form(ecfg, kernel) as ecfg:
+        rep = analyze_ranges(
+            lambda st, now, per, nh: expiry.expiry_sweep(
+                ecfg, st, now, per, nh),
+            {"state": state, "now": scalar, "period": scalar,
+             "now_hi": scalar},
+            bounds=expiry.RANGELINT_BOUNDS(ecfg),
+            allowlist=allowlist,
+            name=f"expiry_sweep/{name}" + ("/kernel" if kernel else ""),
+        )
+    assert bool(rep.census.get("pallas_call")) == kernel, rep.census
+    return rep
 
 
 def _oram_cfg(log2_blocks: int, recursive: bool, k: int):
@@ -349,7 +359,8 @@ def run_audit(combos, geometry: int, allowlist=None, verbose=False,
         name = f"2^{geometry}_{vp}_{srt}_{pmi}_k{k}"
         ecfg = _engine(geometry, vp, srt, pmi, k)
         absorb(audit_engine_round(ecfg, allowlist, name))
-        absorb(audit_expiry_sweep(ecfg, allowlist, name))
+        for kernel in (False, True):
+            absorb(audit_expiry_sweep(ecfg, allowlist, name, kernel))
         if with_subrounds:
             absorb(audit_oram_round(
                 allowlist, geometry, occ_impl=vp, sort_impl=srt,
