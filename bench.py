@@ -7,15 +7,7 @@ Configs (BASELINE.md / BASELINE.json, plus two extensions):
   3. zipf_mixed          mixed CRUD, Zipf recipient keys, 62-cap stress
   3b. zipf_pallas_cipher the same workload through the fused Pallas
                          cipher kernel
-  3c. zipf_pallas_fused  …plus the path fetch and write-back fused
-                         into the cipher passes
   4. expiry_sweep        timestamped eviction scan, 2^22 at density 4
-  4b. vphases_ab         dense vs scan slot-order machinery A/B —
-                         B-sweep (64/256/1024) of per-op round cost,
-                         interleaved (PR3; PERF.md Round 6)
-  4c. sort_ab            xla vs radix bounded-key sort engine A/B —
-                         eviction/dedup machinery + whole-round
-                         B-sweep, interleaved (PR5; PERF.md Round 7)
   4d. posmap_ab          flat vs recursive position map A/B — lookup
                          machinery (B × capacity grid, with the
                          private/HBM memory split) + whole-round
@@ -87,8 +79,8 @@ def _p99(times_s: list[float]) -> float:
 
 
 def _mk_engine(cap, recips, batch, stash=None, seed=0, density=2, cipher_impl="jnp",
-               vphases_impl=None, cipher_rounds=8, mailbox_cap=None,
-               sort_impl=None, posmap_impl=None, tree_top_cache=None):
+               cipher_rounds=8, mailbox_cap=None,
+               posmap_impl=None, tree_top_cache=None):
     import jax
 
     from grapevine_tpu.config import GrapevineConfig
@@ -104,8 +96,6 @@ def _mk_engine(cap, recips, batch, stash=None, seed=0, density=2, cipher_impl="j
         tree_density=density,
         bucket_cipher_impl=cipher_impl,
         bucket_cipher_rounds=cipher_rounds,
-        vphases_impl=vphases_impl,
-        sort_impl=sort_impl,
         posmap_impl=posmap_impl,
         tree_top_cache_levels=tree_top_cache,
         **extra,
@@ -323,159 +313,12 @@ def bench_zipf_mixed(smoke, cipher_impl="jnp"):
             "batch": batch, "capacity_log2": cap.bit_length() - 1}
 
 
-def bench_zipf_pallas(smoke, impl="pallas"):
-    """zipf_mixed through a Pallas cipher kernel (``impl="pallas"`` =
-    fused VMEM keystream+XOR; ``"pallas_fused"`` = that plus the path
-    gather/scatter fused into the cipher passes, one HBM pass per row).
-    A full-size run is a chip run (main() refuses anything else), so
-    the kernels are Mosaic-compiled there; ``--smoke`` runs them in
-    interpret mode at toy shapes to keep the path exercised."""
-    return bench_zipf_mixed(smoke, cipher_impl=impl)
-
-
-def bench_vphases_ab(smoke):
-    """Config 7: dense vs scan slot-order machinery A/B (PR3 tentpole).
-
-    B-sweep of whole-round per-op cost with ``vphases_impl`` as the only
-    difference (bit-identical semantics, tests/test_vphases_scan.py).
-    Geometry choices, deliberately:
-
-    - cipher rounds 0: ChaCha8 on a scalar backend is ~90% of round
-      time and identical under both impls — it would bury the A/B;
-    - small trees (2^12) + mailbox_cap 8: bounds the gather/scatter
-      share and compile time so three B points fit the per-config cap;
-    - rounds interleaved dense/scan, compared by MINIMUM round time:
-      the round is oblivious (shape-static, data-independent), so its
-      true cost is a constant and the min is the unbiased estimator
-      under this sandbox's 2-vCPU scheduler noise (back-to-back
-      identical runs were measured 2× apart on wall-clock medians).
-
-    Override the sweep with GRAPEVINE_VPHASES_AB_BS="64,256,..." — the
-    dense quadratic term grows as B² against the round's ~linear rest,
-    so the ratio rises with B (PERF.md Round 6 has the measured curve
-    and the B=4096 memory math)."""
-    import os
-    import time as _time
-
-    import jax
-
-    sweep = [
-        int(x)
-        for x in os.environ.get(
-            "GRAPEVINE_VPHASES_AB_BS", "64,256,1024"
-        ).split(",")
-    ]
-    n_timed = 5 if smoke else 9
-    out = {"sweep": {}}
-    for B in sweep:
-        ctxs = {}
-        for impl in ("dense", "scan"):
-            cfg, ecfg, state, step = _mk_engine(
-                1 << 12, 1 << 9, B, vphases_impl=impl, cipher_rounds=0,
-                mailbox_cap=8,
-            )
-            batches = make_batches(3, B, seed=13)
-            state, resp, _ = step(ecfg, state, batches[0])
-            jax.block_until_ready(resp)  # compile + warm
-            ctxs[impl] = [ecfg, state, step, batches]
-
-        def one_round(ctx, i):
-            ecfg, state, step, batches = ctx
-            t0 = _time.perf_counter()
-            state, resp, _ = step(ecfg, state, batches[i % 3])
-            jax.block_until_ready(resp)
-            ctx[1] = state
-            return _time.perf_counter() - t0
-
-        times = {"dense": [], "scan": []}
-        for i in range(n_timed):  # interleaved A/B
-            times["dense"].append(one_round(ctxs["dense"], i))
-            times["scan"].append(one_round(ctxs["scan"], i))
-        md = float(np.min(times["dense"]))
-        ms = float(np.min(times["scan"]))
-        out["sweep"][str(B)] = {
-            "dense_ms_per_op": round(md / B * 1e3, 4),
-            "scan_ms_per_op": round(ms / B * 1e3, 4),
-            "dense_round_ms": round(md * 1e3, 2),
-            "scan_round_ms": round(ms * 1e3, 2),
-            "median_dense_round_ms": round(
-                float(np.median(times["dense"])) * 1e3, 2
-            ),
-            "median_scan_round_ms": round(
-                float(np.median(times["scan"])) * 1e3, 2
-            ),
-            "speedup": round(md / ms, 3),
-        }
-        if B == 256:
-            out["b256_dense_ms_per_op"] = out["sweep"]["256"]["dense_ms_per_op"]
-            out["b256_scan_ms_per_op"] = out["sweep"]["256"]["scan_ms_per_op"]
-            out["b256_speedup"] = out["sweep"]["256"]["speedup"]
-    out["machinery"] = _vphases_machinery_sweep(smoke)
-    return out
-
-
-def _vphases_machinery_sweep(smoke):
-    """Isolated group-aggregation machinery A/B (the exact term the
-    vphases_impl knob swaps): one jit per (B, impl) exercising every
-    group method at representative shapes. Unlike the whole-round A/B
-    this is stable under the sandbox scheduler (sub-ms to ~100 ms ops,
-    min-of-9) and shows the clean O(B²) vs O(B log B) separation the
-    whole round dilutes with tree gather/scatter traffic."""
-    import time as _time
-
-    import jax
-    import jax.numpy as jnp
-
-    from grapevine_tpu.engine import vphases as V
-
-    def one(B, impl, reps):
-        rng = np.random.default_rng(0)
-        ka = jnp.asarray(
-            rng.integers(0, max(2, B // 8), (B, 8)).astype(np.uint32)
-        )
-        is_real = jnp.asarray(rng.random(B) < 0.9)
-        flags = jnp.asarray(rng.random(B) < 0.3)
-        u = jnp.asarray(rng.random((B, 248)) < 0.1)
-        q = jnp.asarray(rng.integers(-2, 5, B).astype(np.int32))
-        vals = jnp.asarray(rng.integers(0, 1 << 30, (B, 2)).astype(np.uint32))
-
-        class E:
-            vphases_impl = impl
-
-        def work(ka, is_real, flags, u, q, vals):
-            g = V._recipient_groups(E, ka, is_real)
-            return [
-                g.counts_before(flags), g.any_before(flags),
-                g.total_sum(flags), g.total_or(flags), g.total_or_rows(u),
-                g.total_sum_rows(u), g.group_first(), g.group_last(),
-                g.first_flag_index(flags)[0],
-                g.last_flag_index_upto(flags), g.last_flag_index(flags),
-                g.select_by_rank(flags, vals, q),
-            ]
-
-        f = jax.jit(work)
-        o = f(ka, is_real, flags, u, q, vals)
-        jax.block_until_ready(o)
-        ts = []
-        for _ in range(reps):
-            t0 = _time.perf_counter()
-            o = f(ka, is_real, flags, u, q, vals)
-            jax.block_until_ready(o)
-            ts.append(_time.perf_counter() - t0)
-        return float(np.min(ts))
-
-    sweep = (256, 1024) if smoke else (256, 1024, 2048, 4096)
-    reps = 5 if smoke else 9
-    res = {}
-    for B in sweep:
-        d = one(B, "dense", reps)
-        s = one(B, "scan", reps)
-        res[str(B)] = {
-            "dense_ms": round(d * 1e3, 2),
-            "scan_ms": round(s * 1e3, 2),
-            "speedup": round(d / s, 2),
-        }
-    return res
+def bench_zipf_pallas(smoke):
+    """zipf_mixed through the Pallas cipher kernel (fused VMEM
+    keystream+XOR). A full-size run is a chip run (main() refuses
+    anything else), so the kernel is Mosaic-compiled there; ``--smoke``
+    runs it in interpret mode at toy shapes to keep the path exercised."""
+    return bench_zipf_mixed(smoke, cipher_impl="pallas")
 
 
 def _model_ab(kind, measured, **kw):
@@ -516,160 +359,10 @@ def _min_of(fn, args, reps):
     return float(np.min(ts))
 
 
-def bench_sort_ab(smoke):
-    """Config 4c: xla vs radix bounded-key sort engine A/B (PR5).
-
-    Two scopes, both interleaved min-of-N (the min is the unbiased cost
-    of a shape-static oblivious program under this sandbox's 2-vCPU
-    scheduler noise — the vphases_ab methodology):
-
-    - **machinery**: the exact sort the knob swaps, isolated — stable
-      leaf-rank (``radix_rank`` vs ``jnp.argsort(stable=True)``) at
-      eviction-shaped working-set sizes W with h-bit keys, plus the
-      dedup group sort (``radix_group_sort`` vs
-      ``multiword_group_sort``) at round batch sizes. Radix is timed at
-      its best ``bits_per_pass`` per size so the comparison can't be
-      rigged against it.
-    - **whole round**: B-sweep with ``sort_impl`` as the only knob
-      (vphases pinned "scan" so the bounded group sorts are actually in
-      the round under both impls).
-
-    Honest-reporting note (the PR-3 lesson, PERF.md Round 7): on
-    XLA:CPU each radix pass pays a serial ~80 ns/elem scatter, so the
-    native comparison sort wins here at every size — these numbers are
-    the *CPU floor record* that justifies keeping ``sort_impl`` auto =
-    "xla" off-TPU; the TPU decision belongs to the capture's
-    ``sort_perf`` stage. Override sweeps with
-    GRAPEVINE_SORT_AB_BS / GRAPEVINE_SORT_AB_WS."""
-    import os
-    import time as _time
-
-    import jax
-    import jax.numpy as jnp
-
-    from grapevine_tpu.oblivious.radix import radix_group_sort, radix_rank
-    from grapevine_tpu.oblivious.segmented import multiword_group_sort
-
-    reps = 3 if smoke else 7
-    out = {"machinery": {}, "sweep": {}}
-
-    # --- machinery: eviction leaf rank at working-set sizes ------------
-    h = 16 if smoke else 20  # leaf bits of a 2^16 / 2^20-capacity tree
-    ws = [
-        int(x)
-        for x in os.environ.get(
-            "GRAPEVINE_SORT_AB_WS",
-            "4096,16384" if smoke else "16384,65536,262144",
-        ).split(",")
-    ]
-    rng = np.random.default_rng(5)
-    for w in ws:
-        keys = jnp.asarray(
-            rng.integers(0, 1 << h, w).astype(np.uint32)
-        )
-        tx = _min_of(
-            jax.jit(lambda k: jnp.argsort(k, stable=True)), (keys,), reps
-        )
-        # radix at its best pass width for this size (1-bit passes have
-        # no [W,R] bin table; wider passes amortize the per-pass
-        # gather+scatter) — report the winner so the A/B is fair to it
-        tr, bpp_best = None, None
-        for bpp in (1, 4, 8):
-            t = _min_of(
-                jax.jit(lambda k, b=bpp: radix_rank(k, h + 1, b)),
-                (keys,), reps,
-            )
-            if tr is None or t < tr:
-                tr, bpp_best = t, bpp
-        out["machinery"][f"evict_rank_w{w}"] = {
-            "key_bits": h + 1,
-            "xla_ms": round(tx * 1e3, 3),
-            "radix_ms": round(tr * 1e3, 3),
-            "radix_bits_per_pass": bpp_best,
-            "speedup_radix_over_xla": round(tx / tr, 3),
-        }
-    # --- machinery: dedup group sort at batch sizes --------------------
-    for b in (256, 1024) if smoke else (1024, 4096):
-        kb = max(1, (b * 4).bit_length())
-        idxs = jnp.asarray(
-            rng.integers(0, b * 4, b).astype(np.uint32)
-        )
-        tx = _min_of(jax.jit(lambda i: multiword_group_sort([i])), (idxs,), reps)
-        tr = _min_of(
-            jax.jit(lambda i: radix_group_sort([i], kb)), (idxs,), reps
-        )
-        out["machinery"][f"dedup_group_b{b}"] = {
-            "key_bits": kb,
-            "xla_ms": round(tx * 1e3, 3),
-            "radix_ms": round(tr * 1e3, 3),
-            "speedup_radix_over_xla": round(tx / tr, 3),
-        }
-
-    # --- whole round: sort_impl the only knob --------------------------
-    sweep = [
-        int(x)
-        for x in os.environ.get(
-            "GRAPEVINE_SORT_AB_BS", "64,256" if smoke else "64,256,1024"
-        ).split(",")
-    ]
-    n_timed = 3 if smoke else 9
-    for B in sweep:
-        ctxs = {}
-        for impl in ("xla", "radix"):
-            cfg, ecfg, state, step = _mk_engine(
-                1 << 12, 1 << 9, B, vphases_impl="scan", sort_impl=impl,
-                cipher_rounds=0, mailbox_cap=8,
-            )
-            batches = make_batches(3, B, seed=13)
-            state, resp, _ = step(ecfg, state, batches[0])
-            jax.block_until_ready(resp)
-            ctxs[impl] = [ecfg, state, step, batches]
-
-        def one_round(ctx, i):
-            ecfg, state, step, batches = ctx
-            t0 = _time.perf_counter()
-            state, resp, _ = step(ecfg, state, batches[i % 3])
-            jax.block_until_ready(resp)
-            ctx[1] = state
-            return _time.perf_counter() - t0
-
-        times = {"xla": [], "radix": []}
-        for i in range(n_timed):  # interleaved A/B
-            times["xla"].append(one_round(ctxs["xla"], i))
-            times["radix"].append(one_round(ctxs["radix"], i))
-        mx = float(np.min(times["xla"]))
-        mr = float(np.min(times["radix"]))
-        out["sweep"][str(B)] = {
-            "xla_round_ms": round(mx * 1e3, 2),
-            "radix_round_ms": round(mr * 1e3, 2),
-            "median_xla_round_ms": round(
-                float(np.median(times["xla"])) * 1e3, 2
-            ),
-            "median_radix_round_ms": round(
-                float(np.median(times["radix"])) * 1e3, 2
-            ),
-            "speedup_radix_over_xla": round(mx / mr, 3),
-        }
-
-    # modeled-vs-measured winner per config group (ISSUE 17): sort is
-    # a structural verdict — backend decides (serial scatter floor on
-    # XLA:CPU), not a byte count — so one verdict covers every group
-    backend = jax.default_backend()
-    for scope in ("machinery", "sweep"):
-        for g in out[scope].values():
-            g["model"] = _model_ab(
-                "sort",
-                "radix" if g["speedup_radix_over_xla"] > 1.0 else "xla",
-                scope=scope, backend=backend,
-            )
-    return out
-
-
 def bench_posmap_ab(smoke):
     """Config 4d: flat vs recursive position map A/B (PR7).
 
-    Two scopes, both interleaved min-of-N (the vphases/sort_ab
-    methodology):
+    Two scopes, both interleaved min-of-N:
 
     - **machinery**: ``lookup_remap_round`` isolated — the exact code
       the knob swaps — over a (batch B × capacity) grid: flat is one
@@ -748,8 +441,7 @@ def bench_posmap_ab(smoke):
                 )
                 # pm2 must be a live output: dropping it lets XLA
                 # dead-code-eliminate flat's remap scatter and the
-                # internal round's whole eviction write-back (the
-                # sort_ab full-output rule)
+                # internal round's whole eviction write-back
                 return (pm2, leaves) if inner is None else (pm2, leaves, inner)
 
             tf = _min_of(
@@ -827,7 +519,7 @@ def bench_posmap_ab(smoke):
 def bench_tree_cache_ab(smoke):
     """Config 4e: tree-top cache A/B (PR8; ROADMAP item 1).
 
-    Two scopes, both interleaved min-of-N (the vphases/sort/posmap_ab
+    Two scopes, both interleaved min-of-N (the posmap_ab
     methodology):
 
     - **machinery**: one records-shaped ``oram_round`` isolated (trivial
@@ -1464,8 +1156,7 @@ def bench_pipeline_ab(smoke):
     state dir with ``journal_fsync_every=1`` and checkpoints pushed out
     of the window, so the A/B prices exactly the claim — at depth 2 the
     fsync barrier overlaps device execution instead of serializing
-    with it. Min-of-N interleaved at the whole-rep level (the
-    vphases/sort/posmap playbook): arms alternate rep by rep so drift
+    with it. Min-of-N interleaved at the whole-rep level: arms alternate rep by rep so drift
     in the shared host hits both equally; per arm the best rep's
     throughput and the minimum p99 are reported. The tracer rides both
     arms and contributes the measured journal-span p99 and the bubble
@@ -2168,10 +1859,7 @@ CONFIGS = [
     ("zipf_mixed", bench_zipf_mixed),
     ("batched_read", bench_batched_read),
     ("zipf_pallas_cipher", bench_zipf_pallas),
-    ("zipf_pallas_fused", lambda smoke: bench_zipf_pallas(smoke, "pallas_fused")),
     ("crd_loop", bench_crd_loop),
-    ("vphases_ab", bench_vphases_ab),
-    ("sort_ab", bench_sort_ab),
     ("posmap_ab", bench_posmap_ab),
     ("tree_cache_ab", bench_tree_cache_ab),
     ("expiry_sweep", bench_expiry_sweep),

@@ -25,7 +25,7 @@ chip:
 4. replays every round, in the order and slot composition the engine
    saw, on the plain oracle (``testing/reference.py``) and requires
    identical statuses and records op for op, and zero stash overflow;
-5. kernel phase: a few rounds through each Pallas ``bucket_cipher_impl``
+5. kernel phase: a few rounds through ``bucket_cipher_impl="pallas"``
    at 2^16 messages / B=256, Mosaic-compiled (``tpu_custom_call`` in the
    compiled text), state bit-identical to ``jnp`` rounds on the same ops;
    then the write-back's row-placement kernel alone against the jnp
@@ -555,14 +555,14 @@ def kernel_phase(seed: int, cap: int = 1 << 16, batch: int = 256,
     from grapevine_tpu.engine.batcher import pack_batch, unpack_responses
     from grapevine_tpu.engine.round_step import engine_round_step
     from grapevine_tpu.engine.state import EngineConfig, init_engine
-    from grapevine_tpu.testing.compare import states_equal_excluding_junk
+    from grapevine_tpu.testing.compare import states_equal
     from grapevine_tpu.wire import constants as C
 
     scheme, idents = make_identities(seed, 32)
     traffic = SchedulerTraffic(scheme, idents, random.Random(f"{seed}-kern"))
     results = {}
-    script: list = []  # the jnp run decides the ops; the others repeat them
-    for impl in ("jnp", "pallas", "pallas_fused"):
+    script: list = []  # the jnp run decides the ops; pallas repeats them
+    for impl in ("jnp", "pallas"):
         cfg = GrapevineConfig(
             max_messages=cap, max_recipients=1 << 10, batch_size=batch,
             tree_density=2, bucket_cipher_impl=impl,
@@ -578,8 +578,7 @@ def kernel_phase(seed: int, cap: int = 1 << 16, batch: int = 256,
         calls = [line for line in compiled.as_text().split("\n")
                  if 'custom_call_target="tpu_custom_call"' in line]
         # the write-back's row placements (PR 46) are Mosaic kernels
-        # under every cipher impl but the fused one, whose write-back is
-        # its own kernel: three a round, one a tree pass
+        # under either cipher impl: three a round, one a tree pass
         n_placed = sum("place_rows" in line for line in calls)
         n_kernels = len(calls) - n_placed
         # off the chip (a rehearsal importing this function) the kernels
@@ -587,8 +586,7 @@ def kernel_phase(seed: int, cap: int = 1 << 16, batch: int = 256,
         check(not on_tpu() or (n_kernels > 0) == (impl != "jnp"),
               f"{impl}: {n_kernels} Mosaic cipher kernels in the compiled "
               "round")
-        check(not on_tpu()
-              or n_placed == (0 if impl == "pallas_fused" else 3),
+        check(not on_tpu() or n_placed == 3,
               f"{impl}: {n_placed} placement kernels in the compiled round")
         t0 = time.perf_counter()
         outs = []
@@ -614,17 +612,15 @@ def kernel_phase(seed: int, cap: int = 1 << 16, batch: int = 256,
             successful_ops=ok_ops, capacity_log2=cap.bit_length() - 1,
             batch=batch)
     ref_outs, ref_state = results["jnp"]
-    for impl in ("pallas", "pallas_fused"):
-        outs, state = results[impl]
-        for k, (a, b) in enumerate(zip(ref_outs, outs)):
-            for key in a:
-                check(np.array_equal(a[key], b[key]),
-                      f"{impl}: round {k} response field {key} differs "
-                      "from jnp")
-        same, where = states_equal_excluding_junk(ref_state, state)
-        check(same, f"{impl}: state differs from jnp at {where}")
-    say(phase="kernels.compare", ok=True,
-        bit_identical_to_jnp=["pallas", "pallas_fused"])
+    outs, state = results["pallas"]
+    for k, (a, b) in enumerate(zip(ref_outs, outs)):
+        for key in a:
+            check(np.array_equal(a[key], b[key]),
+                  f"pallas: round {k} response field {key} differs "
+                  "from jnp")
+    same, where = states_equal(ref_state, state)
+    check(same, f"pallas: state differs from jnp at {where}")
+    say(phase="kernels.compare", ok=True, bit_identical_to_jnp=["pallas"])
 
 
 def placement_phase(seed: int, n: int = 1 << 13, tiles: int = 48,

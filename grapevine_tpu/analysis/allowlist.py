@@ -72,18 +72,13 @@ _ORAM_CORE = (
        "the batch covers — into a private map of fixed shape, "
        "whatever the leaves; covered buckets are never looked up in "
        "it"),
-    _A("gather", "oram/round.py:occurrence_masks_sorted",
-       "sorted dedup: permutation/boundary gathers over fixed [B] "
-       "arrays — oblivious-sort data movement, schedule fixed by B"),
     _A("gather", "oram/round.py:_assign_evictions",
        "eviction assignment: the bucket-map lookup of a per-path level "
        "and nothing else — one fixed [W]-shaped read of the private "
        "owner map per level under the covered ones (bucket -> output "
        "row: covered buckets are their own id without a lookup, deeper "
        "buckets their owner copy); ranks come from scans and the "
-       "sorted keys from the sort's own payload, never from a gather "
-       "(the radix sort path, which returns a permutation only, reads "
-       "its keys through it once: permutation plumbing over [W])"),
+       "sorted keys from the sort's own payload, never from a gather"),
     _A("scatter", "oram/round.py:_assign_evictions",
        "eviction assignment: the one inverse-permutation scatter back "
        "to working-set order — every row of the fixed working set "
@@ -104,36 +99,12 @@ _POSMAP = (
     _A("scatter", "oram/posmap.py:apply_pm",
        "recursive map entry writes onto committed internal rows — "
        "private working set, unique in-bounds targets"),
-    _A("gather", "oram/posmap.py:_group_last_slot",
-       "sorted last-occurrence dedup: the occurrence_masks_sorted "
-       "mirror, permutation gathers over fixed [B] arrays"),
 )
 
-#: oblivious sort/scan machinery (bit-identity with argsort is
-#: separately pinned by tests/test_radix.py, test_segmented.py)
+#: the admission walk's grouping sort
 _SORTS = (
-    _A("gather", "oblivious/radix.py:_rank_pass",
-       "counting-sort rank pass: per-digit histogram reads, all B rows "
-       "touched exactly once per pass"),
-    _A("scatter", "oblivious/radix.py:_rank_pass",
-       "counting-sort histogram scatter: fixed digit-bucket array, all "
-       "B rows contribute exactly once per pass"),
-    _A("gather", "oblivious/radix.py:radix_group_sort",
-       "radix group sort: permutation gathers over fixed [B] arrays"),
-    _A("scatter", "oblivious/radix.py:radix_group_sort",
-       "radix group sort: rank-targeted scatter — targets are a "
-       "permutation of [B], every row written once"),
-    _A("scatter", "oblivious/radix.py:radix_rank",
-       "radix rank materialization: permutation scatter over [W]"),
-    _A("scatter", "oblivious/segmented.py:multiword_group_sort",
-       "wide-key group sort: inverse-permutation scatter over fixed "
-       "[B] arrays"),
     _A("gather", "oblivious/segmented.py:group_sort",
        "bounded-key group sort: permutation gathers over fixed [B]"),
-    _A("gather", "oblivious/segmented.py:segmented_sum_before",
-       "segmented scan boundary reads: permutation-indexed, fixed [B]"),
-    _A("gather", "oblivious/segmented.py:segmented_sum_total",
-       "segmented totals broadcast back by segment id: fixed [B]"),
 )
 
 #: slot-order semantics + admission (engine/vphases.py): all of it runs
@@ -151,22 +122,6 @@ _VPHASES = (
     _A("scatter", "engine/vphases.py:apply_batch",
        "slot-order chain commits: [B]-row scatters into private "
        "working rows, unique in-bounds targets"),
-    _A("gather", "engine/vphases.py:select_by_rank",
-       "k-th-flag selection: rank-indexed gather over fixed [B]"),
-    _A("scatter", "engine/vphases.py:select_by_rank",
-       "k-th-flag selection: rank scatter over fixed [B]"),
-    _A("gather", "engine/vphases.py:group_first",
-       "group-boundary gather over the sorted [B] slot order"),
-    _A("gather", "engine/vphases.py:group_last",
-       "group-boundary gather over the sorted [B] slot order"),
-    _A("gather", "engine/vphases.py:first_flag_index",
-       "first-flag rank gather over fixed [B]"),
-    _A("gather", "engine/vphases.py:last_flag_index",
-       "last-flag rank gather over fixed [B]"),
-    _A("gather", "engine/vphases.py:_to",
-       "scan-impl permutation into sorted order: fixed [B] gather"),
-    _A("gather", "engine/vphases.py:_back",
-       "scan-impl permutation out of sorted order: fixed [B] gather"),
     _A("scatter", "engine/vphases.py:step",
        "exact-admission scan body: per-op counter updates, private [B] "
        "state, fixed trip count"),
@@ -266,7 +221,7 @@ RANGE_ALLOWLIST: tuple = (
     _A("reduce_sum", "engine/vphases.py:select_by_rank",
        "rank-equality one-hot select: at most one lane of a group has "
        "rank q, so the masked sum is a private row select"),
-    _A("add", "oblivious/radix.py:_rank_pass",
+    _A("add", "oblivious/primitives.py:partition_rank",
        "counting-rank recombination: zeros-rank + ones-rank of one "
        "stable partition is a permutation of [0, B) (sums below B "
        "pointwise, 2B only in interval arithmetic); the adjacent clip "

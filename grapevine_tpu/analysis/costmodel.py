@@ -644,10 +644,9 @@ class CostLedger:
         return self.per_shard_steady_round_bytes / (gbytes_per_s * 1e6)
 
 
-def _round_sort_keys(cfg, b: int, sort_impl: str, occ_impl: str) -> int:
+def _round_sort_keys(cfg, b: int) -> int:
     """Sort key-volume of one oram_round: the eviction leaf sort over
-    the working set plus the dedup group sorts under the scan occurrence
-    machinery, composed recursively for the internal map round."""
+    the working set, composed recursively for the internal map round."""
     z = cfg.bucket_slots
     plen = cfg.path_len
     # working set: stash, every dense bucket once, the per-path copies
@@ -655,16 +654,10 @@ def _round_sort_keys(cfg, b: int, sort_impl: str, occ_impl: str) -> int:
     ld = _round_dense_levels(cfg, b)
     rows = ((1 << ld) - 1) + b * (plen - ld)
     keys = cfg.stash_size + rows * z + b
-    if occ_impl == "scan":
-        keys += b  # occurrence group sort
     if cfg.posmap is not None:
         from ..oram.posmap import inner_oram_config
 
-        if occ_impl == "scan":
-            keys += b  # recursive group-last-slot sort
-        keys += _round_sort_keys(
-            inner_oram_config(cfg.posmap), b, sort_impl, occ_impl
-        )
+        keys += _round_sort_keys(inner_oram_config(cfg.posmap), b)
     return keys
 
 
@@ -685,8 +678,7 @@ def _round_cipher_rows(cfg, b: int) -> int:
     return inner
 
 
-def engine_cost_ledger(ecfg, occ_impl: str | None = None,
-                       shards: int = 1) -> CostLedger:
+def engine_cost_ledger(ecfg, shards: int = 1) -> CostLedger:
     """The full modeled ledger for one engine geometry × knob setting —
     the object obs/costmon.py exports and bench.py grades. ``shards``
     is the bucket-tree mesh width (GrapevineConfig.shards — engine
@@ -694,9 +686,6 @@ def engine_cost_ledger(ecfg, occ_impl: str | None = None,
     parameter here, not a field read off ``ecfg``)."""
     if shards < 1 or shards & (shards - 1):
         raise ValueError(f"shards={shards}: want a power of two >= 1")
-    occ = occ_impl if occ_impl is not None else (
-        "scan" if ecfg.vphases_impl == "scan" else "dense"
-    )
     b, d = ecfg.batch_size, ecfg.mb_choices
     round_rows = engine_round_rows(ecfg)
     fetch = PhaseCost().add_rows({
@@ -723,8 +712,8 @@ def engine_cost_ledger(ecfg, occ_impl: str | None = None,
     dec_total = (_round_cipher_rows(ecfg.rec, b)
                  + 2 * _round_cipher_rows(ecfg.mb, b * d))
     sort_total = (
-        _round_sort_keys(ecfg.rec, b, ecfg.sort_impl, occ)
-        + 2 * _round_sort_keys(ecfg.mb, b * d, ecfg.sort_impl, occ)
+        _round_sort_keys(ecfg.rec, b)
+        + 2 * _round_sort_keys(ecfg.mb, b * d)
     )
     # the fetch/write-back split of the joint round program is half
     # decrypt, half re-encrypt; the eviction sort rides the write-back
@@ -794,8 +783,7 @@ def _pick(arms: dict, order) -> str:
 
 
 def ab_verdict(kind: str, *, scope: str = "machinery",
-               cap_n: int = 65536, batch: int = 256, arms=None,
-               backend: str = "cpu") -> dict:
+               cap_n: int = 65536, batch: int = 256, arms=None) -> dict:
     """The model's pick for one shipped A/B config — the number
     bench.py reports next to the measured winner and
     tools/check_cost_model.py grades against every banked
@@ -804,9 +792,9 @@ def ab_verdict(kind: str, *, scope: str = "machinery",
     The decision rule is modeled amortized HBM bytes with the
     :data:`TIE_BAND` preference for less machinery: a knob arm only
     wins when it actually removes traffic (tree-top cache converts
-    HBM rows to private rows). ``sort`` and ``pipeline`` swap machinery
-    without changing plane traffic, so their verdicts are structural
-    and flagged in ``basis``.
+    HBM rows to private rows). ``pipeline`` swaps machinery without
+    changing plane traffic, so its verdict is structural and flagged in
+    ``basis``.
     """
     out: dict = {"kind": kind, "scope": scope, "arms": {}}
     if kind == "tree_cache":
@@ -829,17 +817,6 @@ def ab_verdict(kind: str, *, scope: str = "machinery",
             "band (a level the batch covers saves only its own 2^L "
             "rows: a cut of 2-11 % at the banked machinery configs, "
             "inside the band in the engine sweeps)"
-        )
-    elif kind == "sort":
-        out["arms"] = {"xla": {"model": "W·log2(W) compare sort"},
-                       "radix": {"model": "ceil(key_bits/bpp) serial "
-                                          "scatter passes over W keys"}}
-        out["winner"] = "xla" if backend == "cpu" else "defer"
-        out["basis"] = (
-            "bytes-identical machinery swap: the banked PR-5 floor "
-            "records show CPU serial-scatter constants price radix "
-            "out at every banked W; the TPU verdict defers to the "
-            "cost_calibrate/sort_perf capture"
         )
     elif kind == "pipeline":
         out["arms"] = {"depth1": {"model": "host + device serialized"},

@@ -116,14 +116,10 @@ class GrapevineConfig:
     #: over a dozen fusions and hands state words and keystream through
     #: HBM — the reference and the CPU path), "pallas" (one pass: the
     #: keystream made in VMEM on the row's own lane tiles and XORed
-    #: where it is made, oblivious/pallas_cipher.py), "pallas_fused"
-    #: ("pallas" plus the path fetch fused into the decrypt,
-    #: oblivious/pallas_gather.py: one row a grid step, not made fast;
-    #: single-chip fetches only, the sharded path keeps
-    #: decrypt-after-psum so plaintext never transits ICI). Interpret
-    #: mode on the CPU; bit-identical ciphertext in all three. None =
-    #: by backend: "pallas" on a TPU, "jnp" on the CPU (PERF.md §5/§6,
-    #: PR 40: the chip's A/B in backlog-1chip-r2p16).
+    #: where it is made, oblivious/pallas_cipher.py; interpret mode on
+    #: the CPU, where tests hold it to "jnp"). Bit-identical ciphertext
+    #: in both. None = by backend: "pallas" on a TPU, "jnp" on the CPU
+    #: (PERF.md §5/§6, PR 40: the chip's A/B in backlog-1chip-r2p16).
     bucket_cipher_impl: str | None = None
     #: per-request signature scheme: "schnorrkel" (sr25519, byte-compatible
     #: with the reference's sign_schnorrkel clients — README.md:193-199,
@@ -146,27 +142,15 @@ class GrapevineConfig:
             raise ValueError(
                 f"bucket_cipher_rounds must be 0 or an even value >= 8, got {r}"
             )
-        if self.bucket_cipher_impl not in (
-            None, "jnp", "pallas", "pallas_fused"
-        ):
+        if self.bucket_cipher_impl not in (None, "jnp", "pallas"):
             raise ValueError(
-                f"bucket_cipher_impl must be None, 'jnp', 'pallas' or "
-                f"'pallas_fused', got {self.bucket_cipher_impl!r}"
+                f"bucket_cipher_impl must be None, 'jnp' or 'pallas', "
+                f"got {self.bucket_cipher_impl!r}"
             )
         if self.signature_scheme not in ("schnorrkel", "rfc9496"):
             raise ValueError(
                 f"signature_scheme must be 'schnorrkel' or 'rfc9496', got "
                 f"{self.signature_scheme!r}"
-            )
-        if self.vphases_impl not in (None, "dense", "scan"):
-            raise ValueError(
-                f"vphases_impl must be None, 'dense' or 'scan', got "
-                f"{self.vphases_impl!r}"
-            )
-        if self.sort_impl not in (None, "xla", "radix"):
-            raise ValueError(
-                f"sort_impl must be None, 'xla' or 'radix', got "
-                f"{self.sort_impl!r}"
             )
         if self.max_messages < 2 or self.max_messages & (self.max_messages - 1):
             raise ValueError("max_messages must be a power of two >= 2")
@@ -228,43 +212,6 @@ class GrapevineConfig:
                 "make_sharded_step), and the op-major engine stays "
                 "single-chip as the differential oracle"
             )
-    #: slot-order semantics implementation for the phase-major engine's
-    #: vectorized phases (engine/vphases.py): "dense" = [B,B] masked
-    #: matrices + one-hot bool-matmuls (MXU-shaped; O(B²) compute and
-    #: intermediate memory), "scan" = group-sort + segmented scans
-    #: (O(B log B), no [B,B] intermediate — the form that scales past
-    #: B=2048). Bit-identical responses and final engine state
-    #: (tests/test_vphases_scan.py). None = auto by backend: "dense" on
-    #: the TPU (the MXU eats the masks; the dense/scan A/B is not
-    #: measured on the chip), "scan" on the CPU — there the aggregation machinery itself
-    #: measures ~1.4× faster at B=256 rising to ~23× at B=4096, while
-    #: whole-round CPU gains stay small below B≈2048 (the round is
-    #: gather/scatter-bound; measured curve + the B=4096 dense memory
-    #: math: PERF.md Round 6).
-    vphases_impl: str | None = None
-
-    #: bounded-key sort engine for the device round (oblivious/radix.py):
-    #: "xla" = the comparison sorts XLA lowers natively (a serial
-    #: ``while`` thunk on XLA:CPU — the round's measured floor after
-    #: PR 3, PERF.md Round 6 — and a bitonic network on TPU), "radix" =
-    #: data-oblivious LSD counting passes for every sort whose key
-    #: carries a declared bit bound: eviction's leaf sort and round
-    #: dedup (oram/round.py), the scan impl's bucket/record group sorts
-    #: and the admission walk's slot grouping (engine/vphases.py). The
-    #: 256-bit recipient-key sort stays on lax.sort under either
-    #: setting (explicit key-bits gate: radix refuses keys wider than
-    #: MAX_RADIX_BITS rather than hashing them down). Bit-identical
-    #: responses and final engine state (tests/test_radix.py /
-    #: test_sort_radix.py; the radix ORAM round traces ZERO ``sort``
-    #: HLO ops, CI-audited). None = auto: currently "xla" on every
-    #: backend — on XLA:CPU the native serial sort beats any
-    #: scatter-per-pass radix formulation (each pass costs one ~80
-    #: ns/elem serial scatter; measured, bench.py ``sort_ab`` / PERF.md
-    #: Round 7), and on TPU — where scatters vectorize and lax.sort is
-    #: the O(n log² n) bitonic side — the default flips only on the
-    #: capture's ``sort_perf`` device A/B (the vphases_impl playbook).
-    sort_impl: str | None = None
-
     #: position-map implementation for both ORAMs (oram/posmap.py):
     #: "flat" = the private u32[blocks+1] table in working memory —
     #: bit-for-bit the pre-PR-7 engine; "recursive" = the classic
@@ -283,7 +230,7 @@ class GrapevineConfig:
     #: map pays ~2× HBM path traffic per round for a ~k× smaller
     #: resident footprint, a trade that only *wins* once capacity
     #: exceeds private memory; flip per capacity (OPERATIONS.md §13)
-    #: — not measured on the chip (the vphases/sort playbook). Requires
+    #: — not measured on the chip. Requires
     #: commit="phase" and power-of-two block spaces >= 8 on both trees.
     posmap_impl: str | None = None
 
@@ -308,9 +255,8 @@ class GrapevineConfig:
     #: is (2^k−1)·bucket-row bytes per tree (OPERATIONS.md §14 sizing
     #: table). None = auto: 4 on the TPU AND on the CPU —
     #: the cache strictly removes gather/scatter/cipher rows rather than
-    #: trading one algorithm for another, and the CPU A/B (bench.py
-    #: ``tree_cache_ab``, PERF.md Round 10) confirms the win off-TPU;
-    #: not measured on the chip. Requires commit="phase".
+    #: trading one algorithm for another; the k = 0 / k = 4 A/B is not
+    #: measured on the chip. Requires commit="phase".
     tree_top_cache_levels: int | None = None
 
     #: round-pipeline depth: the number of dispatched-but-unresolved

@@ -412,17 +412,6 @@ class GrapevineEngine:
                 self.ecfg, self._mesh, seed)
             self.state, state_init_s = _build_state(self._init_state)
             state_shardings = jax.tree.map(lambda x: x.sharding, self.state)
-            if self.config.bucket_cipher_impl == "pallas_fused":
-                # said once, at build: the fused gather/scatter kernels
-                # are single-chip (tree plaintext must not transit ICI)
-                _log.warning(
-                    "bucket_cipher_impl='pallas_fused' with shards=%d "
-                    "runs as 'pallas': the sharded round gathers, psums "
-                    "and only then decrypts with the Pallas cipher "
-                    "kernel; the fused gather/scatter kernels do not "
-                    "engage under shard_map",
-                    self.config.shards,
-                )
             sstep = make_sharded_step(self.ecfg, self._mesh)
             step_fn = lambda _ecfg, state, batch: sstep(state, batch)  # noqa: E731
             self._step = step_fn
@@ -438,9 +427,8 @@ class GrapevineEngine:
             self.state, state_init_s = _build_state(self._init_state)
             step_fn = (engine_round_step if self.config.commit == "phase"
                        else engine_step)
-            # donate the state: trees update in place (no per-round copy,
-            # and the fused pallas scatter's input/output aliasing would
-            # otherwise force XLA to defensively copy both tree arrays)
+            # donate the state: trees update in place (no per-round copy;
+            # the placement kernel aliases the value plane through)
             self._step = self._step_jit = jax.jit(
                 step_fn, static_argnums=(0,), donate_argnums=(1,)
             )
@@ -466,8 +454,8 @@ class GrapevineEngine:
         #: overlapping host work and the journal fsync behind it is the
         #: whole win; not measured on the chip), 1 on the CPU — there the
         #: extra in-flight round buys no overlap but costs up to one
-        #: full device round of open-loop commit latency (measured:
-        #: PERF.md Round 11; the vphases/sort flip-on-evidence playbook)
+        #: full device round of open-loop commit latency (measured on
+        #: the CPU)
         if self.config.pipeline_depth is not None:
             self.pipeline_depth = self.config.pipeline_depth
         else:
@@ -923,20 +911,15 @@ class GrapevineEngine:
         places by DMA (oram/path_oram.py ``_path_scatter``): every row
         the tree's passes fetch, where the plane stores its rows as
         whole memory tiles (``OramConfig.stored_row_shape``) and the
-        backend is a TPU; 0 where the plane keeps XLA's scatter, and
-        under ``pallas_fused`` on one chip, whose write-back is the
-        fused kernel's. On a mesh each chip places the rows it owns
-        of them."""
+        backend is a TPU; 0 where the plane keeps XLA's scatter. On a
+        mesh each chip places the rows it owns of them."""
         from ..config import on_tpu
 
         b, d = self.ecfg.batch_size, self.ecfg.mb_choices
         out = {}
         for tree, cfg, n, passes in (("rec", self.ecfg.rec, b, 1),
                                      ("mb", self.ecfg.mb, b * d, 2)):
-            fused = (cfg.cipher_impl == "pallas_fused" and cfg.encrypted
-                     and self._mesh is None)
-            by_dma = (on_tpu() and len(cfg.stored_row_shape) == 2
-                      and not fused)
+            by_dma = on_tpu() and len(cfg.stored_row_shape) == 2
             out[tree] = passes * cfg.fetched_bucket_rows(n) if by_dma else 0
         return out
 

@@ -44,8 +44,13 @@ import jax
 import jax.numpy as jnp
 
 from ..oblivious.bucket_cipher import epoch_next, row_keystream
-from ..oblivious.primitives import SENTINEL, is_zero_words, u64_le, u64_sub
-from ..oblivious.radix import partition_rank
+from ..oblivious.primitives import (
+    SENTINEL,
+    is_zero_words,
+    partition_rank,
+    u64_le,
+    u64_sub,
+)
 from ..obs.phases import device_phase
 from ..oram.path_oram import (
     OramConfig,
@@ -350,12 +355,8 @@ def expiry_sweep(
 
     # --- rebuild the free-block list from surviving record liveness ----
     # stable partition (free indices first, each side in index order):
-    # the 1-bit counting pass of the radix-rank engine — two exclusive
-    # ranks + one unique scatter, O(n), sort-free under every sort_impl
-    # (this site's O(n log n) argsort was retired in Round 5; the shared
-    # primitive keeps the idiom in one place). Identical output by
-    # construction: pos is exactly where a stable free-first partition
-    # puts each index.
+    # two exclusive ranks + one unique scatter, O(n), no sort. pos is
+    # exactly where a stable free-first partition puts each index.
     pos = partition_rank(present).astype(U32)
     freelist = (
         jnp.zeros((n_msgs,), U32)

@@ -314,7 +314,6 @@ def engine_round_step(
         mb1, out_a, leaf_a = oram_round(
             ecfg.mb, state.mb, idxs_mb_flat, nl_a, dl_a,
             apply_a, axis_name,
-            occ_impl=ecfg.vphases_impl, sort_impl=ecfg.sort_impl,
             pm_new_leaves=pm["a"][0], pm_dummy_leaves=pm["a"][1],
         )
     with device_phase("freelist_counters"):
@@ -360,7 +359,6 @@ def engine_round_step(
         rec1, out_b, leaf_b = oram_round(
             ecfg.rec, state.rec, idx_b, nl_b, dl_b,
             apply_b, axis_name,
-            occ_impl=ecfg.vphases_impl, sort_impl=ecfg.sort_impl,
             pm_new_leaves=pm["b"][0], pm_dummy_leaves=pm["b"][1],
         )
 
@@ -388,7 +386,6 @@ def engine_round_step(
         mb2, _out_c, leaf_c = oram_round(
             ecfg.mb, mb1, idxs_mb_flat, nl_c, dl_c,
             apply_c, axis_name,
-            occ_impl=ecfg.vphases_impl, sort_impl=ecfg.sort_impl,
             pm_new_leaves=pm["c"][0], pm_dummy_leaves=pm["c"][1],
         )
 

@@ -75,12 +75,15 @@ class EngineConfig:
     mb_table_buckets: int
     mb_slots: int  # K mailboxes per hash bucket
     mb_choices: int = 1  # hash choices per recipient (2 = power-of-two)
-    #: slot-order machinery (engine/vphases.py): "dense" [B,B] masks or
-    #: "scan" sort + segmented scans — bit-identical semantics
-    vphases_impl: str = "dense"
-    #: bounded-key sort engine (oblivious/radix.py): "xla" comparison
-    #: sorts or "radix" counting passes — bit-identical permutations
-    sort_impl: str = "xla"
+    # Constants that cannot be set, stand-ins for two deleted options
+    # (PR 49: there is one slot-order machinery and one sort).
+    # benchmarks/lib/harness.py:218-219 and chip_smoke.py print them in
+    # every run's `init` line, and as fields they stay in ``repr``, so
+    # `engine_fingerprint` is what it was for every state directory a TPU
+    # wrote (tests/test_checkpoint.py pins the digest). They go with the
+    # next checkpoint VERSION (ROADMAP Design 1, debt (i)).
+    vphases_impl: str = dataclasses.field(default="dense", init=False)
+    sort_impl: str = dataclasses.field(default="xla", init=False)
     #: resolved position-map implementation (oram/posmap.py): "flat" or
     #: "recursive" — the per-tree geometry lives in rec.posmap/mb.posmap
     #: (PosMapSpec), which the checkpoint fingerprint covers via repr
@@ -110,35 +113,16 @@ class EngineConfig:
         m = cfg.mailbox_table_buckets
         k = max(1, cfg.mailbox_slots)
         mb_value_words = k * (KEY_WORDS + ENTRY_WORDS * cfg.mailbox_cap)
-        vimpl = cfg.vphases_impl
-        simpl = cfg.sort_impl
         cimpl = cfg.bucket_cipher_impl
-        if vimpl is None or simpl is None or cimpl is None:
-            # per-backend defaults: the MXU eats the [B,B] masks and
-            # lowers lax.sort to a parallel bitonic network; scalar
-            # backends pay O(B²) masks and *serial* comparison sorts
-            # directly (config.py knob docstrings). Resolved here —
+        if cimpl is None:
+            # the one-pass Pallas kernel where Mosaic compiles it; the
+            # CPU would run it in interpret mode, a step a row tile
+            # (PERF.md §6, PR 40: the chip's A/B). Resolved here —
             # engine construction time — because config objects must
             # stay importable without initializing a JAX backend.
             from ..config import on_tpu
 
-            if vimpl is None:
-                vimpl = "dense" if on_tpu() else "scan"
-            if simpl is None:
-                # "xla" on EVERY backend until measured otherwise: on
-                # XLA:CPU the native serial sort (~0.4 µs/elem) beats
-                # any scatter-per-pass radix formulation (~80 ns/elem
-                # PER scatter, one per pass — bench.py `sort_ab`,
-                # PERF.md Round 7); on TPU — where scatters vectorize
-                # and the bitonic lax.sort is the O(n log² n) side —
-                # the decision belongs to a `sort_perf` A/B on a real
-                # chip: not measured on the chip.
-                simpl = "xla"
-            if cimpl is None:
-                # the one-pass Pallas kernel where Mosaic compiles it;
-                # the CPU would run it in interpret mode, a step a
-                # row tile (PERF.md §6, PR 40: the chip's A/B)
-                cimpl = "pallas" if on_tpu() else "jnp"
+            cimpl = "pallas" if on_tpu() else "jnp"
         # position-map impl: auto resolves to "flat" on every backend —
         # the recursive map trades ~2× HBM path traffic per round for a
         # ~sqrt(blocks)× smaller resident footprint, a win only once
@@ -206,8 +190,6 @@ class EngineConfig:
             mb_table_buckets=m,
             mb_slots=k,
             mb_choices=cfg.resolved_mailbox_choices,
-            vphases_impl=vimpl,
-            sort_impl=simpl,
             posmap_impl=pimpl,
             tree_top_cache_levels=tc,
         )

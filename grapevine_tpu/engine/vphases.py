@@ -5,34 +5,22 @@ documents the semantics per phase) were originally applied op-by-op under
 ``lax.scan``. On TPU a scan body costs ~30-130µs *per iteration* (each
 tiny op in the body pays fixed sequencer overhead), which made the scans
 >99% of round latency. This module computes identical slot-order
-semantics with **no per-op loop at all**, via one of two selectable
-implementations (``ecfg.vphases_impl``):
+semantics with **no per-op loop at all**: same-key chains (ops on one
+record / one mailbox in one round) become [B,B] masked matrices — "did
+any earlier op of my group do X" — with OR-aggregates as one-hot
+bool-matmuls on the MXU. O(B²) compute and intermediate memory, but
+every op is a wide matrix/reduction the MXU/VPU eat at the batch sizes
+the engine runs (every op with a [B,B] or [B·D,B] operand together is
+0.31 ms of a device round at B = 2048, ROADMAP Speed 6).
+tests/test_vphases.py holds each group query to a plain Python loop and
+the whole engine to the CPU oracle.
 
-- ``"dense"``: same-key chains (ops on one record / one mailbox in one
-  round) become [B,B] masked matrices — "did any earlier op of my group
-  do X" — with OR-aggregates as one-hot bool-matmuls on the MXU. O(B²)
-  compute and intermediate memory, but every op is a wide
-  matrix/reduction the MXU/VPU eat for free at moderate B.
-- ``"scan"``: the same aggregations in O(B log B) with **no [B,B]
-  intermediate at all** — sort ops by (group key, slot), answer
-  count/any-of-earlier-flagged and OR/sum-over-group queries as
-  segmented scans over the sorted order (oblivious/segmented.py), then
-  invert the permutation back to slot order. This is the
-  bandwidth-shaped form accelerator oblivious-map work (BOLT, Palermo —
-  PAPERS.md) gets its throughput from, and the form that scales past
-  B=2048 where the [B,B] masks start to own the round.
-
-Both implementations are bit-identical in responses AND final engine
-state (tests/test_vphases_scan.py holds them equal against each other
-and the CPU oracle); the per-backend default lives in
-``EngineConfig.from_config`` (engine/state.py).
-
-Common machinery either way:
+Beside the masks:
 
 - the mailbox occupancy walk (CREATE = min(count+1, cap), zero-id DELETE
   pop = max(count-1, 0)) is a *saturating-counter* walk, computed exactly
   with a segmented associative scan in O(log B) depth
-  (oblivious/segmented.py) — both impls share it;
+  (oblivious/segmented.py);
 - entry selection ("pop the oldest") becomes a per-mailbox sort by seq +
   a rank gather;
 - final block values are rebuilt once per touched bucket with shifts and
@@ -43,20 +31,18 @@ Admission quotas (bus capacity, recipient-table capacity) couple ops
 state — admission decouples and everything above is exact. When the bus
 or recipient table is within B of saturation, a fallback ``lax.scan``
 over [B] resolves just the admission bits sequentially (tiny body —
-counters only, no values; identical under both impls). The branch
-predicate reveals only "bus or recipient table nearly full", an
-aggregate the reference's own error responses already expose to clients
-(and Create is permitted to be distinguishable, reference
-grapevine.proto:120-122); per-op secrets never influence the branch.
+counters only, no values). The branch predicate reveals only "bus or
+recipient table nearly full", an aggregate the reference's own error
+responses already expose to clients (and Create is permitted to be
+distinguishable, reference grapevine.proto:120-122); per-op secrets
+never influence the branch.
 
-Obliviousness note for the scan impl: it gathers at sort permutations
-and segment-boundary indices, which are functions of the batch's
-same-key structure — exactly the standing the existing admission walk's
-``group_sort`` already has (and the working-set row maps in
-oram/round.py): these are private-working-memory accesses, the EPC
-analog, not the HBM bucket-tree transcript obliviousness is claimed
-for. Dedup inside oram_round keeps same-key ops uncorrelated in the
-public transcript under either impl.
+Obliviousness note: the admission walk gathers at its ``group_sort``
+permutation, a function of the batch's same-key structure, like the
+working-set row maps in oram/round.py: these are private-working-memory
+accesses, the EPC analog, not the HBM bucket-tree transcript
+obliviousness is claimed for. Dedup inside oram_round keeps same-key ops
+uncorrelated in the public transcript.
 
 Semantics notes vs the original chain engine (mirrored by the oracle):
 
@@ -87,13 +73,8 @@ from ..oblivious.primitives import (
 from ..oblivious.prp import prp2_encrypt
 from ..oblivious.segmented import (
     group_sort,
-    multiword_group_sort,
     sat_apply,
-    segment_bounds,
     segmented_exclusive_sat_scan,
-    segmented_scan,
-    segmented_sum_before,
-    segmented_sum_total,
 )
 from ..wire import constants as C
 from .state import (
@@ -147,7 +128,7 @@ def _bool_matmul(m: jax.Array, u: jax.Array) -> jax.Array:
 
 
 # ----------------------------------------------------------------------
-# group aggregation engine: one semantics, two implementations
+# group aggregation engine
 # ----------------------------------------------------------------------
 #
 # Every within-round chain question the three phases ask is one of a
@@ -164,24 +145,20 @@ def _bool_matmul(m: jax.Array, u: jax.Array) -> jax.Array:
 #                          (optionally restricted to at-or-before me)
 #   select_by_rank(f,v,q)  v-row of my group's q-th flagged op (0 if none)
 #
-# _DenseGroups answers them with [B,B] masks and one-hot matmuls;
-# _SortedGroups answers them in O(B log B) with one multi-word sort and
-# segmented scans. The two are bit-identical on every method for the
-# flag patterns the phases produce (dummy ops never raise flags — all
-# flags are masked by is_real), which the A/B test suite enforces
-# end-to-end.
+# _DenseGroups answers them with [B,B] masks and one-hot matmuls, and
+# is the one place that knows that formulation. Dummy ops never raise
+# flags (all flags are masked by is_real).
 
 
 class _DenseGroups:
-    """[B,B]-mask implementation (``vphases_impl="dense"``)."""
+    """The group queries over a [B,B] same-group mask."""
 
     def __init__(self, same: jax.Array):
         b = same.shape[0]
         self.b = b
         # real ops already include themselves in `same`; adding the
         # diagonal only turns dummy rows into singleton groups, which
-        # matches the sorted impl and never changes a flagged result
-        # (dummies raise no flags)
+        # never changes a flagged result (dummies raise no flags)
         self.m = same | jnp.eye(b, dtype=jnp.bool_)
         self._same = same
 
@@ -236,151 +213,24 @@ class _DenseGroups:
         )
 
 
-class _SortedGroups:
-    """Sort + segmented-scan implementation (``vphases_impl="scan"``).
-
-    One O(B log B) variadic sort orders ops by (group key, slot); every
-    aggregation is then a cumsum / segmented scan over the sorted order
-    plus a permutation inverse — no [B,B] intermediate anywhere.
-
-    ``sort_impl="radix"`` with a declared per-column ``key_bits`` bound
-    swaps the comparison sort for bounded-key counting passes
-    (oblivious/radix.py) — bit-identical (perm, inv, seg). Callers that
-    cannot bound their key (the 256-bit recipient pubkey) pass
-    ``key_bits=None`` and keep ``lax.sort``; radix itself refuses keys
-    wider than ``MAX_RADIX_BITS`` so correctness can never silently
-    ride on a hashed-down key.
-    """
-
-    def __init__(self, cols, key_bits=None, sort_impl: str = "xla"):
-        if sort_impl == "radix" and key_bits is not None:
-            from ..oblivious.radix import radix_group_sort
-
-            self.perm, self.inv, self.seg = radix_group_sort(cols, key_bits)
-        else:
-            self.perm, self.inv, self.seg = multiword_group_sort(cols)
-        b = self.perm.shape[0]
-        self.b = b
-        self.start, self.end = segment_bounds(self.seg)
-        self._pi = self.perm.astype(I32)
-
-    def _to(self, x):
-        return x[self.perm]
-
-    def _back(self, x):
-        return x[self.inv]
-
-    def _counts_before_sorted(self, f):
-        return segmented_sum_before(f, self.seg, (self.start, self.end))
-
-    def _total_sorted(self, x):
-        return segmented_sum_total(x, self.seg, (self.start, self.end))
-
-    def counts_before(self, flags):
-        return self._back(self._counts_before_sorted(self._to(flags)))
-
-    def any_before(self, flags):
-        return self.counts_before(flags) > 0
-
-    def total_sum(self, flags):
-        return self._back(self._total_sorted(self._to(flags)))
-
-    def total_or(self, flags):
-        return self.total_sum(flags) > 0
-
-    def total_sum_rows(self, u):
-        return self._back(self._total_sorted(self._to(u)))
-
-    def total_or_rows(self, u):
-        return self.total_sum_rows(u) > 0
-
-    def group_first(self):
-        return self._back(self.perm[self.start])
-
-    def group_last(self):
-        return self._back(self.perm[self.end])
-
-    def first_flag_index(self, flags):
-        v = jnp.where(self._to(flags), self._pi, I32(self.b))
-        m = segmented_scan(v, self.seg, jnp.minimum)[self.end]
-        has = m < self.b
-        return self._back(jnp.clip(m, 0, self.b - 1)), self._back(has)
-
-    def last_flag_index_upto(self, flags):
-        v = jnp.where(self._to(flags), self._pi, -1)
-        # within a segment ops sit in slot order, so position-≤-mine is
-        # exactly slot-≤-mine: the inclusive segmented max IS "last
-        # flagged at or before me"
-        return self._back(segmented_scan(v, self.seg, jnp.maximum))
-
-    def last_flag_index(self, flags):
-        v = jnp.where(self._to(flags), self._pi, -1)
-        return self._back(segmented_scan(v, self.seg, jnp.maximum)[self.end])
-
-    def select_by_rank(self, flags, vals, q):
-        f = self._to(flags)
-        rank = self._counts_before_sorted(f)
-        # each flagged op owns sorted slot (segment start + its rank):
-        # in-segment, collision-free — scatter values, gather at q
-        tgt = jnp.where(f, self.start + rank, I32(self.b))
-        table = (
-            jnp.zeros((self.b,) + vals.shape[1:], vals.dtype)
-            .at[tgt]
-            .set(self._to(vals), mode="drop", unique_indices=True)
-        )
-        q_s = self._to(q)
-        nfl = self._total_sorted(f)
-        pos = jnp.clip(self.start + q_s, 0, self.b - 1)
-        ok = (q_s >= 0) & (q_s < nfl)
-        return self._back(jnp.where(ok[:, None], table[pos], 0))
-
-
-def _recipient_groups(ecfg: EngineConfig, ka: jax.Array, is_real: jax.Array):
+def _recipient_groups(ka: jax.Array, is_real: jax.Array):
     """Groups over the recipient key ka (dummies singleton)."""
-    b = ka.shape[0]
-    if ecfg.vphases_impl == "dense":
-        requal = (
-            words_equal(ka[:, None, :], ka[None, :, :])
-            & is_real[:, None]
-            & is_real[None, :]
-        )
-        return _DenseGroups(requal)
-    iota = jnp.arange(b, dtype=U32)
-    # key = (real?, ka words, dummy-uniquifier): real ops group by ka,
-    # each dummy is its own group. 1 + 8·32 + 32 declared bits — far
-    # past MAX_RADIX_BITS, so this sort stays on lax.sort under every
-    # sort_impl (radix would demand a hashed-down key, and grouping
-    # correctness must never depend on a hash).
-    cols = (
-        [(~is_real).astype(U32)]
-        + [ka[:, w] for w in range(KEY_WORDS)]
-        + [jnp.where(is_real, U32(0), iota)]
+    requal = (
+        words_equal(ka[:, None, :], ka[None, :, :])
+        & is_real[:, None]
+        & is_real[None, :]
     )
-    return _SortedGroups(cols)
+    return _DenseGroups(requal)
 
 
-def _index_groups(ecfg: EngineConfig, idx: jax.Array, is_real: jax.Array,
-                  dummy_base: int):
-    """Groups over a single u32 index column (bucket / record block).
-
-    ``dummy_base``: sorted-impl uniquifier base for dummy ops — any
-    value with ``dummy_base + iota`` disjoint from real index values.
-    """
-    b = idx.shape[0]
-    if ecfg.vphases_impl == "dense":
-        eq = (
-            (idx[:, None] == idx[None, :])
-            & is_real[:, None]
-            & is_real[None, :]
-        )
-        return _DenseGroups(eq)
-    iota = jnp.arange(b, dtype=U32)
-    # bounded key: real < dummy_base, dummies dummy_base..dummy_base+B-1
-    return _SortedGroups(
-        [jnp.where(is_real, idx, U32(dummy_base) + iota)],
-        key_bits=max(1, (dummy_base + b - 1).bit_length()),
-        sort_impl=ecfg.sort_impl,
+def _index_groups(idx: jax.Array, is_real: jax.Array):
+    """Groups over a single u32 index column (bucket / record block)."""
+    eq = (
+        (idx[:, None] == idx[None, :])
+        & is_real[:, None]
+        & is_real[None, :]
     )
+    return _DenseGroups(eq)
 
 
 def _mb_parse_batch(ecfg: EngineConfig, vals: jax.Array):
@@ -484,12 +334,7 @@ def _admission_fast(
     add = jnp.where(create_elem, 1, jnp.where(pop_elem, -1, 0)).astype(I32)
     lo = jnp.zeros((b,), I32)
     hi = jnp.full((b,), cap, I32)
-    # rslot is a slot index (< B) — bounded, so the walk's grouping sort
-    # follows the sort_impl knob under BOTH vphases impls
-    perm, inv, seg = group_sort(
-        rslot, sort_impl=ecfg.sort_impl,
-        key_bits=max(1, (b - 1).bit_length()),
-    )
+    perm, inv, seg = group_sort(rslot)
     pre = segmented_exclusive_sat_scan((add[perm], lo[perm], hi[perm]), seg)
     count_before = sat_apply(pre, init_count[perm])[inv]
 
@@ -525,8 +370,7 @@ def _admission_slow(
     A tiny scan over counters only — no block values — so its per-op cost
     is bounded by a dozen scalar/[B]-element ops. Runs only when the bus
     or recipient table is within B of full (see module docstring for the
-    leak analysis of the branch). Shared verbatim by both vphases
-    implementations."""
+    leak analysis of the branch)."""
     b = rslot.shape[0]
     cap = ecfg.mailbox_cap
     iota = jnp.arange(b, dtype=U32)
@@ -619,7 +463,7 @@ def phase_a_batch(ecfg: EngineConfig, ctx: dict):
 
     # recipient groups (ka equality); bucket groups move inside the
     # callback — the effective bucket depends on fetched occupancy
-    groups_r = _recipient_groups(ecfg, ka, is_real)
+    groups_r = _recipient_groups(ka, is_real)
     rslot = groups_r.group_first()
 
     def apply_batch(vals0, present0):
@@ -649,9 +493,7 @@ def phase_a_batch(ecfg: EngineConfig, ctx: dict):
         eff_idx = jnp.where(is_real, eff_idx, m_sentinel + U32(1) + iota)
 
         # bucket groups over the effective bucket (dummies unique)
-        groups_g = _index_groups(
-            ecfg, eff_idx, is_real, ecfg.mb_table_buckets + 1
-        )
+        groups_g = _index_groups(eff_idx, is_real)
         gslot = groups_g.group_first()
         glast = groups_g.group_last()
 
@@ -877,7 +719,7 @@ def phase_b_batch(ecfg: EngineConfig, ctx: dict):
     realb = ctx["real_b"]
     # record-block groups; dummies (idx_b = rec.dummy_index, shared)
     # must stay singletons, exactly as the realb-masked dense equality
-    groups_k = _index_groups(ecfg, ctx["idx_b"], realb, ecfg.rec.blocks + 1)
+    groups_k = _index_groups(ctx["idx_b"], realb)
     now = ctx["now"]
     create_ev = ctx["is_create"] & ctx["create_ok"] & realb
 
@@ -1027,23 +869,9 @@ def phase_c_batch(ecfg: EngineConfig, ctx: dict):
 
         # aggregate op mutations onto every row of the op's bucket
         rows_idx = idxs_mb2.reshape(b * d)  # [B*D]
-        if ecfg.vphases_impl == "dense":
-            row_op = (rows_idx[:, None] == eff_idx[None, :]) & mutating[None, :]
-            clear = _bool_matmul(row_op, u_clear).reshape(b * d, k, cap)
-            refr = _bool_matmul(row_op, u_refresh).reshape(b * d, k, cap)
-        else:
-            # bucket table: non-mutating ops scatter all-false vectors
-            # into the sentinel row, which dummy/unmutated rows then read
-            # back as zeros — identical to the masked matmul
-            u2 = jnp.stack([u_clear, u_refresh], axis=1).astype(I32)
-            tbl = (
-                jnp.zeros((ecfg.mb_table_buckets + 1, 2, k * cap), I32)
-                .at[jnp.minimum(eff_idx, m_sentinel)]
-                .add(u2)
-            )
-            agg = tbl[jnp.minimum(rows_idx, m_sentinel)] > 0
-            clear = agg[:, 0].reshape(b * d, k, cap)
-            refr = agg[:, 1].reshape(b * d, k, cap)
+        row_op = (rows_idx[:, None] == eff_idx[None, :]) & mutating[None, :]
+        clear = _bool_matmul(row_op, u_clear).reshape(b * d, k, cap)
+        refr = _bool_matmul(row_op, u_refresh).reshape(b * d, k, cap)
 
         rows_entries = entries_c.reshape(b * d, k, cap, ENTRY_WORDS)
         rows_keys = keys_c.reshape(b * d, k, 8)

@@ -27,8 +27,8 @@ byte-shuffles would be a bitsliced Pallas project for strictly worse
 throughput at no security gain over ChaCha.
 
 Stream order (the ONE definition; :func:`stream_tiles` and
-:func:`group_words`, which the jnp path below and the Pallas kernels of
-pallas_cipher.py / pallas_gather.py all go through): stream position ``p``
+:func:`group_words`, which the jnp path below and the Pallas kernel of
+pallas_cipher.py both go through): stream position ``p``
 of a row is **state word ``(p // 128) % 16`` of the block whose counter
 is ``(p // 2048) * 128 + p % 128``**. So every 128-lane tile ``q`` of
 the stream is one whole state word (``q % 16``) of one group of 128
@@ -191,9 +191,8 @@ def keystream_tile(key, n1, n2, n3, rows: int, n_words: int, rounds: int):
     """ChaCha keystream u32[rows, n_words] in stream order, unmasked,
     for rows whose nonce words are ``n1/n2/n3`` ([rows, 1] or scalars)
     and ``key`` eight u32 scalars: the stream as ONE array, for the jnp
-    path and for the kernels that want it so (pallas_gather.py's one-row
-    fetch and write-back; pallas_cipher.py's XOR kernel stores tile by
-    tile instead). A stream under one lane tile computes only its own
+    path (pallas_cipher.py's XOR kernel stores tile by tile instead).
+    A stream under one lane tile computes only its own
     blocks of state word 0."""
     lanes = min(LANES, n_words)
     # 2-D iota: the one form Mosaic takes, and jnp alike

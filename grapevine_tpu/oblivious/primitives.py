@@ -91,6 +91,36 @@ def rank_of(mask):
     incl = jnp.cumsum(m)
     return jnp.concatenate([jnp.zeros((1,), jnp.int32), incl[:-1]])
 
+
+def partition_rank(flags):
+    """Positions of a stable two-way partition (False first): i32[B].
+
+    ``pos[i]`` is where element i lands when all False-flagged elements
+    precede all True ones, each side in original order: two exclusive
+    ranks, no sort and no bin table. The expiry sweep's freelist rebuild
+    is exactly this pass (engine/expiry.py).
+    """
+    digit = jnp.asarray(flags).astype(jnp.int32)
+    b = digit.shape[0]
+    iota = jnp.arange(b, dtype=jnp.int32)
+    # the max/clip below are runtime identities (an exclusive prefix
+    # never exceeds its position, a permutation never exceeds B-1)
+    # written so a non-relational interval domain (analysis/rangelint.py)
+    # can carry the bound instead of widening to 2B — which would escape
+    # int32 at the 2^30 certified geometry
+    incl = jnp.cumsum(digit)
+    ones_before = jnp.concatenate([jnp.zeros((1,), jnp.int32), incl[:-1]])
+    zeros_before = jnp.maximum(iota - ones_before, 0)
+    n_zeros = jnp.maximum(b - incl[-1], 0)
+    # n_zeros + ones_before <= B-1 truly (a stable partition is a
+    # permutation) but sums to 2B in interval arithmetic; the add rides
+    # RANGE_ALLOWLIST and the clip re-bounds the permutation downstream
+    return jnp.clip(
+        jnp.where(digit == 1, n_zeros + ones_before, zeros_before),
+        0, b - 1,
+    )
+
+
 def u64_add_u32(lo, hi, k):
     """(lo, hi) + k with carry — u64 arithmetic in u32 lanes (x64 off)."""
     s = lo + k

@@ -109,24 +109,14 @@ def segmented_exclusive_sat_scan(elems, seg_start):
     return excl
 
 
-def group_sort(group: jax.Array, sort_impl: str = "xla",
-               key_bits: int | None = None):
+def group_sort(group: jax.Array):
     """Stable permutation ordering ops by (group, slot).
 
     group: u32[B] group id per op (e.g. the first-occurrence slot of the
     op's key). Returns (perm, inv, seg_start_sorted):
     ``x[perm]`` is segment-contiguous, ``y[inv]`` undoes it, and
     seg_start marks group boundaries in sorted order.
-
-    ``sort_impl="radix"`` with a declared ``key_bits`` bound computes
-    the same permutation with counting passes instead of a comparison
-    sort (oblivious/radix.py) — bit-identical outputs, zero ``sort``
-    HLO; without a declared bound the XLA sort is kept.
     """
-    if sort_impl == "radix" and key_bits is not None:
-        from .radix import radix_group_sort
-
-        return radix_group_sort([group], key_bits)
     perm = jnp.argsort(group, stable=True)  # stable ⇒ slot order
     inv = jnp.argsort(perm)
     sorted_g = group[perm]
@@ -134,105 +124,3 @@ def group_sort(group: jax.Array, sort_impl: str = "xla",
         [jnp.ones((1,), jnp.bool_), sorted_g[1:] != sorted_g[:-1]]
     )
     return perm, inv, seg_start
-
-
-def segmented_counts_before(group: jax.Array, flags: jax.Array) -> jax.Array:
-    """#True flags among earlier ops of the same group, per op. O(B²) mask.
-
-    Cheap and simple for B ≤ a few thousand; use the sorted scans above
-    only where clamping (saturation) is required.
-    """
-    b = group.shape[0]
-    same = group[:, None] == group[None, :]
-    earlier = jnp.tril(jnp.ones((b, b), jnp.bool_), k=-1)
-    return jnp.sum((same & earlier) & flags[None, :], axis=1).astype(I32)
-
-
-# ----------------------------------------------------------------------
-# sort-based grouping over multi-word keys (the O(B log B) forms the
-# scan vphases implementation builds on — no [B,B] mask anywhere)
-# ----------------------------------------------------------------------
-
-
-def multiword_group_sort(cols):
-    """Permutation ordering ops by a multi-word key, then slot.
-
-    ``cols``: sequence of u32[B] key words, most significant first.
-    Returns ``(perm, inv, seg_start)`` like `group_sort`: ``x[perm]`` is
-    segment-contiguous with ops in slot order within each segment (the
-    slot index rides as the final sort key, so no stability assumption),
-    ``y[inv]`` undoes it, and ``seg_start`` marks group boundaries in
-    sorted order. One variadic O(B log B) device sort.
-    """
-    cols = [jnp.asarray(c) for c in cols]
-    b = cols[0].shape[0]
-    iota = jnp.arange(b, dtype=jnp.uint32)
-    out = jax.lax.sort(
-        tuple(cols) + (iota,), num_keys=len(cols) + 1, is_stable=False
-    )
-    perm = out[-1]
-    neq = jnp.zeros((b - 1,), jnp.bool_)
-    for k in out[:-1]:
-        neq = neq | (k[1:] != k[:-1])
-    seg_start = jnp.concatenate([jnp.ones((1,), jnp.bool_), neq])
-    inv = jnp.zeros((b,), jnp.uint32).at[perm].set(iota, unique_indices=True)
-    return perm, inv, seg_start
-
-
-def segment_bounds(seg_start: jax.Array):
-    """Per element: the index of its segment's first and last element.
-
-    ``seg_start``: bool[B] in segment-contiguous (sorted) order. Both
-    returned arrays are i32[B] in the same order; O(log B) via cummax /
-    cummin.
-    """
-    b = seg_start.shape[0]
-    iota = jnp.arange(b, dtype=I32)
-    start = jax.lax.cummax(jnp.where(seg_start, iota, 0))
-    is_last = jnp.concatenate([seg_start[1:], jnp.ones((1,), jnp.bool_)])
-    end = jnp.flip(jax.lax.cummin(jnp.flip(jnp.where(is_last, iota, b - 1))))
-    return start, end
-
-
-def segmented_scan(vals: jax.Array, seg_start: jax.Array, op):
-    """Inclusive segmented scan of ``op`` (associative) along axis 0.
-
-    ``vals``: [B, ...] in segment-contiguous order; ``seg_start``
-    bool[B]. Standard flagged-operator trick: a segment start resets the
-    running aggregate. O(log B) depth via ``lax.associative_scan``.
-    """
-
-    def combine(x, y):
-        xs, xv = x
-        ys, yv = y
-        ysb = ys.reshape(ys.shape + (1,) * (yv.ndim - ys.ndim))
-        return (xs | ys, jnp.where(ysb, yv, op(xv, yv)))
-
-    _, out = jax.lax.associative_scan(combine, (seg_start, vals))
-    return out
-
-
-def segmented_sum_before(
-    vals: jax.Array, seg_start: jax.Array, bounds=None
-) -> jax.Array:
-    """Exclusive segmented sum along axis 0 (i32). ``vals`` [B, ...] in
-    segment-contiguous order — unsegmented cumsum re-based at each
-    segment start (exact in i32; callers sum bounded counts).
-    ``bounds``: optional precomputed ``segment_bounds(seg_start)`` so
-    repeat callers (one group, many queries) pay for it once."""
-    v = vals.astype(I32)
-    c = jnp.cumsum(v, axis=0)
-    start = (segment_bounds(seg_start) if bounds is None else bounds)[0]
-    excl = c - v
-    return excl - excl[start]
-
-
-def segmented_sum_total(
-    vals: jax.Array, seg_start: jax.Array, bounds=None
-) -> jax.Array:
-    """Per-element total sum over its whole segment (i32), axis 0.
-    ``bounds`` as in `segmented_sum_before`."""
-    v = vals.astype(I32)
-    c = jnp.cumsum(v, axis=0)
-    start, end = segment_bounds(seg_start) if bounds is None else bounds
-    return c[end] - (c[start] - v[start])

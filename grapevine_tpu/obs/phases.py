@@ -104,9 +104,7 @@ DEVICE_SCOPES = (
     "cache_write",         # working set -> tree-top cache planes
     # other programs
     "sweep_records", "sweep_mailbox",
-    # bounded-key sorts (oblivious/radix.py), one scope per digit pass
-    "radix_rank", "radix_group_sort",
-) + tuple(f"radix_pass_s{shift}" for shift in range(64))
+)
 
 #: fixed histogram boundaries for phase durations (seconds). Spans the
 #: measured range: ~100 µs host phases at B=8 up to multi-second expiry

@@ -187,7 +187,7 @@ def cipher_rows(
     # plaintext on its way to the plane?
     to_store = pval.ndim == 2 if chunk is None else plane is not None
     tiled = len(cfg.stored_row_shape) == 2
-    kernel = cfg.encrypted and cfg.cipher_impl in ("pallas", "pallas_fused")
+    kernel = cfg.encrypted and cfg.cipher_impl == "pallas"
     if chunk is not None and not kernel:
         first = chunk * U32(r)
         if not to_store:
@@ -252,8 +252,8 @@ class OramConfig:
     #: ChaCha rounds for at-rest bucket encryption; 0 disables the
     #: cipher (oblivious/bucket_cipher.py — the EPC-encryption analog)
     cipher_rounds: int = 0
-    #: "jnp", "pallas" (the one-pass VMEM keystream+XOR kernel; see
-    #: cipher_rows and oblivious/pallas_cipher.py) or "pallas_fused";
+    #: "jnp" or "pallas" (the one-pass VMEM keystream+XOR kernel; see
+    #: cipher_rows and oblivious/pallas_cipher.py);
     #: EngineConfig.from_config resolves the engine's trees to "pallas"
     #: on a TPU; posmap.py pins the recursive map's inner tree to "jnp"
     cipher_impl: str = "jnp"
@@ -587,8 +587,8 @@ def leaf_plane_cipher(
     offset by ``n_buckets_padded``: heap ids never reach that range, so
     the leaf stream can never two-time-pad against the row stream under
     the same (bucket, epoch). Kept out of ``cipher_rows`` on purpose —
-    the fused Pallas fetch/write kernels cover only the idx/val planes,
-    and this jnp path composes with all cipher_impls."""
+    the Pallas kernel covers only the idx/val planes, and this jnp path
+    composes with both cipher_impls."""
     if not cfg.encrypted:
         return pleaf
     ks = row_keystream(

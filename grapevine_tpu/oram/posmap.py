@@ -254,28 +254,13 @@ def init_posmap(cfg, key: jax.Array):
     return RecursivePosMapState(inner=inner, dummy_entry=table[cfg.blocks])
 
 
-def _group_last_slot(idxs, dummy_index, occ_impl, sort_impl, key_bits):
+def _group_last_slot(idxs, dummy_index):
     """u32[B]: the slot of the round's LAST op on the same (real) index;
     dummies get their own slot — the mirror of ``occurrence_masks``'
-    first-occurrence ``chain_slot``, in both the dense [B,B] and the
-    sorted O(B log B) forms (matching the engine's impl knobs so the
-    scan engine's no-[B,B] jaxpr audit holds through the posmap glue)."""
+    first-occurrence ``chain_slot``, in the same [B,B] form."""
     b = idxs.shape[0]
     slot_iota = jnp.arange(b, dtype=U32)
     is_real = idxs != U32(dummy_index)
-    if occ_impl == "scan":
-        from ..oblivious.segmented import segment_bounds
-
-        if sort_impl == "radix":
-            from ..oblivious.radix import radix_group_sort
-
-            perm, inv, seg_start = radix_group_sort([idxs], key_bits)
-        else:
-            from ..oblivious.segmented import multiword_group_sort
-
-            perm, inv, seg_start = multiword_group_sort([idxs])
-        _, end = segment_bounds(seg_start)
-        return jnp.where(is_real, perm[end][inv].astype(U32), slot_iota)
     eq = (idxs[:, None] == idxs[None, :]) & is_real[:, None] & is_real[None, :]
     last = U32(b - 1) - jnp.argmax(eq[:, ::-1], axis=1).astype(U32)
     return jnp.where(is_real, last, slot_iota)
@@ -341,8 +326,6 @@ def lookup_remap_round(
     last_occ: jax.Array,  # bool[B] (this op's remap wins)
     pm_new_leaves: jax.Array | None = None,  # u32[B] internal remaps
     pm_dummy_leaves: jax.Array | None = None,  # u32[B] internal dummies
-    occ_impl: str = "dense",
-    sort_impl: str = "xla",
 ):
     """Resolve B positions with a fixed access schedule.
 
@@ -381,10 +364,7 @@ def lookup_remap_round(
     # onto that row so one committed row carries all of its block's
     # entry writes (distinct outer indices in one block have distinct
     # offsets, so in-bounds targets are unique)
-    last_slot = _group_last_slot(
-        inner_idxs, icfg.dummy_index, occ_impl, sort_impl,
-        key_bits=max(1, icfg.dummy_index.bit_length()),
-    )
+    last_slot = _group_last_slot(inner_idxs, icfg.dummy_index)
 
     def apply_pm(vals0, present0):
         # vals0 u32[B, k]: each op's internal block at round start —
@@ -408,7 +388,6 @@ def lookup_remap_round(
         inner2, looked, inner_leaves = oram_round(
             icfg, pm_state.inner, inner_idxs, pm_new_leaves,
             pm_dummy_leaves, apply_pm,
-            occ_impl=occ_impl, sort_impl=sort_impl,
         )
     # looked-up entries come out of the (decrypted) internal tree, which
     # interval reasoning must treat as opaque; the mask re-establishes

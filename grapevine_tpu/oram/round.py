@@ -61,12 +61,9 @@ O(log B)-depth parallel form.
 from __future__ import annotations
 
 import jax
-
-from ..config import on_tpu as _on_tpu
 import jax.numpy as jnp
 
 from ..oblivious.primitives import SENTINEL, rank_of
-from ..oblivious.radix import radix_rank
 from ..oblivious.bucket_cipher import epoch_next
 from ..obs.phases import device_phase
 from .path_oram import (
@@ -110,8 +107,7 @@ def occurrence_masks(idxs: jax.Array, dummy_index: int):
     slot of the round's first op on the same index (dummies get their own
     slot) — the shared chain-buffer slot for within-round read-after-write.
 
-    The classic [B,B]-mask form; `occurrence_masks_sorted` computes the
-    identical masks in O(B log B) for the scan engine.
+    The classic [B,B]-mask form.
     """
     is_real = idxs != U32(dummy_index)
     eq = (idxs[:, None] == idxs[None, :]) & is_real[:, None] & is_real[None, :]
@@ -121,36 +117,6 @@ def occurrence_masks(idxs: jax.Array, dummy_index: int):
     last_occ = is_real & ~jnp.any(eq & earlier.T, axis=1)
     slot_iota = jnp.arange(b, dtype=U32)
     chain_slot = jnp.where(is_real, jnp.argmax(eq, axis=1).astype(U32), slot_iota)
-    return first_occ, last_occ, chain_slot
-
-
-def occurrence_masks_sorted(idxs: jax.Array, dummy_index: int,
-                            sort_impl: str = "xla",
-                            key_bits: int | None = None):
-    """`occurrence_masks` in O(B log B): one sort by (index, slot), then
-    segment boundaries in sorted order mark first/last occurrences — no
-    [B,B] intermediate (bit-identical outputs; tests/test_round.py).
-
-    ``sort_impl="radix"`` with a declared ``key_bits`` bound (block
-    indices are ≤ log2(blocks)+1 bits — oram_round passes the bound
-    from its geometry) replaces the comparison sort with counting
-    passes (oblivious/radix.py); identical masks either way."""
-    from ..oblivious.segmented import multiword_group_sort, segment_bounds
-
-    b = idxs.shape[0]
-    is_real = idxs != U32(dummy_index)
-    slot_iota = jnp.arange(b, dtype=U32)
-    if sort_impl == "radix" and key_bits is not None:
-        from ..oblivious.radix import radix_group_sort
-
-        perm, inv, seg_start = radix_group_sort([idxs], key_bits)
-    else:
-        perm, inv, seg_start = multiword_group_sort([idxs])
-    start, end = segment_bounds(seg_start)
-    iota_i = jnp.arange(b, dtype=jnp.int32)
-    first_occ = is_real & ((iota_i == start)[inv])
-    last_occ = is_real & ((iota_i == end)[inv])
-    chain_slot = jnp.where(is_real, perm[start][inv], slot_iota)
     return first_occ, last_occ, chain_slot
 
 
@@ -183,7 +149,6 @@ def _assign_evictions(
     wleaf: jax.Array,  # u32[W] leaf assignment per row
     bucket_map: jax.Array,  # u32[n_buckets_padded] heap bucket -> output row
     n_rows: int,  # output bucket rows; doubles as the "not fetched" sentinel
-    sort_impl: str,
     dense_levels: int,  # levels whose buckets are their own output row
 ):
     """Joint level-synchronous greedy eviction assignment (module
@@ -212,23 +177,11 @@ def _assign_evictions(
     no_leaf = U32(0xFFFFFFFF)  # sorts after every leaf: invalid rows go last
     skey = jnp.where(valid, wleaf, no_leaf)
     with device_phase("oram_evict_sort"):
-        if sort_impl == "radix":
-            # leaves are h bits; invalid rows sort last under the 2^h
-            # sentinel exactly as they do under 0xFFFFFFFF (both stable
-            # sorts keep equal keys in working-set order), so the
-            # permutation is bit-identical to the comparison sort's — at
-            # h+1 declared key bits instead of 32. A rank pass returns
-            # the permutation alone, so the keys are read through it
-            eperm = radix_rank(
-                jnp.where(valid, wleaf, U32(1) << U32(h)), h + 1
-            )
-            sleaf = skey[eperm]
-        else:
-            # the keys and the permutation from one stable sort (what
-            # jnp.argsort runs, which then drops the sorted keys)
-            sleaf, eperm = jax.lax.sort(
-                (skey, jnp.arange(w, dtype=jnp.int32)), num_keys=1
-            )
+        # the keys and the permutation from one stable sort (what
+        # jnp.argsort runs, which then drops the sorted keys)
+        sleaf, eperm = jax.lax.sort(
+            (skey, jnp.arange(w, dtype=jnp.int32)), num_keys=1
+        )
     svalid = sleaf != no_leaf  # a live row's leaf is < cfg.leaves
     placed = jnp.zeros((w,), jnp.bool_)  # sorted order
     slot_tgt_s = jnp.full((w,), nslots, U32)  # sorted order; OOB = unplaced
@@ -291,8 +244,6 @@ def oram_round(
     dummy_leaves: jax.Array,  # u32[B] fresh uniform leaves (dummy fetches)
     apply_batch,
     axis_name: str | None = None,
-    occ_impl: str = "dense",
-    sort_impl: str = "xla",
     pm_new_leaves: jax.Array | None = None,  # u32[B] (recursive posmap)
     pm_dummy_leaves: jax.Array | None = None,  # u32[B] (recursive posmap)
 ):
@@ -340,17 +291,6 @@ def oram_round(
     cache: ``top_cache_levels`` decides which rows come from HBM, never
     where a block is placed (tests/test_tree_cache.py, contract 1).
 
-    ``occ_impl``: "dense" = [B,B]-mask dedup, "scan" = sorted dedup with
-    no quadratic intermediate (bit-identical; matches the engine's
-    ``vphases_impl`` knob).
-
-    ``sort_impl``: "xla" = the comparison sorts XLA lowers natively,
-    "radix" = bounded-key counting passes (oblivious/radix.py) for the
-    eviction leaf sort and the sorted dedup — bit-identical
-    permutations, zero ``sort`` HLO in this round (matches the engine's
-    ``GrapevineConfig.sort_impl`` knob; CI-audited in
-    tests/test_radix.py).
-
     With a recursive position map (``cfg.posmap`` set; oram/posmap.py)
     ``pm_new_leaves``/``pm_dummy_leaves`` must supply fresh uniform
     *internal* leaves and the returned ``leaves`` is u32[B, 2]: column 0
@@ -376,19 +316,11 @@ def oram_round(
     with device_phase("oram_fetch"):
         # --- 1. dedup, position-map read/remap, path fetch -----------------
         with device_phase("dedup"):
-            if occ_impl == "scan":
-                # block indices are bounded: real < blocks, dummy = blocks
-                first_occ, last_occ, _ = occurrence_masks_sorted(
-                    idxs, cfg.dummy_index, sort_impl=sort_impl,
-                    key_bits=max(1, cfg.dummy_index.bit_length()),
-                )
-            else:
-                first_occ, last_occ, _ = occurrence_masks(idxs, cfg.dummy_index)
+            first_occ, last_occ, _ = occurrence_masks(idxs, cfg.dummy_index)
         posmap, leaves, inner_leaves = lookup_remap_round(
             cfg, state.posmap, idxs, new_leaves, dummy_leaves,
             first_occ, last_occ,
             pm_new_leaves=pm_new_leaves, pm_dummy_leaves=pm_dummy_leaves,
-            occ_impl=occ_impl, sort_impl=sort_impl,
         )
 
         with device_phase("path_index"):
@@ -427,34 +359,20 @@ def oram_round(
                 [jnp.ones((nd - cb,), jnp.bool_), bmap[sparse_b] == sparse_rows]
             )
 
-    fused = cfg.cipher_impl == "pallas_fused"
     with device_phase("oram_fetch"):
-        if axis_name is None and fused and cfg.encrypted:
-            # single-chip fast path: gather + decrypt in ONE HBM pass
-            # (oblivious/pallas_gather.py); the sharded path below keeps
-            # decrypt-after-psum so tree plaintext never transits ICI
-            from ..oblivious.pallas_gather import gather_decrypt_rows
-
-            pidx, pval = gather_decrypt_rows(
-                state.cipher_key, state.tree_idx, state.tree_val, state.nonces,
-                bot_b, z=z, rounds=cfg.cipher_rounds,
-                interpret=not _on_tpu(),
+        pidx = _path_gather(
+            state.tree_idx.reshape(-1, z), bot_b, axis_name
+        )  # [nbot, z]
+        pval = _path_gather(state.tree_val, bot_b, axis_name)  # [nbot, z*v]
+        pnonce = _path_gather(state.nonces, bot_b, axis_name)
+        with device_phase("cipher_decrypt"):
+            pidx, pval = cipher_rows(
+                cfg, state.cipher_key, bot_b, pnonce, pidx, pval
             )
-        else:
-            pidx = _path_gather(
-                state.tree_idx.reshape(-1, z), bot_b, axis_name
-            )  # [nbot, z]
-            pval = _path_gather(state.tree_val, bot_b, axis_name)  # [nbot, z*v]
-            pnonce = _path_gather(state.nonces, bot_b, axis_name)
-            with device_phase("cipher_decrypt"):
-                pidx, pval = cipher_rows(
-                    cfg, state.cipher_key, bot_b, pnonce, pidx, pval
-                )
         # non-owner copies of shared per-path buckets are invalidated
         pidx = jnp.where(owner_bot[:, None], pidx, SENTINEL)
         if recursive:
-            # per-slot leaf metadata rides its own (jnp) cipher plane —
-            # the fused kernels cover only the idx/val planes
+            # per-slot leaf metadata rides its own (jnp) cipher plane
             from .path_oram import leaf_plane_cipher
 
             pleaf = _path_gather(
@@ -556,7 +474,7 @@ def oram_round(
     with device_phase("oram_evict"):
         valid = widx != SENTINEL
         slot_tgt, placed = _assign_evictions(
-            cfg, valid, wleaf, bmap, nrows, sort_impl, dense_levels=le
+            cfg, valid, wleaf, bmap, nrows, dense_levels=le
         )
 
         # eviction slots are unique by construction (rank < z within a
@@ -603,44 +521,29 @@ def oram_round(
         bot_pval = new_pval[cb * z:].reshape(nbot, z * v)
         epochs_w = jnp.broadcast_to(state.epoch[None, :], (nbot, 2))
     with device_phase("oram_writeback"):
-        if axis_name is None and fused and cfg.encrypted:
-            # single-chip fast path: encrypt + scatter in ONE HBM pass (the
-            # write-back mirror of the fused fetch; pallas_gather.py) —
-            # the nonce commit rides the same kernel, so this branch has no
-            # XLA scatter at all
-            from ..oblivious.pallas_gather import scatter_encrypt_rows
-
-            tree_idx_new, tree_val_new, nonces = scatter_encrypt_rows(
-                state.cipher_key, state.tree_idx, state.tree_val, state.nonces,
-                bot_b, owner_bot, state.epoch,
-                bot_pidx, stored_rows(cfg, bot_pval),
-                z=z, rounds=cfg.cipher_rounds,
-                interpret=not _on_tpu(),
+        with device_phase("cipher_encrypt"):
+            enc_pidx, enc_pval = cipher_rows(
+                cfg,
+                state.cipher_key,
+                bot_b,
+                epochs_w,
+                bot_pidx,
+                bot_pval,
             )
-        else:
-            with device_phase("cipher_encrypt"):
-                enc_pidx, enc_pval = cipher_rows(
-                    cfg,
-                    state.cipher_key,
-                    bot_b,
-                    epochs_w,
-                    bot_pidx,
-                    bot_pval,
-                )
-            tree_idx_new = _path_scatter(
-                state.tree_idx.reshape(-1, z), bot_b, enc_pidx, axis_name,
-                owner_bot,
-            ).reshape(-1)
-            tree_val_new = _path_scatter(
-                state.tree_val, bot_b, enc_pval, axis_name, owner_bot
+        tree_idx_new = _path_scatter(
+            state.tree_idx.reshape(-1, z), bot_b, enc_pidx, axis_name,
+            owner_bot,
+        ).reshape(-1)
+        tree_val_new = _path_scatter(
+            state.tree_val, bot_b, enc_pval, axis_name, owner_bot
+        )
+        nonces = (
+            _path_scatter(
+                state.nonces, bot_b, epochs_w, axis_name, owner_bot
             )
-            nonces = (
-                _path_scatter(
-                    state.nonces, bot_b, epochs_w, axis_name, owner_bot
-                )
-                if cfg.encrypted
-                else state.nonces
-            )
+            if cfg.encrypted
+            else state.nonces
+        )
         # cached levels leave as they came: whole planes, plaintext (a
         # bucket no path met keeps its rows);
         # replicated private state, so no collective even under sharding —

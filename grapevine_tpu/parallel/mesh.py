@@ -153,10 +153,8 @@ def validate_sharded_geometry(ecfg: EngineConfig, mesh: Mesh) -> None:
 
     Everything the sharded step DOES cover is silent here: recursive
     position maps (inner trees replicated), tree-top caching (cache planes
-    replicated), all cipher impls ("pallas_fused" runs as "pallas"
-    inside shard_map — gather, psum, then the Pallas cipher kernel —
-    and GrapevineEngine says so once at WARNING when it is built), both
-    sort/vphases impls.
+    replicated), both cipher impls (gather, psum, then the cipher: tree
+    plaintext never transits ICI).
     """
     n_dev = mesh.devices.size
     for label, cfg in (("records", ecfg.rec), ("mailbox", ecfg.mb)):

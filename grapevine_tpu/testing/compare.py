@@ -6,22 +6,16 @@ import jax
 import numpy as np
 
 __all__ = [
-    "states_equal_excluding_junk",
+    "states_equal",
     "logical_tree_planes",
     "assert_logical_state_equal",
     "logical_block_map",
 ]
 
 
-def states_equal_excluding_junk(sa, sb):
-    """Engine-state bit-equality with the padded junk bucket masked.
-
-    The fused encrypt+scatter kernel redirects non-owner duplicate-row
-    writes to the LAST (padded) bucket of each tree, which heap indices
-    never address (oblivious/pallas_gather.py) — so that bucket's
-    at-rest bytes legitimately differ from the jnp path while every
-    path-addressable byte must match exactly. Z is derived per tree
-    from the paired ``tree_idx``/``tree_val`` leaves, never hardcoded.
+def states_equal(sa, sb):
+    """Engine-state bit-equality, every leaf, at-rest ciphertext and the
+    padded bucket no heap index addresses included.
 
     Returns (equal, first_differing_keypath_or_None).
     """
@@ -33,18 +27,7 @@ def states_equal_excluding_junk(sa, sb):
     }
     lb = dict(zip(la.keys(), map(np.asarray, jax.tree_util.tree_leaves(sb))))
     for key, x in la.items():
-        y = lb[key]
-        if key.endswith("tree_val"):
-            x, y = x[:-1], y[:-1]
-        elif key.endswith("tree_idx"):
-            val = la[key[: -len("tree_idx")] + "tree_val"]
-            z = x.size // val.shape[0]
-            x, y = x[:-z], y[:-z]
-        elif key.endswith("nonces"):
-            # the fused kernel also commits the write epoch through the
-            # junk redirect, so the junk bucket's nonce row differs too
-            x, y = x[:-1], y[:-1]
-        if not np.array_equal(x, y):
+        if not np.array_equal(x, lb[key]):
             return False, key
     return True, None
 
@@ -144,7 +127,8 @@ def assert_logical_state_equal(ecfg_a, sa, ecfg_b, sb, ctx=""):
         for name, x, y in zip(("idx", "val", "leaf"), pa, pb):
             if x is None and y is None:
                 continue
-            # mask the padded junk bucket (states_equal_excluding_junk)
+            # but for the padded last bucket, which no heap index
+            # addresses
             assert np.array_equal(x[:-1], y[:-1]), (
                 f"{ctx}: {tree} logical {name} plane diverges"
             )

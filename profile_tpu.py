@@ -5,7 +5,7 @@ PERF.md lever 1: replace the analytic ~5-10 ms/round cost model with a
 trace-backed attribution. Run on a machine with a TPU, as the one
 process that uses it:
 
-    python profile_tpu.py [--impl jnp|pallas|pallas_fused]
+    python profile_tpu.py [--impl jnp|pallas]
                           [--cap-log2 20] [--batch 2048] [--rounds 8]
                           [--outdir chiprun_out/grapevine-trace]
 
@@ -29,8 +29,7 @@ import time
 
 def main() -> int:
     ap = argparse.ArgumentParser()
-    ap.add_argument("--impl", default="jnp",
-                    choices=["jnp", "pallas", "pallas_fused"])
+    ap.add_argument("--impl", default="jnp", choices=["jnp", "pallas"])
     ap.add_argument("--cap-log2", type=int, default=20)
     ap.add_argument("--batch", type=int, default=2048)
     ap.add_argument("--rounds", type=int, default=8)

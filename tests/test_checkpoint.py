@@ -388,3 +388,46 @@ def test_checkpoint_seal_gate_passes():
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     assert mod.main() == 0
+
+
+# -- the fingerprint across PR 49 (one slot-order machinery, one sort) ---
+
+
+@pytest.mark.parametrize("posmap,digest", [
+    ("flat",
+     "5a18ee82fee8ecb7279fc51832ebda17fc412b46185afb1e779589025386476c"),
+    ("recursive",
+     "8d862fe580009f9a6933713cb8f91e9ee2880fefded227ddc02d75fe2159a598"),
+])
+def test_engine_fingerprint_is_what_a_tpu_wrote_before_pr_49(posmap, digest):
+    """The digests the parent of PR 49 gave this geometry resolved as a
+    TPU resolved it (``vphases_impl="dense"``, the Pallas cipher). They
+    move only with a checkpoint ``VERSION``: ``repr(ecfg)`` decides
+    whether a state directory loads, so a field added to, dropped from
+    or renamed in ``EngineConfig`` or ``OramConfig`` refuses every
+    directory a deployment holds."""
+    ecfg = EngineConfig.from_config(GrapevineConfig(
+        max_messages=1 << 12, max_recipients=1 << 8, batch_size=16,
+        bucket_cipher_impl="pallas", posmap_impl=posmap))
+    assert "vphases_impl='dense', sort_impl='xla'" in repr(ecfg)
+    assert cp.engine_fingerprint(ecfg) == digest
+
+
+@pytest.mark.parametrize("knob", [
+    {"vphases_impl": "dense"}, {"sort_impl": "xla"},
+    {"bucket_cipher_impl": "pallas_fused"},
+], ids=["vphases_impl", "sort_impl", "pallas_fused"])
+def test_the_deleted_implementation_knobs_are_refused(knob):
+    """``vphases_impl`` and ``sort_impl`` are no options of the
+    configuration any more, at any value, and are constants of
+    ``EngineConfig`` that its constructor does not take;
+    ``"pallas_fused"`` is refused like any unknown cipher name."""
+    import dataclasses
+
+    with pytest.raises((TypeError, ValueError), match=next(iter(knob))):
+        GrapevineConfig(**knob)
+    ecfg = EngineConfig.from_config(SMALL)
+    assert (ecfg.vphases_impl, ecfg.sort_impl) == ("dense", "xla")
+    if "bucket_cipher_impl" not in knob:
+        with pytest.raises((TypeError, ValueError), match="init=False"):
+            dataclasses.replace(ecfg, **knob)

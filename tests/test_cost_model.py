@@ -138,7 +138,6 @@ def test_ab_verdicts_shape():
                           batch=64)
         assert v["winner"] in v["arms"]
         assert all(d["modeled_bytes"] > 0 for d in v["arms"].values())
-    assert cm.ab_verdict("sort", backend="cpu")["winner"] == "xla"
     assert cm.ab_verdict("pipeline")["winner"] == "depth2"
     with pytest.raises(ValueError):
         cm.ab_verdict("nonsense")
@@ -148,7 +147,7 @@ def test_ab_verdicts_shape():
 
 
 def test_check_cost_model_grade_banked_trajectory():
-    """The gate's --grade replay covers the three banked A/B kinds
+    """The gate's --grade replay covers the two banked A/B kinds
     whose programs still exist and the model's pick measures within
     the A/B's resolution (``MEASURED_TIE``) of every banked winner. The
     A/Bs whose arms ran the per-path round (PR 8) are marked
@@ -160,9 +159,7 @@ def test_check_cost_model_grade_banked_trajectory():
     tool = _load_tool("check_cost_model")
     results, problems = tool.grade_trajectory()
     assert problems == []
-    assert {r["kind"] for r in results} == {
-        "sort", "tree_cache", "pipeline"
-    }
+    assert {r["kind"] for r in results} == {"tree_cache", "pipeline"}
     assert not any(r["config"].startswith("PR8/")
                    for r in results)
     disagreements = {r["config"] for r in results if r["agree"] is False}
@@ -208,7 +205,7 @@ def test_grade_reads_ties_and_skips_superseded_lines(tmp_path):
     }
     assert results[0]["lead"] == pytest.approx(10.0 / 9.0 - 1.0)
     assert not any(" no tree_cache_ab " in p for p in problems)
-    assert any(" no sort_ab " in p for p in problems)
+    assert any(" no pipeline_ab " in p for p in problems)
 
 
 def test_check_cost_model_smoke_gate():

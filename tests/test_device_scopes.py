@@ -78,9 +78,8 @@ def test_device_phase_refuses_a_name_outside_the_list():
 
 @pytest.mark.parametrize("knobs", [
     {},
-    {"vphases_impl": "scan", "sort_impl": "radix"},
     {"posmap_impl": "recursive"},
-], ids=["default", "scan-radix", "recursive-posmap"])
+], ids=["default", "recursive-posmap"])
 def test_every_equation_of_the_round_sits_under_a_device_scope(knobs):
     """Trace only, no compile: each leaf equation of the round program
     carries (itself or through the call it sits in) a ``grapevine/``
@@ -133,8 +132,7 @@ def test_scopes_change_nothing_the_compiler_builds(monkeypatch):
     with_scopes = _hlo_ops(ecfg, state, batch)
     for mod in ("grapevine_tpu.engine.round_step", "grapevine_tpu.oram.round",
                 "grapevine_tpu.oram.path_oram", "grapevine_tpu.oram.posmap",
-                "grapevine_tpu.engine.responses",
-                "grapevine_tpu.oblivious.radix"):
+                "grapevine_tpu.engine.responses"):
         monkeypatch.setattr(f"{mod}.device_phase",
                             lambda name: contextlib.nullcontext())
     without = _hlo_ops(ecfg, state, batch)

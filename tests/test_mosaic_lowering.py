@@ -31,10 +31,6 @@ import pytest
 from jax import export
 
 from grapevine_tpu.oblivious.pallas_cipher import cipher_rows_pallas
-from grapevine_tpu.oblivious.pallas_gather import (
-    gather_decrypt_rows,
-    scatter_encrypt_rows,
-)
 from grapevine_tpu.oblivious.pallas_place import place_rows
 
 U32 = jnp.uint32
@@ -103,7 +99,7 @@ def _served_tree(tree):
 
     cfg = GrapevineConfig(
         max_messages=1 << 20, max_recipients=1 << 12, batch_size=2048,
-        tree_density=2, vphases_impl="dense", sort_impl="xla",
+        tree_density=2,
     )
     ecfg = EngineConfig.from_config(cfg)
     oc = {"records": ecfg.rec, "mailbox": ecfg.mb}[tree]
@@ -286,33 +282,14 @@ def test_cipher_kernel_compiles_at_the_row_count_the_chip_refused(one_chip):
     assert "tpu_custom_call" in text
 
 
-@pytest.mark.parametrize("tree", ["records", "mailbox"])
-def test_gather_kernel_compiles_for_v5e(one_chip, tree):
-    n, r, z, zv = _served_tree(tree)
-    text = _compile_for(
-        one_chip, gather_decrypt_rows, _s(8), _s(n * z), _s(n, zv),
-        _s(n, 2), _s(r), z=z, rounds=8, interpret=False,
-    )
-    assert "tpu_custom_call" in text
-
-
-@pytest.mark.parametrize("tree", ["records", "mailbox"])
-def test_scatter_kernel_compiles_for_v5e(one_chip, tree):
-    n, r, z, zv = _served_tree(tree)
-    text = _compile_for(
-        one_chip, scatter_encrypt_rows, _s(8), _s(n * z), _s(n, zv),
-        _s(n, 2), _s(r), _s(r, dtype=jnp.bool_), _s(2), _s(r, z),
-        _s(r, zv), z=z, rounds=8, interpret=False,
-    )
-    assert "tpu_custom_call" in text
-
-
 # ----------------------------------------------------------------------
-# the whole phase-major engine round, per vphases impl: the sort/scan
-# path (variadic lax.sort, associative scans, cummax/cummin, scatter
-# tables) must lower for TPU cross-platform just like the Pallas
-# kernels. Export is enough for these six (no Pallas kernel inside:
-# the jnp cipher); the Pallas round is compiled for real below.
+# the whole phase-major engine round, at every corner of the knob matrix
+# that is left (position map x tree-top cache): the slot-order masks,
+# the one-hot matmuls, lax.sort, the admission walk's associative scan
+# and the scatter tables must lower for a TPU just like the Pallas
+# kernels. Export is enough for these eight (no Pallas kernel inside:
+# the CPU resolves the jnp cipher); the Pallas round is compiled for
+# real below.
 # ----------------------------------------------------------------------
 
 
@@ -340,24 +317,15 @@ def _round_specs(cfg):
     return ecfg, state, batch
 
 
+@pytest.mark.parametrize("cache", [0, 4])
+@pytest.mark.parametrize("posmap", ["flat", "recursive"])
 @pytest.mark.parametrize(
-    "impl,sort,geom",
-    [
-        # (batch, max_messages, max_recipients, mailbox_cap, density);
-        # scan gets both geometries, dense one. Each vphases impl also
-        # lowers with sort_impl="radix" — the counting-pass engine
-        # (scatter-bincount, [B,R] cumsum tables, per-pass unique
-        # scatters) must pass the TPU lowering before a sort A/B meets
-        # a real chip.
-        ("scan", "xla", (8, 64, 8, 4, 2)),
-        ("scan", "xla", (16, 1 << 10, 1 << 6, 62, 4)),  # production-shaped
-        ("dense", "xla", (8, 64, 8, 4, 2)),
-        ("scan", "radix", (8, 64, 8, 4, 2)),
-        ("scan", "radix", (16, 1 << 10, 1 << 6, 62, 4)),
-        ("dense", "radix", (8, 64, 8, 4, 2)),
-    ],
+    "geom",
+    # (batch, max_messages, max_recipients, mailbox_cap, density)
+    [(8, 64, 8, 4, 2), (16, 1 << 10, 1 << 6, 62, 4)],
+    ids=["toy", "production-shaped"],
 )
-def test_engine_round_lowers_for_tpu(impl, sort, geom):
+def test_engine_round_lowers_for_tpu(geom, posmap, cache):
     from grapevine_tpu.config import GrapevineConfig
     from grapevine_tpu.engine.round_step import engine_round_step
 
@@ -369,42 +337,14 @@ def test_engine_round_lowers_for_tpu(impl, sort, geom):
         batch_size=b,
         tree_density=density,
         bucket_cipher_rounds=8,
-        vphases_impl=impl,
-        sort_impl=sort,
+        posmap_impl=posmap,
+        tree_top_cache_levels=cache,
     ))
+    assert (ecfg.vphases_impl, ecfg.sort_impl) == ("dense", "xla")
     export.export(
         jax.jit(functools.partial(engine_round_step, ecfg)),
         platforms=("tpu",),
     )(state, batch)
-
-
-def test_engine_round_with_fused_kernels_compiles_for_v5e(
-    one_chip, monkeypatch
-):
-    """The whole round through ``bucket_cipher_impl="pallas_fused"``
-    compiles for the chip with all three Mosaic kernels inside. The
-    program picks interpret mode from ``jax.default_backend()``, which
-    is the CPU here, so the test steers that one question (no program
-    option exists for it, on purpose)."""
-    from grapevine_tpu.config import GrapevineConfig
-    from grapevine_tpu.engine.round_step import engine_round_step
-
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    ecfg, state, batch = _round_specs(GrapevineConfig(
-        max_messages=64, max_recipients=8, mailbox_cap=4, batch_size=8,
-        tree_density=2, bucket_cipher_impl="pallas_fused",
-        vphases_impl="dense", sort_impl="xla",
-    ))
-    place = lambda t: jax.tree.map(  # noqa: E731
-        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
-        t,
-    )
-    text = (
-        jax.jit(functools.partial(engine_round_step, ecfg),
-                donate_argnums=(0,))
-        .lower(place(state), place(batch)).compile().as_text()
-    )
-    assert text.count("tpu_custom_call") >= 3
 
 
 # ----------------------------------------------------------------------

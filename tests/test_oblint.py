@@ -311,7 +311,7 @@ def test_allowlist_round_trip_default_sweep():
 
 @pytest.mark.slow
 def test_full_matrix_and_mutants_via_cli():
-    """The whole gate end to end at the full 2x2x2x2 cross-product."""
+    """The whole gate end to end, the census on every combination."""
     import check_oblivious as gate
 
     assert gate.main(["--full"]) == 0

@@ -87,14 +87,17 @@ def test_kernel_pads_narrow_plaintext_as_the_jnp_path_does(zin, zv):
     assert not np.asarray(back[:, zin:]).any()
 
 
-@pytest.mark.slow  # ~68 s interpret-mode whole-engine campaign; the
-# kernel keystream bit-equality unit tests above and the Mosaic
-# lowering gate (test_mosaic_lowering.py) stay always-on. Tier-1
-# budget: ROADMAP.md tier-1 note (PR 5).
+@pytest.mark.slow  # XLA:CPU does not get through compiling one round
+# of interpreted kernels at this size in ten minutes (PR 49; 68 s when
+# the mark was set, before PR 40's kernel). The kernel's bit-equality
+# tests above and the Mosaic compile gate (test_mosaic_lowering.py)
+# stay always-on; chip_smoke.py's kernel phase runs this identity on
+# the chip, every leaf of the state.
 def test_engine_states_bit_identical_across_cipher_impls():
     """A CRUD stream through cipher_impl='pallas' produces the same
     responses AND the same device state as cipher_impl='jnp' — the two
-    paths are interchangeable at rest."""
+    paths are interchangeable at rest, every leaf, the padded bucket
+    included."""
     import dataclasses
 
     from grapevine_tpu.config import GrapevineConfig
@@ -141,5 +144,7 @@ def test_engine_states_bit_identical_across_cipher_impls():
         streams.append([(x.status_code, x.record.payload) for x in resps])
         states.append(e.state)
     assert streams[0] == streams[1]
-    for x, y in zip(jax.tree.leaves(states[0]), jax.tree.leaves(states[1])):
-        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+    from grapevine_tpu.testing.compare import states_equal
+
+    same, where = states_equal(*states)
+    assert same, f"state differs at {where}"

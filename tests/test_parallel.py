@@ -137,7 +137,10 @@ def mixed_script(ecfg, known, rounds=8, seed=2023):
         # what backlog-4chip-2p23 adds: per-path levels of both trees
         # gathered, all-reduced and scattered on a chip
         (8, 4, "jnp", mixed_script),
-        # the Pallas cipher waits for its own fork's decision
+        # the cipher a TPU resolves (PR 40): XLA:CPU does not get
+        # through compiling a round of interpreted kernels in ten
+        # minutes (PR 49), so the chip's own run of it is
+        # chip_smoke.py --four-chips
         pytest.param(8, 8, "pallas", three_batches, marks=pytest.mark.slow),
     ],
 )

@@ -31,7 +31,7 @@ import sys
 import numpy as np
 import pytest
 
-from test_vphases_scan import (
+from test_vphases import (
     BASE,
     NOW,
     SAT_BUS,
@@ -264,15 +264,6 @@ def test_posmap_ab_campaign_cipher_on():
     expiry sweep) must preserve bit-identity end to end."""
     cfg = dict(BASE, bucket_cipher_rounds=8)
     _run_pm_campaign(cfg, seed=4300, n_batches=4, sweep=True)
-
-
-@pytest.mark.slow
-def test_posmap_ab_campaign_scan_radix():
-    """The recursive lookup's dedup glue follows the engine's
-    vphases/sort knobs (the no-[B,B] audit holds through the posmap) —
-    the scan+radix pair must stay bit-identical too."""
-    cfg = dict(BASE, vphases_impl="scan", sort_impl="radix")
-    _run_pm_campaign(cfg, seed=4400, n_batches=3)
 
 
 @pytest.mark.slow

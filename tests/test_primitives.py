@@ -77,3 +77,74 @@ def test_rank_of():
     mask = jnp.array([True, False, True, True, False, True])
     r = np.asarray(jax.jit(P.rank_of)(mask))
     assert r.tolist() == [0, 1, 1, 2, 3, 3]
+
+
+# ----------------------------------------------------------------------
+# partition_rank: the expiry sweep's stable free-first partition
+# ----------------------------------------------------------------------
+
+
+def _partition_inverse(flags):
+    """Where a stable argsort of the 1-bit keys puts each element."""
+    flags = np.asarray(flags)
+    perm = np.argsort(flags.astype(np.int64), kind="stable")
+    pos = np.zeros(flags.shape[0], np.int64)
+    pos[perm] = np.arange(flags.shape[0])
+    return pos
+
+
+def test_partition_rank_is_the_freelist_formula():
+    """partition_rank == the expiry sweep's stable free-first partition
+    (engine/expiry.py) == the inverse of a stable sort of 1-bit keys."""
+    rng = np.random.default_rng(5)
+    for n in (1, 2, 100, 1023):
+        present = rng.random(n) < 0.4
+        pos = np.asarray(P.partition_rank(jnp.asarray(present)))
+        pi = present.astype(np.int64)
+        n_free = n - pi.sum()
+        ref = np.where(
+            present,
+            n_free + (np.cumsum(pi) - pi),
+            np.cumsum(1 - pi) - (1 - pi),
+        )
+        np.testing.assert_array_equal(pos, ref)
+        np.testing.assert_array_equal(pos, _partition_inverse(present))
+
+
+def test_partition_rank_is_stable_on_each_side():
+    """Each side keeps its original order: the positions of the False
+    flags ascend with the index, and so do those of the True ones."""
+    flags = np.array([1, 0, 1, 0, 1, 1, 0, 1, 1, 0], bool)
+    pos = np.asarray(P.partition_rank(jnp.asarray(flags)))
+    assert pos[~flags].tolist() == [0, 1, 2, 3]
+    assert pos[flags].tolist() == [4, 5, 6, 7, 8, 9]
+    rng = np.random.default_rng(0)
+    heavy = rng.random(512) < 0.9
+    np.testing.assert_array_equal(
+        np.asarray(P.partition_rank(jnp.asarray(heavy))),
+        _partition_inverse(heavy),
+    )
+
+
+def test_partition_rank_all_equal_flags_is_the_identity():
+    for b in (1, 2, 97):
+        for value in (False, True):
+            got = np.asarray(P.partition_rank(jnp.full((b,), value)))
+            np.testing.assert_array_equal(got, np.arange(b))
+
+
+def test_partition_rank_one_element_and_one_flag_edges():
+    """One flag of either kind at either end, and tiny batches."""
+    rng = np.random.default_rng(1)
+    for b in (1, 2, 5, 256):
+        for flags in (
+            rng.integers(0, 2, b).astype(bool),
+            np.arange(b) == 0,
+            np.arange(b) == b - 1,
+            np.arange(b) != 0,
+            np.arange(b) != b - 1,
+        ):
+            np.testing.assert_array_equal(
+                np.asarray(jax.jit(P.partition_rank)(jnp.asarray(flags))),
+                _partition_inverse(flags),
+            )

@@ -15,7 +15,7 @@ Five suites:
 4. geometry certification: 2^36 (the ROADMAP item 4 design point) is
    REFUSED at construction by the certified-bound guard with a message
    this report can cite, while the max certified per-tree geometry
-   traces clean (the knob cross-product at 2^30 rides -m slow);
+   traces clean;
 5. the allowlist contract: reachability accounting and family matching
    shared with oblint's AllowEntry.
 """
@@ -382,9 +382,7 @@ def test_full_certification_at_max_certified_geometry():
 
 
 def _served_ecfg(config_name: str):
-    """The engine geometry of one benchmark configuration file, with
-    the value-phase knob a TPU resolves (``dense``; a CPU's auto is
-    ``scan``), so that the lanes certified are the ones the chip runs."""
+    """The engine geometry of one benchmark configuration file."""
     import json
 
     from grapevine_tpu.config import GrapevineConfig
@@ -394,7 +392,7 @@ def _served_ecfg(config_name: str):
                            f"{config_name}.json")) as f:
         spec = json.load(f)
     return EngineConfig.from_config(GrapevineConfig(
-        **spec["grapevine_config"], vphases_impl="dense"))
+        **spec["grapevine_config"]))
 
 
 @pytest.mark.parametrize("config_name", [
@@ -448,13 +446,6 @@ def test_overflow_mutants_keep_their_teeth_at_the_chips_real_share():
         {"state": state, "batch": gate._batch_spec(ecfg)},
         bounds=bounds, allowlist=RANGE_ALLOWLIST)
     assert [f.kind for f in rep.findings] == ["overflow"]
-
-
-@pytest.mark.slow
-def test_full_knob_cross_product():
-    import check_ranges as gate
-
-    assert gate.main(["--full"]) == 0
 
 
 # ----------------------------------------------------------------------

@@ -17,7 +17,6 @@ import numpy as np
 
 from grapevine_tpu.config import GrapevineConfig
 from grapevine_tpu.engine.batcher import GrapevineEngine
-from grapevine_tpu.oblivious.radix import radix_rank
 from grapevine_tpu.oram.path_oram import (
     OramConfig,
     init_oram,
@@ -29,7 +28,6 @@ from grapevine_tpu.oram.round import (
     _assign_evictions,
     _bucket_owner_map,
     occurrence_masks,
-    occurrence_masks_sorted,
     oram_round,
 )
 from grapevine_tpu.testing.reference import ReferenceEngine
@@ -301,9 +299,9 @@ def test_occurrence_masks():
     np.testing.assert_array_equal(np.asarray(chain), [0, 1, 0, 3, 1, 0, 6])
 
 
-def test_occurrence_masks_sorted_bit_identical():
-    """The O(B log B) dedup (scan engine) must match the [B,B] form on
-    random index streams with duplicates and dummies, including B=1."""
+def test_occurrence_masks_equal_a_python_loop():
+    """The [B,B] dedup against a plain loop on random index streams
+    with duplicates and dummies, including B=1."""
     rng = np.random.default_rng(17)
     sizes = [1, 2, 5, 8, 16, 32]  # fixed shapes: bounded compile count
     for trial in range(24):
@@ -311,15 +309,18 @@ def test_occurrence_masks_sorted_bit_identical():
         dummy = 64
         idxs = rng.integers(0, 6, b).astype(np.uint32)
         idxs[rng.random(b) < 0.25] = dummy
-        f1, l1, c1 = occurrence_masks(jnp.asarray(idxs), dummy)
-        f2, l2, c2 = occurrence_masks_sorted(jnp.asarray(idxs), dummy)
-        np.testing.assert_array_equal(np.asarray(f1), np.asarray(f2), trial)
-        np.testing.assert_array_equal(np.asarray(l1), np.asarray(l2), trial)
-        np.testing.assert_array_equal(np.asarray(c1), np.asarray(c2), trial)
+        first, last, chain = map(
+            np.asarray, occurrence_masks(jnp.asarray(idxs), dummy))
+        for i in range(b):
+            same = [j for j in range(b)
+                    if idxs[i] != dummy and idxs[j] == idxs[i]]
+            assert first[i] == (bool(same) and same[0] == i), trial
+            assert last[i] == (bool(same) and same[-1] == i), trial
+            assert chain[i] == (same[0] if same else i), trial
 
 
 def _assign_evictions_by_gathers(cfg, valid, wleaf, bucket_map, n_rows,
-                                 sort_impl, dense_levels):
+                                 dense_levels):
     """The placement as it stood before PR 38, kept as the reference
     `_assign_evictions` is held to bit for bit: an argsort and two
     gathers through its permutation, per level the rank base read back
@@ -331,10 +332,7 @@ def _assign_evictions_by_gathers(cfg, valid, wleaf, bucket_map, n_rows,
     w = valid.shape[0]
     nslots = n_rows * z
     skey = jnp.where(valid, wleaf, U32(0xFFFFFFFF))
-    if sort_impl == "radix":
-        eperm = radix_rank(jnp.where(valid, wleaf, U32(1) << U32(h)), h + 1)
-    else:
-        eperm = jnp.argsort(skey)
+    eperm = jnp.argsort(skey)
     sleaf = skey[eperm]
     svalid = valid[eperm]
     iota_w = jnp.arange(w, dtype=jnp.int32)
@@ -408,20 +406,19 @@ def _eviction_case(z, dense_levels, rows):
     return cfg, jnp.asarray(valid), jnp.asarray(wleaf, U32), bucket_map, n_rows
 
 
-@pytest.mark.parametrize("sort_impl", ["xla", "radix"])
 @pytest.mark.parametrize(
     "rows", ["mixed", "one_leaf", "crowded", "none_valid", "all_valid"]
 )
 @pytest.mark.parametrize("z", [2, 4])
 @pytest.mark.parametrize("dense_levels", [0, 2, _EVICT_H + 1])
 def test_assign_evictions_bit_identical_to_gather_form(
-    dense_levels, z, rows, sort_impl
+    dense_levels, z, rows
 ):
     """PR 38: ranks from scans alone, the sorted keys from the sort's
     own payload and one back-scatter place every row exactly where the
     gather formulation did: ``slot_tgt`` and ``placed`` bit for bit."""
     cfg, valid, wleaf, bucket_map, n_rows = _eviction_case(z, dense_levels, rows)
-    args = (cfg, valid, wleaf, bucket_map, n_rows, sort_impl, dense_levels)
+    args = (cfg, valid, wleaf, bucket_map, n_rows, dense_levels)
     want_tgt, want_placed = _assign_evictions_by_gathers(*args)
     got_tgt, got_placed = _assign_evictions(*args)
     np.testing.assert_array_equal(np.asarray(got_tgt), np.asarray(want_tgt))
@@ -451,7 +448,7 @@ def test_assign_evictions_holds_no_gather_a_scan_can_replace(dense_levels):
     cfg, valid, wleaf, bucket_map, n_rows = _eviction_case(4, dense_levels, "mixed")
     counts = census(jax.make_jaxpr(
         lambda v, lf, m: _assign_evictions(
-            cfg, v, lf, m, n_rows, "xla", dense_levels)
+            cfg, v, lf, m, n_rows, dense_levels)
     )(valid, wleaf, bucket_map))
     assert counts["gather"] == cfg.path_len - dense_levels
     assert counts["scatter"] == 1

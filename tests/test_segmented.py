@@ -5,17 +5,11 @@ import jax.numpy as jnp
 
 from grapevine_tpu.oblivious.segmented import (
     group_sort,
-    multiword_group_sort,
     sat_apply,
     sat_compose,
     sat_elem,
     sat_identity,
-    segment_bounds,
-    segmented_counts_before,
     segmented_exclusive_sat_scan,
-    segmented_scan,
-    segmented_sum_before,
-    segmented_sum_total,
 )
 
 
@@ -79,63 +73,18 @@ def test_segmented_exclusive_scan_counts():
     np.testing.assert_array_equal(got, want_before)
 
 
-def test_segmented_counts_before():
-    group = jnp.asarray([0, 1, 0, 2, 1, 0], jnp.uint32)
-    flags = jnp.asarray([1, 0, 1, 1, 1, 0], bool)
-    got = np.asarray(segmented_counts_before(group, flags))
-    np.testing.assert_array_equal(got, [0, 0, 1, 0, 0, 2])
-
-
-def test_multiword_group_sort_and_bounds_vs_naive():
-    """The scan-vphases sort machinery vs a naive Python model: the
-    permutation orders ops by (multi-word key, slot), segment starts
-    mark key boundaries, and segment_bounds finds each element's
-    first/last segment index — including B=1 and all-equal keys."""
+def test_group_sort_vs_naive():
+    """The admission walk's grouping against a plain Python model: the
+    permutation orders ops by (group, slot), ``inv`` undoes it, and the
+    segment starts mark the group boundaries — B = 1 and one group
+    included."""
     rng = np.random.default_rng(3)
-    sizes = [1, 2, 3, 7, 16, 33]  # fixed shapes: bounded compile count
-    for trial in range(18):
-        b = sizes[trial % len(sizes)]
-        nw = int(rng.integers(1, 4))
-        cols = [rng.integers(0, 3, b).astype(np.uint32) for _ in range(nw)]
-        keys = list(zip(*[c.tolist() for c in cols]))
-        perm, inv, seg = multiword_group_sort([jnp.asarray(c) for c in cols])
-        perm, inv, seg = np.asarray(perm), np.asarray(inv), np.asarray(seg)
-        want = sorted(range(b), key=lambda i: (keys[i], i))
-        assert perm.tolist() == want, trial
+    for b, n_groups in ((1, 1), (2, 2), (7, 1), (16, 3), (33, 40)):
+        group = rng.integers(0, n_groups, b).astype(np.uint32)
+        perm, inv, seg = map(np.asarray, group_sort(jnp.asarray(group)))
+        want = sorted(range(b), key=lambda i: (group[i], i))
+        assert perm.tolist() == want
         assert (np.arange(b)[perm][inv] == np.arange(b)).all()
-        want_seg = [True] + [
-            keys[perm[i]] != keys[perm[i - 1]] for i in range(1, b)
+        assert seg.tolist() == [True] + [
+            group[perm[i]] != group[perm[i - 1]] for i in range(1, b)
         ]
-        assert seg.tolist() == want_seg
-        start, end = map(np.asarray, segment_bounds(jnp.asarray(seg)))
-        for j in range(b):
-            s = j
-            while not seg[s]:
-                s -= 1
-            e = j
-            while e + 1 < b and not seg[e + 1]:
-                e += 1
-            assert start[j] == s and end[j] == e
-
-
-def test_segmented_sums_and_scan_vs_naive():
-    rng = np.random.default_rng(4)
-    sizes = [1, 2, 5, 17, 40]
-    for trial in range(15):
-        b = sizes[trial % len(sizes)]
-        seg = np.zeros(b, bool)
-        seg[0] = True
-        seg[1:] = rng.random(b - 1) < 0.3
-        start, end = map(np.asarray, segment_bounds(jnp.asarray(seg)))
-        x = rng.integers(0, 5, (b, 2)).astype(np.int32)
-        bef = np.asarray(segmented_sum_before(jnp.asarray(x), jnp.asarray(seg)))
-        tot = np.asarray(segmented_sum_total(jnp.asarray(x), jnp.asarray(seg)))
-        v = rng.integers(-9, 9, b).astype(np.int32)
-        mx = np.asarray(
-            segmented_scan(jnp.asarray(v), jnp.asarray(seg), jnp.maximum)
-        )
-        for j in range(b):
-            s, e = start[j], end[j]
-            np.testing.assert_array_equal(bef[j], x[s:j].sum(axis=0))
-            np.testing.assert_array_equal(tot[j], x[s : e + 1].sum(axis=0))
-            assert mx[j] == v[s : j + 1].max()

@@ -26,8 +26,7 @@ The first half shares ONE cached + ONE uncached engine compile
 (plaintext BASE geometry) + small directed-ORAM compiles + trace-only
 audits; the second half builds fresh pairs: the cipher pair (every
 configuration runs ChaCha8 at rest), regime breadth and chaos.
-Recursive-posmap and scan/radix pairs ride ``-m slow`` until those
-forks are decided.
+Recursive-posmap pairs ride ``-m slow`` until that fork is decided.
 """
 
 from __future__ import annotations
@@ -40,7 +39,7 @@ import jax
 import numpy as np
 import pytest
 
-from test_vphases_scan import (
+from test_vphases import (
     BASE,
     NOW,
     SAT_BUS,
@@ -438,14 +437,6 @@ def test_tree_cache_campaign_recursive_posmap():
     included."""
     cfg = dict(BASE, posmap_impl="recursive", bucket_cipher_rounds=8)
     _run_tc_campaign(cfg, seed=5400, n_batches=3, sweep=True, k=2)
-
-
-@pytest.mark.slow
-def test_tree_cache_campaign_scan_radix():
-    """The cache split composes with the scan/radix round machinery
-    (different gather layout, same logical content)."""
-    cfg = dict(BASE, vphases_impl="scan", sort_impl="radix")
-    _run_tc_campaign(cfg, seed=5500, n_batches=3)
 
 
 def test_tree_cache_single_op_batch_geometry():

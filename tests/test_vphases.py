@@ -1,22 +1,21 @@
-"""Dense-vs-scan vphases equivalence: bit-identical engines, no [B,B].
+"""The slot-order machinery (engine/vphases.py) against independent models.
 
-The tentpole contract of the scan slot-order machinery
-(engine/vphases.py, ``vphases_impl="scan"``):
+1. every group query of ``_DenseGroups`` against a plain Python loop,
+   under duplicate-heavy keys, dummies, one group and all-distinct keys:
+   the [B,B] formulation every cell runs, checked below the level of the
+   whole engine;
+2. randomized campaigns of the whole engine against the oracle
+   (testing/reference.py) over op mixes heavy in same-key chains,
+   zero-id pops, saturation-fallback rounds and single-op batches;
+3. order along a mailbox's cap axis (PR 31) against the gather
+   formulation it replaced, and the audit that round A's callback holds
+   no per-element gather.
 
-1. responses AND final engine state bit-identical to the dense impl —
-   randomized oracle campaigns over op mixes heavy in same-key chains,
-   zero-id pops, saturation-fallback rounds, and single-op batches
-   (the same contract the cipher impls carry, testing/compare.py);
-2. the scan impl's jaxpr materializes NO [B,B]-shaped intermediate at
-   B=256 (asserted on the traced jaxpr, with the dense impl as the
-   positive control proving the checker sees such intermediates).
-
-The fast campaign count keeps tier-1 within budget; the full ≥200-
-campaign sweep runs under ``-m slow`` (and was run at PR time — see
-PERF.md Round 6). Set $GRAPEVINE_VPHASES_CAMPAIGNS to override.
+The campaign helpers here are shared by tests/test_posmap_ab.py and
+tests/test_tree_cache.py. Set $GRAPEVINE_VPHASES_CAMPAIGNS to run more
+campaigns than the tier-1 eight.
 """
 
-import functools
 import os
 import random
 
@@ -27,21 +26,20 @@ import pytest
 
 from grapevine_tpu.config import GrapevineConfig
 from grapevine_tpu.engine.batcher import GrapevineEngine
-from grapevine_tpu.engine.round_step import engine_round_step
 from grapevine_tpu.engine.state import (
     ENT_SEQ,
     ENT_SEQH,
     ENTRY_WORDS,
     EngineConfig,
-    ID_WORDS,
     KEY_WORDS,
-    PAYLOAD_WORDS,
     init_engine,
 )
 from grapevine_tpu.engine.vphases import (
     _drop_oldest,
+    _index_groups,
     _oldest_first,
     _pth_entry,
+    _recipient_groups,
     phase_a_batch,
 )
 from grapevine_tpu.oblivious.primitives import shift_down
@@ -86,16 +84,6 @@ def req(rt, auth, msg_id=C.ZERO_MSG_ID, recipient=C.ZERO_PUBKEY, tag=0):
     )
 
 
-def _mk_pair(cfg_kwargs, seed):
-    dense = GrapevineEngine(
-        GrapevineConfig(vphases_impl="dense", **cfg_kwargs), seed=seed
-    )
-    scan = GrapevineEngine(
-        GrapevineConfig(vphases_impl="scan", **cfg_kwargs), seed=seed
-    )
-    return dense, scan
-
-
 def _assert_responses_bitequal(rd, rs, ctx=""):
     for j, (d, s) in enumerate(zip(rd, rs)):
         assert d.status_code == s.status_code, f"{ctx} slot {j}: status"
@@ -104,15 +92,6 @@ def _assert_responses_bitequal(rd, rs, ctx=""):
         assert d.record.recipient == s.record.recipient, f"{ctx} slot {j}"
         assert d.record.timestamp == s.record.timestamp, f"{ctx} slot {j}: ts"
         assert d.record.payload == s.record.payload, f"{ctx} slot {j}: payload"
-
-
-def _assert_states_bitequal(ea, eb, ctx=""):
-    la = jax.tree_util.tree_leaves_with_path(ea.state)
-    lb = jax.tree_util.tree_leaves(eb.state)
-    for (path, x), y in zip(la, lb):
-        assert np.array_equal(np.asarray(x), np.asarray(y)), (
-            f"{ctx}: state diverges at {jax.tree_util.keystr(path)}"
-        )
 
 
 def _gen_batch(rng, idents, live_ids, n):
@@ -154,19 +133,32 @@ def _gen_batch(rng, idents, live_ids, n):
     return reqs
 
 
-def _run_campaign(cfg_kwargs, seed, n_batches=3, batch_fill=None,
-                  mk_pair=None):
-    """One campaign: a fresh engine A/B pair + oracle, mixed batches.
+def _assert_matches_oracle(oracle, reqs, resps, t, ctx=""):
+    """One batch's responses against the oracle's, the engine's msg ids
+    forced into it (they are engine-private PRP outputs)."""
+    forced = [
+        d.record.msg_id
+        if r.request_type == C.REQUEST_TYPE_CREATE
+        and d.status_code == C.STATUS_CODE_SUCCESS
+        else None
+        for r, d in zip(reqs, resps)
+    ]
+    want = oracle.handle_batch(reqs, t, forced)
+    for j, (d, o) in enumerate(zip(resps, want)):
+        assert d.status_code == o.status_code, (
+            f"{ctx} slot {j}: engine {d.status_code} != oracle "
+            f"{o.status_code}"
+        )
+    _assert_responses_bitequal(resps, want, ctx)
 
-    Asserts pair ≡ bitwise (responses, then final state) and both
-    ≡ oracle semantics (forced-id comparison, counts included).
-    ``mk_pair`` builds the (a, b) engines under test — default the
-    dense/scan vphases pair; tests/test_sort_radix.py reuses the whole
-    campaign with an xla/radix sort pair instead.
-    """
+
+def _run_campaign(cfg_kwargs, seed, n_batches=3, batch_fill=None):
+    """One campaign: a fresh engine and oracle, mixed batches; every
+    response (status, id, sender, recipient, timestamp, payload) and the
+    message and recipient counts after every batch equal the oracle's."""
     rng = np.random.default_rng(seed)
-    dense, scan = (mk_pair or _mk_pair)(
-        cfg_kwargs, seed=int(rng.integers(1 << 30))
+    engine = GrapevineEngine(
+        GrapevineConfig(**cfg_kwargs), seed=int(rng.integers(1 << 30))
     )
     oracle = ReferenceEngine(
         config=GrapevineConfig(**cfg_kwargs), rng=random.Random(seed)
@@ -178,28 +170,12 @@ def _run_campaign(cfg_kwargs, seed, n_batches=3, batch_fill=None,
         n = batch_fill or int(rng.integers(1, bs + 1))
         reqs = _gen_batch(rng, idents, live_ids, n)
         t = NOW + bi
-        rd = dense.handle_queries(reqs, t)
-        rs = scan.handle_queries(reqs, t)
-        _assert_responses_bitequal(rd, rs, f"seed {seed} batch {bi}")
-        forced = [
-            d.record.msg_id
-            if r.request_type == C.REQUEST_TYPE_CREATE
-            and d.status_code == C.STATUS_CODE_SUCCESS
-            else None
-            for r, d in zip(reqs, rd)
-        ]
-        ro = oracle.handle_batch(reqs, t, forced)
-        for j, (r, d, o) in enumerate(zip(reqs, rd, ro)):
-            assert d.status_code == o.status_code, (
-                f"seed {seed} batch {bi} slot {j}: engine "
-                f"{d.status_code} != oracle {o.status_code}"
-            )
-            assert d.record.msg_id == o.record.msg_id
-            assert d.record.payload == o.record.payload
-            assert d.record.timestamp == o.record.timestamp
-        assert dense.message_count() == oracle.message_count()
-        assert dense.recipient_count() == oracle.recipient_count()
-        for r, d in zip(reqs, rd):
+        resps = engine.handle_queries(reqs, t)
+        _assert_matches_oracle(
+            oracle, reqs, resps, t, f"seed {seed} batch {bi}")
+        assert engine.message_count() == oracle.message_count()
+        assert engine.recipient_count() == oracle.recipient_count()
+        for r, d in zip(reqs, resps):
             if (
                 r.request_type == C.REQUEST_TYPE_CREATE
                 and d.status_code == C.STATUS_CODE_SUCCESS
@@ -212,7 +188,6 @@ def _run_campaign(cfg_kwargs, seed, n_batches=3, batch_fill=None,
                 live_ids = [
                     (m, o_) for m, o_ in live_ids if m != d.record.msg_id
                 ]
-    _assert_states_bitequal(dense, scan, f"seed {seed}")
 
 
 def _campaign_plan(n_total):
@@ -234,45 +209,27 @@ def _campaign_plan(n_total):
 _FAST_N = int(os.environ.get("GRAPEVINE_VPHASES_CAMPAIGNS", "8"))
 
 
-def test_randomized_ab_campaigns():
+def test_randomized_campaigns_match_the_oracle():
     """Budget-shaped fast set: the cost is ~all jit compiles (one per
-    distinct geometry × impl), so the fast plan spans two geometries —
+    distinct geometry), so the fast plan spans two geometries —
     steady-state and bus-saturation. Both saturation regimes resolve
     through the same _admission_slow scan (only the tripping guard
-    differs), so bus-saturation keeps the fallback branch covered; the
-    recipient-table geometry runs in the -m slow full sweep."""
+    differs), so bus-saturation keeps the fallback branch covered."""
     for i, (cfg, fill) in enumerate(_campaign_plan(_FAST_N)):
         if cfg is SAT_RECIP:
             cfg = SAT_BUS
         _run_campaign(cfg, seed=1000 + i, batch_fill=fill)
 
 
-@pytest.mark.slow
-def test_randomized_ab_campaigns_full():
-    """The full ≥200-campaign acceptance sweep (run at PR time; kept
-    under -m slow so tier-1 stays within its budget)."""
-    for i, (cfg, fill) in enumerate(_campaign_plan(220)):
-        _run_campaign(cfg, seed=5000 + i, batch_fill=fill)
-
-
-@pytest.mark.slow  # two extra engine compiles (~15 s); the B=1 segment
-# edge cases are covered always-on by the segmented property tests and
-# the fill=1 campaigns in the fast plan
-def test_single_op_batch_engine_ab():
-    """batch_size=1 end to end: the sort/scan machinery at B=1 (segment
-    logic degenerate cases) stays bit-identical and oracle-true."""
-    cfg = dict(BASE, batch_size=1)
-    for i in range(6):
-        _run_campaign(cfg, seed=300 + i, n_batches=6, batch_fill=1)
-
-
-def test_saturation_fallback_engaged_and_bitequal():
+def test_saturation_fallback_engaged_and_matches_the_oracle():
     """Drive the bus to saturation so fast_ok is False (free_top < B):
-    rounds resolve through _admission_slow under both impls and must
-    stay bit-identical, including TOO_MANY_MESSAGES admission order."""
-    dense, scan = _mk_pair(SAT_BUS, seed=9)
+    rounds resolve through _admission_slow and must match the oracle,
+    TOO_MANY_MESSAGES admission order included."""
+    engine = GrapevineEngine(GrapevineConfig(**SAT_BUS), seed=9)
+    oracle = ReferenceEngine(
+        config=GrapevineConfig(**SAT_BUS), rng=random.Random(9)
+    )
     a, x = key(1), key(2)
-    t = NOW
     # 3 full batches of creates against max_messages=16: round 2 onward
     # runs with free_top < B=8 → the lax.scan branch
     for bi in range(3):
@@ -280,108 +237,190 @@ def test_saturation_fallback_engaged_and_bitequal():
             req(C.REQUEST_TYPE_CREATE, a, recipient=x, tag=bi * 8 + j)
             for j in range(8)
         ]
-        rd = dense.handle_queries(reqs, t + bi)
-        rs = scan.handle_queries(reqs, t + bi)
-        _assert_responses_bitequal(rd, rs, f"sat batch {bi}")
-    assert dense.message_count() <= 16
-    codes = {r.status_code for r in rd}
+        resps = engine.handle_queries(reqs, NOW + bi)
+        _assert_matches_oracle(oracle, reqs, resps, NOW + bi, f"sat {bi}")
+    assert engine.message_count() == oracle.message_count() <= 16
+    codes = {r.status_code for r in resps}
     assert C.STATUS_CODE_TOO_MANY_MESSAGES in codes  # quota actually hit
-    _assert_states_bitequal(dense, scan, "saturation")
 
 
 # ----------------------------------------------------------------------
-# jaxpr shape audit: the scan impl materializes no [B,B] intermediate
+# the group queries of _DenseGroups, each against a plain Python loop
 # ----------------------------------------------------------------------
 
-JAXPR_B = 256
+GROUPS_B = 12
 
 
-def _iter_jaxprs(jaxpr):
-    yield jaxpr
-    for eqn in jaxpr.eqns:
-        for v in eqn.params.values():
-            vs = v if isinstance(v, (list, tuple)) else (v,)
-            for x in vs:
-                inner = getattr(x, "jaxpr", None)
-                if inner is not None and hasattr(inner, "eqns"):
-                    yield from _iter_jaxprs(inner)
-                elif hasattr(x, "eqns"):
-                    yield from _iter_jaxprs(x)
+def _group_pattern(name):
+    """(idx u32[B], is_real bool[B]): duplicate-heavy keys, keys with
+    dummies among them (a dummy shares its index value with other
+    dummies and must still be a group of its own), one group, and
+    all-distinct keys."""
+    rng = np.random.default_rng(11)
+    b = GROUPS_B
+    real = np.ones(b, bool)
+    with_dummies = rng.random(b) < 0.6
+    with_dummies[:2] = [True, False]
+    return {
+        "duplicate-heavy": (rng.integers(0, 3, b), real),
+        "dummies": (np.where(with_dummies, rng.integers(0, 4, b), 99),
+                    with_dummies),
+        "one-group": (np.full(b, 7), real),
+        "all-distinct": (rng.permutation(b), real),
+    }[name]
 
 
-def _quadratic_avals(jaxpr, b):
-    """All bool/f32 avals in the jaxpr with ≥2 axes of extent ≥ b.
-
-    Record values are u32[B, 256] at the 1KB record size — exactly B
-    words wide at B=256 — so a ``jnp.where(mask[:, None], rows, ...)``
-    over record rows carries a broadcast bool predicate of shape
-    (B, 256) that is batch×value-width, not a same-key matrix. Those
-    two representational primitives (the predicate broadcast and the
-    select it feeds) are excluded for bools; every *computational* use
-    of a genuine [B,B] mask (and/or/reduce/convert, and the f32 one-hot
-    matmul operands) remains audited, which the dense positive-control
-    test proves is sufficient to detect the dense impl.
-    """
-    bad = []
-    skip_bool = ("select_n", "broadcast_in_dim")
-    for jx in _iter_jaxprs(jaxpr):
-        for eqn in jx.eqns:
-            for var in list(eqn.invars) + list(eqn.outvars):
-                aval = getattr(var, "aval", None)
-                shape = getattr(aval, "shape", ())
-                dtype = getattr(aval, "dtype", None)
-                if dtype is None:
-                    continue
-                if dtype not in (jnp.bool_, jnp.float32):
-                    continue
-                if dtype == jnp.bool_ and eqn.primitive.name in skip_bool:
-                    continue
-                if sum(1 for dim in shape if dim >= b) >= 2:
-                    bad.append((eqn.primitive.name, str(dtype), tuple(shape)))
-    return bad
+def _loop_groups(idx, is_real):
+    """members[i]: the slots of op i's group, ascending — ops on the
+    same index if op i is real, the op alone if it is a dummy."""
+    b = len(idx)
+    return [
+        [j for j in range(b)
+         if j == i or (is_real[i] and is_real[j] and idx[j] == idx[i])]
+        for i in range(b)
+    ]
 
 
-def _trace_engine_jaxpr(impl):
-    cfg = GrapevineConfig(
-        max_messages=1 << 12,
-        max_recipients=1 << 8,
-        mailbox_cap=4,
-        batch_size=JAXPR_B,
-        bucket_cipher_rounds=0,
-        stash_size=512,
-        vphases_impl=impl,
+def _loop_rank(members, flags):
+    """Per op: the flagged strictly-earlier ops of its group."""
+    return [sum(1 for j in m if j < i and flags[j])
+            for i, m in enumerate(members)]
+
+
+def _flag_sets(is_real):
+    """Flag vectors as the phases raise them (never on a dummy) and, the
+    last two, raised on dummies too: all set and none set included."""
+    rng = np.random.default_rng(12)
+    b = len(is_real)
+    masked = [(rng.random(b) < p) & is_real for p in (0.2, 0.5, 0.8)]
+    return masked + [is_real.copy(), np.zeros(b, bool),
+                     rng.random(b) < 0.5, np.ones(b, bool)]
+
+
+def _both_constructions(idx, is_real):
+    """The two ways the phases build a group object: over one index
+    column, and over a recipient key's eight words."""
+    idx_j = jnp.asarray(idx, jnp.uint32)
+    real_j = jnp.asarray(is_real)
+    ka = jnp.stack(
+        [idx_j * jnp.uint32(w + 1) + jnp.uint32(w) for w in range(KEY_WORDS)],
+        axis=1,
     )
-    ecfg = EngineConfig.from_config(cfg)
-    state = jax.eval_shape(lambda: init_engine(ecfg, 0))
-    b = JAXPR_B
-    u32 = jnp.uint32
-    batch = {
-        "req_type": jax.ShapeDtypeStruct((b,), u32),
-        "auth": jax.ShapeDtypeStruct((b, KEY_WORDS), u32),
-        "msg_id": jax.ShapeDtypeStruct((b, ID_WORDS), u32),
-        "recipient": jax.ShapeDtypeStruct((b, KEY_WORDS), u32),
-        "payload": jax.ShapeDtypeStruct((b, PAYLOAD_WORDS), u32),
-        "now": jax.ShapeDtypeStruct((), u32),
-        "now_hi": jax.ShapeDtypeStruct((), u32),
-    }
-    return jax.make_jaxpr(functools.partial(engine_round_step, ecfg))(
-        state, batch
-    ).jaxpr
+    return _index_groups(idx_j, real_j), _recipient_groups(ka, real_j)
 
 
-def test_scan_jaxpr_has_no_quadratic_intermediate():
-    bad = _quadratic_avals(_trace_engine_jaxpr("scan"), JAXPR_B)
-    assert not bad, (
-        f"scan impl materializes quadratic mask intermediates at "
-        f"B={JAXPR_B}: {sorted(set(bad))[:8]}"
-    )
+def _want_counts_before(members, flags, **_):
+    return _loop_rank(members, flags)
 
 
-def test_dense_jaxpr_audit_positive_control():
-    """The dense impl DOES materialize [B,B] masks — proving the audit
-    actually detects the intermediates the scan test asserts away."""
-    bad = _quadratic_avals(_trace_engine_jaxpr("dense"), JAXPR_B)
-    assert bad, "audit found no [B,B] intermediates even in the dense impl"
+def _want_any_before(members, flags, **_):
+    return [r > 0 for r in _loop_rank(members, flags)]
+
+
+def _want_total_sum(members, flags, **_):
+    return [sum(int(flags[j]) for j in m) for m in members]
+
+
+def _want_total_or(members, flags, **_):
+    return [any(flags[j] for j in m) for m in members]
+
+
+def _want_total_sum_rows(members, u, **_):
+    return [u[m].sum(axis=0).tolist() for m in members]
+
+
+def _want_total_or_rows(members, u, **_):
+    return [u[m].any(axis=0).tolist() for m in members]
+
+
+def _want_group_first(members, **_):
+    return [m[0] for m in members]
+
+
+def _want_group_last(members, **_):
+    return [m[-1] for m in members]
+
+
+def _want_first_flag_index(members, flags, **_):
+    hits = [[j for j in m if flags[j]] for m in members]
+    return [h[0] if h else 0 for h in hits], [bool(h) for h in hits]
+
+
+def _want_last_flag_index_upto(members, flags, **_):
+    return [max([j for j in m if flags[j] and j <= i], default=-1)
+            for i, m in enumerate(members)]
+
+
+def _want_last_flag_index(members, flags, **_):
+    return [max([j for j in m if flags[j]], default=-1) for m in members]
+
+
+def _want_select_by_rank(members, flags, vals, q, **_):
+    rank = _loop_rank(members, flags)
+    out = []
+    for i, m in enumerate(members):
+        hit = [j for j in m if flags[j] and rank[j] == q[i]]
+        assert len(hit) <= 1  # ranks within a group are distinct
+        out.append(vals[hit[0]].tolist() if hit else [0] * vals.shape[1])
+    return out
+
+
+#: method -> (the loop model, which of flags / u / (vals, q) it takes)
+_GROUP_QUERIES = {
+    "counts_before": (_want_counts_before, "flags"),
+    "any_before": (_want_any_before, "flags"),
+    "total_sum": (_want_total_sum, "flags"),
+    "total_or": (_want_total_or, "flags"),
+    "total_sum_rows": (_want_total_sum_rows, "u"),
+    "total_or_rows": (_want_total_or_rows, "u"),
+    "group_first": (_want_group_first, None),
+    "group_last": (_want_group_last, None),
+    "first_flag_index": (_want_first_flag_index, "flags"),
+    "last_flag_index_upto": (_want_last_flag_index_upto, "flags"),
+    "last_flag_index": (_want_last_flag_index, "flags"),
+    "select_by_rank": (_want_select_by_rank, "rank"),
+}
+
+
+def _as_lists(got):
+    if isinstance(got, tuple):
+        return tuple(np.asarray(g).tolist() for g in got)
+    return np.asarray(got).tolist()
+
+
+@pytest.mark.parametrize(
+    "pattern", ["duplicate-heavy", "dummies", "one-group", "all-distinct"])
+@pytest.mark.parametrize("method", list(_GROUP_QUERIES))
+def test_dense_group_query_equals_a_python_loop(method, pattern):
+    want_fn, takes = _GROUP_QUERIES[method]
+    rng = np.random.default_rng(13)
+    idx, is_real = _group_pattern(pattern)
+    members = _loop_groups(idx, is_real)
+    for groups in _both_constructions(idx, is_real):
+        for k, flags in enumerate(_flag_sets(is_real)):
+            if takes is None:
+                got = getattr(groups, method)()
+                want = want_fn(members)
+            elif takes == "flags":
+                got = getattr(groups, method)(jnp.asarray(flags))
+                want = want_fn(members, flags=flags)
+            elif takes == "u":
+                u = (rng.random((GROUPS_B, 5)) < 0.4) & flags[:, None]
+                got = getattr(groups, method)(jnp.asarray(u))
+                want = want_fn(members, u=u)
+            else:
+                vals = rng.integers(
+                    1, 1 << 32, (GROUPS_B, 3), dtype=np.uint64
+                ).astype(np.uint32)
+                q = rng.integers(-1, 4, GROUPS_B).astype(np.int32)
+                got = groups.select_by_rank(
+                    jnp.asarray(flags), jnp.asarray(vals), jnp.asarray(q))
+                want = want_fn(members, flags=flags, vals=vals, q=q)
+            got = _as_lists(got)
+            want = tuple(want) if isinstance(got, tuple) else want
+            assert got == want, f"flags {k}"
+            if takes is None:
+                break  # no flags to vary
 
 
 # ----------------------------------------------------------------------
@@ -519,6 +558,19 @@ def test_shift_down_every_shift_and_width():
         assert np.array_equal(got, want), n
 
 
+def _iter_jaxprs(jaxpr):
+    yield jaxpr
+    for eqn in jaxpr.eqns:
+        for v in eqn.params.values():
+            vs = v if isinstance(v, (list, tuple)) else (v,)
+            for x in vs:
+                inner = getattr(x, "jaxpr", None)
+                if inner is not None and hasattr(inner, "eqns"):
+                    yield from _iter_jaxprs(inner)
+                elif hasattr(x, "eqns"):
+                    yield from _iter_jaxprs(x)
+
+
 def _per_element_gathers(jaxpr, min_slices, max_slice_words):
     """(result shape, slice sizes) of every ``gather`` that fetches
     ``min_slices`` or more slices of at most ``max_slice_words`` words
@@ -538,12 +590,14 @@ def _per_element_gathers(jaxpr, min_slices, max_slice_words):
     return bad
 
 
-def _trace_round_a_apply(impl):
+JAXPR_B = 256
+
+
+def _trace_round_a_apply():
     """The jaxpr of round A's callback alone: ``phase_a_batch``'s
     precomputation and its ``apply_batch`` over [B*D] fetched rows."""
     ecfg = EngineConfig.from_config(
-        GrapevineConfig(**{**BASE, "batch_size": JAXPR_B, "mailbox_cap": 8},
-                        vphases_impl=impl)
+        GrapevineConfig(**{**BASE, "batch_size": JAXPR_B, "mailbox_cap": 8})
     )
     state = jax.eval_shape(lambda: init_engine(ecfg, 0))
     b, d = JAXPR_B, ecfg.mb_choices
@@ -565,19 +619,18 @@ def _trace_round_a_apply(impl):
     return ecfg, jaxpr
 
 
-@pytest.mark.parametrize("impl", ["dense", "scan"])
-def test_round_a_apply_has_no_per_element_gather(impl):
-    """Both vphases impls share the ordering body; on a CPU-only PR a
-    ``take_along_axis`` over [B,K,cap] or [B,cap] would cost nothing
-    here and 12 ns an element on the chip. B*cap slices is the smallest
-    of the gathers PR 31 removed; what the callback still gathers by
-    index is B*D slices or fewer, or whole rows of V words."""
-    ecfg, jaxpr = _trace_round_a_apply(impl)
+def test_round_a_apply_has_no_per_element_gather():
+    """On a CPU-only PR a ``take_along_axis`` over [B,K,cap] or [B,cap]
+    would cost nothing here and 12 ns an element on the chip. B*cap
+    slices is the smallest of the gathers PR 31 removed; what the
+    callback still gathers by index is B*D slices or fewer, or whole
+    rows of V words."""
+    ecfg, jaxpr = _trace_round_a_apply()
     assert ecfg.mailbox_cap > ecfg.mb_choices
     bad = _per_element_gathers(
         jaxpr, JAXPR_B * ecfg.mailbox_cap, ENTRY_WORDS
     )
-    assert not bad, f"{impl}: per-element gathers in round A: {bad[:6]}"
+    assert not bad, f"per-element gathers in round A: {bad[:6]}"
 
 
 def test_per_element_gather_audit_positive_control():
@@ -596,17 +649,3 @@ def test_per_element_gather_audit_positive_control():
     ), shapes
     clean = jax.make_jaxpr(_new_mailbox_order)(*case).jaxpr
     assert not _per_element_gathers(clean, 1, 1 << 30)
-
-
-def test_vphases_impl_knob_validation():
-    with pytest.raises(ValueError):
-        GrapevineConfig(vphases_impl="bogus")
-    # None resolves per backend at engine-config time; tests force CPU
-    ecfg = EngineConfig.from_config(GrapevineConfig())
-    assert ecfg.vphases_impl == "scan"
-    assert (
-        EngineConfig.from_config(
-            GrapevineConfig(vphases_impl="dense")
-        ).vphases_impl
-        == "dense"
-    )

@@ -154,21 +154,7 @@ def grade_trajectory(path: str = TRAJECTORY) -> tuple:
 
     for line in lines:
         pr = line.get("pr", "?")
-        backend = line.get("backend", "cpu")
         configs = line.get("configs", {})
-
-        if "sort_ab" in configs:
-            kinds_seen.add("sort")
-            v = cm.ab_verdict("sort", backend=backend)
-            for scope in ("machinery", "sweep"):
-                for gname, arms in configs["sort_ab"].get(scope, {}).items():
-                    sp = arms.get("speedup_radix_over_xla")
-                    if sp is None:
-                        continue
-                    measured = "radix" if sp > 1.0 else "xla"
-                    _grade_entry(results, "sort",
-                                 f"{pr}/{scope}/{gname}",
-                                 v["winner"], measured, v["basis"])
 
         ab = _live(configs, "tree_cache_ab")
         if ab:
@@ -205,7 +191,7 @@ def grade_trajectory(path: str = TRAJECTORY) -> tuple:
             _grade_entry(results, "pipeline", f"{pr}/pipeline_ab",
                          v["winner"], measured, v["basis"])
 
-    for kind in ("sort", "tree_cache", "pipeline"):
+    for kind in ("tree_cache", "pipeline"):
         if kind not in kinds_seen:
             problems.append(
                 f"banked trajectory has no {kind}_ab line to grade — "

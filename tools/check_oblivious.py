@@ -15,11 +15,10 @@ secret-derived — modulo the reviewed allowlist
 one-line leak argument AND must be *reached* somewhere in the swept knob
 matrix (dead entries fail the run).
 
-Sweep: the shipped knob combinations over
-{vphases_impl, sort_impl, posmap_impl, tree_top_cache_levels} by
-default; the full 2x2x2x2 cross-product under ``--full`` (the -m slow
-tier). ``--smoke`` is the tier-1 budget: one representative combo, one
-engine trace, no compile.
+Sweep: the 2x2 knob matrix {posmap_impl} x {tree_top_cache_levels};
+``--full`` (the -m slow tier) adds the program-equality census on
+every combination, not only the first. ``--smoke`` is the tier-1
+budget: one representative combo, one engine trace, no compile.
 
 Teeth: the seeded mutants (grapevine_tpu/analysis/mutants.py) run under
 the production allowlists on every invocation and must each FAIL — the
@@ -49,30 +48,25 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if REPO not in sys.path:
     sys.path.insert(0, REPO)
 
-#: shipped auto-reachable knob combinations (vphases, sort, posmap, k):
-#: chosen so every allowlist entry is reachable — dense+scan,
-#: xla+radix, flat+recursive and cached+uncached all appear, in the
-#: pairings the `auto` resolution ships (config.py: dense/xla is the
-#: measured CPU default; scan/radix the TPU-leaning pairing; recursive
-#: rides both).
+#: the knob matrix (posmap, k): flat+recursive x uncached+cached, so
+#: that every allowlist entry is reachable
 DEFAULT_COMBOS = (
-    ("dense", "xla", "flat", 0),
-    ("scan", "xla", "recursive", 2),
-    ("scan", "radix", "flat", 2),
-    ("dense", "radix", "recursive", 0),
+    ("flat", 0),
+    ("recursive", 2),
+    ("flat", 2),
+    ("recursive", 0),
 )
 #: tier-1 budget: ONE combo
-SMOKE_COMBO = ("dense", "xla", "flat", 0)
+SMOKE_COMBO = ("flat", 0)
 
 
-def _small_engine(vp: str, srt: str, pmi: str, k: int):
+def _small_engine(pmi: str, k: int):
     from grapevine_tpu.config import GrapevineConfig
     from grapevine_tpu.engine.state import EngineConfig
 
     cfg = GrapevineConfig(
         max_messages=32, max_recipients=16, batch_size=4,
-        vphases_impl=vp, sort_impl=srt, posmap_impl=pmi,
-        tree_top_cache_levels=k,
+        posmap_impl=pmi, tree_top_cache_levels=k,
     )
     return EngineConfig.from_config(cfg)
 
@@ -156,8 +150,7 @@ def _small_oram_cfg(recursive: bool, k: int):
     )
 
 
-def audit_oram_round(allowlist, occ_impl: str, sort_impl: str,
-                     recursive: bool, k: int):
+def audit_oram_round(allowlist, recursive: bool, k: int):
     """Taint-audit the library sub-rounds standalone: oram_round (and
     through it lookup_remap_round) at a small geometry."""
     import jax
@@ -181,7 +174,6 @@ def audit_oram_round(allowlist, occ_impl: str, sort_impl: str,
             pm_dummy_leaves):
         return oround.oram_round(
             cfg, state, idxs, new_leaves, dummy_leaves, apply_batch,
-            occ_impl=occ_impl, sort_impl=sort_impl,
             pm_new_leaves=pm_new_leaves if recursive else None,
             pm_dummy_leaves=pm_dummy_leaves if recursive else None,
         )
@@ -193,13 +185,11 @@ def audit_oram_round(allowlist, occ_impl: str, sort_impl: str,
          "pm_dummy_leaves": sds(b)},
         secrets=oround.OBLINT_SECRETS,
         allowlist=allowlist,
-        name=f"oram_round/{occ_impl}_{sort_impl}_"
-             f"{'rec' if recursive else 'flat'}_k{k}",
+        name=f"oram_round/{'rec' if recursive else 'flat'}_k{k}",
     )
 
 
-def audit_lookup_remap(allowlist, occ_impl: str, sort_impl: str,
-                       recursive: bool):
+def audit_lookup_remap(allowlist, recursive: bool):
     """Taint-audit lookup_remap_round standalone against ITS OWN
     anchors (oram/posmap.py OBLINT_SECRETS — the occurrence masks are
     secrets here, which the engine-round audit derives internally)."""
@@ -228,7 +218,6 @@ def audit_lookup_remap(allowlist, occ_impl: str, sort_impl: str,
             first_occ, last_occ,
             pm_new_leaves=pm_new_leaves if recursive else None,
             pm_dummy_leaves=pm_dummy_leaves if recursive else None,
-            occ_impl=occ_impl, sort_impl=sort_impl,
         )
 
     return analyze(
@@ -239,8 +228,7 @@ def audit_lookup_remap(allowlist, occ_impl: str, sort_impl: str,
          "pm_dummy_leaves": sds(b)},
         secrets=pmod.OBLINT_SECRETS,
         allowlist=allowlist,
-        name=f"lookup_remap/{occ_impl}_{sort_impl}_"
-             f"{'rec' if recursive else 'flat'}",
+        name=f"lookup_remap/{'rec' if recursive else 'flat'}",
     )
 
 
@@ -408,31 +396,27 @@ def run_audit(combos, allowlist=None, with_census="first",
             print(rep.summary())
         problems.extend(f"{rep.name}: {v}" for v in rep.violations)
 
-    for vp, srt, pmi, k in combos:
-        name = f"{vp}_{srt}_{pmi}_k{k}"
-        absorb(audit_engine_round(_small_engine(vp, srt, pmi, k),
-                                  allowlist, name))
+    for pmi, k in combos:
+        name = f"{pmi}_k{k}"
+        absorb(audit_engine_round(_small_engine(pmi, k), allowlist, name))
         for kernel in (False, True):
-            absorb(audit_expiry_sweep(_small_engine(vp, srt, pmi, k),
+            absorb(audit_expiry_sweep(_small_engine(pmi, k),
                                       allowlist, name, kernel))
         if with_subrounds:
             absorb(audit_oram_round(
-                allowlist, occ_impl=vp, sort_impl=srt,
-                recursive=(pmi == "recursive"), k=k,
+                allowlist, recursive=(pmi == "recursive"), k=k,
             ))
             absorb(audit_lookup_remap(
-                allowlist, occ_impl=vp, sort_impl=srt,
-                recursive=(pmi == "recursive"),
+                allowlist, recursive=(pmi == "recursive"),
             ))
     if with_subrounds:
         for rep in audit_dma_write_back():
             absorb(rep)
     if with_census:
         census_combos = combos if with_census == "all" else combos[:1]
-        for vp, srt, pmi, k in census_combos:
+        for pmi, k in census_combos:
             for v in census_equal_engine(
-                _small_engine(vp, srt, pmi, k),
-                f"{vp}_{srt}_{pmi}_k{k}",
+                _small_engine(pmi, k), f"{pmi}_k{k}",
             ):
                 problems.append(str(v))
     return problems, hits
@@ -453,7 +437,6 @@ def check_allowlist_reachability(hits: dict) -> list:
 
 def main(argv=None) -> int:
     import argparse
-    import itertools
 
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--smoke", action="store_true",
@@ -461,8 +444,8 @@ def main(argv=None) -> int:
                          "mutants + locklint; no census sweep, no "
                          "reachability check")
     ap.add_argument("--full", action="store_true",
-                    help="full 2x2x2x2 knob cross-product + census "
-                         "equality on every combo (the -m slow tier)")
+                    help="census equality on every combo of the knob "
+                         "matrix, not only the first (the -m slow tier)")
     ap.add_argument("--skip-mutants", action="store_true")
     ap.add_argument("--verbose", action="store_true")
     args = ap.parse_args(argv)
@@ -471,23 +454,15 @@ def main(argv=None) -> int:
 
     problems: list = []
     if args.smoke:
-        vp, srt, pmi, k = SMOKE_COMBO
+        pmi, k = SMOKE_COMBO
         rep = audit_engine_round(
-            _small_engine(vp, srt, pmi, k), ENGINE_ALLOWLIST,
-            f"{vp}_{srt}_{pmi}_k{k}",
+            _small_engine(pmi, k), ENGINE_ALLOWLIST, f"{pmi}_k{k}",
         )
         print(rep.summary())
         problems.extend(f"{rep.name}: {v}" for v in rep.violations)
     else:
-        combos = (
-            tuple(itertools.product(
-                ("dense", "scan"), ("xla", "radix"),
-                ("flat", "recursive"), (0, 2),
-            ))
-            if args.full else DEFAULT_COMBOS
-        )
         swept, hits = run_audit(
-            combos, with_census="all" if args.full else "first",
+            DEFAULT_COMBOS, with_census="all" if args.full else "first",
             with_subrounds=True, verbose=args.verbose,
         )
         problems.extend(swept)
@@ -504,7 +479,7 @@ def main(argv=None) -> int:
         return 1
     scope = (
         "smoke combo" if args.smoke
-        else "full knob matrix" if args.full else "shipped knob matrix"
+        else "knob matrix, census on all" if args.full else "knob matrix"
     )
     reach = "" if args.smoke else "; every allowlist entry reachable"
     teeth = "" if args.skip_mutants else "; all mutants caught"

@@ -62,7 +62,7 @@ LOWER_BETTER = ("_ms", "_ms_per_op", "_s")
 #: series (comparing B=8 smoke against B=2048 full would be noise, not
 #: signal) and are excluded from the metrics themselves
 GEOMETRY_KEYS = ("batch", "capacity_log2", "mesh", "clients",
-                 "tree_density", "key_bits", "radix_bits_per_pass",
+                 "tree_density", "key_bits",
                  "rounds", "slo_target_ms", "pipeline_depth",
                  "shard_count", "tail_frames",
                  "worker_count", "adaptive_batch", "crypto_backend",

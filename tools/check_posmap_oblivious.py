@@ -5,8 +5,7 @@ The recursive position map's obliviousness claim (oram/posmap.py) is
 that resolving a batch of B positions performs a FIXED schedule of
 device memory accesses — the same number of gathers and scatters, in
 the same program, no matter which indices are queried (duplicates,
-all-same, all-dummy, anything). The jaxpr-audit pattern of PR 3/PR 5
-(no-[B,B] / zero-sort-HLO gates) extends here to the access census:
+all-same, all-dummy, anything). The access census:
 
 1. trace ``lookup_remap_round`` with the *indices baked in as concrete
    constants* for several adversarially different index sets (all
@@ -70,14 +69,14 @@ def _index_sets(cfg, b: int):
     }
 
 
-def _trace_lookup(cfg, idxs, b: int, occ_impl: str, sort_impl: str):
+def _trace_lookup(cfg, idxs, b: int):
     """Jaxpr of one whole-batch lookup+remap with ``idxs`` constant."""
     import jax
     import jax.numpy as jnp
 
     from grapevine_tpu.oram.path_oram import init_oram
     from grapevine_tpu.oram.posmap import lookup_remap_round
-    from grapevine_tpu.oram.round import occurrence_masks, occurrence_masks_sorted
+    from grapevine_tpu.oram.round import occurrence_masks
 
     state = jax.eval_shape(lambda: init_oram(cfg, jax.random.PRNGKey(0)))
     pm_shape = state.posmap
@@ -85,18 +84,11 @@ def _trace_lookup(cfg, idxs, b: int, occ_impl: str, sort_impl: str):
     cidxs = jnp.asarray(idxs)
 
     def run(pm, nl, dl, pm_nl, pm_dl):
-        if occ_impl == "scan":
-            fo, lo, _ = occurrence_masks_sorted(
-                cidxs, cfg.dummy_index, sort_impl=sort_impl,
-                key_bits=max(1, cfg.dummy_index.bit_length()),
-            )
-        else:
-            fo, lo, _ = occurrence_masks(cidxs, cfg.dummy_index)
+        fo, lo, _ = occurrence_masks(cidxs, cfg.dummy_index)
         return lookup_remap_round(
             cfg, pm, cidxs, nl, dl, fo, lo,
             pm_new_leaves=pm_nl if cfg.posmap is not None else None,
             pm_dummy_leaves=pm_dl if cfg.posmap is not None else None,
-            occ_impl=occ_impl, sort_impl=sort_impl,
         )
 
     u32 = jnp.uint32
@@ -107,10 +99,7 @@ def _trace_lookup(cfg, idxs, b: int, occ_impl: str, sort_impl: str):
     )
 
 
-def check_posmap_access_schedule(
-    b: int = 16, occ_impl: str = "dense", sort_impl: str = "xla",
-    verbose: bool = False,
-) -> dict:
+def check_posmap_access_schedule(b: int = 16, verbose: bool = False) -> dict:
     """Run the audit; returns the census summary, raises AssertionError
     on any violation."""
     from grapevine_tpu.oram.path_oram import OramConfig
@@ -126,7 +115,7 @@ def check_posmap_access_schedule(
     for name, cfg in (("flat", flat_cfg), ("recursive", rec_cfg)):
         censuses = {}
         for iname, idxs in _index_sets(cfg, b).items():
-            c = _census(_trace_lookup(cfg, idxs, b, occ_impl, sort_impl))
+            c = _census(_trace_lookup(cfg, idxs, b))
             censuses[iname] = c
         base_name, base = next(iter(censuses.items()))
         for iname, c in censuses.items():
@@ -170,11 +159,8 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--batch", type=int, default=16)
     args = ap.parse_args(argv)
-    for occ, srt in (("dense", "xla"), ("scan", "xla"), ("scan", "radix")):
-        out = check_posmap_access_schedule(
-            b=args.batch, occ_impl=occ, sort_impl=srt, verbose=True
-        )
-        print(f"[check_posmap_oblivious] occ={occ} sort={srt}: OK {out}")
+    out = check_posmap_access_schedule(b=args.batch, verbose=True)
+    print(f"[check_posmap_oblivious] OK {out}")
     print("[check_posmap_oblivious] PASS: position-map access schedule "
           "is a constant of the geometry")
     return 0

@@ -14,15 +14,14 @@ would hide those). Intentional mod-2^32 sites (ChaCha ARX, the keyed
 mixers, u64 two-lane carries) pass through the reviewed RANGE_ALLOWLIST,
 each entry with its one-line range argument; dead entries fail the run.
 
-Sweep: the shipped knob combinations over {vphases_impl, sort_impl,
-posmap_impl, tree_top_cache_levels} at the declared
+Sweep: the 2x2 knob matrix {posmap_impl} x {tree_top_cache_levels}
+at the declared
 ``--geometry`` (log2 records; default 30 — the max certified per-tree
 capacity, where every allowlist entry genuinely fires), engine round +
 expiry sweep + standalone oram_round/lookup_remap_round per combo, plus
 the write-back scatter under a 2-shard ``shard_map`` (the lanes only
-the mesh has). ``--full`` sweeps the 2x2x2x2 cross-product (the -m slow
-tier). ``--smoke`` is the tier-1 budget: one combo at toy geometry,
-traces only, zero engine compiles.
+the mesh has). ``--smoke`` is the tier-1 budget: one combo at toy
+geometry, traces only, zero engine compiles.
 
 Geometry certification: ``--geometry 30`` certifies today's capacity
 point clean; ``--geometry 36`` (the ROADMAP item 4 design point) must be
@@ -53,20 +52,20 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if REPO not in sys.path:
     sys.path.insert(0, REPO)
 
-#: shipped auto-reachable knob combinations — the check_oblivious set,
-#: so the two analyzers certify the identical program matrix
+#: the knob matrix (posmap, k) — the check_oblivious set, so the two
+#: analyzers certify the identical program matrix
 DEFAULT_COMBOS = (
-    ("dense", "xla", "flat", 0),
-    ("scan", "xla", "recursive", 2),
-    ("scan", "radix", "flat", 2),
-    ("dense", "radix", "recursive", 0),
+    ("flat", 0),
+    ("recursive", 2),
+    ("flat", 2),
+    ("recursive", 0),
 )
 #: tier-1 budget: ONE combo (check_oblivious's smoke)
-SMOKE_COMBO = ("dense", "xla", "flat", 0)
+SMOKE_COMBO = ("flat", 0)
 
 #: default certification geometry (log2 records) for the standalone
 #: sweep: the max certified per-tree capacity — several allowlist
-#: entries (e.g. the _rank_pass rank recombination) only *fire* once
+#: entries (e.g. partition_rank's rank recombination) only *fire* once
 #: the lanes get tight, so reachability at toy geometry would misread
 #: them as dead. --smoke uses the toy engine regardless.
 DEFAULT_GEOMETRY = 30
@@ -79,8 +78,7 @@ DESIGN_POINT = 36
 MAX_CERTIFIED_GEOMETRY = 30
 
 
-def _engine(log2_msgs: int, vp: str, srt: str, pmi: str, k: int,
-            batch: int = 4):
+def _engine(log2_msgs: int, pmi: str, k: int, batch: int = 4):
     from grapevine_tpu.config import GrapevineConfig
     from grapevine_tpu.engine.state import EngineConfig
 
@@ -88,8 +86,7 @@ def _engine(log2_msgs: int, vp: str, srt: str, pmi: str, k: int,
         max_messages=1 << log2_msgs,
         max_recipients=max(16, 1 << min(log2_msgs, 20)),
         batch_size=batch,
-        vphases_impl=vp, sort_impl=srt, posmap_impl=pmi,
-        tree_top_cache_levels=k,
+        posmap_impl=pmi, tree_top_cache_levels=k,
     )
     return EngineConfig.from_config(cfg)
 
@@ -230,8 +227,8 @@ def audit_sharded_path_scatter(allowlist, log2_blocks: int,
     return rep
 
 
-def audit_oram_round(allowlist, log2_blocks: int, occ_impl: str,
-                     sort_impl: str, recursive: bool, k: int):
+def audit_oram_round(allowlist, log2_blocks: int, recursive: bool,
+                     k: int):
     import jax
     import jax.numpy as jnp
 
@@ -257,7 +254,6 @@ def audit_oram_round(allowlist, log2_blocks: int, occ_impl: str,
             pm_dummy_leaves):
         return oround.oram_round(
             cfg, state, idxs, new_leaves, dummy_leaves, apply_batch,
-            occ_impl=occ_impl, sort_impl=sort_impl,
             pm_new_leaves=pm_new_leaves if recursive else None,
             pm_dummy_leaves=pm_dummy_leaves if recursive else None,
         )
@@ -277,13 +273,12 @@ def audit_oram_round(allowlist, log2_blocks: int, occ_impl: str,
          "pm_dummy_leaves": sds(b)},
         bounds=bounds,
         allowlist=allowlist,
-        name=f"oram_round/2^{log2_blocks}_{occ_impl}_{sort_impl}_"
+        name=f"oram_round/2^{log2_blocks}_"
              f"{'rec' if recursive else 'flat'}_k{k}",
     )
 
 
-def audit_lookup_remap(allowlist, log2_blocks: int, occ_impl: str,
-                       sort_impl: str, recursive: bool):
+def audit_lookup_remap(allowlist, log2_blocks: int, recursive: bool):
     import jax
     import jax.numpy as jnp
 
@@ -307,7 +302,6 @@ def audit_lookup_remap(allowlist, log2_blocks: int, occ_impl: str,
             first_occ, last_occ,
             pm_new_leaves=pm_new_leaves if recursive else None,
             pm_dummy_leaves=pm_dummy_leaves if recursive else None,
-            occ_impl=occ_impl, sort_impl=sort_impl,
         )
 
     return analyze_ranges(
@@ -318,7 +312,7 @@ def audit_lookup_remap(allowlist, log2_blocks: int, occ_impl: str,
          "pm_dummy_leaves": sds(b)},
         bounds=pmod.RANGELINT_BOUNDS(cfg),
         allowlist=allowlist,
-        name=f"lookup_remap/2^{log2_blocks}_{occ_impl}_{sort_impl}_"
+        name=f"lookup_remap/2^{log2_blocks}_"
              f"{'rec' if recursive else 'flat'}",
     )
 
@@ -355,20 +349,18 @@ def run_audit(combos, geometry: int, allowlist=None, verbose=False,
 
     # engine geometry: max_messages = 2^geometry; sub-round geometry:
     # the same block count standalone
-    for vp, srt, pmi, k in combos:
-        name = f"2^{geometry}_{vp}_{srt}_{pmi}_k{k}"
-        ecfg = _engine(geometry, vp, srt, pmi, k)
+    for pmi, k in combos:
+        name = f"2^{geometry}_{pmi}_k{k}"
+        ecfg = _engine(geometry, pmi, k)
         absorb(audit_engine_round(ecfg, allowlist, name))
         for kernel in (False, True):
             absorb(audit_expiry_sweep(ecfg, allowlist, name, kernel))
         if with_subrounds:
             absorb(audit_oram_round(
-                allowlist, geometry, occ_impl=vp, sort_impl=srt,
-                recursive=(pmi == "recursive"), k=k,
+                allowlist, geometry, recursive=(pmi == "recursive"), k=k,
             ))
             absorb(audit_lookup_remap(
-                allowlist, geometry, occ_impl=vp, sort_impl=srt,
-                recursive=(pmi == "recursive"),
+                allowlist, geometry, recursive=(pmi == "recursive"),
             ))
     if with_subrounds:
         import jax
@@ -407,7 +399,7 @@ def certify_design_point(log2_records: int) -> "tuple[list, str]":
     certified bound; returns (problems, the refusal text this report
     cites)."""
     try:
-        _engine(log2_records, "dense", "xla", "flat", 0)
+        _engine(log2_records, "flat", 0)
     except ValueError as exc:
         return [], str(exc)
     return [
@@ -437,9 +429,6 @@ def main(argv=None) -> int:
                     help="tier-1 budget: one toy-geometry combo, engine "
                          "trace + range mutants + design-point refusal; "
                          "zero compiles")
-    ap.add_argument("--full", action="store_true",
-                    help="full 2x2x2x2 knob cross-product (the -m slow "
-                         "tier)")
     ap.add_argument("--geometry", type=int, default=None, metavar="LOG2",
                     help=f"records capacity to certify (log2; default "
                          f"{DEFAULT_GEOMETRY}; {DESIGN_POINT} = the "
@@ -457,10 +446,10 @@ def main(argv=None) -> int:
     )
 
     if args.smoke:
-        vp, srt, pmi, k = SMOKE_COMBO
-        ecfg = _engine(5, vp, srt, pmi, k)
+        pmi, k = SMOKE_COMBO
+        ecfg = _engine(5, pmi, k)
         rep = audit_engine_round(
-            ecfg, RANGE_ALLOWLIST, f"smoke_{vp}_{srt}_{pmi}_k{k}",
+            ecfg, RANGE_ALLOWLIST, f"smoke_{pmi}_k{k}",
         )
         print(rep.summary())
         problems.extend(f"{rep.name}: {f}" for f in rep.findings)
@@ -497,17 +486,8 @@ def main(argv=None) -> int:
                     "widened lanes (item 4)"
                 )
             sweep_geometry = MAX_CERTIFIED_GEOMETRY
-        combos = None
-        if args.full:
-            import itertools
-
-            combos = tuple(itertools.product(
-                ("dense", "scan"), ("xla", "radix"),
-                ("flat", "recursive"), (0, 2),
-            ))
         swept, hits = run_audit(
-            combos or DEFAULT_COMBOS, sweep_geometry,
-            verbose=args.verbose,
+            DEFAULT_COMBOS, sweep_geometry, verbose=args.verbose,
         )
         problems.extend(swept)
         problems.extend(check_allowlist_reachability(hits))
@@ -522,8 +502,7 @@ def main(argv=None) -> int:
         return 1
     scope = (
         "smoke combo" if args.smoke
-        else f"full knob matrix @ 2^{geometry}" if args.full
-        else f"shipped knob matrix @ 2^{geometry}"
+        else f"knob matrix @ 2^{geometry}"
     )
     reach = "" if args.smoke else "; every range-allowlist entry reachable"
     teeth = "" if args.skip_mutants else "; all overflow mutants caught"
