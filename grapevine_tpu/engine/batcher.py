@@ -344,6 +344,13 @@ class PendingRound:
                             phases, queue_depth=self._qdepth)
 
 
+def _sweep_clock(now: int, period: int) -> tuple:
+    """The sweep program's three scalars: the clock's low word, the
+    period, the clock's high word."""
+    return (np.uint32(int(now) & 0xFFFFFFFF), np.uint32(period),
+            np.uint32((int(now) >> 32) & 0xFFFFFFFF))
+
+
 #: the journal's counts of a round on an engine with no state directory
 _NO_JOURNAL = {"seal_s": 0.0, "fsync_s": 0.0, "bytes": 0}
 
@@ -420,6 +427,9 @@ class GrapevineEngine:
             self._step_jit = sstep
             ssweep = make_sharded_sweep(self.ecfg, self._mesh)
             self._sweep = lambda _ecfg, state, *clock: ssweep(state, *clock)
+            #: the jit that holds the sweep's executable
+            #: (:meth:`_warm_sweep`)
+            self._sweep_jit = ssweep
         else:
             #: builds the empty state: at construction, and again when a
             #: restart in place (:meth:`recover`) finds none on the device
@@ -432,7 +442,7 @@ class GrapevineEngine:
             self._step = self._step_jit = jax.jit(
                 step_fn, static_argnums=(0,), donate_argnums=(1,)
             )
-            self._sweep = jax.jit(
+            self._sweep = self._sweep_jit = jax.jit(
                 expiry_sweep, static_argnums=(0,), donate_argnums=(1,)
             )
         #: (perf_counter when the last resolved round was observed
@@ -504,6 +514,9 @@ class GrapevineEngine:
         #: recovery (checkpoint load + deterministic journal replay), so
         #: a freshly built engine already holds the pre-crash state
         self.durability = None
+        if self.config.expiry_period > 0:
+            # before the recovery, which may meet a sweep frame
+            self._warm_sweep()
         if durability is not None:
             from .checkpoint import DurabilityManager
 
@@ -561,6 +574,19 @@ class GrapevineEngine:
             self.ecfg, state,
             np.uint32(rec.now), np.uint32(rec.period), np.uint32(rec.now_hi),
         )
+
+    def _warm_sweep(self) -> None:
+        """Compile the sweep program (or load it from the compile
+        cache) for the state this engine holds, running nothing: jax
+        keeps the executable on the lowering the jit's own calls find,
+        so neither the first :meth:`expire` of a served bus, one
+        ``expiry_period / 10`` after start and under the engine's lock
+        with every Query waiting behind it, nor a sweep frame that a
+        recovery replays compiles anything."""
+        operands = (self.state, *_sweep_clock(1, 1))
+        if self._mesh is None:  # the one-chip jit takes ecfg, statically
+            operands = (self.ecfg,) + operands
+        self._sweep_jit.lower(*operands).compile()
 
     def checkpoint_now(self) -> int | None:
         """Force a sealed checkpoint of the current state (the drain
@@ -788,23 +814,21 @@ class GrapevineEngine:
         period = self.config.expiry_period if period is None else period
         if period <= 0:
             return 0
+        span = self.metrics.span
+        clock = _sweep_clock(now, period)
+        # call -> lock held: the rounds (and the checkpoint) ahead of it
+        waiting = span("sweep_lock").begin()
         with self._lock:
+            waiting.end()
             before = int(self.state.free_top)
             if self.durability is not None:
                 # journal-before-mutate, same as rounds: a crash between
                 # append and apply replays the sweep (apply ≡ replay)
-                self.durability.append_sweep(
-                    int(now) & 0xFFFFFFFF, (int(now) >> 32) & 0xFFFFFFFF,
-                    int(period),
-                )
-            with self.metrics.span("sweep"):
-                self.state = self._sweep(
-                    self.ecfg,
-                    self.state,
-                    np.uint32(int(now) & 0xFFFFFFFF),
-                    np.uint32(period),
-                    np.uint32((int(now) >> 32) & 0xFFFFFFFF),
-                )
+                with span("sweep_journal"):
+                    self.durability.append_sweep(
+                        int(clock[0]), int(clock[2]), int(period))
+            with span("sweep"):
+                self.state = self._sweep(self.ecfg, self.state, *clock)
                 jax.block_until_ready(self.state.free_top)
             self._other_device_work = True
             evicted = int(self.state.free_top) - before

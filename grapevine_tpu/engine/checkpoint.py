@@ -822,6 +822,7 @@ class DurabilityManager:
         self._g_durable = self._g_ckpt = self._g_replayed = None
         self._g_recovery_s = self._g_applied = self._g_load_s = None
         self._g_ckpt_s = self._g_ckpt_bytes = None
+        self._c_replayed = self._c_replay_s = None
         if registry is not None:
             self._c_records = registry.counter(
                 "grapevine_journal_records_total",
@@ -841,6 +842,16 @@ class DurabilityManager:
             self._g_replayed = registry.gauge(
                 "grapevine_recovery_replayed_records",
                 "journal records replayed during the last recovery")
+            self._c_replayed = registry.counter(
+                "grapevine_recover_replayed_total",
+                "journal records replayed by this process's recoveries, "
+                "by kind", labels={"kind": ("round", "sweep")})
+            self._c_replay_s = registry.counter(
+                "grapevine_recover_replay_seconds",
+                "wall seconds this process's recoveries spent replaying "
+                "the journal's tail: the first frame read to the device "
+                "ready with the last (over grapevine_recover_replayed_"
+                "total: the replay rate, the RTO's second term)")
             self._g_recovery_s = registry.gauge(
                 "grapevine_recovery_seconds",
                 "wall time of the last startup recovery")
@@ -915,6 +926,8 @@ class DurabilityManager:
         into its arrays (:func:`load_checkpoint` ``into``), so the
         device holds one state throughout. After a raise it is not to
         be used."""
+        from .journal import KIND_ROUND
+
         t0 = time.monotonic()
         state = init_state
         load_s = 0.0
@@ -938,16 +951,21 @@ class DurabilityManager:
             self.recovered_from_checkpoint = True
         self.replayed = 0
         self.note_applied_seq(self.ckpt_seq)
+        t_replay = time.monotonic()
         for rec in self.journal.replay(after_seq=self.ckpt_seq):
             state = apply_fn(state, rec)
             self.replayed += 1
             self.note_applied_seq(self.journal.seq)
             if self._g_replayed is not None:
                 self._g_replayed.set(self.replayed)
+                self._c_replayed.inc(
+                    kind="round" if rec.kind == KIND_ROUND else "sweep")
         self.journal.open_for_append()
         # the replay is dispatched, not yet applied: the recovery ends
         # when the device has it
         jax.block_until_ready(state)
+        if self._c_replay_s is not None:
+            self._c_replay_s.inc(time.monotonic() - t_replay)
         if self._g_ckpt is not None:
             self._g_ckpt.set(self.ckpt_seq)
             self._g_durable.set(self.journal.seq)

@@ -62,6 +62,7 @@ from ..testing.leakcheck import (
     uniformity_z_from_counts,
 )
 from .flightrec import FlightRecorder
+from .phases import trace_span
 from .registry import TelemetryRegistry
 
 log = logging.getLogger("grapevine_tpu.obs.leakmon")
@@ -120,6 +121,16 @@ class LeakMonitorConfig:
     #: where a PASS→SUSPECT transition dumps the flight recorder
     #: (None = no automatic dump; /flightrec still serves it on demand)
     dump_path: str | None = None
+
+    @classmethod
+    def coerce(cls, value) -> "LeakMonitorConfig | None":
+        """``value`` as a LeakMonitorConfig: one as it is, None as None
+        (no monitor), and a mapping of the fields (what a JSON
+        configuration file holds; the empty mapping is ``--leakmon``
+        with every default) built; a key that is no field raises."""
+        if value is None or isinstance(value, cls):
+            return value
+        return cls(**dict(value))
 
 
 class _Stream:
@@ -425,7 +436,7 @@ class EngineLeakMonitor:
         self.monitor = TranscriptLeakMonitor(trees, self.cfg, registry)
         self.recorder = recorder or FlightRecorder(self.cfg.flight_capacity)
         self._c_rounds = self._c_dropped = self._c_transitions = None
-        self._g_suspect = None
+        self._c_seconds = self._g_suspect = None
         if registry is not None:
             self._c_rounds = registry.counter(
                 "grapevine_leakmon_rounds_total",
@@ -434,6 +445,12 @@ class EngineLeakMonitor:
                 "grapevine_leakmon_rounds_dropped_total",
                 "engine rounds dropped at the monitor hand-off queue "
                 "(monitor slower than the round rate)")
+            self._c_seconds = registry.counter(
+                "grapevine_leakmon_seconds_total",
+                "CPU seconds of the monitor's own thread "
+                "(time.thread_time) over the rounds it audited: the "
+                "transcript's copy off the device, key grouping, the "
+                "detectors, the verdict and the flight record")
             self._c_transitions = registry.counter(
                 "grapevine_leakmon_suspect_transitions_total",
                 "PASS→SUSPECT verdict transitions")
@@ -535,12 +552,16 @@ class EngineLeakMonitor:
             if item is None:
                 self._q.task_done()
                 return
+            c0 = time.thread_time()
             try:
-                self._process(*item)
+                with trace_span("leakmon"):
+                    self._process(*item)
             except Exception:
                 log.exception("leak monitor failed on a round "
                               "(monitoring continues)")
             finally:
+                if self._c_seconds is not None:
+                    self._c_seconds.inc(time.thread_time() - c0)
                 self._processed += 1
                 self._q.task_done()
 

@@ -21,7 +21,13 @@ Host-side phases (histograms + ``jax.profiler`` annotations):
                   reduced by the ``DEVICE_SCOPES`` below, not from
                   metrics — the host cannot time inside one XLA program)
 - ``demux``     — device→wire response unpacking
-- ``sweep``     — expiry sweep (engine/expiry.py)
+- ``sweep``     — expiry sweep (engine/expiry.py): the device's pass,
+                  enqueue to ready; before it in ``expire``, on the
+                  caller's thread: ``sweep_lock`` (``expire`` called to
+                  the engine's lock held: the rounds and the checkpoint
+                  ahead of it) and ``sweep_journal`` (the sweep's journal
+                  frame sealed, written and fsynced; 0 with no state
+                  directory)
 - ``journal``   — sealed batch-journal append + fsync (engine/journal.py)
 - ``checkpoint``— sealed whole-state checkpoint write (engine/checkpoint.py)
                   and, inside it on the same thread, its three parts:
@@ -72,7 +78,8 @@ from .registry import TelemetryLeakError
 #: so a typo'd phase name raises instead of minting a new series
 PHASES = ("assembly", "verify", "dispatch", "evict", "demux", "sweep",
           "journal", "checkpoint", "replay",
-          "checkpoint_read", "checkpoint_seal", "checkpoint_write")
+          "checkpoint_read", "checkpoint_seal", "checkpoint_write",
+          "sweep_lock", "sweep_journal")
 
 #: canonical device scope names — ``device_phase`` refuses any other, so
 #: a typo'd or per-op scope name raises at trace time instead of minting
@@ -136,9 +143,10 @@ SPAN_NAMES = frozenset(PHASES) | frozenset(ROUND_SPANS)
 
 #: every name :func:`trace_span` takes, annotations outside any round:
 #: start-up's ``state_init``, the engine tier's ``ingress`` (a handler
-#: thread's), and ``asleep``, the collector with nothing queued and
-#: nothing in flight, between two cycles
-ANNOTATION_NAMES = frozenset(("state_init", "ingress", "asleep"))
+#: thread's), ``asleep``, the collector with nothing queued and
+#: nothing in flight, between two cycles, and ``leakmon``, one round
+#: audited on the leak monitor's own thread (obs/leakmon.py)
+ANNOTATION_NAMES = frozenset(("state_init", "ingress", "asleep", "leakmon"))
 
 #: the collector thread's OS name (15 bytes): what ``top -H`` shows, and
 #: the name of its line in a profiler capture, by which a reader tells

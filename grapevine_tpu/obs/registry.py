@@ -42,6 +42,8 @@ ALLOWED_LABEL_KEYS = frozenset({
     "tree",    # which ORAM ("rec" / "mb") — structural, not data
     "role",    # serving role ("mono" / "engine" / "frontend")
     "result",  # coarse outcome bucket ("ok" / "error")
+    "kind",    # journal record kind ("round" / "sweep"): which of the
+               # engine's two fixed-shape programs a record re-runs
     "shard",   # fleet shard index — declared small-integer topology
                # positions only (obs/fleet.py); never a member name,
                # address, or anything derived from traffic

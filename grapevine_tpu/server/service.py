@@ -218,8 +218,9 @@ class GrapevineServer:
                 self.scheduler.hostpipe = self.hostpipe
         self._metrics_server = None
         #: continuous obliviousness auditing (obs/leakmon.py): pass a
-        #: LeakMonitorConfig to watch every round's transcript. Device-
-        #: owner only — the frontend role never sees a transcript.
+        #: LeakMonitorConfig, or a mapping of its fields (what a JSON
+        #: configuration file holds), to watch every round's transcript.
+        #: Device-owner only — the frontend role never sees a transcript.
         self.leakmon = None
         if leakmon is not None:
             if self.engine is None:
@@ -227,9 +228,10 @@ class GrapevineServer:
                     "leak monitoring needs the device engine in-process "
                     "(the frontend role has no transcript to audit)"
                 )
-            from ..obs.leakmon import EngineLeakMonitor
+            from ..obs.leakmon import EngineLeakMonitor, LeakMonitorConfig
 
-            self.leakmon = EngineLeakMonitor.for_engine(self.engine, leakmon)
+            self.leakmon = EngineLeakMonitor.for_engine(
+                self.engine, LeakMonitorConfig.coerce(leakmon))
             self.engine.attach_leakmon(self.leakmon)
         #: primary-side journal shipping (engine/replication.py): stream
         #: every sealed frame to a hot standby. Device-owner only — the

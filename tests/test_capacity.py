@@ -270,6 +270,79 @@ def test_the_durable_deployment_resolves_to_what_its_file_says(tmp_path):
     assert said["checkpoint_bytes"] == 10_253_824_277
 
 
+def test_the_runbook_deployment_resolves_to_what_its_file_says_and_holds_its_siblings():
+    """``benchmarks/configs/chipshare-2p21-r2p17-runbook.json`` is
+    ``chipshare-2p21-r2p17`` with the runbook's three options on
+    together; shapes only, no tree allocated. It is held to its three
+    sibling files key for key: the round's geometry is the parent's, the
+    TTL and its guarantee are the TTL file's, the ``durability`` mapping
+    (but for the state directory) and its guarantee are the durable
+    file's; and the ``leakmon`` mapping is what the CLI passes for a
+    bare ``--leakmon``."""
+    import json
+
+    from grapevine_tpu.config import DurabilityConfig
+    from grapevine_tpu.engine.journal import _HEADER, _SEAL_OVERHEAD
+    from grapevine_tpu.obs.leakmon import LeakMonitorConfig
+    from grapevine_tpu.server.cli import _leakmon_config, build_parser
+
+    spec, cfg, ecfg, _, state_bytes = _held_to_its_file(
+        "chipshare-2p21-r2p17-runbook")
+    configs = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks", "configs")
+    parent, ttl, durable = (
+        json.load(open(os.path.join(configs, f"chipshare-2p21-r2p17{x}.json")))
+        for x in ("", "-ttl1d", "-durable"))
+    assert spec["reduced"] == {} and spec["chips"] == 1
+    assert spec["grapevine_config"] == ttl["grapevine_config"] == dict(
+        parent["grapevine_config"], expiry_period=86400)
+    assert durable["grapevine_config"] == parent["grapevine_config"]
+    assert cfg.expiry_period == 86400
+    said = spec["resolves_to"]
+    for tree in ("records", "mailbox"):
+        assert said[tree] == parent["resolves_to"][tree] == (
+            ttl["resolves_to"][tree]) == durable["resolves_to"][tree]
+    assert said["state_bytes"] == parent["resolves_to"]["state_bytes"]
+    assert state_bytes == said["state_bytes"] + _mailbox_pad_bytes(ecfg)
+    assert state_bytes == 10_270_604_656
+    assert said["journal_frame_bytes"] == (
+        durable["resolves_to"]["journal_frame_bytes"]) == 2_089_037
+    # a sweep's frame: header, seal, kind and three words
+    assert said["sweep_frame_bytes"] == _HEADER.size + _SEAL_OVERHEAD + 13
+    # the guarantees: the parent's, the two siblings' own word for word,
+    # and the auditor's
+    g = spec["guarantees"]
+    for k, v in parent["guarantees"].items():
+        assert g[k] == v
+    assert g["expiry"] == ttl["guarantees"]["expiry"]
+    assert g["durability"] == durable["guarantees"]["durability"]
+    assert "PASS" in g["audit"] and "dropped" in g["audit"]
+    assert set(g) == set(parent["guarantees"]) | {
+        "expiry", "durability", "audit"}
+    # the server's three mappings
+    server = spec["server"]
+    assert set(server) == {"trace_ring_size", "durability", "leakmon"}
+    assert server["trace_ring_size"] == parent["server"]["trace_ring_size"]
+    fields, theirs = server["durability"], durable["server"]["durability"]
+    assert {k: v for k, v in fields.items() if k != "state_dir"} == {
+        k: v for k, v in theirs.items() if k != "state_dir"}
+    dcfg = DurabilityConfig.coerce(fields)
+    assert "/.scratch/backlog-runbook-1chip-2p21/" in dcfg.state_dir
+    assert dcfg.journal_fsync_every == 1
+    assert dcfg.checkpoint_every_rounds == 8192 == (
+        spec["assumed"]["checkpoint_every_rounds"]["N"])
+    # `--leakmon` with no other flag: every documented default
+    args = build_parser().parse_args(["--leakmon"])
+    assert LeakMonitorConfig.coerce(server["leakmon"]) == (
+        _leakmon_config(args)) == LeakMonitorConfig()
+    # what its siblings assume, it assumes
+    for k in ("max_recipients", "batch_size", "tree_density",
+              "scheduler_window"):
+        assert spec["assumed"][k] == parent["assumed"][k]
+    assert spec["assumed"]["journal"] == durable["assumed"]["journal"]
+    assert len(spec["source"]) < 200
+
+
 def test_the_four_chip_host_at_each_chips_share_resolves_to_what_its_file_says():
     """``benchmarks/configs/host4-sharded-2p23.json`` is one four-chip
     host of the v5e-8 bus, half of it, with each chip holding exactly
