@@ -121,6 +121,25 @@ def RANGELINT_BOUNDS(ecfg: EngineConfig) -> dict:
     }
 
 
+def _first_appearance_ids(rows: np.ndarray, mask: np.ndarray) -> np.ndarray:
+    """i64[B] group ids of the rows of ``rows`` under ``mask``: equal
+    rows share an id, ids count up in order of first appearance (what a
+    dictionary filled in slot order would hand out), ``-1`` outside the
+    mask. One ``np.unique`` over the rows seen as single items of the
+    row's width in bytes."""
+    ids = np.full(mask.shape, -1, np.int64)
+    idx = np.flatnonzero(mask)
+    if idx.size:
+        picked = np.ascontiguousarray(rows[idx])
+        items = picked.view(f"V{picked.shape[1] * picked.itemsize}")
+        _, first, inverse = np.unique(
+            items.ravel(), return_index=True, return_inverse=True)
+        rank = np.empty(first.size, np.int64)
+        rank[np.argsort(first)] = np.arange(first.size)
+        ids[idx] = rank[inverse]
+    return ids
+
+
 def transcript_key_groups(batch: dict, mb_choices: int):
     """Host-side mirror of this step's key selection, for the leak
     monitor (obs/leakmon.py).
@@ -141,9 +160,14 @@ def transcript_key_groups(batch: dict, mb_choices: int):
       ops group by ``msg_id`` (one msg_id = one PRP-resolved block).
       CREATE (allocates a fresh block) and zero-id ops (block selected
       inside the oblivious round) are not host-resolvable → ``-1``.
-    - ``*_stable``: per-slot cross-round-stable ids (bytes) for the
-      repeat tracker, ``None`` where keyless.
+    - ``*_stable``: per-slot cross-round-stable ids for the repeat
+      tracker, one row of u32 words a slot: ``mb_stable`` u32[B·D, 9]
+      (the eight ``ka`` words, then the choice column), ``rec_stable``
+      u32[B, 4] (the ``msg_id`` words). A keyless slot's row is read by
+      nobody.
 
+    Array work over the batch's columns: no statement runs once per op
+    (the loop this replaced is the oracle in tests/test_leakmon.py).
     The key material stays in process memory (the monitor's standing —
     same as the position map); only windowed aggregates are exported.
     """
@@ -151,32 +175,24 @@ def transcript_key_groups(batch: dict, mb_choices: int):
     auth = np.asarray(batch["auth"], dtype=np.uint32)
     recipient = np.asarray(batch["recipient"], dtype=np.uint32)
     msg_id = np.asarray(batch["msg_id"], dtype=np.uint32)
-    b = rt.shape[0]
     is_real = (rt >= C.REQUEST_TYPE_CREATE) & (rt <= C.REQUEST_TYPE_DELETE)
     is_create = rt == C.REQUEST_TYPE_CREATE
     id_zero = ~msg_id.any(axis=1)
     ka = np.where((is_create | ~id_zero)[:, None], recipient, auth)
 
     d = mb_choices
-    mb_keys = np.full((b * d,), -1, np.int64)
-    mb_stable: list[bytes | None] = [None] * (b * d)
-    mb_groups: dict[bytes, int] = {}
-    rec_keys = np.full((b,), -1, np.int64)
-    rec_stable: list[bytes | None] = [None] * b
-    rec_groups: dict[bytes, int] = {}
-    for j in range(b):
-        if not is_real[j]:
-            continue
-        kb = ka[j].tobytes()
-        g = mb_groups.setdefault(kb, len(mb_groups))
-        for c in range(d):
-            mb_keys[j * d + c] = g * d + c
-            mb_stable[j * d + c] = kb + bytes([c])
-        if not is_create[j] and not id_zero[j]:
-            mid = msg_id[j].tobytes()
-            rec_keys[j] = rec_groups.setdefault(mid, len(rec_groups))
-            rec_stable[j] = mid
-    return (mb_keys, mb_stable), (rec_keys, rec_stable)
+    choice = np.arange(d, dtype=np.int64)
+    group = _first_appearance_ids(ka, is_real)
+    mb_keys = np.where(
+        group[:, None] >= 0, group[:, None] * d + choice, -1
+    ).ravel()
+    mb_stable = np.concatenate(
+        [np.repeat(ka, d, axis=0),
+         np.tile(choice.astype(np.uint32), ka.shape[0])[:, None]],
+        axis=1,
+    )
+    rec_keys = _first_appearance_ids(msg_id, is_real & ~is_create & ~id_zero)
+    return (mb_keys, mb_stable), (rec_keys, msg_id)
 
 
 def engine_round_step(

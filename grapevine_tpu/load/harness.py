@@ -278,7 +278,7 @@ class ProbeCampaignInjector:
     interface the engine hands transcripts to (engine.attach_leakmon
     accepts it transparently) and rewrites each round's transcript
     *copy* before delegating: every real op's mailbox fetch slots are
-    pinned to one remembered leaf per (key, choice column) — the
+    pinned to one leaf per key, a function of the key alone — the
     steady-state signature of a broken remap/dedup path. Same-key
     collision AND cross-round repeat statistics are driven toward 1 on
     the ``mb`` stream, so the monitor must flip SUSPECT within its
@@ -293,7 +293,6 @@ class ProbeCampaignInjector:
         self.monitor = monitor
         self._d = int(ecfg.mb_choices)
         self._mb_leaves = int(ecfg.mb.leaves)
-        self._pinned: dict = {}
 
     # engine-facing surface (PendingRound.resolve duck-types these)
     @property
@@ -326,15 +325,11 @@ class ProbeCampaignInjector:
         (mb_keys, mb_stable), _ = transcript_key_groups(
             {k: np.asarray(v) for k, v in batch.items()
              if k in ("req_type", "auth", "msg_id", "recipient")}, d)
-        for slot in np.nonzero(mb_keys >= 0)[0]:
-            j, c = divmod(int(slot), d)
-            stable = mb_stable[slot]
-            leaf = self._pinned.setdefault(
-                stable,
-                int.from_bytes(stable[:4], "little") % self._mb_leaves,
-            )
-            tr[j, c] = leaf           # mailbox round A column
-            tr[j, d + 1 + c] = leaf   # mailbox round C column
+        # one leaf a key, the same in every round: the key's first word
+        real = mb_keys.reshape(-1, d) >= 0
+        leaf = mb_stable[:, 0].reshape(-1, d) % self._mb_leaves
+        tr[:, :d] = np.where(real, leaf, tr[:, :d])          # round A
+        tr[:, d + 1:] = np.where(real, leaf, tr[:, d + 1:])  # round C
         return self.monitor.submit_round(
             batch, tr, n_real, batch_size, phases, queue_depth)
 

@@ -52,12 +52,14 @@ import math
 import queue
 import threading
 import time
-from collections import OrderedDict, deque
+from bisect import bisect_left, insort
+from collections import deque
 
 import numpy as np
 
 from ..testing.leakcheck import (
     _leaf_hist,
+    first_of_each_key,
     samekey_collision_counts,
     uniformity_z_from_counts,
 )
@@ -133,12 +135,160 @@ class LeakMonitorConfig:
         return cls(**dict(value))
 
 
+class _RepeatTable:
+    """The repeat tracker's LRU over stable key ids, in arrays.
+
+    Holds at most ``track`` keys, each with the leaf its last access
+    showed and a stamp that counts accesses: the least recently touched
+    key is the one with the smallest stamp. ``keys`` is kept sorted (a
+    1-D array of ints, or of one fixed-width ``V<n>`` item a key for
+    ids given as rows of words), ``leaf`` and ``stamp`` lie beside it.
+    Private host state, like the posmap; only the windowed rate leaves
+    the module.
+    """
+
+    __slots__ = ("track", "keys", "leaf", "stamp", "_clock")
+
+    def __init__(self, track: int):
+        self.track = track
+        self.keys = None  # takes the first call's dtype
+        self.leaf = np.zeros((0,), np.int64)
+        self.stamp = np.zeros((0,), np.int64)
+        self._clock = 0
+
+    def by_recency(self) -> tuple[np.ndarray, np.ndarray]:
+        """(keys, leaves), least recently touched first."""
+        if self.keys is None:
+            return np.zeros((0,), np.int64), self.leaf
+        order = np.argsort(self.stamp)
+        return self.keys[order], self.leaf[order]
+
+    def touch(self, keys: np.ndarray, leaves: np.ndarray) -> tuple[int, int]:
+        """Look up, judge and re-insert one call's distinct ``keys`` in
+        the order given; returns ``(repeats, opportunities)``.
+
+        The statistic is that of touching the keys one at a time — pop
+        the key (an opportunity if it was there, a repeat if its leaf
+        was this one), insert it as the most recent, evict the least
+        recent while more than ``track`` are held — so a key among the
+        oldest can be evicted by an earlier miss of the same call and
+        then count as a miss. Evictions take the table's oldest entries
+        in order, skipping those an earlier key of the call has touched,
+        and each miss beyond a full table causes one: with at most E of
+        them in the call, nothing more recent than the E-th oldest entry
+        that the call does not touch can leave. Keys found beyond that
+        zone are hits against the table as it stood; only those inside
+        it go through the sequential rule, in a loop that carries the
+        count of misses so far and the ranks already rescued.
+        """
+        m = keys.size
+        if self.keys is None:
+            self.keys = keys[:0].copy()
+        s = self.keys.size
+        pos = np.searchsorted(self.keys, keys)
+        found = np.zeros((m,), bool)
+        if s:
+            inside = pos < s
+            found[inside] = self.keys[pos[inside]] == keys[inside]
+        hit = found.copy()
+        old_stamp = self.stamp[pos[found]]
+        if s + m > self.track and old_stamp.size:
+            untouched = np.ones((s,), bool)
+            untouched[pos[found]] = False
+            free = np.sort(self.stamp[untouched])
+            # the call's misses number at most its keys not found plus
+            # the found ones the zone can reach; shrink the zone with
+            # that bound until it stops shrinking
+            in_zone = np.ones(old_stamp.shape, bool)
+            sure_misses = s + m - old_stamp.size - self.track
+            while True:
+                evictions = sure_misses + int(in_zone.sum())
+                if evictions <= 0:
+                    in_zone[:] = False
+                    break
+                if evictions > free.size:
+                    break  # the whole table can go
+                shrunk = old_stamp < free[evictions - 1]
+                if shrunk.sum() == in_zone.sum():
+                    break
+                in_zone = shrunk
+            if in_zone.any():
+                zone_at = np.flatnonzero(found)[in_zone]
+                # misses before each zone key's turn among the keys not
+                # found: a found key adds nothing to its own count
+                hit[zone_at] = self._zone_hits(
+                    old_stamp[in_zone], np.cumsum(~found)[zone_at])
+        repeats = int(np.sum(self.leaf[pos[hit]] == leaves[hit]))
+        opportunities = int(hit.sum())
+
+        # the table afterwards does not depend on which of the found
+        # keys were evicted first: every key of the call ends as the
+        # most recent, in the call's order, and the least recent of the
+        # rest leave until ``track`` are held
+        stamps = self._clock + np.arange(m, dtype=np.int64)
+        self._clock += m
+        self.leaf[pos[found]] = leaves[found]
+        self.stamp[pos[found]] = stamps[found]
+        new = np.flatnonzero(~found)
+        if not new.size:
+            return repeats, opportunities  # nothing enters, nothing leaves
+        new = new[np.argsort(keys[new])]
+        # merge the keys not found into the sorted table, then drop the
+        # least recent down to ``track``: one mask of who stays, old
+        # entries and new alike, laid out in key order
+        at = pos[new] + np.arange(new.size)
+        is_new = np.zeros((s + new.size,), bool)
+        is_new[at] = True
+        stamp = np.empty((s + new.size,), np.int64)
+        stamp[at] = stamps[new]
+        stamp[~is_new] = self.stamp
+        over = stamp.size - self.track
+        keep = np.ones(stamp.shape, bool) if over <= 0 else (
+            stamp >= np.partition(stamp, over)[over])
+        kept_old, kept_new = keep[~is_new], new[keep[at]]
+        from_new = is_new[keep]
+
+        def merged(old_column, call_column):
+            column = np.empty(from_new.shape, old_column.dtype)
+            column[from_new] = call_column[kept_new]
+            column[~from_new] = old_column[kept_old]
+            return column
+
+        self.keys = merged(self.keys, keys)
+        self.leaf = merged(self.leaf, leaves)
+        self.stamp = stamp[keep]
+        return repeats, opportunities
+
+    def _zone_hits(self, zone_stamp, missed_before) -> np.ndarray:
+        """Which of the call's keys found in the oldest zone are still
+        there when their turn comes (bool, one per zone key, in the
+        call's order), from the stamps they were found under and the
+        misses the call's other keys cause before each. A key of rank p
+        among the table's entries, oldest first, is gone iff the
+        evictions so far outnumber the entries before it that no
+        earlier key of the call rescued."""
+        rank = np.searchsorted(np.sort(self.stamp), zone_stamp)
+        room = self.track - self.stamp.size
+        hits = []
+        rescued: list[int] = []
+        zone_misses = 0
+        for p, before in zip(rank.tolist(), missed_before.tolist()):
+            evicted = before + zone_misses - room
+            alive = evicted <= p - bisect_left(rescued, p)
+            if alive:
+                insort(rescued, p)
+            else:
+                zone_misses += 1
+            hits.append(alive)
+        return np.array(hits, bool)
+
+
 class _Stream:
     """Sliding-window state for one leaf space (one ORAM tree)."""
 
     __slots__ = (
         "n_leaves", "bins", "window", "hist_sum", "collisions", "pairs",
-        "repeats", "opportunities", "last_leaf", "_window_max", "_track",
+        "repeats", "opportunities", "last_leaf", "_window_max",
     )
 
     def __init__(self, n_leaves: int, bins: int, window: int, track: int):
@@ -154,8 +304,7 @@ class _Stream:
         self.pairs = 0
         self.repeats = 0
         self.opportunities = 0
-        self.last_leaf: OrderedDict = OrderedDict()
-        self._track = track
+        self.last_leaf = _RepeatTable(track)
 
 
 class TranscriptLeakMonitor:
@@ -235,10 +384,12 @@ class TranscriptLeakMonitor:
         sequence). ``keys``: per-leaf within-round key group ids,
         ``-1`` = no key (padding / host-unresolvable); None disables the
         keyed detectors for this call. ``stable``: optional per-leaf
-        cross-round-stable ids (hashable; e.g. recipient-key bytes) for
-        the repeat tracker — defaults to the key group values, which is
-        only correct when the caller's group ids are themselves stable
-        across rounds (block indices in the oram-level tests)."""
+        cross-round-stable ids for the repeat tracker, an array with one
+        row of fixed-width words a leaf (e.g. the recipient key's words;
+        the same width in every call of a stream) — defaults to the key
+        group values, which is only correct when the caller's group ids
+        are themselves stable across rounds (block indices in the
+        oram-level tests)."""
         st = self._streams[tree]  # KeyError = undeclared stream, loudly
         leaves = np.asarray(leaves, np.int64).ravel()
         hist = _leaf_hist(leaves, st.n_leaves, st.bins)
@@ -271,26 +422,19 @@ class TranscriptLeakMonitor:
     def _track_repeats(self, st: _Stream, keys, leaves, stable):
         """Cross-round freshness: compare each key's authoritative
         (first-occurrence — the real path fetch; later occurrences are
-        dummies) leaf against its previous round's. The tracker is an
-        LRU over stable key ids — private host state, like the posmap;
-        only the windowed rate leaves this module."""
-        repeats = opportunities = 0
-        real_idx = np.nonzero(keys >= 0)[0]
+        dummies) leaf against its previous round's, the round's keys
+        taken in the order of their group ids."""
+        real_idx = np.flatnonzero(keys >= 0)
         if real_idx.size == 0:
             return 0, 0
-        _, first = np.unique(keys[real_idx], return_index=True)
-        for i in real_idx[first]:
-            skey = stable[i] if stable is not None else int(keys[i])
-            leaf = int(leaves[i])
-            prev = st.last_leaf.pop(skey, None)
-            if prev is not None:
-                opportunities += 1
-                if prev == leaf:
-                    repeats += 1
-            st.last_leaf[skey] = leaf
-            while len(st.last_leaf) > st._track:
-                st.last_leaf.popitem(last=False)
-        return repeats, opportunities
+        at = real_idx[first_of_each_key(keys[real_idx])]
+        if stable is None:
+            ids = keys[at]
+        else:
+            rows = np.ascontiguousarray(np.asarray(stable)[at]).reshape(
+                at.size, -1)
+            ids = rows.view(f"V{rows.shape[1] * rows.itemsize}").ravel()
+        return st.last_leaf.touch(ids, leaves[at])
 
     def _export_locked(self, tree: str, st: _Stream) -> None:
         if self._g_collision is None:

@@ -45,15 +45,55 @@ def samekey_leaf_collisions(keys: np.ndarray, leaves: np.ndarray) -> int:
     return int(np.sum(same_key & same_leaf & upper))
 
 
+def _run_starts(sorted_values: np.ndarray) -> np.ndarray:
+    """Where each run of equal values of a sorted, non-empty array
+    begins."""
+    return np.flatnonzero(np.concatenate(
+        ([True], sorted_values[1:] != sorted_values[:-1])))
+
+
+def _pairs_in_runs(sorted_values: np.ndarray) -> int:
+    """Sum of c·(c−1)/2 over the runs of equal values of a sorted array."""
+    counts = np.diff(_run_starts(sorted_values), append=sorted_values.size)
+    return int(np.sum(counts * (counts - 1) // 2))
+
+
+def _fold(keys: np.ndarray, low: np.ndarray, span: int) -> np.ndarray:
+    """``keys * span + low`` as i64, for ``0 <= low < span``: one value
+    to sort by key and then by ``low``. A product that would leave an
+    i64 raises."""
+    if int(keys.max()) > (np.iinfo(np.int64).max - span) // span:
+        raise ValueError(
+            f"key {int(keys.max())} x span {span} does not fit an i64"
+        )
+    return keys.astype(np.int64, copy=False) * span + low
+
+
+def first_of_each_key(keys: np.ndarray) -> np.ndarray:
+    """Index of the first occurrence of each distinct value of the
+    non-negative ``keys``, in ascending order of the values — what
+    ``np.unique(keys, return_index=True)`` gives, from a plain sort of
+    key and index folded into one i64 instead of a stable argsort."""
+    n = keys.size
+    folded = np.sort(_fold(keys, np.arange(n, dtype=np.int64), n))
+    return folded[_run_starts(folded // n)] % n
+
+
 def samekey_collision_counts(
     keys: np.ndarray, leaves: np.ndarray
 ) -> tuple[int, int]:
     """(collisions, same-key pairs) for one round — the streaming form.
 
     Same statistic as :func:`samekey_leaf_collisions` plus the pair
-    denominator, but grouped (O(B log B)) instead of all-pairs (O(B²))
-    so the continuous monitor (obs/leakmon.py) can afford it every
-    round at production batch sizes. Entries with ``keys < 0`` are
+    denominator, but from ONE 1-D sort (O(B log B)) instead of all
+    pairs (O(B²)) so the continuous monitor (obs/leakmon.py) can afford
+    it every round at production batch sizes: key and leaf are folded
+    into one i64, ``key * span + leaf`` with ``span`` one more than the
+    largest leaf, so equal (key, leaf) pairs are the runs of the sorted
+    array and equal keys the runs of its quotient by ``span``. The
+    monitor's group ids are under B·D ≤ 2^13 and its leaves under 2^21;
+    a pair whose product would leave i64 raises (as does a negative
+    leaf: a transcript leaf is a u32). Entries with ``keys < 0`` are
     excluded (the caller's "no key" sentinel for padding dummies and
     host-unresolvable ops); the quadratic detector instead counts
     whatever key values it is given, so callers there mask dummies
@@ -62,21 +102,14 @@ def samekey_collision_counts(
     keys = np.asarray(keys).ravel()
     leaves = np.asarray(leaves).ravel()
     real = keys >= 0
-    k, lf = keys[real], leaves[real]
+    k, lf = keys[real], leaves[real].astype(np.int64, copy=False)
     if k.size < 2:
         return 0, 0
-
-    def _pairs(counts: np.ndarray) -> int:
-        counts = counts.astype(np.int64)
-        return int(np.sum(counts * (counts - 1) // 2))
-
-    _, key_counts = np.unique(k, return_counts=True)
-    _, pair_counts = np.unique(
-        np.stack([k.astype(np.int64), np.asarray(lf, np.int64)], axis=1),
-        axis=0,
-        return_counts=True,
-    )
-    return _pairs(pair_counts), _pairs(key_counts)
+    if int(lf.min()) < 0:
+        raise ValueError("transcript leaves are non-negative")
+    span = int(lf.max()) + 1
+    combined = np.sort(_fold(k, lf, span))
+    return _pairs_in_runs(combined), _pairs_in_runs(combined // span)
 
 
 def cross_round_repeat_rate(leaf_seq: np.ndarray) -> float:

@@ -18,26 +18,35 @@ parameters, so the monitor is auditing the real round code path:
 - the streaming collision counter agrees with the quadratic pytest
   detector; the flight recorder enforces its batch-level schema so a
   dump can never carry logical keys, recipient ids, or per-op
-  timestamps.
+  timestamps;
+- the auditor's round is array work (ISSUE 51): the per-op key grouping
+  and the per-key LRU it replaced are kept here as oracles, and the
+  array forms are held to them element for element and call for call,
+  the tracker's table overflowing inside calls.
 """
 
 import json
+import zlib
+from collections import OrderedDict
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from grapevine_tpu.engine.round_step import transcript_key_groups
 from grapevine_tpu.obs.flightrec import FlightRecorder
 from grapevine_tpu.obs.leakmon import (
     PASS,
     SUSPECT,
     LeakMonitorConfig,
     TranscriptLeakMonitor,
+    _RepeatTable,
 )
 from grapevine_tpu.obs.registry import TelemetryLeakError, TelemetryRegistry
 from grapevine_tpu.oram.path_oram import OramConfig, init_oram
 from grapevine_tpu.oram.round import oram_round
+from grapevine_tpu.wire import constants as C
 from grapevine_tpu.testing.leakcheck import (
     samekey_collision_counts,
     samekey_leaf_collisions,
@@ -218,6 +227,23 @@ def test_streaming_collision_counts_match_quadratic_detector():
         np.array([-1, -1, 3, 3]), np.array([5, 5, 7, 7])
     )
     assert (coll, pairs) == (1, 1)
+    # the combined key's edge: the auditor's largest group id beside the
+    # largest leaf of the largest tree, next to their neighbours
+    g, lf = 2 ** 13 - 1, 2 ** 21 - 1
+    keys = np.array([g, g, g, g - 1, g - 1, 0, 0, 0])
+    leaves = np.array([lf, lf, lf - 1, lf, lf, 0, lf, 0])
+    coll, pairs = samekey_collision_counts(keys, leaves)
+    assert coll == samekey_leaf_collisions(keys, leaves) == 3
+    assert pairs == 3 + 1 + 3
+    # u32 leaves straight off a transcript fold without wrapping
+    assert samekey_collision_counts(
+        keys, leaves.astype(np.uint32)) == (coll, pairs)
+    # what does not fit an i64 is refused, not wrapped
+    with pytest.raises(ValueError, match="i64"):
+        samekey_collision_counts(
+            np.array([2 ** 62, 2 ** 62]), np.array([lf, lf]))
+    with pytest.raises(ValueError, match="non-negative"):
+        samekey_collision_counts(np.array([1, 1]), np.array([-1, 4]))
 
 
 def test_uniformity_from_counts_matches_pooled_detector():
@@ -252,6 +278,247 @@ def test_undeclared_stream_raises():
     mon = _mon()
     with pytest.raises(KeyError):
         mon.observe("nope", None, np.zeros(4, np.int64))
+
+
+# ---------------------------------------------------------------------
+# the auditor's round in arrays (ISSUE 51): the loops it replaced, kept
+# as the oracles the array forms are held to
+# ---------------------------------------------------------------------
+
+
+def _loop_key_groups(batch: dict, mb_choices: int):
+    """engine/round_step.py ``transcript_key_groups`` as it stood before
+    ISSUE 51: a dictionary operation per op, ``bytes`` ids."""
+    rt = np.asarray(batch["req_type"]).astype(np.uint32)
+    auth = np.asarray(batch["auth"], dtype=np.uint32)
+    recipient = np.asarray(batch["recipient"], dtype=np.uint32)
+    msg_id = np.asarray(batch["msg_id"], dtype=np.uint32)
+    b = rt.shape[0]
+    is_real = (rt >= C.REQUEST_TYPE_CREATE) & (rt <= C.REQUEST_TYPE_DELETE)
+    is_create = rt == C.REQUEST_TYPE_CREATE
+    id_zero = ~msg_id.any(axis=1)
+    ka = np.where((is_create | ~id_zero)[:, None], recipient, auth)
+
+    d = mb_choices
+    mb_keys = np.full((b * d,), -1, np.int64)
+    mb_stable = [None] * (b * d)
+    mb_groups: dict = {}
+    rec_keys = np.full((b,), -1, np.int64)
+    rec_stable = [None] * b
+    rec_groups: dict = {}
+    for j in range(b):
+        if not is_real[j]:
+            continue
+        kb = ka[j].tobytes()
+        g = mb_groups.setdefault(kb, len(mb_groups))
+        for c in range(d):
+            mb_keys[j * d + c] = g * d + c
+            mb_stable[j * d + c] = kb + bytes([c])
+        if not is_create[j] and not id_zero[j]:
+            mid = msg_id[j].tobytes()
+            rec_keys[j] = rec_groups.setdefault(mid, len(rec_groups))
+            rec_stable[j] = mid
+    return (mb_keys, mb_stable), (rec_keys, rec_stable)
+
+
+class _LoopTracker:
+    """obs/leakmon.py ``_track_repeats`` as it stood before ISSUE 51:
+    an ``OrderedDict`` popped, inserted and trimmed once per key."""
+
+    def __init__(self, track: int):
+        self.track = track
+        self.last_leaf: OrderedDict = OrderedDict()
+
+    def touch(self, skeys, leaves):
+        repeats = opportunities = 0
+        for skey, leaf in zip(skeys, leaves):
+            leaf = int(leaf)
+            prev = self.last_leaf.pop(skey, None)
+            if prev is not None:
+                opportunities += 1
+                if prev == leaf:
+                    repeats += 1
+            self.last_leaf[skey] = leaf
+            while len(self.last_leaf) > self.track:
+                self.last_leaf.popitem(last=False)
+        return repeats, opportunities
+
+    def track_repeats(self, keys, leaves, stable):
+        real_idx = np.nonzero(keys >= 0)[0]
+        if real_idx.size == 0:
+            return 0, 0
+        _, first = np.unique(keys[real_idx], return_index=True)
+        at = real_idx[first]
+        return self.touch(
+            [stable[i] if stable is not None else int(keys[i]) for i in at],
+            leaves[at],
+        )
+
+
+def _unique_axis0_collision_counts(keys, leaves):
+    """testing/leakcheck.py ``samekey_collision_counts`` as it stood
+    before ISSUE 51: a structured sort over stacked (key, leaf) rows."""
+    real = keys >= 0
+    k, lf = keys[real], leaves[real]
+    if k.size < 2:
+        return 0, 0
+
+    def _pairs(counts):
+        counts = counts.astype(np.int64)
+        return int(np.sum(counts * (counts - 1) // 2))
+
+    _, key_counts = np.unique(k, return_counts=True)
+    _, pair_counts = np.unique(
+        np.stack([k.astype(np.int64), lf.astype(np.int64)], axis=1),
+        axis=0, return_counts=True)
+    return _pairs(pair_counts), _pairs(key_counts)
+
+
+@pytest.mark.parametrize("track", [3, 16, 64])
+@pytest.mark.parametrize("universe,m_max", [(24, 12), (40, 40), (200, 90)])
+def test_repeat_table_equals_the_per_key_lru_call_for_call(
+    track, universe, m_max
+):
+    """The array table against the OrderedDict it replaced on streams
+    built to overflow inside calls: a call mixes the table's oldest keys
+    with keys it has never held, in an order that lets an earlier miss
+    evict a later key (the sequential rule's case), and some calls hold
+    more keys than the table."""
+    rng = np.random.default_rng(universe * 1000 + m_max * 10 + track)
+    table, loop = _RepeatTable(track), _LoopTracker(track)
+    for call in range(400):
+        m = int(rng.integers(1, m_max + 1))
+        keys = rng.choice(universe, size=min(m, universe), replace=False)
+        if call % 3 == 0 and loop.last_leaf:
+            # the oldest keys the oracle holds, each behind a fresh one
+            oldest = np.array(list(loop.last_leaf)[: m // 2], np.int64)
+            fresh = universe + call * m_max + np.arange(oldest.size)
+            keys = np.stack([fresh, oldest], axis=1).ravel()
+        keys = keys.astype(np.int64)
+        # leaves from a small range, so repeats happen
+        leaves = rng.integers(0, 3, size=keys.size)
+        assert table.touch(keys, leaves) == loop.touch(
+            keys.tolist(), leaves), f"call {call}"
+        held, held_leaf = table.by_recency()
+        assert held.tolist() == list(loop.last_leaf), f"call {call}"
+        assert held_leaf.tolist() == list(loop.last_leaf.values())
+
+
+def _campaign_batch(rng, b, traffic, idents, ids):
+    """One round's host-side columns, no engine: ``idents`` u32[N, 8]
+    the identities, ``ids`` u32[M, 4] the msg_ids in circulation."""
+    n = idents.shape[0]
+    if traffic == "zipf":
+        who = np.minimum(rng.zipf(1.5, size=b) - 1, n - 1)
+    else:
+        who = rng.integers(0, n, size=b)
+    rt = rng.integers(C.REQUEST_TYPE_CREATE, C.REQUEST_TYPE_DELETE + 1,
+                      size=b).astype(np.uint32)
+    msg_id = ids[rng.integers(0, ids.shape[0], size=b)].copy()
+    msg_id[rng.random(b) < 0.5] = 0  # zero-id READ / DELETE
+    if traffic == "padding":
+        rt[rng.random(b) < 0.6] = 0  # padding rows
+        rt[rng.random(b) < 0.05] = 7  # and a type out of range
+    elif traffic == "all_create":
+        rt[:] = C.REQUEST_TYPE_CREATE
+    elif traffic == "all_zero_id":
+        rt[:] = rng.integers(C.REQUEST_TYPE_READ, C.REQUEST_TYPE_DELETE + 1,
+                             size=b)
+        msg_id[:] = 0
+    elif traffic == "dup_msg_ids":
+        rt[:] = rng.integers(C.REQUEST_TYPE_READ, C.REQUEST_TYPE_DELETE + 1,
+                             size=b)
+        msg_id = ids[rng.integers(0, max(2, b // 8), size=b)].copy()
+    return {
+        "req_type": rt,
+        "auth": idents[rng.integers(0, n, size=b)],
+        "recipient": idents[who],
+        "msg_id": msg_id,
+    }
+
+
+def _stable_bytes(stable_rows, at):
+    """A new stable id as the bytes the loop made of it: the ``ka``
+    words then the choice as one byte, or the msg_id's words."""
+    row = stable_rows[at]
+    if row.size == 9:
+        return row[:8].tobytes() + bytes([int(row[8])])
+    return row.tobytes()
+
+
+@pytest.mark.parametrize("track", ["small", "production"])
+@pytest.mark.parametrize("traffic", [
+    "zipf", "uniform", "padding", "all_create", "all_zero_id",
+    "dup_msg_ids",
+])
+@pytest.mark.parametrize("b", [8, 2048])
+@pytest.mark.parametrize("d", [1, 2])
+def test_the_round_in_arrays_equals_the_round_per_key(d, b, traffic, track):
+    """64 consecutive rounds through the monitor as ``_process`` feeds
+    it (mailbox A, records, mailbox C), the table small enough to
+    overflow inside calls: group ids equal element for element,
+    (collisions, pairs, repeats, opportunities) equal call for call, the
+    tracker's table equal in content and recency order at the end."""
+    track_keys = {"small": 4 if b == 8 else 64,
+                  "production": 64 if b == 8 else 8192}[track]
+    rng = np.random.default_rng(
+        zlib.crc32(repr((d, b, traffic, track_keys)).encode()))
+    n_idents = 16 if b == 8 else 65536
+    idents = rng.integers(0, 2 ** 32, size=(n_idents, 8), dtype=np.uint32)
+    idents[0] = 0           # an all-zero key row
+    idents[1, :7] = 0       # and rows that differ in their last word only
+    idents[2] = idents[1]
+    idents[2, 7] ^= 1
+    ids = rng.integers(0, 2 ** 32, size=(4 * b, 4), dtype=np.uint32)
+    mb_leaves, rec_leaves = 1 << 15, 1 << 21
+    mon = TranscriptLeakMonitor(
+        {"rec": rec_leaves, "mb": mb_leaves},
+        LeakMonitorConfig(track_keys=track_keys),
+    )
+    loops = {"rec": _LoopTracker(track_keys), "mb": _LoopTracker(track_keys)}
+    overflowed = 0
+    for r in range(64):
+        batch = _campaign_batch(rng, b, traffic, idents, ids)
+        (mb_keys, mb_stable), (rec_keys, rec_stable) = transcript_key_groups(
+            batch, d)
+        (o_mb_keys, o_mb_stable), (o_rec_keys, o_rec_stable) = (
+            _loop_key_groups(batch, d))
+        np.testing.assert_array_equal(mb_keys, o_mb_keys)
+        np.testing.assert_array_equal(rec_keys, o_rec_keys)
+        assert mb_keys.dtype == rec_keys.dtype == np.int64
+        for slot in np.flatnonzero(mb_keys >= 0)[:: max(1, b // 16)]:
+            assert _stable_bytes(mb_stable, slot) == o_mb_stable[slot]
+        for slot in np.flatnonzero(rec_keys >= 0)[:: max(1, b // 16)]:
+            assert _stable_bytes(rec_stable, slot) == o_rec_stable[slot]
+        # a tenth of a no-remap engine's repeats, so both counts move
+        tr = np.stack(
+            [rng.integers(0, mb_leaves, size=b * d) for _ in range(2)]
+            + [np.resize(rng.integers(0, rec_leaves, size=b), b * d)])
+        if r:
+            keep = rng.random(tr.shape) < 0.1
+            tr = np.where(keep, last_tr, tr)
+        last_tr = tr
+        for tree, keys, leaves, stable, o_stable in (
+            ("mb", mb_keys, tr[0], mb_stable, o_mb_stable),
+            ("rec", rec_keys, tr[2][:b], rec_stable, o_rec_stable),
+            ("mb", mb_keys, tr[1], mb_stable, o_mb_stable),
+        ):
+            before = len(loops[tree].last_leaf)
+            want = _unique_axis0_collision_counts(keys, leaves) + (
+                loops[tree].track_repeats(keys, leaves, o_stable))
+            overflowed += before + np.unique(keys[keys >= 0]).size > track_keys
+            mon.observe(tree, keys, leaves, stable)
+            got = mon._streams[tree].window[-1][1:]
+            assert tuple(int(x) for x in got) == want, (r, tree)
+    if traffic in ("zipf", "uniform", "padding") and track == "small":
+        assert overflowed > 32  # the campaign did overflow inside calls
+    for tree, loop in loops.items():
+        held, held_leaf = mon._streams[tree].last_leaf.by_recency()
+        width = 9 if tree == "mb" else 4
+        rows = held.view(np.uint32).reshape(-1, width) if held.size else []
+        assert [_stable_bytes(rows, i) for i in range(len(rows))] == list(
+            loop.last_leaf), tree
+        assert held_leaf.tolist() == list(loop.last_leaf.values()), tree
 
 
 # ---------------------------------------------------------------------
